@@ -240,6 +240,10 @@ def main(argv=None) -> None:
     args = p.parse_args(argv)
     if not args.data and not args.requests:
         p.error("pass --data and/or --requests")
+    from cloud_server_tpu.utils.platform import (
+        device_line, enable_compile_cache)
+    enable_compile_cache()
+    device_line("evaluate")
 
     with open(args.config) as f:
         model_cfg = from_json(ModelConfig, json.load(f)["model"])

@@ -288,6 +288,11 @@ def load_params(model_cfg, checkpoint_dir: str | None, step: int | None,
 def main(argv=None) -> None:
     args = build_parser().parse_args(argv)
 
+    from cloud_server_tpu.utils.platform import (
+        device_line, enable_compile_cache)
+    enable_compile_cache()
+    device_line("generate")
+
     from cloud_server_tpu.config import InferConfig, ModelConfig, from_json
     from cloud_server_tpu.data.tokenizer import get_tokenizer
     from cloud_server_tpu.inference.server import InferenceServer

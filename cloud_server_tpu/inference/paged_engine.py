@@ -194,9 +194,10 @@ def _write_window(cache: PagedKVCache, layer: int, k, v, pos):
 # (prefill chunks) dispatch the grid-over-(slot, head) wide kernel
 # (ops.paged_attention._paged_attention_wide) — length-bounded page
 # reads instead of the XLA path's full-padded-cache gather per layer
-# per chunk. Beyond this cap (wider than any prefill chunk the server
-# issues) the XLA gather path remains the fallback.
-_PALLAS_MAX_W = 256
+# per chunk. A wider window is refused, never handed to the XLA
+# reference: a config that asks for the kernel either runs the kernel
+# or fails (the paged server checks its prefill chunk at construction).
+PALLAS_MAX_W = 256
 
 
 def window_forward(params, tokens: jnp.ndarray, cfg: ModelConfig,
@@ -248,8 +249,12 @@ def window_forward(params, tokens: jnp.ndarray, cfg: ModelConfig,
     cos, sin = rope_table(cfg, cache.max_context)
     x = params["embed"]["tokens"].astype(cfg.dtype)[tokens]  # (B, W, D)
 
-    use_pallas = (cfg.decode_attention_impl == "pallas"
-                  and w <= _PALLAS_MAX_W)
+    use_pallas = cfg.decode_attention_impl == "pallas"
+    if use_pallas and w > PALLAS_MAX_W:
+        raise ValueError(
+            f"window width {w} exceeds the pallas paged-attention cap "
+            f"({PALLAS_MAX_W}); use a narrower window or "
+            "decode_attention_impl='xla'")
     if pages_per_block is None:
         # wider windows leave less VMEM for the double-buffered page
         # blocks; 8 pages measured fastest at W=1 on v5e

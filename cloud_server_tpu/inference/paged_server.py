@@ -1008,6 +1008,15 @@ class PagedInferenceServer:
         self.prefill_chunk = max(page_size, min(prefill_chunk, max_context))
         if self.prefill_chunk % page_size:
             raise ValueError("prefill_chunk must be a page multiple")
+        if (cfg.decode_attention_impl == "pallas"
+                and self.prefill_chunk > paged_engine.PALLAS_MAX_W):
+            # the widest window this server dispatches; refuse here
+            # rather than at the first long prompt's trace
+            raise ValueError(
+                f"prefill_chunk={self.prefill_chunk} exceeds the pallas "
+                f"paged-attention window cap ({paged_engine.PALLAS_MAX_W})"
+                "; lower prefill_chunk (and page_size with it) or use "
+                "decode_attention_impl='xla'")
         if prompt_buckets is None:
             prompt_buckets = _pow2_buckets(16, max_context)
         self.prompt_buckets = sorted(prompt_buckets)

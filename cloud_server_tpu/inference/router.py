@@ -336,7 +336,11 @@ class ReplicatedRouter:
         replicas = []
         for d in devices:
             local = jax.tree.map(lambda x: jax.device_put(x, d), params)
-            replicas.append(server_cls(local, cfg, infer_cfg, **srv_kw))
+            # the server allocates its cache with jnp.zeros: build it
+            # with `d` as the default device, or every replica's pools
+            # are born on device 0 and only move at the first dispatch
+            with jax.default_device(d):
+                replicas.append(server_cls(local, cfg, infer_cfg, **srv_kw))
         return cls(replicas)
 
     # -- placement ----------------------------------------------------------

@@ -213,9 +213,8 @@ def forward_hidden(params: Params, tokens: jnp.ndarray, cfg: ModelConfig,
     if segment_ids is not None:
         from cloud_server_tpu.ops.segments import positions_from_segments
         positions = positions_from_segments(segment_ids)
-        attn_fn = transformer._packed_attention_fn(cfg, segment_ids)
-    else:
-        attn_fn = transformer._get_attention_fn(cfg)
+    attn_fn = transformer._get_attention_fn(
+        cfg, segment_ids, mesh=transformer.kernel_mesh())
 
     block = partial(_moe_block, cfg=cfg, cos=cos, sin=sin, attn_fn=attn_fn,
                     positions=positions)

@@ -24,6 +24,7 @@ from jax import lax
 from cloud_server_tpu.config import ModelConfig
 from cloud_server_tpu.ops import (apply_rope, causal_attention, rms_norm,
                                   rope_table, swiglu)
+from cloud_server_tpu.parallel.mesh import kernel_mesh
 from cloud_server_tpu.parallel.sharding import constrain
 
 Params = dict
@@ -235,17 +236,23 @@ def apply_remat(block, cfg: ModelConfig):
     raise ValueError(f"unknown remat policy: {cfg.remat!r}")
 
 
-def _get_attention_fn(cfg: ModelConfig, segment_ids=None):
+def _get_attention_fn(cfg: ModelConfig, segment_ids=None, mesh=None):
     """The one attention-impl dispatch table, with or without a packed
     segment mask (both callers — plain and packed forward — use this, so
-    segment support for a new impl lands everywhere at once)."""
+    segment support for a new impl lands everywhere at once). `mesh`
+    (parallel.mesh.kernel_mesh(), from callers that trace under plain
+    jit) runs the flash kernel per device under shard_map; callers
+    already inside a shard_map (the pipeline) pass none."""
     if cfg.attention_impl == "xla":
         if segment_ids is None:
             return causal_attention
         return partial(causal_attention, segment_ids=segment_ids)
     if cfg.attention_impl == "flash":
-        from cloud_server_tpu.ops.flash_attention import flash_attention
-        return partial(flash_attention, segment_ids=segment_ids,
+        from cloud_server_tpu.ops.flash_attention import (
+            flash_attention, flash_attention_sharded)
+        fn = (flash_attention if mesh is None
+              else partial(flash_attention_sharded, mesh=mesh))
+        return partial(fn, segment_ids=segment_ids,
                        block_q=cfg.flash_block_q,
                        block_kv=cfg.flash_block_kv)
     if cfg.attention_impl == "ring":
@@ -320,9 +327,7 @@ def forward_hidden(params: Params, tokens: jnp.ndarray,
     if segment_ids is not None:
         from cloud_server_tpu.ops.segments import positions_from_segments
         positions = positions_from_segments(segment_ids)
-        attn_fn = _packed_attention_fn(cfg, segment_ids)
-    else:
-        attn_fn = _get_attention_fn(cfg)
+    attn_fn = _get_attention_fn(cfg, segment_ids, mesh=kernel_mesh())
 
     block = partial(_block, cfg=cfg, cos=cos, sin=sin, attn_fn=attn_fn,
                     positions=positions)
@@ -483,12 +488,16 @@ def pallas_cross_entropy(x, params: Params, batch: dict,
     online-logsumexp kernel — no f32 logits in HBM, and the backward's
     matmuls run in the model dtype (its one (B*S, V) buffer is the
     model-dtype d_logits; see ops/fused_ce.py)."""
-    from cloud_server_tpu.ops.fused_ce import fused_ce_stats
+    from cloud_server_tpu.ops.fused_ce import (
+        fused_ce_stats, fused_ce_stats_sharded)
 
     b, s = batch["tokens"].shape
     targets, mask = _shifted_targets_mask(batch)
     head = _unembed_head(params, cfg).astype(cfg.dtype)
-    logz, target_logit, argmax_idx = fused_ce_stats(
+    mesh = kernel_mesh()
+    stats = (fused_ce_stats if mesh is None
+             else partial(fused_ce_stats_sharded, mesh=mesh))
+    logz, target_logit, argmax_idx = stats(
         x.reshape(b * s, -1), head, targets.reshape(-1))
     return _stats_loss(logz.reshape(b, s), target_logit.reshape(b, s),
                        argmax_idx.reshape(b, s), targets, mask,

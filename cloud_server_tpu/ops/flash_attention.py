@@ -185,6 +185,7 @@ def _fwd(q, k, v, segment_ids, *, scale, block_q, block_kv, interpret):
             _vmem((block_q, 128), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_fwd",
     )(*inputs)
     # Named so remat policies can choose to save these instead of re-running
     # the kernel in the backward pass (see models/transformer.py remat="dots").
@@ -393,6 +394,7 @@ def _flash_bwd_rule(scale, block_q, block_kv, interpret, res, do):
         scratch_shapes=[_vmem((block_kv, d), jnp.float32),
                         _vmem((block_kv, d), jnp.float32)],
         interpret=interpret,
+        name="flash_bwd_dkdv",
     )(q, k, v, out, lse, do, *seg_inputs)
 
     # Pass 2 (q-stationary, kv innermost): dq accumulates in scratch.
@@ -415,6 +417,7 @@ def _flash_bwd_rule(scale, block_q, block_kv, interpret, res, do):
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[_vmem((block_q, d), jnp.float32)],
         interpret=interpret,
+        name="flash_bwd_dq",
     )(q, k, v, out, lse, do, *seg_inputs)
     dk = dk_h.reshape(b, kh, g, skv, d).sum(axis=2).astype(k.dtype)
     dv = dv_h.reshape(b, kh, g, skv, d).sum(axis=2).astype(v.dtype)
@@ -451,6 +454,7 @@ def _flash_bwd_fused(q, k, v, segment_ids, out, lse, do, *, scale,
                    jax.ShapeDtypeStruct((b, h, sq, d), jnp.float32),
                    jax.ShapeDtypeStruct((b, h, sq, d), jnp.float32)],
         interpret=interpret,
+        name="flash_bwd_fused",
     )(*inputs)
     dk = dk_h.reshape(b, kh, g, sq, d).sum(axis=2).astype(k.dtype)
     dv = dv_h.reshape(b, kh, g, sq, d).sum(axis=2).astype(v.dtype)
@@ -484,3 +488,28 @@ def flash_attention(q, k, v, *, scale=None, block_q: int = 1024,
            else jnp.asarray(segment_ids, jnp.int32))
     out = _flash_bhsd(qt, kt, vt, seg, scale, block_q, block_kv, interpret)
     return out.transpose(0, 2, 1, 3)
+
+
+def flash_attention_sharded(q, k, v, mesh, *, segment_ids=None,
+                            batch_axes=("dp", "fsdp"), head_axis="tp",
+                            **kwargs):
+    """`flash_attention` under a device mesh. jit cannot partition a
+    Mosaic kernel, so each device runs it on its own block under
+    shard_map: batch over `batch_axes`, heads over `head_axis` (whole GQA
+    groups, so it must divide the kv heads), the sequence whole (sharding
+    it belongs to ring/ulysses). The kernel is independent per (batch,
+    head), so there are no collectives, forward or backward."""
+    from jax.sharding import PartitionSpec as P
+
+    spec = P(batch_axes, None, head_axis, None)
+    args, specs = [q, k, v], [spec, spec, spec]
+    if segment_ids is not None:
+        args.append(segment_ids)
+        specs.append(P(batch_axes, None))
+
+    def local(q, k, v, *seg):
+        return flash_attention(q, k, v, segment_ids=seg[0] if seg else None,
+                               **kwargs)
+
+    return jax.shard_map(local, mesh=mesh, in_specs=tuple(specs),
+                         out_specs=spec, check_vma=False)(*args)

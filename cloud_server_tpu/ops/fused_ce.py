@@ -50,16 +50,10 @@ import numpy as _np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams across versions;
-# resolve whichever this jax ships so the kernel imports everywhere
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-if _CompilerParams is None:  # diagnose clearly at first use, not import
-    def _CompilerParams(*_a, **_k):
-        raise ImportError(
-            "this jax exposes neither pallas.tpu.CompilerParams nor "
-            "TPUCompilerParams; the fused-CE pallas kernels need one — "
-            "use ce_impl='dense' or change jax versions")
+# dtype-determined MXU precision: under a global
+# jax_default_matmul_precision="highest" a plain jnp.dot of bf16 tiles
+# asks Mosaic for an fp32 contraction, which it refuses ("Bad lhs type")
+from cloud_server_tpu.ops.flash_attention import _dot
 
 NEG_INF = -1e30
 
@@ -90,8 +84,7 @@ def _fwd_kernel(x_ref, w_ref, t_ref, logz_ref, tl_ref, am_ref,
         amv_ref[:] = jnp.full_like(amv_ref, NEG_INF)
         am_ref[:] = jnp.zeros_like(am_ref)
 
-    logits = jnp.dot(x_ref[:], w_ref[:],
-                     preferred_element_type=jnp.float32)  # (TN, TV)
+    logits = _dot(x_ref[:], w_ref[:], ((1,), (0,)))  # (TN, TV) f32
     cols = j * tv + jax.lax.broadcasted_iota(jnp.int32, logits.shape, 1)
     hit = cols == t_ref[:]  # (TN, 1) broadcasts
     tla_ref[:] += jnp.sum(jnp.where(hit, logits, 0.0), axis=1,
@@ -121,7 +114,7 @@ def _fwd_kernel(x_ref, w_ref, t_ref, logz_ref, tl_ref, am_ref,
 
 
 def _dlogits(x, w, t_col, logz_col, g_col, h_col, j, tv):
-    logits = jnp.dot(x, w, preferred_element_type=jnp.float32)
+    logits = _dot(x, w, ((1,), (0,)))
     p = jnp.exp(logits - logz_col)
     cols = j * tv + jax.lax.broadcasted_iota(jnp.int32, logits.shape, 1)
     d = g_col * p + jnp.where(cols == t_col, h_col, 0.0)
@@ -142,8 +135,7 @@ def _dx_kernel(x_ref, w_ref, t_ref, logz_ref, g_ref, h_ref, dx_ref,
     d = _dlogits(x_ref[:], w_ref[:], t_ref[:], logz_ref[:], g_ref[:],
                  h_ref[:], j, tv)
     d_ref[:] = d
-    dx_ref[:] += jnp.dot(d, w_ref[:].T,
-                         preferred_element_type=jnp.float32)
+    dx_ref[:] += _dot(d, w_ref[:], ((1,), (1,)))  # d @ W^T
 
 
 # ---------------------------------------------------------------------------
@@ -205,9 +197,10 @@ def _fwd(x, head, targets, interpret):
             pltpu.VMEM((tn, 1), jnp.float32),
             pltpu.VMEM((tn, 1), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=100 * 1024 * 1024),
         interpret=interpret,
+        name="fused_ce_fwd",
     )(x, head, t2)
     out = (logz[:, 0], tl[:, 0], am[:, 0])
     return out, (x, head, t2, logz)
@@ -226,7 +219,7 @@ def _bwd(interpret, res, cts):
     # big vocab tiles keep the MXU busy and the grid short
     tv = _pick_tile(v, 3200, 128)
     nr, nv = n // tn, v // tv
-    bwd_params = _CompilerParams(vmem_limit_bytes=100 * 1024 * 1024)
+    bwd_params = pltpu.CompilerParams(vmem_limit_bytes=100 * 1024 * 1024)
     g = d_logz.astype(jnp.float32)[:, None]
     h = d_tl.astype(jnp.float32)[:, None]
     row_specs = [
@@ -251,6 +244,7 @@ def _bwd(interpret, res, cts):
         ],
         compiler_params=bwd_params,
         interpret=interpret,
+        name="fused_ce_dx",
     )(x, head, t2, logz, g, h)
     # dW = x^T @ d over the emitted tiles: one model-dtype matmul XLA
     # already runs near peak — no hand-rolled kernel, and no second
@@ -263,3 +257,21 @@ def _bwd(interpret, res, cts):
 
 
 fused_ce_stats.defvjp(_fwd, _bwd)
+
+
+def fused_ce_stats_sharded(x, head, targets, mesh,
+                           interpret: bool | None = None):
+    """`fused_ce_stats` under a device mesh. jit cannot partition a
+    Mosaic kernel, so the rows are split over EVERY mesh axis (each
+    device owns N / mesh.size rows, a multiple of 128) and each device
+    runs the kernels on its rows against the whole head under shard_map.
+    Rows are independent, so the forward has no collectives; the
+    backward sums the per-device head gradients (shard_map's transpose
+    of the replicated operand)."""
+    from jax.sharding import PartitionSpec as P
+
+    rows = P(mesh.axis_names)
+    return jax.shard_map(
+        lambda x, h, t: fused_ce_stats(x, h, t, interpret), mesh=mesh,
+        in_specs=(P(mesh.axis_names, None), P(), rows),
+        out_specs=(rows, rows, rows), check_vma=False)(x, head, targets)

@@ -373,6 +373,7 @@ def paged_attention(q, k_pool, v_pool, lengths, tables, layer=0, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kh, w * g, d), q.dtype),
         interpret=interpret,
+        name="paged_attention_narrow",
     )(lengths.astype(jnp.int32), tables.astype(jnp.int32),
       widths.astype(jnp.int32),
       jnp.asarray(layer, jnp.int32).reshape(1), *inputs)
@@ -426,6 +427,7 @@ def _paged_attention_wide(qg, k_pool, v_pool, lengths, tables, widths,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kh, wg, d), qg.dtype),
         interpret=interpret,
+        name="paged_attention_wide",
     )(lengths.astype(jnp.int32), tables.astype(jnp.int32),
       widths.astype(jnp.int32),
       jnp.asarray(layer, jnp.int32).reshape(1), *inputs)
@@ -588,12 +590,6 @@ def paged_attention_tp(q, k_pool, v_pool, lengths, tables, layer=0, *,
     split aligns with the KH split).
     """
     from jax.sharding import PartitionSpec as P
-    try:  # jax >= 0.8
-        from jax import shard_map
-        no_check = {"check_vma": False}
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
-        no_check = {"check_rep": False}
 
     kh = k_pool.shape[2]
     h = q.shape[2]
@@ -620,8 +616,8 @@ def paged_attention_tp(q, k_pool, v_pool, lengths, tables, layer=0, *,
             k_scale_pool=scales[0] if scales else None,
             v_scale_pool=scales[1] if scales else None, widths=wid)
 
-    return shard_map(local, mesh=mesh, in_specs=tuple(in_specs),
-                     out_specs=head_spec, **no_check)(*args)
+    return jax.shard_map(local, mesh=mesh, in_specs=tuple(in_specs),
+                         out_specs=head_spec, check_vma=False)(*args)
 
 
 # ---------------------------------------------------------------------------

@@ -5,12 +5,11 @@ One canonical mesh for the whole framework, axes (dp, pp, fsdp, ep, sp, tp)
 lays the logical mesh onto the physical ICI torus so the innermost axes
 (tp, sp) get the shortest links; across slices/hosts the outer axes (dp, pp)
 ride DCN. On CPU (tests / dry-run with --xla_force_host_platform_device_count)
-we fall back to a plain reshape of the device list.
+there is no topology and `create_device_mesh` reshapes the device list as
+is; on TPU devices a layout it cannot place is an error.
 """
 
 from __future__ import annotations
-
-import numpy as np
 
 import jax
 from jax.experimental import mesh_utils
@@ -45,6 +44,19 @@ def maybe_current_mesh() -> Mesh | None:
     return _CURRENT_MESH
 
 
+def kernel_mesh() -> Mesh | None:
+    """The registered mesh if a compiled pallas kernel traced now must be
+    wrapped in shard_map over it, else None. jit cannot partition a
+    Mosaic kernel ("Mosaic kernels cannot be automatically partitioned"),
+    so on TPU, under a mesh of more than one device, each device runs
+    the kernel on its own block. Interpreted kernels (the CPU tests) are
+    plain XLA ops and partition like any other."""
+    mesh = _CURRENT_MESH
+    if mesh is None or mesh.size == 1 or jax.default_backend() != "tpu":
+        return None
+    return mesh
+
+
 def make_mesh(cfg: MeshConfig, devices=None) -> Mesh:
     """Build a named Mesh with canonical axis order from a MeshConfig.
 
@@ -61,10 +73,7 @@ def make_mesh(cfg: MeshConfig, devices=None) -> Mesh:
         )
     devices = devices[:n]
     shape = tuple(cfg.axis_sizes()[a] for a in MeshConfig.AXIS_ORDER)
-    try:
-        dev_array = mesh_utils.create_device_mesh(shape, devices=devices)
-    except (ValueError, AssertionError, NotImplementedError):
-        dev_array = np.asarray(devices).reshape(shape)
+    dev_array = mesh_utils.create_device_mesh(shape, devices=devices)
     return set_current_mesh(Mesh(dev_array, MeshConfig.AXIS_ORDER))
 
 

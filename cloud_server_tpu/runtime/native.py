@@ -1,16 +1,22 @@
 """ctypes bindings for the native C++ shard reader (csrc/data_loader.cpp).
 
 The .so is built on demand with g++ the first time it's needed (one-time
-~2s; cached beside this file). Everything degrades gracefully: if no
+~2s; kept beside this file under a name that carries the hash of the
+source it was built from — a copy or a checkout resets mtimes, so a
+timestamp cannot say whether a library is stale; the content can). If no
 compiler is available or the build fails, `load_library()` returns None
-and callers fall back to the pure-numpy path in `cloud_server_tpu.data`.
+and says so once on stderr, and callers use the pure-numpy reader in
+`cloud_server_tpu.data`.
 """
 
 from __future__ import annotations
 
 import ctypes
+import glob
+import hashlib
 import os
 import subprocess
+import sys
 import threading
 from typing import Iterator
 
@@ -18,21 +24,37 @@ import numpy as np
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_HERE, "csrc", "data_loader.cpp")
-_SO = os.path.join(_HERE, "_native_data_loader.so")
 
 _lock = threading.Lock()
 _lib = None
 _tried = False
 
 
-def _build() -> bool:
+def _so_path() -> str:
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    return os.path.join(_HERE, f"_native_data_loader.{digest}.so")
+
+
+def _build(so: str) -> str | None:
+    """Compile the reader to `so`; None on success, else the reason."""
+    tmp = f"{so}.{os.getpid()}.tmp"
     cmd = ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", "-pthread",
-           _SRC, "-o", _SO]
+           _SRC, "-o", tmp]
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-        return True
-    except (OSError, subprocess.SubprocessError):
-        return False
+        os.replace(tmp, so)  # atomic: a concurrent loader never sees half
+    except subprocess.CalledProcessError as e:
+        return f"g++ failed: {e.stderr.decode(errors='replace')[-300:]}"
+    except (OSError, subprocess.SubprocessError) as e:
+        return f"{type(e).__name__}: {e}"
+    finally:
+        if os.path.exists(tmp):
+            os.remove(tmp)
+    for stale in glob.glob(os.path.join(_HERE, "_native_data_loader*.so")):
+        if stale != so:
+            os.remove(stale)
+    return None
 
 
 def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
@@ -57,20 +79,26 @@ def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
 
 
 def load_library() -> ctypes.CDLL | None:
-    """The native library, building it if needed; None when unavailable."""
+    """The native library, building it if needed; None when unavailable.
+    Says once on stderr which reader is in use."""
     global _lib, _tried
     with _lock:
         if _lib is not None or _tried:
             return _lib
         _tried = True
-        if not os.path.exists(_SO) or (
-                os.path.getmtime(_SO) < os.path.getmtime(_SRC)):
-            if not _build():
-                return None
-        try:
-            _lib = _bind(ctypes.CDLL(_SO))
-        except OSError:
-            _lib = None
+        so = _so_path()
+        why = None if os.path.exists(so) else _build(so)
+        if why is None:
+            try:
+                _lib = _bind(ctypes.CDLL(so))
+            except OSError as e:
+                why = f"cannot load {so}: {e}"
+        if _lib is not None:
+            print(f"[native] C++ shard reader in use "
+                  f"({os.path.basename(so)})", file=sys.stderr)
+        else:
+            print(f"[native] C++ shard reader unavailable ({why}); the "
+                  "numpy reader is in use", file=sys.stderr)
         return _lib
 
 

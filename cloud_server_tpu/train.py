@@ -100,9 +100,13 @@ def configs_from_args(args) -> tuple:
 
 def main(argv=None) -> None:
     args = build_parser().parse_args(argv)
+    from cloud_server_tpu.utils.platform import (
+        device_line, enable_compile_cache)
+    enable_compile_cache()
     if args.distributed:
         from cloud_server_tpu.parallel.distributed import initialize
         initialize()
+    device_line("train")
 
     from cloud_server_tpu.data.dataset import (
         MemmapTokenDataset, MixtureDataset, SyntheticLMDataset)

@@ -21,11 +21,11 @@ from cloud_server_tpu.utils.serving_metrics import (  # noqa: F401
     render_prometheus,
 )
 from cloud_server_tpu.utils.metrics import (  # noqa: F401
-    DEVICE_PEAK_FLOPS,
+    DEVICE_PEAKS,
     MetricAggregator,
     StepTimer,
+    device_peaks,
     param_count,
-    peak_flops_per_device,
     transformer_flops_per_token,
 )
 from cloud_server_tpu.utils.tracing import (  # noqa: F401

@@ -2,16 +2,16 @@
 
 MFU follows the PaLM-style accounting: matmul FLOPs/token = 6·N (2·N
 forward, 4·N backward) plus causal attention score/value FLOPs; the
-denominator is the device's peak bf16 FLOPs (looked up from device_kind,
-overridable). Numbers are comparable across frameworks because nothing
-here depends on how the step is implemented.
+denominator is the device's published peak bf16 FLOP/s (`DEVICE_PEAKS`,
+keyed by device_kind; overridable). Numbers are comparable across
+frameworks because nothing here depends on how the step is implemented.
 """
 
 from __future__ import annotations
 
 import collections
 import time
-from typing import Any
+from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -19,24 +19,41 @@ import numpy as np
 
 from cloud_server_tpu.config import ModelConfig
 
-# Peak dense bf16 FLOPs/s per chip. Extend as hardware appears.
-DEVICE_PEAK_FLOPS: dict[str, float] = {
-    "TPU v4": 275e12,
-    "TPU v5 lite": 197e12,
-    "TPU v5e": 197e12,
-    "TPU v5p": 459e12,
-    "TPU v6 lite": 918e12,
-    "TPU v6e": 918e12,
-    "cpu": 1e11,  # nominal; keeps MFU finite in CPU tests
+
+class DevicePeaks(NamedTuple):
+    bf16_flops: float       # dense bf16 FLOP/s per chip
+    hbm_bytes_per_s: float  # HBM bandwidth per chip
+
+
+# Published per-chip peaks, keyed by `jax.devices()[0].device_kind` as
+# JAX reports it (a v5e reports "TPU v5 lite"; both spellings of a part
+# are listed). Source: Google Cloud TPU documentation, the
+# system-architecture page of each generation ("TPU v4", "TPU v5e",
+# "TPU v5p", "TPU v6e"). The one table for every utilization and
+# roofline figure in the repo (training MFU here, bench.py's sanity
+# floor). A device that is not here is an error, not a default.
+_V5E = DevicePeaks(197e12, 0.819e12)
+_V5P = DevicePeaks(459e12, 2.765e12)
+_V6E = DevicePeaks(918e12, 1.640e12)
+DEVICE_PEAKS: dict[str, DevicePeaks] = {
+    "TPU v4": DevicePeaks(275e12, 1.228e12),
+    "TPU v5 lite": _V5E, "TPU v5e": _V5E,
+    "TPU v5": _V5P, "TPU v5p": _V5P,
+    "TPU v6 lite": _V6E, "TPU v6e": _V6E,
 }
 
 
-def peak_flops_per_device(default: float = 197e12) -> float:
+def device_peaks() -> DevicePeaks:
+    """Peaks of the device this process runs on; ValueError for a
+    device the table does not know (the CPU included — a utilization
+    against an invented peak is not a measurement)."""
     kind = jax.devices()[0].device_kind
-    for name, peak in DEVICE_PEAK_FLOPS.items():
-        if kind.lower().startswith(name.lower()):
-            return peak
-    return default
+    try:
+        return DEVICE_PEAKS[kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device_kind {kind!r}; add it to "
+            "utils.metrics.DEVICE_PEAKS with its source") from None
 
 
 def param_count(params: Any) -> int:
@@ -79,7 +96,11 @@ class StepTimer:
                  peak_flops: float | None = None, window: int = 20):
         self.flops_per_token = flops_per_token
         self.n_devices = n_devices or jax.device_count()
-        self.peak_flops = peak_flops or peak_flops_per_device()
+        if (peak_flops is None and flops_per_token
+                and jax.devices()[0].platform != "cpu"):
+            peak_flops = device_peaks().bf16_flops
+        # None on the CPU: no MFU is reported there, not an invented one
+        self.peak_flops = peak_flops
         self._times: collections.deque = collections.deque(maxlen=window + 1)
         self._tokens: collections.deque = collections.deque(maxlen=window)
         self._times.append(time.perf_counter())
@@ -91,7 +112,7 @@ class StepTimer:
         toks = sum(self._tokens)
         out = {"step_time_s": self._times[-1] - self._times[-2],
                "tokens_per_sec": toks / dt if dt > 0 else 0.0}
-        if self.flops_per_token:
+        if self.flops_per_token and self.peak_flops:
             out["mfu"] = (out["tokens_per_sec"] * self.flops_per_token
                           / (self.peak_flops * self.n_devices))
         return out

@@ -1,5 +1,4 @@
-"""Runtime capability gates for network-dependent tests (the loopback
-sibling of tests/jax_compat.py's version gates).
+"""Runtime capability gates for network-dependent tests.
 
 The streaming-disconnect lifecycle test
 (test_lifecycle.py::test_disconnect_aborts_streaming_request) relies

@@ -44,7 +44,7 @@ def test_headline_printed_before_sections(patched, monkeypatch, capsys):
     stdout) must not prevent the headline: the FIRST JSON line appears
     before any section runs and already carries approx_mfu."""
     def boom():
-        raise RuntimeError("tunnel died")
+        raise RuntimeError("section died")
     monkeypatch.setattr(bench, "serving_bench", boom)
     bench.main()
     lines = _lines(capsys)

@@ -68,8 +68,7 @@ def test_backward_gqa():
                                    rtol=1e-3, err_msg=f"d{name}")
 
 
-@pytest.mark.skipif(jax.default_backend() != "tpu",
-                    reason="compiled-mode Mosaic lowering needs a real TPU")
+@pytest.mark.on_tpu
 def test_compiled_on_tpu():
     """Regression guard for Mosaic lowering: r1's (1, 1, block_q) LSE block
     spec failed to lower on-chip while every interpret-mode test passed."""
@@ -94,8 +93,7 @@ def test_compiled_on_tpu():
                                    err_msg=f"d{name}")
 
 
-@pytest.mark.skipif(jax.default_backend() != "tpu",
-                    reason="compiled-mode Mosaic lowering needs a real TPU")
+@pytest.mark.on_tpu
 def test_segments_compiled_on_tpu():
     """The segment-mask variant must also lower on-chip (its extra
     (bq,1)/(1,bkv) seg block specs are exactly the shape class that broke

@@ -14,10 +14,6 @@ from cloud_server_tpu.config import MeshConfig, ModelConfig, TrainConfig
 from cloud_server_tpu.data.packing import pack_documents
 from cloud_server_tpu.models import transformer
 from cloud_server_tpu.parallel.mesh import make_mesh
-from jax_compat import requires_jax08_shard_map
-
-# whole-module gate: every test here drives jax.shard_map
-pytestmark = requires_jax08_shard_map
 
 
 TINY = ModelConfig(

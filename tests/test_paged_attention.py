@@ -136,12 +136,9 @@ def test_int8_scales_paths(impl):
     np.testing.assert_allclose(got, want, atol=5e-3, rtol=5e-3)
 
 
-@pytest.mark.skipif("config.getoption('--co', default=False)")
+@pytest.mark.on_tpu
 def test_compiled_on_tpu_paged_attention():
-    """Gated: CST_TPU_TESTS=1 runs the real Mosaic lowering on chip."""
-    import os
-    if os.environ.get("CST_TPU_TESTS") != "1":
-        pytest.skip("TPU-gated (set CST_TPU_TESTS=1)")
+    """The narrow kernel's real Mosaic lowering on chip."""
     q, k_pool, v_pool, lengths, tables = _make_case(
         jax.random.key(4), b=4, w=4, h=8, kh=8, d=64, ps=128, mp=4,
         num_pages=32, dtype=jnp.bfloat16)
@@ -154,13 +151,10 @@ def test_compiled_on_tpu_paged_attention():
                                atol=2e-2, rtol=2e-2)
 
 
-@pytest.mark.skipif("config.getoption('--co', default=False)")
+@pytest.mark.on_tpu
 def test_compiled_on_tpu_wide_kernel():
-    """Gated: the wide (grid) kernel's Mosaic lowering on chip, bf16 and
+    """The wide (grid) kernel's Mosaic lowering on chip, bf16 and
     int8, at a prefill-chunk width."""
-    import os
-    if os.environ.get("CST_TPU_TESTS") != "1":
-        pytest.skip("TPU-gated (set CST_TPU_TESTS=1)")
     q, k_pool, v_pool, lengths, tables = _make_case(
         jax.random.key(8), b=4, w=64, h=8, kh=8, d=64, ps=128, mp=4,
         num_pages=32, dtype=jnp.bfloat16)
@@ -240,3 +234,77 @@ def test_ragged_widths_kernel_matches_xla(narrow):
         wi = int(widths[i])
         np.testing.assert_allclose(got[i, :wi], want[i, :wi],
                                    atol=2e-4, rtol=2e-4)
+
+
+def _gqa_case(rng, *, b, w, int8=False):
+    """Llama-3.2-1B attention geometry (32 query / 8 kv heads of 64, G=4,
+    128-token pages) in bf16: the shape the serving path meets on chip."""
+    q, k_pool, v_pool, _, tables = _make_case(
+        rng, b=b, w=w, h=32, kh=8, d=64, ps=128, mp=8, L=2,
+        num_pages=b * 8 + 3, dtype=jnp.bfloat16)
+    return q, k_pool, v_pool, tables
+
+
+def _check_ragged(got, q, k_pool, v_pool, lengths, tables, widths, layer,
+                  atol, **scales):
+    want = paged_attention_xla(
+        q.astype(jnp.float32), k_pool, v_pool, lengths, tables, layer,
+        widths=widths, **scales)
+    for i, wi in enumerate(np.asarray(widths)):
+        np.testing.assert_allclose(
+            np.asarray(got[i, :wi], np.float32), np.asarray(want[i, :wi]),
+            atol=atol, rtol=atol, err_msg=f"row {i} width {wi}")
+
+
+@pytest.mark.on_tpu
+@pytest.mark.parametrize("w", [1, 4])
+def test_compiled_on_tpu_narrow_gqa_ragged(w):
+    """Narrow kernel, GQA row folding (W*G = 4 rows at decode, below the
+    bf16 sublane tile) with ragged per-row widths — the decode and
+    speculative-verify dispatch of a mixed batch."""
+    b = 8
+    q, k_pool, v_pool, tables = _gqa_case(jax.random.key(11), b=b, w=w)
+    widths = jnp.asarray(([1, w, max(w // 2, 1), w] * 2)[:b], jnp.int32)
+    base = jnp.asarray([0, 5, 127, 128, 300, 511, 640, 1000], jnp.int32)
+    lengths = base + widths
+    got = jax.jit(functools.partial(
+        paged_attention, pages_per_block=8, interpret=False))(
+            q, k_pool, v_pool, lengths, tables, 1, widths=widths)
+    _check_ragged(got, q, k_pool.astype(jnp.float32),
+                  v_pool.astype(jnp.float32), lengths, tables, widths, 1,
+                  2e-2)
+
+
+@pytest.mark.on_tpu
+def test_compiled_on_tpu_wide_gqa_ragged():
+    """Wide kernel at the default prefill chunk (W = 256, G = 4: 1,024
+    query rows a cell) with ragged widths — decode rows and prefill
+    rows of a mixed batch in one call."""
+    b, w = 4, 256
+    q, k_pool, v_pool, tables = _gqa_case(jax.random.key(12), b=b, w=w)
+    widths = jnp.asarray([1, 256, 128, 17], jnp.int32)
+    base = jnp.asarray([700, 0, 256, 500], jnp.int32)
+    lengths = base + widths
+    got = jax.jit(functools.partial(
+        paged_attention, pages_per_block=4, interpret=False))(
+            q, k_pool, v_pool, lengths, tables, 0, widths=widths)
+    _check_ragged(got, q, k_pool.astype(jnp.float32),
+                  v_pool.astype(jnp.float32), lengths, tables, widths, 0,
+                  2e-2)
+
+
+@pytest.mark.on_tpu
+def test_compiled_on_tpu_gqa_int8_kv():
+    """int8 KV at the GQA geometry, decode width, narrow kernel."""
+    b = 8
+    q, k_pool, v_pool, tables = _gqa_case(jax.random.key(13), b=b, w=1)
+    kq, ksc = quantize_pool(k_pool.astype(jnp.float32))
+    vq, vsc = quantize_pool(v_pool.astype(jnp.float32))
+    widths = jnp.ones((b,), jnp.int32)
+    lengths = jnp.asarray([1, 6, 128, 129, 301, 512, 641, 1001], jnp.int32)
+    got = jax.jit(functools.partial(
+        paged_attention, pages_per_block=8, interpret=False))(
+            q, kq, vq, lengths, tables, 1, widths=widths,
+            k_scale_pool=ksc, v_scale_pool=vsc)
+    _check_ragged(got, q, kq, vq, lengths, tables, widths, 1, 5e-2,
+                  k_scale_pool=ksc, v_scale_pool=vsc)

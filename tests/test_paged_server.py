@@ -182,6 +182,19 @@ def test_draft_vocab_mismatch_fails_at_construction(params):
                              **SRV_KW)
 
 
+def test_pallas_refuses_chunk_wider_than_kernel(params):
+    """A prefill chunk wider than the kernel's window cap used to hand
+    those dispatches to the XLA reference without a word; a config that
+    asks for the kernel now runs the kernel or fails at construction."""
+    pallas = dataclasses.replace(CFG, decode_attention_impl="pallas")
+    with pytest.raises(ValueError, match="window cap"):
+        PagedInferenceServer(params, pallas, GREEDY, max_slots=2,
+                             max_context=1024, page_size=8,
+                             prefill_chunk=512)
+    PagedInferenceServer(params, CFG, GREEDY, max_slots=2,
+                         max_context=1024, page_size=8, prefill_chunk=512)
+
+
 def test_draft_model_spec_sampled_smoke(params):
     draft_params, draft_cfg = _draft_setup()
     icfg = dataclasses.replace(GREEDY, temperature=0.9, top_k=20)

@@ -9,10 +9,6 @@ from cloud_server_tpu.parallel.pipeline import (
     make_pipelined_forward, make_pipelined_loss)
 from cloud_server_tpu.parallel.sharding import DEFAULT_RULES
 from cloud_server_tpu.training import init_train_state, make_train_step
-from jax_compat import requires_jax08_shard_map
-
-# whole-module gate: every test here drives jax.shard_map
-pytestmark = requires_jax08_shard_map
 
 
 TINY = ModelConfig(

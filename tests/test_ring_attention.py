@@ -9,11 +9,6 @@ from cloud_server_tpu.config import MeshConfig
 from cloud_server_tpu.ops.attention import causal_attention
 from cloud_server_tpu.parallel.mesh import make_mesh
 from cloud_server_tpu.parallel.ring_attention import ring_attention_sharded
-from jax_compat import requires_jax08_shard_map
-
-# whole-module gate: every test here drives jax.shard_map
-pytestmark = requires_jax08_shard_map
-
 
 
 def _rand_qkv(key, b, s, h, kh, d):

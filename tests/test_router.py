@@ -54,6 +54,9 @@ def test_single_replica_reference(router, params):
 def test_least_loaded_placement(params):
     r = ReplicatedRouter.over_devices(
         params, CFG, GREEDY, devices=jax.devices()[:2], **SRV_KW)
+    # each replica's cache is born on its own device, not on device 0
+    for rep, d in zip(r.replicas, jax.devices()[:2]):
+        assert all(x.devices() == {d} for x in jax.tree.leaves(rep.state))
     # replica 0 is busy: 3 queued requests
     for _ in range(3):
         r.replicas[0].submit(PROMPT, max_new_tokens=4)

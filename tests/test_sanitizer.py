@@ -12,10 +12,8 @@ from jax.sharding import PartitionSpec as P
 from cloud_server_tpu.config import MeshConfig
 from cloud_server_tpu.parallel.mesh import make_mesh
 from cloud_server_tpu.utils.failure import CollectiveWatchdog
-from jax_compat import requires_jax08_shard_map
 
 
-@requires_jax08_shard_map
 def test_check_vma_catches_unvaried_carry(devices8):
     """The sanitizer the ring/pipeline wrappers run under (check_vma=True)
     must reject a scan whose carry hides a device-varying value behind an
@@ -36,7 +34,6 @@ def test_check_vma_catches_unvaried_carry(devices8):
             jnp.arange(8.0))
 
 
-@requires_jax08_shard_map
 def test_ring_and_pipeline_run_under_check_vma(devices8):
     """The production wrappers hardcode check_vma=True; a smoke run proves
     the shipped collectives are vma-clean (regression guard: r1 shipped

@@ -10,10 +10,6 @@ from cloud_server_tpu.config import MeshConfig, ModelConfig, TrainConfig
 from cloud_server_tpu.models import transformer
 from cloud_server_tpu.parallel.mesh import make_mesh
 from cloud_server_tpu.training import init_train_state, make_train_step
-from jax_compat import requires_jax08_shard_map
-
-# whole-module gate: every test here drives jax.shard_map
-pytestmark = requires_jax08_shard_map
 
 
 RING = ModelConfig(

@@ -57,6 +57,17 @@ def test_step_timer_tokens_per_sec_and_mfu():
     assert out["step_time_s"] == pytest.approx(0.01, rel=0.5)
 
 
+def test_no_invented_peak_on_cpu():
+    """A device the peaks table does not know is an error where a
+    utilization is asked for, and on the CPU the step timer leaves MFU
+    out instead of dividing by a made-up peak."""
+    from cloud_server_tpu.utils.metrics import device_peaks
+    with pytest.raises(ValueError, match="no published peaks"):
+        device_peaks()
+    out = StepTimer(flops_per_token=1e6).tick(tokens=1000)
+    assert "mfu" not in out and out["tokens_per_sec"] > 0
+
+
 def test_metric_aggregator_means_and_resets():
     agg = MetricAggregator()
     agg.update({"loss": jnp.asarray(2.0), "acc": 0.5})
