@@ -64,7 +64,7 @@ HOT_PATHS: dict[str, tuple[str, ...]] = {
         # completion that carries a (head or provisional) trace
         "TraceRecorder._tail_reason",
     ),
-    # iteration-phase profiler: begin/mark run at every phase
+    # iteration-phase profiler: begin/enter/end run at every phase
     # boundary of every scheduler iteration (the tightest loop this
     # roster covers — a stray allocation or sync here would taint the
     # very attribution it produces); phases_ms feeds the per-busy-
@@ -73,7 +73,9 @@ HOT_PATHS: dict[str, tuple[str, ...]] = {
     # and deliberately absent.
     "cloud_server_tpu/inference/iteration_profile.py": (
         "IterationProfiler.begin",
-        "IterationProfiler.mark",
+        "IterationProfiler.enter",
+        "IterationProfiler.end",
+        "IterationProfiler.close",
         "IterationProfiler.phases_ms",
         "derive_gap_fields",
     ),

@@ -89,7 +89,8 @@ def _mlp_apply(x, lp, cfg: ModelConfig, lora=None):
         from cloud_server_tpu.models import moe
         x, _ = moe.moe_mlp_block(x, lp, cfg)
         return x
-    return transformer.mlp_block(x, lp, cfg, lora=lora)
+    with jax.named_scope("mlp"):
+        return transformer.mlp_block(x, lp, cfg, lora=lora)
 
 
 def init_cache(cfg: ModelConfig, batch: int, max_len: int) -> KVCache:

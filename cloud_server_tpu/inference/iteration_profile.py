@@ -23,7 +23,12 @@ boundaries the scheduler already crosses):
                 through the one sanctioned `device_get` commit point —
                 the only phase that waits on the accelerator
     commit      token emit / grammar / speculation bookkeeping on the
-                synced results
+                synced results, up to the next phase's first statement:
+                the commit function's return included, where the synced
+                device arrays are dropped and the scheduler's thread
+                first gives up the interpreter lock to the streaming
+                threads its emits woke (6 ms of 8 with 64 clients
+                attached; PERF.md, PR 25)
     launch      (async scheduler only) the ledger patch + next-
                 dispatch launch that follows the commit — the tail of
                 the serialized critical path when overlap is on
@@ -47,10 +52,13 @@ becomes `host_ms + device_wait_ms + overlap_ms == duration_ms`.
 Design rules (the metrics layer's own):
 
   * **Stdlib only, zero device work.** The clock is
-    `time.perf_counter`; a phase mark is one clock read and one dict
-    add. The module is on the analysis hot-path lint roster AND the
-    dispatch-discipline host-policy (jax-free) roster; the mixed
-    scheduler's dispatch/sync-count regression test runs a
+    `time.perf_counter`; a phase boundary is one clock read, one dict
+    add and one trace annotation (handed in by the servers, inactive
+    unless a jax profiler capture is running: the same boundary is
+    then a `sched/<phase>` event on the profiler's clock, next to the
+    device's programs). The module is on the analysis hot-path lint
+    roster AND the dispatch-discipline host-policy (jax-free) roster;
+    the mixed scheduler's dispatch/sync-count regression test runs a
     profiling-enabled clone, and a bounded CONSTANT number of clock
     reads per mixed iteration is asserted by monkeypatching
     `perf_counter` (tests/test_iteration_profile.py).
@@ -113,6 +121,12 @@ PHASE_MS_BUCKETS: tuple[float, ...] = (
 PHASE_FAMILY = "iter_phase_ms"
 _FULL_FAMILY = f"cloud_server_{PHASE_FAMILY}"
 
+# The phases' names as events of a jax profiler trace (one per open
+# phase, `iteration=<n>` in their stats) and the event enclosing a
+# step: what `cellbench/hostplane.py` and a Perfetto reader look for.
+_PHASE_EVENTS = {p: "sched/" + p for p in PHASES}
+_ITERATION_EVENT = "sched/iteration"
+
 # Flight-record scalars worth carrying into the Perfetto iteration
 # track's args (post-mortem context next to the phase bars).
 _ITER_ARG_KEYS = ("iteration", "scheduler", "n_live", "decode_rounds",
@@ -126,37 +140,100 @@ _ITER_ARG_KEYS = ("iteration", "scheduler", "n_live", "decode_rounds",
 class IterationProfiler:
     """Host-side phase clock for one scheduler iteration.
 
-    `begin()` opens the iteration; `mark(phase)` attributes the time
-    since the previous mark to `phase` (marks ACCUMULATE, so a phase
-    visited several times in one iteration — e.g. `build`/`device`
-    per chunk on the alternating scheduler — sums). Both return the
-    timestamp they read so callers reuse it instead of reading the
-    clock again: the mixed scheduler pays a bounded constant number
-    of `perf_counter` reads per iteration (asserted by test)."""
+    `begin(iteration)` opens the iteration in its first phase, `sweep`;
+    `enter(phase)` is a phase BOUNDARY: the time since the previous
+    boundary goes to the phase that was open, and `phase` opens
+    (entering the phase that is already open is no boundary: no clock
+    read, no event). Time ACCUMULATES per phase, so a phase visited
+    several times in one iteration — e.g. `build`/`device` per chunk on
+    the alternating scheduler — sums. `end()` closes a busy iteration.
+    All three return the boundary's timestamp so callers reuse it
+    instead of reading the clock again: the mixed scheduler pays a
+    bounded constant number of `perf_counter` reads per iteration
+    (asserted by test).
 
-    __slots__ = ("t0", "_last", "_acc")
+    The same boundaries are events on the jax profiler's clock: handed
+    `annotate` (`utils.tracing.annotate`; this module stays jax-free),
+    every open phase is a `sched/<phase>` trace event and the iteration
+    an enclosing `sched/iteration`, each carrying `iteration=<n>`, the
+    flight-recorder index the step gets when it records (the
+    contiguous server has no flight recorder and gives none). A step
+    that dispatches nothing ends in `close()`: no record, and its
+    `sched/iteration` carries no index. With no capture running an
+    annotation is an inactive check."""
 
-    def __init__(self):
+    __slots__ = ("t0", "_last", "_acc", "_phase", "_annotate",
+                 "_stats", "_iter_span", "_span")
+
+    def __init__(self, annotate=None):
         self.t0 = 0.0
         self._last = 0.0
         self._acc: dict[str, float] = {}
+        self._phase = PHASES[0]
+        self._annotate = annotate
+        self._stats: dict[str, int] = {}
+        self._iter_span = None
+        self._span = None
 
-    def begin(self) -> float:
+    def begin(self, iteration: int | None = None) -> float:
+        if self._iter_span is not None:
+            self.close()  # the previous step raised mid-iteration
         t = perf_counter()
         self.t0 = self._last = t
         self._acc = {}
+        self._phase = phase = PHASES[0]
+        if self._annotate is not None:
+            self._stats = ({} if iteration is None
+                           else {"iteration": iteration})
+            self._iter_span = self._annotate(_ITERATION_EVENT)
+            self._iter_span.__enter__()
+            self._span = self._annotate(_PHASE_EVENTS[phase],
+                                        **self._stats)
+            self._span.__enter__()
         return t
 
-    def mark(self, phase: str) -> float:
+    def enter(self, phase: str) -> float:
+        cur = self._phase
+        if phase == cur:
+            return self._last
         t = perf_counter()
         acc = self._acc
-        acc[phase] = acc.get(phase, 0.0) + (t - self._last)
+        acc[cur] = acc.get(cur, 0.0) + (t - self._last)
         self._last = t
+        self._phase = phase
+        if self._span is not None:
+            self._span.__exit__(None, None, None)
+            self._span = self._annotate(_PHASE_EVENTS[phase],
+                                        **self._stats)
+            self._span.__enter__()
         return t
+
+    def end(self) -> float:
+        """Close a busy iteration: the open phase ends here, and the
+        `sched/iteration` event takes the iteration's index."""
+        t = perf_counter()
+        acc = self._acc
+        cur = self._phase
+        acc[cur] = acc.get(cur, 0.0) + (t - self._last)
+        self._last = t
+        if self._iter_span is not None:
+            self._iter_span.set_metadata(**self._stats)
+            self.close()
+        return t
+
+    def close(self) -> None:
+        """Close the open trace events without a clock read: the end of
+        a step that recorded nothing."""
+        if self._span is not None:
+            self._span.__exit__(None, None, None)
+            self._span = None
+        if self._iter_span is not None:
+            self._iter_span.__exit__(None, None, None)
+            self._iter_span = None
 
     def phases_ms(self) -> dict[str, float]:
         """Accumulated per-phase milliseconds, canonical order. The
-        values partition [t0, last mark]: their sum is the elapsed
+        values partition [t0, last boundary]: their sum is the elapsed
         time between those clock reads (no time is double-counted or
         dropped), which is what makes the flight record's
         `host_ms + device_wait_ms == duration_ms` hold exactly."""
@@ -179,21 +256,23 @@ def register_phase_hists(registry) -> dict:
         for p in HIST_PHASES}
 
 
-def resolve_profiler(profile,
-                     cfg_enabled: bool = True) -> IterationProfiler | None:
+def resolve_profiler(profile, cfg_enabled: bool = True,
+                     annotate=None) -> IterationProfiler | None:
     """The one constructor both servers use: `profile` may be a ready
     IterationProfiler, True/False, "off", or None (falling back to
     `InferConfig.iteration_profile`). Returns None when disabled —
     every guarded call site short-circuits and the scheduler keeps
-    the exact pre-profiler clock behavior."""
+    the exact pre-profiler clock behavior. `annotate` (the servers
+    hand `utils.tracing.annotate`) is what a profiler built here opens
+    its trace events with."""
     if profile is False or profile == "off":
         return None
     if isinstance(profile, IterationProfiler):
         return profile
     if profile is True:
-        return IterationProfiler()
+        return IterationProfiler(annotate)
     if profile is None:
-        return IterationProfiler() if cfg_enabled else None
+        return IterationProfiler(annotate) if cfg_enabled else None
     raise ValueError(
         "iteration_profile must be True, False, 'off', None, or an "
         f"IterationProfiler; got {profile!r}")

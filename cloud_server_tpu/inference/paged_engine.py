@@ -265,35 +265,38 @@ def window_forward(params, tokens: jnp.ndarray, cfg: ModelConfig,
         lp = jax.tree.map(lambda p: p[layer_idx], params["layers"])
         ll = (None if lora is None
               else multi_lora.layer_lora(lora, aid, layer_idx))
-        q, k, v = transformer.attention_qkv(x, lp, cfg, cos, sin, pos,
-                                            lora=ll)
-        cache = _write_window(cache, layer_idx, k, v, wpos)
-        if use_pallas:
-            if mesh is not None and mesh.shape.get(tp_axis, 1) > 1:
-                o = paged_attention_tp(
-                    q, cache.k, cache.v, lens_after, cache.tables,
-                    layer_idx, mesh=mesh, axis_name=tp_axis,
-                    pages_per_block=pages_per_block,
-                    k_scale_pool=cache.k_scale, v_scale_pool=cache.v_scale,
-                    widths=widths)
+        with jax.named_scope("attn"):
+            q, k, v = transformer.attention_qkv(x, lp, cfg, cos, sin, pos,
+                                                lora=ll)
+            cache = _write_window(cache, layer_idx, k, v, wpos)
+            if use_pallas:
+                if mesh is not None and mesh.shape.get(tp_axis, 1) > 1:
+                    o = paged_attention_tp(
+                        q, cache.k, cache.v, lens_after, cache.tables,
+                        layer_idx, mesh=mesh, axis_name=tp_axis,
+                        pages_per_block=pages_per_block,
+                        k_scale_pool=cache.k_scale, v_scale_pool=cache.v_scale,
+                        widths=widths)
+                else:
+                    o = paged_attention(
+                        q, cache.k, cache.v, lens_after, cache.tables,
+                        layer_idx, pages_per_block=pages_per_block,
+                        k_scale_pool=cache.k_scale, v_scale_pool=cache.v_scale,
+                        widths=widths)
             else:
-                o = paged_attention(
-                    q, cache.k, cache.v, lens_after, cache.tables,
-                    layer_idx, pages_per_block=pages_per_block,
+                o = paged_attention_xla(
+                    q, cache.k, cache.v, lens_after, cache.tables, layer_idx,
                     k_scale_pool=cache.k_scale, v_scale_pool=cache.v_scale,
                     widths=widths)
-        else:
-            o = paged_attention_xla(
-                q, cache.k, cache.v, lens_after, cache.tables, layer_idx,
-                k_scale_pool=cache.k_scale, v_scale_pool=cache.v_scale,
-                widths=widths)
-        x = transformer.attention_out(x, o, lp, cfg, lora=ll)
+            x = transformer.attention_out(x, o, lp, cfg, lora=ll)
         x = _mlp_apply(x, lp, cfg, lora=ll)
 
-    x = rms_norm(x, params["final_norm"]["scale"], cfg.norm_eps)
-    if all_logits:
-        return transformer.unembed(x, params, cfg), cache
-    if logits_at is not None:
-        x_sel = x[jnp.arange(b), jnp.clip(logits_at, 0, w - 1)]  # (B, D)
-        return transformer.unembed(x_sel, params, cfg), cache
+    with jax.named_scope("unembed"):
+        x = rms_norm(x, params["final_norm"]["scale"], cfg.norm_eps)
+        if all_logits:
+            return transformer.unembed(x, params, cfg), cache
+        if logits_at is not None:
+            # (B, D)
+            x_sel = x[jnp.arange(b), jnp.clip(logits_at, 0, w - 1)]
+            return transformer.unembed(x_sel, params, cfg), cache
     return None, cache

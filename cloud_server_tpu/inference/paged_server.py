@@ -260,6 +260,14 @@ def _split_cache(cache):
     return pools
 
 
+# The scopes below name the two halves of every step program in a
+# device trace (`tf_op` of an op's event metadata): ops of an admission
+# window lie under `prefill_group/`, ops of the decode or speculative
+# rounds under `decode_rounds/`, and inside either under `attn`, the
+# `moe_*` scopes of models/moe.py (`mlp` for a dense block), `unembed`
+# and `sample`. Names are HLO metadata only: no program, cache key or
+# result changes with them.
+@jax.named_scope("prefill_group")
 def _prefill_core(params, state, chunk, g_lens, g_tables, sample_at,
                   slot_ids, prompt_rows, prompt_lens, rng,
                   samp_rows, orig_lens, count_mask,
@@ -335,16 +343,17 @@ def _prefill_core(params, state, chunk, g_lens, g_tables, sample_at,
         # state; EOS allowed only at accepting states
         nrow, amask = _grammar_mask(grammar, gid, gstate0,
                                     infer_cfg.eos_token_id)
-    if use_rows:
-        toks = sample_logits_rows(
-            logits, samp_rows, prompt_lens,
-            prompt_mask=pm[slot_ids] if has_pen else None,
-            out_counts=oc[slot_ids] if has_pen else None,
-            eos_id=infer_cfg.eos_token_id, use_bias=use_bias,
-            allowed_mask=amask)
-    else:
-        toks = sample_logits(logits, rng, infer_cfg)
-    lps = _token_logprobs(logits, toks)
+    with jax.named_scope("sample"):
+        if use_rows:
+            toks = sample_logits_rows(
+                logits, samp_rows, prompt_lens,
+                prompt_mask=pm[slot_ids] if has_pen else None,
+                out_counts=oc[slot_ids] if has_pen else None,
+                eos_id=infer_cfg.eos_token_id, use_bias=use_bias,
+                allowed_mask=amask)
+        else:
+            toks = sample_logits(logits, rng, infer_cfg)
+        lps = _token_logprobs(logits, toks)
     if gstate0 is not None:
         # advance ONLY the rows captured THIS chunk — a multi-chunk job
         # revisits rows whose sample landed in an earlier chunk, and
@@ -406,6 +415,7 @@ _prefill_chunk = partial(jax.jit,
                          donate_argnums=(1,))(_prefill_core)
 
 
+@jax.named_scope("decode_rounds")
 def _decode_plain_core(params, state, lengths, tables, last_token, live,
                        rng, samp_rows, gid=None, grammar=None,
                        lora=None, aid=None, slot_ids=None, *,
@@ -454,25 +464,27 @@ def _decode_plain_core(params, state, lengths, tables, last_token, live,
         if grammar is not None:
             nrow, amask = _grammar_mask(grammar, gid, gstate,
                                         infer_cfg.eos_token_id)
-        if use_rows:
-            # the sampled token sits at position lengths + 1 (`last`
-            # occupies `lengths`); the admission chunk folds the prompt
-            # length, so positions never collide within a request
-            tok = sample_logits_rows(logits, samp_rows, lengths + 1,
-                                     prompt_mask=pm, out_counts=oc,
-                                     eos_id=infer_cfg.eos_token_id,
-                                     use_bias=use_bias,
-                                     allowed_mask=amask)
-            if oc is not None:
-                oc = oc.at[batch_idx, tok].add(live.astype(jnp.int32))
-        else:
-            tok = sample_logits(logits, rng_t, infer_cfg)
+        with jax.named_scope("sample"):
+            if use_rows:
+                # the sampled token sits at position lengths + 1 (`last`
+                # occupies `lengths`); the admission chunk folds the prompt
+                # length, so positions never collide within a request
+                tok = sample_logits_rows(logits, samp_rows, lengths + 1,
+                                         prompt_mask=pm, out_counts=oc,
+                                         eos_id=infer_cfg.eos_token_id,
+                                         use_bias=use_bias,
+                                         allowed_mask=amask)
+                if oc is not None:
+                    oc = oc.at[batch_idx, tok].add(live.astype(jnp.int32))
+            else:
+                tok = sample_logits(logits, rng_t, infer_cfg)
         if grammar is not None:
             # sticky DEAD: a dead row (post-EOS scan tail) must never
             # resurrect through the max(st, 0) clamp
             gstate = jnp.where(live & (gstate != _GDEAD),
                                nrow[batch_idx, tok], gstate)
-        lp = _token_logprobs(logits, tok)
+        with jax.named_scope("sample"):
+            lp = _token_logprobs(logits, tok)
         tok = jnp.where(live, tok, pad)
         new_len = jnp.where(live, lengths + 1, lengths)
         last = jnp.where(live, tok, last)
@@ -497,6 +509,7 @@ _decode_rounds = partial(jax.jit,
                          donate_argnums=(1,))(_decode_plain_core)
 
 
+@jax.named_scope("decode_rounds")
 def _spec_core(params, state, lengths, tables, last_token, live,
                stop_len, rng, samp_rows, gid=None, grammar=None,
                lora=None, aid=None,
@@ -654,36 +667,37 @@ def _spec_core(params, state, lengths, tables, last_token, live,
             sts_m = jnp.stack(sts, axis=1)  # (B, G+1)
             _, amask_w = _grammar_mask(grammar, gid[:, None], sts_m,
                                        infer_cfg.eos_token_id)
-        if use_rows and pm is not None:
-            # counts at window position i = base + drafts committed
-            # before i (position 0 scores the token after `last`, which
-            # is already in the base counts)
-            cum = jnp.cumsum(
-                jax.nn.one_hot(drafts, vlogits.shape[-1],
-                               dtype=jnp.int32), axis=1)
-            counts_w = oc[:, None, :] + jnp.concatenate(
-                [jnp.zeros_like(cum[:, :1]), cum], axis=1)
-            p_probs = sampling_probs_rows(
-                vlogits, samp_rows, prompt_mask=pm, out_counts=counts_w,
-                positions=(lengths + 1)[:, None] + j,
-                eos_id=infer_cfg.eos_token_id, use_bias=use_bias,
-                allowed_mask=amask_w)
-        elif use_rows:
-            p_probs = sampling_probs_rows(
-                vlogits, samp_rows,
-                positions=(lengths + 1)[:, None] + j,
-                eos_id=infer_cfg.eos_token_id, use_bias=use_bias,
-                allowed_mask=amask_w)
-        else:
-            p_probs = sampling_probs(vlogits, infer_cfg)  # (B, G+1, V)
-        seeds = samp_rows.seed if use_rows else None
-        pos0 = (lengths + 1) if use_rows else None
-        if use_draft:
-            n_acc, x = _accept_drafts(drafts, q_probs, p_probs, rng_acc,
-                                      seeds=seeds, pos0=pos0)
-        else:
-            n_acc, x = _accept_point_mass(drafts, p_probs, rng_acc,
+        with jax.named_scope("sample"):
+            if use_rows and pm is not None:
+                # counts at window position i = base + drafts committed
+                # before i (position 0 scores the token after `last`, which
+                # is already in the base counts)
+                cum = jnp.cumsum(
+                    jax.nn.one_hot(drafts, vlogits.shape[-1],
+                                   dtype=jnp.int32), axis=1)
+                counts_w = oc[:, None, :] + jnp.concatenate(
+                    [jnp.zeros_like(cum[:, :1]), cum], axis=1)
+                p_probs = sampling_probs_rows(
+                    vlogits, samp_rows, prompt_mask=pm, out_counts=counts_w,
+                    positions=(lengths + 1)[:, None] + j,
+                    eos_id=infer_cfg.eos_token_id, use_bias=use_bias,
+                    allowed_mask=amask_w)
+            elif use_rows:
+                p_probs = sampling_probs_rows(
+                    vlogits, samp_rows,
+                    positions=(lengths + 1)[:, None] + j,
+                    eos_id=infer_cfg.eos_token_id, use_bias=use_bias,
+                    allowed_mask=amask_w)
+            else:
+                p_probs = sampling_probs(vlogits, infer_cfg)  # (B, G+1, V)
+            seeds = samp_rows.seed if use_rows else None
+            pos0 = (lengths + 1) if use_rows else None
+            if use_draft:
+                n_acc, x = _accept_drafts(drafts, q_probs, p_probs, rng_acc,
                                           seeds=seeds, pos0=pos0)
+            else:
+                n_acc, x = _accept_point_mass(drafts, p_probs, rng_acc,
+                                              seeds=seeds, pos0=pos0)
 
         drafts_x = jnp.concatenate([drafts, x[:, None]], axis=1)
         committed = jnp.where(j < n_acc[:, None], drafts_x,
@@ -1199,8 +1213,10 @@ class PagedInferenceServer:
         # off) keeps the exact pre-profiler two-read clock behavior.
         from cloud_server_tpu.inference.iteration_profile import (
             register_phase_hists, resolve_profiler)
+        from cloud_server_tpu.utils.tracing import annotate
         self._profiler = resolve_profiler(iteration_profile,
-                                          infer_cfg.iteration_profile)
+                                          infer_cfg.iteration_profile,
+                                          annotate)
         # eager per-phase histogram registration: the families exist
         # (and the docs drift check sees them) before any traffic, and
         # the per-iteration observe path is a dict lookup, not a
@@ -2078,6 +2094,11 @@ class PagedInferenceServer:
         if self._faults is not None:
             # injected dispatch failure (see _mixed_dispatch)
             self._faults.check("dispatch")
+        prof = self._profiler
+        if prof is not None:
+            # per-chunk phases ACCUMULATE into the iteration's (the
+            # alternating scheduler runs several chunks per step)
+            prof.enter("build")
         c = job.next_chunk
         w = job.chunk_w
         g = len(job.slots)
@@ -2127,11 +2148,8 @@ class PagedInferenceServer:
         use_lora = bool((self._aid[sl] > 0).any())
         aid_g = jnp.asarray(pad_rows(self._aid[sl], 0))
 
-        prof = self._profiler
         if prof is not None:
-            # per-chunk marks ACCUMULATE into the iteration's phases
-            # (the alternating scheduler runs several chunks per step)
-            prof.mark("build")
+            prof.enter("device")
         self.state, toks, lps = _prefill_chunk(
             self.params, self.state, jnp.asarray(chunk),
             jnp.asarray(g_lens, jnp.int32), jnp.asarray(g_tables),
@@ -2156,7 +2174,7 @@ class PagedInferenceServer:
         # (the dispatch-discipline pass pins the sanctioned set)
         toks, lps = jax.device_get((toks, lps))
         if prof is not None:
-            prof.mark("device")
+            prof.enter("commit")
         toks, lps = np.asarray(toks)[:g], np.asarray(lps)[:g]
         job.toks = np.where(in_range, toks, job.toks)
         job.lps = np.where(in_range, lps, job.lps)
@@ -2183,8 +2201,6 @@ class PagedInferenceServer:
                               float(job.lps[i])):
                     self._finish(sid)
             self._jobs.remove(job)
-        if prof is not None:
-            prof.mark("commit")
 
     # -- decode -------------------------------------------------------------
 
@@ -2414,6 +2430,9 @@ class PagedInferenceServer:
             # injected dispatch failure (see _mixed_dispatch)
             self._faults.check("dispatch")
         prof = self._profiler
+        if prof is not None:
+            # round planning + chain extension/preemption policy
+            prof.enter("admission")
         n = self._chunk_rounds()
         if self.allocation == "ondemand":
             n_eff = self._extend_chains(n)
@@ -2424,8 +2443,7 @@ class PagedInferenceServer:
                 n //= 2      # cache) while honouring chain coverage
             n = max(1, n)
         if prof is not None:
-            # round planning + chain extension/preemption policy
-            prof.mark("admission")
+            prof.enter("build")
         (live_ids, sl, live_g, lengths, tables, last_np, stop, samp_g,
          gid_np, aid_np) = self._gather_decode_rows()
         g_iter, spec_lens = self._spec_plan(live_ids)
@@ -2454,7 +2472,7 @@ class PagedInferenceServer:
         aid = jnp.asarray(aid_np)
         sl_dev = None if sl is None else jnp.asarray(sl)
         if prof is not None:
-            prof.mark("build")
+            prof.enter("device")
         if g_iter > 0:
             lim_dev = (None if spec_lens is None else jnp.asarray(
                 self._pad_limits(spec_lens, int(live_g.shape[0]))))
@@ -2489,12 +2507,10 @@ class PagedInferenceServer:
                 self.spec_control.on_plain_dispatch(
                     [int(s) for s in live_ids], n)
         if prof is not None:
-            prof.mark("device")
+            prof.enter("commit")
         self._commit_decode_rows(live_ids, toks, lps, counts, lens, last,
                                  self._drafted_rows(g_iter, spec_lens,
                                                     len(live_ids)))
-        if prof is not None:
-            prof.mark("commit")
 
     def _commit_decode_rows(self, live_ids, toks, lps, counts, lens,
                             last, drafted=None, owners=None) -> None:
@@ -2845,13 +2861,14 @@ class PagedInferenceServer:
         # current time instead of replaying idle credit)
         sel = self._select_prefill(self._jobs, n_live, win, n_rounds,
                                    planned=False)
-        prof = self._profiler
-        if prof is not None:
-            # budget/round planning, chain extension, QoS funding
-            # order, selection — the host deciding WHAT to dispatch
-            prof.mark("admission")
         if not sel and not n_rounds:
             return
+        prof = self._profiler
+        if prof is not None:
+            # everything above (budget/round planning, chain extension,
+            # QoS funding order, selection: the host deciding WHAT to
+            # dispatch) ran in step()'s `admission`
+            prof.enter("build")
         if self.qos is not None:
             for job, take, _ in sel:
                 self.qos.charge_prefill(
@@ -2897,7 +2914,7 @@ class PagedInferenceServer:
             # host array prep done; the dispatch statement below (arg
             # transfer + launch) through the sanctioned device_get is
             # the device phase
-            prof.mark("build")
+            prof.enter("device")
         # disaggregation handoff: start the committed-page D2H copies
         # BEFORE the dispatch donates self.state (overlaps the final
         # prefill chunk)
@@ -2940,7 +2957,7 @@ class PagedInferenceServer:
         ptoks, plps, toks, lps, counts, lens, last = jax.device_get(
             (ptoks, plps, toks, lps, counts, lens, last))
         if prof is not None:
-            prof.mark("device")
+            prof.enter("commit")
 
         if n_rounds > 0:
             if (g_iter == 0 and self.spec_drafts > 0
@@ -2955,8 +2972,6 @@ class PagedInferenceServer:
         # prefill progress: capture first tokens, activate completed
         # admissions (mirrors _run_one_chunk's completion block)
         self._complete_admission_chunks(sel, ptoks, plps)
-        if prof is not None:
-            prof.mark("commit")
 
     # -- async double-buffered scheduling (overlap on) ----------------------
     #
@@ -3030,6 +3045,12 @@ class PagedInferenceServer:
         prime's fault site is the NEXT step's check, matching the
         contiguous server's convention."""
         prof = self._profiler
+        if prof is not None:
+            # planned-frame budget/round planning, chain growth, QoS
+            # funding order, selection — overlapped host work (a no-op
+            # boundary right after step()'s admissions; a real one
+            # after the pipeline fill's commit)
+            prof.enter("admission")
         b = self.max_slots
         infl = self._inflight
         # --- the planned frame --------------------------------------------
@@ -3109,9 +3130,7 @@ class PagedInferenceServer:
                              {"slot": job.slots[0], "tokens": take,
                               "offset": d0}))
             if prof is not None:
-                # planned-frame budget/round planning, chain growth,
-                # QoS funding order, selection — overlapped host work
-                prof.mark("admission")
+                prof.enter("build")
             pf = self._build_prefill_group(sel)
             sel_mask = pf["sel_mask"]
             (live_ids, sl_d, live_g, d_lens, d_tables, d_last, d_stop,
@@ -3154,7 +3173,7 @@ class PagedInferenceServer:
                     n //= 2
                 n = max(1, n)
             if prof is not None:
-                prof.mark("admission")
+                prof.enter("build")
             (live_ids, sl_d, live_g, d_lens, d_tables, d_last, d_stop,
              samp_d, gid_d, aid_d) = self._gather_decode_rows(
                  planned_active)
@@ -3205,8 +3224,6 @@ class PagedInferenceServer:
         plan.samp_d = jax.tree.map(jnp.asarray, plan.samp_d)
         plan.gid_d = jnp.asarray(plan.gid_d)
         plan.aid_d = jnp.asarray(plan.aid_d)
-        if prof is not None:
-            prof.mark("build")
         return plan
 
     def _launch_plan(self, plan: "_Plan") -> None:
@@ -3219,6 +3236,8 @@ class PagedInferenceServer:
         commit (their sentinel tables drop every device write, and
         `owners` masks their host commit)."""
         prof = self._profiler
+        if prof is not None:
+            prof.enter("launch")
         live_ids = plan.live_ids
         nl = len(live_ids)
         if nl and plan.n_rounds > 0:
@@ -3321,7 +3340,9 @@ class PagedInferenceServer:
                         use_rows=plan.use_rows_d,
                         use_bias=plan.use_bias_d)
             futures = (toks, lps, counts, lens, last)
-        t = (prof.mark("launch") if prof is not None
+        # the launch's end is the epilogue's start: _record_iteration
+        # follows every launch
+        t = (prof.enter("epilogue") if prof is not None
              else time.perf_counter())
         self._iter_launch_ts = t
         self._inflight = _Inflight(
@@ -3340,14 +3361,15 @@ class PagedInferenceServer:
         identity captured at plan time (a whole step ran since the
         launch)."""
         infl, self._inflight = self._inflight, None
-        t_wait = time.perf_counter()
+        prof = self._profiler
+        t_wait = (prof.enter("device") if prof is not None
+                  else time.perf_counter())
         # analysis: allow[lock-discipline] THE sanctioned per-iteration
         # host sync — one launched dispatch, one device_get, under the
         # step lock that serializes the scheduler by design
         vals = jax.device_get(infl.futures)
-        prof = self._profiler
         if prof is not None:
-            prof.mark("device")
+            prof.enter("commit")
         st = infl.stats
         st["overlap"] = True
         st["inflight_depth"] = 1
@@ -3383,8 +3405,6 @@ class PagedInferenceServer:
         if infl.kind == "mixed":
             self._complete_admission_chunks(infl.sel, ptoks, plps)
         self._apply_reaps()
-        if prof is not None:
-            prof.mark("commit")
 
     def _overlap_sweep(self) -> None:
         """Sweep for an overlapped step: cancelled / deadline-expired
@@ -3478,10 +3498,10 @@ class PagedInferenceServer:
                 if self._faults is not None:
                     self._faults.maybe_stall()
                     self._faults.maybe_wedge(self._stop)
-                if prof is not None:
-                    prof.begin()
                 al = self.allocator
                 al.telemetry.iteration = self.flight.iterations + 1
+                if prof is not None:
+                    prof.begin(al.telemetry.iteration)
                 c0 = (al.pages_allocated, al.pages_released,
                       al.evictions)
                 if self._inflight is None:
@@ -3489,10 +3509,8 @@ class PagedInferenceServer:
                     # launch-ahead prime so the NEXT step overlaps
                     self._sweep_cancelled()
                     if prof is not None:
-                        prof.mark("sweep")
+                        prof.enter("admission")
                     self._start_admissions()
-                    if prof is not None:
-                        prof.mark("admission")
                     self._iter_stats = {}
                     p0 = self.preemptions
                     t0 = (prof.t0 if prof is not None
@@ -3515,10 +3533,8 @@ class PagedInferenceServer:
                     # steady state: one commit + one launch per step
                     self._overlap_sweep()
                     if prof is not None:
-                        prof.mark("sweep")
+                        prof.enter("admission")
                     self._start_admissions()
-                    if prof is not None:
-                        prof.mark("admission")
                     p0 = self.preemptions
                     t0 = (prof.t0 if prof is not None
                           else time.perf_counter())
@@ -3642,22 +3658,21 @@ class PagedInferenceServer:
                     # the scenario _fail_all's bounded acquire covers
                     self._faults.maybe_stall()
                     self._faults.maybe_wedge(self._stop)
-                if prof is not None:
-                    prof.begin()
                 al = self.allocator
                 # page-flow baseline for this iteration's flight record
                 # (sweep + admission allocate/release too, so capture
                 # before both) + the telemetry recency stamp: the
                 # flight index THIS iteration will get if it is busy
+                # (the profiler's trace events carry it too)
                 al.telemetry.iteration = self.flight.iterations + 1
+                if prof is not None:
+                    prof.begin(al.telemetry.iteration)
                 c0 = (al.pages_allocated, al.pages_released,
                       al.evictions)
                 self._sweep_cancelled()
                 if prof is not None:
-                    prof.mark("sweep")
+                    prof.enter("admission")
                 self._start_admissions()
-                if prof is not None:
-                    prof.mark("admission")
                 self._iter_stats = {}
                 p0 = self.preemptions
                 t0 = prof.t0 if prof is not None else time.perf_counter()
@@ -3709,8 +3724,15 @@ class PagedInferenceServer:
         beyond the duration_ms one the recorder already pays."""
         spans, self._iter_spans = self._iter_spans, []
         st = self._iter_stats
+        prof = self._profiler
         if not st:
+            if prof is not None:
+                prof.close()
             return
+        if prof is not None:
+            # everything from here to the closing clock read (the
+            # stats assembly below, fair-share scans included)
+            prof.enter("epilogue")
         decode_tokens = st.get("decode_tokens", 0)
         st["tokens_scheduled"] = decode_tokens + st.get("prefill_tokens", 0)
         if st.get("scheduler") == "mixed":
@@ -3756,12 +3778,8 @@ class PagedInferenceServer:
         if mig_in or mig_out:
             st["migrated_in"] = mig_in
             st["migrated_out"] = mig_out
-        prof = self._profiler
         if prof is not None:
-            # everything since the commit mark (the stats assembly
-            # above, fair-share scans included) is epilogue; the mark
-            # doubles as the iteration's closing clock read
-            now = prof.mark("epilogue")
+            now = prof.end()
             phases = prof.phases_ms()
             st["t_start"] = t0
             st["phases_ms"] = phases

@@ -752,8 +752,10 @@ class InferenceServer:
         # every guarded call site.
         from cloud_server_tpu.inference.iteration_profile import (
             register_phase_hists, resolve_profiler)
+        from cloud_server_tpu.utils.tracing import annotate
         self._profiler = resolve_profiler(iteration_profile,
-                                          infer_cfg.iteration_profile)
+                                          infer_cfg.iteration_profile,
+                                          annotate)
         self._phase_hists = ({} if self._profiler is None else
                              register_phase_hists(self.metrics.registry))
         # idle-vs-dead disambiguation (see the paged server): an idle
@@ -1125,11 +1127,11 @@ class InferenceServer:
             return
         self._iter_busy = True
         if self._profiler is not None:
-            # QoS/DRR group selection under the lock; the burst's
-            # padding/dispatch below stamps build/device/commit. The
-            # mark's timestamp doubles as the admit moment below — one
-            # clock read serves both
-            now = self._profiler.mark("admission")
+            # the QoS/DRR group selection under the lock was
+            # `admission`; the burst's padding and dispatch below are
+            # build/device/commit. The boundary's timestamp doubles as
+            # the admit moment below — one clock read serves both
+            now = self._profiler.enter("build")
         else:
             now = time.perf_counter()  # one read per admission burst
         for _, req in group:
@@ -1242,25 +1244,25 @@ class InferenceServer:
     def _admit_group(self, group, token_rows, buckets, run_fn) -> None:
         """Shared burst plumbing: pad, dispatch one batched admission,
         emit first tokens."""
+        prof = self._profiler
+        if prof is not None:
+            prof.enter("build")  # a boundary for a step's second burst
         rows, true_lens, slots = self._pad_group(group, token_rows,
                                                  buckets)
         self._ensure_penalty_state(group)
         samp_rows, use_rows, use_bias = self._group_rows(
             group, rows.shape[0])
-        prof = self._profiler
         if prof is not None:
-            prof.mark("build")
+            prof.enter("device")
         self.state, toks, lps = run_fn(
             jnp.asarray(rows), jnp.asarray(true_lens), jnp.asarray(slots),
             jax.tree.map(jnp.asarray, samp_rows), use_rows, use_bias)
         toks, lps = jax.device_get((toks, lps))
         if prof is not None:
-            prof.mark("device")
+            prof.enter("commit")
         for i, (slot, req) in enumerate(group):
             if self._emit(req, int(toks[i]), float(lps[i])):
                 self._finish(slot, req)
-        if prof is not None:
-            prof.mark("commit")
 
     def _admit_group_plain(self, group) -> None:
         def run(rows, tl, sl, samp, use_rows, use_bias):
@@ -1335,7 +1337,8 @@ class InferenceServer:
                         # overlapped step's sweep/admission/build ran
                         # under the in-flight decode — fold them into
                         # the `overlap` series (see iteration_profile)
-                        prof.mark("epilogue")
+                        prof.enter("epilogue")
+                        prof.end()
                         hists = self._phase_hists
                         phases = prof.phases_ms()
                         if self._iter_overlapped:
@@ -1361,6 +1364,8 @@ class InferenceServer:
                             self._on_anomaly(fired)
                 else:
                     self.idle_iterations += 1
+                    if prof is not None:
+                        prof.close()
                 return n_active
             finally:
                 self.tracer.step_end()
@@ -1371,7 +1376,7 @@ class InferenceServer:
         prof = self._profiler
         self._sweep_cancelled()
         if prof is not None:
-            prof.mark("sweep")
+            prof.enter("admission")
         self._admit_pending()
         if self.num_active == 0:
             return 0
@@ -1381,14 +1386,16 @@ class InferenceServer:
             # crashing this iteration the way a poisoned program would
             # (serve_forever catches, _fail_all unblocks every waiter)
             self._faults.check("dispatch")
+        if prof is not None:
+            prof.enter("admission")  # a boundary after a burst's commit
         n = self._chunk_len()
         use_rows, use_bias = self._rows_mode()
         if prof is not None:
-            # decode planning; the dispatch statements below (arg
-            # transfer + launch + the sanctioned device_get) are the
-            # device phase — the contiguous decode stages no host
-            # arrays, so its build phase is empty by construction
-            prof.mark("admission")
+            # decode planning was `admission`; the dispatch statements
+            # below (arg transfer + launch + the sanctioned device_get)
+            # are the device phase — the contiguous decode stages no
+            # host arrays, so its build phase is empty by construction
+            prof.enter("device")
         if n == 1:
             self.state, out = _decode(
                 self.params, self.state, self._next_rng(),
@@ -1406,15 +1413,13 @@ class InferenceServer:
             chunk = np.asarray(toks)             # (n, B)
             lchunk = np.asarray(lps)
         if prof is not None:
-            prof.mark("device")
+            prof.enter("commit")
         for t in range(chunk.shape[0]):
             for slot, req in enumerate(self._slots):
                 if req is not None and self._emit(
                         req, int(chunk[t, slot]),
                         float(lchunk[t, slot])):
                     self._finish(slot, req)
-        if prof is not None:
-            prof.mark("commit")
         return self.num_active
 
     def _launch_decode(self, use_rows: bool, use_bias: bool):
@@ -1446,9 +1451,11 @@ class InferenceServer:
         one commit-emit block both overlap paths share, and the
         sanctioned per-iteration host sync of the pipelined
         contiguous loop (dispatch-discipline DD2)."""
+        if prof is not None:
+            prof.enter("device")
         toks, lps = jax.device_get(out)
         if prof is not None:
-            prof.mark("device")
+            prof.enter("commit")
         chunk, lchunk = np.asarray(toks), np.asarray(lps)
         if chunk.ndim == 1:
             chunk, lchunk = chunk[None], lchunk[None]
@@ -1458,8 +1465,6 @@ class InferenceServer:
                         and self._emit(req, int(chunk[t, slot]),
                                        float(lchunk[t, slot])):
                     self._finish(slot, req)
-        if prof is not None:
-            prof.mark("commit")
 
     def _step_locked_overlap(self) -> int:
         """Pipelined iteration (overlap on): commit the decode chunk
@@ -1482,14 +1487,8 @@ class InferenceServer:
         prof = self._profiler
         self._sweep_cancelled()
         if prof is not None:
-            prof.mark("sweep")
+            prof.enter("admission")
         self._admit_pending()
-        if prof is not None:
-            # close the admission window HERE: with a chunk in flight
-            # the commit's device mark comes next, and an
-            # admission-less scan must not leak into `device` (the
-            # burst's own build/device/commit marks accumulated above)
-            prof.mark("admission")
         committed = False
         if self._inflight is not None:
             self._iter_busy = True
@@ -1507,15 +1506,16 @@ class InferenceServer:
             # ran, so no synced tokens are ever lost to the injection)
             # analysis: allow[lifecycle-discipline] deliberate raise point: a dispatch fault fails the whole step and _fail_all tears every slot down, so the _iter_busy/_inflight pair is never read torn
             self._faults.check("dispatch")
-        use_rows, use_bias = self._rows_mode()
         if prof is not None:
-            prof.mark("admission")
+            # steady state: the launch tail after the commit; on the
+            # fill path the dispatch through _commit_decode_chunk's
+            # sync is the device phase
+            prof.enter("launch" if committed else "device")
+        use_rows, use_bias = self._rows_mode()
         out = self._launch_decode(use_rows, use_bias)
         if committed:
             # steady state: leave the chunk in flight (launch-ahead)
             self._inflight = (out, list(self._slots))
-            if prof is not None:
-                prof.mark("launch")
             return self.num_active
         # pipeline fill: sequential commit of the chunk just launched
         self._commit_decode_chunk(out, list(self._slots), prof)
@@ -1523,11 +1523,11 @@ class InferenceServer:
             # prime: the next chunk overlaps the NEXT step's host work
             # (its injected-fault site is the NEXT step's check — one
             # check per step, matching the sequential hit pacing)
+            if prof is not None:
+                prof.enter("launch")
             use_rows, use_bias = self._rows_mode()
             out = self._launch_decode(use_rows, use_bias)
             self._inflight = (out, list(self._slots))
-            if prof is not None:
-                prof.mark("launch")
         return self.num_active
 
     def _fail_all(self, exc: BaseException) -> None:

@@ -103,20 +103,28 @@ def moe_mlp(x: jnp.ndarray, lp: dict, cfg: ModelConfig):
     tokens = x.reshape(b * s, d)
     capacity = _capacity(cfg, b * s)
 
-    router_logits = jnp.einsum(
-        "td,de->te", tokens.astype(jnp.float32),
-        lp["router"].astype(jnp.float32))
-    dispatch, combine, aux = top_k_routing(
-        router_logits, cfg.num_experts_per_token, capacity)
+    # scope names are what a device trace tells the einsums apart by
+    # (`tf_op` of an op's event metadata; cellbench/hostplane.py)
+    with jax.named_scope("moe_route"):
+        router_logits = jnp.einsum(
+            "td,de->te", tokens.astype(jnp.float32),
+            lp["router"].astype(jnp.float32))
+        dispatch, combine, aux = top_k_routing(
+            router_logits, cfg.num_experts_per_token, capacity)
 
     # (T, E, C) x (T, D) -> (E, C, D): the all-to-all, inserted by XLA from
     # the `ep` sharding of the expert axis.
-    xs = jnp.einsum("tec,td->ecd", dispatch.astype(cfg.dtype), tokens)
-    gate = jnp.einsum("ecd,edf->ecf", xs, lp["w_gate"].astype(cfg.dtype))
-    up = jnp.einsum("ecd,edf->ecf", xs, lp["w_up"].astype(cfg.dtype))
-    act = jax.nn.silu(gate) * up
-    ys = jnp.einsum("ecf,efd->ecd", act, lp["w_down"].astype(cfg.dtype))
-    out = jnp.einsum("tec,ecd->td", combine.astype(cfg.dtype), ys)
+    with jax.named_scope("moe_dispatch"):
+        xs = jnp.einsum("tec,td->ecd", dispatch.astype(cfg.dtype), tokens)
+    with jax.named_scope("moe_experts"):
+        gate = jnp.einsum("ecd,edf->ecf", xs,
+                          lp["w_gate"].astype(cfg.dtype))
+        up = jnp.einsum("ecd,edf->ecf", xs, lp["w_up"].astype(cfg.dtype))
+        act = jax.nn.silu(gate) * up
+        ys = jnp.einsum("ecf,efd->ecd", act,
+                        lp["w_down"].astype(cfg.dtype))
+    with jax.named_scope("moe_combine"):
+        out = jnp.einsum("tec,ecd->td", combine.astype(cfg.dtype), ys)
     return out.reshape(b, s, d), aux
 
 

@@ -29,7 +29,6 @@ from cloud_server_tpu.utils.metrics import (  # noqa: F401
     transformer_flops_per_token,
 )
 from cloud_server_tpu.utils.tracing import (  # noqa: F401
-    StepProfiler,
     annotate,
     capture_trace,
     start_profiler_server,

@@ -122,7 +122,8 @@ def test_registry_covers_iteration_profile():
     both servers' step loops)."""
     quals = set(
         HOT_PATHS["cloud_server_tpu/inference/iteration_profile.py"])
-    for needed in ("IterationProfiler.begin", "IterationProfiler.mark",
+    for needed in ("IterationProfiler.begin", "IterationProfiler.enter",
+                   "IterationProfiler.end", "IterationProfiler.close",
                    "IterationProfiler.phases_ms", "derive_gap_fields"):
         assert needed in quals, f"{needed} dropped from HOT_PATHS"
     assert ("cloud_server_tpu/inference/iteration_profile.py"

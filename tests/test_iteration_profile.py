@@ -43,31 +43,89 @@ def params():
 # ---------------------------------------------------------------------------
 
 
-def test_profiler_marks_accumulate_and_partition(monkeypatch):
-    """mark(phase) attributes the time since the previous mark;
-    repeated marks ACCUMULATE; the per-phase sum equals the span from
-    t0 to the last mark exactly (no time dropped or double-counted)."""
+def test_profiler_phases_accumulate_and_partition(monkeypatch):
+    """enter(phase) is a boundary: the time since the previous one goes
+    to the phase that was open; a phase entered again ACCUMULATES;
+    entering the open phase reads no clock; the per-phase sum equals
+    the span from t0 to end() exactly (no time dropped or
+    double-counted)."""
     ticks = iter([10.0, 10.5, 11.0, 14.0, 14.25, 15.25, 15.5])
     monkeypatch.setattr(ip, "perf_counter", lambda: next(ticks))
     p = IterationProfiler()
     assert p.begin() == 10.0 and p.t0 == 10.0
-    p.mark("sweep")                 # 0.5 s
-    p.mark("build")                 # 0.5 s
-    p.mark("device")                # 3.0 s
-    p.mark("build")                 # 0.25 s more build (accumulates)
-    p.mark("device")                # 1.0 s more device
-    last = p.mark("commit")         # 0.25 s
+    assert p.enter("sweep") == 10.0  # already open: no boundary
+    p.enter("build")                # sweep 0.5 s
+    p.enter("device")               # build 0.5 s
+    p.enter("build")                # device 3.0 s
+    assert p.enter("build") == 14.0
+    p.enter("device")               # 0.25 s more build (accumulates)
+    p.enter("commit")               # 1.0 s more device
+    last = p.end()                  # commit 0.25 s
     phases = p.phases_ms()
     assert list(phases) == ["sweep", "build", "device", "commit"]
     assert phases["build"] == pytest.approx(750.0)
     assert phases["device"] == pytest.approx(4000.0)
     assert sum(phases.values()) == pytest.approx((last - p.t0) * 1e3)
-    # begin() resets for the next iteration
+    # begin() resets for the next iteration, open in its first phase
     ticks2 = iter([20.0, 21.0])
     monkeypatch.setattr(ip, "perf_counter", lambda: next(ticks2))
     p.begin()
-    p.mark("device")
-    assert p.phases_ms() == {"device": pytest.approx(1000.0)}
+    p.end()
+    assert p.phases_ms() == {"sweep": pytest.approx(1000.0)}
+
+
+def test_profiler_boundaries_are_trace_events():
+    """Handed an annotation, every open phase is one `sched/<phase>`
+    event carrying the iteration's index, inside one `sched/iteration`
+    that takes the index only when the step records (`end`); `close`
+    ends a step that recorded nothing, and a `begin` after a step that
+    raised closes what it left open."""
+    log = []
+
+    class Span:
+        def __init__(self, name, **stats):
+            self.name, self.stats = name, dict(stats)
+
+        def __enter__(self):
+            log.append(("open", self.name, dict(self.stats)))
+
+        def __exit__(self, *exc):
+            log.append(("close", self.name, dict(self.stats)))
+
+        def set_metadata(self, **stats):
+            self.stats.update(stats)
+
+    p = IterationProfiler(Span)
+    p.begin(7)
+    p.enter("admission")
+    p.enter("admission")
+    p.enter("device")
+    p.end()
+    assert log == [
+        ("open", "sched/iteration", {}),
+        ("open", "sched/sweep", {"iteration": 7}),
+        ("close", "sched/sweep", {"iteration": 7}),
+        ("open", "sched/admission", {"iteration": 7}),
+        ("close", "sched/admission", {"iteration": 7}),
+        ("open", "sched/device", {"iteration": 7}),
+        ("close", "sched/device", {"iteration": 7}),
+        ("close", "sched/iteration", {"iteration": 7})]
+    assert set(ip._PHASE_EVENTS) == set(PHASES)
+    del log[:]
+    p.begin(8)
+    p.close()   # an idle step
+    p.close()   # nothing left open: a no-op
+    assert [e[:2] for e in log] == [
+        ("open", "sched/iteration"), ("open", "sched/sweep"),
+        ("close", "sched/sweep"), ("close", "sched/iteration")]
+    assert log[-1][2] == {}
+    del log[:]
+    p.begin(8)
+    p.enter("build")        # ... and the step raises here
+    p.begin(8)
+    assert [e[:2] for e in log[-4:]] == [
+        ("close", "sched/build"), ("close", "sched/iteration"),
+        ("open", "sched/iteration"), ("open", "sched/sweep")]
 
 
 def test_derive_gap_fields():
@@ -255,12 +313,12 @@ def test_profiled_mixed_step_dispatch_sync_and_clock_counts(
         clock_per_step.add(calls["clock"] - before["clock"])
         assert churn_steps < 50
     assert churn_steps >= 2  # real churn: admission spanned iterations
-    # bounded constant: begin + sweep + admission(step) +
-    # admission(plan) + build + device + commit + launch + epilogue = 9
+    # bounded constant: begin + the boundaries into admission, build,
+    # device, commit, launch and epilogue + end = 8
     assert len(clock_per_step) == 1, (
         f"profiler clock reads varied across mixed iterations: "
         f"{clock_per_step}")
-    assert clock_per_step.pop() <= 9
+    assert clock_per_step.pop() <= 8
     for n, f in origs.items():
         monkeypatch.setattr(ps, n, f)
     monkeypatch.setattr(jax, "device_get", orig_get)

@@ -91,9 +91,13 @@ def test_metric_logger_writes_jsonl_and_stdout(tmp_path, capsys):
 
 
 def test_annotate_and_trace_smoke(tmp_path):
-    with annotate("unit-test-region"):
+    with annotate("unit-test-region", step=0):  # no capture: inactive
         jnp.ones((8, 8)).sum().block_until_ready()
     with capture_trace(tmp_path / "trace"):
-        jnp.ones((8, 8)).sum().block_until_ready()
-    # something landed in the trace dir
-    assert any((tmp_path / "trace").rglob("*"))
+        with annotate("unit-test-region", step=1):
+            jnp.ones((8, 8)).sum().block_until_ready()
+    # a trace landed (tests/test_trace_events.py reads one)
+    assert list((tmp_path / "trace").rglob("*.xplane.pb"))
+    # what utils/tracing.py keeps is what has a caller in the program
+    from cloud_server_tpu import utils
+    assert not hasattr(utils, "StepProfiler")
