@@ -55,7 +55,8 @@ MAX_LEN = 4096                # serving context (the config declares 131,072)
 SERVE_PROGRAMS = ["_mixed_step", "_prefill_core", "_decode_plain_core",
                   "_spec_core"]
 KERNEL_TESTS = ["tests/test_paged_attention.py",
-                "tests/test_flash_attention.py", "tests/test_fused_ce.py"]
+                "tests/test_flash_attention.py", "tests/test_fused_ce.py",
+                "tests/test_moe.py"]
 
 _T0 = time.monotonic()
 

@@ -73,7 +73,7 @@ def _kv_dequant(q: jnp.ndarray, scale: jnp.ndarray, dtype) -> jnp.ndarray:
     return (q.astype(jnp.float32) * scale).astype(dtype)
 
 
-def _mlp_apply(x, lp, cfg: ModelConfig, lora=None):
+def _mlp_apply(x, lp, cfg: ModelConfig, lora=None, stack=None):
     """Dense or MoE MLP residual block, chosen by cfg.num_experts.
 
     MoE routing at inference is per-call: prefill routes over the prompt
@@ -81,13 +81,22 @@ def _mlp_apply(x, lp, cfg: ModelConfig, lora=None):
     differs from training's full-batch routing — exact parity with the
     training forward holds only when nothing drops (generous
     expert_capacity_factor), which is also the sane serving configuration.
+    At such a factor (experts / experts per token, or more) a call of
+    `moe.GROUPED_MIN_TOKENS` tokens or more, a prefill group, runs
+    `moe_mlp`'s sorted dispatch, a grouped matmul over T * k rows, if the
+    caller unrolls its layers and says so: `stack` is (params["layers"],
+    layer index), where the kernel finds the experts' weights without a
+    copy. A decode round, a short chunk, a factor that can drop, a scan
+    over the layers, quantized weights and any call under a mesh of
+    several devices run the dense one-hot dispatch over E * capacity rows
+    (`moe._dispatch_grouped`).
 
     `lora`: per-row multi-adapter deltas (dense MLP only; the server
     rejects MLP-targeting adapters on MoE bases).
     """
     if cfg.num_experts >= 2:
         from cloud_server_tpu.models import moe
-        x, _ = moe.moe_mlp_block(x, lp, cfg)
+        x, _ = moe.moe_mlp_block(x, lp, cfg, stack)
         return x
     with jax.named_scope("mlp"):
         return transformer.mlp_block(x, lp, cfg, lora=lora)
@@ -228,7 +237,7 @@ def decode_step(params, token: jnp.ndarray, cfg: ModelConfig,
             v_all = v_all.at[layer_idx, batch_idx, pos].set(v[:, 0])
             o = attend(q, k_all[layer_idx], v_all[layer_idx])
         x = transformer.attention_out(x, o, lp, cfg)
-        x = _mlp_apply(x, lp, cfg)
+        x = _mlp_apply(x, lp, cfg, stack=(params["layers"], layer_idx))
     x = rms_norm(x, params["final_norm"]["scale"], cfg.norm_eps)
     logits = transformer.unembed(x[:, 0], params, cfg)
     return logits, KVCache(k_all, v_all, cache.length + 1, ks_all, vs_all)
@@ -286,7 +295,7 @@ def verify_step(params, tokens: jnp.ndarray, cfg: ModelConfig,
                              q_positions=pos, kv_length=cache.length + kk,
                              **scales)
         x = transformer.attention_out(x, o, lp, cfg)
-        x = _mlp_apply(x, lp, cfg)
+        x = _mlp_apply(x, lp, cfg, stack=(params["layers"], layer_idx))
     x = rms_norm(x, params["final_norm"]["scale"], cfg.norm_eps)
     logits = transformer.unembed(x, params, cfg)  # (B, K, V)
     return logits, KVCache(k_all, v_all, cache.length, ks_all, vs_all)

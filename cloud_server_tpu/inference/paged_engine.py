@@ -289,7 +289,8 @@ def window_forward(params, tokens: jnp.ndarray, cfg: ModelConfig,
                     k_scale_pool=cache.k_scale, v_scale_pool=cache.v_scale,
                     widths=widths)
             x = transformer.attention_out(x, o, lp, cfg, lora=ll)
-        x = _mlp_apply(x, lp, cfg, lora=ll)
+        x = _mlp_apply(x, lp, cfg, lora=ll,
+                       stack=(params["layers"], layer_idx))
 
     with jax.named_scope("unembed"):
         x = rms_norm(x, params["final_norm"]["scale"], cfg.norm_eps)

@@ -1,12 +1,29 @@
 """Sparse Mixture-of-Experts decoder LM (Mixtral-style) with expert
 parallelism.
 
-TPU-first design: routing uses the GShard/Mesh-TF dense-dispatch algorithm —
-top-k assignment becomes a (tokens, experts, capacity) one-hot dispatch
-tensor contracted with two einsums. Everything is static-shaped, so XLA
-tiles it onto the MXU, and the expert axis carries a sharding constraint
-(`ep`) so XLA inserts the all-to-all for expert parallelism automatically.
-No gather/scatter, no dynamic shapes, no host round-trips.
+`moe_mlp` has two dispatches and chooses between them from what it can
+observe (`_dispatch_grouped`):
+
+  * The GShard/Mesh-TF dense dispatch: top-k assignment becomes a (tokens,
+    experts, capacity) one-hot tensor contracted with two einsums, and the
+    experts run as batched einsums over (experts, capacity) rows. Static
+    shapes, no gather/scatter; the expert axis is sharded over `ep` and XLA
+    inserts the all-to-all. An expert's buffer holds `_capacity` rows
+    whether tokens fill them or not, and what overflows is dropped: this
+    is training's dispatch (`expert_capacity_factor` 1.25), every call
+    under a mesh of more than one device, and every call of few tokens,
+    where the experts' weight stream hides the empty rows.
+  * The sorted, dropless dispatch: where the capacity could drop nothing
+    anyway (`_capacity >= T`, a served Mixtral), T is
+    `GROUPED_MIN_TOKENS` or more and the caller says where the layer's
+    weights lie in the stacked parameters (`stack`), the T x k
+    assignments are sorted by expert, gathered to (T*k, D) rows and run
+    through one grouped matmul per weight, then gathered back: T*k expert
+    rows where the dense dispatch computes E*T. Same routing, same gates,
+    same `aux`, same precision; the shapes stay static (`group_sizes` is
+    data).
+
+No dynamic shapes, no host round-trips on either.
 
 Attention/norms/rope are shared with the dense model; only the MLP is
 replaced by the expert layer. Layers are stacked and scanned like
@@ -26,18 +43,79 @@ from jax import lax
 from cloud_server_tpu.config import ModelConfig
 from cloud_server_tpu.models import transformer
 from cloud_server_tpu.ops import rms_norm, rope_table
+from cloud_server_tpu.parallel.mesh import maybe_current_mesh
 
 Params = dict
 
 
 # ---------------------------------------------------------------------------
-# Routing (GShard dense dispatch)
+# Routing
 # ---------------------------------------------------------------------------
+
+# The fewest tokens of a call that the sorted dispatch takes. Placed by the
+# v5e measurement of PERF.md (PR 26): below it the experts' weight stream
+# bounds both dispatches and the dense one has no sort and no gathers.
+GROUPED_MIN_TOKENS = 512
+
 
 def _capacity(cfg: ModelConfig, num_tokens: int) -> int:
     cap = int(math.ceil(cfg.expert_capacity_factor * num_tokens
                         * cfg.num_experts_per_token / cfg.num_experts))
     return max(cap, 4)
+
+
+def _dispatch_grouped(cfg: ModelConfig, num_tokens: int, stack) -> bool:
+    """Whether `moe_mlp` sorts and runs grouped matmuls, from what the call
+    can observe:
+
+      * a capacity under which no expert can overflow, so both dispatches
+        compute the same function;
+      * enough tokens for the dense dispatch's empty rows to cost more
+        than the sort and the row tiles that straddle two experts;
+      * the experts' weights usable where they lie: the caller gave the
+        stacked parameters and the layer's index (`stack`), and they are
+        plain arrays of the compute dtype. XLA gives a custom call no view
+        of a slice, a cast or a dequantized `QTensor`: it would copy the
+        layer's experts first (2.8 GB at Mixtral's widths), where the
+        dense einsums fuse all three;
+      * no mesh of more than one device: a Mosaic kernel under a mesh
+        needs `shard_map`, and the all-to-all over `ep` is the dense
+        dispatch's. Not in this path yet.
+    """
+    if stack is None:
+        return False
+    mesh = maybe_current_mesh()
+    in_place = all(
+        isinstance(stack[0][name], jax.Array)
+        and stack[0][name].dtype == jnp.dtype(cfg.dtype)
+        for name in ("w_gate", "w_up", "w_down"))
+    return (num_tokens >= GROUPED_MIN_TOKENS
+            and _capacity(cfg, num_tokens) >= num_tokens
+            and in_place
+            and (mesh is None or mesh.size == 1))
+
+
+def _top_k_gates(router_logits: jnp.ndarray, k: int):
+    """(T, E) logits -> probs (T, E), renormalised top-k gates (T, k) and
+    their experts (T, k)."""
+    probs = jax.nn.softmax(router_logits, axis=-1)  # (T, E)
+    gate_vals, gate_idx = lax.top_k(probs, k)  # (T, k)
+    gate_vals = gate_vals / jnp.maximum(
+        gate_vals.sum(axis=-1, keepdims=True), 1e-9)
+    return probs, gate_vals, gate_idx
+
+
+def _router_aux(router_logits, probs, top1_onehot, dropped_frac):
+    """Aux stats: fraction of tokens routed to each expert (top-1 view) and
+    mean router prob, per GShard load-balancing loss."""
+    e = probs.shape[-1]
+    frac_tokens = top1_onehot.mean(axis=0)  # (E,)
+    mean_probs = probs.mean(axis=0)  # (E,)
+    return {
+        "load_balance": (frac_tokens * mean_probs).sum() * e,
+        "router_z": jnp.square(jax.nn.logsumexp(router_logits, -1)).mean(),
+        "dropped_frac": dropped_frac,
+    }
 
 
 def top_k_routing(router_logits: jnp.ndarray, k: int, capacity: int):
@@ -56,12 +134,7 @@ def top_k_routing(router_logits: jnp.ndarray, k: int, capacity: int):
       aux: dict with load-balance / z-loss ingredients.
     """
     t, e = router_logits.shape
-    probs = jax.nn.softmax(router_logits, axis=-1)  # (T, E)
-
-    # Top-k gating with renormalised weights.
-    gate_vals, gate_idx = lax.top_k(probs, k)  # (T, k)
-    gate_vals = gate_vals / jnp.maximum(
-        gate_vals.sum(axis=-1, keepdims=True), 1e-9)
+    probs, gate_vals, gate_idx = _top_k_gates(router_logits, k)
 
     # One-hot per assignment: (T, k, E).
     assign = jax.nn.one_hot(gate_idx, e, dtype=jnp.float32)
@@ -81,27 +154,116 @@ def top_k_routing(router_logits: jnp.ndarray, k: int, capacity: int):
     dispatch = slot_onehot.sum(axis=1)  # (T, E, C)
     combine = (slot_onehot * gate_vals[:, :, None, None]).sum(axis=1)
 
-    # Aux stats: fraction of tokens routed to each expert (top-1 view) and
-    # mean router prob, per GShard load-balancing loss.
-    frac_tokens = assign[:, 0, :].mean(axis=0)  # (E,)
-    mean_probs = probs.mean(axis=0)  # (E,)
-    aux = {
-        "load_balance": (frac_tokens * mean_probs).sum() * e,
-        "router_z": jnp.square(jax.nn.logsumexp(router_logits, -1)).mean(),
-        "dropped_frac": 1.0 - keep[:, 0, :].sum() / t,
-    }
+    aux = _router_aux(router_logits, probs, assign[:, 0, :],
+                      1.0 - keep[:, 0, :].sum() / t)
     return dispatch, combine, aux
 
 
-def moe_mlp(x: jnp.ndarray, lp: dict, cfg: ModelConfig):
+# ---------------------------------------------------------------------------
+# The expert layer
+# ---------------------------------------------------------------------------
+
+# (rows, contraction, columns) tiles of the grouped matmul kernel on the
+# TPU, for x @ w_gate|w_up and for act @ w_down, from the v5e sweep of
+# PERF.md (PR 26). A row tile that straddles two experts is computed for
+# both, so fewer rows waste less; a weight tile is fetched once per row
+# tile unless it spans the contraction (as 4,096 does on the way in: an
+# expert's consecutive row tiles then reuse it), so more rows fetch less.
+_GMM_TILING_IN = (256, 4096, 512)
+_GMM_TILING_OUT = (256, 1024, 2048)
+
+
+def _grouped_matmul(lhs, rhs, group_sizes, tiling, kernel: bool):
+    """lhs (M, K) rows sorted by group, rhs (G, K, N), group_sizes (G,)
+    int32 -> (M, N) in lhs.dtype, accumulated in float32: row r of group g
+    is lhs[r] @ rhs[g], and an empty group's weights are not read. The
+    megablox Pallas kernel on the TPU, `lax.ragged_dot` elsewhere (XLA:TPU's
+    own lowering of it is the same kernel at a (512, 512, 512) tiling that
+    a caller cannot choose)."""
+    if not kernel:
+        return lax.ragged_dot(
+            lhs, rhs, group_sizes,
+            preferred_element_type=jnp.float32).astype(lhs.dtype)
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+    m, k = lhs.shape
+    tm, tk, tn = tiling
+    pad = -m % tm  # the kernel wants whole row tiles; no group owns the rest
+    if pad:
+        lhs = jnp.pad(lhs, ((0, pad), (0, 0)))
+    # dtype-determined precision, as this repo's own kernels have it: a
+    # global "highest" would ask Mosaic for an fp32 contraction of bf16
+    # tiles, which it refuses ("Bad lhs type")
+    with jax.default_matmul_precision(
+            "default" if lhs.dtype == jnp.bfloat16 else "highest"):
+        out = gmm(lhs, rhs, group_sizes, preferred_element_type=lhs.dtype,
+                  tiling=(tm, min(tk, k), min(tn, rhs.shape[2])))
+    return out[:m] if pad else out
+
+
+# jitted so that its trace (three Pallas kernels on the TPU) is cached by
+# shape: every layer of every step program calls it, at a few row counts
+@partial(jax.jit, static_argnames=("kernel",))
+def _grouped_experts(rows, w_gate, w_up, w_down, group_sizes, kernel: bool):
+    """SwiGLU experts over rows sorted by expert: (M, D) -> (M, D)."""
+    gate = _grouped_matmul(rows, w_gate, group_sizes, _GMM_TILING_IN, kernel)
+    up = _grouped_matmul(rows, w_up, group_sizes, _GMM_TILING_IN, kernel)
+    act = jax.nn.silu(gate) * up
+    return _grouped_matmul(act, w_down, group_sizes, _GMM_TILING_OUT, kernel)
+
+
+def _moe_grouped(tokens, router_logits, layers, layer, cfg: ModelConfig):
+    """The sorted, dropless dispatch of `moe_mlp`: tokens (T, D) -> (T, D).
+    `layers` holds the stacked (L, E, ...) expert weights, `layer` (an int
+    or an int32 scalar) says which of them is this call's."""
+    t, d = tokens.shape
+    k, e = cfg.num_experts_per_token, cfg.num_experts
+    n_layers = layers["w_gate"].shape[0]
+    with jax.named_scope("moe_route"):
+        probs, gate_vals, gate_idx = _top_k_gates(router_logits, k)
+        aux = _router_aux(
+            router_logits, probs,
+            jax.nn.one_hot(gate_idx[:, 0], e, dtype=jnp.float32),
+            jnp.zeros((), jnp.float32))
+    with jax.named_scope("moe_dispatch"):
+        # assignment a = token * k + slot; a stable sort, so an expert's
+        # rows keep token order
+        expert_of = gate_idx.reshape(t * k)
+        order = jnp.argsort(expert_of, stable=True)
+        rows = tokens[order // k]  # (T*k, D)
+        # the stack seen as L * E groups, every other layer's empty: the
+        # kernel then reads this layer's experts where they lie
+        group_sizes = jnp.zeros((n_layers * e,), jnp.int32).at[
+            layer * e + expert_of].add(1)
+    with jax.named_scope("moe_experts"), jax.named_scope("grouped"):
+        ys = _grouped_experts(
+            rows, *(layers[name].reshape((n_layers * e,)
+                                         + layers[name].shape[2:])
+                    for name in ("w_gate", "w_up", "w_down")),
+            group_sizes, kernel=jax.default_backend() == "tpu")
+    with jax.named_scope("moe_combine"):
+        inverse = jnp.zeros((t * k,), jnp.int32).at[order].set(
+            jnp.arange(t * k, dtype=jnp.int32))
+        # gates rounded to the compute dtype and summed in float32, as the
+        # dense dispatch's combine einsum has them
+        out = jnp.einsum(
+            "tk,tkd->td", gate_vals.astype(cfg.dtype),
+            ys[inverse].reshape(t, k, d),
+            preferred_element_type=jnp.float32).astype(cfg.dtype)
+    return out, aux
+
+
+def moe_mlp(x: jnp.ndarray, lp: dict, cfg: ModelConfig, stack=None):
     """Expert-parallel SwiGLU MoE layer.
 
     x: (B, S, D). lp: router (D, E), w_gate/w_up (E, D, F), w_down (E, F, D).
+    stack: optionally (layers, index), the stacked (L, ...) parameters
+    that `lp` is layer `index` of: a caller that unrolls its layers says
+    so, and the sorted dispatch may then run (`_dispatch_grouped`); one
+    that scans over them cannot, and keeps the dense dispatch.
     Returns (out (B, S, D), aux dict of scalars).
     """
     b, s, d = x.shape
     tokens = x.reshape(b * s, d)
-    capacity = _capacity(cfg, b * s)
 
     # scope names are what a device trace tells the einsums apart by
     # (`tf_op` of an op's event metadata; cellbench/hostplane.py)
@@ -109,8 +271,13 @@ def moe_mlp(x: jnp.ndarray, lp: dict, cfg: ModelConfig):
         router_logits = jnp.einsum(
             "td,de->te", tokens.astype(jnp.float32),
             lp["router"].astype(jnp.float32))
+    if _dispatch_grouped(cfg, b * s, stack):
+        out, aux = _moe_grouped(tokens, router_logits, *stack, cfg)
+        return out.reshape(b, s, d), aux
+    with jax.named_scope("moe_route"):
         dispatch, combine, aux = top_k_routing(
-            router_logits, cfg.num_experts_per_token, capacity)
+            router_logits, cfg.num_experts_per_token,
+            _capacity(cfg, b * s))
 
     # (T, E, C) x (T, D) -> (E, C, D): the all-to-all, inserted by XLA from
     # the `ep` sharding of the expert axis.
@@ -183,15 +350,15 @@ def init_params(cfg: ModelConfig, rng: jax.Array) -> Params:
     return jax.tree.unflatten(treedef, out)
 
 
-def moe_mlp_block(x, lp, cfg: ModelConfig):
+def moe_mlp_block(x, lp, cfg: ModelConfig, stack=None):
     """Residual MoE MLP sub-block: norm -> route/experts -> add.
 
     The single definition shared by training (`_moe_block`) and the
     inference engine (`engine._mlp_apply`), so serve-time MoE math can
-    never drift from the trained model.
+    never drift from the trained model. `stack`: see `moe_mlp`.
     """
     h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
-    out, aux = moe_mlp(h, lp, cfg)
+    out, aux = moe_mlp(h, lp, cfg, stack)
     return x + out, aux
 
 

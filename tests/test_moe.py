@@ -1,6 +1,7 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from cloud_server_tpu.config import MeshConfig, ModelConfig, TrainConfig
 from cloud_server_tpu.models import moe
@@ -116,3 +117,175 @@ def test_moe_fused_ce_matches_dense():
     for a, b in zip(jax.tree.leaves(gf), jax.tree.leaves(gd)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    atol=1e-5, rtol=1e-4)
+
+
+# ---------------------------------------------------------------------------
+# The sorted, dropless dispatch against the dense one
+# ---------------------------------------------------------------------------
+
+LAYERS, LAYER = 3, 1
+
+
+def _stack(e, k, dtype, seed=0, d=32, f=64):
+    """A no-drop configuration and LAYERS layers of stacked parameters."""
+    cfg = ModelConfig(**{**MOE_TINY.__dict__, "num_experts": e,
+                         "num_experts_per_token": k, "dtype": dtype,
+                         "param_dtype": dtype,
+                         "expert_capacity_factor": e / k})
+    ks = jax.random.split(jax.random.key(seed), 4)
+    layers = {"router": jax.random.normal(ks[0], (LAYERS, d, e)) * 0.3,
+              "w_gate": jax.random.normal(ks[1], (LAYERS, e, d, f)) * 0.2,
+              "w_up": jax.random.normal(ks[2], (LAYERS, e, d, f)) * 0.2,
+              "w_down": jax.random.normal(ks[3], (LAYERS, e, f, d)) * 0.2}
+    return cfg, jax.tree.map(lambda w: w.astype(dtype), layers)
+
+
+def _both_dispatches(monkeypatch, x, layers, cfg):
+    """(out, aux) of `moe_mlp` for layer LAYER of the stack through the
+    sorted dispatch and through the dense one, at any T: the threshold is
+    moved, nothing else."""
+    lp = jax.tree.map(lambda w: w[LAYER], layers)
+    stack = (layers, LAYER)
+    t = x.shape[0] * x.shape[1]
+    monkeypatch.setattr(moe, "GROUPED_MIN_TOKENS", 1)
+    assert moe._dispatch_grouped(cfg, t, stack)
+    grouped = moe.moe_mlp(x, lp, cfg, stack)
+    monkeypatch.setattr(moe, "GROUPED_MIN_TOKENS", 10 ** 9)
+    assert not moe._dispatch_grouped(cfg, t, stack)
+    return grouped, moe.moe_mlp(x, lp, cfg, stack)
+
+
+# T up to 2,048 at Mixtral's (8, 2); at OLMoE's (64, 8) the dense
+# dispatch's (T, k, E, C) one-hot is 8 GB there, so 256
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("router", ["random", "one_expert", "empty_expert"])
+@pytest.mark.parametrize("e,k,t", [
+    (8, 2, 64), (8, 2, 512), (8, 2, 2048), (64, 8, 64), (64, 8, 256)])
+def test_grouped_dispatch_matches_dense(monkeypatch, e, k, t, router, dtype):
+    cfg, layers = _stack(e, k, dtype)
+    x = jax.random.normal(jax.random.key(t), (2, t // 2, cfg.embed_dim))
+    bias = jnp.zeros((e,))
+    if router == "one_expert":
+        # every token's first choice is expert 1 (and its others follow
+        # the token): one group holds T rows, the capacity's worst case
+        bias = bias.at[1].set(1e3)
+    elif router == "empty_expert":
+        bias = bias.at[e - 1].set(-1e3)
+    # the bias rides a constant input feature, so the router stays (D, E)
+    x = x.at[..., 0].set(1.0).astype(dtype)
+    layers["router"] = layers["router"].astype(jnp.float32).at[
+        LAYER, 0].set(bias).astype(dtype)
+
+    (out_g, aux_g), (out_d, aux_d) = _both_dispatches(
+        monkeypatch, x, layers, cfg)
+    assert out_g.dtype == out_d.dtype == jnp.dtype(dtype)
+    assert float(aux_d["dropped_frac"]) == float(aux_g["dropped_frac"]) == 0.0
+    for name in ("load_balance", "router_z"):
+        assert float(aux_g[name]) == float(aux_d[name]), name
+    got = np.asarray(out_g, np.float32)
+    want = np.asarray(out_d, np.float32)
+    if dtype == "float32":
+        np.testing.assert_allclose(got, want, atol=1e-5, rtol=1e-5)
+    else:
+        # the einsums' own tolerance: both round to bfloat16 at the same
+        # places (gate, up, act, down, out) and sum in float32 in another
+        # order, so they differ by an ulp (2**-8) of a few of those
+        np.testing.assert_allclose(
+            got, want, rtol=2.0 ** -6, atol=2.0 ** -6 * np.abs(want).max())
+    if router == "one_expert":
+        top1 = np.asarray(jnp.argmax(
+            x.reshape(t, -1).astype(jnp.float32)
+            @ layers["router"][LAYER].astype(jnp.float32), axis=-1))
+        assert (top1 == 1).all()
+
+
+@pytest.mark.parametrize("t", [4, 64, 256, 512, 1024, 2048, 4096, 16384])
+def test_which_dispatch_runs(t, devices8):
+    """The rule reads the capacity, the token count, where the weights
+    lie and the mesh, and nothing else."""
+    from cloud_server_tpu.models.quantization import quantize_params
+    from cloud_server_tpu.parallel.mesh import set_current_mesh
+    no_drop, layers = _stack(8, 2, "float32")          # factor E / k
+    wide, wide_layers = _stack(64, 8, "float32")
+    stack = (layers, LAYER)
+    default = ModelConfig(**{**no_drop.__dict__,
+                             "expert_capacity_factor": 1.25})
+    almost = ModelConfig(**{**no_drop.__dict__,
+                            "expert_capacity_factor": 3.9})
+    # a capacity that can drop keeps the dense dispatch at every T
+    assert not moe._dispatch_grouped(default, t, stack)
+    if t > 4:  # under 4 rows `_capacity` rounds up to all of them
+        assert moe._capacity(no_drop, t) >= t > moe._capacity(almost, t)
+        assert not moe._dispatch_grouped(almost, t, stack)
+    # one that cannot moves at the threshold and above, not below
+    moved = t >= moe.GROUPED_MIN_TOKENS
+    assert moe._dispatch_grouped(no_drop, t, stack) == moved
+    assert moe._dispatch_grouped(wide, t, (wide_layers, 0)) == moved
+    # a caller that scans its layers has no stack to point into; weights
+    # that need a cast or a dequantization first are not used in place
+    assert not moe._dispatch_grouped(no_drop, t, None)
+    assert not moe._dispatch_grouped(
+        no_drop, t, (jax.tree.map(lambda w: w.astype("bfloat16"), layers),
+                     LAYER))
+    assert not moe._dispatch_grouped(
+        no_drop, t, (quantize_params({"layers": layers})["layers"], LAYER))
+    # under a mesh of more than one device the dense dispatch stays
+    make_mesh(MeshConfig(fsdp=2, ep=4))
+    assert not moe._dispatch_grouped(no_drop, t, stack)
+    set_current_mesh(jax.sharding.Mesh(np.array(devices8[:1]), ("ep",)))
+    assert moe._dispatch_grouped(no_drop, t, stack) == moved
+
+
+def test_paged_prefill_takes_the_sorted_dispatch_and_agrees(monkeypatch):
+    """The paged engine's window forward hands `moe_mlp` the stack: a
+    prefill group over the threshold runs the sorted dispatch (seen
+    through `_grouped_experts`) and gives the dense dispatch's logits."""
+    from cloud_server_tpu.inference import paged_engine
+    cfg = ModelConfig(**{**MOE_TINY.__dict__, "expert_capacity_factor": 2.0})
+    params = moe.init_params(cfg, jax.random.key(0))
+    cache = paged_engine.init_paged_cache(
+        cfg, num_pages=16, page_size=8, batch=2, max_pages_per_slot=4)
+    cache = cache._replace(
+        tables=jnp.arange(8, dtype=jnp.int32).reshape(2, 4))
+    tokens = jax.random.randint(jax.random.key(1), (2, 16), 0, 64)
+    calls = []
+    real = moe._grouped_experts
+    monkeypatch.setattr(moe, "_grouped_experts",
+                        lambda *a, **kw: calls.append(a[0].shape) or
+                        real(*a, **kw))
+
+    def logits(threshold):
+        monkeypatch.setattr(moe, "GROUPED_MIN_TOKENS", threshold)
+        out, _ = paged_engine.window_forward(
+            params, tokens, cfg, cache, logits_at=None, all_logits=True)
+        return np.asarray(out)
+
+    grouped = logits(32)
+    assert calls == [(2 * 16 * cfg.num_experts_per_token, cfg.embed_dim)
+                     ] * cfg.num_layers
+    dense = logits(33)
+    assert len(calls) == cfg.num_layers
+    np.testing.assert_allclose(grouped, dense, atol=2e-5, rtol=1e-5)
+
+
+@pytest.mark.on_tpu
+def test_compiled_on_tpu_grouped_dispatch(monkeypatch):
+    """The megablox kernel as Mosaic compiles it, reading the layer's
+    experts out of the stack, against the dense einsums on the chip: off
+    the chip the sorted dispatch runs `lax.ragged_dot`."""
+    assert jax.default_backend() == "tpu"
+    t = moe.GROUPED_MIN_TOKENS
+    cfg, layers = _stack(8, 2, "bfloat16", d=512, f=1024)
+    x = jax.random.normal(jax.random.key(5), (2, t // 2, 512)).astype(
+        jnp.bfloat16)
+    lp = jax.tree.map(lambda w: w[LAYER], layers)
+    stack = (layers, LAYER)
+    assert moe._dispatch_grouped(cfg, t, stack)
+    got, _ = jax.jit(lambda x, lp, layers: moe.moe_mlp(
+        x, lp, cfg, (layers, LAYER)))(x, lp, layers)
+    monkeypatch.setattr(moe, "GROUPED_MIN_TOKENS", 10 ** 9)
+    want, _ = jax.jit(lambda x, lp: moe.moe_mlp(x, lp, cfg))(x, lp)
+    want = np.asarray(want, np.float32)
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32), want, rtol=2.0 ** -6,
+        atol=2.0 ** -6 * np.abs(want).max())
