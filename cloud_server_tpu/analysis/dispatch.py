@@ -107,6 +107,11 @@ SCHEDULER_LOOPS: dict[str, tuple[str, ...]] = {
         "PagedInferenceServer._ensure_penalty_state",
         "PagedInferenceServer._emit",
         "PagedInferenceServer._finish",
+        # deferred delivery: the commit records, `_deliver` wakes the
+        # clients after the next launch — host work only, under a
+        # running program, so a sync here would stall two iterations
+        "PagedInferenceServer._complete_later",
+        "PagedInferenceServer._deliver",
         "PagedInferenceServer._release_slot",
         "PagedInferenceServer._committed",
         "PagedInferenceServer._next_rng",

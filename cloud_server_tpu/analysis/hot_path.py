@@ -79,6 +79,14 @@ HOT_PATHS: dict[str, tuple[str, ...]] = {
         "IterationProfiler.phases_ms",
         "derive_gap_fields",
     ),
+    # deferred delivery: the scheduler's thread runs the commit's
+    # stream calls and completions between a launch and the next
+    # plan, every iteration: queue puts and event sets, nothing that
+    # touches the device, a file or a clock
+    "cloud_server_tpu/inference/paged_server.py": (
+        "PagedInferenceServer._complete_later",
+        "PagedInferenceServer._deliver",
+    ),
     # cache telemetry: the record hooks run inside the allocator's
     # lookup/alloc/release/evict — i.e. inside _start_admissions /
     # _extend_chains / _release_slot on every scheduler iteration that

@@ -77,6 +77,15 @@ code):
     function must still assign ``finish_reason``.
   * ``COMPLETE_FUNCS`` — the ``_complete`` bodies whose LC2
     structure is pinned; a rename breaks the roster loudly.
+  * ``DEFERRED_COMPLETION_FUNCS`` — methods that complete a handle
+    LATER: they put it on a delivery list of the object, and the
+    rostered drain runs that list through ``_complete`` (the paged
+    server's commit, which wakes nobody before the next launch). A
+    call to one counts as a call to ``_complete``, so LC1 is checked
+    at every commit site through the list instead of being waived
+    as an escape into a container. Each must still append to its
+    list, and its drain must still read the list and call
+    ``_complete``.
   * ``OWNERSHIP_TRANSFER_FUNCS`` — callables that accept ownership
     of a page list (today: the ``_Slot`` record, whose pages are
     released later through ``_release_slot``).
@@ -149,6 +158,17 @@ COMPLETE_FUNCS: dict[str, tuple[str, ...]] = {
         "PagedInferenceServer._complete",),
     "cloud_server_tpu/inference/server.py": (
         "InferenceServer._complete",),
+}
+
+# Methods that complete a handle through a delivery list:
+# qualname -> (the list attribute it appends to, the drain that runs
+# the list through `_complete`). Calling one IS completing for LC1.
+# Rot rule: the method still appends to `self.<list>`; the drain still
+# reads `self.<list>` and calls `self._complete`.
+DEFERRED_COMPLETION_FUNCS: dict[str, dict[str, tuple[str, str]]] = {
+    "cloud_server_tpu/inference/paged_server.py": {
+        "PagedInferenceServer._complete_later":
+            ("_deliveries", "PagedInferenceServer._deliver")},
 }
 
 # Callables that take OWNERSHIP of a page list passed to them (LC3
@@ -973,9 +993,13 @@ class _TornWriteScan:
 
 # -- per-file orchestration -------------------------------------------------
 
-def _completing_methods(cls: ast.ClassDef) -> set[str]:
+def _completing_methods(cls: ast.ClassDef,
+                        deferred: frozenset[str] = frozenset()
+                        ) -> set[str]:
     """Self-methods that reach `_complete` transitively — the
-    class-local call-graph propagation the lock pass also uses."""
+    class-local call-graph propagation the lock pass also uses.
+    `deferred` names the class's rostered deferring methods
+    (`Class.method`): they reach it through their delivery list."""
     methods = {c.name: c for c in cls.body
                if isinstance(c, _FUNC_NODES)}
     if "_complete" not in methods:
@@ -991,7 +1015,9 @@ def _completing_methods(cls: ast.ClassDef) -> set[str]:
                     if leaf in methods:
                         out.add(leaf)
         calls[name] = out
-    comp = {"_complete"}
+    comp = {"_complete"} | {
+        name for owner, _, name in (q.rpartition(".") for q in deferred)
+        if owner == cls.name and name in methods}
     changed = True
     while changed:
         changed = False
@@ -1002,7 +1028,8 @@ def _completing_methods(cls: ast.ClassDef) -> set[str]:
     return comp
 
 
-def _iter_functions(tree: ast.Module):
+def _iter_functions(tree: ast.Module,
+                    deferred: frozenset[str] = frozenset()):
     """(qualname, class node | None, completing set, fn node) for
     every function; nested defs are visited at their own qualname."""
     def visit(node, prefix, cls, comp):
@@ -1012,7 +1039,7 @@ def _iter_functions(tree: ast.Module):
                 yield from visit(child, prefix + child.name + ".",
                                  cls, comp)
             elif isinstance(child, ast.ClassDef):
-                sub = _completing_methods(child)
+                sub = _completing_methods(child, deferred)
                 yield from visit(child, prefix + child.name + ".",
                                  child, sub)
 
@@ -1023,7 +1050,8 @@ def check_source(path: str, source: str, *,
                  owner_funcs: tuple[str, ...] | None = None,
                  marker_funcs: tuple[str, ...] | None = None,
                  complete_funcs: tuple[str, ...] | None = None,
-                 transfer_funcs: tuple[str, ...] | None = None
+                 transfer_funcs: tuple[str, ...] | None = None,
+                 deferred_funcs: dict[str, tuple[str, str]] | None = None
                  ) -> list[Finding]:
     """Run LC1–LC4 over one file. Rosters default to the audited
     module constants keyed by `path`; fixtures inject their own."""
@@ -1035,6 +1063,8 @@ def check_source(path: str, source: str, *,
         complete_funcs = COMPLETE_FUNCS.get(path, ())
     if transfer_funcs is None:
         transfer_funcs = OWNERSHIP_TRANSFER_FUNCS.get(path, ())
+    if deferred_funcs is None:
+        deferred_funcs = DEFERRED_COMPLETION_FUNCS.get(path, {})
     tree = ast.parse(source, filename=path)
     functions, classes = collect_functions(tree)
     out: list[Finding] = []
@@ -1077,12 +1107,38 @@ def check_source(path: str, source: str, *,
     for qual in transfer_funcs:
         if qual not in functions and qual not in classes:
             out.append(missing(qual, "OWNERSHIP_TRANSFER_FUNCS"))
+    for qual, (attr, drain) in deferred_funcs.items():
+        fn, drain_fn = functions.get(qual), functions.get(drain)
+        if fn is None or drain_fn is None:
+            out.append(missing(qual if fn is None else drain,
+                               "DEFERRED_COMPLETION_FUNCS"))
+            continue
+        if not any(isinstance(n, ast.Call)
+                   and isinstance(n.func, ast.Attribute)
+                   and n.func.attr == "append"
+                   and dotted_name(n.func.value) == "self." + attr
+                   for n in ast.walk(fn)):
+            out.append(Finding(
+                path, fn.lineno, CHECKER, qual,
+                "sanction rot: DEFERRED_COMPLETION_FUNCS names this "
+                f"function but it no longer appends to self.{attr} — "
+                "remove it from the roster"))
+        names = {dotted_name(n) for n in ast.walk(drain_fn)
+                 if isinstance(n, ast.Attribute)}
+        if not {"self." + attr, "self._complete"} <= names:
+            out.append(Finding(
+                path, drain_fn.lineno, CHECKER, drain,
+                "sanction rot: DEFERRED_COMPLETION_FUNCS names this "
+                f"function as the drain of self.{attr} but it no "
+                "longer reads the list and calls self._complete — "
+                "what is put there would never complete (LC1)"))
 
     transfer_leaves = {q.split(".")[-1] for q in transfer_funcs}
     owner_set = set(owner_funcs)
     marker_set = set(marker_funcs)
 
-    for qual, cls, completing, fn in _iter_functions(tree):
+    for qual, cls, completing, fn in _iter_functions(
+            tree, frozenset(deferred_funcs)):
         is_owner = qual in owner_set
         # LC1a: terminal assignment -> complete exactly once
         if qual not in marker_set \

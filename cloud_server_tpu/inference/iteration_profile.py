@@ -22,16 +22,20 @@ boundaries the scheduler already crosses):
     device      the dispatch statement (arg device transfer + launch)
                 through the one sanctioned `device_get` commit point —
                 the only phase that waits on the accelerator
-    commit      token emit / grammar / speculation bookkeeping on the
-                synced results, up to the next phase's first statement:
-                the commit function's return included, where the synced
-                device arrays are dropped and the scheduler's thread
-                first gives up the interpreter lock to the streaming
-                threads its emits woke (6 ms of 8 with 64 clients
-                attached; PERF.md, PR 25)
+    commit      ledger writes, tokens recorded on their requests,
+                grammar / speculation bookkeeping on the synced
+                results, up to the next phase's first statement. On the
+                sequential paths the delivery of those tokens too; an
+                overlapped step wakes nobody here
     launch      (async scheduler only) the ledger patch + next-
                 dispatch launch that follows the commit — the tail of
                 the serialized critical path when overlap is on
+    deliver     (async scheduler only) the commit's stream calls and
+                completions, run after the launch: the streaming
+                threads they wake take the interpreter lock under the
+                program just launched, not between two programs (6 ms
+                of an 8 ms commit with 64 clients attached before;
+                PERF.md, PR 25 and PR 30)
     epilogue    flight-recorder / tracing / SLO bookkeeping at the end
                 of the iteration
 
@@ -41,8 +45,9 @@ fraction of each iteration the device sits idle while the host works.
 
 OVERLAPPED iterations (the async double-buffered scheduler, ROADMAP
 item 4 — now built): sweep / admission / build run WHILE the device
-executes the previous iteration's program, so they are no longer
-device-idle time. Those phases fold into `overlap_ms` (and the single
+executes the previous iteration's program, and deliver while it
+executes the next one's, so they are no longer device-idle time.
+Those phases fold into `overlap_ms` (and the single
 `overlap`-labeled histogram series), `device` becomes the RESIDUAL
 wait after the overlapped host work, and `host_gap_frac` measures
 only the serialized host tail (`commit` + `launch` + `epilogue`) —
@@ -93,20 +98,22 @@ from time import perf_counter
 from cloud_server_tpu.utils.serving_metrics import histogram_percentile
 
 # Canonical phase order — the contiguous partition of one iteration.
-# `launch` only appears in overlapped iterations (async scheduler).
+# `launch` and `deliver` only appear in iterations of the async
+# scheduler.
 PHASES = ("sweep", "admission", "build", "device", "commit", "launch",
-          "epilogue")
+          "deliver", "epilogue")
 
 # Phases that run concurrently with the in-flight device program when
 # the async double-buffered scheduler has a dispatch outstanding; they
 # fold into the `overlap` histogram label and `overlap_ms`.
-OVERLAP_PHASES = ("sweep", "admission", "build")
+OVERLAP_PHASES = ("sweep", "admission", "build", "deliver")
 
 # Histogram label set: the fine-grained phases plus the folded
 # `overlap` series overlapped iterations observe instead of their
-# sweep/admission/build split (keeping `profile_summary`'s host-gap
-# arithmetic honest across sequential and overlapped iterations — the
-# fine split of overlapped iterations stays in the flight records).
+# sweep/admission/build/deliver split (keeping `profile_summary`'s
+# host-gap arithmetic honest across sequential and overlapped
+# iterations — the fine split of overlapped iterations stays in the
+# flight records).
 HIST_PHASES = PHASES + ("overlap",)
 
 # Millisecond bucket ladder for the per-phase histograms: sub-0.1 ms
@@ -134,7 +141,7 @@ _ITER_ARG_KEYS = ("iteration", "scheduler", "n_live", "decode_rounds",
                   "budget_utilization", "host_ms", "device_wait_ms",
                   "host_gap_frac", "preemptions", "pending", "n_jobs",
                   "overlap", "overlap_ms", "inflight_depth",
-                  "overlap_launch_lead_ms")
+                  "overlap_launch_lead_ms", "delivered")
 
 
 class IterationProfiler:
@@ -287,8 +294,8 @@ def derive_gap_fields(phases_ms: dict[str, float],
 
     Sequential iterations (`overlapped=False`): host = everything
     except `device` — the historical definition, byte-identical.
-    Overlapped iterations: sweep/admission/build ran concurrently with
-    the in-flight device program, so they move into `overlap_ms`;
+    Overlapped iterations: sweep/admission/build and deliver ran
+    concurrently with a device program, so they move into `overlap_ms`;
     `host_ms` keeps only the residual serialized tail (commit + launch
     + epilogue) and `host_gap_frac` therefore measures what the
     overlap could NOT hide."""
@@ -335,7 +342,7 @@ def profile_summary(snapshot: dict) -> dict | None:
             device_ms += entry["sum"]
         elif phase == "overlap":
             # host work performed while a dispatch was in flight (the
-            # async scheduler's hidden sweep/admission/build): not
+            # async scheduler's hidden OVERLAP_PHASES): not
             # device-idle time, so not host gap
             overlap_ms += entry["sum"]
         else:

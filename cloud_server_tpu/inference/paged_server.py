@@ -1398,6 +1398,12 @@ class PagedInferenceServer:
         # deferred sweep reaps: (slot_id, _Slot, reason) marked while a
         # dispatch is in flight; released right after its commit
         self._reaped: list[tuple[int, _Slot, str]] = []
+        # deferred delivery: what a commit would have woken somebody
+        # with, in the order it would have run — (req, token) for a
+        # stream call, (req, None) for the request's completion.
+        # `_deliver` runs it after the next launch (overlapped steps)
+        # or right after the commit (sequential ones)
+        self._deliveries: list[tuple[Request, int | None]] = []
         # disaggregated prefill/decode handoff (the ReplicatedRouter's
         # role-specialized fleets): requests whose chunked prefill
         # completed THIS iteration and that carry a submit-time
@@ -1824,7 +1830,8 @@ class PagedInferenceServer:
 
     def _emit(self, req: Request, token: int, logprob: float) -> bool:
         n0 = len(req.emit_times)
-        done = emit_token(req, token, logprob, self.infer_cfg)
+        done = emit_token(req, token, logprob, self.infer_cfg,
+                          self._deliveries)
         if not (done and req.finish_reason == "eos"):
             self.tokens_emitted += 1  # stop-truncated tokens still count
             if self.qos is not None:
@@ -1875,7 +1882,36 @@ class PagedInferenceServer:
 
     def _finish(self, slot_id: int) -> None:
         slot = self._release_slot(slot_id, self._committed(slot_id))
-        self._complete(slot.req)
+        self._complete_later(slot.req)
+
+    def _complete_later(self, req: Request) -> None:
+        """A commit's `_complete(req)`: the request's fate is decided
+        and its slot released now, its waiters are woken by `_deliver`,
+        behind the tokens the same commit recorded for it."""
+        self._deliveries.append((req, None))
+
+    def _deliver(self) -> None:
+        """Run the delivery list in order: each token to its stream
+        callback, each finished request through `_complete`. The count
+        joins the flight record as `delivered`. A callback that raises
+        ends the step as it always did, but only after the rest of the
+        list ran: a completion queued behind it has no slot any more,
+        so `_fail_all` could not find its request."""
+        out, self._deliveries = self._deliveries, []
+        st = self._iter_stats
+        if st:
+            st["delivered"] = st.get("delivered", 0) + len(out)
+        err = None
+        for req, token in out:
+            try:
+                if token is None:
+                    self._complete(req)
+                else:
+                    req.stream(token)
+            except BaseException as e:  # noqa: BLE001 — raised below
+                err = err or e
+        if err is not None:
+            raise err
 
     # -- admission ----------------------------------------------------------
 
@@ -2194,13 +2230,14 @@ class PagedInferenceServer:
                     # cache — a resubmit would reuse it)
                     slot = self._release_slot(sid, self._committed(sid))
                     slot.req.finish_reason = "cancelled"
-                    self._complete(slot.req)
+                    self._complete_later(slot.req)
                     continue
                 self.active[sid] = True
                 if self._emit(slot.req, int(job.toks[i]),
                               float(job.lps[i])):
                     self._finish(sid)
             self._jobs.remove(job)
+            self._deliver()
 
     # -- decode -------------------------------------------------------------
 
@@ -2511,12 +2548,16 @@ class PagedInferenceServer:
         self._commit_decode_rows(live_ids, toks, lps, counts, lens, last,
                                  self._drafted_rows(g_iter, spec_lens,
                                                     len(live_ids)))
+        self._deliver()
 
     def _commit_decode_rows(self, live_ids, toks, lps, counts, lens,
                             last, drafted=None, owners=None) -> None:
         """Scatter a compacted decode dispatch's results back to slots
-        and emit (shared by _decode_dispatch, _mixed_dispatch, and the
-        async scheduler's _commit_inflight).
+        and record the tokens (shared by _decode_dispatch,
+        _mixed_dispatch, and the async scheduler's _commit_inflight).
+        Nobody is woken here: stream calls and completions go on the
+        delivery list, which the sequential callers run at once and
+        `_step_overlap` after its launch.
 
         `owners` (async scheduler only): the _Slot object each row was
         planned for. Between a launch-ahead and its commit a whole
@@ -2617,7 +2658,7 @@ class PagedInferenceServer:
                 # cache — a resubmit would reuse it)
                 slot = self._release_slot(sid, self._committed(sid))
                 slot.req.finish_reason = "cancelled"
-                self._complete(slot.req)
+                self._complete_later(slot.req)
             else:
                 self.active[sid] = True
                 if self._emit(slot.req, int(job.toks[0]),
@@ -2972,6 +3013,7 @@ class PagedInferenceServer:
         # prefill progress: capture first tokens, activate completed
         # admissions (mirrors _run_one_chunk's completion block)
         self._complete_admission_chunks(sel, ptoks, plps)
+        self._deliver()
 
     # -- async double-buffered scheduling (overlap on) ----------------------
     #
@@ -3340,9 +3382,9 @@ class PagedInferenceServer:
                         use_rows=plan.use_rows_d,
                         use_bias=plan.use_bias_d)
             futures = (toks, lps, counts, lens, last)
-        # the launch's end is the epilogue's start: _record_iteration
-        # follows every launch
-        t = (prof.enter("epilogue") if prof is not None
+        # the launch's end is the delivery's start: `_step_overlap`
+        # wakes the streaming threads under the program launched here
+        t = (prof.enter("deliver") if prof is not None
              else time.perf_counter())
         self._iter_launch_ts = t
         self._inflight = _Inflight(
@@ -3355,11 +3397,14 @@ class PagedInferenceServer:
     def _commit_inflight(self) -> None:
         """Sync and commit the in-flight dispatch: THE serialized
         critical path of the async scheduler. One device_get brings
-        the sampled tokens home; the ledger writes, token emits,
-        activations, speculation feedback, and deferred sweep reaps
-        all run on the synced values — guarded per row by the owners
-        identity captured at plan time (a whole step ran since the
-        launch)."""
+        the sampled tokens home; the ledger writes, the tokens
+        recorded on their requests, activations, speculation feedback,
+        and deferred sweep reaps all run on the synced values —
+        guarded per row by the owners identity captured at plan time
+        (a whole step ran since the launch). Everything the next
+        launch reads is written here; nobody is told: the stream
+        calls and completions wait on the delivery list until that
+        launch is on the device (`_deliver`)."""
         infl, self._inflight = self._inflight, None
         prof = self._profiler
         t_wait = (prof.enter("device") if prof is not None
@@ -3446,7 +3491,7 @@ class PagedInferenceServer:
                 continue  # already torn down (failure path)
             s = self._release_slot(sid, self._committed(sid))
             s.req.finish_reason = reason
-            self._complete(s.req)
+            self._complete_later(s.req)
 
     def _drain_handoff_ready(self) -> None:
         """Fire the queued disaggregation handoff callbacks — OUTSIDE
@@ -3483,7 +3528,8 @@ class PagedInferenceServer:
         """One pipelined scheduler iteration (overlap on). With a
         dispatch in flight: plan iteration N+1 (sweep marks, QoS/DRR
         admission, the whole numpy build) WHILE the device runs
-        iteration N, then sync+commit N, patch, and launch N+1 — one
+        iteration N, then sync+commit N, patch, launch N+1, and only
+        then deliver N's tokens and completions to their clients — one
         fused dispatch and one device_get per step, with only the
         commit/patch/launch tail serialized against the device.
         With nothing in flight (cold start, post-drain, famine): run
@@ -3547,9 +3593,19 @@ class PagedInferenceServer:
                         # unblocks every waiter
                         self._faults.check("dispatch")
                     plan = self._plan_iteration()
-                    self._commit_inflight()
-                    if plan is not None:
-                        self._launch_plan(plan)
+                    try:
+                        self._commit_inflight()
+                        if plan is not None:
+                            self._launch_plan(plan)
+                    finally:
+                        # after the launch, not before: the streaming
+                        # threads these calls wake run under the next
+                        # program. A launch that raised still leaves
+                        # the committed tokens with their clients
+                        # before _fail_all ends the requests
+                        if prof is not None:
+                            prof.enter("deliver")
+                        self._deliver()
                     self._record_iteration(t0, p0, c0)
                     self.last_busy_ts = self._iter_stats["ts"]
                     ret = self.num_active
@@ -3790,7 +3846,8 @@ class PagedInferenceServer:
             hists = self._phase_hists
             if overlapped:
                 # sweep/admission/build ran under the in-flight
-                # device program: fold them into the `overlap` series
+                # device program and deliver under the one launched
+                # since: fold them into the `overlap` series
                 # so the histogram-derived host-gap stays honest (the
                 # fine split survives in this flight record)
                 hists["overlap"].observe(
@@ -4351,8 +4408,10 @@ class PagedInferenceServer:
                 if self._inflight is not None:
                     # drain the pipeline first: the in-flight
                     # dispatch's tokens belong to the stream being
-                    # exported
+                    # exported, and reach its client before the
+                    # destination streams the next one
                     self._commit_inflight()
+                    self._deliver()
                 snap, sid, committed = self._export_request_locked(
                     req, reason)
                 if evacuate:
@@ -4622,6 +4681,7 @@ class PagedInferenceServer:
         with self._step_lock:
             if self._inflight is not None:
                 self._commit_inflight()
+                self._deliver()
             job_slots = {s for job in self._jobs for s in job.slots}
             for sid, slot in enumerate(self._slots):
                 if slot is None or sid in job_slots:
@@ -4689,6 +4749,9 @@ class PagedInferenceServer:
             # dispatch (cloud_server_unserialized_teardown_total)
             self.unserialized_teardowns += 1
         try:
+            # what a sequential step that raised inside its commit
+            # left recorded: to the clients first (nothing, otherwise)
+            self._deliver()
             with self._lock:
                 pending, self._pending = (list(self._pending),
                                           collections.deque())

@@ -590,10 +590,13 @@ def resolve_seed(sampling: SamplingParams | None, host_rng, lock) -> int:
 
 
 def emit_token(req: Request, token: int, logprob: float | None,
-               infer_cfg: InferConfig) -> bool:
+               infer_cfg: InferConfig,
+               deliveries: list | None = None) -> bool:
     """Record one generated token on `req`; True when the request just
     finished (eos / stop sequence / length). The single emit rule both
-    servers share.
+    servers share. Handed `deliveries` (the paged server's list, run
+    after its next launch), the stream call is put there as
+    `(req, token)` and nobody is woken here.
 
     Stop sequences are token-level: when the output's tail equals one of
     `req.sampling.stop`, the matched tokens are removed (OpenAI
@@ -626,7 +629,10 @@ def emit_token(req: Request, token: int, logprob: float | None,
                 req.finish_reason = "stop"
                 return True
     if req.stream is not None:
-        req.stream(token)
+        if deliveries is None:
+            req.stream(token)
+        else:
+            deliveries.append((req, token))
     if len(req.tokens) >= req.max_new_tokens:
         req.finish_reason = "length"
         return True

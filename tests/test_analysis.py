@@ -14,6 +14,8 @@ import subprocess
 import sys
 import time
 
+import pytest
+
 from cloud_server_tpu.analysis import (HOT_PATHS, Finding,
                                        apply_pragmas, check_hot_paths,
                                        check_source, collect_pragmas,
@@ -910,6 +912,75 @@ def test_lifecycle_completion_via_call_graph():
                for f in findings), [str(f) for f in findings]
 
 
+_DEFERRED_SRC = (
+    "class S:\n"
+    "    def _complete(self, req):\n"
+    "        self.metrics.observe_finish(req)\n"
+    "        h = self._fail_handler\n"
+    "        req._done.set()\n"
+    "        cb = req._on_done\n"
+    "    def _complete_later(self, req):\n"
+    "        self._deliveries.append((req, None))\n"
+    "    def _deliver(self):\n"
+    "        out, self._deliveries = self._deliveries, []\n"
+    "        for req, token in out:\n"
+    "            if token is None:\n"
+    "                self._complete(req)\n"
+    "            else:\n"
+    "                req.stream(token)\n"
+    "    def _finish(self, req):\n"
+    "        self._complete_later(req)\n"
+    "    def reap(self, req, cancelled):\n"
+    "        req.finish_reason = 'cancelled'\n"
+    "        if cancelled:\n"
+    "            self._finish(req)\n"
+    "            return\n"
+    "        self._complete_later(req)\n")
+_DEFERRED_KW = dict(owner_funcs=(), marker_funcs=(), complete_funcs=(),
+                    transfer_funcs=())
+_DEFERRED = {"S._complete_later": ("_deliveries", "S._deliver")}
+
+
+@pytest.mark.parametrize("edit,needle", [
+    (None, None),
+    # a path that stops putting the request on the list leaks it: the
+    # list is no waiver, LC1 sees through it
+    (("        self._complete_later(req)\n\n", "        pass\n\n"),
+     "never reaches _complete"),
+    # completed at once AND put on the list: twice
+    (("            self._finish(req)\n            return\n",
+      "            self._complete(req)\n"), "completed again"),
+    # rot: the deferring method no longer appends to its list
+    (("self._deliveries.append((req, None))", "self.count += 1"),
+     "no longer appends to self._deliveries"),
+    # rot: the drain no longer runs the list through _complete
+    (("                self._complete(req)\n", "                pass\n"),
+     "never complete"),
+    # rot: the drain is gone
+    (("def _deliver(self)", "def _drain(self)"), "does not exist"),
+])
+def test_lifecycle_completion_through_the_delivery_list(edit, needle):
+    """The paged server's commit completes a request by putting it on
+    the delivery list (`DEFERRED_COMPLETION_FUNCS`): a call to the
+    rostered method, direct or through `_finish`, IS the completion
+    for LC1, and the roster rots loudly."""
+    src = _DEFERRED_SRC + "\n"
+    if edit is not None:
+        assert src.count(edit[0]) == 1, edit[0]
+        src = src.replace(*edit)
+    findings = lifecycle.check_source("s.py", src, **_DEFERRED_KW,
+                                      deferred_funcs=_DEFERRED)
+    if needle is None:
+        assert not findings, [str(f) for f in findings]
+        # without the roster the same source leaks on every path
+        bare = lifecycle.check_source("s.py", src, **_DEFERRED_KW,
+                                      deferred_funcs={})
+        assert any("never reaches _complete" in f.message for f in bare)
+    else:
+        assert any(needle in f.message for f in findings), \
+            [str(f) for f in findings]
+
+
 def test_lifecycle_roster_rot_is_a_finding():
     """Roster entries that vanished, and entries whose sanctioned
     behavior vanished (an owner without _done.set(), a marker that no
@@ -957,6 +1028,16 @@ def test_lifecycle_rosters_cover_the_serving_stack():
         "cloud_server_tpu/inference/server.py"] == ("emit_token",)
     assert lifecycle.OWNERSHIP_TRANSFER_FUNCS[
         "cloud_server_tpu/inference/paged_server.py"] == ("_Slot",)
+    # the commit completes through the delivery list, and the list's
+    # two ends are on the scheduler-loop and hot-path rosters
+    rel = "cloud_server_tpu/inference/paged_server.py"
+    assert lifecycle.DEFERRED_COMPLETION_FUNCS[rel] == {
+        "PagedInferenceServer._complete_later":
+            ("_deliveries", "PagedInferenceServer._deliver")}
+    for qual in ("PagedInferenceServer._complete_later",
+                 "PagedInferenceServer._deliver"):
+        assert qual in dispatch.SCHEDULER_LOOPS[rel]
+        assert qual in HOT_PATHS[rel]
     report = run_analysis(str(_HERE.parent),
                           checkers=["lifecycle-discipline"])
     assert report.ok, "\n".join(str(f) for f in report.findings)

@@ -16,8 +16,8 @@ import pytest
 from cloud_server_tpu.config import InferConfig, ModelConfig
 from cloud_server_tpu.inference import iteration_profile as ip
 from cloud_server_tpu.inference.iteration_profile import (
-    PHASES, IterationProfiler, derive_gap_fields, profile_summary,
-    resolve_profiler, scheduler_chrome_trace)
+    OVERLAP_PHASES, PHASES, IterationProfiler, derive_gap_fields,
+    profile_summary, resolve_profiler, scheduler_chrome_trace)
 from cloud_server_tpu.inference.paged_server import PagedInferenceServer
 from cloud_server_tpu.inference.router import ReplicatedRouter
 from cloud_server_tpu.inference.server import InferenceServer
@@ -137,6 +137,23 @@ def test_derive_gap_fields():
     assert derive_gap_fields({}, 0.0)["host_gap_frac"] == 0.0
 
 
+def test_derive_gap_fields_overlapped_hides_plan_and_deliver():
+    """An overlapped iteration's planning ran under the program before
+    the commit and its delivery under the one launched after it: both
+    are `overlap_ms`; the serialized tail is commit, launch, epilogue."""
+    phases = {"sweep": 0.5, "admission": 1.5, "build": 3.0, "device": 30.0,
+              "commit": 2.0, "launch": 4.0, "deliver": 6.0, "epilogue": 1.0}
+    d = derive_gap_fields(phases, 48.0, overlapped=True)
+    assert d["overlap_ms"] == pytest.approx(11.0)
+    assert d["host_ms"] == pytest.approx(7.0)
+    assert d["device_wait_ms"] == pytest.approx(30.0)
+    assert d["host_ms"] + d["device_wait_ms"] + d["overlap_ms"] \
+        == pytest.approx(48.0)
+    assert d["host_gap_frac"] == pytest.approx(7.0 / 48.0)
+    # the same split, sequential: nothing hides
+    assert derive_gap_fields(phases, 48.0)["host_ms"] == pytest.approx(18.0)
+
+
 def test_resolve_profiler_forms():
     assert resolve_profiler(False) is None
     assert resolve_profiler("off") is None
@@ -209,6 +226,39 @@ def test_flight_records_carry_phase_split(params):
     summary = srv.iteration_profile_stats()
     assert set(summary["phases"]) <= set(PHASES) | {"overlap"}
     assert 0.0 <= summary["host_gap_frac"] <= 1.0
+
+
+def test_overlapped_records_carry_the_delivery(params):
+    """A step that committed and launched has a `deliver` phase, the
+    count of stream calls and completions it made, and the identity
+    with `deliver` among the hidden phases, not in the host tail."""
+    srv = PagedInferenceServer(params, CFG, GREEDY, scheduler="mixed",
+                               **PAGED_KW)
+    streamed = []
+    reqs = [srv.submit([5 + i, 9, 3], max_new_tokens=8,
+                       stream=streamed.append) for i in range(2)]
+    srv.run_until_idle()
+    assert all(r.done for r in reqs)
+    window = srv.flight_window()
+    ov = [rec for rec in window if rec.get("overlap")]
+    assert ov
+    for rec in ov:
+        ph = rec["phases_ms"]
+        assert ph["deliver"] >= 0.0 and rec["delivered"] > 0
+        assert rec["overlap_ms"] == pytest.approx(
+            sum(ph.get(p, 0.0) for p in OVERLAP_PHASES),
+            rel=1e-9, abs=1e-9)
+        assert rec["host_ms"] == pytest.approx(
+            sum(ph.get(p, 0.0) for p in ("commit", "launch", "epilogue")),
+            rel=1e-9, abs=1e-9)
+        assert (rec["host_ms"] + rec["device_wait_ms"]
+                + rec["overlap_ms"]) == pytest.approx(
+            rec["duration_ms"], rel=1e-9, abs=1e-6)
+    # every stream call and every completion is counted once, in the
+    # record of the step that made it (the sequential fill step too)
+    assert sum(rec.get("delivered", 0) for rec in window) \
+        == len(streamed) + len(reqs)
+    assert "deliver" in OVERLAP_PHASES and "deliver" in PHASES
 
 
 def test_alternating_scheduler_phase_split(params):
@@ -314,11 +364,11 @@ def test_profiled_mixed_step_dispatch_sync_and_clock_counts(
         assert churn_steps < 50
     assert churn_steps >= 2  # real churn: admission spanned iterations
     # bounded constant: begin + the boundaries into admission, build,
-    # device, commit, launch and epilogue + end = 8
+    # device, commit, launch, deliver and epilogue + end = 9
     assert len(clock_per_step) == 1, (
         f"profiler clock reads varied across mixed iterations: "
         f"{clock_per_step}")
-    assert clock_per_step.pop() <= 8
+    assert clock_per_step.pop() <= 9
     for n, f in origs.items():
         monkeypatch.setattr(ps, n, f)
     monkeypatch.setattr(jax, "device_get", orig_get)

@@ -363,6 +363,164 @@ def test_overlap_flight_fields_and_stats_block(params):
 
 
 # ---------------------------------------------------------------------------
+# deferred delivery: the commit records, the clients hear after the launch
+# ---------------------------------------------------------------------------
+
+
+def _watched(srv, prompt, max_new, sampling=None):
+    """Submit with every wake-up of the client on one list, in order:
+    ("tok", token) per stream call, ("done", finish_reason) when
+    `_complete` has set `_done` (its `_on_done` hook runs right behind
+    the set, on the same thread)."""
+    events = []
+    req = srv.submit(prompt, max_new_tokens=max_new, sampling=sampling,
+                     stream=lambda t: events.append(("tok", t)))
+    req._on_done = lambda r: events.append(("done", r.finish_reason))
+    return req, events
+
+
+def test_overlap_delivers_after_the_launch(params):
+    """In a step that commits and launches, every stream callback and
+    every `_done.set()` runs with the next program already in flight."""
+    srv = PagedInferenceServer(params, CFG, GREEDY, scheduler="mixed",
+                               overlap=True, **SRV_KW)
+    step = {"n": 0, "steady": False, "launched": -1}
+    seen = []   # (kind, steady step, in flight, launched in this step)
+    launch = srv._launch_plan
+
+    def launch_and_note(plan):
+        launch(plan)
+        if srv._inflight is not None:
+            step["launched"] = step["n"]
+
+    srv._launch_plan = launch_and_note
+
+    def note(kind):
+        seen.append((kind, step["steady"], srv._inflight is not None,
+                     step["launched"] == step["n"]))
+
+    reqs = [srv.submit(p, max_new_tokens=n,
+                       stream=lambda t: note("tok"))
+            for p, n in (([5, 9, 3], 4), ([17, 2, 40, 8, 21], 12))]
+    for r in reqs:
+        r._on_done = lambda r: (note("done"), r._done.is_set()
+                                or pytest.fail("woken before _done"))
+    while not all(r.done for r in reqs):
+        step["n"] += 1
+        step["steady"] = srv._inflight is not None
+        srv.step()
+    steady = [e for e in seen if e[1] and e[3]]
+    # the short request ends while the long one decodes on: its last
+    # token and its completion are delivered under the next program
+    assert sum(k == "done" for k, *_ in steady) >= 1
+    assert sum(k == "tok" for k, *_ in steady) >= 8
+    assert all(inflight for _, _, inflight, _ in steady)
+    # and no steady step that launched told anybody before its launch:
+    # a wake-up in a steady step with a program behind it saw the launch
+    assert not [e for e in seen if e[1] and e[2] and not e[3]]
+    assert srv._deliveries == []
+
+
+@pytest.mark.parametrize("spec_drafts", [0, 2])
+def test_delivery_order_equals_sequential(params, spec_drafts):
+    """What each client is woken with, and in which order, does not
+    depend on when it is woken: overlap on and off give the same
+    stream calls and the completion last, for a length finish and a
+    stop sequence, plain and speculative."""
+    kw = dict(SRV_KW, spec_drafts=spec_drafts)
+    prompts = [REP, [5, 9, 3], REP[:9], [17, 2, 40, 8, 21]]
+    probe = PagedInferenceServer(params, CFG, GREEDY, scheduler="mixed",
+                                 overlap=False, **kw)
+    free = [probe.submit(p, max_new_tokens=12) for p in prompts]
+    probe.run_until_idle()
+    # a two-token stop sequence the second and third requests will meet
+    stops = [None, tuple(free[1].tokens[4:6]), tuple(free[2].tokens[5:7]),
+             None]
+
+    def run(ov):
+        srv = PagedInferenceServer(params, CFG, GREEDY, scheduler="mixed",
+                                   overlap=ov, **kw)
+        pairs = [_watched(srv, p, 12, None if st is None
+                          else SamplingParams(stop=[st]))
+                 for p, st in zip(prompts[:2], stops[:2])]
+        for _ in range(3):
+            srv.step()
+        pairs += [_watched(srv, p, 12, None if st is None
+                           else SamplingParams(stop=[st]))
+                  for p, st in zip(prompts[2:], stops[2:])]
+        srv.run_until_idle()
+        assert srv._deliveries == []
+        return pairs
+
+    on, off = run(True), run(False)
+    assert [ev for _, ev in on] == [ev for _, ev in off]
+    assert [r.tokens for r, _ in on] == [r.tokens for r, _ in off]
+    for (req, events), st in zip(on, stops):
+        assert events[-1] == ("done", "length" if st is None else "stop")
+        assert [k for k, _ in events].count("done") == 1
+        streamed = [t for k, t in events[:-1]]
+        if st is None:
+            assert streamed == req.tokens and len(streamed) == 12
+        else:
+            # the match's last token is never streamed, its first was
+            assert streamed == req.tokens + list(st[:-1])
+
+
+def test_launch_failure_delivers_committed_tokens_first(params):
+    """A launch that raises after the commit: the tokens the commit
+    recorded reach their streams before the error completion, as
+    they did when the commit itself woke the clients."""
+    srv = PagedInferenceServer(params, CFG, GREEDY, scheduler="mixed",
+                               overlap=True, **SRV_KW)
+    pairs = [_watched(srv, p, 48) for p in ([5, 9, 3], [17, 2, 40, 8, 21])]
+    while min(len(r.tokens) for r, _ in pairs) < 3:
+        srv.step()
+    assert srv._inflight is not None
+    before = [len(ev) for _, ev in pairs]
+
+    def broken_launch(plan):
+        raise RuntimeError("launch failed")
+
+    srv._launch_plan = broken_launch
+    with pytest.raises(RuntimeError, match="launch failed"):
+        srv.step()
+    assert srv._deliveries == []
+    for (req, events), n0 in zip(pairs, before):
+        # what the step's commit recorded was streamed, all of it
+        assert len(events) > n0 and not req.done
+        assert events == [("tok", t) for t in req.tokens]
+    srv._fail_all(RuntimeError("launch failed"))
+    for req, events in pairs:
+        assert events[-1][0] == "done"
+        assert events[-1][1].startswith("error")
+        assert [t for k, t in events[:-1]] == req.tokens
+
+
+def test_raising_stream_callback_strands_no_completion(params):
+    """A client's stream callback that raises ends the step, as it did
+    when the commit called it; the completion queued behind it on the
+    delivery list still runs: that request has no slot any more, so
+    `_fail_all` could not find it and its waiter would hang."""
+    srv = PagedInferenceServer(params, CFG, GREEDY, scheduler="mixed",
+                               overlap=True, **SRV_KW)
+
+    def bad_stream(token):
+        if len(bad.tokens) >= 6:
+            raise ValueError("client callback failed")
+
+    bad = srv.submit([5, 9, 3], max_new_tokens=48, stream=bad_stream)
+    good, events = _watched(srv, [17, 2, 40, 8, 21], 6)
+    with pytest.raises(ValueError, match="client callback failed"):
+        for _ in range(20):
+            srv.step()
+    assert good.done and good.finish_reason == "length"
+    assert events == [("tok", t) for t in good.tokens] + [("done", "length")]
+    assert not bad.done and srv._deliveries == []
+    srv._fail_all(RuntimeError("step raised"))
+    assert bad.done and bad.finish_reason.startswith("error")
+
+
+# ---------------------------------------------------------------------------
 # contiguous server: launch-ahead decode pipelining
 # ---------------------------------------------------------------------------
 
