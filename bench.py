@@ -232,13 +232,11 @@ def longseq_attention_bench():
 
 def serving_bench():
     """Steady-state continuous-batching decode on the 330M model: 8 slots
-    x 1024 context, contiguous server (XLA decode) vs PAGED server
-    (ops.paged_attention kernel), bf16/int8 weights and KV, and in-server
-    n-gram speculative decoding.
+    x 1024 context on the paged server (ops.paged_attention kernel),
+    bf16 and int8 KV, and in-server n-gram speculative decoding.
 
-    Keys keep their r1/r2 names for round-over-round comparability;
-    "pallas" rows now mean the PAGED server + kernel (the contiguous
-    pallas decode kernel was removed in r3 — it lost to XLA everywhere).
+    Keys keep their r1/r2 names for round-over-round comparability:
+    "pallas" rows mean the paged server + kernel.
 
     Every scheduler iteration pays one fixed dispatch+sync cost,
     amortised here over decode_chunk=32 rounds. Kernel-level numbers
@@ -250,9 +248,7 @@ def serving_bench():
 
     from cloud_server_tpu.config import InferConfig, ModelConfig
     from cloud_server_tpu.inference.paged_server import PagedInferenceServer
-    from cloud_server_tpu.inference.server import InferenceServer
     from cloud_server_tpu.models import transformer
-    from cloud_server_tpu.models.quantization import quantize_params
 
     base = ModelConfig(
         vocab_size=32000, embed_dim=1024, num_layers=16, num_heads=16,
@@ -261,7 +257,6 @@ def serving_bench():
     infer_cfg = InferConfig(max_decode_len=900, temperature=1.0,
                             eos_token_id=-1, pad_token_id=0)
     params_bf16 = transformer.init_params(base, jax.random.key(0))
-    params_int8 = quantize_params(params_bf16)
     _rng = np.random.RandomState(7)
     plain_prompts = [[int(x) for x in _rng.randint(1, 30000, size=64)]
                      for _ in range(8)]
@@ -271,24 +266,6 @@ def serving_bench():
 
     chunk = 32
     out = {}
-
-    def run_contiguous(tag, params, kv):
-        cfg = dataclasses.replace(base, kv_cache_dtype=kv)
-        srv = InferenceServer(params, cfg, infer_cfg, max_slots=8,
-                              max_len=1024, prompt_buckets=[64],
-                              decode_chunk=chunk)
-        for p in plain_prompts:
-            srv.submit(p, max_new_tokens=900)
-        for _ in range(3):
-            srv.step()
-        before = srv.tokens_emitted
-        t0 = time.perf_counter()
-        for _ in range(8):
-            srv.step()
-        dt = time.perf_counter() - t0
-        out[tag] = (srv.tokens_emitted - before) / dt
-        print(f"[serving_bench] {tag}: {out[tag]:.1f}", flush=True)
-        srv.stop()
 
     def run_paged(tag, params, kv, *, spec=0, prompts=plain_prompts,
                   icfg=None, sampling=None):
@@ -317,9 +294,6 @@ def serving_bench():
                                     / max(rounds, 1))
         srv.stop()
 
-    run_contiguous("decode_tok_s_xla_bf16", params_bf16, "model")
-    run_contiguous("decode_tok_s_xla_int8", params_int8, "model")
-    run_contiguous("decode_tok_s_xla_bf16_kvint8", params_bf16, "int8")
     run_paged("decode_tok_s_pallas_bf16", params_bf16, "model")
     # A/B for the per-request-sampling hot path: SamplingParams(seed=i)
     # forces the SamplingRows decode dispatch with math identical to the

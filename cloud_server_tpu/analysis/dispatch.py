@@ -4,7 +4,7 @@ PR 2/9's load-bearing invariant: each scheduler iteration runs ONE
 fused jitted dispatch and pays ONE host<->device sync (the
 ``device_get`` of the sampled tokens). The runtime regression tests
 count dispatches on one driven path; this pass pins the invariant
-statically across the whole scheduler loop of both servers.
+statically across the whole scheduler loop of the server.
 
 Rules:
 
@@ -137,28 +137,6 @@ SCHEDULER_LOOPS: dict[str, tuple[str, ...]] = {
         "PagedInferenceServer._drain_handoff_ready",
         "PagedInferenceServer.pending_prefill_tokens",
     ),
-    "cloud_server_tpu/inference/server.py": (
-        "InferenceServer.step",
-        "InferenceServer._step_locked",
-        "InferenceServer._step_locked_overlap",
-        "InferenceServer._commit_decode_chunk",
-        "InferenceServer._launch_decode",
-        "InferenceServer.serve_forever",
-        "InferenceServer._sweep_cancelled",
-        "InferenceServer._admit_pending",
-        "InferenceServer._use_prefix",
-        "InferenceServer._pad_group",
-        "InferenceServer._ensure_penalty_state",
-        "InferenceServer._group_rows",
-        "InferenceServer._rows_mode",
-        "InferenceServer._admit_group",
-        "InferenceServer._admit_group_plain",
-        "InferenceServer._admit_group_prefixed",
-        "InferenceServer._chunk_len",
-        "InferenceServer._emit",
-        "InferenceServer._finish",
-        "InferenceServer._next_rng",
-    ),
 }
 
 # The ONE sanctioned per-iteration host sync per dispatch path: these
@@ -179,11 +157,6 @@ SANCTIONED_SYNCS: dict[str, tuple[str, ...]] = {
         # first), under the step lock and off the plan path, so DD5's
         # overlap window never sees it
         "PagedInferenceServer._export_request_locked",
-    ),
-    "cloud_server_tpu/inference/server.py": (
-        "InferenceServer._admit_group",
-        "InferenceServer._step_locked",
-        "InferenceServer._commit_decode_chunk",
     ),
 }
 
@@ -255,11 +228,8 @@ BOUNDED_HELPERS = {
     "_bucket",         # fixed bucket table lookup
     "_rem_bucket",     # bucket table / prefill_chunk multiples
     "_chunk_rounds",   # power-of-two round planner (paged)
-    "_chunk_len",      # power-of-two round planner (contiguous)
     "_mixed_rounds",   # power-of-two round planner (mixed budget)
     "_spec_plan",      # draft width quantized to {0, spec_drafts}
-    "_rows_mode",      # (bool, bool)
-    "_group_rows",     # (..., bool, bool)
     "bool",
 }
 # bounded only when every argument is bounded (len is NOT here: a

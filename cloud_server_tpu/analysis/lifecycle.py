@@ -116,13 +116,13 @@ from cloud_server_tpu.analysis.locks import guarded_attributes
 
 CHECKER = "lifecycle-discipline"
 
-# The request-lifecycle modules this pass audits: both servers (the
-# terminal paths), the allocator (the page side of the ledger), the
-# migration snapshot layer, and the router (the completion-ownership
-# transfer paths).
+# The request-lifecycle modules this pass audits: the server and the
+# request's emit rule (the terminal paths), the allocator (the page
+# side of the ledger), the migration snapshot layer, and the router
+# (the completion-ownership transfer paths).
 LIFECYCLE_ROSTER: tuple[str, ...] = (
     "cloud_server_tpu/inference/paged_server.py",
-    "cloud_server_tpu/inference/server.py",
+    "cloud_server_tpu/inference/request.py",
     "cloud_server_tpu/inference/block_allocator.py",
     "cloud_server_tpu/inference/migration.py",
     "cloud_server_tpu/inference/router.py",
@@ -148,7 +148,7 @@ COMPLETION_OWNER_FUNCS: dict[str, tuple[str, ...]] = {
 # `_finish` the moment the emit returns done). Rot rule: each must
 # still assign `finish_reason`.
 TERMINAL_MARKER_FUNCS: dict[str, tuple[str, ...]] = {
-    "cloud_server_tpu/inference/server.py": ("emit_token",),
+    "cloud_server_tpu/inference/request.py": ("emit_token",),
 }
 
 # The `_complete` implementations whose LC2 terminal ordering is
@@ -156,8 +156,6 @@ TERMINAL_MARKER_FUNCS: dict[str, tuple[str, ...]] = {
 COMPLETE_FUNCS: dict[str, tuple[str, ...]] = {
     "cloud_server_tpu/inference/paged_server.py": (
         "PagedInferenceServer._complete",),
-    "cloud_server_tpu/inference/server.py": (
-        "InferenceServer._complete",),
 }
 
 # Methods that complete a handle through a delivery list:

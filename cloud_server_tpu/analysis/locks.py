@@ -69,7 +69,7 @@ from cloud_server_tpu.analysis.framework import (Finding, Pass,
 CHECKER = "lock-discipline"
 
 # The serving modules whose cross-thread state this pass audits (the
-# two servers' shared-state mutexes plus every policy/telemetry module
+# server's shared-state mutexes plus every policy/telemetry module
 # the scheduler iteration consults).
 LOCK_ROSTER: tuple[str, ...] = (
     "cloud_server_tpu/inference/paged_server.py",

@@ -54,8 +54,8 @@ class ModelConfig:
     flash_block_kv: int = 1024
     # decode-time (cached) attention: "xla" | "pallas". "pallas" selects
     # the paged-attention kernel and is only meaningful with the paged
-    # serving stack (inference.paged_server); the contiguous engine
-    # always uses the XLA path.
+    # serving stack (inference.paged_server); inference.engine's
+    # contiguous cache always uses the XLA path.
     decode_attention_impl: str = "xla"
     # KV-cache storage: "model" (cfg.dtype) | "int8" (symmetric
     # per-(position, head) absmax quantization — halves cache memory;
@@ -181,9 +181,9 @@ class InferConfig:
     top_p: float = 1.0  # 1.0 => disabled
     eos_token_id: int = -1  # -1 => never stop early
     pad_token_id: int = 0
-    # Paged-server scheduling under admission churn (the contiguous
-    # server ignores both; PagedInferenceServer constructor arguments of
-    # the same names override these defaults):
+    # Paged-server scheduling under admission churn
+    # (PagedInferenceServer constructor arguments of the same names
+    # override these defaults):
     #   "mixed" — stall-free token-budget batching: chunked prefills
     #     piggyback on decode batches in one ragged dispatch, so decode
     #     never stalls behind an admission (Sarathi-style).
@@ -192,9 +192,7 @@ class InferConfig:
     scheduler: str = "mixed"
     # Async double-buffered scheduling (paged server, MIXED scheduler
     # only — the alternating scheduler always keeps its sequential
-    # per-chunk loop; the contiguous server's simpler launch-ahead
-    # decode pipelining is gated on this same knob). True (the
-    # default) overlaps host policy work —
+    # per-chunk loop). True (the default) overlaps host policy work —
     # sweep, QoS/DRR admission, deadline checks, and the numpy
     # dispatch build — with the device executing the PREVIOUS
     # iteration's fused program: each step plans iteration N+1 against

@@ -4,9 +4,8 @@ Loads model params from a training checkpoint (or random-inits for smoke
 runs), tokenizes prompts, and serves them through the paged
 continuous-batching server (`PagedInferenceServer` — block-table KV,
 radix prefix reuse, chunked prefill, optional in-server speculative
-decoding via `--spec-drafts`). `--contiguous` selects the legacy
-fixed-slot `InferenceServer` instead. The tokenizer is byte-level by
-default or a local HuggingFace `tokenizer.json` via `--tokenizer`.
+decoding via `--spec-drafts`). The tokenizer is byte-level by default
+or a local HuggingFace `tokenizer.json` via `--tokenizer`.
 """
 
 from __future__ import annotations
@@ -58,11 +57,6 @@ def build_parser() -> argparse.ArgumentParser:
                    help="serve the EMA-averaged weights from a checkpoint "
                    "trained with ema_decay > 0 (reads the checkpoint's "
                    "'ema' item — one params-sized restore)")
-    p.add_argument("--prefix", metavar="TEXT",
-                   help="shared prompt prefix (e.g. a system prompt): its "
-                   "KV is prefilled once and cached; prompts extending it "
-                   "only run their remainder (prefix caching). Applies to "
-                   "batch and --serve-http serving")
     p.add_argument("--serve-http", type=int, metavar="PORT", default=None,
                    help="instead of batch generation, run the continuous-"
                    "batching server behind an HTTP streaming endpoint "
@@ -71,16 +65,11 @@ def build_parser() -> argparse.ArgumentParser:
                    help="decode steps per scheduler iteration (multi-token "
                    "scheduling; >1 amortises host sync at the cost of "
                    "admission latency)")
-    p.add_argument("--contiguous", action="store_true",
-                   help="serve through the legacy fixed-slot contiguous "
-                   "server instead of the paged server (no paging, no "
-                   "radix prefix reuse, no chunked prefill, no in-server "
-                   "speculation; supports --prefix single-prefix caching)")
     p.add_argument("--max-slots", type=int, default=8,
                    help="concurrent request slots in the server")
     p.add_argument("--spec-drafts", type=int, default=0,
-                   help="paged server only: in-server speculative decoding "
-                   "with N n-gram draft tokens per round (exact accept "
+                   help="in-server speculative decoding with N n-gram "
+                   "draft tokens per round (exact accept "
                    "rule — output distribution unchanged; wins on "
                    "repetition-heavy output)")
     p.add_argument("--spec-control", metavar="FILE_OR_JSON",
@@ -295,7 +284,6 @@ def main(argv=None) -> None:
 
     from cloud_server_tpu.config import InferConfig, ModelConfig, from_json
     from cloud_server_tpu.data.tokenizer import get_tokenizer
-    from cloud_server_tpu.inference.server import InferenceServer
 
     raw = {}
     if args.config:
@@ -323,17 +311,8 @@ def main(argv=None) -> None:
     if args.kv_cache_int8:
         model_cfg = dataclasses.replace(model_cfg, kv_cache_dtype="int8")
     if args.decode_impl is not None:
-        if args.contiguous and args.decode_impl != "xla":
-            raise SystemExit(
-                "--decode-impl pallas needs the paged server; drop "
-                "--contiguous")
         model_cfg = dataclasses.replace(
             model_cfg, decode_attention_impl=args.decode_impl)
-    if args.spec_drafts and args.contiguous:
-        raise SystemExit(
-            "--spec-drafts is the paged server's in-server speculation; "
-            "it cannot run with --contiguous (use --ngram-draft/"
-            "--draft-config for the batch API instead)")
     if args.spec_drafts and args.ngram_draft:
         raise SystemExit(
             "--spec-drafts (in-server n-gram) and --ngram-draft (batch "
@@ -416,9 +395,9 @@ def main(argv=None) -> None:
         bundle_on_anomaly=args.bundle_on_anomaly)
 
     def load_draft():
-        """Draft model for in-server speculation (--draft-config with
-        the paged server). Returns (params, cfg) or (None, None)."""
-        if not args.draft_config or args.contiguous:
+        """Draft model for in-server speculation (--draft-config).
+        Returns (params, cfg) or (None, None)."""
+        if not args.draft_config:
             return None, None
         with open(args.draft_config) as f:
             draft_cfg = from_json(ModelConfig, json.load(f).get("model", {}))
@@ -431,32 +410,7 @@ def main(argv=None) -> None:
         return draft_params, draft_cfg
 
     def make_server(max_len: int, max_slots: int):
-        """Build the serving backend: paged by default, contiguous on
-        --contiguous. Same client API either way (submit / generate /
-        start / stop)."""
-        if args.contiguous:
-            prefix_toks = (tok.encode(args.prefix,
-                                      add_bos=args.add_bos
-                                      and tok.bos_id is not None)
-                           if args.prefix else None)
-            return InferenceServer(
-                params, model_cfg, infer_cfg, max_slots=max_slots,
-                max_len=max_len, seed=args.seed,
-                decode_chunk=args.decode_chunk,
-                prefix_tokens=prefix_toks,
-                qos=args.qos_config,
-                slo=args.slo_config,
-                tracing=args.trace_sample_rate or None,
-                faults=args.fault_plan,
-                anomaly=args.anomaly_config,
-                overlap=False if args.no_overlap else None,
-                iteration_profile=False if args.no_iteration_profile else None)
-        if args.prefix:
-            print("[generate] note: the paged server reuses shared "
-                  "prefixes automatically (radix page cache); --prefix "
-                  "needs no pre-registration — prompts that start with "
-                  "the prefix text hit the cache after the first request",
-                  file=sys.stderr)
+        """Build the server (submit / generate / start / stop)."""
         ps = args.page_size
         max_context = -(-max_len // ps) * ps  # round up to a page multiple
         prefill_chunk = -(-max(ps, args.prefill_chunk) // ps) * ps
@@ -490,11 +444,10 @@ def main(argv=None) -> None:
             tokenizer=tok)  # regex-constrained requests compile vs it
 
     if args.serve_http is not None:
-        if args.ngram_draft or (args.draft_config and args.contiguous):
+        if args.ngram_draft:
             raise SystemExit(
                 "--ngram-draft is batch-mode only (the serving "
-                "equivalent is --spec-drafts), and --draft-config "
-                "serving needs the paged server (drop --contiguous)")
+                "equivalent is --spec-drafts)")
         from cloud_server_tpu.inference.http_server import HttpFrontend
         max_len = args.max_len or model_cfg.max_seq_len
         srv = make_server(max_len, args.max_slots).start()
@@ -537,10 +490,6 @@ def main(argv=None) -> None:
         if args.draft_config and args.ngram_draft:
             raise SystemExit("--draft-config and --ngram-draft are "
                              "mutually exclusive draft sources")
-        if args.prefix:
-            raise SystemExit(
-                "--prefix is a serving-path feature; the speculative "
-                "batch path would silently ignore it")
         draft_cfg = draft_params = None
         if args.draft_config:
             with open(args.draft_config) as f:
@@ -595,8 +544,7 @@ def main(argv=None) -> None:
     longest = max(len(e) for e in encoded)
     max_len = args.max_len or min(model_cfg.max_seq_len,
                                   longest + args.max_new +
-                                  (0 if args.contiguous
-                                   else args.spec_drafts + 1))
+                                  args.spec_drafts + 1)
     srv = make_server(max_len, min(args.max_slots, len(encoded)))
     outs = srv.generate(encoded, max_new_tokens=args.max_new)
     for prompt, out in zip(prompts, outs):

@@ -19,41 +19,41 @@ Deterministic fault injection
 paths (each call site guarded by ``if self._faults is not None`` so an
 unconfigured server runs the byte-identical pre-fault code):
 
-  * ``submit_reject``    — submit() raises `InjectedFault` (both
-                           servers): exercises router failover on
-                           submit and client 503 handling.
+  * ``submit_reject``    — submit() raises `InjectedFault`:
+                           exercises router failover on submit and
+                           client 503 handling.
   * ``dispatch``         — the next dispatch path raises
                            `InjectedFault` before launching device
-                           work (paged: `_mixed_dispatch` /
-                           `_decode_dispatch` / `_run_one_chunk`;
-                           contiguous: `_step_locked`): the scheduler
-                           thread crashes exactly the way a poisoned
+                           work (`_mixed_dispatch` /
+                           `_decode_dispatch` / `_run_one_chunk`):
+                           the scheduler thread crashes exactly the
+                           way a poisoned
                            device program would, driving
                            `serve_forever` -> `_fail_all` -> router
                            retry.
-  * ``iteration_stall``  — step() sleeps `stall_ms` before the sweep
-                           (both servers): simulates a slow host or a
+  * ``iteration_stall``  — step() sleeps `stall_ms` before the
+                           sweep: simulates a slow host or a
                            long device round, the input the brownout
                            detector and SLO burn rates key on.
   * ``wedge``            — step() blocks (holding `_step_lock`) until
-                           the server's stop event is set (paged
-                           only): the "scheduler wedged inside a
-                           dispatch" shape `_fail_all`'s bounded
-                           lock acquire exists for.
+                           the server's stop event is set: the
+                           "scheduler wedged inside a dispatch"
+                           shape `_fail_all`'s bounded lock acquire
+                           exists for.
   * ``alloc_famine``     — the next admission pretends the page pool
-                           is empty (paged only): exercises the
-                           famine-retry / preemption paths without
-                           shrinking the pool.
+                           is empty: exercises the famine-retry /
+                           preemption paths without shrinking the
+                           pool.
   * ``migrate_export``   — the next migration export raises
-                           `InjectedFault` before snapshotting (paged
-                           only): exercises the non-migratable
-                           fallback (the request fails fast with
-                           today's `retriable: false` body).
+                           `InjectedFault` before snapshotting:
+                           exercises the non-migratable fallback
+                           (the request fails fast with today's
+                           `retriable: false` body).
   * ``migrate_import``   — the next migration import raises
-                           `InjectedFault` on the destination (paged
-                           only): exercises the router's
-                           import-failure path (failure stands on the
-                           original handle).
+                           `InjectedFault` on the destination:
+                           exercises the router's import-failure
+                           path (failure stands on the original
+                           handle).
 
 Plans are SEEDED: a spec may fire probabilistically (``p < 1``) and
 the draw sequence comes from one `random.Random(seed)`, so a given
@@ -110,7 +110,7 @@ import time
 
 # imported like qos.py does (the servers import this module lazily, so
 # there is no cycle); keeps BrownoutShedError on the HTTP 429 path
-from cloud_server_tpu.inference.server import QueueFullError
+from cloud_server_tpu.inference.request import QueueFullError
 
 # The named injection sites the servers thread. Order is documentation
 # only; membership is validated at spec construction so a typo'd site
@@ -285,7 +285,7 @@ def _resolve_config(value, fallback: str, cls, what: str):
 
 def resolve_fault_plan(faults, fault_plan_config: str = ""
                        ) -> FaultPlan | None:
-    """The one constructor both servers use: `faults` may be a ready
+    """The one constructor: `faults` may be a ready
     FaultPlan, a config dict, a JSON string, a file path, None
     (falling back to `InferConfig.fault_plan`), or False — injection
     force-disabled regardless of the config fallback. Returns None

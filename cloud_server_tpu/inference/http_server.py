@@ -1,11 +1,11 @@
-"""HTTP front-end for the continuous-batching servers.
+"""HTTP front-end for the continuous-batching server.
 
-A thin stdlib (`http.server`) layer over the server `submit` API —
-`PagedInferenceServer` (the recommended backend: paged KV, radix prefix
-reuse, chunked prefill, in-server speculative decoding) or the legacy
-contiguous `InferenceServer`; both expose the same submit / num_active /
-num_pending surface. No framework dependency — the serving hot path
-stays the jitted TPU program; this module only does sockets and JSON.
+A thin stdlib (`http.server`) layer over the `submit` / `num_active` /
+`num_pending` surface of `PagedInferenceServer` (paged KV, radix prefix
+reuse, chunked prefill, in-server speculative decoding) or of a
+`ReplicatedRouter` over several. No framework dependency — the serving
+hot path stays the jitted TPU program; this module only does sockets
+and JSON.
 
 Endpoints:
 
@@ -222,11 +222,11 @@ from urllib.parse import parse_qs, urlparse
 
 from cloud_server_tpu.inference.iteration_profile import (
     profile_summary, scheduler_chrome_trace)
+from cloud_server_tpu.inference.request import QueueFullError
 from cloud_server_tpu.inference.request_trace import (
     TRACEPARENT_HEADER, chrome_trace, format_traceparent,
     parse_traceparent)
 from cloud_server_tpu.inference.sampling import SamplingParams
-from cloud_server_tpu.inference.server import QueueFullError
 from cloud_server_tpu.utils.logging import JsonLogger
 from cloud_server_tpu.utils.serving_metrics import (
     histogram_summary, render_prometheus)
@@ -364,7 +364,7 @@ def _query_int(url, name: str, default: int | None) -> int | None:
 class HttpFrontend:
     """Bind a serving backend (+ optional tokenizer) to an HTTP port.
 
-    `srv` is a `PagedInferenceServer` or `InferenceServer` (any object
+    `srv` is a `PagedInferenceServer` or a `ReplicatedRouter` (any object
     with submit/num_active/num_pending). Its scheduler must be running
     (srv.start()) or be driven externally; this class never steps it.
     """

@@ -163,8 +163,7 @@ class IterationProfiler:
     `annotate` (`utils.tracing.annotate`; this module stays jax-free),
     every open phase is a `sched/<phase>` trace event and the iteration
     an enclosing `sched/iteration`, each carrying `iteration=<n>`, the
-    flight-recorder index the step gets when it records (the
-    contiguous server has no flight recorder and gives none). A step
+    flight-recorder index the step gets when it records. A step
     that dispatches nothing ends in `close()`: no record, and its
     `sched/iteration` carries no index. With no capture running an
     annotation is an inactive check."""
@@ -252,7 +251,7 @@ def register_phase_hists(registry) -> dict:
     """Eagerly register the per-phase histogram family on a server's
     registry (one labeled series per phase) and return the
     phase -> Histogram dict the per-iteration observe path indexes.
-    THE one registration site for both servers: the family name, help
+    THE one registration site: the family name, help
     text, and ms ladder must match everywhere or the router's
     bucket-for-bucket fleet merge breaks."""
     return {
@@ -265,7 +264,7 @@ def register_phase_hists(registry) -> dict:
 
 def resolve_profiler(profile, cfg_enabled: bool = True,
                      annotate=None) -> IterationProfiler | None:
-    """The one constructor both servers use: `profile` may be a ready
+    """The one constructor: `profile` may be a ready
     IterationProfiler, True/False, "off", or None (falling back to
     `InferConfig.iteration_profile`). Returns None when disabled —
     every guarded call site short-circuits and the scheduler keeps

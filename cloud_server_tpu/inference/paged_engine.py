@@ -301,3 +301,10 @@ def window_forward(params, tokens: jnp.ndarray, cfg: ModelConfig,
             x_sel = x[jnp.arange(b), jnp.clip(logits_at, 0, w - 1)]
             return transformer.unembed(x_sel, params, cfg), cache
     return None, cache
+
+
+def _token_logprobs(logits: jnp.ndarray, toks: jnp.ndarray) -> jnp.ndarray:
+    """log P(tok) under the model's raw (pre-filter) distribution — the
+    one serving-API logprob convention, shared by admission and decode."""
+    return jnp.take_along_axis(jax.nn.log_softmax(logits, axis=-1),
+                               toks[:, None], axis=-1)[:, 0]

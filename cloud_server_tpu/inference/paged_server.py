@@ -1,8 +1,7 @@
 """Paged continuous-batching server: block-table KV, shared prefixes,
 chunked prefill, and in-server speculative decoding.
 
-This is the successor of `inference.server.InferenceServer` (which keeps
-the contiguous slot cache). What the paged design buys:
+What the paged design buys over one contiguous cache row a slot:
 
   * Memory scales with resident tokens, not max_slots x max_len: the pool
     is `num_pages` fixed-size pages; a slot holds ceil(context / ps)
@@ -96,7 +95,7 @@ last_token live in numpy and ride into each dispatch as small inputs);
 the device owns only the big buffers (page pools + per-slot token
 history), donated through every dispatch. One device_get per scheduler
 iteration, amortised over `decode_chunk` (speculative) rounds
-(multi-token scheduling, as in the contiguous server).
+(multi-token scheduling).
 
 Write-safety rules the scheduler maintains (see paged_engine for why
 writes through sentinel tables drop):
@@ -138,19 +137,20 @@ from cloud_server_tpu.inference.block_allocator import BlockAllocator
 from cloud_server_tpu.inference.grammar import DEAD as _GDEAD
 from cloud_server_tpu.inference.iteration_profile import (
     OVERLAP_PHASES, derive_gap_fields)
+from cloud_server_tpu.inference.paged_engine import _token_logprobs
 from cloud_server_tpu.inference.sampling import (
     SamplingParams, SamplingRows, make_rows, sample_from_probs,
     sample_logits, sample_logits_rows, sampling_probs,
     sampling_probs_rows)
-from cloud_server_tpu.inference.server import (
-    QueueFullError, Request, _StepTracer, _bucket, _token_logprobs,
-    emit_token, resolve_seed)
+from cloud_server_tpu.inference.request import (
+    QueueFullError, Request, _bucket, emit_token, resolve_seed)
 from cloud_server_tpu.inference.spec_control import resolve_controller
 from cloud_server_tpu.inference.speculative import (
     _TAG_DRAFT, _accept_drafts, _accept_point_mass, _ngram_drafts,
     _row_pos_keys, sample_from_probs_keyed)
 from cloud_server_tpu.utils.serving_metrics import (
     FlightRecorder, ServingMetrics)
+from cloud_server_tpu.utils.tracing import _StepTracer
 
 
 def _pow2_buckets(lo: int, hi: int) -> list[int]:
@@ -961,9 +961,9 @@ class _Inflight:
 class PagedInferenceServer:
     """Continuous-batching server over the paged KV cache.
 
-    Same client API as `InferenceServer` (submit / generate / step /
-    start / stop / run_until_idle); see the module docstring for what
-    changes inside.
+    The client API is submit / generate / step / start / stop /
+    run_until_idle, from any thread; the module docstring says what
+    happens inside.
     """
 
     def __init__(self, params, cfg: ModelConfig, infer_cfg: InferConfig, *,
@@ -3084,8 +3084,8 @@ class PagedInferenceServer:
         hit the site twice on a pipeline-fill step (breaking the
         FaultPlan's one-hit-per-iteration pacing) and could fire
         AFTER the fill dispatch already streamed tokens — the fill
-        prime's fault site is the NEXT step's check, matching the
-        contiguous server's convention."""
+        prime's fault site is the NEXT step's check, before anything
+        of that step has streamed."""
         prof = self._profiler
         if prof is not None:
             # planned-frame budget/round planning, chain growth, QoS

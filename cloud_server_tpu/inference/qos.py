@@ -50,7 +50,7 @@ import math
 import threading
 import time
 
-from cloud_server_tpu.inference.server import QueueFullError
+from cloud_server_tpu.inference.request import QueueFullError
 
 DEFAULT_TENANT = "default"
 
@@ -701,7 +701,7 @@ class TenantRegistry:
 
 
 def resolve_registry(qos, qos_config: str = "") -> TenantRegistry | None:
-    """The one constructor both servers use: `qos` may be a ready
+    """The one constructor: `qos` may be a ready
     TenantRegistry, a config dict, a JSON string, a file path, None
     (falling back to `InferConfig.qos_config`, itself a JSON string or
     path), or the literal False — QoS force-disabled regardless of the

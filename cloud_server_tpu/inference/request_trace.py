@@ -312,7 +312,7 @@ TAIL_REASONS = ("failed", "deadline", "cancelled", "migrated", "slo",
 class TraceRecorder:
     """Head-sampled per-request trace store: a dict of in-flight
     sampled requests plus a bounded ring of finished ones (oldest
-    evicted). Both servers consult it at submit (`begin`) and at
+    evicted). The server consults it at submit (`begin`) and at
     request completion (`finish`); everything else — lookup, the ring
     export — runs on the read path.
 
@@ -568,7 +568,7 @@ def chrome_trace(trees: list[dict],
 def resolve_recorder(tracing, sample_rate: float = 0.0,
                      capacity: int = 256, tail_capacity: int = 0
                      ) -> TraceRecorder | None:
-    """The one constructor both servers use: `tracing` may be a ready
+    """The one constructor: `tracing` may be a ready
     TraceRecorder, a sampling rate (float in [0, 1]), None (falling
     back to `InferConfig.trace_sample_rate`), or False — tracing
     force-disabled regardless of the config fallback. `capacity` /

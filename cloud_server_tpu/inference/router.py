@@ -46,9 +46,9 @@ from typing import Sequence
 
 import jax
 
+from cloud_server_tpu.inference.request import QueueFullError
 from cloud_server_tpu.inference.request_trace import (any_trace,
                                                       continuation_ctx)
-from cloud_server_tpu.inference.server import QueueFullError
 
 _log = logging.getLogger(__name__)
 

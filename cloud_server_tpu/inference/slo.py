@@ -369,7 +369,7 @@ def merge_reports(reports) -> dict | None:
 
 
 def resolve_slo(slo, slo_config: str = "") -> SLOTracker | None:
-    """The one constructor both servers use: `slo` may be a ready
+    """The one constructor: `slo` may be a ready
     SLOTracker, a config dict, a JSON string, a file path, None
     (falling back to `InferConfig.slo_config`), or False — SLO
     tracking force-disabled regardless of the config fallback.

@@ -339,7 +339,7 @@ def render_prometheus(snapshot: dict[str, dict]) -> str:
 
 
 # ---------------------------------------------------------------------------
-# Request-lifecycle instruments shared by both servers
+# Request-lifecycle instruments
 # ---------------------------------------------------------------------------
 
 

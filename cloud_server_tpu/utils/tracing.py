@@ -4,7 +4,7 @@
 schedulers' `IterationProfiler` opens its `sched/<phase>` events with it
 (inference/iteration_profile.py stays jax-free and is handed this
 function). `capture_trace` is the one place the program starts a trace
-(`POST /debug/trace`, the servers' `_StepTracer`); `start_profiler_server`
+(`POST /debug/trace`, the server's `_StepTracer`); `start_profiler_server`
 enables on-demand remote capture (`generate --profiler-port`).
 """
 
@@ -12,6 +12,8 @@ from __future__ import annotations
 
 import contextlib
 import os
+import sys
+import threading
 from typing import Iterator
 
 import jax
@@ -44,6 +46,64 @@ def capture_trace(logdir: str | os.PathLike) -> Iterator[None]:
         yield
     finally:
         jax.profiler.stop_trace()
+
+
+class _StepTracer:
+    """On-demand profiling of the next N scheduler iterations into a
+    jax profiler trace (`capture_trace`), armed from any thread (the
+    HTTP /debug/trace endpoint) and driven by the
+    scheduler's own step() — the capture window aligns exactly with
+    iteration boundaries, so a dump shows whole dispatches, not
+    fragments. Trace failures are swallowed with a stderr note: the
+    profiler is process-global and telemetry must never take the
+    scheduler (and every in-flight request) down with it."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._pending: tuple[int, str] | None = None
+        self._cm = None
+        self._left = 0
+
+    def request(self, n_steps: int, logdir: str | os.PathLike) -> None:
+        if n_steps <= 0:
+            raise ValueError("trace step count must be positive")
+        with self._lock:
+            if self._pending is not None or self._cm is not None:
+                raise ValueError("a trace capture is already in progress")
+            self._pending = (int(n_steps), os.fspath(logdir))
+
+    @property
+    def active(self) -> bool:
+        return self._pending is not None or self._cm is not None
+
+    def step_start(self) -> None:
+        with self._lock:
+            if self._pending is None:
+                return
+            n, logdir = self._pending
+            self._pending = None
+            try:
+                cm = capture_trace(logdir)
+                cm.__enter__()
+            except Exception as exc:  # noqa: BLE001 — see class docstring
+                print(f"[server] trace capture failed to start: {exc!r}",
+                      file=sys.stderr)
+                return
+            self._cm, self._left = cm, n
+
+    def step_end(self) -> None:
+        with self._lock:
+            if self._cm is None:
+                return
+            self._left -= 1
+            if self._left > 0:
+                return
+            cm, self._cm = self._cm, None
+            try:
+                cm.__exit__(None, None, None)
+            except Exception as exc:  # noqa: BLE001
+                print(f"[server] trace capture failed to stop: {exc!r}",
+                      file=sys.stderr)
 
 
 def start_profiler_server(port: int = 9999):

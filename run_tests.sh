@@ -1,192 +1,36 @@
 #!/bin/bash
-# Test entry point. Tests run on the CPU: JAX_PLATFORMS=cpu, a virtual
-# 8-device mesh (tests/conftest.py), pallas kernels interpreted. The
-# chip is reached through chip_smoke.py and the CST_TPU_TESTS=1 set.
+# The builder's test entry point. Tests run on the CPU: JAX_PLATFORMS=cpu,
+# a virtual 8-device mesh (tests/conftest.py), pallas kernels interpreted.
+# The chip is reached through chip_smoke.py and the CST_TPU_TESTS=1 set.
 #
-# The suite runs as THREE sequential pytest processes. This is a
-# workaround for a PROVEN environment ceiling, not a style choice:
-# each jit compilation leaks memory mappings (LLVM JIT code pages are
-# never unmapped in-process), and once the process crosses
-# vm.max_map_count (65530 here) the next XLA CPU backend_compile
-# SEGFAULTS instead of erroring. Measured r5: /proc/<pid>/num_maps
-# grows ~linearly with tests run and the crash lands within ~400 maps
-# of the ceiling, reproduced on an UNMODIFIED r4 checkout — every
-# test file passes in isolation. Splitting keeps each process at
-# ~20-25k maps. Groups are alphabetical file ranges so ordering stays
-# stable and predictable.
+#   ./run_tests.sh                  # the fast set: everything not listed
+#                                   # in tests/slow_tests.txt
+#   ./run_tests.sh --all            # every test
+#   ./run_tests.sh <pytest args>    # the fast set with extra arguments
 #
-# Default: the FAST set (~5-6 min/group) — everything except the tests
-# marked slow via tests/slow_tests.txt, which still covers every
-# parallelism family (dp/fsdp/tp, sp-ring, ulysses, pp, ep, hybrid-dcn)
-# plus the engine/server/checkpoint flows.
-#   ./run_tests.sh --all   # full sweep (~35 min)
-#   ./run_tests.sh <pytest args...>  # fast set with extra args
-#
-# Group membership is by filename glob, so new test files land
-# automatically: tests/test_qos.py (multi-tenant QoS) rides the [p-r]
-# group with the other serving-stack heavies,
-# tests/test_spec_control.py (adaptive speculation: controller law,
-# the mixed+draft-spec+adaptive dispatch-count clone, /stats merge)
-# rides [s-z] with test_speculative.py, tests/test_analysis.py
-# (the stdlib-only static-analysis gate: hot-path lint +
-# lock-discipline + dispatch-discipline, see docs/analysis.md) rides
-# [a-f], tests/test_cache_observability.py (KV-cache & memory
-# observability: per-tenant prefix attribution, eviction forensics,
-# the hot-prefix sketch + its fleet merge, /debug/cache) rides [a-f]
-# with test_block_allocator.py, tests/test_faults.py (failure-domain
-# layer: deterministic fault injection, request deadlines, overload
-# brownout, router breaker/failover e2e incl. the wedged-teardown
-# counter) rides [a-f] too, the router failover/breaker/drain-race
-# satellites ride tests/test_router.py in [p-r], and
-# tests/test_iteration_profile.py
-# (the scheduler phase
-# clock: overhead/clock-read guard, flight-record phase split,
-# /debug/scheduler_trace Perfetto export + span cross-links, idle
-# visibility, fleet merge) rides [g-o], and tests/test_overlap.py
-# (the async double-buffered scheduler: overlap-on/off exactness
-# parity, pipeline dispatch discipline, deferred sweep reaps, fault
-# injection with a dispatch in flight, idle-spin bounds) rides [g-o]
-# too, as does tests/test_migration.py (live in-flight request
-# migration: export/import round-trips, migrated-vs-uninterrupted
-# token exactness, drain(migrate=True), the armed-but-idle
-# dispatch-count clone, and the tier-1-sized chaos variant; the
-# 3-replica soak + speculation/grammar exactness runs are marked
-# slow), and tests/test_disagg.py (disaggregated prefill/decode:
-# role validation + colocated-default parity, role-aware _pick,
-# handoff e2e token exactness with the merged cross-replica span
-# tree, QoS continuation billing; the 4-replica drain-compose soak
-# and the batch-flood non-starvation e2e are marked slow) rides
-# [a-f], as does tests/test_anomaly.py (anomaly watchdog + tail-based
-# trace retention + forensic bundles: rule hysteresis with injected
-# clocks, the retention predicate clause by clause, fleet stat
-# merging, bundle auto-capture, /debug/bundle), and
-# tests/test_scenarios.py (scenario harness + SLO-burn autoscaler:
-# seeded workload determinism, the replay timing contract, the
-# discrete-event simulator's calibration-vs-live bar, autoscaler
-# decision law with stub fleets, the scale-down drain race, and the
-# replay-driven dispatch-count clone) rides [s-z] — its two heavies
-# (calibration, dispatch clone) share the group process's jit cache
-# with the other serving e2es. The suite is also
-# runnable
-# standalone:
-#   python -m cloud_server_tpu.analysis [--json] [--checker <id>]
-#
-# Tier-1 budget note (PR 14): the driver's one-process gate
-# (`timeout 870 pytest tests/ -m 'not slow'`) had been TRUNCATING at
-# the budget since ~PR 13 — DOTS_PASSED=318 with the whole
-# alphabetical tail (test_p* onward) never executed, so the gate
-# measured less than the fast set claims. PR 14 re-balanced by
-# marking the ~300 s of heaviest REDUNDANT e2e tests slow (see the
-# PR-14 block at the end of tests/slow_tests.txt: profiler-capture
-# smokes, duplicate speculation-parity e2es whose exactness twins
-# remain fast, debug-endpoint round-trips — NOT
-# test_paged_server_tp_sharded_matches_single_device, which stays
-# fast as the sole sharded-paged-serving parity check now that the
-# async scheduler defaults on). Measured baseline after the
-# re-balance on the reference sandbox:
-#   one-process fast set: 744 s wall / 711 s pytest, DOTS_PASSED=547
-#   — a COMPLETE run back under the 870 s budget with ~125 s headroom
-#   for box-load variance (vs 318 truncated dots before; a first
-#   re-balance at 788 s/557 dots was observed to graze the budget on
-#   a slower run, hence the extra ~90 s of demotions).
-# If the gate starts truncating again (RC=124, DOTS below the
-# baseline), move the newest heavy non-essential tests to
-# slow_tests.txt rather than letting the tail silently drop.
-#
-# PR 15 re-balance: test_migration.py's ~85 s tier-1 set pushed a
-# measured complete run to 936 s / 558 dots — OVER the 870 s budget
-# (and box-speed variance between back-to-back runs measured up to
-# ~20%, so the margin must absorb that). Seventeen redundant heavies
-# (~190 s) demoted (the PR-15 block at the end of
-# tests/slow_tests.txt): the ondemand reservation-overflow stress +
-# one of the two oversized-fail twins; the seeded/penalties overlap
-# parity duplicates whose reference-exactness twins in
-# test_sampling_params already run under the default-ON async
-# scheduler; spec/param twins with a fast sibling remaining
-# (grammar schema[2], beam[7-1.0], wide-kernel[4-4-48],
-# min_tokens[2], v1_completions[paged-spec], roundtrip[paged-spec],
-# spec greedy-rows parity next to test_speculative_actually_accepts,
-# logit-bias whose HTTP twin stays fast, ngram-draft CLI next to
-# the spec-drafts CLI); the mixed-scheduler budget-cap heavy; and
-# three telemetry/HTTP round-trips (spec flight-recorder,
-# adapter-over-http, json-schema-over-http) whose engine-level twins
-# stay fast. Six new pure-host migration unit tests (milliseconds:
-# snapshot math, ledger accounting, fleet merge) keep DOTS_PASSED at
-# the 547 baseline. Measured after the re-balance: ~750 s complete
-# at the session-typical speed. CAVEAT: a sustained ~20-25%-slower
-# load window was also observed on the sandbox (back-to-back gate
-# runs at ~1.7 s/item vs 1.4) in which even the PRE-rebalance seed
-# set would overrun 870 s; in such a window the gate truncates with
-# ZERO failures in the executed prefix (the full set was verified
-# green in a complete untimed run). Demoting another ~100 s to absorb
-# that worst case would push DOTS permanently below the baseline, so
-# the re-balance targets the typical speed instead.
-#
-# PR 17 re-balance: test_disagg.py's ~33 s tier-1 set measured a
-# COMPLETE green run at 842 s pytest on a ~8%-slow window — grazing
-# the 870 s wall once interpreter startup is counted (timeout fired
-# during teardown AFTER the "560 passed" summary). Three demotions
-# (~25 s, the PR-17 block at the end of tests/slow_tests.txt): the
-# disagg batch-flood non-starvation e2e (role-aware _pick + the
-# handoff e2e keep the fast coverage), the grammar slot-reuse hygiene
-# e2e (its constrained-exactness twin stays fast, its
-# preemption-survival twin was already slow),
-# test_paged_server_matches_engine_greedy[ondemand] (the [reserve]
-# twin stays fast as the core engine-parity check), and
-# test_mixed_step_dispatch_count_with_qos (the
-# test_observability dispatch/sync-count guard's [qos_cache] clone
-# runs the SAME invariant with a live multi-tenant registry and stays
-# fast). A first re-run also surfaced a race in the new disagg
-# handoff e2e — the async handoff worker losing to a short local
-# decode on a loaded box — fixed by enlarging the decode window to
-# 32 tokens (the flood-test fix), not by demotion. DOTS lands at 556
-# vs the 547 baseline.
-# PR 20 re-balance: tests/test_scenarios.py's ~58 s tier-1 set (its
-# two heavies — the sim calibration-vs-live run and the replay-driven
-# dispatch-count clone — compile fresh bucket shapes) measured a
-# COMPLETE green run at 1034 s / 616 dots on a ~20%-slow load window
-# (1.68 s/item vs the 1.4 typical; the PR-15 caveat window) — the
-# timed gate truncated. Nine redundant heavies (~98 s at that speed,
-# the PR-20 block at the end of tests/slow_tests.txt): the span-tree
-# preemption soak (span recording keeps broad fast coverage and the
-# preempt-requeue lifecycle twin was already slow); the profiler
-# dispatch/sync/clock-count clone (the canonical test_observability
-# guard plus the anomaly_tail and new scenario-replay clones stay
-# fast); the migration snapshot-field/evacuation audit and the
-# drain(migrate=True) evacuate-all e2e (the new
-# scale-down-drain-race and add/remove-replica live tests keep fast
-# drain-migrate coverage; the chaos kill and live-migration exactness
-# e2es stay fast); many-adapters-matches-merged (the single-adapter
-# parity twin stays); the contiguous server engine-parity (its
-# CLI contiguous-vs-paged twin stays); grammar pattern[2] (the [0]
-# twin stays; spec-grammar parity was already slow); the heaviest
-# xla-reference-matches-dense shape (three cheaper shapes stay); and
-# the logit-bias HTTP [paged-spec] variant (the [paged] twin stays).
-# Per the PR-15 precedent this targets the TYPICAL box speed
-# (~780 s complete, ~90 s headroom); a sustained slow window can
-# still truncate with zero failures in the executed prefix — the
-# full set was verified green in a complete untimed run.
+# Four sequential pytest processes, by alphabetical ranges of file names
+# (a new test file lands in its range by itself). One process cannot run
+# the whole suite: each jit compilation leaves memory mappings behind
+# (LLVM's JIT code pages are never unmapped in-process), and past
+# vm.max_map_count (65530 here) the next XLA CPU compile segfaults
+# instead of erroring. The driver's gate runs the same fast set on six
+# xdist workers, one file a worker at a time (`-p xdist -n 6 --dist
+# loadfile`, 1,470 s), which stays under the ceiling the same way; the
+# benchmark's own tests are `python -m pytest cellbench -m "not slow"`
+# and belong to neither.
+
 MARK=(-m "not slow")
 if [ "$1" = "--all" ]; then
     MARK=(); shift
 fi
 if [ "$#" -eq 0 ]; then set -- -x -q; fi
 
-# The static-analysis suite as an EXPLICIT gating step (stdlib-only,
-# ~instant), not only via tests/test_analysis.py: ALL passes run —
-# hot-path (per-iteration scheduler code free of device work/syncs/
-# allocation/wall-clock/I-O), lock-discipline (guarded-attribute and
-# _step_lock -> _lock ordering audit over the serving modules),
-# dispatch-discipline (one sanctioned device_get per iteration,
-# jax-free host-policy modules, bounded jit static args), and
-# lifecycle-discipline (finish-exactly-once through _complete in the
-# documented terminal order, page-ownership balance on every edge,
-# no torn guarded writes across may-raise calls). The exit code
-# propagates, so a failure here reads as "serving invariant
-# regression", loudly, before any pytest output scrolls past. The
-# machine-readable report lands in a /tmp artifact so CI can upload
-# it (and render --sarif annotations) without re-running the suite.
-# Checker catalog + suppression-pragma syntax: docs/analysis.md.
+# The static-analysis passes first, as a step of their own (stdlib only,
+# about a second): hot-path, lock-discipline, dispatch-discipline and
+# lifecycle-discipline over the serving modules (docs/analysis.md has
+# the catalog and the pragma syntax). The exit code propagates, so a
+# finding reads as a broken serving invariant before any pytest output;
+# the JSON report is left where ANALYSIS_JSON says, for CI to upload.
 ANALYSIS_JSON="${ANALYSIS_JSON:-/tmp/cloud_server_tpu_analysis.json}"
 env JAX_PLATFORMS=cpu \
     python -m cloud_server_tpu.analysis --json > "$ANALYSIS_JSON"

@@ -121,7 +121,7 @@ def test_registry_covers_iteration_profile():
     """The iteration-phase profiler's record path runs at every phase
     boundary of every scheduler iteration — the tightest loop on the
     roster — and the module must stay jax-free (it is consulted from
-    both servers' step loops)."""
+    the server's step loop)."""
     quals = set(
         HOT_PATHS["cloud_server_tpu/inference/iteration_profile.py"])
     for needed in ("IterationProfiler.begin", "IterationProfiler.enter",
@@ -705,11 +705,10 @@ def test_dispatch_host_policy_purity():
     assert not dispatch.check_host_policy_source("policy.py", clean)
 
 
-def test_dispatch_rosters_cover_both_servers():
-    for rel in ("cloud_server_tpu/inference/paged_server.py",
-                "cloud_server_tpu/inference/server.py"):
-        assert rel in dispatch.SCHEDULER_LOOPS
-        assert dispatch.SANCTIONED_SYNCS[rel]
+def test_dispatch_rosters_cover_the_server():
+    rel = "cloud_server_tpu/inference/paged_server.py"
+    assert rel in dispatch.SCHEDULER_LOOPS
+    assert dispatch.SANCTIONED_SYNCS[rel]
     for rel in ("cloud_server_tpu/inference/qos.py",
                 "cloud_server_tpu/inference/slo.py",
                 "cloud_server_tpu/inference/request_trace.py",
@@ -1011,12 +1010,12 @@ def test_lifecycle_roster_rot_is_a_finding():
 def test_lifecycle_rosters_cover_the_serving_stack():
     """The real rosters stay anchored: the five lifecycle modules,
     the router's completion owners, emit_token as the terminal
-    marker, both _complete bodies, and _Slot as the audited page
+    marker, the _complete body, and _Slot as the audited page
     transferee. check_lifecycle over the repo is clean (deliberate
     exceptions ride as pragmas, applied by run_analysis)."""
     assert lifecycle.LIFECYCLE_ROSTER == (
         "cloud_server_tpu/inference/paged_server.py",
-        "cloud_server_tpu/inference/server.py",
+        "cloud_server_tpu/inference/request.py",
         "cloud_server_tpu/inference/block_allocator.py",
         "cloud_server_tpu/inference/migration.py",
         "cloud_server_tpu/inference/router.py")
@@ -1025,7 +1024,7 @@ def test_lifecycle_rosters_cover_the_serving_stack():
     assert "ReplicatedRouter._retry_submit" in owners
     assert "ReplicatedRouter._mirror_retry" in owners
     assert lifecycle.TERMINAL_MARKER_FUNCS[
-        "cloud_server_tpu/inference/server.py"] == ("emit_token",)
+        "cloud_server_tpu/inference/request.py"] == ("emit_token",)
     assert lifecycle.OWNERSHIP_TRANSFER_FUNCS[
         "cloud_server_tpu/inference/paged_server.py"] == ("_Slot",)
     # the commit completes through the delivery list, and the list's

@@ -19,7 +19,6 @@ from cloud_server_tpu.inference.paged_server import PagedInferenceServer
 from cloud_server_tpu.inference.request_trace import (
     TAIL_REASONS, RequestTrace, TraceRecorder, resolve_recorder)
 from cloud_server_tpu.inference.router import ReplicatedRouter
-from cloud_server_tpu.inference.server import InferenceServer
 from cloud_server_tpu.models import transformer
 
 CFG = ModelConfig(
@@ -418,16 +417,10 @@ def _run_deadline_incident(srv):
     return ok, dead
 
 
-@pytest.mark.parametrize("kind", ["contiguous", "paged"])
-def test_watchdog_fires_and_bundle_autocaptures(params, kind):
-    if kind == "contiguous":
-        srv = InferenceServer(params, CFG, _FORENSIC_ICFG, max_slots=2,
-                              max_len=64, prompt_buckets=[16, 48],
-                              tracing=0.0, anomaly=_TRIGGER_CFG)
-    else:
-        srv = PagedInferenceServer(params, CFG, _FORENSIC_ICFG,
-                                   tracing=0.0, anomaly=_TRIGGER_CFG,
-                                   **PAGED_KW)
+def test_watchdog_fires_and_bundle_autocaptures(params):
+    srv = PagedInferenceServer(params, CFG, _FORENSIC_ICFG,
+                               tracing=0.0, anomaly=_TRIGGER_CFG,
+                               **PAGED_KW)
     ok, dead = _run_deadline_incident(srv)
     # the watchdog latched the incident...
     astats = srv.anomaly_stats()
@@ -451,9 +444,8 @@ def test_watchdog_fires_and_bundle_autocaptures(params, kind):
     # lands just after, so the ring block is present but may predate it
     assert set(bundle["tail_retention"]) == {
         "capacity", "retained", "retained_total", "evicted_total"}
-    if kind == "paged":  # flight/cache blocks are paged-scheduler-only
-        assert isinstance(bundle["flight"], list)
-        assert "cache" in bundle
+    assert isinstance(bundle["flight"], list)
+    assert "cache" in bundle
     assert isinstance(bundle["metrics"], dict)
     # metric families mirror the same counts
     snap = srv.metrics_snapshot()

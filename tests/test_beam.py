@@ -195,7 +195,7 @@ def test_best_of_ranks_by_mean_logprob(params):
 
 
 def test_beam_tiny_vocab_rejected(params):
-    """ADVICE r5: 2*k > vocab_size breaks the 2k-candidate selection
+    """2*k > vocab_size breaks the 2k-candidate selection
     (NEG_INF dead-beam candidates get picked, yielding duplicate
     hypotheses silently) — it must be a trace-time ValueError."""
     tiny = ModelConfig(

@@ -18,7 +18,7 @@ from cloud_server_tpu.inference.faults import (BrownoutShedError,
 from cloud_server_tpu.inference.paged_server import PagedInferenceServer
 from cloud_server_tpu.inference.request_trace import PHASES
 from cloud_server_tpu.inference.router import ReplicatedRouter
-from cloud_server_tpu.inference.server import QueueFullError, Request
+from cloud_server_tpu.inference.request import QueueFullError, Request
 from cloud_server_tpu.models import transformer
 
 CFG = ModelConfig(

@@ -76,47 +76,6 @@ def test_generate_ngram_draft_cli(tmp_path, capsys, devices8):
     assert spec.rsplit("'abab'", 1)[1] == plain.rsplit("'abab'", 1)[1]
 
 
-def test_generate_prefix_caching_cli(tmp_path, capsys, devices8):
-    """--prefix serves prompts extending the prefix with identical output
-    to the plain path."""
-    from cloud_server_tpu.generate import main as generate_main
-
-    model = {"vocab_size": 259, "embed_dim": 32, "num_layers": 2,
-             "num_heads": 4, "num_kv_heads": 2, "head_dim": 8,
-             "mlp_dim": 64, "max_seq_len": 128, "dtype": "float32",
-             "param_dtype": "float32", "remat": "none"}
-    (tmp_path / "cfg.json").write_text(json.dumps({"model": model}))
-    base_args = ["--config", str(tmp_path / "cfg.json"),
-                 "--prompt", "sys: abcdef", "--max-new", "8",
-                 "--temperature", "0"]
-    generate_main(base_args)
-    plain = capsys.readouterr().out
-    generate_main(base_args + ["--prefix", "sys: "])
-    fast = capsys.readouterr().out
-    assert fast == plain
-
-
-def test_generate_contiguous_matches_paged_default(tmp_path, capsys,
-                                                   devices8):
-    """The default (paged) and --contiguous backends must produce
-    identical greedy output through the CLI."""
-    from cloud_server_tpu.generate import main as generate_main
-
-    model = {"vocab_size": 259, "embed_dim": 32, "num_layers": 2,
-             "num_heads": 4, "num_kv_heads": 2, "head_dim": 8,
-             "mlp_dim": 64, "max_seq_len": 128, "dtype": "float32",
-             "param_dtype": "float32", "remat": "none"}
-    (tmp_path / "cfg.json").write_text(json.dumps({"model": model}))
-    base_args = ["--config", str(tmp_path / "cfg.json"),
-                 "--prompt", "abcd", "--prompt", "xyz",
-                 "--max-new", "8", "--temperature", "0"]
-    generate_main(base_args)
-    paged = capsys.readouterr().out
-    generate_main(base_args + ["--contiguous"])
-    contiguous = capsys.readouterr().out
-    assert paged == contiguous
-
-
 def test_generate_spec_drafts_cli(tmp_path, capsys, devices8):
     """--spec-drafts (in-server speculation through the paged server)
     must match the plain greedy path token-for-token."""

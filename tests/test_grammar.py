@@ -15,7 +15,6 @@ from cloud_server_tpu.data.tokenizer import ByteTokenizer
 from cloud_server_tpu.inference import grammar
 from cloud_server_tpu.inference.paged_server import PagedInferenceServer
 from cloud_server_tpu.inference.sampling import SamplingParams
-from cloud_server_tpu.inference.server import InferenceServer
 from cloud_server_tpu.models import transformer
 
 TOK = ByteTokenizer()
@@ -380,10 +379,6 @@ def test_constrained_validation(params):
         **SRV_KW)
     with pytest.raises(ValueError):
         no_eos.submit([1], sampling=SamplingParams(regex="[0-9]+"))
-    contig = InferenceServer(params, CFG, ICFG, max_slots=2, max_len=64,
-                             prompt_buckets=[16])
-    with pytest.raises(ValueError):
-        contig.submit([1], sampling=SamplingParams(regex="[0-9]+"))
 
 
 def test_sampled_constrained_generation(params):

@@ -1,6 +1,5 @@
 """HTTP front-end: loopback round-trip, streaming, protocol errors —
-over BOTH serving backends (contiguous and paged, the latter with and
-without in-server speculation)."""
+over the paged server with and without in-server speculation."""
 
 import dataclasses
 import json
@@ -16,7 +15,6 @@ from cloud_server_tpu.data.tokenizer import get_tokenizer
 from cloud_server_tpu.inference import engine
 from cloud_server_tpu.inference.http_server import HttpFrontend
 from cloud_server_tpu.inference.paged_server import PagedInferenceServer
-from cloud_server_tpu.inference.server import InferenceServer
 from cloud_server_tpu.models import transformer
 
 CFG = ModelConfig(
@@ -27,18 +25,13 @@ GREEDY = InferConfig(max_decode_len=8, temperature=0.0, eos_token_id=-1,
                      pad_token_id=0)
 
 
-@pytest.fixture(scope="module",
-                params=["contiguous", "paged", "paged-spec"])
+@pytest.fixture(scope="module", params=["paged", "paged-spec"])
 def frontend(request):
     params = transformer.init_params(CFG, jax.random.key(0))
-    if request.param == "contiguous":
-        srv = InferenceServer(params, CFG, GREEDY, max_slots=2, max_len=64,
-                              prompt_buckets=[16, 48])
-    else:
-        srv = PagedInferenceServer(
-            params, CFG, GREEDY, max_slots=2, max_context=64, page_size=8,
-            prefill_chunk=16, prompt_buckets=[16, 48],
-            spec_drafts=2 if request.param == "paged-spec" else 0)
+    srv = PagedInferenceServer(
+        params, CFG, GREEDY, max_slots=2, max_context=64, page_size=8,
+        prefill_chunk=16, prompt_buckets=[16, 48],
+        spec_drafts=2 if request.param == "paged-spec" else 0)
     srv.start()
     front = HttpFrontend(srv, tokenizer=get_tokenizer("byte")).start()
     yield front, params

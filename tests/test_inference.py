@@ -161,7 +161,8 @@ def test_moe_prefill_decode_matches_full_forward():
 
 def test_moe_server_generates(devices8):
     """The continuous-batching server serves the MoE family end-to-end."""
-    from cloud_server_tpu.inference.server import InferenceServer
+    from cloud_server_tpu.inference.paged_server import (
+        PagedInferenceServer)
     from cloud_server_tpu.models import moe
 
     cfg = ModelConfig(
@@ -172,8 +173,9 @@ def test_moe_server_generates(devices8):
     params = moe.init_params(cfg, jax.random.key(0))
     icfg = InferConfig(max_decode_len=6, temperature=0.0, eos_token_id=-1,
                        pad_token_id=0)
-    srv = InferenceServer(params, cfg, icfg, max_slots=2, max_len=32,
-                          prompt_buckets=[8])
+    srv = PagedInferenceServer(params, cfg, icfg, max_slots=2,
+                               max_context=32, page_size=8,
+                               prefill_chunk=8, prompt_buckets=[8])
     outs = srv.generate([[5, 9, 3], [17, 2]], max_new_tokens=6)
     # greedy reference from the batch engine
     for prompt, out in zip([[5, 9, 3], [17, 2]], outs):

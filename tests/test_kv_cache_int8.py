@@ -77,14 +77,17 @@ def test_generate_greedy_matches_fp():
 
 
 def test_server_int8_cache_runs():
-    from cloud_server_tpu.inference.server import InferenceServer
+    from cloud_server_tpu.inference.paged_server import (
+        PagedInferenceServer)
 
     params = transformer.init_params(BASE, jax.random.key(0))
     icfg = InferConfig(max_decode_len=8, temperature=0.0, eos_token_id=-1,
                        pad_token_id=0)
-    srv_fp = InferenceServer(params, BASE, icfg, max_slots=2, max_len=32)
+    kw = dict(max_slots=2, max_context=32, page_size=8, prefill_chunk=8,
+              prompt_buckets=[8])
+    srv_fp = PagedInferenceServer(params, BASE, icfg, **kw)
     want = srv_fp.generate([[3, 7, 11], [9, 1, 4, 8]], max_new_tokens=8)
-    srv = InferenceServer(params, INT8, icfg, max_slots=2, max_len=32)
+    srv = PagedInferenceServer(params, INT8, icfg, **kw)
     got = srv.generate([[3, 7, 11], [9, 1, 4, 8]], max_new_tokens=8)
     assert got == want
 

@@ -14,7 +14,7 @@ from net_compat import requires_loopback_disconnect
 
 from cloud_server_tpu.config import InferConfig, ModelConfig
 from cloud_server_tpu.inference.paged_server import PagedInferenceServer
-from cloud_server_tpu.inference.server import QueueFullError
+from cloud_server_tpu.inference.request import QueueFullError
 from cloud_server_tpu.models import transformer
 
 CFG = ModelConfig(
@@ -238,87 +238,38 @@ def test_drain_with_background_thread(params):
 
 
 def test_drain_then_resume_accepts_again(params):
-    """ADVICE r5: a successful drain quiesces (submission refused) and
-    resume() reopens it WITHOUT a stop/start cycle — on both servers."""
-    from cloud_server_tpu.inference.server import InferenceServer
-    paged = PagedInferenceServer(params, CFG, GREEDY, **SRV_KW)
-    contig = InferenceServer(params, CFG, GREEDY, max_slots=2, max_len=64,
-                             prompt_buckets=[16])
-    for srv in (paged, contig):
-        r1 = srv.submit(PROMPT, max_new_tokens=4)
-        assert srv.drain(timeout=120) is True
-        assert len(r1.tokens) == 4
-        with pytest.raises(RuntimeError, match="draining"):
-            srv.submit(PROMPT, max_new_tokens=2)
-        srv.resume()
-        r2 = srv.submit(PROMPT, max_new_tokens=4)
-        srv.run_until_idle()
-        assert r2.tokens == r1.tokens
-        srv.stop()
+    """A successful drain quiesces (submission refused) and resume()
+    reopens it WITHOUT a stop/start cycle."""
+    srv = PagedInferenceServer(params, CFG, GREEDY, **SRV_KW)
+    r1 = srv.submit(PROMPT, max_new_tokens=4)
+    assert srv.drain(timeout=120) is True
+    assert len(r1.tokens) == 4
+    with pytest.raises(RuntimeError, match="draining"):
+        srv.submit(PROMPT, max_new_tokens=2)
+    srv.resume()
+    r2 = srv.submit(PROMPT, max_new_tokens=4)
+    srv.run_until_idle()
+    assert r2.tokens == r1.tokens
+    srv.stop()
 
 
 def test_stop_drain_timeout_latches_draining(params):
-    """ADVICE r5: stop(drain=True, timeout=...)'s timed-out drain must
-    NOT reopen submission before _stop is set — no request may be
-    accepted just to be failed. The internal latch is what closes the
-    window; verify it directly (deterministic), on both servers."""
-    from cloud_server_tpu.inference.server import InferenceServer
-    paged = PagedInferenceServer(params, CFG, GREEDY, **SRV_KW)
-    contig = InferenceServer(params, CFG, GREEDY, max_slots=2, max_len=64,
-                             prompt_buckets=[16])
-    for srv in (paged, contig):
-        r = srv.submit(PROMPT, max_new_tokens=8)
-        # the stop(drain=True) path: a timed-out drain keeps _draining
-        assert srv.drain(timeout=0.0, _resume_on_timeout=False) is False
-        with pytest.raises(RuntimeError, match="draining"):
-            srv.submit(PROMPT, max_new_tokens=2)  # the race window
-        srv.stop()  # fails the straggler, unblocks its waiter
-        assert r.done and r.finish_reason.startswith("error")
-        # and the PUBLIC drain contract still resumes on timeout
-        srv2 = (PagedInferenceServer(params, CFG, GREEDY, **SRV_KW)
-                if srv is paged else
-                InferenceServer(params, CFG, GREEDY, max_slots=2,
-                                max_len=64, prompt_buckets=[16]))
-        srv2.submit(PROMPT, max_new_tokens=8)
-        assert srv2.drain(timeout=0.0) is False
-        srv2.submit(PROMPT, max_new_tokens=2)  # accepted again
-        srv2.stop()
+    """stop(drain=True, timeout=...)'s timed-out drain must NOT reopen
+    submission before _stop is set — no request may be accepted just
+    to be failed. The internal latch is what closes the window; verify
+    it directly (deterministic)."""
+    srv = PagedInferenceServer(params, CFG, GREEDY, **SRV_KW)
+    r = srv.submit(PROMPT, max_new_tokens=8)
+    # the stop(drain=True) path: a timed-out drain keeps _draining
+    assert srv.drain(timeout=0.0, _resume_on_timeout=False) is False
+    with pytest.raises(RuntimeError, match="draining"):
+        srv.submit(PROMPT, max_new_tokens=2)  # the race window
+    srv.stop()  # fails the straggler, unblocks its waiter
+    assert r.done and r.finish_reason.startswith("error")
+    # and the PUBLIC drain contract still resumes on timeout
+    srv2 = PagedInferenceServer(params, CFG, GREEDY, **SRV_KW)
+    srv2.submit(PROMPT, max_new_tokens=8)
+    assert srv2.drain(timeout=0.0) is False
+    srv2.submit(PROMPT, max_new_tokens=2)  # accepted again
+    srv2.stop()
 
-
-def test_contiguous_server_cancel(params):
-    """The contiguous server shares the cancel surface: pending finishes
-    immediately, active slots release at the next step."""
-    from cloud_server_tpu.inference.server import InferenceServer
-    srv = InferenceServer(params, CFG, GREEDY, max_slots=2, max_len=64,
-                          prompt_buckets=[16])
-    pending = srv.submit(PROMPT, max_new_tokens=8)
-    pending.cancel()
-    assert pending.done and pending.finish_reason == "cancelled"
-    active = srv.submit(PROMPT, max_new_tokens=30)
-    srv.step()
-    assert not active.done
-    active.cancel()
-    srv.step()
-    assert active.done and active.finish_reason == "cancelled"
-    assert srv.num_active == 0
-    ok = srv.submit(PROMPT, max_new_tokens=4)
-    srv.run_until_idle()
-    assert len(ok.result()) == 4
-
-
-def test_contiguous_server_backpressure_and_drain(params):
-    """max_pending and stop(drain=True) behave identically on the
-    contiguous server (shared lifecycle contract)."""
-    from cloud_server_tpu.inference.server import InferenceServer
-    srv = InferenceServer(params, CFG, GREEDY, max_slots=2, max_len=64,
-                          prompt_buckets=[16], max_pending=1)
-    srv.submit(PROMPT, max_new_tokens=4)
-    with pytest.raises(QueueFullError):
-        srv.submit(PROMPT, max_new_tokens=4)
-    srv.run_until_idle()
-    reqs = [srv.submit(PROMPT, max_new_tokens=6)]
-    srv.stop(drain=True)
-    assert reqs[0].finish_reason == "length"
-    assert len(reqs[0].tokens) == 6
-    with pytest.raises(RuntimeError, match="stopped"):
-        srv.submit(PROMPT, max_new_tokens=2)

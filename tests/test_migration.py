@@ -29,7 +29,7 @@ from cloud_server_tpu.inference.paged_server import PagedInferenceServer
 from cloud_server_tpu.inference.request_trace import PHASES
 from cloud_server_tpu.inference.router import ReplicatedRouter
 from cloud_server_tpu.inference.sampling import SamplingParams
-from cloud_server_tpu.inference.server import Request
+from cloud_server_tpu.inference.request import Request
 from cloud_server_tpu.models import transformer
 
 CFG = ModelConfig(

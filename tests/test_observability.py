@@ -1,6 +1,6 @@
 """Serving observability: metrics registry semantics (bucket edges,
 merge, rendering), request-lifecycle timestamp monotonicity across
-finish/cancel/preempt paths on both servers, router snapshot merging,
+finish/cancel/preempt paths, router snapshot merging,
 the flight recorder, docs-catalog drift, and the dispatch-count
 regression guard (instrumentation must add zero dispatches/syncs)."""
 
@@ -17,7 +17,6 @@ import pytest
 from cloud_server_tpu.config import InferConfig, ModelConfig
 from cloud_server_tpu.inference.paged_server import PagedInferenceServer
 from cloud_server_tpu.inference.router import ReplicatedRouter
-from cloud_server_tpu.inference.server import InferenceServer
 from cloud_server_tpu.models import transformer
 from cloud_server_tpu.utils.logging import JsonLogger
 from cloud_server_tpu.utils.serving_metrics import (
@@ -181,7 +180,7 @@ def test_flight_recorder_ring():
 
 
 # ---------------------------------------------------------------------------
-# lifecycle monotonicity (both servers, finish/cancel/preempt)
+# lifecycle monotonicity (finish/cancel/preempt)
 # ---------------------------------------------------------------------------
 
 
@@ -203,44 +202,38 @@ def _check_monotonic(req, *, expect=()):
         assert req.submit_time <= times[i_admit] <= times[i_ft]
 
 
-def test_lifecycle_monotonic_finish_both_servers(params):
-    contig = InferenceServer(params, CFG, GREEDY, max_slots=2, max_len=64,
-                             prompt_buckets=[16])
-    paged = PagedInferenceServer(params, CFG, GREEDY, **PAGED_KW)
-    for srv in (contig, paged):
-        reqs = [srv.submit([5, 9, 3], max_new_tokens=4),
-                srv.submit([7, 7, 2, 1], max_new_tokens=4)]
-        srv.run_until_idle()
-        for r in reqs:
-            _check_monotonic(r, expect=("admit", "first_token",
-                                        "finish:length"))
-        snap = srv.metrics_snapshot()
-        assert snap["cloud_server_ttft_seconds"]["count"] == 2
-        assert snap["cloud_server_queue_wait_seconds"]["count"] == 2
-        assert snap["cloud_server_e2e_seconds"]["count"] == 2
-        # 4 tokens per request -> 3 inter-token gaps each
-        assert snap["cloud_server_itl_seconds"]["count"] == 6
-        assert snap["cloud_server_requests_finished_total"]["value"] == 2
+def test_lifecycle_monotonic_finish(params):
+    srv = PagedInferenceServer(params, CFG, GREEDY, **PAGED_KW)
+    reqs = [srv.submit([5, 9, 3], max_new_tokens=4),
+            srv.submit([7, 7, 2, 1], max_new_tokens=4)]
+    srv.run_until_idle()
+    for r in reqs:
+        _check_monotonic(r, expect=("admit", "first_token",
+                                    "finish:length"))
+    snap = srv.metrics_snapshot()
+    assert snap["cloud_server_ttft_seconds"]["count"] == 2
+    assert snap["cloud_server_queue_wait_seconds"]["count"] == 2
+    assert snap["cloud_server_e2e_seconds"]["count"] == 2
+    # 4 tokens per request -> 3 inter-token gaps each
+    assert snap["cloud_server_itl_seconds"]["count"] == 6
+    assert snap["cloud_server_requests_finished_total"]["value"] == 2
 
 
-def test_lifecycle_monotonic_cancel_both_servers(params):
-    contig = InferenceServer(params, CFG, GREEDY, max_slots=1, max_len=64,
-                             prompt_buckets=[16])
-    paged = PagedInferenceServer(params, CFG, GREEDY,
-                                 **{**PAGED_KW, "max_slots": 1})
-    for srv in (contig, paged):
-        active = srv.submit([5, 9, 3], max_new_tokens=8)
-        queued = srv.submit([8, 1, 1], max_new_tokens=8)
-        queued.cancel()  # still pending: finishes immediately
-        _check_monotonic(queued, expect=("finish:cancelled",))
-        assert "admit" not in [n for n, _ in queued.timeline()]
-        srv.step()
-        active.cancel()  # holds a slot: reaped by the next step's sweep
-        srv.run_until_idle()
-        _check_monotonic(active, expect=("admit", "finish:cancelled"))
-        snap = srv.metrics_snapshot()
-        assert snap["cloud_server_requests_cancelled_total"]["value"] == 2
-        assert snap["cloud_server_e2e_seconds"]["count"] == 2
+def test_lifecycle_monotonic_cancel(params):
+    srv = PagedInferenceServer(params, CFG, GREEDY,
+                               **{**PAGED_KW, "max_slots": 1})
+    active = srv.submit([5, 9, 3], max_new_tokens=8)
+    queued = srv.submit([8, 1, 1], max_new_tokens=8)
+    queued.cancel()  # still pending: finishes immediately
+    _check_monotonic(queued, expect=("finish:cancelled",))
+    assert "admit" not in [n for n, _ in queued.timeline()]
+    srv.step()
+    active.cancel()  # holds a slot: reaped by the next step's sweep
+    srv.run_until_idle()
+    _check_monotonic(active, expect=("admit", "finish:cancelled"))
+    snap = srv.metrics_snapshot()
+    assert snap["cloud_server_requests_cancelled_total"]["value"] == 2
+    assert snap["cloud_server_e2e_seconds"]["count"] == 2
 
 
 def test_lifecycle_monotonic_preempt_requeue(params):
@@ -464,8 +457,7 @@ def test_flight_recorder_alternating(params):
 
 
 def test_router_snapshot_merge(params):
-    replicas = [InferenceServer(params, CFG, GREEDY, max_slots=2,
-                                max_len=64, prompt_buckets=[16])
+    replicas = [PagedInferenceServer(params, CFG, GREEDY, **PAGED_KW)
                 for _ in range(2)]
     router = ReplicatedRouter(replicas)
     reqs = [router.submit([5 + i, 9, 3], max_new_tokens=4)
@@ -603,14 +595,12 @@ def test_metric_catalog_matches_docs(params):
     docs/observability.md's catalog tables, and vice versa — the
     catalog cannot rot in either direction. Tenant-labeled series
     (multi-tenant QoS) are cataloged by their FAMILY name, so the
-    label suffix is stripped before comparing; one paged server runs
-    with a QoS config so the per-tenant families register."""
+    label suffix is stripped before comparing; the server runs with
+    a QoS config so the per-tenant families register."""
     doc = (pathlib.Path(__file__).resolve().parents[1]
            / "docs" / "observability.md").read_text()
     catalog = set(re.findall(r"^\|\s*`(cloud_server_[a-z0-9_]+)`", doc,
                              re.M))
-    contig = InferenceServer(params, CFG, GREEDY, max_slots=1,
-                             max_len=64, prompt_buckets=[16])
     # qos + slo so the per-tenant AND per-class labeled families
     # register (labeled series are cataloged by family name)
     paged = PagedInferenceServer(params, CFG, GREEDY,
@@ -628,8 +618,7 @@ def test_metric_catalog_matches_docs(params):
     SLOBurnAutoscaler(router, spawn=lambda role: None)
     driver = ReplayDriver(router, [])
     runtime = {name.split("{")[0] for name in
-               set(contig.metrics_snapshot())
-               | set(router.metrics_snapshot())
+               set(router.metrics_snapshot())
                | set(driver.metrics_snapshot())}
     missing_from_docs = runtime - catalog
     stale_in_docs = catalog - runtime

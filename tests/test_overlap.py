@@ -20,7 +20,6 @@ from cloud_server_tpu.config import InferConfig, ModelConfig
 from cloud_server_tpu.inference.faults import FaultPlan
 from cloud_server_tpu.inference.paged_server import PagedInferenceServer
 from cloud_server_tpu.inference.sampling import SamplingParams
-from cloud_server_tpu.inference.server import InferenceServer
 from cloud_server_tpu.models import transformer
 
 CFG = ModelConfig(
@@ -521,58 +520,16 @@ def test_raising_stream_callback_strands_no_completion(params):
 
 
 # ---------------------------------------------------------------------------
-# contiguous server: launch-ahead decode pipelining
+# idle-spin bound
 # ---------------------------------------------------------------------------
 
 
-def test_contiguous_overlap_parity(params):
-    def run(ov):
-        srv = InferenceServer(params, CFG, GREEDY, max_slots=4,
-                              max_len=64, prompt_buckets=[16],
-                              decode_chunk=2, overlap=ov)
-        reqs = [srv.submit(p, max_new_tokens=8)
-                for p in ([5, 9, 3], [7, 2, 4, 1])]
-        for _ in range(2):
-            srv.step()
-        reqs.append(srv.submit([9, 9, 2], max_new_tokens=8))
-        srv.run_until_idle()
-        return [r.result() for r in reqs]
-
-    assert run(True) == run(False)
-
-
-def test_contiguous_overlap_cancel_inflight(params):
-    srv = InferenceServer(params, CFG, GREEDY, max_slots=2, max_len=64,
-                          prompt_buckets=[16], decode_chunk=2,
-                          overlap=True)
-    victim = srv.submit([5, 9, 3], max_new_tokens=30)
-    srv.step()
-    assert srv._inflight is not None
-    victim.cancel()
-    srv.step()  # sweep finishes it; the stale in-flight rows are
-    #             identity-masked at commit
-    assert victim.done and victim.finish_reason == "cancelled"
-    fresh = srv.submit([1, 2, 3], max_new_tokens=4)
-    srv.run_until_idle()
-    assert fresh.result() is not None and len(fresh.tokens) == 4
-
-
-# ---------------------------------------------------------------------------
-# idle-spin bound (both servers)
-# ---------------------------------------------------------------------------
-
-
-@pytest.mark.parametrize("kind", ["paged", "contiguous"])
-def test_idle_iterations_stay_bounded(params, kind):
+def test_idle_iterations_stay_bounded(params):
     """An idle started server parks on the bounded condition wait
     instead of busy-polling: the idle_iterations_total growth rate
     stays far below the old 2 ms poll (~500/s), and a submit still
     wakes it immediately."""
-    if kind == "paged":
-        srv = PagedInferenceServer(params, CFG, GREEDY, **SRV_KW)
-    else:
-        srv = InferenceServer(params, CFG, GREEDY, max_slots=2,
-                              max_len=64, prompt_buckets=[16])
+    srv = PagedInferenceServer(params, CFG, GREEDY, **SRV_KW)
     srv.start()
     try:
         time.sleep(0.2)  # let any startup work settle

@@ -1,6 +1,6 @@
-"""Paged server: parity with the engine/contiguous server, prefix reuse,
-chunked prefill, in-server speculative decoding, capacity beyond the
-contiguous layout."""
+"""Paged server: parity with the engine, the client API (streaming,
+validation, failure, logprobs), prefix reuse, chunked prefill, in-server
+speculative decoding, capacity beyond a contiguous layout."""
 
 import dataclasses
 
@@ -62,6 +62,81 @@ def test_paged_server_interleaves(params):
     assert r0.result() == _engine_reference(params, PROMPTS[0], 12)
     assert r1.result() == _engine_reference(params, PROMPTS[1], 6)
     assert r2.result() == _engine_reference(params, PROMPTS[2], 6)
+
+
+def test_streaming_callback_sees_tokens_in_order(params):
+    seen = []
+    srv = PagedInferenceServer(params, CFG, GREEDY, **SRV_KW)
+    req = srv.submit(PROMPTS[0], max_new_tokens=8, stream=seen.append)
+    srv.run_until_idle()
+    assert seen == req.tokens == _engine_reference(params, PROMPTS[0], 8)
+
+
+def test_submit_validation(params):
+    srv = PagedInferenceServer(params, CFG, GREEDY, max_slots=1,
+                               max_context=16, page_size=8,
+                               prefill_chunk=8, prompt_buckets=[8])
+    with pytest.raises(ValueError, match="empty prompt"):
+        srv.submit([])
+    with pytest.raises(ValueError, match="exceeds largest bucket"):
+        srv.submit(list(range(9)))
+    with pytest.raises(ValueError, match="no room to decode"):
+        srv.submit(list(range(8)), max_new_tokens=0)
+    assert srv.num_pending == 0  # nothing refused was queued
+
+
+def test_shape_validation_at_init(params):
+    with pytest.raises(ValueError, match="multiple of"):
+        PagedInferenceServer(params, CFG, GREEDY, max_slots=1,
+                             max_context=60, page_size=8)
+    with pytest.raises(ValueError, match="allocation"):
+        PagedInferenceServer(params, CFG, GREEDY, allocation="eager",
+                             **SRV_KW)
+
+
+def test_scheduler_error_unblocks_clients(params):
+    """A fatal step() error must fail waiting requests, not hang them."""
+    srv = PagedInferenceServer(params, CFG, GREEDY, **SRV_KW)
+    srv.step = lambda: (_ for _ in ()).throw(RuntimeError("boom"))
+    # submit BEFORE start: the patched step() raises on the scheduler's
+    # first iteration, and a post-crash submit would (correctly) be
+    # rejected with "server is stopped" — a race this test isn't about
+    reqs = [srv.submit(p, max_new_tokens=4) for p in PROMPTS[:2]]
+    srv.start()
+    try:
+        for req in reqs:
+            with pytest.raises(RuntimeError, match="boom"):
+                req.result(timeout=60)
+    finally:
+        srv.stop()
+
+
+def test_slot_reuse_no_leakage(params):
+    """A slot freed by one request must serve the next one exactly:
+    one slot, and a second prompt that shares no page with the first."""
+    srv = PagedInferenceServer(params, CFG, GREEDY,
+                               **{**SRV_KW, "max_slots": 1})
+    first = srv.generate([PROMPTS[1]], max_new_tokens=10)[0]
+    second = srv.generate([PROMPTS[2]], max_new_tokens=10)[0]
+    assert first == _engine_reference(params, PROMPTS[1], 10)
+    assert second == _engine_reference(params, PROMPTS[2], 10)
+
+
+def test_logprobs_recorded(params):
+    """Every emitted token carries the log-probability the model assigned
+    it: checked for the first token against a hand prefill."""
+    import jax.numpy as jnp
+
+    srv = PagedInferenceServer(params, CFG, GREEDY, **SRV_KW)
+    req = srv.submit([3, 7, 11], max_new_tokens=6)
+    srv.run_until_idle()
+    assert len(req.logprobs) == len(req.tokens) == 6
+    assert all(lp <= 0.0 for lp in req.logprobs)
+    cache = engine.init_cache(CFG, 1, 32)
+    logits, _ = engine.prefill(params, jnp.asarray([[3, 7, 11]], jnp.int32),
+                               CFG, cache)
+    want = float(jax.nn.log_softmax(logits[0])[req.tokens[0]])
+    np.testing.assert_allclose(req.logprobs[0], want, rtol=1e-4)
 
 
 def test_chunked_prefill_long_prompt(params):
@@ -215,9 +290,9 @@ def test_speculative_sampled_distribution_smoke(params):
 
 def test_capacity_beyond_contiguous(params):
     """A pool sized for 4 full-context slots serves 8 concurrent short
-    requests — the capacity win paging exists for. (The contiguous server
-    with max_slots=4 would queue them 4 at a time; here all 8 are in
-    flight at once.)"""
+    requests — the capacity win paging exists for. (A contiguous
+    (slots, max_context) cache of the same bytes has 4 rows and would
+    queue them 4 at a time; here all 8 are in flight at once.)"""
     srv = PagedInferenceServer(params, CFG, GREEDY, max_slots=8,
                                max_context=64, page_size=8,
                                num_pages=4 * 8,  # 4 slots' worth of pages

@@ -25,7 +25,7 @@ from cloud_server_tpu.inference.qos import (
     DEFAULT_TENANT, TenantConfig, TenantQueueFullError, TenantRegistry,
     TokenBucket, resolve_registry)
 from cloud_server_tpu.inference.router import ReplicatedRouter
-from cloud_server_tpu.inference.server import InferenceServer, QueueFullError
+from cloud_server_tpu.inference.request import QueueFullError
 from cloud_server_tpu.models import transformer
 
 CFG = ModelConfig(
@@ -440,28 +440,6 @@ def test_preemption_under_qos_keeps_outputs_exact(params):
             + stats["fg"]["preempt_requeues"]) == srv.preemptions
     for p, r in zip(prompts, reqs):
         assert r.result() == _engine_reference(params, p, 40), p
-
-
-def test_contiguous_server_fair_admission(params):
-    """The contiguous server shares the DRR admission + accounting
-    path (no preemption there — only slot admission order)."""
-    srv = InferenceServer(
-        params, CFG, GREEDY, max_slots=1, max_len=64,
-        prompt_buckets=[16],
-        qos={"quantum": 1, "tenants": {"a": {"weight": 3.0},
-                                       "b": {"weight": 1.0}}})
-    reqs = []
-    for _ in range(8):
-        reqs.append(srv.submit([5, 9, 3], max_new_tokens=2, tenant="a"))
-        reqs.append(srv.submit([5, 9, 3], max_new_tokens=2, tenant="b"))
-    srv.run_until_idle()
-    assert all(r.done for r in reqs)
-    s = srv.qos.stats()
-    assert s["a"]["generated"] == s["b"]["generated"]  # all finished
-    # admission ORDER was weighted: a's last admission precedes b's
-    a_admits = sorted(r.admit_time for r in reqs if r.tenant == "a")
-    b_admits = sorted(r.admit_time for r in reqs if r.tenant == "b")
-    assert a_admits[-1] < b_admits[-1]
 
 
 # ---------------------------------------------------------------------------

@@ -19,7 +19,6 @@ from cloud_server_tpu.inference.request_trace import (
     PHASES, TraceRecorder, build_tree, chrome_trace, format_traceparent,
     parse_traceparent, request_phases, resolve_recorder)
 from cloud_server_tpu.inference.router import ReplicatedRouter
-from cloud_server_tpu.inference.server import InferenceServer
 from cloud_server_tpu.models import transformer
 from cloud_server_tpu.utils.logging import JsonLogger
 
@@ -147,19 +146,6 @@ def test_span_tree_paged_server(params):
         for c in iter_spans:
             assert 1 <= c["tags"]["iteration"] <= srv.flight.iterations
     assert srv.lookup_trace("nonexistent") is None
-
-
-def test_span_tree_contiguous_server(params):
-    srv = InferenceServer(params, CFG, GREEDY, max_slots=2, max_len=64,
-                          prompt_buckets=[16], tracing=1.0)
-    req = srv.submit([5, 9, 3], max_new_tokens=4)
-    srv.run_until_idle()
-    tree = srv.lookup_trace(req.request_id)
-    assert tree is not None
-    names = [p["name"] for p in _phases(tree)]
-    for want in ("queue", "prefill", "decode", "emit"):
-        assert want in names, names
-    _assert_contiguous(tree)
 
 
 def test_span_tree_survives_preemption(params):

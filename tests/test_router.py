@@ -242,7 +242,7 @@ def test_half_open_probe_released_on_client_refusal():
     with `probing` latched and the replica never rejoins."""
     import time as _time
 
-    from cloud_server_tpu.inference.server import QueueFullError
+    from cloud_server_tpu.inference.request import QueueFullError
 
     class _Stub:
         def __init__(self, preload=0):
@@ -362,7 +362,7 @@ def test_drain_resume_racing_concurrent_submits():
 
 
 def test_burst_submit_sees_inflight_picks():
-    """ADVICE r5: a submit still blocked inside its replica (the router
+    """A submit still blocked inside its replica (the router
     lock is not held across replica.submit) must be visible to
     concurrent _pick()s via the in-router in-flight counter — otherwise
     a burst piles onto the replica whose queue insert is slowest.

@@ -1,4 +1,4 @@
-"""Per-request sampling: SamplingParams rows through both servers.
+"""Per-request sampling: SamplingParams rows through the server.
 
 Covers the filter chain units (top-k/top-p/min-p/penalties), per-request
 seed reproducibility across batch compositions, mixed greedy/sampled
@@ -21,8 +21,7 @@ from cloud_server_tpu.inference.paged_server import PagedInferenceServer
 from cloud_server_tpu.inference.sampling import (
     SamplingParams, filtered_logits_rows, make_rows,
     sample_logits_rows, sampling_probs, sampling_probs_rows)
-from cloud_server_tpu.inference.server import InferenceServer, Request
-from cloud_server_tpu.inference.server import emit_token
+from cloud_server_tpu.inference.request import Request, emit_token
 from cloud_server_tpu.models import transformer
 
 CFG = ModelConfig(
@@ -35,7 +34,6 @@ SAMPLED = dataclasses.replace(GREEDY, temperature=1.0)
 
 PAGED_KW = dict(max_slots=4, max_context=64, page_size=8, prefill_chunk=16,
                 prompt_buckets=[16, 32])
-CONTIG_KW = dict(max_slots=4, max_len=64, prompt_buckets=[16, 32])
 
 
 @pytest.fixture(scope="module")
@@ -163,23 +161,16 @@ def test_ignore_eos_runs_to_length():
 PROMPTS = [[5, 9, 3], [17, 2, 40, 8, 21], [60], list(range(1, 14))]
 
 
-def _greedy_ref(srv_cls, params, prompt, n_new, **kw):
-    srv = srv_cls(params, CFG, GREEDY, **kw)
+def _greedy_ref(params, prompt, n_new, **kw):
+    srv = PagedInferenceServer(params, CFG, GREEDY, **kw)
     return srv.generate([prompt], max_new_tokens=n_new)[0]
 
 
-@pytest.mark.parametrize("server", ["paged", "contiguous"])
-def test_mixed_greedy_and_sampled_batch(params, server):
+def test_mixed_greedy_and_sampled_batch(params):
     """Greedy rows inside a sampled batch still match the pure-greedy
     reference (per-row temperature routing)."""
-    if server == "paged":
-        srv = PagedInferenceServer(params, CFG, SAMPLED, **PAGED_KW)
-        ref = _greedy_ref(PagedInferenceServer, params, PROMPTS[0], 8,
-                          **PAGED_KW)
-    else:
-        srv = InferenceServer(params, CFG, SAMPLED, **CONTIG_KW)
-        ref = _greedy_ref(InferenceServer, params, PROMPTS[0], 8,
-                          **CONTIG_KW)
+    srv = PagedInferenceServer(params, CFG, SAMPLED, **PAGED_KW)
+    ref = _greedy_ref(params, PROMPTS[0], 8, **PAGED_KW)
     r_greedy = srv.submit(PROMPTS[0], max_new_tokens=8,
                           sampling=SamplingParams(temperature=0.0))
     r_hot = srv.submit(PROMPTS[1], max_new_tokens=8,
@@ -189,17 +180,12 @@ def test_mixed_greedy_and_sampled_batch(params, server):
     assert len(r_hot.result()) == 8
 
 
-@pytest.mark.parametrize("server", ["paged", "contiguous"])
-def test_seed_reproducible_across_batch_compositions(params, server):
+def test_seed_reproducible_across_batch_compositions(params):
     """A seeded request's stream does not depend on its batch mates or
     slot placement."""
     def run(extra_first):
-        if server == "paged":
-            srv = PagedInferenceServer(params, CFG, SAMPLED, seed=123,
-                                       **PAGED_KW)
-        else:
-            srv = InferenceServer(params, CFG, SAMPLED, seed=123,
-                                  **CONTIG_KW)
+        srv = PagedInferenceServer(params, CFG, SAMPLED, seed=123,
+                                   **PAGED_KW)
         if extra_first:  # occupy slot 0 with an unrelated request
             srv.submit(PROMPTS[3], max_new_tokens=8,
                        sampling=SamplingParams(temperature=1.0, seed=999))
@@ -247,8 +233,7 @@ def test_spec_decoding_exact_with_penalties(params, spec_drafts):
 def test_spec_decoding_greedy_rows_parity(params):
     """Mixed rows batch through the speculative server: greedy rows keep
     exact parity with the non-speculative greedy reference."""
-    ref = _greedy_ref(PagedInferenceServer, params, PROMPTS[1], 10,
-                      **PAGED_KW)
+    ref = _greedy_ref(params, PROMPTS[1], 10, **PAGED_KW)
     srv = PagedInferenceServer(params, CFG, SAMPLED, spec_drafts=2,
                                **PAGED_KW)
     r0 = srv.submit(PROMPTS[1], max_new_tokens=10,

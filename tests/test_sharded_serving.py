@@ -10,7 +10,7 @@ import numpy as np
 
 from cloud_server_tpu.config import InferConfig, MeshConfig, ModelConfig
 from cloud_server_tpu.inference.engine import generate
-from cloud_server_tpu.inference.server import InferenceServer
+from cloud_server_tpu.inference.paged_server import PagedInferenceServer
 from cloud_server_tpu.models import transformer
 from cloud_server_tpu.parallel.mesh import make_mesh
 from cloud_server_tpu.parallel.sharding import logical_to_sharding
@@ -44,25 +44,6 @@ def test_engine_generate_tp_sharded_matches_single_device(devices8):
     np.testing.assert_array_equal(np.asarray(got), want)
 
 
-def test_server_tp_sharded_matches_single_device(devices8):
-    icfg = InferConfig(max_decode_len=8, temperature=0.0, eos_token_id=-1,
-                       pad_token_id=0)
-    prompts = [[3, 7, 11], [9, 1, 4, 8, 2]]
-
-    srv_plain = InferenceServer(
-        transformer.init_params(TINY, jax.random.key(0)), TINY, icfg,
-        max_slots=2, max_len=32)
-    want = srv_plain.generate(prompts, max_new_tokens=8)
-
-    mesh = make_mesh(MeshConfig(fsdp=2, tp=4))
-    params = _sharded_params(mesh)
-    srv = InferenceServer(params, TINY, icfg, max_slots=2, max_len=32)
-    got = srv.generate(prompts, max_new_tokens=8)
-    assert got == want
-
-
-# -- paged server ------------------------------------------------------------
-
 PAGED_KW = dict(max_slots=2, max_context=64, page_size=8, prefill_chunk=16,
                 prompt_buckets=[16])
 _ICFG = InferConfig(max_decode_len=8, temperature=0.0, eos_token_id=-1,
@@ -71,18 +52,26 @@ _PROMPTS = [[3, 7, 11], [9, 1, 4, 8, 2]]
 
 
 def _paged_single_device_reference(cfg=TINY, **kw):
-    from cloud_server_tpu.inference.paged_server import PagedInferenceServer
     srv = PagedInferenceServer(
         transformer.init_params(TINY, jax.random.key(0)), cfg, _ICFG,
         **PAGED_KW, **kw)
     return srv.generate(_PROMPTS, max_new_tokens=8)
 
 
+def test_server_tp_sharded_matches_single_device(devices8):
+    """Sharded params alone, no `mesh=` handed to the server: jit
+    propagates the params' NamedShardings through the dispatches."""
+    want = _paged_single_device_reference()
+    mesh = make_mesh(MeshConfig(fsdp=2, tp=4))
+    srv = PagedInferenceServer(_sharded_params(mesh), TINY, _ICFG,
+                               **PAGED_KW)
+    assert srv.generate(_PROMPTS, max_new_tokens=8) == want
+
+
 def test_paged_server_tp_sharded_matches_single_device(devices8):
     """tp/fsdp-sharded params through the PAGED server (XLA decode
     path): page pools shard on kv heads, outputs match single-device
     exactly — plain and speculative decode."""
-    from cloud_server_tpu.inference.paged_server import PagedInferenceServer
 
     want = _paged_single_device_reference()
     mesh = make_mesh(MeshConfig(fsdp=2, tp=4))
@@ -100,7 +89,6 @@ def test_paged_server_tp_pallas_kernel_matches(devices8):
     tp) matches the single-device kernel path exactly."""
     import dataclasses
 
-    from cloud_server_tpu.inference.paged_server import PagedInferenceServer
     cfg = dataclasses.replace(TINY, decode_attention_impl="pallas")
 
     want = _paged_single_device_reference(cfg=cfg)
@@ -117,7 +105,6 @@ def test_paged_kernel_tp_rejects_indivisible_heads(devices8):
 
     import pytest
 
-    from cloud_server_tpu.inference.paged_server import PagedInferenceServer
     cfg = dataclasses.replace(TINY, num_kv_heads=2, decode_attention_impl="pallas")
     mesh = make_mesh(MeshConfig(fsdp=2, tp=4))
     with pytest.raises(ValueError, match="num_kv_heads"):

@@ -13,7 +13,6 @@ import pytest
 from cloud_server_tpu.config import InferConfig, ModelConfig
 from cloud_server_tpu.inference.paged_server import PagedInferenceServer
 from cloud_server_tpu.inference.router import ReplicatedRouter
-from cloud_server_tpu.inference.server import InferenceServer
 from cloud_server_tpu.inference.slo import (
     SLOTracker, merge_reports, resolve_slo)
 from cloud_server_tpu.models import transformer
@@ -220,24 +219,19 @@ def test_class_mapping_from_qos_priority(params):
 
 
 def test_server_report_matches_hand_count(params):
-    """Both servers: N finished requests -> exactly N ttft/queue_wait/
-    e2e observations and (tokens-1)*N itl observations, all good under
+    """N finished requests -> exactly N ttft/queue_wait/e2e
+    observations and (tokens-1)*N itl observations, all good under
     generous targets."""
-    for make in (lambda: InferenceServer(params, CFG, GREEDY, max_slots=2,
-                                         max_len=64, prompt_buckets=[16],
-                                         slo=EASY),
-                 lambda: PagedInferenceServer(params, CFG, GREEDY,
-                                              slo=EASY, **PAGED_KW)):
-        srv = make()
-        for i in range(2):
-            srv.submit([5 + i, 9, 3], max_new_tokens=4)
-        srv.run_until_idle()
-        m = srv.slo_report()["classes"]["default"]["metrics"]
-        assert m["ttft"]["lifetime"] == {
-            "good": 2, "total": 2, "attainment": 1.0, "burn_rate": 0.0}
-        assert m["queue_wait"]["lifetime"]["total"] == 2
-        assert m["e2e"]["lifetime"]["total"] == 2
-        assert m["itl"]["lifetime"]["total"] == 6  # 3 gaps x 2 requests
+    srv = PagedInferenceServer(params, CFG, GREEDY, slo=EASY, **PAGED_KW)
+    for i in range(2):
+        srv.submit([5 + i, 9, 3], max_new_tokens=4)
+    srv.run_until_idle()
+    m = srv.slo_report()["classes"]["default"]["metrics"]
+    assert m["ttft"]["lifetime"] == {
+        "good": 2, "total": 2, "attainment": 1.0, "burn_rate": 0.0}
+    assert m["queue_wait"]["lifetime"]["total"] == 2
+    assert m["e2e"]["lifetime"]["total"] == 2
+    assert m["itl"]["lifetime"]["total"] == 6  # 3 gaps x 2 requests
 
 
 def test_slo_gauges_in_snapshot(params):
