@@ -77,18 +77,22 @@ def _mlp_apply(x, lp, cfg: ModelConfig, lora=None, stack=None):
     """Dense or MoE MLP residual block, chosen by cfg.num_experts.
 
     MoE routing at inference is per-call: prefill routes over the prompt
-    batch, each decode step over its B single tokens. Capacity therefore
-    differs from training's full-batch routing — exact parity with the
-    training forward holds only when nothing drops (generous
+    batch, each decode step over its B single tokens, and a mixed step
+    of the paged server that walks the layers once
+    (`paged_engine.forward_sets`) over its chunk tokens and its decode
+    rows together. Capacity therefore differs from training's full-batch
+    routing — exact parity with the training forward, and of one call
+    with two, holds only when nothing drops (generous
     expert_capacity_factor), which is also the sane serving configuration.
     At such a factor (experts / experts per token, or more) a call of
-    `moe.GROUPED_MIN_TOKENS` tokens or more, a prefill group, runs
-    `moe_mlp`'s sorted dispatch, a grouped matmul over T * k rows, if the
-    caller unrolls its layers and says so: `stack` is (params["layers"],
-    layer index), where the kernel finds the experts' weights without a
-    copy. A decode round, a short chunk, a factor that can drop, a scan
-    over the layers, quantized weights and any call under a mesh of
-    several devices run the dense one-hot dispatch over E * capacity rows
+    `moe.GROUPED_MIN_TOKENS` tokens or more, a prefill group with the
+    decode rows beside it, runs `moe_mlp`'s sorted dispatch, a grouped
+    matmul over T * k rows, if the caller unrolls its layers and says
+    so: `stack` is (params["layers"], layer index), where the kernel
+    finds the experts' weights without a copy. A decode round alone, a
+    short chunk, a factor that can drop, a scan over the layers,
+    quantized weights and any call under a mesh of several devices run
+    the dense one-hot dispatch over E * capacity rows
     (`moe._dispatch_grouped`).
 
     `lora`: per-row multi-adapter deltas (dense MLP only; the server

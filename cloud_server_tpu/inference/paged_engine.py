@@ -7,6 +7,10 @@ actually resident (not max_slots x max_len) and pages can be SHARED
 between slots (refcounted prefix reuse — inference/block_allocator.py).
 
 Everything the paged server dispatches is one primitive,
+`forward_sets`, a walk of the layer stack over one or more row sets that
+share the pool; a mixed step hands it its prefill group and its decode
+round together where that computes the same function, so that a layer's
+weights are read once for both. Its one-set case is
 `window_forward(tokens (B, W))`: embed W new positions per slot at
 absolute positions [lengths, lengths + W), write their kv into the pool
 through the page table, and attend each window row against the slot's
@@ -40,6 +44,7 @@ dispatching the full slot batch while some slots are empty.
 
 from __future__ import annotations
 
+import contextlib
 from typing import NamedTuple
 
 import jax
@@ -200,13 +205,171 @@ def _write_window(cache: PagedKVCache, layer: int, k, v, pos):
 PALLAS_MAX_W = 256
 
 
+class RowSet(NamedTuple):
+    """One set of rows of a forward (`forward_sets`): the tokens, where
+    each row stands in the pools, and what is wanted back."""
+
+    tokens: jnp.ndarray    # (B, W) int32, positions [lengths, lengths + W)
+    lengths: jnp.ndarray   # (B,) int32: committed kv entries per row
+    tables: jnp.ndarray    # (B, max_pages_per_slot) int32
+    widths: jnp.ndarray | None = None     # (B,) valid widths, ragged rows
+    logits_at: jnp.ndarray | None = None  # (B,) in-window index to unembed
+    scope: str | None = None  # names the set's own ops in a device trace
+
+
+def _side_by_side(parts):
+    """Per-set (B, W, ...) arrays laid out as one row of tokens
+    (1, sum(B * W), ...); one set stays as it is."""
+    if len(parts) == 1:
+        return parts[0]
+    return jnp.concatenate(
+        [p.reshape((1, -1) + p.shape[2:]) for p in parts], axis=1)
+
+
+def _part(y, sets, i: int):
+    """Set i's (B, W, ...) rows of an array laid out by `_side_by_side`."""
+    if len(sets) == 1:
+        return y
+    at = sum(s.tokens.size for s in sets[:i])
+    shape = sets[i].tokens.shape
+    return y[0, at:at + shape[0] * shape[1]].reshape(shape + y.shape[2:])
+
+
+def _scope(name: str | None):
+    return (contextlib.nullcontext() if name is None
+            else jax.named_scope(name))
+
+
+def forward_sets(params, cfg: ModelConfig, cache: PagedKVCache,
+                 sets: list[RowSet], *, all_logits: bool = False,
+                 pages_per_block: int | None = None,
+                 mesh=None, tp_axis: str = "tp", lora=None, aid=None):
+    """One walk of the layer stack over one or more row sets that share
+    `cache`'s pools (its own `lengths` and `tables` are not read: every
+    set brings its rows').
+
+    Per layer, everything that is per token (the norms, the q/k/v and
+    output projections, the MLP or the experts) runs ONCE over all
+    sets' tokens laid side by side, so a layer's weights are read once
+    whatever the number of sets; the cache write and the paged kernel
+    run once per set, each at its own window width (a decode row as a
+    width-1 row of a 256-wide window would waste the wide kernel). The
+    sets' rows are disjoint slots, so the order of their writes does
+    not matter. With more than one set the shared work lies under the
+    scope `joined_walk` and each set's own (cache write, kernel,
+    unembed) under its `scope`; one set adds no scope of its own and is
+    exactly `window_forward`.
+
+    Joining is the caller's decision: the result equals separate walks
+    only where a token's MLP output does not depend on which other
+    tokens share the call (a dense MLP, or experts at a capacity that
+    cannot overflow), and per-row `lora` deltas need rows, so they come
+    with one set only.
+
+    Returns ([logits per set], cache'): per set (B, V) f32 at its
+    `logits_at`, (B, W, V) with `all_logits`, None with neither.
+    `cache'` has every window written and keeps `cache`'s lengths and
+    tables.
+    """
+    joined = len(sets) > 1
+    if joined and lora is not None:
+        raise ValueError("per-row lora deltas need one row set")
+    use_pallas = cfg.decode_attention_impl == "pallas"
+    shared = "joined_walk" if joined else None
+    rows = []  # per set: positions, write positions, lengths after, block
+    for s in sets:
+        w = s.tokens.shape[1]
+        if use_pallas and w > PALLAS_MAX_W:
+            raise ValueError(
+                f"window width {w} exceeds the pallas paged-attention cap "
+                f"({PALLAS_MAX_W}); use a narrower window or "
+                "decode_attention_impl='xla'")
+        with _scope(s.scope):
+            ar = jnp.arange(w, dtype=jnp.int32)[None, :]
+            pos = s.lengths[:, None] + ar
+            # ragged rows: positions past a row's width write nowhere (pos
+            # -1 never matches a page slot in _write_window, so the page
+            # merge is an identity rewrite of the row's own private pages —
+            # shared pages are never touched because writes start at
+            # lengths >= private start)
+            wpos = pos if s.widths is None else jnp.where(
+                ar < s.widths[:, None], pos, -1)
+            lens_after = s.lengths + (w if s.widths is None else s.widths)
+        # wider windows leave less VMEM for the double-buffered page
+        # blocks; 8 pages measured fastest at W=1 on v5e
+        rows.append((pos, wpos, lens_after,
+                     pages_per_block if pages_per_block is not None
+                     else 8 if w <= 8 else 4))
+    with _scope(shared):
+        cos, sin = rope_table(cfg, cache.max_context)
+        embed = params["embed"]["tokens"].astype(cfg.dtype)
+        x = _side_by_side([embed[s.tokens] for s in sets])  # (B, W, D)
+        pos_all = _side_by_side([r[0] for r in rows])
+    pools = cache
+
+    for layer_idx in range(cfg.num_layers):
+        lp = jax.tree.map(lambda p: p[layer_idx], params["layers"])
+        ll = (None if lora is None
+              else multi_lora.layer_lora(lora, aid, layer_idx))
+        with _scope(shared), jax.named_scope("attn"):
+            qkv = transformer.attention_qkv(x, lp, cfg, cos, sin, pos_all,
+                                            lora=ll)
+        outs = []
+        for i, (s, (_, wpos, lens_after, ppb)) in enumerate(zip(sets, rows)):
+            with _scope(s.scope), jax.named_scope("attn"):
+                q, k, v = (_part(y, sets, i) for y in qkv)
+                pools = _write_window(
+                    pools._replace(lengths=s.lengths, tables=s.tables),
+                    layer_idx, k, v, wpos)
+                kw = dict(k_scale_pool=pools.k_scale,
+                          v_scale_pool=pools.v_scale, widths=s.widths)
+                if not use_pallas:
+                    o = paged_attention_xla(
+                        q, pools.k, pools.v, lens_after, s.tables,
+                        layer_idx, **kw)
+                elif mesh is not None and mesh.shape.get(tp_axis, 1) > 1:
+                    o = paged_attention_tp(
+                        q, pools.k, pools.v, lens_after, s.tables,
+                        layer_idx, mesh=mesh, axis_name=tp_axis,
+                        pages_per_block=ppb, **kw)
+                else:
+                    o = paged_attention(
+                        q, pools.k, pools.v, lens_after, s.tables,
+                        layer_idx, pages_per_block=ppb, **kw)
+            outs.append(o)
+        with _scope(shared):
+            with jax.named_scope("attn"):
+                x = transformer.attention_out(x, _side_by_side(outs), lp,
+                                              cfg, lora=ll)
+            x = _mlp_apply(x, lp, cfg, lora=ll,
+                           stack=(params["layers"], layer_idx))
+
+    logits = []
+    for i, s in enumerate(sets):
+        with _scope(s.scope), jax.named_scope("unembed"):
+            b, w = s.tokens.shape
+            xs = rms_norm(_part(x, sets, i), params["final_norm"]["scale"],
+                          cfg.norm_eps)
+            if all_logits:
+                logits.append(transformer.unembed(xs, params, cfg))
+            elif s.logits_at is not None:
+                # (B, D)
+                x_sel = xs[jnp.arange(b), jnp.clip(s.logits_at, 0, w - 1)]
+                logits.append(transformer.unembed(x_sel, params, cfg))
+            else:
+                logits.append(None)
+    return logits, pools._replace(lengths=cache.lengths,
+                                  tables=cache.tables)
+
+
 def window_forward(params, tokens: jnp.ndarray, cfg: ModelConfig,
                    cache: PagedKVCache, *, logits_at: jnp.ndarray | None,
                    all_logits: bool = False,
                    pages_per_block: int | None = None,
                    mesh=None, tp_axis: str = "tp",
                    lora=None, aid=None, widths: jnp.ndarray | None = None):
-    """Forward W new positions per slot against the paged cache.
+    """Forward W new positions per slot against the paged cache: the
+    one-set case of `forward_sets`.
 
     Args:
       tokens: (B, W) int32 — slot b's tokens for absolute positions
@@ -238,69 +401,12 @@ def window_forward(params, tokens: jnp.ndarray, cfg: ModelConfig,
     Returns (logits, cache') — cache' has the window written but lengths
     UNCHANGED (see module docstring).
     """
-    b, w = tokens.shape
-    pos = cache.lengths[:, None] + jnp.arange(w, dtype=jnp.int32)[None, :]
-    # ragged rows: positions past a row's width write nowhere (pos -1
-    # never matches a page slot in _write_window, so the page merge is an
-    # identity rewrite of the row's own private pages — shared pages are
-    # never touched because writes start at lengths >= private start)
-    wpos = pos if widths is None else jnp.where(
-        jnp.arange(w, dtype=jnp.int32)[None, :] < widths[:, None], pos, -1)
-    cos, sin = rope_table(cfg, cache.max_context)
-    x = params["embed"]["tokens"].astype(cfg.dtype)[tokens]  # (B, W, D)
-
-    use_pallas = cfg.decode_attention_impl == "pallas"
-    if use_pallas and w > PALLAS_MAX_W:
-        raise ValueError(
-            f"window width {w} exceeds the pallas paged-attention cap "
-            f"({PALLAS_MAX_W}); use a narrower window or "
-            "decode_attention_impl='xla'")
-    if pages_per_block is None:
-        # wider windows leave less VMEM for the double-buffered page
-        # blocks; 8 pages measured fastest at W=1 on v5e
-        pages_per_block = 8 if w <= 8 else 4
-    lens_after = cache.lengths + (w if widths is None else widths)
-
-    for layer_idx in range(cfg.num_layers):
-        lp = jax.tree.map(lambda p: p[layer_idx], params["layers"])
-        ll = (None if lora is None
-              else multi_lora.layer_lora(lora, aid, layer_idx))
-        with jax.named_scope("attn"):
-            q, k, v = transformer.attention_qkv(x, lp, cfg, cos, sin, pos,
-                                                lora=ll)
-            cache = _write_window(cache, layer_idx, k, v, wpos)
-            if use_pallas:
-                if mesh is not None and mesh.shape.get(tp_axis, 1) > 1:
-                    o = paged_attention_tp(
-                        q, cache.k, cache.v, lens_after, cache.tables,
-                        layer_idx, mesh=mesh, axis_name=tp_axis,
-                        pages_per_block=pages_per_block,
-                        k_scale_pool=cache.k_scale, v_scale_pool=cache.v_scale,
-                        widths=widths)
-                else:
-                    o = paged_attention(
-                        q, cache.k, cache.v, lens_after, cache.tables,
-                        layer_idx, pages_per_block=pages_per_block,
-                        k_scale_pool=cache.k_scale, v_scale_pool=cache.v_scale,
-                        widths=widths)
-            else:
-                o = paged_attention_xla(
-                    q, cache.k, cache.v, lens_after, cache.tables, layer_idx,
-                    k_scale_pool=cache.k_scale, v_scale_pool=cache.v_scale,
-                    widths=widths)
-            x = transformer.attention_out(x, o, lp, cfg, lora=ll)
-        x = _mlp_apply(x, lp, cfg, lora=ll,
-                       stack=(params["layers"], layer_idx))
-
-    with jax.named_scope("unembed"):
-        x = rms_norm(x, params["final_norm"]["scale"], cfg.norm_eps)
-        if all_logits:
-            return transformer.unembed(x, params, cfg), cache
-        if logits_at is not None:
-            # (B, D)
-            x_sel = x[jnp.arange(b), jnp.clip(logits_at, 0, w - 1)]
-            return transformer.unembed(x_sel, params, cfg), cache
-    return None, cache
+    (logits,), cache = forward_sets(
+        params, cfg, cache,
+        [RowSet(tokens, cache.lengths, cache.tables, widths, logits_at)],
+        all_logits=all_logits, pages_per_block=pages_per_block, mesh=mesh,
+        tp_axis=tp_axis, lora=lora, aid=aid)
+    return logits, cache
 
 
 def _token_logprobs(logits: jnp.ndarray, toks: jnp.ndarray) -> jnp.ndarray:

@@ -148,6 +148,7 @@ from cloud_server_tpu.inference.spec_control import resolve_controller
 from cloud_server_tpu.inference.speculative import (
     _TAG_DRAFT, _accept_drafts, _accept_point_mass, _ngram_drafts,
     _row_pos_keys, sample_from_probs_keyed)
+from cloud_server_tpu.models import moe
 from cloud_server_tpu.utils.serving_metrics import (
     FlightRecorder, ServingMetrics)
 from cloud_server_tpu.utils.tracing import _StepTracer
@@ -273,7 +274,8 @@ def _prefill_core(params, state, chunk, g_lens, g_tables, sample_at,
                   samp_rows, orig_lens, count_mask,
                   gid=None, gstate0=None, grammar=None,
                   lora=None, aid=None,
-                  draft_params=None, widths=None, scatter_mask=None, *,
+                  draft_params=None, widths=None, scatter_mask=None,
+                  logits=None, *,
                   cfg: ModelConfig, infer_cfg: InferConfig,
                   scatter_prompt: bool, mesh=None, draft_cfg=None,
                   use_rows: bool = False, use_bias: bool = False):
@@ -302,14 +304,20 @@ def _prefill_core(params, state, chunk, g_lens, g_tables, sample_at,
     `samp_rows` always lands in the slots' row state; `use_rows`
     (static) additionally samples the first token through it.
 
+    `logits` (G, V): the window's forward is the caller's (`_mixed_step`
+    where it walks the layers once for both halves): `state["pools"]`
+    already holds the window's writes, and everything after the forward
+    runs here as it always does.
+
     Returns (state', first-token candidates (G,), their logprobs (G,)).
     """
-    cache = _make_cache(state["pools"], g_lens, g_tables)
-    logits, cache = paged_engine.window_forward(
-        params, chunk, cfg, cache, logits_at=sample_at, mesh=mesh,
-        lora=lora, aid=aid, widths=widths)
     new_state = dict(state)
-    new_state["pools"] = _split_cache(cache)
+    if logits is None:
+        cache = _make_cache(state["pools"], g_lens, g_tables)
+        logits, cache = paged_engine.window_forward(
+            params, chunk, cfg, cache, logits_at=sample_at, mesh=mesh,
+            lora=lora, aid=aid, widths=widths)
+        new_state["pools"] = _split_cache(cache)
 
     has_pen = "prompt_mask" in state  # buffers materialize lazily
     pm = oc = None
@@ -418,7 +426,7 @@ _prefill_chunk = partial(jax.jit,
 @jax.named_scope("decode_rounds")
 def _decode_plain_core(params, state, lengths, tables, last_token, live,
                        rng, samp_rows, gid=None, grammar=None,
-                       lora=None, aid=None, slot_ids=None, *,
+                       lora=None, aid=None, slot_ids=None, logits=None, *,
                        cfg: ModelConfig,
                        infer_cfg: InferConfig, n_rounds: int, mesh=None,
                        use_rows: bool = False, use_bias: bool = False):
@@ -440,6 +448,9 @@ def _decode_plain_core(params, state, lengths, tables, last_token, live,
     what keeps decode affordable while admissions hold slots.
     slot_ids=None means rows ARE slots (the uncompacted layout).
 
+    `logits` (Bg, V): the one round's forward is the caller's, as in
+    `_prefill_core` (n_rounds == 1; `state["pools"]` holds its writes).
+
     Returns (state', lengths', last', (toks (R, Bg), lps (R, Bg),
     counts (R, Bg) int32)) — rows in the caller's gathered order.
     """
@@ -455,11 +466,15 @@ def _decode_plain_core(params, state, lengths, tables, last_token, live,
         # drafting/multi-turn reads see an unbroken token sequence
         cols = jnp.where(live, lengths, hist.shape[1])
         hist = hist.at[sids, cols].set(last, mode="drop")
-        cache = _make_cache(pools, lengths, tables)
-        logits, cache = paged_engine.window_forward(
-            params, last[:, None], cfg, cache,
-            logits_at=jnp.zeros_like(lengths), mesh=mesh,
-            lora=lora, aid=aid)
+        if logits is None:
+            cache = _make_cache(pools, lengths, tables)
+            round_logits, cache = paged_engine.window_forward(
+                params, last[:, None], cfg, cache,
+                logits_at=jnp.zeros_like(lengths), mesh=mesh,
+                lora=lora, aid=aid)
+            pools = _split_cache(cache)
+        else:
+            round_logits = logits
         amask = None
         if grammar is not None:
             nrow, amask = _grammar_mask(grammar, gid, gstate,
@@ -469,7 +484,8 @@ def _decode_plain_core(params, state, lengths, tables, last_token, live,
                 # the sampled token sits at position lengths + 1 (`last`
                 # occupies `lengths`); the admission chunk folds the prompt
                 # length, so positions never collide within a request
-                tok = sample_logits_rows(logits, samp_rows, lengths + 1,
+                tok = sample_logits_rows(round_logits, samp_rows,
+                                         lengths + 1,
                                          prompt_mask=pm, out_counts=oc,
                                          eos_id=infer_cfg.eos_token_id,
                                          use_bias=use_bias,
@@ -477,18 +493,18 @@ def _decode_plain_core(params, state, lengths, tables, last_token, live,
                 if oc is not None:
                     oc = oc.at[batch_idx, tok].add(live.astype(jnp.int32))
             else:
-                tok = sample_logits(logits, rng_t, infer_cfg)
+                tok = sample_logits(round_logits, rng_t, infer_cfg)
         if grammar is not None:
             # sticky DEAD: a dead row (post-EOS scan tail) must never
             # resurrect through the max(st, 0) clamp
             gstate = jnp.where(live & (gstate != _GDEAD),
                                nrow[batch_idx, tok], gstate)
         with jax.named_scope("sample"):
-            lp = _token_logprobs(logits, tok)
+            lp = _token_logprobs(round_logits, tok)
         tok = jnp.where(live, tok, pad)
         new_len = jnp.where(live, lengths + 1, lengths)
         last = jnp.where(live, tok, last)
-        return ((new_len, last, hist, _split_cache(cache), oc, gstate),
+        return ((new_len, last, hist, pools, oc, gstate),
                 (tok, lp, live.astype(jnp.int32)))
 
     (lengths, last, hist, pools, oc, gstate), out = lax.scan(
@@ -760,6 +776,29 @@ _spec_rounds = partial(jax.jit,
                        donate_argnums=(1,))(_spec_core)
 
 
+def _walks_once(cfg: ModelConfig, n_tokens: int, n_rounds: int,
+                n_drafts: int, draft_cfg, lora) -> bool:
+    """Whether `_mixed_step` walks the layer stack once for both of its
+    halves (`paged_engine.forward_sets`), from what the call can observe,
+    all of it static: the one walk computes what the two compute where
+
+      * the decode half is one plain round: further rounds are a scan
+        over the cache the first one wrote, `_spec_core` verifies
+        windows of drafts, and a draft model walks layers of its own;
+      * a token's MLP output does not depend on which other tokens
+        share the call: a dense MLP, or experts at a capacity under
+        which none of the `n_tokens` (chunk and decode together) can
+        overflow, the test `moe._dispatch_grouped` makes. A capacity
+        that can drop would drop other tokens in one call than in two;
+      * no adapter is live: per-row low-rank deltas need rows, and the
+        one walk lays every token in one row.
+    """
+    return (n_rounds == 1 and n_drafts == 0 and draft_cfg is None
+            and lora is None
+            and (cfg.num_experts < 2
+                 or moe._capacity(cfg, n_tokens) >= n_tokens))
+
+
 @partial(jax.jit,
          static_argnames=("cfg", "infer_cfg", "n_rounds", "n_drafts",
                           "scatter_prompt", "mesh", "draft_cfg",
@@ -801,13 +840,24 @@ def _mixed_step(params, state,
     shrinks decode to `admit_decode_chunk` (default 1) rounds while any
     admission is in flight; the mixed program keeps decode at its full
     round count and retires every prefill chunk in the same dispatch, so
-    decode throughput under churn stays at its steady-state slope. Both
-    halves are exactly the alternating dispatches' traced bodies —
-    greedy/seeded outputs are token-for-token identical by construction
+    decode throughput under churn stays at its steady-state slope.
+
+    ONE WALK OF THE LAYERS where that computes the same function
+    (`_walks_once`: one plain decode round, no drafts, no draft model,
+    no live adapter, an MLP under which no token can be dropped): the
+    chunk tokens and the decode round's rows meet every layer's weights
+    in one call (`paged_engine.forward_sets`), so a step streams them
+    once, not twice; each half keeps its own cache write, paged kernel,
+    unembed and sampler, and everything of the two cores around their
+    forward runs as it is (they are handed the logits). Elsewhere the
+    halves are exactly the alternating dispatches' traced bodies, one
+    walk each. Greedy/seeded outputs are token-for-token identical to
+    the alternating scheduler's either way
     (tests/test_mixed_scheduler.py).
 
     Prefill rows and decode rows are DISJOINT slots (a slot is live xor
-    mid-admission), so program order between the halves is irrelevant;
+    mid-admission), so program order between the halves is irrelevant,
+    as is the order of their cache writes inside the one walk;
     slots in neither half ride along fully inert (width 0 and sentinel
     tables in the prefill group, live=False and sentinel tables in the
     decode half) — the sentinel-safety invariant for mid-admission rows.
@@ -818,11 +868,23 @@ def _mixed_step(params, state,
     decode half and returns R = 0 outputs.
     """
     rng_p, rng_d = jax.random.split(rng)
+    plogits = dlogits = None
+    if _walks_once(cfg, chunk.size + lengths.size, n_rounds, n_drafts,
+                   draft_cfg, lora):
+        (plogits, dlogits), cache = paged_engine.forward_sets(
+            params, cfg, _make_cache(state["pools"], g_lens, g_tables),
+            [paged_engine.RowSet(chunk, g_lens, g_tables, widths,
+                                 sample_at, "prefill_group"),
+             paged_engine.RowSet(last_token[:, None], lengths, tables,
+                                 None, jnp.zeros_like(lengths),
+                                 "decode_rounds")],
+            mesh=mesh)
+        state = {**state, "pools": _split_cache(cache)}
     state, ptoks, plps = _prefill_core(
         params, state, chunk, g_lens, g_tables, sample_at, slot_ids,
         prompt_rows, prompt_lens, rng_p, samp_rows_g, orig_lens,
         count_mask, gid_g, gstate0_g, grammar, lora, aid_g,
-        draft_params, widths, scatter_mask,
+        draft_params, widths, scatter_mask, plogits,
         cfg=cfg, infer_cfg=infer_cfg, scatter_prompt=scatter_prompt,
         mesh=mesh, draft_cfg=draft_cfg, use_rows=use_rows_p,
         use_bias=use_bias_p)
@@ -844,7 +906,7 @@ def _mixed_step(params, state,
     else:
         state, lengths, last, (dtoks, dlps, dcnts) = _decode_plain_core(
             params, state, lengths, tables, last_token, live, rng_d,
-            samp_rows_b, gid_b, grammar, lora, aid_b, slot_ids_d,
+            samp_rows_b, gid_b, grammar, lora, aid_b, slot_ids_d, dlogits,
             cfg=cfg, infer_cfg=infer_cfg, n_rounds=n_rounds, mesh=mesh,
             use_rows=use_rows_d, use_bias=use_bias_d)
         out = (dtoks[:, :, None], dlps[:, :, None], dcnts)
@@ -2960,6 +3022,10 @@ class PagedInferenceServer:
         # BEFORE the dispatch donates self.state (overlaps the final
         # prefill chunk)
         self._handoff_prefetch(sel)
+        lora = self.adapters.device_args() if use_lora else None
+        self._iter_stats["joined"] = _walks_once(
+            self.cfg, pf["chunk"].size + live_g.size, n_rounds, g_iter,
+            self.draft_cfg, lora)
         self.state, ptoks, plps, lens, last, (toks, lps, counts) = \
             _mixed_step(
                 self.params, self.state, jnp.asarray(pf["chunk"]),
@@ -2982,8 +3048,7 @@ class PagedInferenceServer:
                 self._next_rng(),
                 # analysis: allow[lock-discipline] atomically-swapped
                 # reference, rebuilt under _lock pre-admission
-                self._grammar_dev if use_grammar else None,
-                self.adapters.device_args() if use_lora else None,
+                self._grammar_dev if use_grammar else None, lora,
                 jnp.asarray(pf["aid_g"]), jnp.asarray(aid_d),
                 self.draft_params,
                 cfg=self.cfg, infer_cfg=self.infer_cfg,
@@ -3319,6 +3384,9 @@ class PagedInferenceServer:
             # committed ones — start the D2H copies for admissions the
             # plan completes, before the dispatch donates self.state
             self._handoff_prefetch(plan.sel)
+            plan.stats["joined"] = _walks_once(
+                self.cfg, pf["chunk"].size + plan.live_g.size,
+                plan.n_rounds, plan.g_iter, self.draft_cfg, lora)
             self.state, ptoks, plps, lens, last, (toks, lps, counts) = \
                 _mixed_step(
                     self.params, self.state, jnp.asarray(pf["chunk"]),
@@ -3807,6 +3875,10 @@ class PagedInferenceServer:
                 for k, v in self.qos.fair_shares().items()}
         st["n_jobs"] = len(self._jobs)
         st["pending"] = self.num_pending
+        # whether the program walked the layers once for a prefill group
+        # and the decode round together (`_walks_once`); a step program
+        # of one half alone has nothing to join
+        st.setdefault("joined", False)
         # KV-pool telemetry (joins phases_ms in the record): the
         # iteration's page flow (deltas against the step-start
         # baseline — sweep/admission included) and the occupancy split

@@ -53,9 +53,11 @@ Params = dict
 # ---------------------------------------------------------------------------
 
 # The fewest tokens of a call that the sorted dispatch takes. Placed by the
-# v5e measurement of PERF.md (PR 26): below it the experts' weight stream
-# bounds both dispatches and the dense one has no sort and no gathers.
-GROUPED_MIN_TOKENS = 512
+# v5e measurements of PERF.md (PR 26, PR 32): below it the experts' weight
+# stream bounds both dispatches and the dense one has no sort and no
+# gathers. A whole layer at Mixtral's widths, dense and sorted: 320 tokens
+# 5.01 and 5.26 ms, 384 tokens 6.17 and 5.27.
+GROUPED_MIN_TOKENS = 384
 
 
 def _capacity(cfg: ModelConfig, num_tokens: int) -> int:
@@ -169,14 +171,22 @@ def top_k_routing(router_logits: jnp.ndarray, k: int, capacity: int):
 # both, so fewer rows waste less; a weight tile is fetched once per row
 # tile unless it spans the contraction (as 4,096 does on the way in: an
 # expert's consecutive row tiles then reuse it), so more rows fetch less.
-_GMM_TILING_IN = (256, 4096, 512)
-_GMM_TILING_OUT = (256, 1024, 2048)
+# `_GMM_ROWS` is both tilings' row tile. The sorted rows are gathered out
+# to a whole number of them (`_moe_grouped`): rows past the last group
+# belong to no expert, are not computed and are never gathered back. A
+# mixed step's one walk brings 2 * (chunk tokens + decode rows) of them,
+# never whole tiles: padding each matmul's operand instead copied the
+# (rows, F) activation once a layer.
+_GMM_ROWS = 256
+_GMM_TILING_IN = (_GMM_ROWS, 4096, 512)
+_GMM_TILING_OUT = (_GMM_ROWS, 1024, 2048)
 
 
 def _grouped_matmul(lhs, rhs, group_sizes, tiling, kernel: bool):
-    """lhs (M, K) rows sorted by group, rhs (G, K, N), group_sizes (G,)
-    int32 -> (M, N) in lhs.dtype, accumulated in float32: row r of group g
-    is lhs[r] @ rhs[g], and an empty group's weights are not read. The
+    """lhs (M, K) rows sorted by group, M whole row tiles, rhs (G, K, N),
+    group_sizes (G,) int32 -> (M, N) in lhs.dtype, accumulated in float32:
+    row r of group g is lhs[r] @ rhs[g], an empty group's weights are not
+    read, and a row past the last group is not computed. The
     megablox Pallas kernel on the TPU, `lax.ragged_dot` elsewhere (XLA:TPU's
     own lowering of it is the same kernel at a (512, 512, 512) tiling that
     a caller cannot choose)."""
@@ -185,19 +195,15 @@ def _grouped_matmul(lhs, rhs, group_sizes, tiling, kernel: bool):
             lhs, rhs, group_sizes,
             preferred_element_type=jnp.float32).astype(lhs.dtype)
     from jax.experimental.pallas.ops.tpu.megablox import gmm
-    m, k = lhs.shape
-    tm, tk, tn = tiling
-    pad = -m % tm  # the kernel wants whole row tiles; no group owns the rest
-    if pad:
-        lhs = jnp.pad(lhs, ((0, pad), (0, 0)))
+    k = lhs.shape[1]
+    tm, tk, tn = tiling  # lhs is whole row tiles: `_moe_grouped`
     # dtype-determined precision, as this repo's own kernels have it: a
     # global "highest" would ask Mosaic for an fp32 contraction of bf16
     # tiles, which it refuses ("Bad lhs type")
     with jax.default_matmul_precision(
             "default" if lhs.dtype == jnp.bfloat16 else "highest"):
-        out = gmm(lhs, rhs, group_sizes, preferred_element_type=lhs.dtype,
-                  tiling=(tm, min(tk, k), min(tn, rhs.shape[2])))
-    return out[:m] if pad else out
+        return gmm(lhs, rhs, group_sizes, preferred_element_type=lhs.dtype,
+                   tiling=(tm, min(tk, k), min(tn, rhs.shape[2])))
 
 
 # jitted so that its trace (three Pallas kernels on the TPU) is cached by
@@ -229,7 +235,8 @@ def _moe_grouped(tokens, router_logits, layers, layer, cfg: ModelConfig):
         # rows keep token order
         expert_of = gate_idx.reshape(t * k)
         order = jnp.argsort(expert_of, stable=True)
-        rows = tokens[order // k]  # (T*k, D)
+        # (T*k, D) and the rest of the last row tile
+        rows = tokens[jnp.pad(order // k, (0, -(t * k) % _GMM_ROWS))]
         # the stack seen as L * E groups, every other layer's empty: the
         # kernel then reads this layer's experts where they lie
         group_sizes = jnp.zeros((n_layers * e,), jnp.int32).at[

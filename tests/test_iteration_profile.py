@@ -260,6 +260,32 @@ def test_overlapped_records_carry_the_delivery(params):
     assert "deliver" in OVERLAP_PHASES and "deliver" in PHASES
 
 
+@pytest.mark.parametrize("overlap", [True, False])
+def test_records_say_which_programs_walked_the_layers_once(params, overlap):
+    """`joined`: true on the records of mixed steps whose program took
+    the one walk (one plain decode round beside a prefill group), false
+    on decode-only programs and on steps of several rounds, and on
+    every record, whichever way its step was launched."""
+    def churn(**kw):
+        srv = PagedInferenceServer(params, CFG, GREEDY, scheduler="mixed",
+                                   overlap=overlap, **PAGED_KW, **kw)
+        assert all(r.done for r in _churn(srv))
+        return srv.flight_window()
+
+    window = churn(decode_chunk=1)
+    assert all(isinstance(rec["joined"], bool) for rec in window)
+    mixed = [rec for rec in window
+             if rec.get("prefill_tokens") and rec["decode_rounds"]]
+    assert mixed and all(rec["joined"] for rec in mixed)
+    alone = [rec for rec in window if rec not in mixed]
+    assert alone and not any(rec["joined"] for rec in alone)
+    # the default eight rounds a step keep the two walks while the
+    # requests have tokens enough left to run more than one
+    for rec in churn():
+        assert rec["joined"] == (rec.get("prefill_tokens", 0) > 0
+                                 and rec["decode_rounds"] == 1)
+
+
 def test_alternating_scheduler_phase_split(params):
     srv = PagedInferenceServer(params, CFG, GREEDY,
                                scheduler="alternating", **PAGED_KW)

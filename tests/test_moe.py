@@ -261,8 +261,11 @@ def test_paged_prefill_takes_the_sorted_dispatch_and_agrees(monkeypatch):
         return np.asarray(out)
 
     grouped = logits(32)
-    assert calls == [(2 * 16 * cfg.num_experts_per_token, cfg.embed_dim)
-                     ] * cfg.num_layers
+    # the T * k sorted rows, gathered out to whole row tiles
+    n_rows = 2 * 16 * cfg.num_experts_per_token
+    assert n_rows % moe._GMM_ROWS
+    assert calls == [(-(-n_rows // moe._GMM_ROWS) * moe._GMM_ROWS,
+                      cfg.embed_dim)] * cfg.num_layers
     dense = logits(33)
     assert len(calls) == cfg.num_layers
     np.testing.assert_allclose(grouped, dense, atol=2e-5, rtol=1e-5)

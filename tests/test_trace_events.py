@@ -138,12 +138,17 @@ def test_capture_keeps_annotations_and_drops_the_python_tracer(tmp_path):
     assert n_default > n_ours + 100
 
 
-def test_mixed_step_ops_carry_the_scope_names(monkeypatch):
-    """The HLO of a tiny MoE `_mixed_step` names its two halves and the
-    expert einsums in its `op_name`s (metadata only)."""
+# what the step's decode half runs: the default eight rounds keep the
+# two walks of the layers, one round a step joins them
+@pytest.mark.parametrize("decode_chunk", [8, 1])
+def test_mixed_step_ops_carry_the_scope_names(monkeypatch, decode_chunk):
+    """The HLO of a tiny MoE `_mixed_step` names its two halves, the
+    walk they share where they share it, and the expert einsums in its
+    `op_name`s (metadata only)."""
     params = moe.init_params(MOE_CFG, jax.random.key(0))
     srv = PagedInferenceServer(params, MOE_CFG, GREEDY, scheduler="mixed",
-                               overlap=False, **PAGED_KW)
+                               overlap=False, decode_chunk=decode_chunk,
+                               **PAGED_KW)
     texts = []
     orig = ps._mixed_step
 
@@ -160,17 +165,27 @@ def test_mixed_step_ops_carry_the_scope_names(monkeypatch):
     assert first.done and texts
     names = [ln.split('op_name="', 1)[1].split('"', 1)[0]
              for ln in texts[0].splitlines() if 'op_name="' in ln]
-    for scope in ("prefill_group/attn/", "prefill_group/moe_route/",
-                  "prefill_group/moe_experts/", "prefill_group/sample/",
+    joined = decode_chunk == 1
+    # the weights' side of a layer, once for both halves or once in each
+    shared = "joined_walk/" if joined else "prefill_group/"
+    for scope in (shared + "attn/", shared + "moe_route/",
+                  shared + "moe_experts/", "prefill_group/attn/",
+                  "prefill_group/unembed/", "prefill_group/sample/",
                   "decode_rounds/", "/attn/", "/moe_dispatch/",
                   "/moe_experts/", "/moe_combine/", "/unembed/",
                   "/sample/"):
         assert any(scope in n for n in names), scope
+    assert any("joined_walk/" in n for n in names) == joined
     decode = [n for n in names if "decode_rounds/" in n]
-    assert any("/moe_experts/" in n for n in decode)
-    # every op of the program itself lies in one of the halves (the
-    # reducers XLA's CPU backend names `reduce_sum` and the like are
-    # not ops of the traced function)
+    # a half's own experts only where it walks the layers itself; its
+    # cache write, kernel, unembed and sampler always
+    assert any("/moe_experts/" in n for n in decode) != joined
+    for scope in ("/attn/", "/unembed/", "/sample/"):
+        assert any(scope in n for n in decode), scope
+    # every op of the program itself lies in one of the halves or in
+    # the walk they share (the reducers XLA's CPU backend names
+    # `reduce_sum` and the like are not ops of the traced function)
     stray = [n for n in names if n.startswith("jit(")
-             and "prefill_group/" not in n and "decode_rounds/" not in n]
+             and "prefill_group/" not in n and "decode_rounds/" not in n
+             and "joined_walk/" not in n]
     assert not stray, stray[:5]
