@@ -51,7 +51,70 @@ def _diff_time(make_fn, q0):
     return diff_time_scan(make_fn, (q0,), N1, N2, reps=3)
 
 
+def window_against_full(rows: int, heads: int, kv_heads: int, head_dim: int,
+                        contexts: list[int], window: int) -> None:
+    """The paged kernel at one decode round's shape of a configuration
+    with sliding-window layers: `rows` slots whose contexts cycle through
+    `contexts`, every key read (a full layer) against the last `window`
+    (a window layer, whose table holds no page behind the bound). Prints
+    us a call and the bytes a second of the keys that had to be read."""
+    ks = jax.random.split(jax.random.key(1), 4)
+    dtype = jnp.bfloat16
+    lens_np = np.asarray([contexts[i % len(contexts)] for i in range(rows)])
+    mp = -(-int(lens_np.max()) // PS)
+    full_pages = int(sum(-(-n // PS) for n in lens_np))
+    k_pool = jax.random.normal(ks[0], (1, full_pages, kv_heads, head_dim, PS),
+                               dtype)
+    v_pool = jax.random.normal(ks[1], (1, full_pages, kv_heads, head_dim, PS),
+                               dtype)
+    tables = np.full((rows, mp), full_pages, np.int32)
+    behind = np.full((rows, mp), full_pages, np.int32)  # pages given back
+    at = 0
+    for i, n in enumerate(lens_np):
+        used = -(-int(n) // PS)
+        tables[i, :used] = np.arange(at, at + used)
+        first = max(int(n) - window, 0) // PS
+        behind[i, first:used] = tables[i, first:used]
+        at += used
+    lens = jnp.asarray(lens_np, jnp.int32)
+    q = jax.random.normal(ks[2], (rows, 1, heads, head_dim), dtype)
+
+    def scan_of(body, n):
+        def fn(q0):
+            def f(q, _):
+                return body(q).astype(q.dtype), None
+            return lax.scan(f, q0, None, length=n)[0]
+        return fn
+
+    per_key = 2 * kv_heads * head_dim * 2
+    for name, tab, win in (("full", tables, 0), ("window", behind, window)):
+        keys = int(np.minimum(lens_np, win).sum() if win else lens_np.sum())
+        for npb in (4, 8):
+            def body(q, tab=jnp.asarray(tab), win=win, npb=npb):
+                return paged_attention(q, k_pool, v_pool, lens, tab, 0,
+                                       pages_per_block=npb, interpret=False,
+                                       window=win)
+            dt = diff_time_scan(lambda n: scan_of(body, n), (q,), 20, 120,
+                                reps=3)
+            print(f"paged {name:6s} rows={rows} G={heads // kv_heads} "
+                  f"npb={npb} keys={keys} {dt * 1e6:9.1f} us/call "
+                  f"{keys * per_key / dt / 1e9:7.1f} GB/s of keys", flush=True)
+
+
 def main():
+    if len(sys.argv) > 1:
+        import argparse
+        ap = argparse.ArgumentParser()
+        ap.add_argument("--rows", type=int, default=64)
+        ap.add_argument("--heads", type=int, default=28)
+        ap.add_argument("--kv-heads", type=int, default=4)
+        ap.add_argument("--head-dim", type=int, default=128)
+        ap.add_argument("--contexts", default="8192,10240,12288,14336")
+        ap.add_argument("--window", type=int, default=4096)
+        a = ap.parse_args()
+        window_against_full(a.rows, a.heads, a.kv_heads, a.head_dim,
+                            [int(c) for c in a.contexts.split(",")], a.window)
+        return
     ks = jax.random.split(jax.random.key(0), 8)
     dtype = jnp.bfloat16
     lens = jnp.full((B,), S, jnp.int32)

@@ -1,4 +1,5 @@
-"""One expert layer over T + 64 tokens against two calls of T and 64.
+"""One expert layer over T + 64 tokens against two calls of T and 64,
+both dispatches of it at every T, and the grouped matmul's tiles.
 
 The question a mixed step's one walk of the layers rests on (PERF.md,
 PR 32): the decode round's 64 rows and a prefill group's T chunk tokens
@@ -8,7 +9,15 @@ Whole layer (norm, router, dispatch, experts, combine, residual), `stack`
 given as the paged engine gives it, times from the host's clock around a
 queue of calls that ends in `block_until_ready`.
 
+The widths, the experts, the experts a token, the activation and where
+the router reads come from a configuration file of the benchmark
+(`--config`, through its family's `model_config`), so one script made
+the table of every configuration (PERF.md, PR 35).
+
     python benchmarks/moe_joined_call_bench.py            # on a TPU
+    python benchmarks/moe_joined_call_bench.py \
+        --config cellbench/configs/smallthinker-21b-a3b-instruct.json \
+        --tokens 16,64,256,512,1024,2048 --tiles
     JAX_PLATFORMS=cpu python benchmarks/moe_joined_call_bench.py --tiny
 
 Prints one JSON line per measurement and writes them to
@@ -23,11 +32,18 @@ import os
 import statistics
 import time
 
+import dataclasses
+import sys
+
 import jax
 import jax.numpy as jnp
 
-from cloud_server_tpu.config import ModelConfig
-from cloud_server_tpu.models import moe
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+from cloud_server_tpu.config import ModelConfig  # noqa: E402
+from cloud_server_tpu.models import moe  # noqa: E402
 
 DECODE_ROWS = 64
 
@@ -50,7 +66,16 @@ def _layers(cfg: ModelConfig, n_layers: int, key):
 
 def _block(x, layers, cfg, layer):
     lp = jax.tree.map(lambda p: p[layer], layers)
-    return moe.moe_mlp_block(x, lp, cfg, (layers, layer))[0]
+    # the stream stands in for the layer's input where the router reads it
+    return moe.moe_mlp_block(x, lp, cfg, (layers, layer), layer_in=x)[0]
+
+
+def _config(path: str) -> ModelConfig:
+    """The expert layer of a benchmark configuration file, by its family."""
+    from cellbench import families
+    with open(path) as f:
+        cfg_file = json.load(f)
+    return families.of(cfg_file).model_config(cfg_file)
 
 
 def _time_ms(fn, args, reps: int, sets: int) -> list[float]:
@@ -71,21 +96,33 @@ def main() -> None:
                     help="tiny widths: a CPU rehearsal of the control flow")
     ap.add_argument("--reps", type=int, default=20)
     ap.add_argument("--sets", type=int, default=5)
+    ap.add_argument("--config",
+                    default="cellbench/configs/mixtral-8x7b-v0.1.json",
+                    help="a configuration file of the benchmark")
+    ap.add_argument("--tokens", default="16,64,128,256,512,1024,2048",
+                    help="the T of the joined and the two-call cases")
+    ap.add_argument("--between", default="192,256,320,384,448,512,576",
+                    help="the T at which both dispatches are measured")
+    ap.add_argument("--tiles", action="store_true",
+                    help="sweep the grouped matmul's row and weight tiles")
     a = ap.parse_args()
     dev = jax.devices()[0]
     print(json.dumps({"device": {"platform": dev.platform,
                                  "kind": dev.device_kind}}), flush=True)
+    cfg = _config(a.config)
     if a.tiny:
-        cfg = ModelConfig(embed_dim=64, mlp_dim=128, num_experts=8,
-                          num_experts_per_token=2,
-                          expert_capacity_factor=4.0, dtype="float32")
+        cfg = dataclasses.replace(cfg, embed_dim=64, mlp_dim=128,
+                                  dtype="float32")
         a.reps, a.sets = 2, 1
-    else:
-        if dev.platform != "tpu":
-            raise SystemExit("real widths are measured on a TPU only")
-        cfg = ModelConfig(embed_dim=4096, mlp_dim=14336, num_experts=8,
-                          num_experts_per_token=2,
-                          expert_capacity_factor=4.0, dtype="bfloat16")
+    elif dev.platform != "tpu":
+        raise SystemExit("real widths are measured on a TPU only")
+    print(json.dumps({"config": a.config, "embed_dim": cfg.embed_dim,
+                      "mlp_dim": cfg.mlp_dim, "experts": cfg.num_experts,
+                      "a_token": cfg.num_experts_per_token,
+                      "activation": cfg.mlp_activation,
+                      "router_input": cfg.router_input,
+                      "placed_min_tokens": moe.grouped_min_tokens(cfg),
+                      "placed_tilings": moe._gmm_tilings(cfg)}), flush=True)
     layers = _layers(cfg, 2, jax.random.PRNGKey(0))
     lines = []
 
@@ -111,7 +148,7 @@ def main() -> None:
         return _block(xc, layers, cfg, 1), _block(xd, layers, cfg, 1)
 
     xd = x_of(DECODE_ROWS, 1)
-    for t in (16, 64, 128, 256, 512, 1024, 2048):
+    for t in [int(t) for t in a.tokens.split(",") if t]:
         xc = x_of(t, 2)
         apart = measure(f"two_calls_{t}_and_{DECODE_ROWS}", two,
                         (xc, xd, layers), tokens=t)
@@ -122,14 +159,48 @@ def main() -> None:
         print(json.dumps({"tokens": t, "saved_ms_a_layer": apart - joined}),
               flush=True)
 
-    # both dispatches in the gap PR 26 left between 256 and 512, and at
-    # the neighbours a joined call moves to (256 + 64, 512 + 64)
-    placed = moe.GROUPED_MIN_TOKENS
-    for t in (192, 256, 320, 384, 448, 512, 576):
+    # both dispatches where the threshold is looked for (PR 26 left a gap
+    # between 256 and 512 at Mixtral's widths) and at the neighbours a
+    # joined call moves to (256 + 64, 512 + 64). The dense dispatch's
+    # one-hot is (T, k, E, C): quadratic in T, so it is not asked past
+    # 2 GB of it
+    placed = moe.grouped_min_tokens
+    for t in [int(t) for t in a.between.split(",") if t]:
         for name, floor in (("dense", 1 << 30), ("sorted", 1)):
-            moe.GROUPED_MIN_TOKENS = floor
+            if name == "dense" and (t * t * cfg.num_experts_per_token
+                                    * cfg.num_experts * 4) > 2 << 30:
+                continue
+            moe.grouped_min_tokens = lambda cfg, floor=floor: floor
             measure(f"{name}_{t}", one, (x_of(t, 4), layers), tokens=t)
-    moe.GROUPED_MIN_TOKENS = placed
+    moe.grouped_min_tokens = placed
+
+    if a.tiles:
+        # the sorted dispatch alone at a decode-and-chunks call and at a
+        # full step's, over row tiles and weight tiles; the placed pair
+        # first. `_grouped_experts` takes the pair as a static argument,
+        # so every case is a trace of its own
+        d, f = cfg.embed_dim, cfg.mlp_dim
+        placed_t = moe._gmm_tilings
+        moe.grouped_min_tokens = lambda cfg: 1
+        cases = [placed_t(cfg)]
+        for rows in (128, 256, 512):
+            for kin, nin in ((d, f), (d, f // 2), (d // 2, f)):
+                for kout, nout in ((f, d), (f, d // 2), (f // 2, d)):
+                    pair = ((rows, kin, nin), (rows, kout, nout))
+                    if pair not in cases:
+                        cases.append(pair)
+        for pair in cases:
+            moe._gmm_tilings = lambda cfg, pair=pair: pair
+            for t in (512 + DECODE_ROWS, 2048 + DECODE_ROWS):
+                try:
+                    measure(f"tiles_{t}", one, (x_of(t, 5), layers),
+                            tokens=t, tilings=pair)
+                except Exception as exc:  # noqa: BLE001 - a tiling Mosaic refuses
+                    print(json.dumps({"case": f"tiles_{t}", "tilings": pair,
+                                      "refused": repr(exc)[:200]}),
+                          flush=True)
+        moe._gmm_tilings = placed_t
+        moe.grouped_min_tokens = placed
 
     os.makedirs("chiprun_out", exist_ok=True)
     with open("chiprun_out/moe_joined_call_bench.jsonl", "w") as f:

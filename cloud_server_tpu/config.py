@@ -66,6 +66,23 @@ class ModelConfig:
     num_experts: int = 0
     num_experts_per_token: int = 2
     expert_capacity_factor: float = 1.25
+    # the gated MLP's gate activation, dense or expert: "silu" (SwiGLU) |
+    # "relu" (ReGLU)
+    mlp_activation: str = "silu"
+    # what the router reads: "mlp_norm" (the normed post-attention
+    # stream, beside the experts) | "layer_input" (the layer's input
+    # before its attention norm: the router placed before attention)
+    router_input: str = "mlp_norm"
+    # A pattern of layers. `sliding_window` > 0 is the number of keys a
+    # query of a window layer reads, its own included (i - j <
+    # sliding_window); `window_layout` says which layers are window layers
+    # and `rope_layout` which layers rotate q and k (a layer with 0 carries
+    # no position at all). Each is a period of 0/1 flags repeated over the
+    # depth, or one flag a layer; empty = no window layer / every layer
+    # rotary, which is every configuration that states neither.
+    sliding_window: int = 0
+    window_layout: tuple = ()
+    rope_layout: tuple = ()
     # rematerialisation policy for the layer scan:
     # "none" | "full" | "dots" | "attn" (save only flash-attention residuals)
     remat: str = "full"
@@ -95,6 +112,18 @@ class ModelConfig:
     ce_impl: str = "dense"
 
     def __post_init__(self) -> None:
+        for name in ("window_layout", "rope_layout"):
+            # JSON gives lists; a jit static argument must be hashable
+            object.__setattr__(self, name, tuple(
+                int(bool(f)) for f in getattr(self, name)))
+        if self.mlp_activation not in ("silu", "relu"):
+            raise ValueError(
+                f"unknown mlp_activation: {self.mlp_activation!r}")
+        if self.router_input not in ("mlp_norm", "layer_input"):
+            raise ValueError(f"unknown router_input: {self.router_input!r}")
+        if any(self.window_layout) and self.sliding_window <= 0:
+            raise ValueError("window_layout names window layers but "
+                             "sliding_window is not set")
         if self.num_heads % max(self.num_kv_heads, 1) != 0:
             raise ValueError(
                 f"num_heads={self.num_heads} must be a multiple of "
@@ -114,6 +143,37 @@ class ModelConfig:
     @property
     def q_per_kv(self) -> int:
         return self.num_heads // self.num_kv_heads
+
+    def layer_window(self, layer: int) -> int:
+        """Keys a query of `layer` reads, its own included; 0 = all."""
+        lay = self.window_layout
+        return self.sliding_window if lay and lay[layer % len(lay)] else 0
+
+    def layer_rope(self, layer: int) -> bool:
+        lay = self.rope_layout
+        return bool(lay[layer % len(lay)]) if lay else True
+
+    @property
+    def layer_kinds(self) -> tuple:
+        """Per layer its cache kind, "full" or "window": a kind is a page
+        pool of its own in the paged cache."""
+        return tuple("window" if self.layer_window(i) else "full"
+                     for i in range(self.num_layers))
+
+    @property
+    def has_window_layers(self) -> bool:
+        return "window" in self.layer_kinds
+
+    @property
+    def has_layer_pattern(self) -> bool:
+        """Layers differ: the scans carry per-layer flags."""
+        return self.has_window_layers or not all(
+            self.layer_rope(i) for i in range(self.num_layers))
+
+    def layer_pool(self, layer: int) -> tuple:
+        """(kind, index of `layer` among the layers of its kind)."""
+        kinds = self.layer_kinds
+        return kinds[layer], kinds[:layer].count(kinds[layer])
 
 
 @dataclasses.dataclass(frozen=True)

@@ -351,3 +351,51 @@ class BlockAllocator:
                     self._owner[page] = None
         if pages:
             self.telemetry.record_release(tenant, len(pages))
+
+
+class WindowPagePool:
+    """The page pool of the window kind of layer (a sliding window of
+    `ModelConfig.sliding_window` keys): a free list and nothing else.
+
+    A window page is private to one slot from `alloc` to `free`: it is
+    never keyed, shared or cached (a prefix hit on a window layer would
+    need the pages behind the hit's end, which the slot that made them
+    has given back). The server sizes the pool at the most pages every
+    slot can hold at once (`paged_engine.window_pages_per_slot`), so
+    `alloc` cannot run short and this pool never forces a preemption; a
+    shortage is therefore a bug and raises. A page given back twice, or
+    one this pool never handed out, raises too."""
+
+    def __init__(self, num_pages: int):
+        self.num_pages = num_pages
+        self._free: collections.deque[int] = collections.deque(
+            range(num_pages))
+        self._held = [False] * num_pages
+        self.pages_allocated = 0   # lifetime counters, as BlockAllocator's
+        self.pages_returned = 0
+
+    @property
+    def active(self) -> int:
+        return self.num_pages - len(self._free)
+
+    def alloc(self, n: int) -> list[int]:
+        if n > len(self._free):
+            raise RuntimeError(
+                f"window page pool: {n} pages asked, {len(self._free)} of "
+                f"{self.num_pages} free; the pool is sized so that this "
+                "cannot happen")
+        pages = [self._free.popleft() for _ in range(n)]
+        for p in pages:
+            self._held[p] = True
+        self.pages_allocated += n
+        return pages
+
+    def free(self, pages) -> None:
+        for p in pages:
+            p = int(p)
+            if not (0 <= p < self.num_pages) or not self._held[p]:
+                raise RuntimeError(
+                    f"window page {p} given back but not held")
+            self._held[p] = False
+            self._free.append(p)
+        self.pages_returned += len(pages)

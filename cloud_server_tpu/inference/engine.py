@@ -73,7 +73,8 @@ def _kv_dequant(q: jnp.ndarray, scale: jnp.ndarray, dtype) -> jnp.ndarray:
     return (q.astype(jnp.float32) * scale).astype(dtype)
 
 
-def _mlp_apply(x, lp, cfg: ModelConfig, lora=None, stack=None):
+def _mlp_apply(x, lp, cfg: ModelConfig, lora=None, stack=None,
+               layer_in=None):
     """Dense or MoE MLP residual block, chosen by cfg.num_experts.
 
     MoE routing at inference is per-call: prefill routes over the prompt
@@ -96,17 +97,23 @@ def _mlp_apply(x, lp, cfg: ModelConfig, lora=None, stack=None):
     (`moe._dispatch_grouped`).
 
     `lora`: per-row multi-adapter deltas (dense MLP only; the server
-    rejects MLP-targeting adapters on MoE bases).
+    rejects MLP-targeting adapters on MoE bases). `layer_in`: the
+    layer's input, for a router that reads it (`moe.moe_mlp_block`).
     """
     if cfg.num_experts >= 2:
         from cloud_server_tpu.models import moe
-        x, _ = moe.moe_mlp_block(x, lp, cfg, stack)
+        x, _ = moe.moe_mlp_block(x, lp, cfg, stack, layer_in)
         return x
     with jax.named_scope("mlp"):
         return transformer.mlp_block(x, lp, cfg, lora=lora)
 
 
 def init_cache(cfg: ModelConfig, batch: int, max_len: int) -> KVCache:
+    if cfg.has_layer_pattern:
+        raise ValueError(
+            "the contiguous cache of inference.engine holds one kind of "
+            "layer; a model with window or position-free layers is served "
+            "by the paged server (inference.paged_engine)")
     shape = (cfg.num_layers, batch, max_len, cfg.num_kv_heads, cfg.head_dim)
     if cfg.kv_cache_dtype == "int8":
         sshape = shape[:-1] + (1,)

@@ -6,6 +6,15 @@ layer plus per-slot page tables, so device memory scales with the tokens
 actually resident (not max_slots x max_len) and pages can be SHARED
 between slots (refcounted prefix reuse — inference/block_allocator.py).
 
+A model whose layers are of two kinds (`ModelConfig.layer_kinds`: full
+layers read every key, window layers the last `sliding_window`) has a
+pool and a table for each kind. The full kind is the pool above. The
+window kind's pages are private to a slot, addressed by the same logical
+index (position // page size), and given back by the host once every
+position in them lies behind the window of every query still to come;
+the table's entry is then the sentinel, and the kernels never fetch a
+block wholly behind the bound. A model of one kind has one pool.
+
 Everything the paged server dispatches is one primitive,
 `forward_sets`, a walk of the layer stack over one or more row sets that
 share the pool; a mixed step hands it its prefill group and its decode
@@ -60,15 +69,28 @@ from cloud_server_tpu.ops.paged_attention import (
 
 
 class PagedKVCache(NamedTuple):
-    """Page pool + per-slot view. One pool serves every slot and layer."""
+    """Page pools + per-slot view. One pool serves every slot and every
+    layer of a kind: `k`/`v` the full layers (all of them, for a model of
+    one kind), `wk`/`wv` the window layers of a model that has them. A
+    layer's place is `cfg.layer_pool(layer)`: (kind, index in its pool).
 
-    k: jnp.ndarray        # (L, num_pages, KH, Dh, ps) cfg.dtype | int8
-    v: jnp.ndarray        # (L, num_pages, KH, Dh, ps) — transposed pages
-    #                       (positions on lanes; see ops/paged_attention)
+    `tables` holds a table per kind side by side: columns
+    [0, max_pages_per_slot) the full kind's, and with a window pool
+    columns [max_pages_per_slot, 2 * max_pages_per_slot) the window
+    kind's. Both are indexed by position // page size; each kind's
+    sentinel ("no page") is any id >= its own pool's pages."""
+
+    k: jnp.ndarray        # (L_full, num_pages, KH, Dh, ps) cfg.dtype | int8
+    v: jnp.ndarray        # (L_full, num_pages, KH, Dh, ps) — transposed
+    #                       pages (positions on lanes; ops/paged_attention)
     lengths: jnp.ndarray  # (B,) int32 — committed kv entries per slot
-    tables: jnp.ndarray   # (B, max_pages_per_slot) int32; num_pages = free
-    k_scale: jnp.ndarray | None = None  # (L, num_pages, KH, ps) f32
+    tables: jnp.ndarray   # (B, kinds * max_pages_per_slot) int32
+    k_scale: jnp.ndarray | None = None  # (L_full, num_pages, KH, ps) f32
     v_scale: jnp.ndarray | None = None
+    wk: jnp.ndarray | None = None  # (L_window, window_num_pages, KH, Dh, ps)
+    wv: jnp.ndarray | None = None
+    wk_scale: jnp.ndarray | None = None
+    wv_scale: jnp.ndarray | None = None
 
     @property
     def page_size(self) -> int:
@@ -79,29 +101,82 @@ class PagedKVCache(NamedTuple):
         return self.k.shape[1]
 
     @property
+    def max_pages_per_slot(self) -> int:
+        return self.tables.shape[1] // (1 if self.wk is None else 2)
+
+    @property
     def max_context(self) -> int:
-        return self.tables.shape[1] * self.page_size
+        return self.max_pages_per_slot * self.page_size
+
+    def of_kind(self, kind: str, tables: jnp.ndarray) -> "PagedKVCache":
+        """One kind's pools and its columns of `tables` as a cache of
+        one kind: what `_write_window` and the kernels take."""
+        mp = tables.shape[1] // (1 if self.wk is None else 2)
+        if kind == "full":
+            return PagedKVCache(self.k, self.v, self.lengths,
+                                tables[:, :mp], self.k_scale, self.v_scale)
+        return PagedKVCache(self.wk, self.wv, self.lengths, tables[:, mp:],
+                            self.wk_scale, self.wv_scale)
+
+    def with_kind(self, kind: str, view: "PagedKVCache") -> "PagedKVCache":
+        """The pools of `view` (an `of_kind` cache) put back."""
+        if kind == "full":
+            return self._replace(k=view.k, v=view.v, k_scale=view.k_scale,
+                                 v_scale=view.v_scale)
+        return self._replace(wk=view.k, wv=view.v, wk_scale=view.k_scale,
+                             wv_scale=view.v_scale)
+
+
+def window_pages_per_slot(window: int, page_size: int, max_write: int,
+                          max_pages_per_slot: int) -> int:
+    """The most pages of the window kind one slot can hold: those that
+    cover the `window - 1` keys behind the oldest query not yet
+    committed and the `max_write` positions written ahead of it (the
+    widest dispatch, times the dispatches in flight), plus one for
+    where the span starts inside a page."""
+    return min(-(-(window - 1 + max_write) // page_size) + 1,
+               max_pages_per_slot)
 
 
 def init_paged_cache(cfg: ModelConfig, *, num_pages: int, page_size: int,
-                     batch: int, max_pages_per_slot: int) -> PagedKVCache:
-    """Zeroed pool; all tables at the sentinel (num_pages = "no page")."""
-    shape = (cfg.num_layers, num_pages, cfg.num_kv_heads, cfg.head_dim,
-             page_size)
-    tables = jnp.full((batch, max_pages_per_slot), num_pages, jnp.int32)
-    lengths = jnp.zeros((batch,), jnp.int32)
-    if cfg.kv_cache_dtype == "int8":
-        sshape = shape[:3] + (page_size,)
-        return PagedKVCache(
-            k=jnp.zeros(shape, jnp.int8), v=jnp.zeros(shape, jnp.int8),
-            lengths=lengths, tables=tables,
-            k_scale=jnp.zeros(sshape, jnp.float32),
-            v_scale=jnp.zeros(sshape, jnp.float32))
-    if cfg.kv_cache_dtype != "model":
+                     batch: int, max_pages_per_slot: int,
+                     window_num_pages: int | None = None) -> PagedKVCache:
+    """Zeroed pools; all tables at the sentinel ("no page"). `num_pages`
+    is the full kind's pool. The window kind's, of a model that has
+    window layers, is `window_num_pages`: the paged server gives the
+    size it reckoned from its own widths, and without one every slot
+    gets `window_pages_per_slot` for the widest window the kernels
+    serve, written twice ahead."""
+    kinds = cfg.layer_kinds
+    if "window" in kinds and window_num_pages is None:
+        window_num_pages = batch * window_pages_per_slot(
+            cfg.sliding_window, page_size, 2 * PALLAS_MAX_W,
+            max_pages_per_slot)
+    if cfg.kv_cache_dtype not in ("model", "int8"):
         raise ValueError(f"unknown kv_cache_dtype: {cfg.kv_cache_dtype!r}")
-    dtype = jnp.dtype(cfg.dtype)
-    return PagedKVCache(k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype),
-                        lengths=lengths, tables=tables)
+    int8 = cfg.kv_cache_dtype == "int8"
+    dtype = jnp.int8 if int8 else jnp.dtype(cfg.dtype)
+
+    def pools(kind: str, pages: int):
+        shape = (kinds.count(kind), pages, cfg.num_kv_heads, cfg.head_dim,
+                 page_size)
+        k, v = jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)
+        if not int8:
+            return k, v, None, None
+        sshape = shape[:3] + (page_size,)
+        return (k, v, jnp.zeros(sshape, jnp.float32),
+                jnp.zeros(sshape, jnp.float32))
+
+    k, v, ks, vs = pools("full", num_pages)
+    lengths = jnp.zeros((batch,), jnp.int32)
+    tables = jnp.full((batch, max_pages_per_slot), num_pages, jnp.int32)
+    if "window" not in kinds:
+        return PagedKVCache(k, v, lengths, tables, ks, vs)
+    wk, wv, wks, wvs = pools("window", window_num_pages)
+    tables = jnp.concatenate(
+        [tables, jnp.full((batch, max_pages_per_slot), window_num_pages,
+                          jnp.int32)], axis=1)
+    return PagedKVCache(k, v, lengths, tables, ks, vs, wk, wv, wks, wvs)
 
 
 def quantize_pool(pool: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
@@ -119,12 +194,13 @@ def quantize_pool(pool: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
 
 
 def hbm_bytes(cache: PagedKVCache) -> int:
-    """Device bytes held by the pool (the capacity comparison the paged
-    layout exists to win — see tests/test_paged_server.py)."""
-    n = cache.k.size * cache.k.dtype.itemsize * 2
-    if cache.k_scale is not None:
-        n += cache.k_scale.size * 4 * 2
-    return n
+    """Device bytes held by the pools of every kind (the capacity
+    comparison the paged layout exists to win — see
+    tests/test_paged_server.py)."""
+    return sum(p.size * p.dtype.itemsize
+               for p in (cache.k, cache.v, cache.k_scale, cache.v_scale,
+                         cache.wk, cache.wv, cache.wk_scale, cache.wv_scale)
+               if p is not None)
 
 
 def _write_window(cache: PagedKVCache, layer: int, k, v, pos):
@@ -307,42 +383,54 @@ def forward_sets(params, cfg: ModelConfig, cache: PagedKVCache,
         pos_all = _side_by_side([r[0] for r in rows])
     pools = cache
 
+    # each layer's kind is static (the walk is unrolled): a model of one
+    # kind adds no scope and no argument, and builds the program it built
+    two_kinds = cfg.has_window_layers
     for layer_idx in range(cfg.num_layers):
         lp = jax.tree.map(lambda p: p[layer_idx], params["layers"])
         ll = (None if lora is None
               else multi_lora.layer_lora(lora, aid, layer_idx))
+        kind, at = cfg.layer_pool(layer_idx)
+        window = cfg.layer_window(layer_idx)
+        x_in = x
         with _scope(shared), jax.named_scope("attn"):
             qkv = transformer.attention_qkv(x, lp, cfg, cos, sin, pos_all,
-                                            lora=ll)
+                                            lora=ll,
+                                            rope=cfg.layer_rope(layer_idx))
         outs = []
         for i, (s, (_, wpos, lens_after, ppb)) in enumerate(zip(sets, rows)):
-            with _scope(s.scope), jax.named_scope("attn"):
+            with _scope(s.scope), jax.named_scope("attn"), \
+                    _scope(kind if two_kinds else None):
                 q, k, v = (_part(y, sets, i) for y in qkv)
-                pools = _write_window(
-                    pools._replace(lengths=s.lengths, tables=s.tables),
-                    layer_idx, k, v, wpos)
-                kw = dict(k_scale_pool=pools.k_scale,
-                          v_scale_pool=pools.v_scale, widths=s.widths)
+                view = _write_window(
+                    pools.of_kind(kind, s.tables)._replace(
+                        lengths=s.lengths), at, k, v, wpos)
+                pools = pools.with_kind(kind, view)
+                kw = dict(k_scale_pool=view.k_scale,
+                          v_scale_pool=view.v_scale, widths=s.widths)
+                if window:
+                    kw["window"] = window
                 if not use_pallas:
                     o = paged_attention_xla(
-                        q, pools.k, pools.v, lens_after, s.tables,
-                        layer_idx, **kw)
+                        q, view.k, view.v, lens_after, view.tables,
+                        at, **kw)
                 elif mesh is not None and mesh.shape.get(tp_axis, 1) > 1:
                     o = paged_attention_tp(
-                        q, pools.k, pools.v, lens_after, s.tables,
-                        layer_idx, mesh=mesh, axis_name=tp_axis,
+                        q, view.k, view.v, lens_after, view.tables,
+                        at, mesh=mesh, axis_name=tp_axis,
                         pages_per_block=ppb, **kw)
                 else:
                     o = paged_attention(
-                        q, pools.k, pools.v, lens_after, s.tables,
-                        layer_idx, pages_per_block=ppb, **kw)
+                        q, view.k, view.v, lens_after, view.tables,
+                        at, pages_per_block=ppb, **kw)
             outs.append(o)
         with _scope(shared):
             with jax.named_scope("attn"):
                 x = transformer.attention_out(x, _side_by_side(outs), lp,
                                               cfg, lora=ll)
             x = _mlp_apply(x, lp, cfg, lora=ll,
-                           stack=(params["layers"], layer_idx))
+                           stack=(params["layers"], layer_idx),
+                           layer_in=x_in)
 
     logits = []
     for i, s in enumerate(sets):

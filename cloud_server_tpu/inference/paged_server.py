@@ -133,7 +133,8 @@ from jax import lax
 
 from cloud_server_tpu.config import InferConfig, ModelConfig
 from cloud_server_tpu.inference import paged_engine, sampling
-from cloud_server_tpu.inference.block_allocator import BlockAllocator
+from cloud_server_tpu.inference.block_allocator import (
+    BlockAllocator, WindowPagePool)
 from cloud_server_tpu.inference.grammar import DEAD as _GDEAD
 from cloud_server_tpu.inference.iteration_profile import (
     OVERLAP_PHASES, derive_gap_fields)
@@ -247,18 +248,22 @@ def _grammar_mask(grammar, gid, st, eos_id):
     return nrow, amask
 
 
+_POOL_NAMES = ("k", "v", "k_scale", "v_scale",
+               "wk", "wv", "wk_scale", "wv_scale")
+
+
 def _make_cache(pools, lengths, tables):
     return paged_engine.PagedKVCache(
-        k=pools["k"], v=pools["v"], lengths=lengths, tables=tables,
-        k_scale=pools.get("k_scale"), v_scale=pools.get("v_scale"))
+        lengths=lengths, tables=tables,
+        **{name: pools.get(name) for name in _POOL_NAMES})
 
 
 def _split_cache(cache):
-    pools = {"k": cache.k, "v": cache.v}
-    if cache.k_scale is not None:
-        pools["k_scale"] = cache.k_scale
-        pools["v_scale"] = cache.v_scale
-    return pools
+    """The cache's pools by name; a pool the model does not have (the
+    scales of a bf16 cache, the window kind of a model without window
+    layers) is left out."""
+    return {name: getattr(cache, name) for name in _POOL_NAMES
+            if getattr(cache, name) is not None}
 
 
 # The scopes below name the two halves of every step program in a
@@ -1140,9 +1145,46 @@ class PagedInferenceServer:
             cast_leaf, draft_params,
             is_leaf=lambda x: isinstance(x, QTensor)))
 
+        # A model with window layers has a second pool, for them. The
+        # program sizes it from what it knows, at the most pages every
+        # slot can hold at once, so it takes no option and can never be
+        # the pool that forces a preemption; `num_pages` stays the full
+        # kind's. A model of one kind has one pool and one table.
+        self.window_pool: WindowPagePool | None = None
+        self.window_pages_per_slot = 0
+        if cfg.has_window_layers:
+            if draft_cfg is not None:
+                raise ValueError(
+                    "draft-model speculation shares the target's page "
+                    "tables, which hold one kind of page for the draft; "
+                    "a target with window layers takes n-gram speculation "
+                    "(spec_drafts without a draft model)")
+            ahead = (2 if (infer_cfg.overlap if overlap is None
+                           else bool(overlap))
+                     and (scheduler or infer_cfg.scheduler) == "mixed" else 1)
+            self.window_pages_per_slot = paged_engine.window_pages_per_slot(
+                cfg.sliding_window, page_size,
+                ahead * max(self.prefill_chunk,
+                            self.decode_chunk * self.window),
+                self.max_pages_per_slot)
+            self.window_pool = WindowPagePool(
+                max_slots * self.window_pages_per_slot)
+        # the tables' width and their "no page": a table per kind side
+        # by side (`PagedKVCache.tables`), one id past both pools
+        kinds = 2 if self.window_pool is not None else 1
+        self._table_cols = kinds * self.max_pages_per_slot
+        self._no_page = max(num_pages, 0 if self.window_pool is None
+                            else self.window_pool.num_pages)
+        # per slot the logical pages [lo, hi) of the window kind it holds
+        self._win_lo = np.zeros((max_slots,), np.int64)
+        self._win_hi = np.zeros((max_slots,), np.int64)
+        self.window_pages_peak_slot = 0
+        self._keys_stage: dict = {}
         cache = paged_engine.init_paged_cache(
             cfg, num_pages=num_pages, page_size=page_size, batch=max_slots,
-            max_pages_per_slot=self.max_pages_per_slot)
+            max_pages_per_slot=self.max_pages_per_slot,
+            window_num_pages=(None if self.window_pool is None
+                              else self.window_pool.num_pages))
         # per-request sampling penalty state ("prompt_mask" /
         # "out_counts", (B, V) per slot) is NOT allocated here — the
         # first admission that needs penalties materializes it
@@ -1185,8 +1227,8 @@ class PagedInferenceServer:
                        else put(val, P()))
                 for name, val in self.state.items()}
         # host-authoritative scheduling state
-        self.tables = np.full((max_slots, self.max_pages_per_slot),
-                              num_pages, np.int32)
+        self.tables = np.full((max_slots, self._table_cols),
+                              self._no_page, np.int32)
         self.lengths = np.zeros((max_slots,), np.int32)
         self.active = np.zeros((max_slots,), bool)
         self.last_token = np.zeros((max_slots,), np.int32)
@@ -1919,6 +1961,93 @@ class PagedInferenceServer:
         stream = slot.prompt + slot.req.tokens[since:]
         return stream[:int(self.lengths[slot_id])]
 
+    # -- the window kind's pages ----------------------------------------------
+
+    def _window_cover(self, sid: int, upto: int) -> None:
+        """Hold window pages for every position of slot `sid` below
+        `upto` that a dispatch about to be built may write. The pool
+        cannot run short (`WindowPagePool`). No-op for a model without
+        window layers."""
+        if self.window_pool is None:
+            return
+        hi = int(self._win_hi[sid])
+        need = min(-(-int(upto) // self.page_size), self.max_pages_per_slot)
+        if need > hi:
+            mp = self.max_pages_per_slot
+            self.tables[sid, mp + hi:mp + need] = self.window_pool.alloc(
+                need - hi)
+            self._win_hi[sid] = need
+            held = need - int(self._win_lo[sid])
+            self.window_pages_peak_slot = max(self.window_pages_peak_slot,
+                                              held)
+            assert held <= self.window_pages_per_slot, (
+                f"slot {sid} holds {held} window pages, over the "
+                f"{self.window_pages_per_slot} its pool was sized for")
+
+    def _window_cover_rounds(self, n_rounds: int, lengths, active) -> None:
+        """`_window_cover` for `n_rounds` decode rounds of every live
+        slot, from `lengths` (the planned frame's under overlap)."""
+        if self.window_pool is None or n_rounds <= 0:
+            return
+        ids = [sid for sid in np.flatnonzero(active)
+               if self._slots[sid] is not None]
+        for sid in ids:
+            self._window_cover(sid, min(
+                int(lengths[sid]) + n_rounds * self.window,
+                self._slots[sid].stop_len + self.window))
+        for r in range(1, n_rounds + 1):
+            self._stage_keys(np.asarray(lengths)[ids] + r * self.window,
+                             self.window, decode=True)
+
+    def _stage_keys(self, kv_len, width: int, decode: bool) -> None:
+        """Count the keys one layer of each kind reads for rows whose
+        `width` queries end at `kv_len` (an int, or an array of rows):
+        every key for a full layer, for a window layer those from the
+        first query's bound on."""
+        ks = self._keys_stage
+        full = int(np.sum(kv_len))
+        win = int(np.sum(np.minimum(
+            kv_len, self.cfg.sliding_window - 1 + width)))
+        ks["keys_full"] = ks.get("keys_full", 0) + full
+        ks["keys_window"] = ks.get("keys_window", 0) + win
+        if decode:
+            ks["keys_window_decode"] = ks.get("keys_window_decode", 0) + win
+
+    def _take_keys(self) -> dict:
+        """The flight record's `keys_full`, `keys_window` and
+        `keys_window_decode` of the dispatch being built; nothing for a
+        model without window layers."""
+        ks, self._keys_stage = self._keys_stage, {}
+        return ks
+
+    def _window_free(self, sid: int, before: int) -> None:
+        """Give back slot `sid`'s window pages of logical index below
+        `before`."""
+        lo = int(self._win_lo[sid])
+        before = min(int(before), int(self._win_hi[sid]))
+        if self.window_pool is None or before <= lo:
+            return
+        mp = self.max_pages_per_slot
+        self.window_pool.free(self.tables[sid, mp + lo:mp + before])
+        self.tables[sid, mp + lo:mp + before] = self._no_page
+        self._win_lo[sid] = before
+        self._iter_stats["pages_returned"] = (
+            self._iter_stats.get("pages_returned", 0) + before - lo)
+
+    def _window_trim(self, sid: int, committed: int) -> None:
+        """At a commit that moved slot `sid`'s cursor to `committed`: give
+        back the window pages whose every position lies behind the
+        window of every query still to come. The next query stands at
+        `committed` or later and reads back to `committed -
+        (sliding_window - 1)`; a program built before this commit and
+        launched after it starts at `committed` too (the planned cursor
+        never lags the committed one), so it reads none of them either,
+        whatever its copy of the table says."""
+        if self.window_pool is not None:
+            self._window_free(sid, max(
+                0, int(committed) - (self.cfg.sliding_window - 1))
+                // self.page_size)
+
     def _release_slot(self, slot_id: int, keyed_tokens: list[int]) -> _Slot:
         """The slot-teardown invariant, in ONE place: release the page
         chain (keyed by `keyed_tokens` — pass [] to key nothing), clear
@@ -1926,11 +2055,14 @@ class PagedInferenceServer:
         retires a slot (finish, preemption, failure) goes through here;
         what happens to the request afterwards is the caller's story."""
         slot = self._slots[slot_id]
-        self.allocator.release(slot.pages, keyed_tokens,
-                               namespace=slot.req.adapter or "",
-                               tenant=slot.req.tenant)
+        # a model with window layers keys nothing: a later hit on the full
+        # kind's pages would find the window kind's given back
+        self.allocator.release(
+            slot.pages, [] if self.window_pool is not None else keyed_tokens,
+            namespace=slot.req.adapter or "", tenant=slot.req.tenant)
         self._slots[slot_id] = None
-        self.tables[slot_id, :] = self.allocator.num_pages  # sentinel
+        self._window_free(slot_id, int(self._win_hi[slot_id]))
+        self.tables[slot_id, :] = self._no_page  # sentinel
         self.active[slot_id] = False
         self.lengths[slot_id] = 0
         self._needs_rows[slot_id] = False  # don't pin rows-mode dispatch
@@ -2013,9 +2145,15 @@ class PagedInferenceServer:
                 req = self._pending[idx]
                 prompt = list(req.prompt) + list(req.tokens)
                 remaining = req.max_new_tokens - len(req.tokens)
-                shared, shared_len = self.allocator.lookup_prefix(
-                    prompt, namespace=req.adapter or "",
-                    tenant=req.tenant)
+                if self.window_pool is not None:
+                    # no prefix hit for a model with window layers (see
+                    # `_release_slot`): every admission prefills its whole
+                    # prompt through both pools
+                    shared, shared_len = [], 0
+                else:
+                    shared, shared_len = self.allocator.lookup_prefix(
+                        prompt, namespace=req.adapter or "",
+                        tenant=req.tenant)
                 if self.allocation == "ondemand":
                     # prompt + one decode window; chains grow per
                     # dispatch in _extend_chains
@@ -2077,8 +2215,9 @@ class PagedInferenceServer:
                              stop_len=len(prompt) + remaining,
                              admit_seq=self._admit_seq)
                 self._slots[slot_id] = slot
-                self.tables[slot_id, :] = self.allocator.num_pages
+                self.tables[slot_id, :] = self._no_page
                 self.tables[slot_id, :len(slot.pages)] = slot.pages
+                self._win_lo[slot_id] = self._win_hi[slot_id] = 0
                 self.lengths[slot_id] = shared_len
                 self.stop_len[slot_id] = slot.stop_len
                 self.active[slot_id] = False  # live once admission is done
@@ -2223,8 +2362,9 @@ class PagedInferenceServer:
                          self.infer_cfg.pad_token_id)
         g_lens = pad_rows(job.base_lens + c * w, 0)
         slot_ids = pad_rows(np.asarray(job.slots, np.int32), self.max_slots)
-        g_tables = np.full((gp, self.max_pages_per_slot),
-                           self.allocator.num_pages, np.int32)
+        for i, sid in enumerate(job.slots):
+            self._window_cover(sid, int(job.base_lens[i]) + (c + 1) * w)
+        g_tables = np.full((gp, self._table_cols), self._no_page, np.int32)
         g_tables[:g] = self.tables[np.asarray(job.slots)]
         sample_at = pad_rows(np.clip(job.rem_lens - 1 - c * w, 0, w - 1), 0)
         in_range = ((job.rem_lens - 1) >= c * w) & (
@@ -2278,6 +2418,9 @@ class PagedInferenceServer:
         job.lps = np.where(in_range, lps, job.lps)
         job.got |= in_range
         job.next_chunk += 1
+        for i, sid in enumerate(job.slots):
+            self._window_trim(sid, int(job.base_lens[i]) + min(
+                job.next_chunk * w, int(job.rem_lens[i])))
 
         if job.next_chunk >= job.n_chunks:
             # admission complete: activate slots, emit first tokens
@@ -2460,7 +2603,7 @@ class PagedInferenceServer:
         live_g[:nl] = True
         lengths = self.lengths[slr].copy()
         tables = self.tables[slr].copy()
-        tables[nl:] = self.allocator.num_pages
+        tables[nl:] = self._no_page
         last = self.last_token[slr].copy()
         stop = self.stop_len[slr].copy()
         samp = _gather_samp_rows(self.samp_rows, slr, nl)
@@ -2541,6 +2684,7 @@ class PagedInferenceServer:
             while n > n_eff:  # keep round counts powers of two (compile
                 n //= 2      # cache) while honouring chain coverage
             n = max(1, n)
+        self._window_cover_rounds(n, self.lengths, self.active)
         if prof is not None:
             prof.enter("build")
         (live_ids, sl, live_g, lengths, tables, last_np, stop, samp_g,
@@ -2552,6 +2696,7 @@ class PagedInferenceServer:
             decode_tokens=len(live_ids) * (g_iter + 1) * n,
             decode_rows=int(live_g.shape[0]),
             compaction_ratio=len(live_ids) / max(int(live_g.shape[0]), 1))
+        self._iter_stats.update(self._take_keys())
         self._stage_spec_stats(g_iter, len(live_ids))
         if self.trace_recorder is not None:
             self._stage_decode_spans(live_ids, n)
@@ -2651,6 +2796,15 @@ class PagedInferenceServer:
                 if self._slots[sid] is owners[i] and self.active[sid]:
                     self.lengths[sid] = lens[i]
                     self.last_token[sid] = last[i]
+        if self.window_pool is not None:
+            # a row passes a page's end once in `page_size` tokens: find
+            # those rows in one comparison, on the serialized commit
+            ids = np.asarray(live_ids)[self.active[live_ids]]
+            due = ids[np.maximum(self.lengths[ids]
+                                 - (self.cfg.sliding_window - 1), 0)
+                      // self.page_size > self._win_lo[ids]]
+            for sid in due:
+                self._window_trim(sid, self.lengths[sid])
         self.decode_rounds += int(counts.shape[0]) * nl
         self.decode_tokens_committed += int(counts.sum())
         sp_drafted = sp_accepted = 0
@@ -2707,6 +2861,7 @@ class PagedInferenceServer:
                 job.got[0] = True
             job.done = d0 + take
             job.planned = max(job.planned, job.done)
+            self._window_trim(sid, int(job.base_lens[0]) + job.done)
             if job.done < rl:
                 continue
             slot = self._slots[sid]
@@ -2785,7 +2940,8 @@ class PagedInferenceServer:
                 [self._slots[j.slots[0]].req.tenant for j in jobs])
             jobs = [jobs[i] for i in order]
         sel: list[tuple[_AdmitJob, int, int]] = []
-        left = self.mixed_token_budget - n_live * win * n_rounds
+        left = room = self.mixed_token_budget - n_live * win * n_rounds
+        widest = 0
         for job in jobs:
             if left <= 0:
                 break
@@ -2793,6 +2949,16 @@ class PagedInferenceServer:
             take = min(rem_left, left, self.prefill_chunk)
             if take <= 0:
                 continue
+            # the budget bounds what the step computes, and the group is
+            # computed at its padded size (`_build_prefill_group`: rows
+            # to a power of two, every row at the widest chunk's bucket):
+            # a row that would pad the group past the budget's room
+            # waits, or a 9th chunk of a few tokens doubles a step of 8
+            slots = _pad_pow2(len(sel) + 1) * _bucket(
+                max(widest, take), self._mixed_buckets)
+            if sel and slots > room:
+                break
+            widest = max(widest, take)
             sel.append((job, take, cur(job)))
             left -= take
         if jobs and not sel:
@@ -2821,8 +2987,7 @@ class PagedInferenceServer:
         chunk = np.full((gp, w), pad_tok, np.int32)
         widths = np.zeros((gp,), np.int32)
         g_lens = np.zeros((gp,), np.int32)
-        g_tables = np.full((gp, self.max_pages_per_slot),
-                           self.allocator.num_pages, np.int32)
+        g_tables = np.full((gp, self._table_cols), self._no_page, np.int32)
         sample_at = np.zeros((gp,), np.int32)
         slot_ids = np.full((gp,), self.max_slots, np.int32)
         countm = np.zeros((gp,), bool)
@@ -2834,6 +2999,9 @@ class PagedInferenceServer:
             chunk[i, :take] = job.rows[0, d0:d0 + take]
             widths[i] = take
             g_lens[i] = int(job.base_lens[0]) + d0
+            self._window_cover(sid, int(g_lens[i]) + take)
+            if self.window_pool is not None:
+                self._stage_keys(int(g_lens[i]) + take, take, decode=False)
             g_tables[i] = self.tables[sid]
             sample_at[i] = min(max(rl - 1 - d0, 0), take - 1)
             slot_ids[i] = sid
@@ -2948,6 +3116,7 @@ class PagedInferenceServer:
                 while n_rounds > n_eff:
                     n_rounds //= 2
                 n_rounds = max(1, n_rounds)
+        self._window_cover_rounds(n_rounds, self.lengths, self.active)
         live = self.active if n_rounds > 0 else np.zeros((b,), bool)
         n_live = int(live.sum())
         # authoritative speculation plan for the dispatch (re-planned:
@@ -3000,6 +3169,7 @@ class PagedInferenceServer:
         # -- decode half (compacted: one row per live slot) -----------------
         (live_ids, sl_d, live_g, d_lens, d_tables, d_last, d_stop,
          samp_d, gid_d, aid_d) = self._gather_decode_rows()
+        self._iter_stats.update(self._take_keys())
         self._iter_stats.update(
             decode_rows=int(live_g.shape[0]) if n_rounds else 0,
             compaction_ratio=(n_live / max(int(live_g.shape[0]), 1)
@@ -3204,6 +3374,7 @@ class PagedInferenceServer:
                     while n_rounds > n_eff:
                         n_rounds //= 2
                     n_rounds = max(1, n_rounds)
+            self._window_cover_rounds(n_rounds, planned_len, planned_active)
             live = (planned_active if n_rounds > 0
                     else np.zeros((b,), bool))
             n_live = int(live.sum())
@@ -3242,6 +3413,7 @@ class PagedInferenceServer:
             sel_mask = pf["sel_mask"]
             (live_ids, sl_d, live_g, d_lens, d_tables, d_last, d_stop,
              samp_d, gid_d, aid_d) = self._gather_decode_rows(live)
+            stats.update(self._take_keys())
             stats.update(
                 decode_rows=int(live_g.shape[0]) if n_rounds else 0,
                 compaction_ratio=(n_live / max(int(live_g.shape[0]), 1)
@@ -3279,12 +3451,14 @@ class PagedInferenceServer:
                 while n > n_eff:
                     n //= 2
                 n = max(1, n)
+            self._window_cover_rounds(n, planned_len, planned_active)
             if prof is not None:
                 prof.enter("build")
             (live_ids, sl_d, live_g, d_lens, d_tables, d_last, d_stop,
              samp_d, gid_d, aid_d) = self._gather_decode_rows(
                  planned_active)
             g_iter, spec_lens = self._spec_plan(live_ids)
+            stats.update(self._take_keys())
             stats.update(
                 scheduler=self.scheduler, n_live=len(live_ids),
                 decode_rounds=n,
@@ -3893,6 +4067,12 @@ class PagedInferenceServer:
         st["pool_free"] = free
         st["pool_cached"] = cached
         st["pool_active"] = al.num_pages - free - cached
+        if self.window_pool is not None:
+            # the window kind beside the full kind: `pool_*` and
+            # `pages_total` go on describing the full kind alone
+            st["window_pool_active"] = self.window_pool.active
+            st["window_num_pages"] = self.window_pool.num_pages
+            st.setdefault("pages_returned", 0)
         frac = (free + cached) / max(al.num_pages, 1)
         st["pool_evictable_frac"] = frac
         h = self._cache_hists.get("evictable_frac")
@@ -4111,6 +4291,17 @@ class PagedInferenceServer:
         reg.counter("pages_released_total",
                     "KV pages whose refcount reached zero (cached or "
                     "freed)").set_total(self.allocator.pages_released)
+        # the window kind's pool of a model with sliding-window layers;
+        # always registered (0 for a model of one kind)
+        wp = self.window_pool
+        reg.gauge("window_pages_active",
+                  "Pages of the window kind's pool held by slots (a "
+                  "model with sliding-window layers; given back behind "
+                  "the window at every commit)"
+                  ).set(0 if wp is None else wp.active)
+        reg.counter("window_pages_returned_total",
+                    "Window-kind pages given back to their pool"
+                    ).set_total(0 if wp is None else wp.pages_returned)
         reg.gauge("cache_namespaces",
                   "Distinct KV namespaces (base model + LoRA "
                   "adapters) that touched the prefix cache").set(
@@ -4268,6 +4459,12 @@ class PagedInferenceServer:
                 "pages_active": s.pages_active,
                 "evictable_frac": ((s.pages_free + s.pages_cached)
                                    / max(s.pages_total, 1)),
+                # the window kind's pool, of a model that has one; the
+                # keys above describe the full kind alone
+                **({} if self.window_pool is None else {
+                    "window_pool_active": self.window_pool.active,
+                    "window_num_pages": self.window_pool.num_pages,
+                    "window_pages_per_slot": self.window_pages_per_slot}),
             },
             "prefix": {
                 "hit_pages": hit_pages,
@@ -4450,6 +4647,15 @@ class PagedInferenceServer:
 
     # -- live migration -----------------------------------------------------
 
+    def _refuse_window(self, mechanism: str) -> None:
+        """Raise for a mechanism that moves or shares pages of the full
+        kind only, on a model that also has window layers."""
+        if self.window_pool is not None:
+            raise ValueError(
+                f"{mechanism} moves the full kind's pages only and this "
+                "model also has sliding-window layers, whose pages it "
+                "would leave behind; not supported for such a model")
+
     def migrate_export(self, req: Request, *, reason: str = "failover",
                        evacuate: bool = True):
         """Snapshot one live (slot or pending) request for migration
@@ -4471,6 +4677,7 @@ class PagedInferenceServer:
         outcome back. A request mid-admission (chunked prefill still
         dispatching) is not exportable and raises RuntimeError; the
         caller lets it finish or fail normally."""
+        self._refuse_window("live migration (migrate_export)")
         led = self._migration
         led.record_export_start()
         try:
@@ -4659,6 +4866,7 @@ class PagedInferenceServer:
         Returns the new Request handle. Only NEW tokens are emitted
         on `stream`; the snapshot's already-delivered tokens are
         pre-filled so the client keeps one contiguous stream."""
+        self._refuse_window("live migration (migrate_import)")
         from cloud_server_tpu.inference.migration import (
             MIGRATION_VERSION)
         led = self._migration
@@ -4923,6 +5131,7 @@ class PagedInferenceServer:
         with self._lock:
             self._draining = True
         if migrate is not None:
+            self._refuse_window("drain(migrate=...)")
             self._evacuate(migrate)
         deadline = (None if timeout is None
                     else time.perf_counter() + timeout)

@@ -180,6 +180,16 @@ class ReplicatedRouter:
         # placement/handoff path below short-circuits, byte-identical
         # to the role-less router (pinned by the existing exact-output
         # and dispatch-count guard tests).
+        for r in self.replicas:
+            if getattr(getattr(r, "cfg", None), "has_window_layers", False):
+                # failover's live migration, drain(migrate=True) and the
+                # prefill/decode hand-off all export the full kind's
+                # pages alone (inference/migration.py)
+                raise ValueError(
+                    "ReplicatedRouter: live migration and the "
+                    "disaggregated hand-off move one kind of page; a "
+                    "model with sliding-window layers is served by a lone "
+                    "PagedInferenceServer")
         if roles is None:
             self.roles = [ROLE_COLOCATED] * len(self.replicas)
         else:

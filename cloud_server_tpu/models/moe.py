@@ -42,7 +42,7 @@ from jax import lax
 
 from cloud_server_tpu.config import ModelConfig
 from cloud_server_tpu.models import transformer
-from cloud_server_tpu.ops import rms_norm, rope_table
+from cloud_server_tpu.ops import gated, rms_norm, rope_table
 from cloud_server_tpu.parallel.mesh import maybe_current_mesh
 
 Params = dict
@@ -52,12 +52,31 @@ Params = dict
 # Routing
 # ---------------------------------------------------------------------------
 
-# The fewest tokens of a call that the sorted dispatch takes. Placed by the
-# v5e measurements of PERF.md (PR 26, PR 32): below it the experts' weight
-# stream bounds both dispatches and the dense one has no sort and no
-# gathers. A whole layer at Mixtral's widths, dense and sorted: 320 tokens
-# 5.01 and 5.26 ms, 384 tokens 6.17 and 5.27.
+# The fewest tokens of a call that the sorted dispatch takes at 8 experts, 2
+# a token. Placed by the v5e measurements of PERF.md (PR 26, PR 32): below
+# it the experts' weight stream bounds both dispatches and the dense one has
+# no sort and no gathers. A whole layer at Mixtral's widths, dense and
+# sorted: 320 tokens 5.01 and 5.26 ms, 384 tokens 6.17 and 5.27.
 GROUPED_MIN_TOKENS = 384
+
+
+def grouped_min_tokens(cfg: ModelConfig) -> int:
+    """The fewest tokens of a call that the sorted dispatch takes, from the
+    experts and the experts a token. The dense dispatch's bookkeeping (the
+    (T, k, E, C) one-hots and the two contractions over (T, E, C)) grows
+    with E * k * T * T where the sort and the gathers grow with k * T, so
+    the more assignments there are to place, the sooner the sorted one
+    wins. Measured on the v5e at two configurations (PERF.md, PR 32 and PR
+    35): 8 experts of 14,336, 2 a token: dense wins at 320 tokens and
+    loses from 384; 64 experts of 768, 6 a token: dense 1.13 and sorted
+    1.22 ms a layer at 128 tokens, 1.34 and 1.28 at 192. The fourth root
+    of E * k passes through both (384 and 174) and is a fit, not a law: a
+    third configuration tests it, and nothing was measured under 16
+    assignments, where the placed value stands. The widths do not enter:
+    both dispatches leave the weight stream near 240 tokens a call at any
+    width (peak FLOPs over peak bytes)."""
+    assignments = cfg.num_experts * cfg.num_experts_per_token
+    return round(GROUPED_MIN_TOKENS * min(1.0, (16 / assignments) ** 0.25))
 
 
 def _capacity(cfg: ModelConfig, num_tokens: int) -> int:
@@ -91,7 +110,7 @@ def _dispatch_grouped(cfg: ModelConfig, num_tokens: int, stack) -> bool:
         isinstance(stack[0][name], jax.Array)
         and stack[0][name].dtype == jnp.dtype(cfg.dtype)
         for name in ("w_gate", "w_up", "w_down"))
-    return (num_tokens >= GROUPED_MIN_TOKENS
+    return (num_tokens >= grouped_min_tokens(cfg)
             and _capacity(cfg, num_tokens) >= num_tokens
             and in_place
             and (mesh is None or mesh.size == 1))
@@ -166,20 +185,37 @@ def top_k_routing(router_logits: jnp.ndarray, k: int, capacity: int):
 # ---------------------------------------------------------------------------
 
 # (rows, contraction, columns) tiles of the grouped matmul kernel on the
-# TPU, for x @ w_gate|w_up and for act @ w_down, from the v5e sweep of
-# PERF.md (PR 26). A row tile that straddles two experts is computed for
-# both, so fewer rows waste less; a weight tile is fetched once per row
-# tile unless it spans the contraction (as 4,096 does on the way in: an
-# expert's consecutive row tiles then reuse it), so more rows fetch less.
+# TPU, for x @ w_gate|w_up and for act @ w_down. A row tile that straddles
+# two experts is computed for both, so fewer rows waste less; a weight tile
+# is fetched once per row tile unless it spans the contraction (as 4,096
+# does on the way in at Mixtral's widths: an expert's consecutive row tiles
+# then reuse it), so more rows fetch less. `_gmm_tilings` places them from
+# the widths: the weight tile is the largest of 4 MiB or less that spans
+# the contraction where the contraction is 4,096 or less, and takes 1,024
+# of it otherwise. At (4,096 x 14,336) that is the (256, 4096, 512) and
+# (256, 1024, 2048) of the v5e sweep of PERF.md (PR 26); at (2,560 x 768)
+# an expert's whole matrix is one tile (the sweep of PR 35).
 # `_GMM_ROWS` is both tilings' row tile. The sorted rows are gathered out
 # to a whole number of them (`_moe_grouped`): rows past the last group
 # belong to no expert, are not computed and are never gathered back. A
-# mixed step's one walk brings 2 * (chunk tokens + decode rows) of them,
+# mixed step's one walk brings k * (chunk tokens + decode rows) of them,
 # never whole tiles: padding each matmul's operand instead copied the
 # (rows, F) activation once a layer.
 _GMM_ROWS = 256
-_GMM_TILING_IN = (_GMM_ROWS, 4096, 512)
-_GMM_TILING_OUT = (_GMM_ROWS, 1024, 2048)
+_GMM_WEIGHT_TILE_BYTES = 4 << 20
+
+
+def _gmm_tiling(k: int, n: int, itemsize: int = 2) -> tuple:
+    """(rows, contraction, columns) of the grouped matmul (M, k) @ (k, n)."""
+    tk = k if k <= 4096 else 1024
+    tn = _GMM_WEIGHT_TILE_BYTES // (tk * itemsize) // 128 * 128
+    return (_GMM_ROWS, tk, max(128, min(n, tn)))
+
+
+def _gmm_tilings(cfg: ModelConfig) -> tuple:
+    """The tilings of the way in (D -> F) and of the way out (F -> D)."""
+    return (_gmm_tiling(cfg.embed_dim, cfg.mlp_dim),
+            _gmm_tiling(cfg.mlp_dim, cfg.embed_dim))
 
 
 def _grouped_matmul(lhs, rhs, group_sizes, tiling, kernel: bool):
@@ -208,13 +244,17 @@ def _grouped_matmul(lhs, rhs, group_sizes, tiling, kernel: bool):
 
 # jitted so that its trace (three Pallas kernels on the TPU) is cached by
 # shape: every layer of every step program calls it, at a few row counts
-@partial(jax.jit, static_argnames=("kernel",))
-def _grouped_experts(rows, w_gate, w_up, w_down, group_sizes, kernel: bool):
-    """SwiGLU experts over rows sorted by expert: (M, D) -> (M, D)."""
-    gate = _grouped_matmul(rows, w_gate, group_sizes, _GMM_TILING_IN, kernel)
-    up = _grouped_matmul(rows, w_up, group_sizes, _GMM_TILING_IN, kernel)
-    act = jax.nn.silu(gate) * up
-    return _grouped_matmul(act, w_down, group_sizes, _GMM_TILING_OUT, kernel)
+@partial(jax.jit, static_argnames=("kernel", "activation", "tilings"))
+def _grouped_experts(rows, w_gate, w_up, w_down, group_sizes, kernel: bool,
+                     activation: str = "silu", tilings: tuple | None = None):
+    """Gated experts over rows sorted by expert: (M, D) -> (M, D).
+    `tilings`: `_gmm_tilings`' pair, placed from the widths without one."""
+    t_in, t_out = tilings or (_gmm_tiling(*w_gate.shape[1:]),
+                              _gmm_tiling(*w_down.shape[1:]))
+    gate = _grouped_matmul(rows, w_gate, group_sizes, t_in, kernel)
+    up = _grouped_matmul(rows, w_up, group_sizes, t_in, kernel)
+    act = gated(gate, up, activation)
+    return _grouped_matmul(act, w_down, group_sizes, t_out, kernel)
 
 
 def _moe_grouped(tokens, router_logits, layers, layer, cfg: ModelConfig):
@@ -246,7 +286,8 @@ def _moe_grouped(tokens, router_logits, layers, layer, cfg: ModelConfig):
             rows, *(layers[name].reshape((n_layers * e,)
                                          + layers[name].shape[2:])
                     for name in ("w_gate", "w_up", "w_down")),
-            group_sizes, kernel=jax.default_backend() == "tpu")
+            group_sizes, kernel=jax.default_backend() == "tpu",
+            activation=cfg.mlp_activation, tilings=_gmm_tilings(cfg))
     with jax.named_scope("moe_combine"):
         inverse = jnp.zeros((t * k,), jnp.int32).at[order].set(
             jnp.arange(t * k, dtype=jnp.int32))
@@ -259,10 +300,14 @@ def _moe_grouped(tokens, router_logits, layers, layer, cfg: ModelConfig):
     return out, aux
 
 
-def moe_mlp(x: jnp.ndarray, lp: dict, cfg: ModelConfig, stack=None):
-    """Expert-parallel SwiGLU MoE layer.
+def moe_mlp(x: jnp.ndarray, lp: dict, cfg: ModelConfig, stack=None,
+            router_x=None):
+    """Expert-parallel gated MoE layer (`cfg.mlp_activation`).
 
     x: (B, S, D). lp: router (D, E), w_gate/w_up (E, D, F), w_down (E, F, D).
+    router_x: (B, S, D) what the router reads where that is not `x`
+    (`cfg.router_input` "layer_input": the layer's input travels here
+    beside the normed activations the experts read).
     stack: optionally (layers, index), the stacked (L, ...) parameters
     that `lp` is layer `index` of: a caller that unrolls its layers says
     so, and the sorted dispatch may then run (`_dispatch_grouped`); one
@@ -276,7 +321,9 @@ def moe_mlp(x: jnp.ndarray, lp: dict, cfg: ModelConfig, stack=None):
     # (`tf_op` of an op's event metadata; cellbench/hostplane.py)
     with jax.named_scope("moe_route"):
         router_logits = jnp.einsum(
-            "td,de->te", tokens.astype(jnp.float32),
+            "td,de->te",
+            (tokens if router_x is None
+             else router_x.reshape(b * s, d)).astype(jnp.float32),
             lp["router"].astype(jnp.float32))
     if _dispatch_grouped(cfg, b * s, stack):
         out, aux = _moe_grouped(tokens, router_logits, *stack, cfg)
@@ -294,7 +341,7 @@ def moe_mlp(x: jnp.ndarray, lp: dict, cfg: ModelConfig, stack=None):
         gate = jnp.einsum("ecd,edf->ecf", xs,
                           lp["w_gate"].astype(cfg.dtype))
         up = jnp.einsum("ecd,edf->ecf", xs, lp["w_up"].astype(cfg.dtype))
-        act = jax.nn.silu(gate) * up
+        act = gated(gate, up, cfg.mlp_activation)
         ys = jnp.einsum("ecf,efd->ecd", act,
                         lp["w_down"].astype(cfg.dtype))
     with jax.named_scope("moe_combine"):
@@ -357,21 +404,31 @@ def init_params(cfg: ModelConfig, rng: jax.Array) -> Params:
     return jax.tree.unflatten(treedef, out)
 
 
-def moe_mlp_block(x, lp, cfg: ModelConfig, stack=None):
+def moe_mlp_block(x, lp, cfg: ModelConfig, stack=None, layer_in=None):
     """Residual MoE MLP sub-block: norm -> route/experts -> add.
 
     The single definition shared by training (`_moe_block`) and the
     inference engine (`engine._mlp_apply`), so serve-time MoE math can
     never drift from the trained model. `stack`: see `moe_mlp`.
+    `layer_in`: the layer's input, before its attention; what the router
+    reads under `cfg.router_input` "layer_input", and then required.
     """
     h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
-    out, aux = moe_mlp(h, lp, cfg, stack)
+    router_x = None
+    if cfg.router_input == "layer_input":
+        if layer_in is None:
+            raise ValueError("router_input='layer_input': the caller has "
+                             "to hand the layer's input to the MLP block")
+        router_x = layer_in
+    out, aux = moe_mlp(h, lp, cfg, stack, router_x)
     return x + out, aux
 
 
-def _moe_block(x, lp, cfg: ModelConfig, cos, sin, attn_fn, positions=None):
-    x = transformer._attention_block(x, lp, cfg, cos, sin, attn_fn, positions)
-    return moe_mlp_block(x, lp, cfg)
+def _moe_block(x, lp, cfg: ModelConfig, cos, sin, attn_fn, positions=None,
+               flags=None):
+    y = transformer._attention_block(x, lp, cfg, cos, sin, attn_fn,
+                                     positions, flags)
+    return moe_mlp_block(y, lp, cfg, layer_in=x)
 
 
 def forward_hidden(params: Params, tokens: jnp.ndarray, cfg: ModelConfig,
@@ -402,15 +459,19 @@ def forward_hidden(params: Params, tokens: jnp.ndarray, cfg: ModelConfig,
                     positions=positions)
     block = transformer.apply_remat(block, cfg)
 
-    def scan_body(carry, lp):
+    flags = transformer.layer_flags(cfg)
+
+    def scan_body(carry, xs):
         x, lb, rz, dropped = carry
-        x, aux = block(x, lp)
+        x, aux = (block(x, xs) if flags is None
+                  else block(x, xs[0], flags=xs[1]))
         return (x, lb + aux["load_balance"], rz + aux["router_z"],
                 dropped + aux["dropped_frac"]), None
 
     zero = jnp.zeros((), jnp.float32)
     (x, lb, rz, dropped), _ = lax.scan(
-        scan_body, (x, zero, zero, zero), params["layers"])
+        scan_body, (x, zero, zero, zero),
+        params["layers"] if flags is None else (params["layers"], flags))
     x = rms_norm(x, params["final_norm"]["scale"], cfg.norm_eps)
     n = cfg.num_layers
     aux = {"load_balance": lb / n, "router_z": rz / n, "dropped_frac": dropped / n}

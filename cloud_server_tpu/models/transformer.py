@@ -22,8 +22,8 @@ import jax.numpy as jnp
 from jax import lax
 
 from cloud_server_tpu.config import ModelConfig
-from cloud_server_tpu.ops import (apply_rope, causal_attention, rms_norm,
-                                  rope_table, swiglu)
+from cloud_server_tpu.ops import (apply_rope, causal_attention, gated,
+                                  rms_norm, rope_table)
 from cloud_server_tpu.parallel.mesh import kernel_mesh
 from cloud_server_tpu.parallel.sharding import constrain
 
@@ -128,13 +128,17 @@ def lora_row_delta(h, ab) -> jnp.ndarray:
 
 
 def attention_qkv(x, lp, cfg: ModelConfig, cos, sin, positions=None,
-                  lora=None):
+                  lora=None, rope=True):
     """Pre-norm + q/k/v projection + rope. Single source of truth for the
     attention input path — the inference engine's prefill/decode reuse this
     so cached inference can never drift numerically from training.
 
     `lora` (serving only): {target: (a, b, scale)} per-row adapters —
-    deltas land BEFORE rope, exactly where a merged weight would."""
+    deltas land BEFORE rope, exactly where a merged weight would.
+
+    `rope`: whether this layer rotates q and k (`cfg.layer_rope`): a
+    bool where the caller unrolls its layers, a traced flag where it
+    scans over them."""
     h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
     q = jnp.einsum("bsd,dhk->bshk", h, lp["wq"].astype(cfg.dtype))
     k = jnp.einsum("bsd,dhk->bshk", h, lp["wk"].astype(cfg.dtype))
@@ -146,9 +150,13 @@ def attention_qkv(x, lp, cfg: ModelConfig, cos, sin, positions=None,
             k = k + lora_row_delta(h, lora["wk"]).reshape(k.shape)
         if "wv" in lora:
             v = v + lora_row_delta(h, lora["wv"]).reshape(v.shape)
-    q = apply_rope(q, cos, sin, positions)
-    k = apply_rope(k, cos, sin, positions)
-    return q, k, v
+    if rope is False:
+        return q, k, v
+    qr = apply_rope(q, cos, sin, positions)
+    kr = apply_rope(k, cos, sin, positions)
+    if rope is True:
+        return qr, kr, v
+    return jnp.where(rope, qr, q), jnp.where(rope, kr, k), v
 
 
 def attention_out(x, o, lp, cfg: ModelConfig, lora=None):
@@ -160,10 +168,33 @@ def attention_out(x, o, lp, cfg: ModelConfig, lora=None):
     return x + y
 
 
+def layer_flags(cfg: ModelConfig):
+    """What a scan over the layers carries beside their parameters when
+    the layers differ (`cfg.has_layer_pattern`): per layer its window (0
+    = every key) and whether it rotates q and k; None otherwise."""
+    if not cfg.has_layer_pattern:
+        return None
+    if cfg.attention_impl != "xla":
+        raise ValueError(
+            "a pattern of window or position-free layers needs "
+            f"attention_impl='xla'; {cfg.attention_impl!r} has no lower "
+            "bound of the keys")
+    n = range(cfg.num_layers)
+    return {"window": jnp.asarray([cfg.layer_window(i) for i in n],
+                                  jnp.int32),
+            "rope": jnp.asarray([cfg.layer_rope(i) for i in n], bool)}
+
+
 def _attention_block(x, lp, cfg: ModelConfig, cos, sin, attn_fn,
-                     positions=None):
-    q, k, v = attention_qkv(x, lp, cfg, cos, sin, positions)
-    o = attn_fn(q, k, v)
+                     positions=None, flags=None):
+    """`flags`: this layer's entry of `layer_flags`, or None."""
+    if flags is None:
+        q, k, v = attention_qkv(x, lp, cfg, cos, sin, positions)
+        o = attn_fn(q, k, v)
+    else:
+        q, k, v = attention_qkv(x, lp, cfg, cos, sin, positions,
+                                rope=flags["rope"])
+        o = attn_fn(q, k, v, window=flags["window"])
     return attention_out(x, o, lp, cfg)
 
 
@@ -176,7 +207,7 @@ def mlp_block(x, lp, cfg: ModelConfig, lora=None):
             gate = gate + lora_row_delta(h, lora["w_gate"])
         if "w_up" in lora:
             up = up + lora_row_delta(h, lora["w_up"])
-    act = swiglu(gate, up)
+    act = gated(gate, up, cfg.mlp_activation)
     down = jnp.einsum("bsf,fd->bsd", act, lp["w_down"].astype(cfg.dtype))
     if lora and "w_down" in lora:
         down = down + lora_row_delta(act, lora["w_down"])
@@ -197,8 +228,9 @@ def unembed(x, params: Params, cfg: ModelConfig) -> jnp.ndarray:
 
 
 def _block(x, layer_params, cfg: ModelConfig, cos, sin, attn_fn,
-           positions=None):
-    x = _attention_block(x, layer_params, cfg, cos, sin, attn_fn, positions)
+           positions=None, flags=None):
+    x = _attention_block(x, layer_params, cfg, cos, sin, attn_fn, positions,
+                         flags)
     x = mlp_block(x, layer_params, cfg)
     return x
 
@@ -333,10 +365,15 @@ def forward_hidden(params: Params, tokens: jnp.ndarray,
                     positions=positions)
     block = apply_remat(block, cfg)
 
-    def scan_body(carry, layer_params):
-        return block(carry, layer_params), None
+    flags = layer_flags(cfg)
 
-    x, _ = lax.scan(scan_body, x, params["layers"],
+    def scan_body(carry, xs):
+        if flags is None:
+            return block(carry, xs), None
+        return block(carry, xs[0], flags=xs[1]), None
+
+    x, _ = lax.scan(scan_body, x, params["layers"] if flags is None
+                    else (params["layers"], flags),
                     unroll=cfg.scan_layers_unroll)
     x = constrain(x, ("batch", "sequence", None))
     return rms_norm(x, params["final_norm"]["scale"], cfg.norm_eps)

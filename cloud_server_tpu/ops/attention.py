@@ -30,6 +30,7 @@ def causal_attention(
     segment_ids: jnp.ndarray | None = None,
     k_scale: jnp.ndarray | None = None,
     v_scale: jnp.ndarray | None = None,
+    window=0,
 ) -> jnp.ndarray:
     """Causal grouped-query attention, dense XLA implementation.
 
@@ -49,6 +50,9 @@ def causal_attention(
         block-diagonal causal structure packed training needs. The causal
         mask itself stays on global row positions (within a segment the
         global and local orders agree; across segments this mask wins).
+      window: keys a query reads, its own included: a key at position j
+        is kept for a query at i where i - j < window. 0 = every key. An
+        int, or an int32 scalar where a scan carries it per layer.
       k_scale, v_scale: optional (B, Skv, KH, 1) f32 absmax scales for an
         int8 k/v (engine `_kv_quant` layout). Dequantization is folded
         into the attention math — scales are per (position, head), so
@@ -88,6 +92,9 @@ def causal_attention(
     kv_pos = (jnp.arange(skv) + kv_segment_start)[None, :]  # (1, Skv)
 
     causal = q_pos[:, :, None] >= kv_pos[:, None, :]  # (B|1, Sq, Skv)
+    if not (isinstance(window, int) and window == 0):
+        near = q_pos[:, :, None] - kv_pos[:, None, :] < window
+        causal = jnp.logical_and(causal, jnp.logical_or(near, window <= 0))
     if kv_length is not None:
         valid = kv_pos < kv_length[:, None]  # (B, Skv)
         causal = jnp.logical_and(causal, valid[:, None, :])

@@ -27,6 +27,16 @@ int32 page table, so slot memory scales with actual context (not
 max_slots x max_len) and pages can be shared between slots (refcounted
 prefix reuse — see inference/block_allocator.py).
 
+A sliding-window layer passes `window`, the keys a query reads with its
+own: the key at j is kept for the query at i where i - j < window, per
+query row of a wide chunk. Both kernels start at the block that holds the
+first query's oldest key (`_first_block`), not at block 0, so the pages
+wholly behind the bound are never fetched and the table's entries for
+them may be anything: the host gives those pages back to their pool
+(inference/paged_engine.py, `PagedKVCache`). With 7 query heads a key
+head the folded W*G rows of a decode call are 7, no multiple of the
+sublane tile; Mosaic pads them (AOT for a v5e, PR 35).
+
 Design (and why it can beat streaming the cache through XLA einsums):
 
   * The pool lives in HBM (`memory_space=ANY`); the kernel issues its own
@@ -88,6 +98,18 @@ def _dot(a, b, dims):
                            preferred_element_type=jnp.float32)
 
 
+def _first_block(kv_len, width, window: int, blk: int, n_blocks):
+    """The first block of keys a row's window of queries reads: block 0
+    without a lower bound (`window` 0), else the block that holds the
+    first query's oldest key, position (kv_len - width) - (window - 1).
+    Blocks wholly behind it are never fetched; inside it the mask
+    decides. Never past the row's last block, so every row runs one."""
+    if not window:
+        return 0
+    lo = jnp.maximum(kv_len - width - (window - 1), 0)
+    return jnp.minimum(lax.div(lo, blk), n_blocks - 1)
+
+
 # ---------------------------------------------------------------------------
 # Kernel
 # ---------------------------------------------------------------------------
@@ -112,6 +134,7 @@ def _paged_attention_kernel(
     ps: int,
     npages: int,
     int8_kv: bool,
+    window: int,
 ):
     if int8_kv:
         (ks_pool_ref, vs_pool_ref, o_ref,
@@ -133,6 +156,10 @@ def _paged_attention_kernel(
         # every slot runs >= 1 block so the cross-slot DMA prefetch chain
         # stays uniform (each started copy has exactly one matching wait)
         return jnp.maximum(1, lax.div(lens_ref[b] + blk - 1, blk))
+
+    def first_block(b):
+        return _first_block(lens_ref[b], widths_ref[b], window, blk,
+                            n_blocks(b))
 
     def _copies(buf_idx, page_ids):
         """The async-copy descriptors of one block fetch; `start` on each
@@ -177,7 +204,7 @@ def _paged_attention_kernel(
             c.wait()
 
     # prologue: first block of slot 0 into buffer 0
-    start_fetch(0, 0, 0)
+    start_fetch(0, first_block(0), 0)
 
     buf_idx = jnp.int32(0)
     for b in range(batch):  # static unroll over slots
@@ -196,7 +223,8 @@ def _paged_attention_kernel(
             # prefetch next block (or the next slot's first block) into
             # the other buffer while this one computes
             is_last = i == nb - 1
-            nxt = jnp.where(is_last, 0, i + 1)
+            nxt = jnp.where(
+                is_last, first_block(b + 1) if b + 1 < batch else 0, i + 1)
             if b + 1 < batch:
                 nxt_b = jnp.where(is_last, b + 1, b)
                 start_fetch(nxt_b, nxt, 1 - buf_idx)
@@ -212,6 +240,8 @@ def _paged_attention_kernel(
             # col < kv_len is implied by col <= row for the last row but
             # not for earlier window rows; both bounds are needed
             mask = jnp.logical_and(col_pos <= row_pos, col_pos < kv_len)
+            if window:
+                mask = jnp.logical_and(mask, row_pos - col_pos < window)
 
             new_state = []
             for h in range(kh):
@@ -251,7 +281,7 @@ def _paged_attention_kernel(
             init += [jnp.full((wg, 1), NEG_INF, jnp.float32),
                      jnp.zeros((wg, 1), jnp.float32),
                      jnp.zeros((wg, d), jnp.float32)]
-        out = lax.fori_loop(0, n_blocks(b), body, tuple(init))
+        out = lax.fori_loop(first_block(b), n_blocks(b), body, tuple(init))
         buf_idx = out[0]
         for h in range(kh):
             # inactive slots (kv_len 0) divide garbage by blk — finite,
@@ -275,7 +305,8 @@ _NARROW_MAX_B = 16
 def paged_attention(q, k_pool, v_pool, lengths, tables, layer=0, *,
                     scale=None, pages_per_block: int = 4,
                     interpret: bool | None = None,
-                    k_scale_pool=None, v_scale_pool=None, widths=None):
+                    k_scale_pool=None, v_scale_pool=None, widths=None,
+                    window: int = 0):
     """Uniform- or ragged-window attention against a paged KV cache.
 
     Args:
@@ -301,6 +332,11 @@ def paged_attention(q, k_pool, v_pool, lengths, tables, layer=0, *,
       layer: int or scalar int32 — pool layer to attend against.
       k_scale_pool, v_scale_pool: (L, num_pages, KH, page_size) f32
         absmax scales when the pools are int8.
+      window: static int, the keys a query reads, its own included (a
+        sliding-window layer): the key at j is kept for the query at i
+        where i - j < window; 0 = every key. Blocks wholly behind the
+        first query's bound are never fetched, so the table's entries
+        for them may be anything (their pages given back).
 
     Returns (B, W, H, Dh) in q.dtype. Equivalent to gathering each slot's
     pages into a contiguous cache and running
@@ -331,7 +367,8 @@ def paged_attention(q, k_pool, v_pool, lengths, tables, layer=0, *,
         out = _paged_attention_wide(
             qg, k_pool, v_pool, lengths, tables, widths, layer, scale=scale,
             npages=npages, interpret=interpret,
-            k_scale_pool=k_scale_pool, v_scale_pool=v_scale_pool, w=w, g=g)
+            k_scale_pool=k_scale_pool, v_scale_pool=v_scale_pool, w=w, g=g,
+            window=window)
         return out.reshape(b, kh, w, g, d).transpose(0, 2, 1, 3, 4).reshape(
             b, w, h, d)
 
@@ -367,7 +404,7 @@ def paged_attention(q, k_pool, v_pool, lengths, tables, layer=0, *,
     )
     kernel = functools.partial(
         _paged_attention_kernel, scale=float(scale), batch=b, w=w, g=g,
-        kh=kh, ps=ps, npages=npages, int8_kv=int8_kv)
+        kh=kh, ps=ps, npages=npages, int8_kv=int8_kv, window=int(window))
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
@@ -382,11 +419,18 @@ def paged_attention(q, k_pool, v_pool, lengths, tables, layer=0, *,
         b, w, h, d)
 
 
+@functools.partial(jax.jit, static_argnames=(
+    "scale", "npages", "interpret", "w", "g", "window"))
 def _paged_attention_wide(qg, k_pool, v_pool, lengths, tables, widths,
                           layer, *, scale, npages, interpret, k_scale_pool,
-                          v_scale_pool, w, g):
+                          v_scale_pool, w, g, window=0):
     """Grid-over-(slot, kv head) dispatch for wide windows / big batches.
-    qg: (B, KH, WG, Dh) folded queries; returns the same layout."""
+    qg: (B, KH, WG, Dh) folded queries; returns the same layout.
+
+    Jitted, with `layer` an operand: the unrolled walk of a serving
+    program calls it once a layer and row set, and the layers of one kind
+    share one trace and one Mosaic lowering (4 of each where a program of
+    8 layers made 16: 2.6 of the 6.4 s it took to trace and lower)."""
     b, kh, wg, d = qg.shape
     ps = k_pool.shape[-1]
     int8_kv = k_scale_pool is not None
@@ -421,7 +465,7 @@ def _paged_attention_wide(qg, k_pool, v_pool, lengths, tables, widths,
     )
     kernel = functools.partial(
         _paged_attention_wide_kernel, scale=float(scale), w=w, g=g,
-        ps=ps, npages=npages, int8_kv=int8_kv)
+        ps=ps, npages=npages, int8_kv=int8_kv, window=int(window))
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
@@ -450,6 +494,7 @@ def _paged_attention_wide_kernel(
     ps: int,
     npages: int,
     int8_kv: bool,
+    window: int,
 ):
     """Wide-window (prefill-chunk) variant: one grid cell per
     (slot, kv head) instead of a whole-batch unroll.
@@ -481,6 +526,7 @@ def _paged_attention_wide_kernel(
     dot_dtype = (jnp.float32 if k_pool_ref.dtype == jnp.float32
                  else jnp.bfloat16)
     n_blocks = jnp.maximum(1, lax.div(kv_len + blk - 1, blk))
+    first = _first_block(kv_len, widths_ref[b], window, blk, n_blocks)
 
     def _copies(buf_idx, page_ids):
         """One block's async copies — PER-HEAD (Dh, ps) slices here (the
@@ -520,14 +566,14 @@ def _paged_attention_wide_kernel(
         for c in _copies(buf_idx, [0] * npages):
             c.wait()
 
-    start_fetch(0, 0)
+    start_fetch(first, 0)
     row_pos = (kv_len - widths_ref[b]) + lax.broadcasted_iota(
         jnp.int32, (wg, blk), 0) // g
     qh = q_ref[0, 0].astype(dot_dtype)  # (WG, Dh)
 
     def body(i, carry):
         m_prev, l_prev, acc_prev = carry
-        buf_idx = lax.rem(i, 2)
+        buf_idx = lax.rem(i - first, 2) if window else lax.rem(i, 2)
 
         @pl.when(i + 1 < n_blocks)
         def _():
@@ -537,6 +583,8 @@ def _paged_attention_wide_kernel(
 
         col_pos = i * blk + lax.broadcasted_iota(jnp.int32, (wg, blk), 1)
         mask = jnp.logical_and(col_pos <= row_pos, col_pos < kv_len)
+        if window:
+            mask = jnp.logical_and(mask, row_pos - col_pos < window)
 
         cols = []
         for p in range(npages):
@@ -565,7 +613,7 @@ def _paged_attention_wide_kernel(
     m0 = jnp.full((wg, 1), NEG_INF, jnp.float32)
     l0 = jnp.zeros((wg, 1), jnp.float32)
     a0 = jnp.zeros((wg, d), jnp.float32)
-    _, l_f, acc_f = lax.fori_loop(0, n_blocks, body, (m0, l0, a0))
+    _, l_f, acc_f = lax.fori_loop(first, n_blocks, body, (m0, l0, a0))
     o_ref[0, 0] = (acc_f / jnp.maximum(l_f, 1e-30)).astype(o_ref.dtype)
 
 
@@ -573,7 +621,8 @@ def paged_attention_tp(q, k_pool, v_pool, lengths, tables, layer=0, *,
                        mesh, axis_name: str = "tp", scale=None,
                        pages_per_block: int = 4,
                        interpret: bool | None = None,
-                       k_scale_pool=None, v_scale_pool=None, widths=None):
+                       k_scale_pool=None, v_scale_pool=None, widths=None,
+                       window: int = 0):
     """`paged_attention` under tensor parallelism: kv heads shard over
     `axis_name`, each device runs the kernel on its local heads.
 
@@ -614,7 +663,8 @@ def paged_attention_tp(q, k_pool, v_pool, lengths, tables, layer=0, *,
             q_l, k_l, v_l, lens, tabs, layer, scale=scale,
             pages_per_block=pages_per_block, interpret=interpret,
             k_scale_pool=scales[0] if scales else None,
-            v_scale_pool=scales[1] if scales else None, widths=wid)
+            v_scale_pool=scales[1] if scales else None, widths=wid,
+            window=window)
 
     return jax.shard_map(local, mesh=mesh, in_specs=tuple(in_specs),
                          out_specs=head_spec, check_vma=False)(*args)
@@ -646,7 +696,7 @@ def gather_scale_pages(scale_pool, tables, layer=0):
 
 def paged_attention_xla(q, k_pool, v_pool, lengths, tables, layer=0, *,
                         scale=None, k_scale_pool=None, v_scale_pool=None,
-                        widths=None):
+                        widths=None, window: int = 0):
     """Dense-XLA equivalent of `paged_attention` (gather + masked attention).
 
     The test oracle, and the serving fallback on non-TPU backends. The
@@ -669,4 +719,4 @@ def paged_attention_xla(q, k_pool, v_pool, lengths, tables, layer=0, *,
                         else widths)
     pos = anchor[:, None] + jnp.arange(w)[None, :]
     return causal_attention(q, k, v, scale=scale, q_positions=pos,
-                            kv_length=lengths, **scales)
+                            kv_length=lengths, window=int(window), **scales)

@@ -127,8 +127,11 @@ LAYERS, LAYER = 3, 1
 
 
 def _stack(e, k, dtype, seed=0, d=32, f=64):
-    """A no-drop configuration and LAYERS layers of stacked parameters."""
+    """A no-drop configuration and LAYERS layers of stacked parameters,
+    of the widths the configuration states (the grouped matmul's tiles
+    are placed from them)."""
     cfg = ModelConfig(**{**MOE_TINY.__dict__, "num_experts": e,
+                         "embed_dim": d, "mlp_dim": f,
                          "num_experts_per_token": k, "dtype": dtype,
                          "param_dtype": dtype,
                          "expert_capacity_factor": e / k})
@@ -220,7 +223,10 @@ def test_which_dispatch_runs(t, devices8):
     # one that cannot moves at the threshold and above, not below
     moved = t >= moe.GROUPED_MIN_TOKENS
     assert moe._dispatch_grouped(no_drop, t, stack) == moved
-    assert moe._dispatch_grouped(wide, t, (wide_layers, 0)) == moved
+    # at (64, 8) the threshold is the experts' own (PR 35): 161 tokens
+    assert moe.grouped_min_tokens(no_drop) == moe.GROUPED_MIN_TOKENS
+    assert moe._dispatch_grouped(wide, t, (wide_layers, 0)) == (
+        t >= moe.grouped_min_tokens(wide) == 161)
     # a caller that scans its layers has no stack to point into; weights
     # that need a cast or a dequantization first are not used in place
     assert not moe._dispatch_grouped(no_drop, t, None)
