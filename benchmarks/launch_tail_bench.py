@@ -1,0 +1,266 @@
+"""The parts of one launch of the overlapped scheduler, on the host's clock.
+
+Between a read-back (`commit`) and the next program the device has no
+work: `PagedInferenceServer._launch_plan` is serialized by construction
+(PERF.md section 5). This script takes that phase apart at a cell's
+dispatch shapes: decode rows (`--rows`), pages a row (`--pages-per-row`)
+and the argument structure of a configuration's family (`--config`), at
+the family's tiny widths: what the host pays for a launch follows from
+the number and the sizes of the arrays it hands over and from the leaves
+of the call, not from the model's widths, and a tiny program leaves the
+device idle at every launch, as the closed loops of the cells do.
+
+Two tables, one JSON line each:
+
+* `loop`: a server driven by `submit` and `step` through some hundreds of
+  steady-state launches, with the conversions `paged_server` makes and
+  the step's call timed where they are made. By kind of plan, medians:
+  the whole phase, host arrays handed to the device and how many,
+  conversions of arrays that are on the device already and how many,
+  the call of the step program, `_handoff_prefetch`, and the rest (the
+  patch's copies, `_walks_once`, the profiler's boundary).
+* `alone`: the two forms of the patch side by side, outside any server:
+  a key split on the host and four arrays handed over one by one, then
+  some thirty conversions of device arrays (the form before PR 36,
+  whose loop table is in PERF.md section 6), against one packed buffer
+  and one transfer (the form since); and a jitted call handed the
+  staged patch against one handed the host buffer itself.
+
+    python benchmarks/launch_tail_bench.py                    # on a TPU
+    python benchmarks/launch_tail_bench.py --rows 64 --pages-per-row 128 \
+        --config cellbench/configs/smallthinker-21b-a3b-instruct.json
+    JAX_PLATFORMS=cpu python benchmarks/launch_tail_bench.py --requests 40
+
+Prints one JSON line per table and appends them to
+`chiprun_out/launch_tail_bench.jsonl`.
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import random
+import statistics
+import sys
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+from cloud_server_tpu.inference import paged_server as ps  # noqa: E402
+
+PAGE = 128
+STEP_PROGRAMS = ("_mixed_step", "_decode_rounds", "_spec_rounds")
+PARTS = ("h2d", "on_device", "step_call", "prefetch")
+
+
+class _Clock:
+    """Where one launch's time went; `None` outside a launch."""
+
+    def __init__(self):
+        self.parts = None
+        self.depth = 0  # > 0 inside a timed call: its callees are its own
+
+    def timed(self, part, fn, count=False):
+        def call(*args, **kwargs):
+            if self.parts is None or self.depth:
+                return fn(*args, **kwargs)
+            self.depth += 1
+            t0 = time.perf_counter()
+            try:
+                return fn(*args, **kwargs)
+            finally:
+                self.parts[part] += time.perf_counter() - t0
+                self.depth -= 1
+                if count:
+                    self.parts[part + "_n"] += 1
+        return call
+
+    def conversion(self, fn, leaf_of):
+        """`fn` timed as `h2d` or `on_device` by what it is handed."""
+        to_dev = self.timed("h2d", fn, count=True)
+        on_dev = self.timed("on_device", fn, count=True)
+
+        def call(*args, **kwargs):
+            leaf = leaf_of(*args)
+            return (on_dev if isinstance(leaf, jax.Array)
+                    else to_dev)(*args, **kwargs)
+        return call
+
+
+class _Proxy:
+    """A module with some attributes replaced."""
+
+    def __init__(self, real, **replaced):
+        self.__dict__.update(replaced)
+        self._real = real
+
+    def __getattr__(self, name):
+        return getattr(self._real, name)
+
+
+def instrument(srv, clock: _Clock, records: list) -> None:
+    """Time the conversions of `paged_server` and the step's call while a
+    launch runs; one record a launch that dispatched a program."""
+    def first_leaf(fn, tree, *rest):
+        return jax.tree.leaves(tree)[0]
+
+    ps.jnp = _Proxy(jnp, asarray=clock.conversion(
+        jnp.asarray, lambda x, *a: x))
+    ps.jax = _Proxy(
+        jax, device_put=clock.conversion(jax.device_put, lambda x, *a: x),
+        tree=_Proxy(jax.tree, map=clock.conversion(jax.tree.map,
+                                                   first_leaf)))
+    for name in STEP_PROGRAMS:
+        setattr(ps, name, clock.timed("step_call", getattr(ps, name)))
+    srv._handoff_prefetch = clock.timed("prefetch", srv._handoff_prefetch)
+    launch = srv._launch_plan
+
+    def timed_launch(plan):
+        clock.parts = dict.fromkeys(
+            PARTS + ("h2d_n", "on_device_n"), 0.0)
+        t0 = time.perf_counter()
+        launch(plan)
+        total = time.perf_counter() - t0
+        parts, clock.parts = clock.parts, None
+        if parts["step_call"]:
+            kind = plan.kind + ("" if plan.sl_d is None else "+slot_ids")
+            parts["rest"] = total - sum(parts[p] for p in PARTS)
+            records.append({"kind": kind, "launch": total, **parts})
+    srv._launch_plan = timed_launch
+
+
+def build_server(config: str, rows: int, pages_per_row: int, seed: int):
+    from cellbench import families, serve
+    with open(config) as f:
+        cfg_file = json.load(f)
+    _, mcfg, weights = serve.make_model(
+        cfg_file, families.of(cfg_file).TINY, seed)
+    opts = {"max_slots": rows, "max_len": pages_per_row * PAGE,
+            "page_size": PAGE, "prefill_chunk": 256, "decode_chunk": 1,
+            "num_pages": rows * 8, "flight_recorder": 4096}
+    return serve.build_server(mcfg, weights, opts, 128), mcfg
+
+
+def drive(srv, vocab: int, n_requests: int, seed: int) -> None:
+    """A queue of prompts of two lengths and answers of 16 to 128 tokens:
+    rows end one by one, so most steps carry a prefill group and some are
+    decode rounds alone, as in the cells."""
+    rng = random.Random(seed)
+    reqs = [srv.submit([rng.randrange(1, vocab)
+                        for _ in range(rng.choice((300, 520)))],
+                       max_new_tokens=rng.randrange(16, 129))
+            for _ in range(n_requests)]
+    while not all(r._done.is_set() for r in reqs):
+        srv.step()
+
+
+def loop_table(records: list) -> dict:
+    """Medians in microseconds by kind of plan, over the later two thirds
+    of the launches (the first third meets the compiles)."""
+    steady = records[len(records) // 3:]
+    out = {}
+    for kind in sorted({r["kind"] for r in steady}):
+        rs = [r for r in steady if r["kind"] == kind]
+        row = {"n": len(rs)}
+        for k in ("launch",) + PARTS + ("rest",):
+            row[k + "_us"] = round(
+                statistics.median(r[k] for r in rs) * 1e6, 1)
+        for k in ("h2d_n", "on_device_n"):
+            row[k] = statistics.median(r[k] for r in rs)
+        out[kind] = row
+    return out
+
+
+def _median_us(fn, reps: int) -> float:
+    out = []
+    for _ in range(reps):
+        t0 = time.perf_counter()
+        y = fn()
+        out.append(time.perf_counter() - t0)
+        jax.block_until_ready(y)  # not timed: the next call meets no queue
+    return round(statistics.median(out) * 1e6, 1)
+
+
+def alone_table(rows: int, table_cols: int, reps: int) -> dict:
+    """The patch's two forms outside any server, microseconds a launch."""
+    r = np.random.default_rng(0)
+    lens = r.integers(0, 2000, rows).astype(np.int32)
+    last = r.integers(0, 32000, rows).astype(np.int32)
+    live = r.integers(0, 2, rows).astype(bool)
+    tables = r.integers(0, 800, (rows, table_cols)).astype(np.int32)
+    staged = [jnp.asarray(lens + i) for i in range(33)]
+    jax.block_until_ready(staged)
+    key = [jax.random.key(0)]
+
+    def split_on_host():
+        key[0], sub = jax.random.split(key[0])
+        return sub
+
+    def four_arrays():
+        return (jnp.asarray(lens), jnp.asarray(tables), jnp.asarray(last),
+                jnp.asarray(live))
+
+    def one_array():
+        return jax.device_put(ps._pack_patch(7, lens, last, live, tables))
+
+    # the transfer as a call of its own against the transfer a jitted
+    # call makes for a host array among its arguments
+    program = jax.jit(lambda patch, *xs: patch[:, 0] + xs[0])
+    on_device = one_array()
+    jax.block_until_ready([program(on_device, *staged),
+                           program(np.asarray(on_device), *staged)])
+    return {
+        "split_on_host_us": _median_us(split_on_host, reps),
+        "four_arrays_us": _median_us(four_arrays, reps),
+        "on_device_x33_us": _median_us(
+            lambda: [jnp.asarray(x) for x in staged], reps),
+        "one_array_us": _median_us(one_array, reps),
+        "call_staged_patch_us": _median_us(
+            lambda: program(on_device, *staged), reps),
+        "call_host_patch_us": _median_us(
+            lambda: program(ps._pack_patch(7, lens, last, live, tables),
+                            *staged), reps)}
+
+
+def main() -> None:
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--config",
+                    default="cellbench/configs/mixtral-8x7b-v0.1.json",
+                    help="a configuration file of the benchmark: its "
+                         "family's tiny widths are served")
+    ap.add_argument("--rows", type=int, default=64)
+    ap.add_argument("--pages-per-row", type=int, default=16)
+    ap.add_argument("--requests", type=int, default=600)
+    ap.add_argument("--reps", type=int, default=300)
+    ap.add_argument("--seed", type=int, default=0)
+    a = ap.parse_args()
+    dev = jax.devices()[0]
+    head = {"device": {"platform": dev.platform, "kind": dev.device_kind},
+            "config": a.config, "rows": a.rows,
+            "pages_per_row": a.pages_per_row}
+    srv, mcfg = build_server(a.config, a.rows, a.pages_per_row, a.seed)
+    lines = [{**head, "table": "alone", **alone_table(
+        a.rows, srv.tables.shape[1], a.reps)}]
+    print(json.dumps(lines[-1]), flush=True)
+    clock, records = _Clock(), []
+    instrument(srv, clock, records)
+    drive(srv, mcfg.vocab_size, a.requests, a.seed)
+    lines.append({**head, "table": "loop", "launches": len(records),
+                  **loop_table(records)})
+    print(json.dumps(lines[-1]), flush=True)
+    out = os.path.join(ROOT, "chiprun_out")
+    os.makedirs(out, exist_ok=True)
+    with open(os.path.join(out, "launch_tail_bench.jsonl"), "a") as f:
+        for line in lines:
+            f.write(json.dumps(line) + "\n")
+
+
+if __name__ == "__main__":
+    main()

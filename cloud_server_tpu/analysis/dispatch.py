@@ -114,7 +114,9 @@ SCHEDULER_LOOPS: dict[str, tuple[str, ...]] = {
         "PagedInferenceServer._deliver",
         "PagedInferenceServer._release_slot",
         "PagedInferenceServer._committed",
-        "PagedInferenceServer._next_rng",
+        "PagedInferenceServer._next_dispatch",
+        "PagedInferenceServer._to_device",
+        "PagedInferenceServer._feed_patch",
         # live-migration path: off the step loop (it runs on router /
         # drain threads), but policed by the same sync discipline — the
         # export owns its ONE sanctioned device_get (below), and the

@@ -266,6 +266,41 @@ def _split_cache(cache):
             if getattr(cache, name) is not None}
 
 
+# A dispatch's data-dependent decode inputs cross to the device as ONE
+# int32 array, a row a decode row: its length, last token, live flag,
+# the dispatch's count (the same in every row) and its page table. The
+# layout follows from the shapes of what is packed, so a program takes
+# it apart with static slices and no layout is handed over beside it.
+_PATCH_HEAD = 4
+
+
+def _pack_patch(count: int, lengths, last_token, live,
+                tables) -> np.ndarray:
+    """The patch of one dispatch, in a buffer of its own: the transfer
+    is asynchronous and may read (on the CPU backend, alias) the host
+    memory after the call returns, so nothing writes it again."""
+    buf = np.empty((tables.shape[0], _PATCH_HEAD + tables.shape[1]),
+                   np.int32)
+    buf[:, 0] = lengths
+    buf[:, 1] = last_token
+    buf[:, 2] = live
+    buf[:, 3] = count
+    buf[:, _PATCH_HEAD:] = tables
+    return buf
+
+
+@jax.named_scope("decode_rounds")
+def _unpack_patch(patch, rng):
+    """(lengths, tables, last_token, live, key) inside a program. The
+    key is the server's one key with the dispatch's count folded in: the
+    nth dispatch draws from n on every scheduler path, the count is an
+    operand like any other (no compile follows it), and no program but
+    the step's own runs to make a key. The slices are the decode half's
+    inputs and lie under its scope in a device trace."""
+    return (patch[:, 0], patch[:, _PATCH_HEAD:], patch[:, 1],
+            patch[:, 2] != 0, jax.random.fold_in(rng, patch[0, 3]))
+
+
 # The scopes below name the two halves of every step program in a
 # device trace (`tf_op` of an op's event metadata): ops of an admission
 # window lie under `prefill_group/`, ops of the decode or speculative
@@ -417,15 +452,32 @@ def _prefill_core(params, state, chunk, g_lens, g_tables, sample_at,
     return new_state, toks, lps
 
 
-# Alternating-scheduler admission dispatch: `_prefill_core` at one
-# uniform chunk width per group (widths/scatter_mask default to None —
-# every row full-width, every row scattering on its first chunk).
-_prefill_chunk = partial(jax.jit,
-                         static_argnames=("cfg", "infer_cfg",
-                                          "scatter_prompt", "mesh",
-                                          "draft_cfg", "use_rows",
-                                          "use_bias"),
-                         donate_argnums=(1,))(_prefill_core)
+@partial(jax.jit,
+         static_argnames=("cfg", "infer_cfg", "scatter_prompt", "mesh",
+                          "draft_cfg", "use_rows", "use_bias"),
+         donate_argnums=(1,))
+def _prefill_chunk(params, state, chunk, g_lens, g_tables, sample_at,
+                   slot_ids, prompt_rows, prompt_lens, rng, count,
+                   samp_rows, orig_lens, count_mask,
+                   gid=None, gstate0=None, grammar=None,
+                   lora=None, aid=None, draft_params=None, widths=None,
+                   scatter_mask=None, *,
+                   cfg: ModelConfig, infer_cfg: InferConfig,
+                   scatter_prompt: bool, mesh=None, draft_cfg=None,
+                   use_rows: bool = False, use_bias: bool = False):
+    """Alternating-scheduler admission dispatch: `_prefill_core` at one
+    uniform chunk width per group (`widths`/`scatter_mask` default to
+    None: every row full-width, every row scattering on its first
+    chunk), under the server's key with the dispatch's `count` folded
+    in (`_unpack_patch`)."""
+    return _prefill_core(
+        params, state, chunk, g_lens, g_tables, sample_at, slot_ids,
+        prompt_rows, prompt_lens, jax.random.fold_in(rng, count),
+        samp_rows, orig_lens, count_mask, gid, gstate0, grammar, lora,
+        aid, draft_params, widths, scatter_mask,
+        cfg=cfg, infer_cfg=infer_cfg, scatter_prompt=scatter_prompt,
+        mesh=mesh, draft_cfg=draft_cfg, use_rows=use_rows,
+        use_bias=use_bias)
 
 
 @jax.named_scope("decode_rounds")
@@ -524,10 +576,23 @@ def _decode_plain_core(params, state, lengths, tables, last_token, live,
     return new_state, lengths, last, out
 
 
-_decode_rounds = partial(jax.jit,
-                         static_argnames=("cfg", "infer_cfg", "n_rounds",
-                                          "mesh", "use_rows", "use_bias"),
-                         donate_argnums=(1,))(_decode_plain_core)
+@partial(jax.jit,
+         static_argnames=("cfg", "infer_cfg", "n_rounds", "mesh",
+                          "use_rows", "use_bias"),
+         donate_argnums=(1,))
+def _decode_rounds(params, state, patch, rng, samp_rows, gid=None,
+                   grammar=None, lora=None, aid=None, slot_ids=None, *,
+                   cfg: ModelConfig, infer_cfg: InferConfig, n_rounds: int,
+                   mesh=None, use_rows: bool = False,
+                   use_bias: bool = False):
+    """`_decode_plain_core` as a program of its own, fed the packed
+    patch and the server's key (`_unpack_patch`)."""
+    lengths, tables, last_token, live, key = _unpack_patch(patch, rng)
+    return _decode_plain_core(
+        params, state, lengths, tables, last_token, live, key, samp_rows,
+        gid, grammar, lora, aid, slot_ids,
+        cfg=cfg, infer_cfg=infer_cfg, n_rounds=n_rounds, mesh=mesh,
+        use_rows=use_rows, use_bias=use_bias)
 
 
 @jax.named_scope("decode_rounds")
@@ -774,11 +839,25 @@ def _spec_core(params, state, lengths, tables, last_token, live,
     return new_state, lengths, last, out
 
 
-_spec_rounds = partial(jax.jit,
-                       static_argnames=("cfg", "infer_cfg", "n_rounds",
-                                        "n_drafts", "mesh", "draft_cfg",
-                                        "use_rows", "use_bias"),
-                       donate_argnums=(1,))(_spec_core)
+@partial(jax.jit,
+         static_argnames=("cfg", "infer_cfg", "n_rounds", "n_drafts",
+                          "mesh", "draft_cfg", "use_rows", "use_bias"),
+         donate_argnums=(1,))
+def _spec_rounds(params, state, patch, stop_len, rng, samp_rows, gid=None,
+                 grammar=None, lora=None, aid=None, draft_params=None,
+                 slot_ids=None, draft_limit=None, *,
+                 cfg: ModelConfig, infer_cfg: InferConfig, n_rounds: int,
+                 n_drafts: int, mesh=None, draft_cfg=None,
+                 use_rows: bool = False, use_bias: bool = False):
+    """`_spec_core` as a program of its own, fed like `_decode_rounds`."""
+    lengths, tables, last_token, live, key = _unpack_patch(patch, rng)
+    return _spec_core(
+        params, state, lengths, tables, last_token, live, stop_len, key,
+        samp_rows, gid, grammar, lora, aid, draft_params, slot_ids,
+        draft_limit,
+        cfg=cfg, infer_cfg=infer_cfg, n_rounds=n_rounds,
+        n_drafts=n_drafts, mesh=mesh, draft_cfg=draft_cfg,
+        use_rows=use_rows, use_bias=use_bias)
 
 
 def _walks_once(cfg: ModelConfig, n_tokens: int, n_rounds: int,
@@ -814,9 +893,9 @@ def _mixed_step(params, state,
                 chunk, widths, g_lens, g_tables, sample_at, slot_ids,
                 prompt_rows, prompt_lens, samp_rows_g, orig_lens,
                 count_mask, scatter_mask, gid_g, gstate0_g,
-                lengths, tables, last_token, live, stop_len,
-                samp_rows_b, gid_b, slot_ids_d, draft_limit,
-                rng, grammar=None, lora=None, aid_g=None, aid_b=None,
+                patch, stop_len, samp_rows_b, gid_b, slot_ids_d,
+                draft_limit, rng, grammar=None, lora=None, aid_g=None,
+                aid_b=None,
                 draft_params=None, *,
                 cfg: ModelConfig, infer_cfg: InferConfig, n_rounds: int,
                 n_drafts: int, scatter_prompt: bool, mesh=None,
@@ -860,6 +939,11 @@ def _mixed_step(params, state,
     the alternating scheduler's either way
     (tests/test_mixed_scheduler.py).
 
+    The decode half's lengths, tables, last tokens and live flags
+    arrive as the one packed `patch`, and `rng` is the server's one key:
+    `_unpack_patch` takes the array apart and folds the dispatch's count
+    into the key, so the step is the only program a dispatch runs.
+
     Prefill rows and decode rows are DISJOINT slots (a slot is live xor
     mid-admission), so program order between the halves is irrelevant,
     as is the order of their cache writes inside the one walk;
@@ -872,7 +956,8 @@ def _mixed_step(params, state,
     with S = n_drafts + 1; n_rounds == 0 (no live decode slot) skips the
     decode half and returns R = 0 outputs.
     """
-    rng_p, rng_d = jax.random.split(rng)
+    lengths, tables, last_token, live, key = _unpack_patch(patch, rng)
+    rng_p, rng_d = jax.random.split(key)
     plogits = dlogits = None
     if _walks_once(cfg, chunk.size + lengths.size, n_rounds, n_drafts,
                    draft_cfg, lora):
@@ -1000,6 +1085,10 @@ class _Plan:
     use_lora: bool
     stats: dict
     spans: list
+    # device copies of `sl_d` and of the padded draft limits, staged by
+    # `_plan_iteration` with the other launch-stable arrays
+    sl_dev: object = None
+    lim_dev: object = None
 
 
 @dataclasses.dataclass
@@ -1529,7 +1618,16 @@ class PagedInferenceServer:
         # (consumed by _record_iteration into the flight record's
         # t_launch — the Perfetto inflight track's left edge)
         self._iter_launch_ts: float | None = None
+        # the one key every program draws from, made once: a dispatch
+        # carries its count (`_next_dispatch`) and the program folds it
+        # in (`_unpack_patch`), so no key is ever made between programs
         self._rng = jax.random.key(seed)
+        self._dispatches = 0
+        # host arrays handed to the device (`_to_device`), and what the
+        # launch performed THIS iteration added: the flight record's
+        # `launch_h2d`, beside its `launch` phase
+        self._h2d = 0
+        self._iter_launch_h2d = 0
         self._stop = threading.Event()
         self._thread: threading.Thread | None = None
 
@@ -1852,9 +1950,23 @@ class PagedInferenceServer:
 
     # -- internals ----------------------------------------------------------
 
-    def _next_rng(self):
-        self._rng, sub = jax.random.split(self._rng)
-        return sub
+    def _next_dispatch(self) -> int:
+        """The count the next program folds into the server's key: the
+        nth dispatch draws from n, whichever scheduler path launches
+        it (wrapped into int32, the patch's dtype)."""
+        self._dispatches = (self._dispatches + 1) & 0x7FFFFFFF
+        return self._dispatches
+
+    def _to_device(self, host_array):
+        """One asynchronous host-to-device transfer, counted."""
+        self._h2d += 1
+        return jax.device_put(host_array)
+
+    def _feed_patch(self, lengths, last_token, live, tables):
+        """The next dispatch's packed patch (`_pack_patch`), on its way
+        to the device: the one array a launch hands over."""
+        return self._to_device(_pack_patch(
+            self._next_dispatch(), lengths, last_token, live, tables))
 
     def add_adapter(self, name: str, lora_params: dict,
                     lora_cfg) -> int:
@@ -2393,7 +2505,8 @@ class PagedInferenceServer:
             jnp.asarray(g_lens, jnp.int32), jnp.asarray(g_tables),
             jnp.asarray(sample_at, jnp.int32), jnp.asarray(slot_ids),
             jnp.asarray(prompt_rows), jnp.asarray(prompt_lens, jnp.int32),
-            self._next_rng(), jax.tree.map(jnp.asarray, samp_g),
+            self._rng, np.int32(self._next_dispatch()),
+            jax.tree.map(jnp.asarray, samp_g),
             jnp.asarray(orig_lens, jnp.int32), jnp.asarray(count_mask),
             gid_g, gst0_g,
             # analysis: allow[lock-discipline] _grammar_dev is rebuilt
@@ -2700,8 +2813,7 @@ class PagedInferenceServer:
         self._stage_spec_stats(g_iter, len(live_ids))
         if self.trace_recorder is not None:
             self._stage_decode_spans(live_ids, n)
-        args = (jnp.asarray(lengths), jnp.asarray(tables),
-                jnp.asarray(last_np), jnp.asarray(live_g))
+        patch = self._feed_patch(lengths, last_np, live_g, tables)
         samp = jax.tree.map(jnp.asarray, samp_g)
         live = self.active
         use_rows = bool((self._needs_rows & live).any())
@@ -2721,8 +2833,8 @@ class PagedInferenceServer:
             lim_dev = (None if spec_lens is None else jnp.asarray(
                 self._pad_limits(spec_lens, int(live_g.shape[0]))))
             self.state, lens, last, (toks, lps, counts) = _spec_rounds(
-                self.params, self.state, *args,
-                jnp.asarray(stop), self._next_rng(), samp,
+                self.params, self.state, patch,
+                jnp.asarray(stop), self._rng, samp,
                 gid, grammar, lora, aid,
                 self.draft_params, sl_dev, lim_dev,
                 cfg=self.cfg, infer_cfg=self.infer_cfg, n_rounds=n,
@@ -2735,7 +2847,7 @@ class PagedInferenceServer:
                 (toks, lps, counts, lens, last))
         else:
             self.state, lens, last, (toks, lps, counts) = _decode_rounds(
-                self.params, self.state, *args, self._next_rng(), samp,
+                self.params, self.state, patch, self._rng, samp,
                 gid, grammar, lora, aid, sl_dev,
                 cfg=self.cfg, infer_cfg=self.infer_cfg, n_rounds=n,
                 mesh=self.mesh, use_rows=use_rows, use_bias=use_bias)
@@ -3207,15 +3319,14 @@ class PagedInferenceServer:
                 jnp.asarray(pf["orig_lens"]), jnp.asarray(pf["countm"]),
                 jnp.asarray(pf["scatm"]), jnp.asarray(pf["gid_g"]),
                 jnp.asarray(pf["gst0_g"]),
-                jnp.asarray(d_lens), jnp.asarray(d_tables),
-                jnp.asarray(d_last), jnp.asarray(live_g),
+                self._feed_patch(d_lens, d_last, live_g, d_tables),
                 jnp.asarray(d_stop),
                 jax.tree.map(jnp.asarray, samp_d),
                 jnp.asarray(gid_d),
                 None if sl_d is None else jnp.asarray(sl_d),
                 None if spec_lens is None else jnp.asarray(
                     self._pad_limits(spec_lens, int(live_g.shape[0]))),
-                self._next_rng(),
+                self._rng,
                 # analysis: allow[lock-discipline] atomically-swapped
                 # reference, rebuilt under _lock pre-admission
                 self._grammar_dev if use_grammar else None, lora,
@@ -3487,58 +3598,68 @@ class PagedInferenceServer:
                 use_lora=bool(((self._aid > 0) & planned_active).any()),
                 stats=stats, spans=spans)
         # stage the launch-stable inputs onto the device NOW, inside
-        # the overlap window: jnp.asarray is an async host->device
-        # feed (DD2 deliberately never flags those), so these
-        # transfers ride behind the in-flight program and the
-        # serialized launch tail pays only the (rows,)-sized patched
-        # arrays. jnp.asarray on an already-device array is a no-op,
-        # so _launch_plan's conversion sites serve both paths.
+        # the overlap window: an asynchronous host->device feed (DD2
+        # deliberately never flags those), so these transfers ride
+        # behind the in-flight program. `_launch_plan` passes them
+        # through as they are and hands over one array, the patch.
+        put = self._to_device
         if plan.pf is not None:
             pf = plan.pf
             for k in ("chunk", "widths", "g_lens", "g_tables",
                       "sample_at", "slot_ids", "prompt_rows",
                       "prompt_lens", "orig_lens", "countm", "scatm",
                       "gid_g", "gst0_g", "aid_g"):
-                pf[k] = jnp.asarray(pf[k])
-            pf["samp_g"] = jax.tree.map(jnp.asarray, pf["samp_g"])
-        plan.d_stop = jnp.asarray(plan.d_stop)
-        plan.samp_d = jax.tree.map(jnp.asarray, plan.samp_d)
-        plan.gid_d = jnp.asarray(plan.gid_d)
-        plan.aid_d = jnp.asarray(plan.aid_d)
+                pf[k] = put(pf[k])
+            pf["samp_g"] = jax.tree.map(put, pf["samp_g"])
+        plan.d_stop = put(plan.d_stop)
+        plan.samp_d = jax.tree.map(put, plan.samp_d)
+        plan.gid_d = put(plan.gid_d)
+        plan.aid_d = put(plan.aid_d)
+        if plan.sl_d is not None:
+            plan.sl_dev = put(plan.sl_d)
+        if plan.spec_lens is not None:
+            plan.lim_dev = put(self._pad_limits(
+                plan.spec_lens, int(plan.live_g.shape[0])))
         return plan
 
     def _launch_plan(self, plan: "_Plan") -> None:
         """Patch the plan's data-dependent decode inputs from the
         just-committed ledger, then launch it ASYNCHRONOUSLY — no
         device_get here; the sync is the next step's
-        `_commit_inflight`. The patch is the whole serialized cost of
-        re-anchoring the plan: a (rows,) re-gather of lengths / last
-        tokens / table rows plus deadening rows whose slot died at the
-        commit (their sentinel tables drop every device write, and
-        `owners` masks their host commit)."""
+        `_commit_inflight`. The device has no work until this returns,
+        so the host crosses to it once and dispatches one program: the
+        patch (lengths / last tokens / live flags / table rows, rows
+        whose slot died at the commit deadened: their sentinel tables
+        drop every device write, and `owners` masks their host commit)
+        goes over as one packed array with the dispatch's count
+        (`_feed_patch`), the program makes its own key from it, and
+        everything else was staged by `_plan_iteration` and is passed
+        through untouched. The flight record's `launch_h2d` counts the
+        host arrays handed over here."""
         prof = self._profiler
         if prof is not None:
             prof.enter("launch")
+        h2d0 = self._h2d
         live_ids = plan.live_ids
         nl = len(live_ids)
         if nl and plan.n_rounds > 0:
             if plan.sl_d is None:
-                # rows ARE slots: the ledger views are the patched
-                # arrays (dead slots already carry sentinel tables and
+                # rows ARE slots: the ledger's arrays are the patched
+                # ones (dead slots already carry sentinel tables and
                 # active=False from _release_slot)
-                plan.live_g = self.active.copy()
+                plan.live_g = self.active
                 plan.d_lens = self.lengths
                 plan.d_tables = self.tables
                 plan.d_last = self.last_token
             else:
-                for i in range(nl):
-                    sid = int(live_ids[i])
-                    alive = (self._slots[sid] is plan.owners[i]
-                             and self.active[sid])
-                    plan.live_g[i] = alive
-                    plan.d_lens[i] = self.lengths[sid]
-                    plan.d_last[i] = self.last_token[sid]
-                    plan.d_tables[i] = self.tables[sid]
+                slots, owners = self._slots, plan.owners
+                plan.live_g[:nl] = self.active[live_ids] & np.fromiter(
+                    (slots[sid] is owners[i]
+                     for i, sid in enumerate(live_ids.tolist())),
+                    bool, nl)
+                plan.d_lens[:nl] = self.lengths[live_ids]
+                plan.d_last[:nl] = self.last_token[live_ids]
+                plan.d_tables[:nl] = self.tables[live_ids]
             if plan.kind == "decode" and not plan.live_g[:nl].any():
                 # every planned row died at the commit: nothing left
                 # to dispatch — drain the pipeline instead of paying a
@@ -3548,9 +3669,8 @@ class PagedInferenceServer:
         # reference, rebuilt under _lock pre-admission
         grammar = self._grammar_dev if plan.use_grammar else None
         lora = self.adapters.device_args() if plan.use_lora else None
-        sl_dev = None if plan.sl_d is None else jnp.asarray(plan.sl_d)
-        lim_dev = (None if plan.spec_lens is None else jnp.asarray(
-            self._pad_limits(plan.spec_lens, int(plan.live_g.shape[0]))))
+        patch = self._feed_patch(plan.d_lens, plan.d_last, plan.live_g,
+                                 plan.d_tables)
         if plan.kind == "mixed":
             pf = plan.pf
             # disaggregation handoff: the in-flight dispatch committed
@@ -3563,28 +3683,14 @@ class PagedInferenceServer:
                 plan.n_rounds, plan.g_iter, self.draft_cfg, lora)
             self.state, ptoks, plps, lens, last, (toks, lps, counts) = \
                 _mixed_step(
-                    self.params, self.state, jnp.asarray(pf["chunk"]),
-                    jnp.asarray(pf["widths"]),
-                    jnp.asarray(pf["g_lens"]),
-                    jnp.asarray(pf["g_tables"]),
-                    jnp.asarray(pf["sample_at"]),
-                    jnp.asarray(pf["slot_ids"]),
-                    jnp.asarray(pf["prompt_rows"]),
-                    jnp.asarray(pf["prompt_lens"]),
-                    jax.tree.map(jnp.asarray, pf["samp_g"]),
-                    jnp.asarray(pf["orig_lens"]),
-                    jnp.asarray(pf["countm"]),
-                    jnp.asarray(pf["scatm"]), jnp.asarray(pf["gid_g"]),
-                    jnp.asarray(pf["gst0_g"]),
-                    jnp.asarray(plan.d_lens),
-                    jnp.asarray(plan.d_tables),
-                    jnp.asarray(plan.d_last), jnp.asarray(plan.live_g),
-                    jnp.asarray(plan.d_stop),
-                    jax.tree.map(jnp.asarray, plan.samp_d),
-                    jnp.asarray(plan.gid_d), sl_dev, lim_dev,
-                    self._next_rng(), grammar, lora,
-                    jnp.asarray(pf["aid_g"]), jnp.asarray(plan.aid_d),
-                    self.draft_params,
+                    self.params, self.state, pf["chunk"], pf["widths"],
+                    pf["g_lens"], pf["g_tables"], pf["sample_at"],
+                    pf["slot_ids"], pf["prompt_rows"], pf["prompt_lens"],
+                    pf["samp_g"], pf["orig_lens"], pf["countm"],
+                    pf["scatm"], pf["gid_g"], pf["gst0_g"],
+                    patch, plan.d_stop, plan.samp_d, plan.gid_d,
+                    plan.sl_dev, plan.lim_dev, self._rng, grammar, lora,
+                    pf["aid_g"], plan.aid_d, self.draft_params,
                     cfg=self.cfg, infer_cfg=self.infer_cfg,
                     n_rounds=plan.n_rounds, n_drafts=plan.g_iter,
                     scatter_prompt=plan.scatter_prompt, mesh=self.mesh,
@@ -3594,36 +3700,25 @@ class PagedInferenceServer:
                     use_rows_d=plan.use_rows_d,
                     use_bias_d=plan.use_bias_d)
             futures = (ptoks, plps, toks, lps, counts, lens, last)
-        else:
-            args = (jnp.asarray(plan.d_lens),
-                    jnp.asarray(plan.d_tables),
-                    jnp.asarray(plan.d_last), jnp.asarray(plan.live_g))
-            samp = jax.tree.map(jnp.asarray, plan.samp_d)
-            gid = jnp.asarray(plan.gid_d)
-            aid = jnp.asarray(plan.aid_d)
-            if plan.g_iter > 0:
-                self.state, lens, last, (toks, lps, counts) = \
-                    _spec_rounds(
-                        self.params, self.state, *args,
-                        jnp.asarray(plan.d_stop), self._next_rng(),
-                        samp, gid, grammar, lora, aid,
-                        self.draft_params, sl_dev, lim_dev,
-                        cfg=self.cfg, infer_cfg=self.infer_cfg,
-                        n_rounds=plan.n_rounds, n_drafts=plan.g_iter,
-                        mesh=self.mesh, draft_cfg=self.draft_cfg,
-                        use_rows=plan.use_rows_d,
-                        use_bias=plan.use_bias_d)
-            else:
-                self.state, lens, last, (toks, lps, counts) = \
-                    _decode_rounds(
-                        self.params, self.state, *args,
-                        self._next_rng(), samp, gid, grammar, lora,
-                        aid, sl_dev,
-                        cfg=self.cfg, infer_cfg=self.infer_cfg,
-                        n_rounds=plan.n_rounds, mesh=self.mesh,
-                        use_rows=plan.use_rows_d,
-                        use_bias=plan.use_bias_d)
+        elif plan.g_iter > 0:
+            self.state, lens, last, (toks, lps, counts) = _spec_rounds(
+                self.params, self.state, patch, plan.d_stop, self._rng,
+                plan.samp_d, plan.gid_d, grammar, lora, plan.aid_d,
+                self.draft_params, plan.sl_dev, plan.lim_dev,
+                cfg=self.cfg, infer_cfg=self.infer_cfg,
+                n_rounds=plan.n_rounds, n_drafts=plan.g_iter,
+                mesh=self.mesh, draft_cfg=self.draft_cfg,
+                use_rows=plan.use_rows_d, use_bias=plan.use_bias_d)
             futures = (toks, lps, counts, lens, last)
+        else:
+            self.state, lens, last, (toks, lps, counts) = _decode_rounds(
+                self.params, self.state, patch, self._rng, plan.samp_d,
+                plan.gid_d, grammar, lora, plan.aid_d, plan.sl_dev,
+                cfg=self.cfg, infer_cfg=self.infer_cfg,
+                n_rounds=plan.n_rounds, mesh=self.mesh,
+                use_rows=plan.use_rows_d, use_bias=plan.use_bias_d)
+            futures = (toks, lps, counts, lens, last)
+        self._iter_launch_h2d = self._h2d - h2d0
         # the launch's end is the delivery's start: `_step_overlap`
         # wakes the streaming threads under the program launched here
         t = (prof.enter("deliver") if prof is not None
@@ -4053,6 +4148,10 @@ class PagedInferenceServer:
         # and the decode round together (`_walks_once`); a step program
         # of one half alone has nothing to join
         st.setdefault("joined", False)
+        # host arrays handed to the device in this iteration's `launch`
+        # phase; a sequential iteration has no such phase
+        st["launch_h2d"] = self._iter_launch_h2d
+        self._iter_launch_h2d = 0
         # KV-pool telemetry (joins phases_ms in the record): the
         # iteration's page flow (deltas against the step-start
         # baseline — sweep/admission included) and the occupancy split

@@ -151,20 +151,29 @@ def _step_args(cfg, icfg, sampling, penalties):
     return state, group, decode
 
 
+COUNT = 3  # the dispatch's count, folded into the key by the program
+
+_prefill_apart = jax.jit(ps._prefill_core, static_argnames=(
+    "cfg", "infer_cfg", "scatter_prompt", "use_rows"))
+_decode_apart = jax.jit(ps._decode_plain_core, static_argnames=(
+    "cfg", "infer_cfg", "n_rounds", "use_rows"))
+
+
 def _apart(params, state, group, decode, rng, *, cfg, icfg, n_rounds,
            use_rows):
-    """The two cores, each its own program with its own walk."""
+    """The two cores, each its own program with its own walk, under the
+    keys `_mixed_step` hands them."""
     (chunk, widths, g_lens, g_tables, sample_at, slot_ids, prompt_rows,
      prompt_lens, samp_g, orig_lens, count_mask, scatter_mask, gid_g,
      gstate0_g) = group
     (lengths, tables, last, live, _, samp_d, gid_d, slot_ids_d) = decode
-    rng_p, rng_d = jax.random.split(rng)
-    state, ptoks, plps = ps._prefill_chunk(
+    rng_p, rng_d = jax.random.split(jax.random.fold_in(rng, COUNT))
+    state, ptoks, plps = _prefill_apart(
         params, state, chunk, g_lens, g_tables, sample_at, slot_ids,
         prompt_rows, prompt_lens, rng_p, samp_g, orig_lens, count_mask,
         gid_g, gstate0_g, None, None, None, None, widths, scatter_mask,
         cfg=cfg, infer_cfg=icfg, scatter_prompt=True, use_rows=use_rows)
-    state, lens, last, (toks, lps, counts) = ps._decode_rounds(
+    state, lens, last, (toks, lps, counts) = _decode_apart(
         params, state, lengths, tables, last, live, rng_d, samp_d, gid_d,
         None, None, None, slot_ids_d, cfg=cfg, infer_cfg=icfg,
         n_rounds=n_rounds, use_rows=use_rows)
@@ -175,7 +184,9 @@ def _apart(params, state, group, decode, rng, *, cfg, icfg, n_rounds,
 def _mixed(params, state, group, decode, rng, *, cfg, icfg, n_rounds,
            use_rows, lower=False):
     fn = ps._mixed_step.lower if lower else ps._mixed_step
-    return fn(params, state, *group, *decode, None, rng,
+    lengths, tables, last, live, *staged = decode
+    patch = jnp.asarray(ps._pack_patch(COUNT, lengths, last, live, tables))
+    return fn(params, state, *group, patch, *staged, None, rng,
               cfg=cfg, infer_cfg=icfg, n_rounds=n_rounds, n_drafts=0,
               scatter_prompt=True, use_rows_p=use_rows, use_rows_d=use_rows)
 

@@ -1,5 +1,6 @@
 """Metrics accounting, aggregation, logging, tracing smoke tests."""
 
+import itertools
 import json
 import time
 
@@ -45,11 +46,14 @@ def test_flops_training_is_3x_inference():
     assert train == pytest.approx(3 * infer)
 
 
-def test_step_timer_tokens_per_sec_and_mfu():
+def test_step_timer_tokens_per_sec_and_mfu(monkeypatch):
+    # the timer's clock steps 10 ms a read: a loaded host stretches a
+    # sleep past any tolerance
+    reads = itertools.count()
+    monkeypatch.setattr(time, "perf_counter", lambda: next(reads) * 0.01)
     t = StepTimer(flops_per_token=1e6, n_devices=1, peak_flops=1e12,
                   window=10)
     for _ in range(3):
-        time.sleep(0.01)
         out = t.tick(tokens=1000)
     assert out["tokens_per_sec"] == pytest.approx(1000 / 0.01, rel=0.5)
     assert out["mfu"] == pytest.approx(
