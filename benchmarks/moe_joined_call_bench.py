@@ -20,6 +20,15 @@ the table of every configuration (PERF.md, PR 35).
         --tokens 16,64,256,512,1024,2048 --tiles
     JAX_PLATFORMS=cpu python benchmarks/moe_joined_call_bench.py --tiny
 
+Beside every sorted case's ms a layer stand the row-tile visits of its
+grouped matmuls (megablox's own `make_group_metadata` on the call's own
+`group_sizes`, which the bench fetches and the server never does) and
+the rows of the buffer the kernel is handed: the kernel is paid by the
+visit (PERF.md, PR 39), so the next writer reads the fit. The router
+of the bench is even; `--skew 1.5` gives it the second cell's deeper
+layers' (a third of the experts near empty, a few with most rows), where
+the visits are the experts that have rows and a layout moves little.
+
 Prints one JSON line per measurement and writes them to
 `chiprun_out/moe_joined_call_bench.jsonl`.
 """
@@ -90,6 +99,39 @@ def _time_ms(fn, args, reps: int, sets: int) -> list[float]:
     return out
 
 
+def _sorted_call(fn, args) -> dict:
+    """The sorted dispatch's call inside `fn(*args)`: the rows of the
+    buffer `_grouped_experts` is handed and the row-tile visits of each of
+    its grouped matmuls, counted by the kernel's own metadata from the
+    call's `group_sizes`. Empty where `fn` takes the dense dispatch."""
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import (
+        make_group_metadata)
+    seen = []
+    real = moe._grouped_experts
+
+    def spy(rows, w_gate, w_up, w_down, group_sizes, **kw):
+        seen.append((rows.shape[0], group_sizes))
+        return real(rows, w_gate, w_up, w_down, group_sizes, **kw)
+
+    def sizes(*xs):
+        fn(*xs)
+        return seen[-1][1] if seen else None
+
+    moe._grouped_experts = spy
+    try:
+        group_sizes = jax.jit(sizes)(*args)
+    finally:
+        moe._grouped_experts = real
+    if group_sizes is None:
+        return {}
+    _, visits = make_group_metadata(
+        group_sizes=group_sizes, m=seen[-1][0], tm=moe._GMM_ROWS,
+        start_group=0, num_nonzero_groups=group_sizes.shape[0],
+        visit_empty_groups=False)
+    return {"visits": int(visits), "buffer_rows": seen[-1][0],
+            "computed_rows": int(group_sizes.sum())}
+
+
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--tiny", action="store_true",
@@ -105,6 +147,13 @@ def main() -> None:
                     help="the T at which both dispatches are measured")
     ap.add_argument("--tiles", action="store_true",
                     help="sweep the grouped matmul's row and weight tiles")
+    ap.add_argument("--skew", type=float, default=0.0,
+                    help="how far the router is from even: every token "
+                         "gets this much of one common vector, so the "
+                         "experts' logits share a bias of that deviation "
+                         "(0: even; 1 to 2: the second cell's layers 3 to "
+                         "7, where a few experts take most rows and a "
+                         "third take none, PERF.md PR 39)")
     a = ap.parse_args()
     dev = jax.devices()[0]
     print(json.dumps({"device": {"platform": dev.platform,
@@ -121,22 +170,35 @@ def main() -> None:
                       "a_token": cfg.num_experts_per_token,
                       "activation": cfg.mlp_activation,
                       "router_input": cfg.router_input,
+                      "skew": a.skew,
                       "placed_min_tokens": moe.grouped_min_tokens(cfg),
                       "placed_tilings": moe._gmm_tilings(cfg)}), flush=True)
     layers = _layers(cfg, 2, jax.random.PRNGKey(0))
     lines = []
 
+    common = jax.random.normal(jax.random.PRNGKey(9), (cfg.embed_dim,),
+                               jnp.float32)
+
     def x_of(t, seed):
-        return jax.random.normal(jax.random.PRNGKey(seed),
-                                 (1, t, cfg.embed_dim),
-                                 jnp.float32).astype(cfg.dtype)
+        x = jax.random.normal(jax.random.PRNGKey(seed),
+                              (1, t, cfg.embed_dim), jnp.float32)
+        return (x + a.skew * common).astype(cfg.dtype)
 
     def measure(name, fn, args, **extra):
         # a new function object a case: `GROUPED_MIN_TOKENS` is read at
         # trace time, and jit's cache is keyed by the function
-        ms = _time_ms(jax.jit(lambda *xs: fn(*xs)), args, a.reps, a.sets)
+        # a shape the compiler refuses (PR 39 met one: XLA's own gather of
+        # bf16[8192,2560] rows over its scoped VMEM) is a line, not the end
+        try:
+            ms = _time_ms(jax.jit(lambda *xs: fn(*xs)), args, a.reps,
+                          a.sets)
+        except Exception as exc:  # noqa: BLE001
+            print(json.dumps({"case": name, "refused": repr(exc)[:300],
+                              **extra}), flush=True)
+            return float("nan")
         line = {"case": name, "ms_median": statistics.median(ms),
-                "ms_min": min(ms), "ms_max": max(ms), **extra}
+                "ms_min": min(ms), "ms_max": max(ms), **extra,
+                **_sorted_call(fn, args)}
         lines.append(line)
         print(json.dumps(line), flush=True)
         return line["ms_median"]
@@ -192,13 +254,8 @@ def main() -> None:
         for pair in cases:
             moe._gmm_tilings = lambda cfg, pair=pair: pair
             for t in (512 + DECODE_ROWS, 2048 + DECODE_ROWS):
-                try:
-                    measure(f"tiles_{t}", one, (x_of(t, 5), layers),
-                            tokens=t, tilings=pair)
-                except Exception as exc:  # noqa: BLE001 - a tiling Mosaic refuses
-                    print(json.dumps({"case": f"tiles_{t}", "tilings": pair,
-                                      "refused": repr(exc)[:200]}),
-                          flush=True)
+                measure(f"tiles_{t}", one, (x_of(t, 5), layers),
+                        tokens=t, tilings=pair)
         moe._gmm_tilings = placed_t
         moe.grouped_min_tokens = placed
 

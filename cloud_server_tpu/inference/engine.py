@@ -86,9 +86,11 @@ def _mlp_apply(x, lp, cfg: ModelConfig, lora=None, stack=None,
     with two, holds only when nothing drops (generous
     expert_capacity_factor), which is also the sane serving configuration.
     At such a factor (experts / experts per token, or more) a call of
-    `moe.GROUPED_MIN_TOKENS` tokens or more, a prefill group with the
+    `moe.grouped_min_tokens(cfg)` tokens or more, a prefill group with the
     decode rows beside it, runs `moe_mlp`'s sorted dispatch, a grouped
-    matmul over T * k rows, if the caller unrolls its layers and says
+    matmul over the T * k rows sorted by expert (each expert's on row
+    tiles of its own where the call's shape makes that fewer tiles to
+    compute), if the caller unrolls its layers and says
     so: `stack` is (params["layers"], layer index), where the kernel
     finds the experts' weights without a copy. A decode round alone, a
     short chunk, a factor that can drop, a scan over the layers,

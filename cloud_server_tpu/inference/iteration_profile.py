@@ -141,7 +141,7 @@ _ITER_ARG_KEYS = ("iteration", "scheduler", "n_live", "decode_rounds",
                   "budget_utilization", "host_ms", "device_wait_ms",
                   "host_gap_frac", "preemptions", "pending", "n_jobs",
                   "overlap", "overlap_ms", "inflight_depth",
-                  "overlap_launch_lead_ms", "delivered", "joined",
+                  "overlap_launch_lead_ms", "delivered", "joined", "grouped",
                   "launch_h2d")
 
 

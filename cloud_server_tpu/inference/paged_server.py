@@ -883,6 +883,24 @@ def _walks_once(cfg: ModelConfig, n_tokens: int, n_rounds: int,
                  or moe._capacity(cfg, n_tokens) >= n_tokens))
 
 
+def _sorts_experts(cfg: ModelConfig, params, joined: bool,
+                   chunk_tokens: int, decode_tokens: int) -> bool:
+    """Whether an expert call of a step program takes `moe_mlp`'s sorted
+    dispatch: the rule the trace applies (`moe._dispatch_grouped`: the
+    call's token count, the capacity, the stack, the mesh), read on the
+    host when the plan is built. A one-walk step makes one call a layer
+    over both halves' tokens, any other program one for each half it has
+    (`decode_tokens`: the rows of a round times its window). A dense MLP
+    has no such call."""
+    if cfg.num_experts < 2:
+        return False
+    stack = (params["layers"], 0)
+    calls = ((chunk_tokens + decode_tokens,) if joined
+             else (chunk_tokens, decode_tokens))
+    return any(n > 0 and moe._dispatch_grouped(cfg, n, stack)
+               for n in calls)
+
+
 @partial(jax.jit,
          static_argnames=("cfg", "infer_cfg", "n_rounds", "n_drafts",
                           "scatter_prompt", "mesh", "draft_cfg",
@@ -2498,6 +2516,7 @@ class PagedInferenceServer:
         use_lora = bool((self._aid[sl] > 0).any())
         aid_g = jnp.asarray(pad_rows(self._aid[sl], 0))
 
+        self._stage_program_kind(self._iter_stats, chunk.size, 0, 0, 0, None)
         if prof is not None:
             prof.enter("device")
         self.state, toks, lps = _prefill_chunk(
@@ -2827,6 +2846,8 @@ class PagedInferenceServer:
         lora = self.adapters.device_args() if use_lora else None
         aid = jnp.asarray(aid_np)
         sl_dev = None if sl is None else jnp.asarray(sl)
+        self._stage_program_kind(self._iter_stats, 0, live_g.size, n,
+                                 g_iter, lora)
         if prof is not None:
             prof.enter("device")
         if g_iter > 0:
@@ -3305,9 +3326,8 @@ class PagedInferenceServer:
         # prefill chunk)
         self._handoff_prefetch(sel)
         lora = self.adapters.device_args() if use_lora else None
-        self._iter_stats["joined"] = _walks_once(
-            self.cfg, pf["chunk"].size + live_g.size, n_rounds, g_iter,
-            self.draft_cfg, lora)
+        self._stage_program_kind(self._iter_stats, pf["chunk"].size,
+                                 live_g.size, n_rounds, g_iter, lora)
         self.state, ptoks, plps, lens, last, (toks, lps, counts) = \
             _mixed_step(
                 self.params, self.state, jnp.asarray(pf["chunk"]),
@@ -3622,6 +3642,23 @@ class PagedInferenceServer:
                 plan.spec_lens, int(plan.live_g.shape[0])))
         return plan
 
+    def _stage_program_kind(self, stats: dict, chunk_tokens: int,
+                            decode_rows: int, n_rounds: int, n_drafts: int,
+                            lora) -> None:
+        """The record's `joined` and `grouped`, known when the program is
+        chosen: whether it walks the layers once for a prefill group and
+        the decode round together (`_walks_once`; a program of one half
+        alone has nothing to join), and whether its expert calls take the
+        sorted dispatch (`_sorts_experts`). An iteration that launches
+        two programs (the alternating scheduler's) says what either did."""
+        joined = chunk_tokens > 0 and _walks_once(
+            self.cfg, chunk_tokens + decode_rows, n_rounds, n_drafts,
+            self.draft_cfg, lora)
+        stats["joined"] = joined or stats.get("joined", False)
+        stats["grouped"] = stats.get("grouped", False) or _sorts_experts(
+            self.cfg, self.params, joined, chunk_tokens,
+            decode_rows * (n_drafts + 1) * (n_rounds > 0))
+
     def _launch_plan(self, plan: "_Plan") -> None:
         """Patch the plan's data-dependent decode inputs from the
         just-committed ledger, then launch it ASYNCHRONOUSLY — no
@@ -3671,6 +3708,9 @@ class PagedInferenceServer:
         lora = self.adapters.device_args() if plan.use_lora else None
         patch = self._feed_patch(plan.d_lens, plan.d_last, plan.live_g,
                                  plan.d_tables)
+        self._stage_program_kind(
+            plan.stats, plan.pf["chunk"].size if plan.kind == "mixed" else 0,
+            plan.live_g.size, plan.n_rounds, plan.g_iter, lora)
         if plan.kind == "mixed":
             pf = plan.pf
             # disaggregation handoff: the in-flight dispatch committed
@@ -3678,9 +3718,6 @@ class PagedInferenceServer:
             # committed ones — start the D2H copies for admissions the
             # plan completes, before the dispatch donates self.state
             self._handoff_prefetch(plan.sel)
-            plan.stats["joined"] = _walks_once(
-                self.cfg, pf["chunk"].size + plan.live_g.size,
-                plan.n_rounds, plan.g_iter, self.draft_cfg, lora)
             self.state, ptoks, plps, lens, last, (toks, lps, counts) = \
                 _mixed_step(
                     self.params, self.state, pf["chunk"], pf["widths"],
@@ -4144,10 +4181,10 @@ class PagedInferenceServer:
                 for k, v in self.qos.fair_shares().items()}
         st["n_jobs"] = len(self._jobs)
         st["pending"] = self.num_pending
-        # whether the program walked the layers once for a prefill group
-        # and the decode round together (`_walks_once`); a step program
-        # of one half alone has nothing to join
+        # what the launched program was (`_stage_program_kind`); an
+        # iteration that launched none joined and sorted nothing
         st.setdefault("joined", False)
+        st.setdefault("grouped", False)
         # host arrays handed to the device in this iteration's `launch`
         # phase; a sequential iteration has no such phase
         st["launch_h2d"] = self._iter_launch_h2d

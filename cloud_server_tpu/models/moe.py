@@ -19,9 +19,13 @@ observe (`_dispatch_grouped`):
     weights lie in the stacked parameters (`stack`), the T x k
     assignments are sorted by expert, gathered to (T*k, D) rows and run
     through one grouped matmul per weight, then gathered back: T*k expert
-    rows where the dense dispatch computes E*T. Same routing, same gates,
-    same `aux`, same precision; the shapes stay static (`group_sizes` is
-    data).
+    rows where the dense dispatch computes E*T. The kernel computes a
+    whole row tile for every expert with a row in it, so where the call's
+    shape lets that save enough (`_sorted_buffer_rows`) every expert's
+    rows start on a row tile of a somewhat longer buffer and no tile is
+    computed twice (`_aligned_layout`). Same routing, same gates, same
+    `aux`, same products in the same precision; the shapes stay static
+    (`group_sizes` and the rows' places are data).
 
 No dynamic shapes, no host round-trips on either.
 
@@ -53,11 +57,13 @@ Params = dict
 # ---------------------------------------------------------------------------
 
 # The fewest tokens of a call that the sorted dispatch takes at 8 experts, 2
-# a token. Placed by the v5e measurements of PERF.md (PR 26, PR 32): below
-# it the experts' weight stream bounds both dispatches and the dense one has
-# no sort and no gathers. A whole layer at Mixtral's widths, dense and
-# sorted: 320 tokens 5.01 and 5.26 ms, 384 tokens 6.17 and 5.27.
-GROUPED_MIN_TOKENS = 384
+# a token. Placed by the v5e measurements of PERF.md (PR 26, PR 32, PR 39):
+# below it the experts' weight stream bounds both dispatches and the dense
+# one has no sort and no gathers. A whole layer at Mixtral's widths, dense
+# and sorted: 256 tokens 4.24 and 4.71 ms, 288 tokens 4.62 and 4.62, 320
+# tokens 5.01 and 4.63 (from 288 tokens every expert's rows lie on a tile
+# of their own: 8 visits).
+GROUPED_MIN_TOKENS = 288
 
 
 def grouped_min_tokens(cfg: ModelConfig) -> int:
@@ -66,17 +72,18 @@ def grouped_min_tokens(cfg: ModelConfig) -> int:
     (T, k, E, C) one-hots and the two contractions over (T, E, C)) grows
     with E * k * T * T where the sort and the gathers grow with k * T, so
     the more assignments there are to place, the sooner the sorted one
-    wins. Measured on the v5e at two configurations (PERF.md, PR 32 and PR
-    35): 8 experts of 14,336, 2 a token: dense wins at 320 tokens and
-    loses from 384; 64 experts of 768, 6 a token: dense 1.13 and sorted
-    1.22 ms a layer at 128 tokens, 1.34 and 1.28 at 192. The fourth root
-    of E * k passes through both (384 and 174) and is a fit, not a law: a
-    third configuration tests it, and nothing was measured under 16
-    assignments, where the placed value stands. The widths do not enter:
-    both dispatches leave the weight stream near 240 tokens a call at any
-    width (peak FLOPs over peak bytes)."""
+    wins. Measured on the v5e at two configurations (PERF.md, PR 32, PR
+    35 and PR 39): 8 experts of 14,336, 2 a token: dense wins at 256
+    tokens and loses from 320; 64 experts of 768, 6 a token: dense 1.13
+    and sorted 1.22 ms a layer at 128 tokens, 1.25 and 1.24 at 160, 1.34
+    and 1.28 at 192. The fifth root of E * k passes through both (288
+    and 153) and is a fit, not a law: a third configuration tests it, and
+    nothing was measured under 16 assignments, where the placed value
+    stands. The widths do not enter: both dispatches leave the weight
+    stream near 240 tokens a call at any width (peak FLOPs over peak
+    bytes)."""
     assignments = cfg.num_experts * cfg.num_experts_per_token
-    return round(GROUPED_MIN_TOKENS * min(1.0, (16 / assignments) ** 0.25))
+    return round(GROUPED_MIN_TOKENS * min(1.0, (16 / assignments) ** 0.2))
 
 
 def _capacity(cfg: ModelConfig, num_tokens: int) -> int:
@@ -92,7 +99,8 @@ def _dispatch_grouped(cfg: ModelConfig, num_tokens: int, stack) -> bool:
       * a capacity under which no expert can overflow, so both dispatches
         compute the same function;
       * enough tokens for the dense dispatch's empty rows to cost more
-        than the sort and the row tiles that straddle two experts;
+        than the sort and the row tiles computed for more rows than an
+        expert has;
       * the experts' weights usable where they lie: the caller gave the
         stacked parameters and the layer's index (`stack`), and they are
         plain arrays of the compute dtype. XLA gives a custom call no view
@@ -186,18 +194,23 @@ def top_k_routing(router_logits: jnp.ndarray, k: int, capacity: int):
 
 # (rows, contraction, columns) tiles of the grouped matmul kernel on the
 # TPU, for x @ w_gate|w_up and for act @ w_down. A row tile that straddles
-# two experts is computed for both, so fewer rows waste less; a weight tile
-# is fetched once per row tile unless it spans the contraction (as 4,096
-# does on the way in at Mixtral's widths: an expert's consecutive row tiles
-# then reuse it), so more rows fetch less. `_gmm_tilings` places them from
+# two experts is computed for both (the kernel visits it once for each),
+# so fewer rows waste less; a weight tile is fetched once per row tile
+# unless it spans the contraction (as 4,096 does on the way in at
+# Mixtral's widths: an expert's consecutive row tiles then reuse it), so
+# more rows fetch less. `_gmm_tilings` places them from
 # the widths: the weight tile is the largest of 4 MiB or less that spans
 # the contraction where the contraction is 4,096 or less, and takes 1,024
 # of it otherwise. At (4,096 x 14,336) that is the (256, 4096, 512) and
 # (256, 1024, 2048) of the v5e sweep of PERF.md (PR 26); at (2,560 x 768)
 # an expert's whole matrix is one tile (the sweep of PR 35).
-# `_GMM_ROWS` is both tilings' row tile. The sorted rows are gathered out
-# to a whole number of them (`_moe_grouped`): rows past the last group
-# belong to no expert, are not computed and are never gathered back. A
+# `_GMM_ROWS` is both tilings' row tile, and the unit of the sorted rows'
+# layout: the buffer they are gathered out to is whole row tiles
+# (`_sorted_buffer_rows`), and where it has room an expert's extent is
+# rounded up to whole tiles too (`_aligned_layout`), so the next expert
+# starts on a tile and the tile is visited once. The rows of an extent
+# that no assignment landed on are computed and never gathered back; rows
+# past the last extent belong to no expert and are not computed. A
 # mixed step's one walk brings k * (chunk tokens + decode rows) of them,
 # never whole tiles: padding each matmul's operand instead copied the
 # (rows, F) activation once a layer.
@@ -257,6 +270,60 @@ def _grouped_experts(rows, w_gate, w_up, w_down, group_sizes, kernel: bool,
     return _grouped_matmul(act, w_down, group_sizes, t_out, kernel)
 
 
+# The aligned layout is taken where, for an even router, its visits and
+# its longer buffer together cost this share of the packed layout's visits
+# or less: an expert's first visit then fetches a tile of rows with its
+# tile of weights, where a visit inside a shared tile fetches the weights
+# alone, so a visit saved is not a whole visit's time. Placed by the v5e
+# measurements of PERF.md (PR 39), ms a layer, packed then aligned: at
+# Mixtral's widths 8 visits for 10 and 16 for 20 win (5.26 and 4.63, 11.03
+# and 9.54), 16 for 18 and 22 for 23 tie, 16 for 16 loses a millisecond;
+# at 64 experts of 768, 64 for 113 and for 101 win (3.06 and 2.44, 2.45
+# and 2.22), 64 for 89 ties, 64 for 83 loses a tenth.
+_ALIGNED_COST_SHARE = 7 / 8
+
+
+def _sorted_buffer_rows(n_assignments: int, cfg: ModelConfig) -> int:
+    """The rows of the buffer the sorted assignments are laid out in, from
+    the call's shape and the widths. Packed end to end the assignments
+    fill `ceil(n / tile)` row tiles and the kernel visits a tile once for
+    every expert with a row in it: `tiles + E - 1` visits for an even
+    router, each about the time of an expert's weights on the bus. With
+    every expert's rows on tiles of their own it visits
+    `E * ceil(n / E / tile)` tiles, in a buffer of as many and an eighth
+    of `E` more for a router that is not even, and every tile of the
+    buffer beyond the packed ones costs the gather and the activation
+    their bytes (a tile of rows in and out at D, three times at F),
+    counted here in visits: over the bytes of an expert's three matrices.
+    Where that sum is few enough (`_ALIGNED_COST_SHARE`) the buffer is the
+    longer one; elsewhere it is the packed rows' whole tiles, in which
+    `_aligned_layout` finds no room to pad."""
+    e, d, f = cfg.num_experts, cfg.embed_dim, cfg.mlp_dim
+    packed = -(-n_assignments // _GMM_ROWS)
+    visits = e * -(-n_assignments // (e * _GMM_ROWS))
+    aligned = max(packed, visits + e // 8)
+    tile_in_visits = _GMM_ROWS * (4 * d + 6 * f) / (6 * d * f)
+    pays = (visits + tile_in_visits * (aligned - packed)
+            <= _ALIGNED_COST_SHARE * (packed + e - 1))
+    return (aligned if pays else packed) * _GMM_ROWS
+
+
+def _aligned_layout(counts, n_rows: int):
+    """The extents of the experts in a buffer of `n_rows` rows. counts (E,)
+    int32: the sorted assignments of each expert. Returns (sizes (E,),
+    shift (E,)) int32: the rows the kernel is told an expert has, its
+    count rounded up to whole row tiles for as many of the first experts
+    as the buffer has room to pad (all of them where `_sorted_buffer_rows`
+    made room and the router is near even; an expert without rows stays
+    0), and how far an expert's rows lie behind their place in the packed
+    order: an expert whose predecessors are all padded starts on a row
+    tile, and no tile of its extent is computed for another expert."""
+    padded = -(-counts // _GMM_ROWS) * _GMM_ROWS
+    room = n_rows - counts.sum()
+    sizes = jnp.where(jnp.cumsum(padded - counts) <= room, padded, counts)
+    return sizes, jnp.cumsum(sizes - counts) - (sizes - counts)
+
+
 def _moe_grouped(tokens, router_logits, layers, layer, cfg: ModelConfig):
     """The sorted, dropless dispatch of `moe_mlp`: tokens (T, D) -> (T, D).
     `layers` holds the stacked (L, E, ...) expert weights, `layer` (an int
@@ -274,13 +341,26 @@ def _moe_grouped(tokens, router_logits, layers, layer, cfg: ModelConfig):
         # assignment a = token * k + slot; a stable sort, so an expert's
         # rows keep token order
         expert_of = gate_idx.reshape(t * k)
-        order = jnp.argsort(expert_of, stable=True)
-        # (T*k, D) and the rest of the last row tile
-        rows = tokens[jnp.pad(order // k, (0, -(t * k) % _GMM_ROWS))]
+        ranks = jnp.arange(t * k, dtype=jnp.int32)
+        expert_of_rank, order = lax.sort_key_val(expert_of, ranks)
+        # compared and summed, not scattered and gathered: a scatter-add
+        # of 12,672 ones cost 0.11 ms on the v5e, these a hundredth
+        experts = jnp.arange(e, dtype=jnp.int32)
+        counts = (expert_of[:, None] == experts).sum(0, dtype=jnp.int32)
+        n_rows = _sorted_buffer_rows(t * k, cfg)
+        sizes, shift = _aligned_layout(counts, n_rows)
+        row_of_rank = ranks + jnp.where(
+            expert_of_rank[:, None] == experts, shift, 0).sum(1)
+        # the token every row of the buffer reads: a row no assignment
+        # landed on (the rest of a padded extent, the rows behind the last
+        # expert's) reads token 0, finite like any other
+        rows = tokens[jnp.zeros((n_rows,), jnp.int32).at[row_of_rank].set(
+            order // k, indices_are_sorted=True, unique_indices=True)]
         # the stack seen as L * E groups, every other layer's empty: the
         # kernel then reads this layer's experts where they lie
-        group_sizes = jnp.zeros((n_layers * e,), jnp.int32).at[
-            layer * e + expert_of].add(1)
+        group_sizes = lax.dynamic_update_slice(
+            jnp.zeros((n_layers * e,), jnp.int32), sizes,
+            (jnp.asarray(layer, jnp.int32) * e,))
     with jax.named_scope("moe_experts"), jax.named_scope("grouped"):
         ys = _grouped_experts(
             rows, *(layers[name].reshape((n_layers * e,)
@@ -289,13 +369,14 @@ def _moe_grouped(tokens, router_logits, layers, layer, cfg: ModelConfig):
             group_sizes, kernel=jax.default_backend() == "tpu",
             activation=cfg.mlp_activation, tilings=_gmm_tilings(cfg))
     with jax.named_scope("moe_combine"):
-        inverse = jnp.zeros((t * k,), jnp.int32).at[order].set(
-            jnp.arange(t * k, dtype=jnp.int32))
+        # every assignment's row, by sorting the permutation back: a sort
+        # of 12,672 pairs cost 8 us on the v5e, the scatter 75
+        row_of = lax.sort_key_val(order, row_of_rank)[1]
         # gates rounded to the compute dtype and summed in float32, as the
         # dense dispatch's combine einsum has them
         out = jnp.einsum(
             "tk,tkd->td", gate_vals.astype(cfg.dtype),
-            ys[inverse].reshape(t, k, d),
+            ys[row_of].reshape(t, k, d),
             preferred_element_type=jnp.float32).astype(cfg.dtype)
     return out, aux
 

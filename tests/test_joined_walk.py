@@ -307,3 +307,46 @@ def test_joined_server_streams_equal_alternating(name, seeded, overlap):
     # a program of decode rounds alone has nothing to join
     assert all(not r["joined"] for r in records
                if not r.get("prefill_tokens"))
+
+
+@pytest.mark.parametrize("overlap", [True, False])
+@pytest.mark.parametrize("name,threshold", [
+    ("dropless_moe", 18), ("dropless_moe", 10 ** 9), ("can_drop", 18),
+    ("dense", 18)])
+def test_records_say_which_programs_sorted_their_experts(
+        name, threshold, overlap, monkeypatch):
+    """`grouped`: true on the record of a step whose joined call (16
+    chunk tokens a prompt row and the decode rows) is over the threshold,
+    false on a program of decode rounds alone (4 rows at most), at a
+    threshold no call reaches, at a capacity that can drop and for a
+    dense MLP: the host's reading of the rule the trace applies."""
+    monkeypatch.setattr(moe, "GROUPED_MIN_TOKENS", threshold)
+    sorts = name == "dropless_moe" and threshold == 18
+    # where the calls sort, a configuration of its own, so its programs
+    # are traced here, under this threshold, and the spy sees the dispatch
+    # they take; the other cases' programs are the file's own (no call of
+    # theirs sorts at any threshold this file sets), so they compile
+    # nothing new in a process that never unmaps compiled code
+    cfg = CONFIGS[name]
+    if sorts:
+        cfg = dataclasses.replace(cfg, norm_eps=1e-5 + 1e-9 * (1 + overlap))
+    traced = []
+    real = moe._grouped_experts
+    monkeypatch.setattr(moe, "_grouped_experts",
+                        lambda rows, *a, **kw: traced.append(
+                            rows.shape[0]) or real(rows, *a, **kw))
+    srv = PagedInferenceServer(_params(cfg), cfg, GREEDY, scheduler="mixed",
+                               overlap=overlap, **SRV_KW)
+    _staggered(srv, [None] * len(PROMPTS))
+    records = srv.flight_window()
+    assert all(isinstance(r["grouped"], bool) for r in records)
+    assert any(r["grouped"] for r in records) == bool(traced)
+    assert all(not r["grouped"] for r in records
+               if not r.get("prefill_tokens"))
+    if sorts:
+        mixed = [r for r in records if r["joined"]]
+        assert mixed and all(r["grouped"] for r in mixed)
+        # every expert's rows on row tiles of their own
+        assert traced and all(n % moe._GMM_ROWS == 0 for n in traced)
+    else:
+        assert not traced

@@ -202,7 +202,182 @@ def test_grouped_dispatch_matches_dense(monkeypatch, e, k, t, router, dtype):
         assert (top1 == 1).all()
 
 
-@pytest.mark.parametrize("t", [4, 64, 256, 512, 1024, 2048, 4096, 16384])
+def _counts(kind, e, n, rng):
+    """Assignments an expert, `n` in all, of the shapes a router gives."""
+    tile = moe._GMM_ROWS
+    if kind == "uniform":
+        return rng.multinomial(n, np.full(e, 1.0 / e))
+    if kind == "one_expert":  # every token's first choice
+        return np.bincount([1], minlength=e) * n
+    if kind == "empty_experts":  # every other expert has no row
+        return np.repeat(rng.multinomial(n, np.full(e // 2, 2.0 / e)),
+                         2) * (np.arange(e) % 2)
+    if kind == "one_tile":  # a group of exactly one tile
+        rest = rng.multinomial(n - tile, np.full(e - 1, 1.0 / (e - 1)))
+        return np.insert(rest, 2, tile)
+    assert kind == "tile_and_one"  # the bound's worst case
+    return np.full(e, tile * ((n // e) // tile) + 1)
+
+
+def _visits(group_sizes, m):
+    """Row-tile visits of the grouped matmul, by megablox's own metadata,
+    for group sizes handed over as `_moe_grouped` hands them: the stack
+    seen as L * E groups, every other layer's empty."""
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import (
+        make_group_metadata)
+    e = len(group_sizes)
+    sizes = np.zeros((LAYERS * e,), np.int32)
+    sizes[LAYER * e:(LAYER + 1) * e] = group_sizes
+    # jitted: one program a shape, where its eager operations left some
+    # thirty each behind in a process that never unmaps compiled code
+    return int(jax.jit(lambda g: make_group_metadata(
+        group_sizes=g, m=m, tm=moe._GMM_ROWS, start_group=0,
+        num_nonzero_groups=LAYERS * e, visit_empty_groups=False)[1])(
+            jnp.asarray(sizes)))
+
+
+def _widths(e, k, d, f):
+    """A configuration of real widths: the buffer's rule reads them."""
+    return ModelConfig(**{**MOE_TINY.__dict__, "num_experts": e,
+                          "num_experts_per_token": k, "embed_dim": d,
+                          "mlp_dim": f})
+
+
+MIXTRAL = _widths(8, 2, 4096, 14336)
+NARROW = _widths(64, 6, 2560, 768)   # the second cell's experts
+THIRD = _widths(128, 8, 2048, 768)   # a configuration no cell has
+# (widths, tokens, whether the shape's buffer has room to pad): both
+# cells' calls on both sides of the rule, as the v5e placed it (PERF.md,
+# PR 39), and a third configuration
+SHAPES = [(MIXTRAL, 256, False), (MIXTRAL, 288, True), (MIXTRAL, 320, True),
+          (MIXTRAL, 832, True), (MIXTRAL, 1088, False),
+          (MIXTRAL, 1344, False), (MIXTRAL, 1600, True),
+          (MIXTRAL, 2112, False), (NARROW, 576, False),
+          (NARROW, 832, False), (NARROW, 1088, False), (NARROW, 1344, True),
+          (NARROW, 2112, True), (THIRD, 1088, False), (THIRD, 2112, True)]
+SHAPE_IDS = [f"{c.num_experts}x{c.num_experts_per_token}-{t}"
+             for c, t, _ in SHAPES]
+
+
+@pytest.mark.parametrize("cfg,t,room", SHAPES, ids=SHAPE_IDS)
+def test_the_buffer_has_room_where_alignment_cuts_the_visits(cfg, t, room):
+    """`_sorted_buffer_rows` reads the call's shape and the widths: whole
+    row tiles, never fewer than the packed rows need, never more than the
+    bound of any router's padded extents, and more than the packed rows'
+    only where an even router's aligned visits and the longer buffer cost
+    seven eighths of the packed visits or less."""
+    tile, e = moe._GMM_ROWS, cfg.num_experts
+    n = t * cfg.num_experts_per_token
+    rows = moe._sorted_buffer_rows(n, cfg)
+    packed = -(-n // tile)
+    assert rows % tile == 0 and packed * tile <= rows
+    assert rows <= (n // tile + e) * tile
+    assert (rows > packed * tile) == room
+    if room:
+        assert rows == (e * -(-n // (e * tile)) + e // 8) * tile
+    # tiny widths (every other CPU test's): a tile of rows costs more than
+    # an expert's weights, and no shape makes room
+    tiny = _widths(e, cfg.num_experts_per_token, 32, 64)
+    assert moe._sorted_buffer_rows(n, tiny) == packed * tile
+
+
+@pytest.mark.parametrize("kind", ["uniform", "one_expert", "empty_experts",
+                                  "one_tile", "tile_and_one"])
+@pytest.mark.parametrize("cfg,t,room", SHAPES, ids=SHAPE_IDS)
+def test_every_experts_rows_start_on_a_row_tile(cfg, t, room, kind):
+    """`_aligned_layout`: the extents are whole row tiles for as many of
+    the first experts as the buffer has room to pad, they fit the buffer,
+    a rank's row lies in its expert's extent in the sorted order, and
+    megablox's own metadata visits each tile of a padded extent once, for
+    one expert: sum(ceil(g / tile)) visits where every expert is padded,
+    against a visit for every expert with a row in a tile."""
+    tile, e, k = moe._GMM_ROWS, cfg.num_experts, cfg.num_experts_per_token
+    counts = _counts(kind, e, t * k, np.random.default_rng(e + t))
+    n = int(counts.sum())
+    assert n <= t * k and counts.shape == (e,)
+    buffer = moe._sorted_buffer_rows(n, cfg)
+    sizes, shift = (np.asarray(a) for a in jax.jit(
+        moe._aligned_layout, static_argnums=1)(
+            jnp.asarray(counts, jnp.int32), buffer))
+    padded = -(-counts // tile) * tile
+    # a prefix of the experts is padded: as many as there is room for
+    fits = np.cumsum(padded - counts) <= buffer - n
+    n_fit = int(fits.sum())
+    assert fits[:n_fit].all()
+    assert (sizes == np.where(fits, padded, counts)).all()
+    assert (sizes[counts == 0] == 0).all() and sizes.sum() <= buffer
+    start = np.cumsum(sizes) - sizes
+    assert (shift == start - (np.cumsum(counts) - counts)).all()
+    # an expert behind padded ones starts on a row tile
+    assert (start[:n_fit + 1] % tile == 0).all()
+    expert_of_rank = np.repeat(np.arange(e), counts)
+    row_of_rank = np.arange(n) + shift[expert_of_rank]
+    assert (row_of_rank >= start[expert_of_rank]).all()
+    assert (row_of_rank < (start + counts)[expert_of_rank]).all()
+    assert (np.diff(row_of_rank) > 0).all()  # the sorted order, no row twice
+    visits = _visits(sizes, buffer)
+    packed_visits = _visits(counts, -(-n // tile) * tile)
+    assert visits <= packed_visits
+    if fits.all():
+        assert visits == -(-counts // tile).sum() == sizes.sum() // tile
+    if room and kind in ("uniform", "one_tile"):
+        # an even router finds the room the shape's rule made
+        assert fits.all() and 8 * visits <= 7 * packed_visits
+    if kind == "tile_and_one" and counts[0] > 1:
+        # the worst case a router can give: every pad a row short of a tile
+        assert not fits.all()
+
+
+@pytest.mark.parametrize("router", ["uniform", "one_expert", "empty_experts",
+                                    "one_tile"])
+@pytest.mark.parametrize("e,k", [(8, 2), (64, 6)])
+def test_aligned_layout_matches_dense(monkeypatch, e, k, router):
+    """The sorted dispatch with every expert on row tiles of its own gives
+    the dense dispatch's output whatever the groups: uniform routing, one
+    expert holding a row of every token, experts without a row, and a
+    group that fills exactly one tile (no pad row behind it)."""
+    t = moe._GMM_ROWS if router == "one_tile" else 320
+    cfg, layers = _stack(e, k, "float32")
+    # room for any router's padded extents, which the rule gives no shape
+    # at these widths
+    monkeypatch.setattr(
+        moe, "_sorted_buffer_rows",
+        lambda n, cfg: (n // moe._GMM_ROWS + cfg.num_experts) * moe._GMM_ROWS)
+    x = jax.random.normal(jax.random.key(e), (1, t, cfg.embed_dim))
+    bias = jnp.zeros((e,))
+    if router in ("one_expert", "one_tile"):
+        bias = bias.at[1].set(1e3)
+    elif router == "empty_experts":
+        bias = bias.at[::2].set(-1e3)
+    x = x.at[..., 0].set(1.0)
+    layers["router"] = layers["router"].at[LAYER, 0].set(bias)
+    seen = []
+    real = moe._grouped_experts
+    monkeypatch.setattr(
+        moe, "_grouped_experts",
+        lambda rows, wg, wu, wd, sizes, **kw: seen.append(
+            (rows.shape[0], np.asarray(sizes))) or real(
+                rows, wg, wu, wd, sizes, **kw))
+    (out_g, _), (out_d, _) = _both_dispatches(monkeypatch, x, layers, cfg)
+    np.testing.assert_allclose(np.asarray(out_g), np.asarray(out_d),
+                               atol=1e-5, rtol=1e-5)
+    (n_rows, sizes), = seen
+    assert n_rows == moe._sorted_buffer_rows(t * k, cfg)
+    mine = sizes[LAYER * e:(LAYER + 1) * e]
+    assert sizes.sum() == mine.sum() <= n_rows
+    assert (mine % moe._GMM_ROWS == 0).all()
+    if router in ("one_expert", "one_tile"):
+        assert mine[1] == -(-t // moe._GMM_ROWS) * moe._GMM_ROWS
+    if router == "one_tile":
+        assert mine[1] == t == moe._GMM_ROWS
+    if router == "empty_experts":
+        assert (mine[::2] == 0).all() and (mine[1::2] > 0).all()
+    if (e, k) == (8, 2) and router == "uniform":
+        # 320 tokens of 8 x 2: every expert on a tile of its own
+        assert (mine == moe._GMM_ROWS).all()
+
+
+@pytest.mark.parametrize("t", [4, 64, 256, 288, 512, 1024, 2048, 4096, 16384])
 def test_which_dispatch_runs(t, devices8):
     """The rule reads the capacity, the token count, where the weights
     lie and the mesh, and nothing else."""
@@ -223,10 +398,12 @@ def test_which_dispatch_runs(t, devices8):
     # one that cannot moves at the threshold and above, not below
     moved = t >= moe.GROUPED_MIN_TOKENS
     assert moe._dispatch_grouped(no_drop, t, stack) == moved
-    # at (64, 8) the threshold is the experts' own (PR 35): 161 tokens
-    assert moe.grouped_min_tokens(no_drop) == moe.GROUPED_MIN_TOKENS
+    # at (64, 8) the threshold is the experts' own (PR 35, placed again
+    # by PR 39): 144 tokens
+    assert moe.grouped_min_tokens(no_drop) == moe.GROUPED_MIN_TOKENS == 288
     assert moe._dispatch_grouped(wide, t, (wide_layers, 0)) == (
-        t >= moe.grouped_min_tokens(wide) == 161)
+        t >= moe.grouped_min_tokens(wide) == 144)
+    assert moe.grouped_min_tokens(NARROW) == 153
     # a caller that scans its layers has no stack to point into; weights
     # that need a cast or a dequantization first are not used in place
     assert not moe._dispatch_grouped(no_drop, t, None)
@@ -267,11 +444,13 @@ def test_paged_prefill_takes_the_sorted_dispatch_and_agrees(monkeypatch):
         return np.asarray(out)
 
     grouped = logits(32)
-    # the T * k sorted rows, gathered out to whole row tiles
+    # the T * k sorted rows in the buffer their shape is given: here the
+    # packed rows' one tile (4 experts on tiles of their own would be 4
+    # visits for the packed layout's 4: no room is made)
     n_rows = 2 * 16 * cfg.num_experts_per_token
     assert n_rows % moe._GMM_ROWS
-    assert calls == [(-(-n_rows // moe._GMM_ROWS) * moe._GMM_ROWS,
-                      cfg.embed_dim)] * cfg.num_layers
+    assert moe._sorted_buffer_rows(n_rows, cfg) == moe._GMM_ROWS
+    assert calls == [(moe._GMM_ROWS, cfg.embed_dim)] * cfg.num_layers
     dense = logits(33)
     assert len(calls) == cfg.num_layers
     np.testing.assert_allclose(grouped, dense, atol=2e-5, rtol=1e-5)
