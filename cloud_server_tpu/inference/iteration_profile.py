@@ -54,6 +54,14 @@ only the serialized host tail (`commit` + `launch` + `epilogue`) —
 the residual cost the overlap could not hide. The per-record identity
 becomes `host_ms + device_wait_ms + overlap_ms == duration_ms`.
 
+Outside that identity, and in no phase: `between_ms`, from a busy
+step's `end()` to the next step's `begin()` (the loop's yield to the
+streaming threads, the step lock, the preamble), so that
+`between_ms + duration_ms` is the scheduler's period and the records
+of a window add up to its length. Inside `build`, which stays whole:
+`stage_ms`, two `lap()`s around the block that hands the plan's
+arrays to the device.
+
 Design rules (the metrics layer's own):
 
   * **Stdlib only, zero device work.** The clock is
@@ -140,9 +148,9 @@ _ITER_ARG_KEYS = ("iteration", "scheduler", "n_live", "decode_rounds",
                   "decode_tokens", "prefill_tokens", "tokens_scheduled",
                   "budget_utilization", "host_ms", "device_wait_ms",
                   "host_gap_frac", "preemptions", "pending", "n_jobs",
-                  "overlap", "overlap_ms", "inflight_depth",
-                  "overlap_launch_lead_ms", "delivered", "joined", "grouped",
-                  "launch_h2d")
+                  "overlap", "overlap_ms", "overlap_launch_lead_ms",
+                  "delivered", "joined", "grouped", "launch_h2d",
+                  "between_ms", "stage_ms", "plan_h2d", "host_late")
 
 
 class IterationProfiler:
@@ -167,14 +175,21 @@ class IterationProfiler:
     flight-recorder index the step gets when it records. A step
     that dispatches nothing ends in `close()`: no record, and its
     `sched/iteration` carries no index. With no capture running an
-    annotation is an inactive check."""
+    annotation is an inactive check.
 
-    __slots__ = ("t0", "_last", "_acc", "_phase", "_annotate",
-                 "_stats", "_iter_span", "_span")
+    `between_ms` is what passed between the `end()` of a busy step and
+    this step's `begin()`, on the two clock reads those already make;
+    None on a first step and after a `close()`: a step that recorded
+    nothing, or a loop that went to wait for work."""
+
+    __slots__ = ("t0", "between_ms", "_last", "_ended", "_acc", "_phase",
+                 "_annotate", "_stats", "_iter_span", "_span")
 
     def __init__(self, annotate=None):
         self.t0 = 0.0
+        self.between_ms: float | None = None
         self._last = 0.0
+        self._ended = False
         self._acc: dict[str, float] = {}
         self._phase = PHASES[0]
         self._annotate = annotate
@@ -186,6 +201,8 @@ class IterationProfiler:
         if self._iter_span is not None:
             self.close()  # the previous step raised mid-iteration
         t = perf_counter()
+        self.between_ms = (t - self._last) * 1e3 if self._ended else None
+        self._ended = False
         self.t0 = self._last = t
         self._acc = {}
         self._phase = phase = PHASES[0]
@@ -226,11 +243,20 @@ class IterationProfiler:
         if self._iter_span is not None:
             self._iter_span.set_metadata(**self._stats)
             self.close()
+        self._ended = True
         return t
+
+    def lap(self) -> float:
+        """One read of the phase clock that is no boundary: two of them
+        time a part of the open phase (`stage_ms` inside `build`)."""
+        return perf_counter()
 
     def close(self) -> None:
         """Close the open trace events without a clock read: the end of
-        a step that recorded nothing."""
+        a step that recorded nothing, or of a stretch of steps (the
+        loop waits for work), so the time to the next `begin()` is no
+        step's `between_ms`."""
+        self._ended = False
         if self._span is not None:
             self._span.__exit__(None, None, None)
             self._span = None

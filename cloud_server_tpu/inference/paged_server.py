@@ -1643,9 +1643,13 @@ class PagedInferenceServer:
         self._dispatches = 0
         # host arrays handed to the device (`_to_device`), and what the
         # launch performed THIS iteration added: the flight record's
-        # `launch_h2d`, beside its `launch` phase
+        # `launch_h2d`, beside its `launch` phase. What the plan built
+        # THIS iteration staged, and how long that took inside `build`:
+        # the record's `plan_h2d` and `stage_ms`
         self._h2d = 0
         self._iter_launch_h2d = 0
+        self._iter_plan_h2d = 0
+        self._iter_stage_ms: float | None = None
         self._stop = threading.Event()
         self._thread: threading.Thread | None = None
 
@@ -3622,6 +3626,11 @@ class PagedInferenceServer:
         # deliberately never flags those), so these transfers ride
         # behind the in-flight program. `_launch_plan` passes them
         # through as they are and hands over one array, the patch.
+        # The step's own record says how many went and how long the
+        # block took (`plan_h2d`, `stage_ms`): this plan's stats are
+        # recorded a step later, with the program's commit.
+        t_stage = prof.lap() if prof is not None else None
+        h2d0 = self._h2d
         put = self._to_device
         if plan.pf is not None:
             pf = plan.pf
@@ -3640,6 +3649,9 @@ class PagedInferenceServer:
         if plan.spec_lens is not None:
             plan.lim_dev = put(self._pad_limits(
                 plan.spec_lens, int(plan.live_g.shape[0])))
+        self._iter_plan_h2d = self._h2d - h2d0
+        if prof is not None:
+            self._iter_stage_ms = (prof.lap() - t_stage) * 1e3
         return plan
 
     def _stage_program_kind(self, stats: dict, chunk_tokens: int,
@@ -3781,6 +3793,10 @@ class PagedInferenceServer:
         launch is on the device (`_deliver`)."""
         infl, self._inflight = self._inflight, None
         prof = self._profiler
+        # who set the pace: the program had already finished when the
+        # host came for its results, so the device stood idle for the
+        # host in this iteration (a query: no wait, no transfer)
+        host_late = infl.futures[0].is_ready()
         t_wait = (prof.enter("device") if prof is not None
                   else time.perf_counter())
         # analysis: allow[lock-discipline] THE sanctioned per-iteration
@@ -3791,7 +3807,7 @@ class PagedInferenceServer:
             prof.enter("commit")
         st = infl.stats
         st["overlap"] = True
-        st["inflight_depth"] = 1
+        st["host_late"] = host_late
         # how long the device ran ahead of the host needing results:
         # launch -> the moment this step's overlapped work finished
         # and the sync began. Residual device phase > 0 means the
@@ -4189,6 +4205,14 @@ class PagedInferenceServer:
         # phase; a sequential iteration has no such phase
         st["launch_h2d"] = self._iter_launch_h2d
         self._iter_launch_h2d = 0
+        # what THIS step's planning staged onto the device for the
+        # program it launched (0: it planned nothing), and the time of
+        # that block inside `build`
+        st["plan_h2d"] = self._iter_plan_h2d
+        self._iter_plan_h2d = 0
+        if self._iter_stage_ms is not None:
+            st["stage_ms"] = self._iter_stage_ms
+            self._iter_stage_ms = None
         # KV-pool telemetry (joins phases_ms in the record): the
         # iteration's page flow (deltas against the step-start
         # baseline — sweep/admission included) and the occupancy split
@@ -4228,6 +4252,10 @@ class PagedInferenceServer:
             st["t_start"] = t0
             st["phases_ms"] = phases
             st["duration_ms"] = (now - t0) * 1e3
+            if prof.between_ms is not None:
+                # since the previous busy step's closing stamp: with
+                # `duration_ms` the scheduler's period, in no phase
+                st["between_ms"] = prof.between_ms
             overlapped = bool(st.get("overlap"))
             st.update(derive_gap_fields(phases, st["duration_ms"],
                                         overlapped))
@@ -5227,6 +5255,9 @@ class PagedInferenceServer:
                 # so admission latency never pays the timeout; the
                 # timeout itself keeps pending-deadline sweeps and
                 # stop() responsive even if a notify is missed.
+                if self._profiler is not None:
+                    # a wait for work is no step's `between_ms`
+                    self._profiler.close()
                 with self._work:
                     if not self._pending and not self._stop.is_set():
                         self._work.wait(idle_sleep_s)

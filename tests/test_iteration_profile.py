@@ -127,6 +127,47 @@ def test_profiler_boundaries_are_trace_events():
         ("open", "sched/iteration"), ("open", "sched/sweep")]
 
 
+def test_between_ms_is_the_time_from_an_end_to_the_next_begin(monkeypatch):
+    """`begin()` after a busy step's `end()` keeps the difference of
+    the two reads they already make; after a `close()` (an idle step,
+    a wait for work, a step that raised) and on the first step there is
+    none. No read of its own: the ticks below are all there are."""
+    ticks = iter([10.0, 10.5,          # busy step: begin, end
+                  10.75, 11.0,         # busy: between 0.25 s
+                  11.5,                # idle step (close): between 0.5 s
+                  12.0, 12.5,          # busy after an idle one: none
+                  13.0, 13.25])        # busy after a wait for work: none
+    monkeypatch.setattr(ip, "perf_counter", lambda: next(ticks))
+    p = IterationProfiler()
+    p.begin()
+    assert p.between_ms is None        # the first step
+    p.end()
+    p.begin()
+    assert p.between_ms == pytest.approx(250.0)
+    p.end()
+    p.begin()
+    assert p.between_ms == pytest.approx(500.0)
+    p.close()                          # recorded nothing
+    p.begin()
+    assert p.between_ms is None
+    p.end()
+    p.close()                          # the loop goes to wait for work
+    p.begin()
+    assert p.between_ms is None
+    p.end()
+    assert next(ticks, None) is None   # every tick was a begin or an end
+
+
+def test_lap_reads_the_clock_and_moves_no_boundary(monkeypatch):
+    ticks = iter([1.0, 2.0, 3.0, 4.0])
+    monkeypatch.setattr(ip, "perf_counter", lambda: next(ticks))
+    p = IterationProfiler()
+    p.begin()
+    assert (p.lap(), p.lap()) == (2.0, 3.0)
+    p.end()
+    assert p.phases_ms() == {"sweep": pytest.approx(3000.0)}
+
+
 def test_derive_gap_fields():
     d = derive_gap_fields({"sweep": 1.0, "admission": 2.0, "device": 7.0},
                           10.0)
@@ -215,7 +256,7 @@ def test_flight_records_carry_phase_split(params):
     ov = [rec for rec in window if rec.get("overlap")]
     assert ov, "default mixed churn produced no overlapped iterations"
     for rec in ov:
-        assert rec["inflight_depth"] == 1
+        assert "inflight_depth" not in rec
         assert rec["overlap_launch_lead_ms"] >= 0.0
     # per-phase histograms observed once per busy iteration
     snap = srv.metrics_snapshot()
@@ -258,6 +299,84 @@ def test_overlapped_records_carry_the_delivery(params):
     assert sum(rec.get("delivered", 0) for rec in window) \
         == len(streamed) + len(reqs)
     assert "deliver" in OVERLAP_PHASES and "deliver" in PHASES
+
+
+def test_records_tile_the_clock_with_between_ms(params):
+    """`between_ms + duration_ms` is the scheduler's period: the first
+    record has no `between_ms`, every later one of an unbroken run of
+    busy steps starts where the one before closed, and a record after
+    an idle step has none again. `between_ms` is outside the identity
+    `host_ms + device_wait_ms + overlap_ms == duration_ms`."""
+    srv = PagedInferenceServer(params, CFG, GREEDY, scheduler="mixed",
+                               **PAGED_KW)
+    assert all(r.done for r in _churn(srv))
+    window = srv.flight_window()
+    assert len(window) > 3 and "between_ms" not in window[0]
+    for prev, rec in zip(window, window[1:]):
+        assert rec["between_ms"] > 0.0
+        assert rec["t_start"] - rec["between_ms"] * 1e-3 == pytest.approx(
+            prev["t_start"] + prev["duration_ms"] * 1e-3, abs=1e-9)
+        assert (rec["host_ms"] + rec["device_wait_ms"]
+                + rec.get("overlap_ms", 0.0)) == pytest.approx(
+            rec["duration_ms"], rel=1e-9, abs=1e-6)
+    span = (window[-1]["t_start"] + window[-1]["duration_ms"] * 1e-3
+            - window[0]["t_start"]) * 1e3
+    assert sum(r.get("between_ms", 0.0) + r["duration_ms"]
+               for r in window) - window[0].get("between_ms", 0.0) \
+        == pytest.approx(span, abs=1e-6)
+    n = len(window)
+    srv.step()                      # idle: no record, no closing stamp
+    srv.submit([7, 9, 3], max_new_tokens=3)
+    srv.run_until_idle()
+    after = srv.flight_window()[n:]
+    assert after and "between_ms" not in after[0]
+    assert all("between_ms" in rec for rec in after[1:])
+
+
+def test_records_split_build_and_say_who_set_the_pace(params):
+    """`stage_ms` and `plan_h2d` sit on the record of the step that
+    planned (beside its `build` and `launch_h2d`), `host_late` on the
+    record of the step that committed the program: a bool on every
+    overlapped record, true where the program was known ready before
+    the step came for it."""
+    srv = PagedInferenceServer(params, CFG, GREEDY, scheduler="mixed",
+                               **PAGED_KW)
+    first = [srv.submit([5 + i, 9, 3], max_new_tokens=8) for i in range(2)]
+    srv.step()
+    srv.step()
+    assert srv._inflight is not None
+    # the program is ready before the next step is taken
+    jax.block_until_ready(srv._inflight.futures)
+    srv.step()
+    late = srv.flight_window()[-1]
+    assert late["overlap"] and late["host_late"] is True
+    long = srv.submit([(k * 7) % 60 + 1 for k in range(40)],
+                      max_new_tokens=4)
+    srv.run_until_idle()
+    assert all(r.done for r in first + [long])
+    window = srv.flight_window()
+    for rec in window:
+        assert isinstance(rec["plan_h2d"], int) and rec["plan_h2d"] >= 0
+        if rec.get("overlap"):
+            assert isinstance(rec["host_late"], bool)
+        else:
+            assert "host_late" not in rec
+        # a step that staged a plan timed the block, inside `build`;
+        # one that planned nothing staged nothing
+        assert ("stage_ms" in rec) == (rec["plan_h2d"] > 0)
+        if "stage_ms" in rec:
+            assert 0.0 < rec["stage_ms"] <= rec["phases_ms"]["build"]
+        # a launch follows a plan, never the other way round
+        assert rec["launch_h2d"] <= min(rec["plan_h2d"], 1)
+    assert any(rec["plan_h2d"] for rec in window)
+    # with the profiler off the counter stays and the time goes
+    off = PagedInferenceServer(params, CFG, GREEDY, scheduler="mixed",
+                               iteration_profile=False, **PAGED_KW)
+    assert all(r.done for r in _churn(off))
+    for rec in off.flight_window():
+        assert "stage_ms" not in rec and "between_ms" not in rec
+        assert rec["plan_h2d"] >= 0
+        assert isinstance(rec.get("host_late", False), bool)
 
 
 @pytest.mark.parametrize("overlap", [True, False])
@@ -377,11 +496,13 @@ def test_profiled_mixed_step_dispatch_sync_and_clock_counts(
         assert churn_steps < 50
     assert churn_steps >= 2  # real churn: admission spanned iterations
     # bounded constant: begin + the boundaries into admission, build,
-    # device, commit, launch, deliver and epilogue + end = 9
+    # device, commit, launch, deliver and epilogue + end = 9, and the
+    # two laps around the plan's staging block (`stage_ms`) = 11;
+    # `between_ms` reads no clock of its own
     assert len(clock_per_step) == 1, (
         f"profiler clock reads varied across mixed iterations: "
         f"{clock_per_step}")
-    assert clock_per_step.pop() <= 9
+    assert clock_per_step.pop() <= 11
     for n, f in origs.items():
         monkeypatch.setattr(ps, n, f)
     monkeypatch.setattr(jax, "device_get", orig_get)
@@ -443,6 +564,18 @@ def test_scheduler_chrome_trace_wellformed(params):
     # iteration indices agree with flight_window()
     assert [e["args"]["iteration"] for e in iters] == \
         [rec["iteration"] for rec in window]
+    # the iteration track's args carry the record's scalars, the parts
+    # of the hidden host work among them
+    for e, rec in zip(iters, window):
+        for k in ("between_ms", "stage_ms", "plan_h2d", "host_late",
+                  "launch_h2d"):
+            assert (k in e["args"]) == (k in rec)
+            if k in rec:
+                assert e["args"][k] == rec[k]
+        assert "inflight_depth" not in e["args"]
+    assert {"between_ms", "stage_ms", "plan_h2d", "host_late"} \
+        <= set().union(*(e["args"] for e in iters))
+    assert "inflight_depth" not in ip._ITER_ARG_KEYS
     by_iter = {e["args"]["iteration"]: e for e in iters}
     for e in phases:
         assert e["name"] in PHASES

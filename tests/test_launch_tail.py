@@ -46,8 +46,9 @@ def _watch_launches(srv, monkeypatch):
     """Count, by the test's own means, what `_launch_plan` does: host
     arrays converted by jax's two conversion calls, host arrays among
     the step program's arguments (the call would transfer those itself),
-    and key splits made outside a trace anywhere in the step."""
-    seen = {"launches": [], "converted": 0, "host_args": 0,
+    key splits made outside a trace anywhere in the step, and what the
+    launched plan had staged (`plan_h2d`)."""
+    seen = {"launches": [], "staged": [], "converted": 0, "host_args": 0,
             "eager_splits": 0, "in_launch": False}
 
     def counting(fn):
@@ -92,6 +93,7 @@ def _watch_launches(srv, monkeypatch):
             seen["launches"].append(
                 (plan.kind, plan.sl_d is None, plan.g_iter > 0,
                  seen["converted"] - before, srv._iter_launch_h2d))
+            seen["staged"].append(srv._iter_plan_h2d)
 
     monkeypatch.setattr(srv, "_launch_plan", watched_launch)
     return seen
@@ -131,6 +133,22 @@ def test_a_launch_hands_the_device_one_array(params, monkeypatch,
     counts = [rec["launch_h2d"] for rec in srv.flight_window()]
     assert set(counts) == {0, 1}
     assert sum(counts) == len(seen["launches"])
+    # what the plan staged while the program before it ran, one transfer
+    # an array: 14 of the prefill group and the 12 leaves of its
+    # samplers (a mixed plan), `d_stop`, the decode rows' 12 sampler
+    # leaves, `gid_d`, `aid_d`, the rows' slots where rows are compacted,
+    # the draft limits where drafts run
+    staged = {(kind, rows_are_slots): n for (kind, rows_are_slots, *_), n
+              in zip(seen["launches"], seen["staged"])}
+    drafts = 1 if spec_drafts else 0
+    assert staged == {("mixed", False): 42 + drafts,
+                      ("decode", False): 16 + drafts,
+                      ("decode", True): 15 + drafts}
+    # on the record of the step that planned, beside that step's launch;
+    # a plan whose rows all died at the commit was staged and not launched
+    plans = [rec["plan_h2d"] for rec in srv.flight_window()]
+    assert set(plans) <= set(staged.values()) | {0}
+    assert [n for n, c in zip(plans, counts) if c] == seen["staged"]
 
 
 # -- (b) the key is made inside the program ----------------------------------

@@ -343,7 +343,8 @@ def test_overlap_flight_fields_and_stats_block(params):
     ov = [r for r in recs if r.get("overlap")]
     assert ov, "no overlapped iterations recorded"
     for r in ov:
-        assert r["inflight_depth"] == 1
+        # `overlap` says it; the depth is `overlap_stats()`'s alone
+        assert "inflight_depth" not in r
         assert r["overlap_launch_lead_ms"] >= 0.0
         assert r["overlap_ms"] >= 0.0
         # residual-host definition: only commit/launch/epilogue count
