@@ -1544,6 +1544,14 @@ class PagedInferenceServer:
         # wait out the production default)
         self.unserialized_teardowns = 0
         self._teardown_lock_timeout_s = 5.0
+        # how long stop() waits for the scheduler thread to leave its
+        # step. A step that meets a dispatch shape no earlier step
+        # drove traces and compiles it: 15 to 30 s and more at serving
+        # depth on the chip. A thread given up on inside such a step
+        # ends it later all the same and writes `self.state` back, so
+        # the pools outlive a caller that dropped them after stop()
+        # (PERF.md, PR 41: a check that then found no room)
+        self._scheduler_join_timeout_s = 120.0
         self._draining = False
         # admission-latency bound: while prefill jobs are in flight,
         # decode dispatches shrink to this many rounds (default 1) so a
@@ -5339,7 +5347,7 @@ class PagedInferenceServer:
             # wait so shutdown does not pay the wait timeout
             self._work.notify_all()
         if self._thread is not None:
-            self._thread.join(timeout=30)
+            self._thread.join(timeout=self._scheduler_join_timeout_s)
             self._thread = None
         # analysis: allow[lock-discipline] post-join read: the scheduler
         # thread is dead (or never ran) by this point

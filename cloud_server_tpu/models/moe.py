@@ -324,11 +324,29 @@ def _aligned_layout(counts, n_rows: int):
     return sizes, jnp.cumsum(sizes - counts) - (sizes - counts)
 
 
+@jax.jit  # traced once a shape, like `_grouped_experts`
+def _weighted_sum(ys, row_of, gates):
+    """out[t] = sum over j of gates[t, j] * ys[row_of[t, j]]: ys (M, D),
+    row_of (T, k) int32, gates (T, k) float32 -> (T, D) in ys.dtype. A
+    token's k rows are gathered one slot after the other, multiplied and
+    added in float32 and rounded once: no (T, k, D) array is written (in
+    float32 a quarter of the layer's time at 2,112 tokens of 6 x 2,560).
+    The gates stay float32: the v5e takes no round trip to bfloat16 inside
+    one fusion, so rounding them would hang on where XLA cuts its fusions.
+    Behind the barrier the sum is one array a layer: without it XLA keeps
+    every layer's k gathered arrays to the program's end (PERF.md, PR 41)."""
+    acc = jnp.zeros((row_of.shape[0], ys.shape[1]), jnp.float32)
+    for j in range(row_of.shape[1]):
+        acc += gates[:, j, None] * ys[row_of[:, j]].astype(jnp.float32)
+    return lax.optimization_barrier(acc.astype(ys.dtype))
+
+
 def _moe_grouped(tokens, router_logits, layers, layer, cfg: ModelConfig):
     """The sorted, dropless dispatch of `moe_mlp`: tokens (T, D) -> (T, D).
     `layers` holds the stacked (L, E, ...) expert weights, `layer` (an int
-    or an int32 scalar) says which of them is this call's."""
-    t, d = tokens.shape
+    or an int32 scalar) says which of them is this call's. The combine is
+    `_weighted_sum`: a token's k rows, by the router's float32 gates."""
+    t = tokens.shape[0]
     k, e = cfg.num_experts_per_token, cfg.num_experts
     n_layers = layers["w_gate"].shape[0]
     with jax.named_scope("moe_route"):
@@ -372,12 +390,7 @@ def _moe_grouped(tokens, router_logits, layers, layer, cfg: ModelConfig):
         # every assignment's row, by sorting the permutation back: a sort
         # of 12,672 pairs cost 8 us on the v5e, the scatter 75
         row_of = lax.sort_key_val(order, row_of_rank)[1]
-        # gates rounded to the compute dtype and summed in float32, as the
-        # dense dispatch's combine einsum has them
-        out = jnp.einsum(
-            "tk,tkd->td", gate_vals.astype(cfg.dtype),
-            ys[row_of].reshape(t, k, d),
-            preferred_element_type=jnp.float32).astype(cfg.dtype)
+        out = _weighted_sum(ys, row_of.reshape(t, k), gate_vals)
     return out, aux
 
 

@@ -277,6 +277,32 @@ def test_wedge_blocks_scheduler_until_stop(params):
     assert srv._thread is None
 
 
+def test_stop_waits_out_a_long_step(params):
+    """stop() racing a step that outlasts the teardown's lock (on the
+    chip: a step that compiles a new dispatch shape, 15 to 30 s) returns
+    only when the scheduler thread has left it: the teardown is
+    serialized, and pools the caller then drops are not written back by
+    a late `self.state = ...` (the benchmark's check found them alive)."""
+    fp = FaultPlan()
+    srv = PagedInferenceServer(params, CFG, GREEDY, **SRV_KW,
+                               faults=fp).start()
+    assert srv._scheduler_join_timeout_s >= 120.0  # a compile and more
+    srv._teardown_lock_timeout_s = 0.05
+    req = srv.submit(PROMPT, max_new_tokens=8)
+    assert req.result(timeout=60) is not None
+    fp.arm("iteration_stall", count=1, stall_ms=600)
+    late = srv.submit(PROMPT, max_new_tokens=8)
+    time.sleep(0.2)  # the scheduler is inside the stalled step
+    thread = srv._thread
+    srv.stop()
+    assert not thread.is_alive()
+    assert srv.unserialized_teardowns == 0
+    assert late.done
+    srv.state = None
+    time.sleep(0.7)  # past the stall's end
+    assert srv.state is None
+
+
 def test_unserialized_teardown_counter(params):
     """_fail_all against a WEDGED scheduler (step lock never released):
     the bounded acquire times out, teardown proceeds unserialized, and

@@ -377,6 +377,98 @@ def test_aligned_layout_matches_dense(monkeypatch, e, k, router):
         assert (mine == moe._GMM_ROWS).all()
 
 
+def _values(jaxpr):
+    """Every value an equation of `jaxpr` writes, nested jaxprs' too."""
+    for eqn in jaxpr.eqns:
+        yield from (v.aval for v in eqn.outvars)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _values(sub)
+
+
+@pytest.mark.parametrize("e,k,t", [(8, 2, 320), (64, 6, 2112)])
+def test_the_combine_writes_no_tokens_by_k_by_hidden_array(monkeypatch, e, k,
+                                                           t):
+    """Both cells' widest calls (tiny widths): outside the experts'
+    kernels the sorted dispatch writes no float32 value of T k D elements
+    or more, and the combine itself none of that size in any dtype: a
+    token's k rows are gathered and added one slot after the other."""
+    cfg, layers = _stack(e, k, "bfloat16")
+    d = cfg.embed_dim
+    # the experts stand aside: off the chip `lax.ragged_dot` accumulates
+    # its (M, N) output in float32, which the megablox kernel does not
+    monkeypatch.setattr(moe, "_grouped_experts",
+                        lambda rows, *a, **kw: rows)
+    whole = jax.make_jaxpr(
+        lambda x, logits: moe._moe_grouped(x, logits, layers, LAYER, cfg))(
+            jax.ShapeDtypeStruct((t, d), jnp.bfloat16),
+            jax.ShapeDtypeStruct((t, e), jnp.float32))
+    wide = [v for v in _values(whole.jaxpr)
+            if v.dtype == jnp.float32 and v.size >= t * k * d]
+    assert not wide, wide
+    rows = moe._sorted_buffer_rows(t * k, cfg)
+    combine = jax.make_jaxpr(moe._weighted_sum)(
+        jax.ShapeDtypeStruct((rows, d), jnp.bfloat16),
+        jax.ShapeDtypeStruct((t, k), jnp.int32),
+        jax.ShapeDtypeStruct((t, k), jnp.float32))
+    sizes = [v.size for v in _values(combine.jaxpr)]
+    assert max(sizes) == t * d < t * k * d, max(sizes)
+    assert combine.out_avals[0].dtype == jnp.bfloat16
+
+
+def _bf16_ulp(x):
+    """The spacing of bfloat16 (8 significant bits) at |x|, float64."""
+    return 2.0 ** (np.floor(np.log2(np.maximum(np.abs(x), 2.0 ** -126))) - 7)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("e,k,t", [(8, 1, 320), (8, 2, 320), (64, 6, 200)])
+def test_the_combine_is_the_float64_sum_rounded_once(monkeypatch, e, k, t,
+                                                     dtype):
+    """out[t] = sum_j gate[t, j] * ys[row_of[t, j]] with the gates in
+    float32 as the router gives them (what the v5e's program computed
+    all along: PERF.md, PR 41), against numpy in float64: float32 to
+    1e-6, bfloat16 within one ulp of the rounded float64 sum. Every other
+    expert has no row. The experts' outputs are a table by buffer row, so
+    a row taken from the wrong place shows; the rows' places are counted
+    here from the router's choices (a stable sort by expert in the packed
+    rows' whole tiles: tiny widths make no more room)."""
+    cfg, layers = _stack(e, k, dtype)
+    keys = jax.random.split(jax.random.key(e + k), 3)
+    logits = jax.random.normal(keys[0], (t, e)).at[:, ::2].add(-1e3)
+    x = jax.random.normal(keys[1], (t, cfg.embed_dim)).astype(dtype)
+    n_rows = moe._sorted_buffer_rows(t * k, cfg)
+    assert n_rows == -(-t * k // moe._GMM_ROWS) * moe._GMM_ROWS
+    table = jax.random.normal(keys[2], (n_rows, cfg.embed_dim)).astype(dtype)
+    monkeypatch.setattr(moe, "_grouped_experts", lambda rows, *a, **kw: table)
+    out, _ = jax.jit(lambda x, logits: moe._moe_grouped(
+        x, logits, layers, LAYER, cfg))(x, logits)
+    assert out.dtype == jnp.dtype(dtype)
+
+    _, gates, idx = jax.jit(moe._top_k_gates, static_argnums=1)(logits, k)
+    assert (np.asarray(idx) % 2 == 1).all()  # the even experts are empty
+    expert_of = np.asarray(idx).reshape(t * k)
+    order = np.argsort(expert_of, kind="stable")
+    counts = np.bincount(expert_of, minlength=e)
+    # the last tile's spare rows pad as many of the first experts as fit
+    pad = -counts % moe._GMM_ROWS
+    pad[np.cumsum(pad) > n_rows - t * k] = 0
+    row_of = np.empty(t * k, np.int64)
+    row_of[order] = np.arange(t * k) + np.repeat(np.cumsum(pad) - pad, counts)
+    assert gates.dtype == jnp.float32
+    terms = (np.asarray(gates, np.float64)[:, :, None]
+             * np.asarray(table, np.float64)[row_of.reshape(t, k)])
+    want = terms.sum(1)
+    got = np.asarray(out, np.float64)
+    # the float32 products and additions: 2 k - 1 roundings of 2**-24
+    adds = (2 * k - 1) * 2.0 ** -24 * np.abs(terms).sum(1)
+    if dtype == "float32":
+        np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
+    else:
+        rounded = want.astype(jnp.bfloat16).astype(np.float64)
+        assert (np.abs(got - rounded)
+                <= np.maximum(_bf16_ulp(rounded), adds)).all()
+
+
 @pytest.mark.parametrize("t", [4, 64, 256, 288, 512, 1024, 2048, 4096, 16384])
 def test_which_dispatch_runs(t, devices8):
     """The rule reads the capacity, the token count, where the weights
