@@ -17,6 +17,15 @@ bandwidth counts one cache read per iteration.
 
 Run on the chip:  python benchmarks/decode_attention_bench.py
 (one process holds the chip; run nothing else concurrently.)
+
+With arguments it times the paged kernel alone at a serving shape:
+`--preset mixtral-decode | smallthinker-decode | chunk | narrow | all`
+are the calls of the benchmark's two cells (64 decode rows at the
+traffic's contexts, the chunk rows at W = 256, the narrow kernel at 8
+rows), each with the bytes of the keys the rows read, of the pages that
+hold them and of the whole blocks, as us at 819 GB/s; `--rows --heads
+--kv-heads --contexts --window` is one decode round of a configuration
+with window layers, a full layer against a window layer.
 """
 
 from __future__ import annotations
@@ -51,54 +60,147 @@ def _diff_time(make_fn, q0):
     return diff_time_scan(make_fn, (q0,), N1, N2, reps=3)
 
 
+HBM_GBS = 819.0  # one v5e chip (cellbench/peaks.json)
+
+
+def scan_of(body, n):
+    def fn(q0):
+        def f(q, _):
+            return body(q).astype(q.dtype), None
+        return lax.scan(f, q0, None, length=n)[0]
+    return fn
+
+
+def _paged_case(lens_np, w: int, heads: int, kv_heads: int, head_dim: int,
+                window: int):
+    """bf16 pools, queries and a table a row for contexts `lens_np`; with
+    `window`, the entries for pages wholly behind the first query's bound
+    point past the pool, as after the host's hand-back."""
+    ks = jax.random.split(jax.random.key(1), 3)
+    dtype = jnp.bfloat16
+    rows = len(lens_np)
+    mp = -(-int(lens_np.max()) // PS)
+    pages = int(sum(-(-int(n) // PS) for n in lens_np))
+    k_pool = jax.random.normal(ks[0], (1, pages, kv_heads, head_dim, PS),
+                               dtype)
+    v_pool = jax.random.normal(ks[1], (1, pages, kv_heads, head_dim, PS),
+                               dtype)
+    tables = np.full((rows, mp), pages, np.int32)
+    at = 0
+    for i, n in enumerate(lens_np):
+        used = -(-int(n) // PS)
+        first = max(int(n) - w - (window - 1), 0) // PS if window else 0
+        tables[i, first:used] = np.arange(at + first, at + used)
+        at += used
+    q = jax.random.normal(ks[2], (rows, w, heads, head_dim), dtype)
+    return (q, k_pool, v_pool, jnp.asarray(lens_np, jnp.int32),
+            jnp.asarray(tables))
+
+
+def _key_counts(lens_np, w: int, window: int, npb: int):
+    """Keys of one call: those some query reads, those of the pages that
+    hold them, and those of the whole blocks from the first block read to
+    the last (what a kernel fetches that does not bound a block's fetch
+    by the row)."""
+    need = by_page = by_block = 0
+    blk = PS * npb
+    for n in map(int, lens_np):
+        lo = max(n - w - (window - 1), 0) if window else 0
+        need += n - lo
+        by_page += (-(-n // PS) - lo // PS) * PS
+        by_block += (max(-(-n // blk), 1) - min(lo // blk,
+                                                max(-(-n // blk), 1) - 1)) * blk
+    return need, by_page, by_block
+
+
+def time_paged_call(name: str, lens_np, *, w: int, heads: int, kv_heads: int,
+                    head_dim: int, window: int, npbs) -> None:
+    """One `paged_attention` call at a cell's shape: us a call, and beside
+    it the bytes of the keys the rows read, of the pages that hold them
+    and of the whole blocks, each as us at the chip's 819 GB/s."""
+    args = _paged_case(lens_np, w, heads, kv_heads, head_dim, window)
+    per_key = 2 * kv_heads * head_dim * 2  # k and v, bf16
+    for npb in npbs:
+        def make(n, npb=npb):
+            # the pools are operands: as constants of the program they
+            # would be compiled into it, a gigabyte at a cell's shape
+            def fn(q0, k_pool, v_pool, lens, tables):
+                def f(q, _):
+                    o = paged_attention(q, k_pool, v_pool, lens, tables, 0,
+                                        pages_per_block=npb, interpret=False,
+                                        window=window)
+                    return o.astype(q.dtype), None
+                return lax.scan(f, q0, None, length=n)[0]
+            return fn
+        dt = diff_time_scan(make, args, 20, 120, reps=3)
+        counts = _key_counts(lens_np, w, window, npb)
+        cols = " ".join(
+            f"{what}={keys * per_key / 1e6:.1f}MB/"
+            f"{keys * per_key / HBM_GBS / 1e3:.1f}us"
+            for what, keys in zip(("read", "pages", "blocks"), counts))
+        need = counts[0] * per_key
+        print(f"{name:24s} rows={len(lens_np)} w={w} kh={kv_heads} "
+              f"G={heads // kv_heads} window={window} npb={npb} "
+              f"{dt * 1e6:9.1f} us/call {cols} "
+              f"roofline={need / HBM_GBS / 1e3 / (dt * 1e6) * 100:.1f}%",
+              flush=True)
+
+
+def _served_contexts(n: int, seed: int = 0):
+    """Contexts of `n` live rows of the serving cells' traffic
+    (cellbench/workloads): a lognormal prompt (median 512, sigma 0.6,
+    272..1536) and a uniform share of a lognormal answer (median 128,
+    sigma 0.7, 16..512): about 300 to 2,000 keys."""
+    rng = np.random.RandomState(seed)
+    prompt = np.clip(np.exp(np.log(512) + 0.6 * rng.randn(n)), 272, 1536)
+    answer = np.clip(np.exp(np.log(128) + 0.7 * rng.randn(n)), 16, 512)
+    return (prompt + rng.rand(n) * answer).astype(np.int64)
+
+
+def presets(which: str) -> None:
+    """The paged kernel at the shapes the benchmark's two cells call it
+    with (`--preset`): each cell's 64 decode rows, the chunk rows, and the
+    narrow kernel at 8 rows."""
+    mixtral = dict(heads=32, kv_heads=8, head_dim=128)
+    thinker = dict(heads=28, kv_heads=4, head_dim=128)
+    short = _served_contexts(64)
+    long_rows = np.random.RandomState(1).randint(8192, 14337, size=35)
+    mixed = np.concatenate([long_rows, short[:29]])
+    np.random.RandomState(2).shuffle(mixed)
+    sweep = (2, 4, 8)
+    if which in ("mixtral-decode", "all"):
+        time_paged_call("mixtral decode", short, w=1, window=0, npbs=sweep,
+                        **mixtral)
+    if which in ("smallthinker-decode", "all"):
+        time_paged_call("smallthinker window", mixed, w=1, window=4096,
+                        npbs=sweep, **thinker)
+        time_paged_call("smallthinker full", mixed, w=1, window=0,
+                        npbs=sweep, **thinker)
+    if which in ("chunk", "all"):
+        # 8 prompts mid-prefill, their newest chunk of 256 included
+        time_paged_call("mixtral chunk", np.arange(1, 9) * 192 + 64, w=256,
+                        window=0, npbs=(2, 4), **mixtral)
+        deep = np.arange(1, 9) * 1536 + 256
+        time_paged_call("smallthinker chunk window", deep, w=256,
+                        window=4096, npbs=(2, 4), **thinker)
+        time_paged_call("smallthinker chunk full", deep, w=256, window=0,
+                        npbs=(2, 4), **thinker)
+    if which in ("narrow", "all"):
+        for w in (1, 4):
+            time_paged_call("narrow", short[:8] + w, w=w, window=0,
+                            npbs=(8,), **mixtral)
+
+
 def window_against_full(rows: int, heads: int, kv_heads: int, head_dim: int,
                         contexts: list[int], window: int) -> None:
     """The paged kernel at one decode round's shape of a configuration
     with sliding-window layers: `rows` slots whose contexts cycle through
     `contexts`, every key read (a full layer) against the last `window`
-    (a window layer, whose table holds no page behind the bound). Prints
-    us a call and the bytes a second of the keys that had to be read."""
-    ks = jax.random.split(jax.random.key(1), 4)
-    dtype = jnp.bfloat16
+    (a window layer, whose table holds no page behind the bound)."""
     lens_np = np.asarray([contexts[i % len(contexts)] for i in range(rows)])
-    mp = -(-int(lens_np.max()) // PS)
-    full_pages = int(sum(-(-n // PS) for n in lens_np))
-    k_pool = jax.random.normal(ks[0], (1, full_pages, kv_heads, head_dim, PS),
-                               dtype)
-    v_pool = jax.random.normal(ks[1], (1, full_pages, kv_heads, head_dim, PS),
-                               dtype)
-    tables = np.full((rows, mp), full_pages, np.int32)
-    behind = np.full((rows, mp), full_pages, np.int32)  # pages given back
-    at = 0
-    for i, n in enumerate(lens_np):
-        used = -(-int(n) // PS)
-        tables[i, :used] = np.arange(at, at + used)
-        first = max(int(n) - window, 0) // PS
-        behind[i, first:used] = tables[i, first:used]
-        at += used
-    lens = jnp.asarray(lens_np, jnp.int32)
-    q = jax.random.normal(ks[2], (rows, 1, heads, head_dim), dtype)
-
-    def scan_of(body, n):
-        def fn(q0):
-            def f(q, _):
-                return body(q).astype(q.dtype), None
-            return lax.scan(f, q0, None, length=n)[0]
-        return fn
-
-    per_key = 2 * kv_heads * head_dim * 2
-    for name, tab, win in (("full", tables, 0), ("window", behind, window)):
-        keys = int(np.minimum(lens_np, win).sum() if win else lens_np.sum())
-        for npb in (4, 8):
-            def body(q, tab=jnp.asarray(tab), win=win, npb=npb):
-                return paged_attention(q, k_pool, v_pool, lens, tab, 0,
-                                       pages_per_block=npb, interpret=False,
-                                       window=win)
-            dt = diff_time_scan(lambda n: scan_of(body, n), (q,), 20, 120,
-                                reps=3)
-            print(f"paged {name:6s} rows={rows} G={heads // kv_heads} "
-                  f"npb={npb} keys={keys} {dt * 1e6:9.1f} us/call "
-                  f"{keys * per_key / dt / 1e9:7.1f} GB/s of keys", flush=True)
+    for name, win in (("full", 0), ("window", window)):
+        time_paged_call(name, lens_np, w=1, heads=heads, kv_heads=kv_heads,
+                        head_dim=head_dim, window=win, npbs=(4, 8))
 
 
 def main():
@@ -111,7 +213,13 @@ def main():
         ap.add_argument("--head-dim", type=int, default=128)
         ap.add_argument("--contexts", default="8192,10240,12288,14336")
         ap.add_argument("--window", type=int, default=4096)
+        ap.add_argument("--preset", default=None, choices=(
+            "mixtral-decode", "smallthinker-decode", "chunk", "narrow",
+            "all"))
         a = ap.parse_args()
+        if a.preset:
+            presets(a.preset)
+            return
         window_against_full(a.rows, a.heads, a.kv_heads, a.head_dim,
                             [int(c) for c in a.contexts.split(",")], a.window)
         return
@@ -149,13 +257,6 @@ def main():
         results[name] = dt
         print(f"{name:30s} {dt * 1e6:9.1f} us/iter   {gbs:7.1f} GB/s eff",
               flush=True)
-
-    def scan_of(body, n):
-        def fn(q0):
-            def f(q, _):
-                return body(q).astype(q.dtype), None
-            return lax.scan(f, q0, None, length=n)[0]
-        return fn
 
     q1 = jax.random.normal(ks[4], (B, 1, H, D), dtype)
 

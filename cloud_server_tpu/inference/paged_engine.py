@@ -270,10 +270,12 @@ def _write_window(cache: PagedKVCache, layer: int, k, v, pos):
                           k_scale=new["k_scale"], v_scale=new["v_scale"])
 
 
-# Widest window the pallas path serves. Thin windows (<= 32) take the
-# batch-unrolled kernel with its cross-slot DMA chain; wider windows
-# (prefill chunks) dispatch the grid-over-(slot, head) wide kernel
-# (ops.paged_attention._paged_attention_wide) — length-bounded page
+# Widest window the pallas path serves. Thin windows (<= 32) of at most
+# 16 rows take the batch-unrolled kernel; wider windows (prefill chunks)
+# and bigger batches (a serving batch's decode rows) dispatch the
+# grid-over-(slot, head) wide kernel
+# (ops.paged_attention._paged_attention_wide). Both chain their page
+# fetches from one slot or cell to the next — length-bounded page
 # reads instead of the XLA path's full-padded-cache gather per layer
 # per chunk. A wider window is refused, never handed to the XLA
 # reference: a config that asks for the kernel either runs the kernel

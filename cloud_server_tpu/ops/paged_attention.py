@@ -44,7 +44,11 @@ Design (and why it can beat streaming the cache through XLA einsums):
     `cdiv(kv_len, page_size * pages_per_block)` iterations, so pages past
     a slot's length are never fetched — XLA's dense path always streams
     the full padded cache. While one block computes, the next block's
-    pages (possibly the next slot's) are already in flight.
+    pages are already in flight, and at a slot's last block the next
+    slot's (in the grid kernel: the next cell's) first block is: neither
+    kernel starts a slot with an empty pipeline. The grid kernel bounds
+    the fetch page by page as well: of a block it fetches only the pages
+    that hold a key some query of the row reads.
   * Page layout is (num_pages, KH, Dh, page_size) — pages are stored
     TRANSPOSED, positions on the minor (lane) dim. One page holds every
     kv head for `page_size` positions, so a page is ONE contiguous DMA;
@@ -108,6 +112,37 @@ def _first_block(kv_len, width, window: int, blk: int, n_blocks):
         return 0
     lo = jnp.maximum(kv_len - width - (window - 1), 0)
     return jnp.minimum(lax.div(lo, blk), n_blocks - 1)
+
+
+def _softmax_block(qh, mask, state, pages, *, scale, ps, dot_dtype):
+    """One block of keys folded into a (W*G, Dh) query tile's running
+    softmax: `state` is (m, l, acc) in float32, `pages` one tuple a page
+    of callables (k, v, k_scale, v_scale) that read its (Dh, ps) slices
+    and, for an int8 pool, its (1, ps) scales (else None). Both kernels
+    run this body; they differ in where a page's slices lie."""
+    m_prev, l_prev, acc_prev = state
+    cols = []
+    for k_page, _, k_scale, _ in pages:
+        s_p = _dot(qh, k_page().astype(dot_dtype), ((1,), (0,)))  # (WG, ps)
+        if k_scale is not None:
+            s_p = s_p * k_scale()
+        cols.append(s_p)
+    qk = jnp.concatenate(cols, axis=1) * scale  # (WG, blk)
+    qk = jnp.where(mask, qk, NEG_INF)
+
+    m_cur = jnp.max(qk, axis=1, keepdims=True)   # (WG, 1)
+    m_new = jnp.maximum(m_prev, m_cur)
+    p_full = jnp.exp(qk - m_new)                 # (WG, blk)
+    corr = jnp.exp(m_prev - m_new)
+    l_new = l_prev * corr + jnp.sum(p_full, axis=1, keepdims=True)
+    pv = jnp.zeros(acc_prev.shape, jnp.float32)
+    for p, (_, v_page, _, v_scale) in enumerate(pages):
+        p_blk = p_full[:, p * ps:(p + 1) * ps]
+        if v_scale is not None:
+            p_blk = p_blk * v_scale()
+        vp = v_page().astype(dot_dtype)
+        pv = pv + _dot(p_blk.astype(dot_dtype), vp, ((1,), (1,)))  # (WG, Dh)
+    return m_new, l_new, acc_prev * corr + pv
 
 
 # ---------------------------------------------------------------------------
@@ -245,35 +280,18 @@ def _paged_attention_kernel(
 
             new_state = []
             for h in range(kh):
-                m_prev = state[3 * h]
-                l_prev = state[3 * h + 1]
-                acc_prev = state[3 * h + 2]
-                qh = q_ref[b, h].astype(dot_dtype)  # (WG, Dh)
-                cols = []
-                for p in range(npages):
-                    kp = kbuf[buf_idx, p, h].astype(dot_dtype)  # (Dh, ps)
-                    s_p = _dot(qh, kp, ((1,), (0,)))  # (WG, ps)
-                    if int8_kv:
-                        s_p = s_p * ksbuf[buf_idx, p, h].reshape(1, ps)
-                    cols.append(s_p)
-                qk = jnp.concatenate(cols, axis=1) * scale  # (WG, blk)
-                qk = jnp.where(mask, qk, NEG_INF)
-
-                m_cur = jnp.max(qk, axis=1, keepdims=True)   # (WG, 1)
-                m_new = jnp.maximum(m_prev, m_cur)
-                p_full = jnp.exp(qk - m_new)                 # (WG, blk)
-                corr = jnp.exp(m_prev - m_new)
-                l_new = (l_prev * corr
-                         + jnp.sum(p_full, axis=1, keepdims=True))
-                pv = jnp.zeros((wg, d), jnp.float32)
-                for p in range(npages):
-                    p_blk = p_full[:, p * ps:(p + 1) * ps]
-                    if int8_kv:
-                        p_blk = p_blk * vsbuf[buf_idx, p, h].reshape(1, ps)
-                    vp = vbuf[buf_idx, p, h].astype(dot_dtype)  # (Dh, ps)
-                    pv = pv + _dot(p_blk.astype(dot_dtype), vp,
-                                   ((1,), (1,)))  # (WG, Dh)
-                new_state += [m_new, l_new, acc_prev * corr + pv]
+                pages = [
+                    (lambda p=p, h=h: kbuf[buf_idx, p, h],
+                     lambda p=p, h=h: vbuf[buf_idx, p, h],
+                     (lambda p=p, h=h: ksbuf[buf_idx, p, h].reshape(1, ps))
+                     if int8_kv else None,
+                     (lambda p=p, h=h: vsbuf[buf_idx, p, h].reshape(1, ps))
+                     if int8_kv else None)
+                    for p in range(npages)]
+                new_state += _softmax_block(
+                    q_ref[b, h].astype(dot_dtype), mask,
+                    state[3 * h:3 * h + 3], pages, scale=scale, ps=ps,
+                    dot_dtype=dot_dtype)
             return tuple([1 - buf_idx] + new_state)
 
         init = [buf_idx]
@@ -300,6 +318,16 @@ def _paged_attention_kernel(
 # math, per-cell blocks).
 _NARROW_MAX_W = 32
 _NARROW_MAX_B = 16
+# Block buffers of the wide kernel: one computes while the fetches of the
+# three blocks after it are in flight. At a decode call's 4 or 7 query rows
+# a block's compute is shorter than its fetch, and one fetch in flight
+# leaves the copy engines short of work: 2, 4 and 8 buffers measured 443,
+# 391 and 392 us a call at 64 rows of 300 to 2,000 keys (8 kv heads) and
+# 648, 533 and 534 at a window layer's mix (the copies unrolled; PERF.md
+# section 6, PR 44). A
+# power of two: the scalar core pays for a remainder by anything else (6
+# buffers measured slower than 2).
+_WIDE_BUFFERS = 4
 
 
 def paged_attention(q, k_pool, v_pool, lengths, tables, layer=0, *,
@@ -328,7 +356,8 @@ def paged_attention(q, k_pool, v_pool, lengths, tables, layer=0, *,
         window. Slots with length 0 are inactive (their output rows are
         garbage; mask downstream).
       tables: (B, max_pages_per_slot) int32 page table. Entries past a
-        slot's length may be arbitrary (they are clamped and masked).
+        slot's length may be arbitrary: the narrow kernel clamps them
+        and masks what it fetched, the wide kernel does not fetch them.
       layer: int or scalar int32 — pool layer to attend against.
       k_scale_pool, v_scale_pool: (L, num_pages, KH, page_size) f32
         absmax scales when the pools are int8.
@@ -447,14 +476,16 @@ def _paged_attention_wide(qg, k_pool, v_pool, lengths, tables, widths,
                      pl.BlockSpec(memory_space=pl.ANY)]
         inputs += [k_scale_pool, v_scale_pool]
 
+    nbuf = _WIDE_BUFFERS
     scratch = [
-        pltpu.VMEM((2, npages, d, ps), k_pool.dtype),
-        pltpu.VMEM((2, npages, d, ps), v_pool.dtype),
+        pltpu.VMEM((nbuf, npages, d, ps), k_pool.dtype),
+        pltpu.VMEM((nbuf, npages, d, ps), v_pool.dtype),
     ]
     if int8_kv:
-        scratch += [pltpu.VMEM((2, npages, 1, ps), jnp.float32),
-                    pltpu.VMEM((2, npages, 1, ps), jnp.float32)]
-    scratch += [pltpu.SemaphoreType.DMA((2, npages))]
+        scratch += [pltpu.VMEM((nbuf, npages, 1, ps), jnp.float32),
+                    pltpu.VMEM((nbuf, npages, 1, ps), jnp.float32)]
+    scratch += [pltpu.SemaphoreType.DMA((nbuf, npages)),
+                pltpu.SMEM((5,), jnp.int32)]  # the fetch chain's state
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
@@ -472,6 +503,10 @@ def _paged_attention_wide(qg, k_pool, v_pool, lengths, tables, widths,
         out_shape=jax.ShapeDtypeStruct((b, kh, wg, d), qg.dtype),
         interpret=interpret,
         name="paged_attention_wide",
+        # the fetch chain runs from each cell to the next: nothing may
+        # reorder the cells or split them over cores
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
     )(lengths.astype(jnp.int32), tables.astype(jnp.int32),
       widths.astype(jnp.int32),
       jnp.asarray(layer, jnp.int32).reshape(1), *inputs)
@@ -496,27 +531,55 @@ def _paged_attention_wide_kernel(
     int8_kv: bool,
     window: int,
 ):
-    """Wide-window (prefill-chunk) variant: one grid cell per
-    (slot, kv head) instead of a whole-batch unroll.
+    """Grid variant: one cell per (slot, kv head) instead of a
+    whole-batch unroll. It serves the prefill chunks (W > 32) and every
+    call of more than 16 rows, a serving batch's decode rows among them.
 
     Why a second kernel: the narrow kernel keeps all B x KH x W*G query
-    rows in one VMEM block and statically unrolls slots — ideal for thin
-    decode windows (W <= 32), where its cross-slot DMA chain hides every
-    page fetch, but its VMEM footprint and code size scale with B x KH
-    so wide chunks do not fit. Here each cell holds only its own
-    (W*G, Dh) rows and 2 x npages page slices; at W >= page_size the
-    matmuls have real arithmetic intensity, so the per-cell prologue
-    bubble is noise while the length-bounded page reads still beat the
-    XLA path's full-padded-cache gather per layer per chunk.
+    rows in one VMEM block and statically unrolls slots, so its VMEM
+    footprint and code size scale with B x KH: wide chunks and serving
+    batches do not fit. Here each cell holds only its own (W*G, Dh) rows
+    and a few blocks of page slices, and the length-bounded page reads
+    beat the XLA path's full-padded-cache gather per layer per chunk.
+
+    The fetches chain across cells as the narrow kernel's chain across
+    slots. The cells run in grid order on the one core (both axes
+    "arbitrary"), so the blocks of a call are one sequence: a cell's
+    blocks, then those of the cell after it, (b, h + 1) or (b + 1, 0).
+    While a block computes, the fetches of the `ahead` blocks after it
+    in that sequence are in flight (one buffer each), whichever cells
+    they belong to: each block starts the fetch of the block `ahead`
+    after it (`advance`), and only the grid's first cell fetches for
+    itself and waits with nothing to compute. At a chunk's width the
+    bubble this closes is noise (a cell has dozens of blocks of real
+    arithmetic intensity); at W = 1 a cell is one or a few blocks of 4
+    or 7 query rows, and the bubble was the kernel. Cells differ in
+    their count of blocks, so the buffer a cell starts in and the newest
+    block in flight are carried from cell to cell in SMEM scratch
+    (`chain`).
+
+    A block's fetch is bounded by the row: a page wholly past the row's
+    last key, or wholly behind the first query's lower bound, is neither
+    started nor waited for (`_page_span`, one span for both, so every
+    started copy keeps exactly one wait), and its table entry may be
+    anything. What such a page's buffer holds is stale. The scores are
+    selected by the mask, so stale keys never reach them; the weights
+    there are exactly 0, and 0 x NaN would still poison `pv`: the grid's
+    first cell therefore zeroes the value buffers (and the value scales)
+    once, after which they only ever hold zeros or pages some row named
+    inside its bound.
     """
     if int8_kv:
         (ks_pool_ref, vs_pool_ref, o_ref,
-         kbuf, vbuf, ksbuf, vsbuf, sems) = refs
+         kbuf, vbuf, ksbuf, vsbuf, sems, chain) = refs
     else:
-        o_ref, kbuf, vbuf, sems = refs
+        o_ref, kbuf, vbuf, sems, chain = refs
         ks_pool_ref = vs_pool_ref = ksbuf = vsbuf = None
+    nbuf = kbuf.shape[0]  # a power of two: a slot is a counter's low bits
+    ahead = nbuf - 1  # blocks in flight while one computes
     b = pl.program_id(0)
     h = pl.program_id(1)
+    nb, nh = pl.num_programs(0), pl.num_programs(1)
     wg = q_ref.shape[2]
     d = q_ref.shape[-1]
     num_pages_total = k_pool_ref.shape[1]
@@ -525,95 +588,150 @@ def _paged_attention_wide_kernel(
     kv_len = lens_ref[b]
     dot_dtype = (jnp.float32 if k_pool_ref.dtype == jnp.float32
                  else jnp.bfloat16)
-    n_blocks = jnp.maximum(1, lax.div(kv_len + blk - 1, blk))
-    first = _first_block(kv_len, widths_ref[b], window, blk, n_blocks)
 
-    def _copies(buf_idx, page_ids):
-        """One block's async copies — PER-HEAD (Dh, ps) slices here (the
-        narrow kernel fetches whole pages; a cell only needs its head)."""
-        out = []
-        for i in range(npages):
-            page = page_ids[i]
-            sem = sems.at[buf_idx, i]
-            out.append(pltpu.make_async_copy(
-                k_pool_ref.at[layer, page, h], kbuf.at[buf_idx, i], sem))
-            out.append(pltpu.make_async_copy(
-                v_pool_ref.at[layer, page, h], vbuf.at[buf_idx, i], sem))
-            if int8_kv:
-                # pl.ds keeps the copy rank-2 ((1, ps), lane-aligned)
-                out.append(pltpu.make_async_copy(
-                    ks_pool_ref.at[layer, page, pl.ds(h, 1)],
-                    ksbuf.at[buf_idx, i], sem))
-                out.append(pltpu.make_async_copy(
-                    vs_pool_ref.at[layer, page, pl.ds(h, 1)],
-                    vsbuf.at[buf_idx, i], sem))
-        return out
+    def blocks_of(row):
+        """(first, end) of the blocks a row's cells run; end - first >= 1
+        so that every cell has a block to be fetched and waited for."""
+        end = jnp.maximum(1, lax.div(lens_ref[row] + blk - 1, blk))
+        return _first_block(lens_ref[row], widths_ref[row], window, blk,
+                            end), end
 
-    def _block_pages(blk_idx):
-        return [
-            jnp.clip(
-                tables_ref[b, jnp.clip(blk_idx * npages + i, 0,
-                                       tables_ref.shape[1] - 1)],
+    def _page_span(row, blk_idx):
+        """[lo, hi) of the block's pages that hold a key some query of
+        the row reads: not those wholly past the last key, nor those
+        wholly behind the first query's lower bound."""
+        n = lens_ref[row]
+        behind = (lax.div(jnp.maximum(n - widths_ref[row] - (window - 1), 0),
+                          ps) if window else 0)
+        base = blk_idx * npages
+        lo = jnp.clip(behind - base, 0, npages)
+        return lo, jnp.clip(lax.div(n + ps - 1, ps) - base, lo, npages)
+
+    def _copies(buf_idx, i, head, page):
+        """The async copies of one page of a block — PER-HEAD (Dh, ps)
+        slices here (the narrow kernel fetches whole pages; a cell only
+        needs its head)."""
+        sem = sems.at[buf_idx, i]
+        cs = [pltpu.make_async_copy(
+                  k_pool_ref.at[layer, page, head], kbuf.at[buf_idx, i], sem),
+              pltpu.make_async_copy(
+                  v_pool_ref.at[layer, page, head], vbuf.at[buf_idx, i], sem)]
+        if int8_kv:
+            # pl.ds keeps the copy rank-2 ((1, ps), lane-aligned)
+            cs += [pltpu.make_async_copy(
+                       ks_pool_ref.at[layer, page, pl.ds(head, 1)],
+                       ksbuf.at[buf_idx, i], sem),
+                   pltpu.make_async_copy(
+                       vs_pool_ref.at[layer, page, pl.ds(head, 1)],
+                       vsbuf.at[buf_idx, i], sem)]
+        return cs
+
+    # a loop over the span and not a branch a page: the program is traced
+    # and lowered for every step program of a server, and eight branches
+    # a site cost more of that than the whole kernel had
+
+    def start_fetch(row, head, blk_idx, buf_idx):
+        def one(i, _):
+            page = jnp.clip(
+                tables_ref[row, jnp.minimum(blk_idx * npages + i,
+                                            tables_ref.shape[1] - 1)],
                 0, num_pages_total - 1)
-            for i in range(npages)
-        ]
+            for c in _copies(buf_idx, i, head, page):
+                c.start()
+            return 0
+        lax.fori_loop(*_page_span(row, blk_idx), one, 0)
 
-    def start_fetch(blk_idx, buf_idx):
-        for c in _copies(buf_idx, _block_pages(blk_idx)):
-            c.start()
+    def wait_fetch(blk_idx, buf_idx):
+        # waits pair up 1:1 with the starts issued into this buffer for
+        # this cell's block, by this cell or by one before it (the source
+        # index is irrelevant to wait; sizes match the starts)
+        def one(i, _):
+            for c in _copies(buf_idx, i, h, 0):
+                c.wait()
+            return 0
+        lax.fori_loop(*_page_span(b, blk_idx), one, 0)
 
-    def wait_fetch(buf_idx):
-        for c in _copies(buf_idx, [0] * npages):
-            c.wait()
+    def advance(pos):
+        """The block the grid runs after `pos` = (row, head, block, live):
+        the cell's next block, or the first block of the cell after it,
+        (row, head + 1) or (row + 1, 0); not `live` past the last cell."""
+        row, head, blk_idx, live = pos
+        more = blk_idx + 1 < blocks_of(row)[1]
+        last_head = head + 1 == nh
+        nxt_row = jnp.where(jnp.logical_or(more, jnp.logical_not(last_head)),
+                            row, row + 1)
+        live = jnp.logical_and(live, nxt_row < nb)
+        nxt_row = jnp.minimum(nxt_row, nb - 1)
+        return (nxt_row,
+                jnp.where(more, head, jnp.where(last_head, 0, head + 1)),
+                jnp.where(more, blk_idx + 1, blocks_of(nxt_row)[0]), live)
 
-    start_fetch(first, 0)
+    def start_at(pos, buf_idx):
+        @pl.when(pos[3])
+        def _():
+            start_fetch(pos[0], pos[1], pos[2], buf_idx)
+
+    first, n_blocks = blocks_of(b)
+
+    @pl.when(jnp.logical_and(b == 0, h == 0))
+    def _():
+        vbuf[...] = jnp.zeros(vbuf.shape, vbuf.dtype)
+        if int8_kv:
+            vsbuf[...] = jnp.zeros(vsbuf.shape, vsbuf.dtype)
+        # the grid's first `ahead` blocks are fetched here; every later
+        # one by the block that runs `ahead` before it
+        pos = (b, h, first, jnp.bool_(True))
+        start_at(pos, 0)
+
+        def fill(k, pos):
+            pos = advance(pos)
+            start_at(pos, k)
+            return pos
+        pos = lax.fori_loop(1, ahead, fill, pos)
+        chain[0] = 0
+        for k in range(4):
+            chain[1 + k] = jnp.asarray(pos[k], jnp.int32)
+
+    # the buffer this cell's first block is in, and the newest block in
+    # flight: both run on from cell to cell
+    slot0 = chain[0]
+    newest0 = (chain[1], chain[2], chain[3], chain[4] != 0)
     row_pos = (kv_len - widths_ref[b]) + lax.broadcasted_iota(
         jnp.int32, (wg, blk), 0) // g
     qh = q_ref[0, 0].astype(dot_dtype)  # (WG, Dh)
 
     def body(i, carry):
-        m_prev, l_prev, acc_prev = carry
-        buf_idx = lax.rem(i - first, 2) if window else lax.rem(i, 2)
+        newest, state = carry[:4], carry[4:]
+        buf_idx = (slot0 + (i - first)) & (nbuf - 1)
 
-        @pl.when(i + 1 < n_blocks)
-        def _():
-            start_fetch(i + 1, 1 - buf_idx)
+        # while this block computes, the `ahead` blocks the grid runs
+        # after it are in flight: start the last of them, this cell's or
+        # a later cell's, into the buffer the block before this one left
+        newest = advance(newest)
+        start_at(newest, (buf_idx + ahead) & (nbuf - 1))
 
-        wait_fetch(buf_idx)
+        wait_fetch(i, buf_idx)
 
         col_pos = i * blk + lax.broadcasted_iota(jnp.int32, (wg, blk), 1)
         mask = jnp.logical_and(col_pos <= row_pos, col_pos < kv_len)
         if window:
             mask = jnp.logical_and(mask, row_pos - col_pos < window)
-
-        cols = []
-        for p in range(npages):
-            kp = kbuf[buf_idx, p].astype(dot_dtype)  # (Dh, ps)
-            s_p = _dot(qh, kp, ((1,), (0,)))         # (WG, ps)
-            if int8_kv:
-                s_p = s_p * ksbuf[buf_idx, p]        # (1, ps) broadcast
-            cols.append(s_p)
-        qk = jnp.concatenate(cols, axis=1) * scale   # (WG, blk)
-        qk = jnp.where(mask, qk, NEG_INF)
-
-        m_cur = jnp.max(qk, axis=1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        p_full = jnp.exp(qk - m_new)
-        corr = jnp.exp(m_prev - m_new)
-        l_new = l_prev * corr + jnp.sum(p_full, axis=1, keepdims=True)
-        pv = jnp.zeros((wg, d), jnp.float32)
-        for p in range(npages):
-            p_blk = p_full[:, p * ps:(p + 1) * ps]
-            if int8_kv:
-                p_blk = p_blk * vsbuf[buf_idx, p]    # (1, ps) broadcast
-            vp = vbuf[buf_idx, p].astype(dot_dtype)
-            pv = pv + _dot(p_blk.astype(dot_dtype), vp, ((1,), (1,)))
-        return m_new, l_new, acc_prev * corr + pv
+        pages = [
+            (lambda p=p: kbuf[buf_idx, p], lambda p=p: vbuf[buf_idx, p],
+             (lambda p=p: ksbuf[buf_idx, p]) if int8_kv else None,
+             (lambda p=p: vsbuf[buf_idx, p]) if int8_kv else None)
+            for p in range(npages)]
+        return newest + _softmax_block(qh, mask, state, pages, scale=scale,
+                                       ps=ps, dot_dtype=dot_dtype)
 
     m0 = jnp.full((wg, 1), NEG_INF, jnp.float32)
     l0 = jnp.zeros((wg, 1), jnp.float32)
     a0 = jnp.zeros((wg, d), jnp.float32)
-    _, l_f, acc_f = lax.fori_loop(first, n_blocks, body, (m0, l0, a0))
+    out = lax.fori_loop(first, n_blocks, body, newest0 + (m0, l0, a0))
+    chain[0] = (slot0 + (n_blocks - first)) & (nbuf - 1)
+    for k in range(4):
+        chain[1 + k] = out[k].astype(jnp.int32)
+    l_f, acc_f = out[5:]
     o_ref[0, 0] = (acc_f / jnp.maximum(l_f, 1e-30)).astype(o_ref.dtype)
 
 

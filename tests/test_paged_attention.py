@@ -1,6 +1,7 @@
 """Paged attention: kernel vs gather-reference vs dense causal_attention."""
 
 import functools
+import hashlib
 
 import jax
 import jax.numpy as jnp
@@ -11,6 +12,16 @@ from cloud_server_tpu.inference.paged_engine import quantize_pool
 from cloud_server_tpu.ops.attention import causal_attention
 from cloud_server_tpu.ops.paged_attention import (
     gather_pages, paged_attention, paged_attention_xla)
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _drop_compiled_programs():
+    """Every interpreted kernel case compiles a CPU program of its own,
+    and a test process dies inside XLA's compile once it holds 65,530
+    memory mappings (run_tests.sh): give this file's back when it ends,
+    for the files the same worker runs after it."""
+    yield
+    jax.clear_caches()
 
 
 def _make_case(rng, *, b=3, w=1, h=4, kh=2, d=16, ps=8, mp=6, L=2,
@@ -115,6 +126,170 @@ def test_wide_kernel_int8():
                           pages_per_block=2, interpret=True,
                           k_scale_pool=ksc, v_scale_pool=vsc)
     np.testing.assert_allclose(got, want, atol=5e-3, rtol=5e-3)
+
+
+def _ragged_cells(b, blk, mp_keys):
+    """Lengths of `b` decode rows whose cells of the wide kernel's grid
+    run 1, 2 and 5 blocks of `blk` keys one after the other, with rows of
+    length 0 between live rows and as the first and the last row."""
+    pattern = [0, blk - 3, 2 * blk - 1, 4 * blk + 1, 5, 0, blk, blk + 1,
+               5 * blk, 1, 2 * blk]
+    lens = [min(pattern[i % len(pattern)], mp_keys) for i in range(b)]
+    lens[-1] = 0
+    return lens
+
+
+def _decode_case(rng, *, g, pages_per_block, b=64, kh=2, d=16, ps=8):
+    """The serving batch's decode call at toy widths: `b` rows of one
+    query, `g` query heads a key head, a table of five blocks a row."""
+    blk = ps * pages_per_block
+    mp = 5 * pages_per_block
+    q, k_pool, v_pool, _, tables = _make_case(
+        rng, b=b, w=1, h=g * kh, kh=kh, d=d, ps=ps, mp=mp,
+        num_pages=b * mp + 7)
+    lengths = jnp.asarray(_ragged_cells(b, blk, mp * ps), jnp.int32)
+    return q, k_pool, v_pool, lengths, tables
+
+
+@pytest.mark.parametrize("g", [4, 7])
+@pytest.mark.parametrize("pages_per_block", [2, 4, 8])
+def test_wide_kernel_decode_rows_chain_across_cells(g, pages_per_block):
+    """W = 1 and 64 rows take the wide kernel: every cell's first block is
+    fetched by the cell before it, through cells of 1, 2 and 5 blocks and
+    rows that hold nothing (first, last and between live rows)."""
+    q, k_pool, v_pool, lengths, tables = _decode_case(
+        jax.random.key(20 + g), g=g, pages_per_block=pages_per_block)
+    got = paged_attention(q, k_pool, v_pool, lengths, tables, 1,
+                          pages_per_block=pages_per_block, interpret=True)
+    want = _dense_ref(q, k_pool, v_pool, lengths, tables, 1)
+    live = np.asarray(lengths) > 0
+    assert live[1] and not live[0] and not live[-1] and not live[5]
+    np.testing.assert_allclose(np.asarray(got)[live], np.asarray(want)[live],
+                               atol=2e-4, rtol=2e-4)
+    assert bool(jnp.isfinite(got).all())
+
+
+def test_wide_kernel_decode_rows_int8():
+    q, k_pool, v_pool, lengths, tables = _decode_case(
+        jax.random.key(30), g=4, pages_per_block=4)
+    kq, ksc = quantize_pool(k_pool)
+    vq, vsc = quantize_pool(v_pool)
+    k_deq = (kq.astype(jnp.float32) * ksc[:, :, :, None, :])
+    v_deq = (vq.astype(jnp.float32) * vsc[:, :, :, None, :])
+    want = _dense_ref(q, k_deq, v_deq, lengths, tables, 0)
+    got = paged_attention(q, kq, vq, lengths, tables, 0, pages_per_block=4,
+                          interpret=True, k_scale_pool=ksc, v_scale_pool=vsc)
+    live = np.asarray(lengths) > 0
+    np.testing.assert_allclose(np.asarray(got)[live], np.asarray(want)[live],
+                               atol=5e-3, rtol=5e-3)
+    assert bool(jnp.isfinite(got).all())
+
+
+def test_wide_kernel_ragged_chunks_of_unequal_blocks():
+    """Chunks 48 wide with ragged widths, their cells 1 to 8 blocks long
+    and one row empty: the chain's parity follows the blocks run."""
+    w, b = 48, 6
+    q, k_pool, v_pool, _, tables = _make_case(
+        jax.random.key(31), b=b, w=w, mp=16, num_pages=b * 16 + 3)
+    widths = jnp.asarray([48, 1, 13, 0, 40, 7], jnp.int32)
+    base = jnp.asarray([80, 3, 0, 0, 20, 55], jnp.int32)
+    lengths = base + widths
+    got = paged_attention(q, k_pool, v_pool, lengths, tables, 0,
+                          pages_per_block=2, interpret=True, widths=widths)
+    want = paged_attention_xla(q, k_pool, v_pool, lengths, tables, 0,
+                               widths=widths)
+    for i, wi in enumerate(np.asarray(widths)):
+        np.testing.assert_allclose(got[i, :wi], want[i, :wi], atol=2e-4,
+                                   rtol=2e-4)
+    assert bool(jnp.isfinite(got).all())
+
+
+def _poisoned(k_pool, v_pool, tables, lengths, poison, *, ps, behind=None):
+    """The pools with every page that no row's table names inside the
+    row's bound filled with `poison`, and every table entry outside the
+    bound (past the row's last key; with `behind`, also the pages wholly
+    behind row i's lower bound behind[i]) pointing at such a page."""
+    tab = np.asarray(tables).copy()
+    used = np.zeros(k_pool.shape[1], bool)
+    for i, n in enumerate(np.asarray(lengths)):
+        first = 0 if behind is None else int(behind[i]) // ps
+        last = -(-int(n) // ps)
+        used[tab[i, first:last]] = True
+        tab[i, :first] = tab[i, last:] = k_pool.shape[1]
+    bad = jnp.asarray(~used)[None, :, None, None, None]
+
+    def spoil(pool):
+        return jnp.concatenate([jnp.where(bad, poison, pool),
+                                jnp.full_like(pool[:, :1], poison)], axis=1)
+
+    return spoil(k_pool), spoil(v_pool), jnp.asarray(tab)
+
+
+@pytest.mark.parametrize("poison", [np.nan, np.inf], ids=["nan", "inf"])
+@pytest.mark.parametrize("shape", ["decode", "chunks"])
+def test_wide_kernel_poisoned_pool(shape, poison):
+    """No byte of a page outside a row's bound reaches an output: with
+    every such page NaN or inf, and scratch that starts as NaN (the
+    interpreter's uninitialised memory), the outputs are finite and the
+    clean pool's bit for bit. A page that is not fetched leaves its stale
+    buffer to weights of exactly 0, and 0 x NaN is NaN: the kernel's
+    first cell zeroes the value buffers."""
+    if shape == "decode":
+        q, k_pool, v_pool, lengths, tables = _decode_case(
+            jax.random.key(40), g=7, pages_per_block=4)
+        kw = dict(pages_per_block=4)
+    else:
+        q, k_pool, v_pool, _, tables = _make_case(
+            jax.random.key(41), b=5, w=48, mp=16, num_pages=5 * 16 + 3)
+        widths = jnp.asarray([48, 1, 13, 0, 40], jnp.int32)
+        lengths = jnp.asarray([80, 3, 0, 0, 20], jnp.int32) + widths
+        kw = dict(pages_per_block=2, widths=widths)
+    clean = paged_attention(q, k_pool, v_pool, lengths, tables, 0,
+                            interpret=True, **kw)
+    kp, vp, tab = _poisoned(k_pool, v_pool, tables, lengths, poison, ps=8)
+    got = paged_attention(q, kp, vp, lengths, tab, 0, interpret=True, **kw)
+    assert np.isfinite(np.asarray(got)).all()
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(clean))
+
+
+# sha256 of the narrow kernel's jaxpr at b = 8, w = 4 as commit ecc80bc
+# (PR 41, before the wide kernel's fetch chain) traced it: the narrow
+# kernel's callers lower to what they lowered to
+_NARROW_JAXPR = {
+    ("float32", False):
+        "2129358b1becf1a0e6591b4aaa8e485e7058b35715415fb867520b1a3836846e",
+    ("float32", True):
+        "54d9c3b59c463df4b6938adcd4b5aa23b306cea2d29cf55ec7cdfef2aab2b681",
+    ("bfloat16", False):
+        "ad364585fe7566ce03367d1ca58af9b013f6d2f24f5554e8ed1400c145df7944",
+    ("bfloat16", True):
+        "4525e3bc9046aa8be0616e2966d8d755179cacc4930f82c24cd89820efb7836f",
+}
+
+
+@pytest.mark.parametrize("dtype,int8", sorted(_NARROW_JAXPR))
+def test_narrow_kernel_traces_to_what_it_did(dtype, int8):
+    """The narrow kernel shares its block body with the wide one; its
+    jaxpr (ragged widths, a window, two pages a block) is letter for
+    letter the one it had before they shared it."""
+    b, w, h, kh, d, ps, mp, pages = 8, 4, 8, 2, 16, 8, 6, 48
+    S = jax.ShapeDtypeStruct
+    pool = S((2, pages, kh, d, ps), jnp.int8 if int8 else dtype)
+    args = [S((b, w, h, d), dtype), pool, pool, S((b,), jnp.int32),
+            S((b, mp), jnp.int32), S((), jnp.int32), S((b,), jnp.int32)]
+    if int8:
+        args += [S((2, pages, kh, ps), jnp.float32)] * 2
+
+    def f(q, k, v, lens, tabs, layer, widths, *scales):
+        kw = (dict(k_scale_pool=scales[0], v_scale_pool=scales[1])
+              if scales else {})
+        return paged_attention(q, k, v, lens, tabs, layer, pages_per_block=2,
+                               interpret=True, widths=widths, window=20, **kw)
+
+    text = str(jax.make_jaxpr(f)(*args))
+    assert "paged_attention_narrow" in text
+    assert hashlib.sha256(text.encode()).hexdigest() == \
+        _NARROW_JAXPR[dtype, int8]
 
 
 @pytest.mark.parametrize("impl", ["xla", "kernel"])
@@ -308,3 +483,31 @@ def test_compiled_on_tpu_gqa_int8_kv():
             k_scale_pool=ksc, v_scale_pool=vsc)
     _check_ragged(got, q, kq, vq, lengths, tables, widths, 1, 5e-2,
                   k_scale_pool=ksc, v_scale_pool=vsc)
+
+
+@pytest.mark.on_tpu
+@pytest.mark.parametrize("pages_per_block", [2, 8])
+def test_compiled_on_tpu_wide_decode_rows_poisoned(pages_per_block):
+    """The serving batch's decode call compiled (20 rows take the wide
+    kernel): cells of 1 to 4 blocks and empty rows chained on the chip,
+    right against the reference and untouched by NaN in every page
+    outside a row's bound."""
+    b = 20
+    q, k_pool, v_pool, tables = _gqa_case(jax.random.key(14), b=b, w=1)
+    lengths = jnp.asarray([0, 1, 128, 129, 700, 1024, 0, 300, 513, 1000,
+                           5, 0, 0, 257, 1023, 640, 64, 900, 384, 0],
+                          jnp.int32)
+    fn = jax.jit(functools.partial(
+        paged_attention, pages_per_block=pages_per_block, interpret=False))
+    clean = fn(q, k_pool, v_pool, lengths, tables, 1)
+    kp, vp, tab = _poisoned(k_pool, v_pool, tables, lengths, np.nan, ps=128)
+    got = fn(q, kp, vp, lengths, tab, 1)
+    assert np.isfinite(np.asarray(got, np.float32)).all()
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(clean, np.float32))
+    live = np.asarray(lengths) > 0
+    want = paged_attention_xla(
+        q.astype(jnp.float32), k_pool.astype(jnp.float32),
+        v_pool.astype(jnp.float32), lengths, tables, 1)
+    np.testing.assert_allclose(np.asarray(got, np.float32)[live],
+                               np.asarray(want)[live], atol=2e-2, rtol=2e-2)

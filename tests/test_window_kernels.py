@@ -9,6 +9,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from test_paged_attention import (  # noqa: F401
+    _decode_case, _drop_compiled_programs, _poisoned)
 from window_model import (  # noqa: F401
     CHUNK, LOGITS_ATOL, LOGPROB_ATOL, PAGE, WINDOW, assert_pages_balance,
     make_model, make_server, ref_logits, serve_all, tokens_of,
@@ -103,3 +105,52 @@ def test_a_chunk_as_wide_as_the_serving_chunk(model):
         np.testing.assert_allclose(got[0], want[0], atol=2e-4, rtol=2e-4)
         np.testing.assert_allclose(got[1, :44], want[1, :44], atol=2e-4,
                                    rtol=2e-4)
+
+
+@pytest.mark.parametrize("g", [4, 7])
+@pytest.mark.parametrize("pages_per_block", [2, 4, 8])
+def test_a_serving_batch_of_decode_rows_with_a_lower_bound(
+        g, pages_per_block):
+    """64 decode rows (the wide kernel), cells of 1, 2 and 5 blocks and
+    empty rows between them; the bound puts the long rows' first block
+    past block 0 and falls inside a block and inside a page."""
+    q, kp, vp, lens, tables = _decode_case(
+        jax.random.key(50 + g), g=g, pages_per_block=pages_per_block)
+    window = 2 * 8 * pages_per_block + 5
+    want = paged_attention_xla(q, kp, vp, lens, tables, 1, window=window)
+    got = paged_attention(q, kp, vp, lens, tables, 1,
+                          pages_per_block=pages_per_block, interpret=True,
+                          window=window)
+    live = np.asarray(lens) > 0
+    np.testing.assert_allclose(np.asarray(got)[live], np.asarray(want)[live],
+                               atol=2e-4, rtol=2e-4)
+    free = paged_attention_xla(q, kp, vp, lens, tables, 1)
+    assert np.abs(np.asarray(free) - np.asarray(want))[live].max() > 1e-3
+    assert np.isfinite(np.asarray(got)).all()
+
+
+@pytest.mark.parametrize("poison", [np.nan, np.inf], ids=["nan", "inf"])
+@pytest.mark.parametrize("shape", ["decode", "chunks"])
+def test_no_page_outside_a_rows_bounds_reaches_an_output(shape, poison):
+    """Every page wholly behind a row's lower bound or past its last key,
+    and every page no table names, is NaN or inf, page by page and not
+    block by block: the outputs are finite and the clean pool's, bit for
+    bit."""
+    if shape == "decode":
+        q, kp, vp, lens, tables = _decode_case(
+            jax.random.key(60), g=7, pages_per_block=4)
+        wid = jnp.ones_like(lens)
+        window, kw = 2 * 32 + 5, dict(pages_per_block=4)
+    else:
+        q, kp, vp, lens, tables = kernel_case(3, 48, [96, 50, 77], seed=61)
+        wid = jnp.asarray([48, 13, 40], jnp.int32)
+        window, kw = 20, dict(pages_per_block=2, widths=wid)
+    clean = paged_attention(q, kp, vp, lens, tables, 0, interpret=True,
+                            window=window, **kw)
+    behind = np.maximum(np.asarray(lens) - np.asarray(wid) - (window - 1), 0)
+    kp, vp, tab = _poisoned(kp, vp, tables, lens, poison, ps=8,
+                            behind=behind)
+    got = paged_attention(q, kp, vp, lens, tab, 0, interpret=True,
+                          window=window, **kw)
+    assert np.isfinite(np.asarray(got)).all()
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(clean))
