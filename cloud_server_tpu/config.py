@@ -83,6 +83,35 @@ class ModelConfig:
     sliding_window: int = 0
     window_layout: tuple = ()
     rope_layout: tuple = ()
+    # Latent attention (MLA), `kv_lora_rank` > 0: queries through a
+    # low-rank bottleneck of `q_lora_rank`, keys and values expanded from
+    # one latent vector of `kv_lora_rank` a token beside one rotary key of
+    # `qk_rope_head_dim` that every head shares. `head_dim` is then the
+    # query/key width (the position-free part and the rotary part) and
+    # `v_head_dim` the value width, apart. The paged cache holds the latent
+    # vector and the rotary key, `latent_dim` values a token an attention
+    # block, and is read in the absorbed form (models/latent.py).
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # The layer's body: "single" (attention, then the MLP or the experts)
+    # | "double_shortcut": two attention blocks and two dense MLPs of
+    # `mlp_dim` in one layer, the experts reading the first half's normed
+    # stream and added after the second half (models/latent.py).
+    layer_body: str = "single"
+    # One chip's share of a wider router. `num_experts` routed experts are
+    # held (the first of `num_routed_experts`, the router's columns for
+    # experts that have weights; 0 = every expert is held), behind them
+    # `num_zero_experts` columns for experts that compute nothing and add
+    # gate * token. `routed_scaling_factor` > 0: the choice is the top k of
+    # probability + bias, the gate probability * factor, not renormalised
+    # (0: the renormalised top-k gates). `expert_mlp_dim`: an expert's
+    # width where the layer also has a dense MLP of `mlp_dim` (0 = mlp_dim).
+    num_routed_experts: int = 0
+    num_zero_experts: int = 0
+    routed_scaling_factor: float = 0.0
+    expert_mlp_dim: int = 0
     # rematerialisation policy for the layer scan:
     # "none" | "full" | "dots" | "attn" (save only flash-attention residuals)
     remat: str = "full"
@@ -124,6 +153,25 @@ class ModelConfig:
         if any(self.window_layout) and self.sliding_window <= 0:
             raise ValueError("window_layout names window layers but "
                              "sliding_window is not set")
+        if self.layer_body not in ("single", "double_shortcut"):
+            raise ValueError(f"unknown layer_body: {self.layer_body!r}")
+        if (self.layer_body == "double_shortcut") != (self.kv_lora_rank > 0):
+            raise ValueError(
+                "latent attention (kv_lora_rank) and the double layer "
+                "(layer_body='double_shortcut') come together: no program "
+                "serves one without the other")
+        if self.kv_lora_rank and (self.has_window_layers
+                                  or self.kv_cache_dtype != "model"):
+            raise ValueError(
+                "a latent cache holds one kind of page in the model's "
+                "dtype: no window layers, no int8 cache")
+        if (self.num_routed_experts or self.num_zero_experts) and not (
+                self.routed_scaling_factor > 0
+                and self.num_routed_experts >= self.num_experts >= 2):
+            raise ValueError(
+                "a router wider than the experts held needs "
+                "routed_scaling_factor > 0 and 2 <= num_experts <= "
+                "num_routed_experts")
         if self.num_heads % max(self.num_kv_heads, 1) != 0:
             raise ValueError(
                 f"num_heads={self.num_heads} must be a multiple of "
@@ -169,6 +217,29 @@ class ModelConfig:
         """Layers differ: the scans carry per-layer flags."""
         return self.has_window_layers or not all(
             self.layer_rope(i) for i in range(self.num_layers))
+
+    @property
+    def latent_dim(self) -> int:
+        """Values a token an attention block in a latent cache: the latent
+        vector and the shared rotary key; 0 without latent attention."""
+        return (self.kv_lora_rank + self.qk_rope_head_dim
+                if self.kv_lora_rank else 0)
+
+    @property
+    def attention_blocks(self) -> int:
+        """Attention blocks, each with cache layers of its own, a layer."""
+        return 2 if self.layer_body == "double_shortcut" else 1
+
+    @property
+    def router_width(self) -> int:
+        """The router's columns: the routed experts as published (held or
+        not), then the experts that compute nothing."""
+        return ((self.num_routed_experts or self.num_experts)
+                + self.num_zero_experts)
+
+    @property
+    def expert_width(self) -> int:
+        return self.expert_mlp_dim or self.mlp_dim
 
     def layer_pool(self, layer: int) -> tuple:
         """(kind, index of `layer` among the layers of its kind)."""
