@@ -62,11 +62,12 @@ import jax.numpy as jnp
 F32 = jnp.float32
 
 # the CPU rehearsal: 4 heads whose key and value widths differ, both
-# low-rank widths under the hidden size, 4 experts held of 8 routed and 4
-# that compute nothing (the router 12 wide), 3 a token, two double layers
-TINY = {"hidden_size": 64, "ffn_hidden_size": 128,
+# low-rank widths in the published ratios to the hidden size (a 4th and a
+# 12th), 4 experts held of 8 routed and 4 that compute nothing (the router
+# 12 wide), 3 a token, two double layers
+TINY = {"hidden_size": 96, "ffn_hidden_size": 128,
         "expert_ffn_hidden_size": 32, "num_attention_heads": 4,
-        "q_lora_rank": 32, "kv_lora_rank": 16, "qk_nope_head_dim": 16,
+        "q_lora_rank": 24, "kv_lora_rank": 8, "qk_nope_head_dim": 16,
         "qk_rope_head_dim": 8, "v_head_dim": 16, "vocab_size": 512,
         "num_layers": 2, "n_routed_experts": 4, "zero_expert_num": 4,
         "moe_topk": 3,
@@ -80,14 +81,32 @@ TINY = {"hidden_size": 64, "ffn_hidden_size": 128,
 _Q_BLOCK = 256
 
 # the router's weights are drawn this many times wider than 1/sqrt(fan-in)
-# (`fan_in` states 1/16 of its inputs). With 1/sqrt(6144) weights the 768
-# logits have unit spread, the largest probability is about 0.02 and every
-# gate about 0.05 to 0.1: the experts' and the identity terms would be a
-# twentieth of the dense halves' and no check could see them. A trained
-# router is far from flat; at four times the spread the largest probability
-# is about 0.15, the twelve gates sum to 3 to 4 and the identity term is as
-# large as the stream it is added to.
-_ROUTER_SHARPER = 4
+# (`fan_in` states a quarter of its inputs). With 1/sqrt(6144) weights the
+# 768 logits have unit spread, the largest probability is about 0.02 and
+# every gate about 0.05 to 0.1: the held experts' terms would be a
+# hundredth of the dense halves' and no check could see them. A trained
+# router is far from flat. At twice the spread the largest probability is
+# about 0.1, the twelve gates sum to about 2 and the identity term is most
+# of the stream it is added to. At four times the spread (tried first, my
+# chip run, PR 45) a gate follows its logit's rounding: probabilities move
+# by a tenth under bfloat16 and the sound median read 0.20.
+_ROUTER_SHARPER = 2
+
+# `mla_scale_q_lora` and `mla_scale_kv_lora` give each latent vector the
+# norm of a hidden-wide vector (sqrt(hidden / rank) after its norm: 2 and
+# sqrt(12) at the published ranks), so the matrices that read them are
+# drawn for that width: `fan_in` states rank x hidden / rank. Drawn for
+# the rank, queries and keys come out 2 and 3.5 times wider, the scores'
+# spread is 6 where standard attention starts at 1, nearly all of a row's
+# weight lies on one key, and bfloat16's rounding of the scores moves a
+# log-probability by 0.2 to 0.4 at the median (my chip run, PR 45: kernel
+# and XLA attention alike, 0.18 with float32 activations), which no limit
+# could tell from a fault. The published ratios; TINY keeps them.
+_LATENT_AS_WIDE = {"wq_b": 4, "wkv_b": 12}
+
+# rows of one expert's stretch in the reference, as a multiple of the even
+# share (tokens x experts a token / the router's columns)
+_ROWS_OVER_SHARE = 4
 
 
 def _routed_total(cfg: dict) -> int:
@@ -148,7 +167,8 @@ def fan_in(path: tuple, shape: tuple) -> int:
     for every expert, which moves no choice, as the zero the configuration
     assumes). Layer leaves lead with the layer axis, what a layer has twice
     with (layer, half), expert leaves with (layer, expert). The router
-    states a sixteenth of its inputs (`_ROUTER_SHARPER`)."""
+    states a quarter of its inputs (`_ROUTER_SHARPER`), the matrices that
+    read a scaled latent vector the hidden size (`_LATENT_AS_WIDE`)."""
     name = path[-1]
     if name in ("attn_norm", "mlp_norm", "q_norm", "kv_norm", "scale",
                 "router_bias"):
@@ -160,7 +180,7 @@ def fan_in(path: tuple, shape: tuple) -> int:
     if name == "tokens":  # (V, D)
         return shape[1]
     if name in ("wq_b", "wkv_b"):  # (L, 2, rank, H, Dh)
-        return shape[2]
+        return shape[2] * _LATENT_AS_WIDE[name]
     if name == "router":  # (L, D, R)
         return max(1, shape[-2] // _ROUTER_SHARPER ** 2)
     if name in ("wq_a", "wkv_a", "ffn_gate", "ffn_up", "ffn_down",
@@ -179,10 +199,10 @@ def cuts(cfg: dict) -> dict:
 
 
 def blocks(cfg: dict) -> dict:
-    """The file as it is drives latent attention, both dense halves, the
-    held experts and the identity experts; with no identity expert the
-    router is as wide as the routed experts alone."""
-    return {"shortcut": cfg, "no_zero_experts": {**cfg, "zero_expert_num": 0}}
+    """Every layer is the double layer: the file as it is drives latent
+    attention, both dense halves, the held experts and the identity
+    experts."""
+    return {"shortcut": cfg}
 
 
 def _rms_norm(x, scale, eps):
@@ -274,12 +294,32 @@ def _moe(u, w, *, top_k, routed, factor, lo, hi):
     y = zero[:, None] * u
     at = 0 if w["w_gate"].shape[0] == hi - lo else lo
 
-    def add(j, y):
-        weight = jnp.sum(jnp.where(idx == lo + j, gates, 0.0), axis=1)
-        return y + weight[:, None] * _swiglu(
-            u, w["w_gate"][at + j], w["w_up"][at + j], w["w_down"][at + j])
+    # departure: an expert runs on the rows that chose it, `rows` of them
+    # at most and the chosen ones first (the others in the stretch weigh
+    # 0), not on all of them: a sixteenth of the rows where the even share
+    # is a sixty-fourth. An expert that more rows chose runs on every row.
+    s = u.shape[0]
+    rows = min(s, -(-int(_ROWS_OVER_SHARE * s * top_k) // score.shape[1]
+                    // 8) * 8)
 
-    return jax.lax.fori_loop(0, hi - lo, add, y), gap
+    def add(j, y):
+        mine = idx == lo + j
+        weight = jnp.sum(jnp.where(mine, gates, 0.0), axis=1)
+        expert = [w[k][at + j] for k in ("w_gate", "w_up", "w_down")]
+
+        def chosen_rows():
+            first = jnp.argsort(~jnp.any(mine, axis=1), stable=True)[:rows]
+            return y.at[first].add(
+                weight[first, None] * _swiglu(u[first], *expert))
+
+        def every_row():
+            return y + weight[:, None] * _swiglu(u, *expert)
+
+        if rows >= s:
+            return every_row()
+        return jax.lax.cond(jnp.sum(mine) <= rows, chosen_rows, every_row)
+
+    return (jax.lax.fori_loop(0, hi - lo, add, y) if hi > lo else y), gap
 
 
 @partial(jax.jit, static_argnames=("eps", "theta", "nope", "rope", "top_k",

@@ -15,6 +15,18 @@ position in them lies behind the window of every query still to come;
 the table's entry is then the sentinel, and the kernels never fetch a
 block wholly behind the bound. A model of one kind has one pool.
 
+A model with latent attention (`ModelConfig.latent_dim` > 0) has one pool
+of the LATENT kind in the full kind's place: a page is (1, latent_dim,
+page size), one entry a token of the normed, scaled latent vector and the
+rotated shared rotary key (`models/latent.py`), with no value pool and no
+scales, and the pool is as deep as the model has attention blocks (two a
+double layer: layer l's block i is pool layer 2 l + i). Keys and values
+are never stored expanded: the kernels read the entry as the one key
+"head" of every query head and take a page's values to be its keys' first
+`kv_lora_rank` rows (`ops.paged_attention`, `latent_dv`). To the
+allocator, the tables, preemption and the prefix cache it is pages, as
+the full kind is.
+
 Everything the paged server dispatches is one primitive,
 `forward_sets`, a walk of the layer stack over one or more row sets that
 share the pool; a mixed step hands it its prefill group and its decode
@@ -62,7 +74,7 @@ import jax.numpy as jnp
 from cloud_server_tpu.config import ModelConfig
 from cloud_server_tpu.inference import multi_lora
 from cloud_server_tpu.inference.engine import _kv_quant, _mlp_apply
-from cloud_server_tpu.models import transformer
+from cloud_server_tpu.models import latent, moe, transformer
 from cloud_server_tpu.ops import rms_norm, rope_table
 from cloud_server_tpu.ops.paged_attention import (
     paged_attention, paged_attention_tp, paged_attention_xla)
@@ -81,8 +93,10 @@ class PagedKVCache(NamedTuple):
     sentinel ("no page") is any id >= its own pool's pages."""
 
     k: jnp.ndarray        # (L_full, num_pages, KH, Dh, ps) cfg.dtype | int8
-    v: jnp.ndarray        # (L_full, num_pages, KH, Dh, ps) — transposed
-    #                       pages (positions on lanes; ops/paged_attention)
+    #                       latent kind: (blocks, num_pages, 1, latent_dim, ps)
+    v: jnp.ndarray | None  # (L_full, num_pages, KH, Dh, ps) — transposed
+    #                       pages (positions on lanes; ops/paged_attention);
+    #                       None for the latent kind
     lengths: jnp.ndarray  # (B,) int32 — committed kv entries per slot
     tables: jnp.ndarray   # (B, kinds * max_pages_per_slot) int32
     k_scale: jnp.ndarray | None = None  # (L_full, num_pages, KH, ps) f32
@@ -91,6 +105,11 @@ class PagedKVCache(NamedTuple):
     wv: jnp.ndarray | None = None
     wk_scale: jnp.ndarray | None = None
     wv_scale: jnp.ndarray | None = None
+    # (3,) int32, a model whose router is wider than the experts held:
+    # the assignments to held, identity and absent experts of every walk
+    # so far, modulo 2**32. It rides the pools through every program, so
+    # the host reads it with a step's results and takes differences.
+    assign: jnp.ndarray | None = None
 
     @property
     def page_size(self) -> int:
@@ -156,6 +175,14 @@ def init_paged_cache(cfg: ModelConfig, *, num_pages: int, page_size: int,
         raise ValueError(f"unknown kv_cache_dtype: {cfg.kv_cache_dtype!r}")
     int8 = cfg.kv_cache_dtype == "int8"
     dtype = jnp.int8 if int8 else jnp.dtype(cfg.dtype)
+    if cfg.latent_dim:  # one latent entry a token a block, no values
+        return PagedKVCache(
+            jnp.zeros((cfg.num_layers * cfg.attention_blocks, num_pages, 1,
+                       cfg.latent_dim, page_size), dtype), None,
+            jnp.zeros((batch,), jnp.int32),
+            jnp.full((batch, max_pages_per_slot), num_pages, jnp.int32),
+            assign=(jnp.zeros((3,), jnp.int32)
+                    if cfg.routed_scaling_factor > 0 else None))
 
     def pools(kind: str, pages: int):
         shape = (kinds.count(kind), pages, cfg.num_kv_heads, cfg.head_dim,
@@ -206,7 +233,8 @@ def hbm_bytes(cache: PagedKVCache) -> int:
 def _write_window(cache: PagedKVCache, layer: int, k, v, pos):
     """Write fresh (B, W, KH, Dh) k/v at absolute positions (B, W)
     through the page table. Out-of-chain positions (sentinel table
-    entries) drop.
+    entries) drop. A latent pool has no values: `v` is None and `k` the
+    tokens' latent entries, (B, W, 1, latent_dim).
 
     Implementation note: a direct elementwise scatter into the transposed
     (.., Dh, ps) pages would write 2-byte elements at stride ps — an XLA
@@ -229,7 +257,7 @@ def _write_window(cache: PagedKVCache, layer: int, k, v, pos):
         v_src = vq.astype(cache.v.dtype)
     else:
         k_src = k.astype(cache.k.dtype)
-        v_src = v.astype(cache.v.dtype)
+        v_src = None if v is None else v.astype(cache.v.dtype)
 
     new = {"k": cache.k, "v": cache.v,
            "k_scale": cache.k_scale, "v_scale": cache.v_scale}
@@ -249,6 +277,8 @@ def _write_window(cache: PagedKVCache, layer: int, k, v, pos):
         ohf = oh.astype(jnp.float32)
         any_write = ohf.sum(axis=1)                          # (B, ps)
         for name, src in (("k", k_src), ("v", v_src)):
+            if src is None:
+                continue
             pool = new[name]
             pages_old = pool[layer, jnp.clip(page_g, 0, pool.shape[1] - 1)]
             upd = jnp.einsum("bwhd,bwp->bhdp",
@@ -281,6 +311,15 @@ def _write_window(cache: PagedKVCache, layer: int, k, v, pos):
 # reference: a config that asks for the kernel either runs the kernel
 # or fails (the paged server checks its prefill chunk at construction).
 PALLAS_MAX_W = 256
+# The latent kind's kernel holds a tile of a window's query rows at a time
+# (`ops.paged_attention.LATENT_TILE`), whatever the window's width: its
+# cap is what was compiled and measured.
+LATENT_MAX_W = 1024
+
+
+def max_window(cfg: ModelConfig) -> int:
+    """The widest window the pallas path serves for this model."""
+    return LATENT_MAX_W if cfg.latent_dim else PALLAS_MAX_W
 
 
 class RowSet(NamedTuple):
@@ -352,15 +391,18 @@ def forward_sets(params, cfg: ModelConfig, cache: PagedKVCache,
     joined = len(sets) > 1
     if joined and lora is not None:
         raise ValueError("per-row lora deltas need one row set")
+    if cfg.latent_dim and (lora is not None or mesh is not None):
+        raise ValueError("the double layer with latent attention takes no "
+                         "adapter and no mesh")
     use_pallas = cfg.decode_attention_impl == "pallas"
     shared = "joined_walk" if joined else None
     rows = []  # per set: positions, write positions, lengths after, block
     for s in sets:
         w = s.tokens.shape[1]
-        if use_pallas and w > PALLAS_MAX_W:
+        if use_pallas and w > max_window(cfg):
             raise ValueError(
                 f"window width {w} exceeds the pallas paged-attention cap "
-                f"({PALLAS_MAX_W}); use a narrower window or "
+                f"({max_window(cfg)}); use a narrower window or "
                 "decode_attention_impl='xla'")
         with _scope(s.scope):
             ar = jnp.arange(w, dtype=jnp.int32)[None, :]
@@ -379,7 +421,8 @@ def forward_sets(params, cfg: ModelConfig, cache: PagedKVCache,
                      pages_per_block if pages_per_block is not None
                      else 8 if w <= 8 else 4))
     with _scope(shared):
-        cos, sin = rope_table(cfg, cache.max_context)
+        cos, sin = (latent.rope_table if cfg.latent_dim else rope_table)(
+            cfg, cache.max_context)
         embed = params["embed"]["tokens"].astype(cfg.dtype)
         x = _side_by_side([embed[s.tokens] for s in sets])  # (B, W, D)
         pos_all = _side_by_side([r[0] for r in rows])
@@ -388,8 +431,16 @@ def forward_sets(params, cfg: ModelConfig, cache: PagedKVCache,
     # each layer's kind is static (the walk is unrolled): a model of one
     # kind adds no scope and no argument, and builds the program it built
     two_kinds = cfg.has_window_layers
+    assigned = 0
     for layer_idx in range(cfg.num_layers):
         lp = jax.tree.map(lambda p: p[layer_idx], params["layers"])
+        if cfg.layer_body == "double_shortcut":
+            # the layer's body is the configuration's: a trace-time branch
+            x, pools, counts = _double_layer(
+                x, lp, layer_idx, params, cfg, pools, sets, rows, cos, sin,
+                pos_all, shared, use_pallas)
+            assigned = assigned + counts
+            continue
         ll = (None if lora is None
               else multi_lora.layer_lora(lora, aid, layer_idx))
         kind, at = cfg.layer_pool(layer_idx)
@@ -448,8 +499,63 @@ def forward_sets(params, cfg: ModelConfig, cache: PagedKVCache,
                 logits.append(transformer.unembed(x_sel, params, cfg))
             else:
                 logits.append(None)
+    if pools.assign is not None:
+        pools = pools._replace(assign=pools.assign + assigned)
     return logits, pools._replace(lengths=cache.lengths,
                                   tables=cache.tables)
+
+
+def _double_layer(x, lp, layer_idx: int, params, cfg: ModelConfig, pools,
+                  sets, rows, cos, sin, pos_all, shared, use_pallas: bool):
+    """One double layer of `forward_sets`' walk (`models/latent.py`): two
+    latent attention blocks, each with a pool layer of its own, two dense
+    MLPs, and the experts computed from the first half's normed stream and
+    added after the second half. What is per token runs once over all
+    sets' tokens; each set writes its tokens' latent entries and runs the
+    kernel in the absorbed form, under `attn/latent` in its own scope.
+    Returns (x', pools', the layer's (held, identity, absent) assignment
+    counts)."""
+    shortcut = None
+    for i in (0, 1):
+        hp = latent.half(params["layers"], layer_idx, i)
+        at = cfg.attention_blocks * layer_idx + i
+        with _scope(shared), jax.named_scope("attn"):
+            q_all, entries = latent.latent_qkv(
+                rms_norm(x, hp["attn_norm"], cfg.norm_eps), hp, cfg, cos,
+                sin, pos_all)
+        outs = []
+        for j, (s, (_, wpos, lens_after, ppb)) in enumerate(zip(sets, rows)):
+            with _scope(s.scope), jax.named_scope("attn"), \
+                    jax.named_scope("latent"):
+                view = _write_window(
+                    pools.of_kind("full", s.tables)._replace(
+                        lengths=s.lengths), at,
+                    _part(entries, sets, j)[:, :, None, :], None, wpos)
+                kw = dict(widths=s.widths, latent_dv=cfg.kv_lora_rank,
+                          scale=cfg.head_dim ** -0.5)
+                if use_pallas:
+                    kw["pages_per_block"] = ppb
+                o = (paged_attention if use_pallas else paged_attention_xla)(
+                    _part(q_all, sets, j), view.k, None, lens_after,
+                    view.tables, at, **kw)
+                # the next write into the pool waits for this read of it:
+                # left to itself XLA keeps the pool as this call read it
+                # and writes into a copy (3.5 GB a block where a group of
+                # one row makes the write a slice update)
+                o, pool = jax.lax.optimization_barrier((o, view.k))
+                pools = pools.with_kind("full", view._replace(k=pool))
+                outs.append(o)
+        with _scope(shared):
+            with jax.named_scope("attn"):
+                x = latent.latent_out(x, _side_by_side(outs), hp, cfg)
+            u = rms_norm(x, hp["mlp_norm"], cfg.norm_eps)
+            if i == 0:
+                with jax.named_scope("moe_shortcut"):
+                    shortcut, aux = moe.moe_mlp(
+                        u, lp, cfg, stack=(params["layers"], layer_idx))
+            x = x + latent.dense_mlp(u, hp, cfg)
+    with _scope(shared):
+        return x + shortcut, pools, aux["assign"]
 
 
 def window_forward(params, tokens: jnp.ndarray, cfg: ModelConfig,

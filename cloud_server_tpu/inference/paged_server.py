@@ -249,7 +249,7 @@ def _grammar_mask(grammar, gid, st, eos_id):
 
 
 _POOL_NAMES = ("k", "v", "k_scale", "v_scale",
-               "wk", "wv", "wk_scale", "wv_scale")
+               "wk", "wv", "wk_scale", "wv_scale", "assign")
 
 
 def _make_cache(pools, lengths, tables):
@@ -261,7 +261,8 @@ def _make_cache(pools, lengths, tables):
 def _split_cache(cache):
     """The cache's pools by name; a pool the model does not have (the
     scales of a bf16 cache, the window kind of a model without window
-    layers) is left out."""
+    layers, the values of a latent cache) is left out. `assign`, the
+    assignment counts of a model with a routed share, rides with them."""
     return {name: getattr(cache, name) for name in _POOL_NAMES
             if getattr(cache, name) is not None}
 
@@ -1197,12 +1198,12 @@ class PagedInferenceServer:
         if self.prefill_chunk % page_size:
             raise ValueError("prefill_chunk must be a page multiple")
         if (cfg.decode_attention_impl == "pallas"
-                and self.prefill_chunk > paged_engine.PALLAS_MAX_W):
+                and self.prefill_chunk > paged_engine.max_window(cfg)):
             # the widest window this server dispatches; refuse here
             # rather than at the first long prompt's trace
             raise ValueError(
                 f"prefill_chunk={self.prefill_chunk} exceeds the pallas "
-                f"paged-attention window cap ({paged_engine.PALLAS_MAX_W})"
+                f"paged-attention window cap ({paged_engine.max_window(cfg)})"
                 "; lower prefill_chunk (and page_size with it) or use "
                 "decode_attention_impl='xla'")
         if prompt_buckets is None:
@@ -1247,6 +1248,12 @@ class PagedInferenceServer:
                 f"draft vocab_size={draft_cfg.vocab_size} != target "
                 f"vocab_size={cfg.vocab_size}; speculative verification "
                 "compares their token distributions elementwise")
+        if cfg.latent_dim and (draft_cfg is not None or mesh is not None):
+            raise ValueError(
+                "a model with latent attention (LongCat-Flash's double "
+                "layer) is served without a draft model, whose pools hold "
+                "keys and values by the target's tables, and without a "
+                "mesh, which shards pools over key heads it does not have")
         self.draft_cfg = draft_cfg
         self.draft_params = (None if draft_params is None else jax.tree.map(
             cast_leaf, draft_params,
@@ -1287,6 +1294,9 @@ class PagedInferenceServer:
         self._win_hi = np.zeros((max_slots,), np.int64)
         self.window_pages_peak_slot = 0
         self._keys_stage: dict = {}
+        # the running assignment counts last read back (`_count_assign`)
+        self._assign_seen = (np.zeros((3,), np.uint32)
+                             if cfg.routed_scaling_factor > 0 else None)
         cache = paged_engine.init_paged_cache(
             cfg, num_pages=num_pages, page_size=page_size, batch=max_slots,
             max_pages_per_slot=self.max_pages_per_slot,
@@ -1772,6 +1782,8 @@ class PagedInferenceServer:
         # prefill completes with decode budget left — the router's
         # hook migrates it to a decode replica. Rides IN through
         # submit for the same reason fail_handler does.
+        if handoff is not None and self.cfg.latent_dim:
+            self._refuse_window("the disaggregated hand-off (handoff=)")
         req._handoff = handoff
         req._on_cancel = self._handle_cancel  # before it can be seen
         with self._lock:
@@ -2129,6 +2141,13 @@ class PagedInferenceServer:
     def _window_cover_rounds(self, n_rounds: int, lengths, active) -> None:
         """`_window_cover` for `n_rounds` decode rounds of every live
         slot, from `lengths` (the planned frame's under overlap)."""
+        if self.cfg.latent_dim and n_rounds > 0:
+            # the keys one latent attention block reads for the decode
+            # rows: every row's context, its new token included
+            live = np.asarray(lengths)[np.asarray(active, bool)]
+            self._keys_stage["keys_latent_decode"] = int(sum(
+                np.sum(live + r * self.window)
+                for r in range(1, n_rounds + 1)))
         if self.window_pool is None or n_rounds <= 0:
             return
         ids = [sid for sid in np.flatnonzero(active)
@@ -2155,10 +2174,29 @@ class PagedInferenceServer:
         if decode:
             ks["keys_window_decode"] = ks.get("keys_window_decode", 0) + win
 
+    def _assign_future(self) -> tuple:
+        """The pools' running assignment counts as the step just launched
+        leaves them (`PagedKVCache.assign`), to be read back with its
+        results; nothing for a model without a routed share."""
+        return (() if self._assign_seen is None
+                else (self.state["pools"]["assign"],))
+
+    def _count_assign(self, stats: dict, assign=None) -> None:
+        """The flight record's `assign_held`, `assign_zero` and
+        `assign_absent`: what the step added to the running counts
+        `assign`, as read back; nothing without them."""
+        if assign is not None:
+            now = np.asarray(assign).astype(np.uint32)
+            held, zero, absent = (now - self._assign_seen).tolist()
+            self._assign_seen = now
+            stats.update(assign_held=held, assign_zero=zero,
+                         assign_absent=absent)
+
     def _take_keys(self) -> dict:
         """The flight record's `keys_full`, `keys_window` and
-        `keys_window_decode` of the dispatch being built; nothing for a
-        model without window layers."""
+        `keys_window_decode` of the dispatch being built, or its
+        `keys_latent_decode`; nothing for a model without window layers
+        or a latent cache."""
         ks, self._keys_stage = self._keys_stage, {}
         return ks
 
@@ -2886,8 +2924,9 @@ class PagedInferenceServer:
                 mesh=self.mesh, use_rows=use_rows, use_bias=use_bias)
             # analysis: allow[lock-discipline] THE sanctioned
             # per-iteration host sync under _step_lock (plain arm)
-            toks, lps, counts, lens, last = jax.device_get(
-                (toks, lps, counts, lens, last))
+            toks, lps, counts, lens, last, *assign = jax.device_get(
+                (toks, lps, counts, lens, last, *self._assign_future()))
+            self._count_assign(self._iter_stats, *assign)
             toks, lps = toks[:, :, None], lps[:, :, None]
             if self.spec_drafts > 0 and self.spec_control is not None:
                 # every live slot decoded plainly: draft-model caches
@@ -3373,8 +3412,10 @@ class PagedInferenceServer:
         # analysis: allow[lock-discipline] THE sanctioned per-iteration
         # host sync — one fused dispatch, one device_get, under the
         # step lock that serializes the scheduler by design
-        ptoks, plps, toks, lps, counts, lens, last = jax.device_get(
-            (ptoks, plps, toks, lps, counts, lens, last))
+        ptoks, plps, toks, lps, counts, lens, last, *assign = jax.device_get(
+            (ptoks, plps, toks, lps, counts, lens, last,
+             *self._assign_future()))
+        self._count_assign(self._iter_stats, *assign)
         if prof is not None:
             prof.enter("commit")
 
@@ -3775,6 +3816,7 @@ class PagedInferenceServer:
                 n_rounds=plan.n_rounds, mesh=self.mesh,
                 use_rows=plan.use_rows_d, use_bias=plan.use_bias_d)
             futures = (toks, lps, counts, lens, last)
+        futures += self._assign_future()
         self._iter_launch_h2d = self._h2d - h2d0
         # the launch's end is the delivery's start: `_step_overlap`
         # wakes the streaming threads under the program launched here
@@ -3814,6 +3856,9 @@ class PagedInferenceServer:
         if prof is not None:
             prof.enter("commit")
         st = infl.stats
+        if self._assign_seen is not None:
+            *vals, assign = vals
+            self._count_assign(st, assign)
         st["overlap"] = True
         st["host_late"] = host_late
         # how long the device ran ahead of the host needing results:
@@ -4821,7 +4866,14 @@ class PagedInferenceServer:
 
     def _refuse_window(self, mechanism: str) -> None:
         """Raise for a mechanism that moves or shares pages of the full
-        kind only, on a model that also has window layers."""
+        kind only, on a model that also has window layers, or whose pages
+        are latent entries."""
+        if self.cfg.latent_dim:
+            raise ValueError(
+                f"{mechanism} exports pages of keys and values and this "
+                "model's pages hold latent entries (latent attention, "
+                "LongCat-Flash's double layer), which nothing on the other "
+                "side could read; not supported for such a model")
         if self.window_pool is not None:
             raise ValueError(
                 f"{mechanism} moves the full kind's pages only and this "

@@ -190,6 +190,13 @@ class ReplicatedRouter:
                     "disaggregated hand-off move one kind of page; a "
                     "model with sliding-window layers is served by a lone "
                     "PagedInferenceServer")
+            if getattr(getattr(r, "cfg", None), "latent_dim", 0):
+                raise ValueError(
+                    "ReplicatedRouter: live migration and the "
+                    "disaggregated hand-off export pages of keys and "
+                    "values; a model with latent attention (LongCat-"
+                    "Flash's double layer) is served by a lone "
+                    "PagedInferenceServer")
         if roles is None:
             self.roles = [ROLE_COLOCATED] * len(self.replicas)
         else:

@@ -45,9 +45,6 @@ from cloud_server_tpu.config import ModelConfig
 from cloud_server_tpu.ops import gated, rms_norm
 from cloud_server_tpu.ops.rope import rope_frequencies
 
-Params = dict
-
-
 def param_shapes(cfg: ModelConfig) -> dict[str, Any]:
     L, D, H, V = cfg.num_layers, cfg.embed_dim, cfg.num_heads, cfg.vocab_size
     rq, rkv, dr = cfg.q_lora_rank, cfg.kv_lora_rank, cfg.qk_rope_head_dim
@@ -82,28 +79,6 @@ def param_shapes(cfg: ModelConfig) -> dict[str, Any]:
     return shapes
 
 
-def init_params(cfg: ModelConfig, rng: jax.Array) -> Params:
-    """Seeded normal leaves of 1/sqrt(fan-in), norms 1, the router's bias
-    0: what the tests serve."""
-    dtype = jnp.dtype(cfg.param_dtype)
-    paths, treedef = jax.tree_util.tree_flatten_with_path(
-        param_shapes(cfg), is_leaf=lambda x: isinstance(x, tuple))
-    out = []
-    for (path, shape), key in zip(paths, jax.random.split(rng, len(paths))):
-        name = path[-1].key
-        if "norm" in name or name == "scale":
-            out.append(jnp.ones(shape, dtype))
-        elif name == "router_bias":
-            out.append(jnp.zeros(shape, dtype))
-        else:
-            fan_in = {"wo": shape[-3] * shape[-2], "wq_b": shape[2],
-                      "wkv_b": shape[2], "tokens": shape[1],
-                      "kernel": shape[0]}.get(name, shape[-2])
-            out.append((jax.random.normal(key, shape, jnp.float32)
-                        / math.sqrt(fan_in)).astype(dtype))
-    return jax.tree.unflatten(treedef, out)
-
-
 def forward_hidden(*_, **__):
     raise NotImplementedError(
         "the double layer with latent attention (layer_body="
@@ -111,12 +86,17 @@ def forward_hidden(*_, **__):
         "training scan, no contiguous cache")
 
 
-def half(lp: dict, i: int) -> dict:
-    """Half `i`'s slice of the leaves a layer has twice."""
-    return {name: p[i] for name, p in lp.items()
-            if name in ("attn_norm", "mlp_norm", "wq_a", "q_norm", "wq_b",
-                        "wkv_a", "kv_norm", "wkv_b", "wo", "ffn_gate",
-                        "ffn_up", "ffn_down")}
+_HALF_LEAVES = ("attn_norm", "mlp_norm", "wq_a", "q_norm", "wq_b", "wkv_a",
+                "kv_norm", "wkv_b", "wo", "ffn_gate", "ffn_up", "ffn_down")
+
+
+def half(layers: dict, layer: int, i: int) -> dict:
+    """Half `i` of layer `layer`'s leaves that a layer has twice, each cut
+    from the stacked (L, 2, ...) leaf in one static slice: a matmul then
+    reads its weights where they lie. Cut in two steps, the layer's pair
+    has two readers and XLA writes it out first (288 MB a dense matrix
+    at the published widths, six a layer)."""
+    return {name: layers[name][layer, i] for name in _HALF_LEAVES}
 
 
 def rope_table(cfg: ModelConfig, seq_len: int):
@@ -144,13 +124,16 @@ def latent_qkv(h, hp: dict, cfg: ModelConfig, cos, sin, positions):
     rkv, dr = cfg.kv_lora_rank, cfg.qk_rope_head_dim
     dn = cfg.head_dim - dr
     with jax.named_scope("mla_proj"):
-        cq = rms_norm(h @ hp["wq_a"].astype(dt), hp["q_norm"], cfg.norm_eps)
-        cq = cq * jnp.asarray(math.sqrt(cfg.embed_dim / cfg.q_lora_rank), dt)
+        # the scale after each latent norm rides the norm's float32 gain
+        cq = rms_norm(h @ hp["wq_a"].astype(dt),
+                      hp["q_norm"].astype(jnp.float32)
+                      * math.sqrt(cfg.embed_dim / cfg.q_lora_rank),
+                      cfg.norm_eps)
         q = jnp.einsum("bwr,rhk->bwhk", cq, hp["wq_b"].astype(dt))
         q_r = _rope_pairs(q[..., dn:], cos, sin, positions)
         ckr = h @ hp["wkv_a"].astype(dt)
-        c = rms_norm(ckr[..., :rkv], hp["kv_norm"], cfg.norm_eps)
-        c = c * jnp.asarray(math.sqrt(cfg.embed_dim / rkv), dt)
+        c = rms_norm(ckr[..., :rkv], hp["kv_norm"].astype(jnp.float32)
+                     * math.sqrt(cfg.embed_dim / rkv), cfg.norm_eps)
         k_r = _rope_pairs(ckr[..., rkv:], cos, sin, positions)
         # the keys' expansion absorbed into the query
         q_abs = jnp.einsum("bwhn,rhn->bwhr", q[..., :dn],
@@ -178,7 +161,3 @@ def dense_mlp(u, hp: dict, cfg: ModelConfig):
         act = gated(u @ hp["ffn_gate"].astype(dt),
                     u @ hp["ffn_up"].astype(dt), cfg.mlp_activation)
         return act @ hp["ffn_down"].astype(dt)
-
-
-def attention_scale(cfg: ModelConfig) -> float:
-    return cfg.head_dim ** -0.5

@@ -29,6 +29,16 @@ observe (`_dispatch_grouped`):
 
 No dynamic shapes, no host round-trips on either.
 
+One chip's share of a wider router (`cfg.routed_scaling_factor` > 0:
+LongCat-Flash, served only): the router has columns for experts held on
+other chips and for experts that compute nothing. The choice is the top k
+of probability + bias and the gate probability x factor (`_share_gates`);
+both dispatches compute the held experts' terms alone (the one-hot one
+builds no column for another index, the sorted one gives it no group and no
+row), the identity experts' term is added beside them (`_share_identity`)
+and the absent experts' terms are left out: the partial sum a chip holds
+before the exchange.
+
 Attention/norms/rope are shared with the dense model; only the MLP is
 replaced by the expert layer. Layers are stacked and scanned like
 `models/transformer.py`; the router aux losses ride the scan carry.
@@ -81,12 +91,30 @@ def grouped_min_tokens(cfg: ModelConfig) -> int:
     nothing was measured under 16 assignments, where the placed value
     stands. The widths do not enter: both dispatches leave the weight
     stream near 240 tokens a call at any width (peak FLOPs over peak
-    bytes)."""
-    assignments = cfg.num_experts * cfg.num_experts_per_token
+    bytes).
+
+    The third configuration (PERF.md, PR 45) is one chip's share: 16
+    experts of 2,048 held of a 768-wide router, 12 a token, so that of a
+    token's 12 assignments 0.25 land on a held expert and the dispatches
+    place 4 assignments' worth of a full router's. Measured, ms a layer,
+    dense and sorted: 1.74 and 1.82 at 128 tokens, 1.88 and 1.99 at 192,
+    2.04 and 2.03 at 256, 2.61 and 2.17 at 320, 5.01 and 2.80 at 576. The
+    crossing is at 256, under 16 assignments where the placed value (288)
+    stands: the fit holds, with a share's assignments counted as those
+    that land here. (At 64 tokens the sorted dispatch reads 1.29 against
+    1.69: 16 assignments reach some ten of the 16 experts and it fetches
+    no expert without a row; one threshold cannot say that, and a
+    deployment's exchange brings every expert 32 times the rows.)"""
+    assignments = (cfg.num_experts * cfg.num_experts_per_token
+                   * cfg.num_experts / cfg.router_width)
     return round(GROUPED_MIN_TOKENS * min(1.0, (16 / assignments) ** 0.2))
 
 
 def _capacity(cfg: ModelConfig, num_tokens: int) -> int:
+    if cfg.routed_scaling_factor > 0:
+        # one chip's share of a wider router (`_share_gates`): a held
+        # expert gets every row at most, and room for every row
+        return max(num_tokens, 4)
     cap = int(math.ceil(cfg.expert_capacity_factor * num_tokens
                         * cfg.num_experts_per_token / cfg.num_experts))
     return max(cap, 4)
@@ -134,6 +162,37 @@ def _top_k_gates(router_logits: jnp.ndarray, k: int):
     return probs, gate_vals, gate_idx
 
 
+def _share_gates(router_logits: jnp.ndarray, bias, cfg: ModelConfig):
+    """One chip's share of a wider router (`cfg.routed_scaling_factor` >
+    0): (T, R) logits over the `cfg.router_width` columns -> the k chosen
+    columns (T, k), the top of probability + `bias` (R,), and their gates
+    (T, k) float32, probability * factor, the bias left out and nothing
+    renormalised. Columns under `cfg.num_experts` are the experts held
+    here; from there to `cfg.num_routed_experts` the experts other chips
+    hold, whose terms this chip leaves out; behind them the experts that
+    compute nothing and add gate * token (`_share_identity`)."""
+    probs = jax.nn.softmax(router_logits, axis=-1)
+    _, gate_idx = lax.top_k(probs + bias.astype(jnp.float32),
+                            cfg.num_experts_per_token)
+    gate_vals = cfg.routed_scaling_factor * jnp.take_along_axis(
+        probs, gate_idx, axis=1)
+    return gate_vals, gate_idx
+
+
+def _share_identity(tokens, gate_vals, gate_idx, cfg: ModelConfig):
+    """The identity experts' term of a share, (T, D) in the tokens' dtype,
+    and the call's assignments to (held, identity, absent) experts, (3,)
+    int32."""
+    with jax.named_scope("moe_zero"):
+        zero = gate_idx >= (cfg.num_routed_experts or cfg.num_experts)
+        gate = jnp.sum(jnp.where(zero, gate_vals, 0.0), axis=1)
+        term = (gate[:, None] * tokens.astype(jnp.float32)).astype(
+            tokens.dtype)
+    n_held = jnp.sum(gate_idx < cfg.num_experts, dtype=jnp.int32)
+    n_zero = jnp.sum(zero, dtype=jnp.int32)
+    return term, jnp.stack([n_held, n_zero, gate_idx.size - n_held - n_zero])
+
+
 def _router_aux(router_logits, probs, top1_onehot, dropped_frac):
     """Aux stats: fraction of tokens routed to each expert (top-1 view) and
     mean router prob, per GShard load-balancing loss."""
@@ -162,8 +221,21 @@ def top_k_routing(router_logits: jnp.ndarray, k: int, capacity: int):
         router probability.
       aux: dict with load-balance / z-loss ingredients.
     """
-    t, e = router_logits.shape
     probs, gate_vals, gate_idx = _top_k_gates(router_logits, k)
+    dispatch, combine, assign, keep = _one_hot_routing(
+        gate_vals, gate_idx, router_logits.shape[1], capacity)
+    aux = _router_aux(router_logits, probs, assign[:, 0, :],
+                      1.0 - keep[:, 0, :].sum() / router_logits.shape[0])
+    return dispatch, combine, aux
+
+
+def _one_hot_routing(gate_vals, gate_idx, e: int, capacity: int):
+    """(dispatch, combine) (T, E, C) of `top_k_routing` from the chosen
+    experts (T, k) and their gates, and the (T, k, E) one-hots of the
+    assignments and of those kept. A chosen index of `e` or more (an
+    expert this chip does not hold) has no column: its row is all zeros
+    and the assignment takes no slot."""
+    t, k = gate_idx.shape
 
     # One-hot per assignment: (T, k, E).
     assign = jax.nn.one_hot(gate_idx, e, dtype=jnp.float32)
@@ -182,10 +254,7 @@ def top_k_routing(router_logits: jnp.ndarray, k: int, capacity: int):
     slot_onehot *= keep[..., None]
     dispatch = slot_onehot.sum(axis=1)  # (T, E, C)
     combine = (slot_onehot * gate_vals[:, :, None, None]).sum(axis=1)
-
-    aux = _router_aux(router_logits, probs, assign[:, 0, :],
-                      1.0 - keep[:, 0, :].sum() / t)
-    return dispatch, combine, aux
+    return dispatch, combine, assign, keep
 
 
 # ---------------------------------------------------------------------------
@@ -227,8 +296,8 @@ def _gmm_tiling(k: int, n: int, itemsize: int = 2) -> tuple:
 
 def _gmm_tilings(cfg: ModelConfig) -> tuple:
     """The tilings of the way in (D -> F) and of the way out (F -> D)."""
-    return (_gmm_tiling(cfg.embed_dim, cfg.mlp_dim),
-            _gmm_tiling(cfg.mlp_dim, cfg.embed_dim))
+    return (_gmm_tiling(cfg.embed_dim, cfg.expert_width),
+            _gmm_tiling(cfg.expert_width, cfg.embed_dim))
 
 
 def _grouped_matmul(lhs, rhs, group_sizes, tiling, kernel: bool):
@@ -298,7 +367,7 @@ def _sorted_buffer_rows(n_assignments: int, cfg: ModelConfig) -> int:
     Where that sum is few enough (`_ALIGNED_COST_SHARE`) the buffer is the
     longer one; elsewhere it is the packed rows' whole tiles, in which
     `_aligned_layout` finds no room to pad."""
-    e, d, f = cfg.num_experts, cfg.embed_dim, cfg.mlp_dim
+    e, d, f = cfg.num_experts, cfg.embed_dim, cfg.expert_width
     packed = -(-n_assignments // _GMM_ROWS)
     visits = e * -(-n_assignments // (e * _GMM_ROWS))
     aligned = max(packed, visits + e // 8)
@@ -341,24 +410,49 @@ def _weighted_sum(ys, row_of, gates):
     return lax.optimization_barrier(acc.astype(ys.dtype))
 
 
-def _moe_grouped(tokens, router_logits, layers, layer, cfg: ModelConfig):
+@jax.jit
+def _weighted_sum_held(ys, row_of, gates, held):
+    """`_weighted_sum` for a share: only the assignments `held` (T, k)
+    have a row in `ys`; the others add nothing, whatever `row_of` names
+    for them and whatever that row holds (it may never have been
+    computed)."""
+    acc = jnp.zeros((row_of.shape[0], ys.shape[1]), jnp.float32)
+    for j in range(row_of.shape[1]):
+        acc += jnp.where(
+            held[:, j, None],
+            gates[:, j, None] * ys[row_of[:, j]].astype(jnp.float32), 0.0)
+    return lax.optimization_barrier(acc.astype(ys.dtype))
+
+
+def _moe_grouped(tokens, router_logits, layers, layer, cfg: ModelConfig,
+                 chosen=None):
     """The sorted, dropless dispatch of `moe_mlp`: tokens (T, D) -> (T, D).
     `layers` holds the stacked (L, E, ...) expert weights, `layer` (an int
     or an int32 scalar) says which of them is this call's. The combine is
-    `_weighted_sum`: a token's k rows, by the router's float32 gates."""
+    `_weighted_sum`: a token's k rows, by the router's float32 gates.
+    `chosen`: (gates, experts) of a share (`_share_gates`), where the
+    router is wider than the `e` experts held: an assignment to an index
+    of `e` or more sorts behind every held expert's, belongs to no group,
+    lands on no row of the buffer and adds nothing to the combine."""
     t = tokens.shape[0]
     k, e = cfg.num_experts_per_token, cfg.num_experts
     n_layers = layers["w_gate"].shape[0]
-    with jax.named_scope("moe_route"):
-        probs, gate_vals, gate_idx = _top_k_gates(router_logits, k)
-        aux = _router_aux(
-            router_logits, probs,
-            jax.nn.one_hot(gate_idx[:, 0], e, dtype=jnp.float32),
-            jnp.zeros((), jnp.float32))
+    if chosen is not None:
+        gate_vals, gate_idx = chosen
+        aux = {}
+    else:
+        with jax.named_scope("moe_route"):
+            probs, gate_vals, gate_idx = _top_k_gates(router_logits, k)
+            aux = _router_aux(
+                router_logits, probs,
+                jax.nn.one_hot(gate_idx[:, 0], e, dtype=jnp.float32),
+                jnp.zeros((), jnp.float32))
     with jax.named_scope("moe_dispatch"):
         # assignment a = token * k + slot; a stable sort, so an expert's
         # rows keep token order
         expert_of = gate_idx.reshape(t * k)
+        if chosen is not None:
+            expert_of = jnp.minimum(expert_of, e)
         ranks = jnp.arange(t * k, dtype=jnp.int32)
         expert_of_rank, order = lax.sort_key_val(expert_of, ranks)
         # compared and summed, not scattered and gathered: a scatter-add
@@ -372,8 +466,14 @@ def _moe_grouped(tokens, router_logits, layers, layer, cfg: ModelConfig):
         # the token every row of the buffer reads: a row no assignment
         # landed on (the rest of a padded extent, the rows behind the last
         # expert's) reads token 0, finite like any other
-        rows = tokens[jnp.zeros((n_rows,), jnp.int32).at[row_of_rank].set(
-            order // k, indices_are_sorted=True, unique_indices=True)]
+        if chosen is None:
+            src = jnp.zeros((n_rows,), jnp.int32).at[row_of_rank].set(
+                order // k, indices_are_sorted=True, unique_indices=True)
+        else:  # what no held expert was chosen for lands past the buffer
+            row_of_rank = jnp.where(expert_of_rank < e, row_of_rank, n_rows)
+            src = jnp.zeros((n_rows,), jnp.int32).at[row_of_rank].set(
+                order // k, mode="drop")
+        rows = tokens[src]
         # the stack seen as L * E groups, every other layer's empty: the
         # kernel then reads this layer's experts where they lie
         group_sizes = lax.dynamic_update_slice(
@@ -390,7 +490,12 @@ def _moe_grouped(tokens, router_logits, layers, layer, cfg: ModelConfig):
         # every assignment's row, by sorting the permutation back: a sort
         # of 12,672 pairs cost 8 us on the v5e, the scatter 75
         row_of = lax.sort_key_val(order, row_of_rank)[1]
-        out = _weighted_sum(ys, row_of.reshape(t, k), gate_vals)
+        if chosen is None:
+            out = _weighted_sum(ys, row_of.reshape(t, k), gate_vals)
+        else:
+            out = _weighted_sum_held(
+                ys, jnp.minimum(row_of, n_rows - 1).reshape(t, k),
+                gate_vals, gate_idx < e)
     return out, aux
 
 
@@ -413,19 +518,42 @@ def moe_mlp(x: jnp.ndarray, lp: dict, cfg: ModelConfig, stack=None,
 
     # scope names are what a device trace tells the einsums apart by
     # (`tf_op` of an op's event metadata; cellbench/hostplane.py)
+    share = cfg.routed_scaling_factor > 0
     with jax.named_scope("moe_route"):
-        router_logits = jnp.einsum(
-            "td,de->te",
-            (tokens if router_x is None
-             else router_x.reshape(b * s, d)).astype(jnp.float32),
-            lp["router"].astype(jnp.float32))
+        if share:
+            # 768 columns: the compute dtype's products summed in float32
+            # are the float32 product of the same values, in one pass
+            router_logits = jnp.einsum(
+                "td,de->te", tokens, lp["router"].astype(cfg.dtype),
+                preferred_element_type=jnp.float32)
+        else:
+            router_logits = jnp.einsum(
+                "td,de->te",
+                (tokens if router_x is None
+                 else router_x.reshape(b * s, d)).astype(jnp.float32),
+                lp["router"].astype(jnp.float32))
+    if share:
+        # one chip's share of a wider router: the held experts' terms by
+        # either dispatch, the identity experts' term beside them, the
+        # absent experts' terms left out
+        with jax.named_scope("moe_route"):
+            chosen = _share_gates(router_logits, lp["router_bias"], cfg)
+        identity, assign = _share_identity(tokens, *chosen, cfg)
     if _dispatch_grouped(cfg, b * s, stack):
-        out, aux = _moe_grouped(tokens, router_logits, *stack, cfg)
+        out, aux = _moe_grouped(tokens, router_logits, *stack, cfg,
+                                chosen if share else None)
+        if share:
+            out, aux = out + identity, {"assign": assign}
         return out.reshape(b, s, d), aux
     with jax.named_scope("moe_route"):
-        dispatch, combine, aux = top_k_routing(
-            router_logits, cfg.num_experts_per_token,
-            _capacity(cfg, b * s))
+        if share:
+            dispatch, combine, _, _ = _one_hot_routing(
+                *chosen, cfg.num_experts, _capacity(cfg, b * s))
+            aux = {"assign": assign}
+        else:
+            dispatch, combine, aux = top_k_routing(
+                router_logits, cfg.num_experts_per_token,
+                _capacity(cfg, b * s))
 
     # (T, E, C) x (T, D) -> (E, C, D): the all-to-all, inserted by XLA from
     # the `ep` sharding of the expert axis.
@@ -440,6 +568,8 @@ def moe_mlp(x: jnp.ndarray, lp: dict, cfg: ModelConfig, stack=None,
                         lp["w_down"].astype(cfg.dtype))
     with jax.named_scope("moe_combine"):
         out = jnp.einsum("tec,ecd->td", combine.astype(cfg.dtype), ys)
+    if share:
+        out = out + identity
     return out.reshape(b, s, d), aux
 
 
@@ -532,6 +662,12 @@ def forward_hidden(params: Params, tokens: jnp.ndarray, cfg: ModelConfig,
     segment_ids: optional packed-sequence ids — same block-diagonal
     attention + per-document RoPE semantics as the dense family
     (transformer.forward_hidden)."""
+    if cfg.layer_body != "single" or cfg.routed_scaling_factor > 0:
+        raise NotImplementedError(
+            "moe.forward_hidden scans single layers of renormalised top-k "
+            "experts; the double layer with shortcut experts and one "
+            "chip's share of a wider router (LongCat-Flash) are served by "
+            "the paged server only")
     cos, sin = rope_table(cfg, tokens.shape[1])
     # Unshard the table's embed dim BEFORE the lookup: a tp-sharded D at
     # the gather makes XLA produce a D-sharded (B, S, D) it must then

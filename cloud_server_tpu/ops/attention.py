@@ -112,4 +112,4 @@ def causal_attention(
     out = jnp.einsum(
         "bkgqs,bskd->bqkgd", probs.astype(out_dtype), v
     )
-    return out.reshape(b, sq, h, dh)
+    return out.reshape(b, sq, h, v.shape[-1])  # values may be narrower

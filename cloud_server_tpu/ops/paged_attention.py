@@ -69,6 +69,16 @@ The q/o layout is (B, KH, W*G, Dh) — grouped-query rows pre-folded per kv
 head — produced by the host-side wrapper below, so in-kernel q slices
 are contiguous too.
 
+A LATENT pool (latent attention read in the absorbed form, `latent_dv`;
+`models/latent.py`) is (L, num_pages, 1, Dl, page_size): one entry of Dl
+values a token, the latent vector of `latent_dv` values and behind it the
+rotary key every head shares, positions on the lanes like every page.
+There is no value pool. The contract: every query head reads the entry
+as its key (the one "key head"), and THE VALUES ARE THE KEYS' FIRST
+`latent_dv` ROWS, so a page is fetched once and the output is
+`latent_dv` wide. The grid kernel serves it with its second axis over
+tiles of the slot's W * H query rows (`_paged_attention_latent`).
+
 Numerics match `ops.attention.causal_attention` (f32 scores and
 accumulators); parity is tested against `paged_attention_xla` in
 interpret mode on CPU and compiled on TPU
@@ -328,13 +338,23 @@ _NARROW_MAX_B = 16
 # power of two: the scalar core pays for a remainder by anything else (6
 # buffers measured slower than 2).
 _WIDE_BUFFERS = 4
+# Query rows of one cell of the grid kernel over a latent pool: a chunk
+# of 256 tokens is 64 heads x 256 = 16,384 rows of 576 on the one key
+# head, 19 MB, so the rows are cut into tiles and the grid's second axis
+# runs over them. 512 rows of bfloat16 are 0.6 MB of queries, 0.5 MB of
+# output and 4 MB of float32 scores, weights and accumulator beside 2.4
+# MB of page buffers, inside the 16 MB a kernel may use (1,024 rows are
+# not: AOT, PR 45); every tile fetches the row's pages again, 1,152 B a
+# key for 512 x 1,088 flops.
+LATENT_TILE = 512
 
 
 def paged_attention(q, k_pool, v_pool, lengths, tables, layer=0, *,
                     scale=None, pages_per_block: int = 4,
                     interpret: bool | None = None,
                     k_scale_pool=None, v_scale_pool=None, widths=None,
-                    window: int = 0):
+                    window: int = 0, latent_dv: int = 0,
+                    latent_tile: int = LATENT_TILE):
     """Uniform- or ragged-window attention against a paged KV cache.
 
     Args:
@@ -366,6 +386,14 @@ def paged_attention(q, k_pool, v_pool, lengths, tables, layer=0, *,
         where i - j < window; 0 = every key. Blocks wholly behind the
         first query's bound are never fetched, so the table's entries
         for them may be anything (their pages given back).
+      latent_dv: static int > 0 for a LATENT pool (latent attention read
+        in the absorbed form): `k_pool` is (L, num_pages, 1, Dl, page_size),
+        one entry of Dl values a token that every query head reads as
+        its key, and the values are the keys' first `latent_dv` rows, so
+        `v_pool` is None and a page is fetched once. q is (B, W, H, Dl),
+        the result (B, W, H, latent_dv). Always the grid kernel, whose
+        second axis then runs over tiles of `latent_tile` of the W * H
+        query rows (a chunk's rows do not fit VMEM at once).
 
     Returns (B, W, H, Dh) in q.dtype. Equivalent to gathering each slot's
     pages into a contiguous cache and running
@@ -387,6 +415,19 @@ def paged_attention(q, k_pool, v_pool, lengths, tables, layer=0, *,
     npages = max(1, min(pages_per_block, tables.shape[1]))
     if widths is None:
         widths = jnp.full((b,), w, jnp.int32)
+
+    if latent_dv:
+        if kh != 1 or v_pool is not None or int8_kv or window:
+            raise ValueError("a latent pool has one entry a token and no "
+                             "value pool, no scales and no window")
+        # (B, W, H, Dl) is already rows of the one key head, query-major;
+        # cut into tiles, which take the place of the grid's head axis
+        tile = w * h if (w * h) % latent_tile else min(latent_tile, w * h)
+        out = _paged_attention_latent(
+            q.reshape(b, w * h // tile, tile, d), k_pool, lengths, tables,
+            widths, layer, scale=scale, npages=npages, interpret=interpret,
+            w=w, g=g, dv=int(latent_dv))
+        return out.reshape(b, w, h, latent_dv)
 
     # fold (W, G) query rows per kv head: (B, W, KH, G, Dh) -> (B, KH, WG, Dh)
     qg = q.reshape(b, w, kh, g, d).transpose(0, 2, 1, 3, 4).reshape(
@@ -512,6 +553,46 @@ def _paged_attention_wide(qg, k_pool, v_pool, lengths, tables, widths,
       jnp.asarray(layer, jnp.int32).reshape(1), *inputs)
 
 
+@functools.partial(jax.jit, static_argnames=(
+    "scale", "npages", "interpret", "w", "g", "dv"))
+def _paged_attention_latent(qg, pool, lengths, tables, widths, layer, *,
+                            scale, npages, interpret, w, g, dv):
+    """`_paged_attention_wide` over a latent pool (L, P, 1, Dl, ps): the
+    same kernel, its grid over (slot, tile of query rows), one fetch a
+    page, the values the keys' first `dv` rows. qg: (B, tiles, tile, Dl)
+    -> (B, tiles, tile, dv). Jitted with `layer` an operand, as the wide
+    dispatch is: a program's 8 attention blocks share one trace a shape."""
+    b, tiles, tile, d = qg.shape
+    ps = pool.shape[-1]
+    nbuf = _WIDE_BUFFERS
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,
+        grid=(b, tiles),
+        in_specs=[
+            pl.BlockSpec((1, 1, tile, d), lambda bi, ti, *_: (bi, ti, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((1, 1, tile, dv),
+                               lambda bi, ti, *_: (bi, ti, 0, 0)),
+        scratch_shapes=[pltpu.VMEM((nbuf, npages, d, ps), pool.dtype),
+                        pltpu.SemaphoreType.DMA((nbuf, npages)),
+                        pltpu.SMEM((5,), jnp.int32)],
+    )
+    kernel = functools.partial(
+        _paged_attention_wide_kernel, scale=float(scale), w=w, g=g, ps=ps,
+        npages=npages, int8_kv=False, window=0, latent_dv=dv)
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, tiles, tile, dv), qg.dtype),
+        interpret=interpret,
+        name="paged_attention_latent",
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
+    )(lengths.astype(jnp.int32), tables.astype(jnp.int32),
+      widths.astype(jnp.int32),
+      jnp.asarray(layer, jnp.int32).reshape(1), qg, pool)
+
+
 def _paged_attention_wide_kernel(
     # scalar prefetch
     lens_ref,          # (B,) i32 — kv length per slot INCLUDING the window
@@ -521,8 +602,7 @@ def _paged_attention_wide_kernel(
     # inputs
     q_ref,             # (1, 1, WG, Dh) VMEM — this (slot, kv head)'s rows
     k_pool_ref,        # (L, P, KH, Dh, ps) HBM (ANY)
-    v_pool_ref,        # (L, P, KH, Dh, ps) HBM (ANY)
-    *refs,             # [k_scale_pool, v_scale_pool,] o_ref, scratch...
+    *refs,             # [v_pool, [k_scale_pool, v_scale_pool,]] o_ref, ...
     scale: float,
     w: int,
     g: int,
@@ -530,6 +610,7 @@ def _paged_attention_wide_kernel(
     npages: int,
     int8_kv: bool,
     window: int,
+    latent_dv: int = 0,
 ):
     """Grid variant: one cell per (slot, kv head) instead of a
     whole-batch unroll. It serves the prefill chunks (W > 32) and every
@@ -568,13 +649,23 @@ def _paged_attention_wide_kernel(
     first cell therefore zeroes the value buffers (and the value scales)
     once, after which they only ever hold zeros or pages some row named
     inside its bound.
+
+    Over a latent pool (`latent_dv`, `_paged_attention_latent`) the pool
+    has one "head", the entry every query head reads, and the grid's
+    second axis runs over tiles of the slot's W * G query rows: cell
+    (b, t) holds rows [t * tile, (t + 1) * tile), query-major, and reads
+    the slot's pages as any cell does. There is no value pool: a page's
+    values are its keys' first `latent_dv` rows, fetched once with them,
+    so it is the key buffers that the first cell zeroes.
     """
-    if int8_kv:
-        (ks_pool_ref, vs_pool_ref, o_ref,
+    v_pool_ref = vbuf = ks_pool_ref = vs_pool_ref = ksbuf = vsbuf = None
+    if latent_dv:
+        o_ref, kbuf, sems, chain = refs
+    elif int8_kv:
+        (v_pool_ref, ks_pool_ref, vs_pool_ref, o_ref,
          kbuf, vbuf, ksbuf, vsbuf, sems, chain) = refs
     else:
-        o_ref, kbuf, vbuf, sems, chain = refs
-        ks_pool_ref = vs_pool_ref = ksbuf = vsbuf = None
+        v_pool_ref, o_ref, kbuf, vbuf, sems, chain = refs
     nbuf = kbuf.shape[0]  # a power of two: a slot is a counter's low bits
     ahead = nbuf - 1  # blocks in flight while one computes
     b = pl.program_id(0)
@@ -612,6 +703,9 @@ def _paged_attention_wide_kernel(
         slices here (the narrow kernel fetches whole pages; a cell only
         needs its head)."""
         sem = sems.at[buf_idx, i]
+        if latent_dv:  # one entry a token, whichever tile the cell is
+            return [pltpu.make_async_copy(
+                k_pool_ref.at[layer, page, 0], kbuf.at[buf_idx, i], sem)]
         cs = [pltpu.make_async_copy(
                   k_pool_ref.at[layer, page, head], kbuf.at[buf_idx, i], sem),
               pltpu.make_async_copy(
@@ -675,7 +769,10 @@ def _paged_attention_wide_kernel(
 
     @pl.when(jnp.logical_and(b == 0, h == 0))
     def _():
-        vbuf[...] = jnp.zeros(vbuf.shape, vbuf.dtype)
+        if latent_dv:
+            kbuf[...] = jnp.zeros(kbuf.shape, kbuf.dtype)
+        else:
+            vbuf[...] = jnp.zeros(vbuf.shape, vbuf.dtype)
         if int8_kv:
             vsbuf[...] = jnp.zeros(vsbuf.shape, vsbuf.dtype)
         # the grid's first `ahead` blocks are fetched here; every later
@@ -696,8 +793,10 @@ def _paged_attention_wide_kernel(
     # flight: both run on from cell to cell
     slot0 = chain[0]
     newest0 = (chain[1], chain[2], chain[3], chain[4] != 0)
-    row_pos = (kv_len - widths_ref[b]) + lax.broadcasted_iota(
-        jnp.int32, (wg, blk), 0) // g
+    row = lax.broadcasted_iota(jnp.int32, (wg, blk), 0)
+    if latent_dv:  # the cell's tile of the slot's rows
+        row = row + h * wg
+    row_pos = (kv_len - widths_ref[b]) + row // g
     qh = q_ref[0, 0].astype(dot_dtype)  # (WG, Dh)
 
     def body(i, carry):
@@ -717,7 +816,9 @@ def _paged_attention_wide_kernel(
         if window:
             mask = jnp.logical_and(mask, row_pos - col_pos < window)
         pages = [
-            (lambda p=p: kbuf[buf_idx, p], lambda p=p: vbuf[buf_idx, p],
+            (lambda p=p: kbuf[buf_idx, p],
+             (lambda p=p: kbuf[buf_idx, p, :latent_dv]) if latent_dv
+             else (lambda p=p: vbuf[buf_idx, p]),
              (lambda p=p: ksbuf[buf_idx, p]) if int8_kv else None,
              (lambda p=p: vsbuf[buf_idx, p]) if int8_kv else None)
             for p in range(npages)]
@@ -726,7 +827,7 @@ def _paged_attention_wide_kernel(
 
     m0 = jnp.full((wg, 1), NEG_INF, jnp.float32)
     l0 = jnp.zeros((wg, 1), jnp.float32)
-    a0 = jnp.zeros((wg, d), jnp.float32)
+    a0 = jnp.zeros((wg, latent_dv or d), jnp.float32)
     out = lax.fori_loop(first, n_blocks, body, newest0 + (m0, l0, a0))
     chain[0] = (slot0 + (n_blocks - first)) & (nbuf - 1)
     for k in range(4):
@@ -814,7 +915,7 @@ def gather_scale_pages(scale_pool, tables, layer=0):
 
 def paged_attention_xla(q, k_pool, v_pool, lengths, tables, layer=0, *,
                         scale=None, k_scale_pool=None, v_scale_pool=None,
-                        widths=None, window: int = 0):
+                        widths=None, window: int = 0, latent_dv: int = 0):
     """Dense-XLA equivalent of `paged_attention` (gather + masked attention).
 
     The test oracle, and the serving fallback on non-TPU backends. The
@@ -828,7 +929,9 @@ def paged_attention_xla(q, k_pool, v_pool, lengths, tables, layer=0, *,
 
     b, w, _, _ = q.shape
     k = gather_pages(k_pool, tables, layer)
-    v = gather_pages(v_pool, tables, layer)
+    # a latent pool: the values are the keys' first `latent_dv` entries
+    v = (k[..., :latent_dv] if latent_dv
+         else gather_pages(v_pool, tables, layer))
     scales = {}
     if k_scale_pool is not None:
         scales = dict(k_scale=gather_scale_pages(k_scale_pool, tables, layer),
