@@ -1,8 +1,11 @@
 """The parts of one launch of the overlapped scheduler, on the host's clock.
 
-Between a read-back (`commit`) and the next program the device has no
-work: `PagedInferenceServer._launch_plan` is serialized by construction
-(PERF.md section 5). This script takes that phase apart at a cell's
+Where a launch has to follow the read-back (`commit`) of the program
+before it, the device has no work between the two and
+`PagedInferenceServer._launch_plan` is serialized by construction
+(PERF.md section 5); in the steady state it goes ahead of that commit
+and costs the host's loop, not the device (PR 46), and what it costs is
+the same. This script takes that phase apart at a cell's
 dispatch shapes: decode rows (`--rows`), pages a row (`--pages-per-row`)
 and the argument structure of a configuration's family (`--config`), at
 the family's tiny widths: what the host pays for a launch follows from

@@ -27,9 +27,16 @@ boundaries the scheduler already crosses):
                 results, up to the next phase's first statement. On the
                 sequential paths the delivery of those tokens too; an
                 overlapped step wakes nobody here
-    launch      (async scheduler only) the ledger patch + next-
-                dispatch launch that follows the commit — the tail of
-                the serialized critical path when overlap is on
+    launch      (async scheduler only) the patch + next-dispatch
+                launch. In the steady state it comes BEFORE `device`:
+                the next program goes onto the device's queue while the
+                one in flight still runs, from the planned frame, and
+                `device`, `commit` and the rest follow under it (the
+                record of the dispatch says so: `launch_ahead`). Where
+                the launch needs what only the commit knows
+                (`launch_waits`: draft tokens, a constrained row, a
+                hand-off) it follows the commit, patched from the
+                ledger: the tail of the serialized critical path
     deliver     (async scheduler only) the commit's stream calls and
                 completions, run after the launch: the streaming
                 threads they wake take the interpreter lock under the
@@ -50,9 +57,15 @@ executes the next one's, so they are no longer device-idle time.
 Those phases fold into `overlap_ms` (and the single
 `overlap`-labeled histogram series), `device` becomes the RESIDUAL
 wait after the overlapped host work, and `host_gap_frac` measures
-only the serialized host tail (`commit` + `launch` + `epilogue`) —
-the residual cost the overlap could not hide. The per-record identity
-becomes `host_ms + device_wait_ms + overlap_ms == duration_ms`.
+only the host tail (`commit` + `launch` + `epilogue`) — the residual
+cost the overlap could not hide where the launch waits for the commit.
+In a step that launched ahead that tail is the same work and the same
+`host_ms`, but it too runs beside a program (`launch` under the one in
+flight, `commit` and `epilogue` under the one just queued): there
+`host_gap_frac` is the tail's share of the step, an upper bound on the
+idle share, and whether the device stood idle at all is the record's
+`host_late`. The per-record identity becomes
+`host_ms + device_wait_ms + overlap_ms == duration_ms`.
 
 Outside that identity, and in no phase: `between_ms`, from a busy
 step's `end()` to the next step's `begin()` (the loop's yield to the
@@ -150,7 +163,8 @@ _ITER_ARG_KEYS = ("iteration", "scheduler", "n_live", "decode_rounds",
                   "host_gap_frac", "preemptions", "pending", "n_jobs",
                   "overlap", "overlap_ms", "overlap_launch_lead_ms",
                   "delivered", "joined", "grouped", "launch_h2d",
-                  "between_ms", "stage_ms", "plan_h2d", "host_late")
+                  "between_ms", "stage_ms", "plan_h2d", "host_late",
+                  "launch_ahead", "launch_waits")
 
 
 class IterationProfiler:

@@ -73,16 +73,28 @@ What the paged design buys over one contiguous cache row a slot:
     plus the in-flight dispatch's deterministic effects (job cursors
     advance by the takes it was launched with; planned lengths use
     the worst-case rounds*window bound) WHILE the device executes
-    iteration N; then it pays the one sanctioned `device_get` commit,
-    patches the handful of data-dependent inputs (row lengths / last
-    tokens / the live mask, re-read from the just-committed ledger),
-    and launches N+1. Only the commit + patch + launch tail stays on
-    the serialized critical path — `host_gap_frac` in the flight
-    records measures exactly that residual. Write-safety: while a
-    dispatch is in flight the planner NEVER releases pages (no
+    iteration N; then it LAUNCHES N+1 onto the device's queue behind N
+    — lengths, live flags and table rows from that planned frame, which
+    is exact while no draft tokens are in play, and each decode row's
+    last token from the per-slot copy every step program leaves on the
+    device (`state["last"]`; the patch says row by row which) — and
+    only then pays the one sanctioned `device_get` commit of N, under
+    N+1. The chip passes from one program to the next with no host in
+    between, and nothing of the host's loop is serialized against it
+    while the loop is shorter than the program. Where the launch needs
+    what only the commit knows (draft tokens, a constrained row, a
+    hand-off to prefetch: `_launch_waits`, read per iteration from the
+    plan) the step takes the older order for that iteration — commit,
+    patch from the just-committed ledger, launch — and `host_gap_frac`
+    in the flight records measures that residual tail. Write-safety:
+    while a dispatch is in flight the PLANNER never releases pages (no
     preemption, no slot teardown — sweep reaps are deferred to just
     after the commit), statically enforced by the dispatch-discipline
-    pass's DD5 rule; on page famine the plan degrades its round count
+    pass's DD5 rule. A COMMIT does release the pages of the rows that
+    end in it, while the dispatch launched ahead may still write them:
+    every later writer of a page takes the pools from `self.state`,
+    that dispatch's output, and so is ordered behind it
+    (`_launch_plan`). On page famine the plan degrades its round count
     and the pipeline drains so the next sequential iteration can run
     the full preemption escalation. Greedy and seeded outputs are
     token-for-token identical with overlap on or off (scheduling is
@@ -93,7 +105,9 @@ What the paged design buys over one contiguous cache row a slot:
 Scheduling state is HOST-authoritative (tables, lengths, active,
 last_token live in numpy and ride into each dispatch as small inputs);
 the device owns only the big buffers (page pools + per-slot token
-history), donated through every dispatch. One device_get per scheduler
+history) and a copy of each slot's last token, which a launch made
+ahead of a commit reads in place of the host's, all donated through
+every dispatch. One device_get per scheduler
 iteration, amortised over `decode_chunk` (speculative) rounds
 (multi-token scheduling).
 
@@ -274,12 +288,21 @@ def _split_cache(cache):
 # it apart with static slices and no layout is handed over beside it.
 _PATCH_HEAD = 4
 
+# The live flag of a patch row says besides where the row's last token
+# is: in the patch (a row the host knows: every row of a launch that
+# followed its commit), or in the per-slot copy the programs keep on
+# the device (`state["last"]`: a row of a launch made AHEAD of the
+# commit that will bring that token home).
+_ROW_DEAD, _ROW_LIVE, _ROW_LAST_ON_DEVICE = 0, 1, 2
+
 
 def _pack_patch(count: int, lengths, last_token, live,
                 tables) -> np.ndarray:
     """The patch of one dispatch, in a buffer of its own: the transfer
     is asynchronous and may read (on the CPU backend, alias) the host
-    memory after the call returns, so nothing writes it again."""
+    memory after the call returns, so nothing writes it again. `live`
+    is a row's flag, bools or the `_ROW_*` codes: a row at
+    `_ROW_LAST_ON_DEVICE` is live and `last_token` says nothing of it."""
     buf = np.empty((tables.shape[0], _PATCH_HEAD + tables.shape[1]),
                    np.int32)
     buf[:, 0] = lengths
@@ -291,15 +314,45 @@ def _pack_patch(count: int, lengths, last_token, live,
 
 
 @jax.named_scope("decode_rounds")
-def _unpack_patch(patch, rng):
+def _unpack_patch(patch, rng, kept=None, slot_ids=None):
     """(lengths, tables, last_token, live, key) inside a program. The
     key is the server's one key with the dispatch's count folded in: the
     nth dispatch draws from n on every scheduler path, the count is an
     operand like any other (no compile follows it), and no program but
     the step's own runs to make a key. The slices are the decode half's
-    inputs and lie under its scope in a device trace."""
-    return (patch[:, 0], patch[:, _PATCH_HEAD:], patch[:, 1],
-            patch[:, 2] != 0, jax.random.fold_in(rng, patch[0, 3]))
+    inputs and lie under its scope in a device trace.
+
+    `kept` is the per-slot last tokens the program before this one left
+    on the device (`state["last"]`), `slot_ids` the rows' slots (None:
+    rows are slots): a row flagged `_ROW_LAST_ON_DEVICE` takes its last
+    token from there, every other row from the patch."""
+    last = patch[:, 1]
+    if kept is not None:
+        rows = (kept if slot_ids is None
+                else kept[jnp.clip(slot_ids, 0, kept.shape[0] - 1)])
+        last = jnp.where(patch[:, 2] == _ROW_LAST_ON_DEVICE, rows, last)
+    return (patch[:, 0], patch[:, _PATCH_HEAD:], last,
+            patch[:, 2] != _ROW_DEAD, jax.random.fold_in(rng, patch[0, 3]))
+
+
+def _assign_out(state):
+    """The pools' running assignment counts as the program leaves them
+    (`PagedKVCache.assign`), as an output of their own: the state's leaf
+    is donated to the next program, and a launch made ahead dispatches
+    that one before these are read back. None for a model without a
+    routed share."""
+    assign = state["pools"].get("assign")
+    return None if assign is None else jnp.copy(assign)
+
+
+def _keep_last(new_state, state, slots, mask, tokens):
+    """Leave `tokens` in the per-slot last tokens of `new_state` for the
+    rows under `mask` (rows' `slots`; a padding row's sentinel drops):
+    what the next program reads where its launch did not wait for the
+    host to learn them (`_unpack_patch`)."""
+    kept = state["last"]
+    new_state["last"] = kept.at[
+        jnp.where(mask, slots, kept.shape[0])].set(tokens, mode="drop")
 
 
 # The scopes below name the two halves of every step program in a
@@ -450,6 +503,10 @@ def _prefill_core(params, state, chunk, g_lens, g_tables, sample_at,
         hist = hist.at[slot_ids[:, None], cols].set(prompt_rows,
                                                     mode="drop")
     new_state["hist"] = hist
+    # the first token of an admission that completes here is its slot's
+    # last token: the host writes it into its ledger at the commit
+    # (`_complete_admission_chunks`), the device keeps it from now
+    _keep_last(new_state, state, slot_ids, count_mask, toks)
     return new_state, toks, lps
 
 
@@ -574,6 +631,7 @@ def _decode_plain_core(params, state, lengths, tables, last_token, live,
     new_state["hist"] = hist
     _scatter_slot_state(new_state, slot_ids, sids, oc, gstate,
                         full_oc, full_gstate)
+    _keep_last(new_state, state, sids, live, last)
     return new_state, lengths, last, out
 
 
@@ -587,13 +645,17 @@ def _decode_rounds(params, state, patch, rng, samp_rows, gid=None,
                    mesh=None, use_rows: bool = False,
                    use_bias: bool = False):
     """`_decode_plain_core` as a program of its own, fed the packed
-    patch and the server's key (`_unpack_patch`)."""
-    lengths, tables, last_token, live, key = _unpack_patch(patch, rng)
-    return _decode_plain_core(
+    patch and the server's key (`_unpack_patch`; a row's last token from
+    the patch or from `state["last"]`, as the patch says), with
+    `_assign_out` behind the core's results."""
+    lengths, tables, last_token, live, key = _unpack_patch(
+        patch, rng, state["last"], slot_ids)
+    state, lengths, last, out = _decode_plain_core(
         params, state, lengths, tables, last_token, live, key, samp_rows,
         gid, grammar, lora, aid, slot_ids,
         cfg=cfg, infer_cfg=infer_cfg, n_rounds=n_rounds, mesh=mesh,
         use_rows=use_rows, use_bias=use_bias)
+    return state, lengths, last, out, _assign_out(state)
 
 
 @jax.named_scope("decode_rounds")
@@ -835,6 +897,7 @@ def _spec_core(params, state, lengths, tables, last_token, live,
     new_state["hist"] = hist
     _scatter_slot_state(new_state, slot_ids, sids, oc, gstate,
                         full_oc, full_gstate)
+    _keep_last(new_state, state, sids, live, last)
     if dpools is not None:
         new_state["draft_pools"] = dpools
     return new_state, lengths, last, out
@@ -851,14 +914,16 @@ def _spec_rounds(params, state, patch, stop_len, rng, samp_rows, gid=None,
                  n_drafts: int, mesh=None, draft_cfg=None,
                  use_rows: bool = False, use_bias: bool = False):
     """`_spec_core` as a program of its own, fed like `_decode_rounds`."""
-    lengths, tables, last_token, live, key = _unpack_patch(patch, rng)
-    return _spec_core(
+    lengths, tables, last_token, live, key = _unpack_patch(
+        patch, rng, state["last"], slot_ids)
+    state, lengths, last, out = _spec_core(
         params, state, lengths, tables, last_token, live, stop_len, key,
         samp_rows, gid, grammar, lora, aid, draft_params, slot_ids,
         draft_limit,
         cfg=cfg, infer_cfg=infer_cfg, n_rounds=n_rounds,
         n_drafts=n_drafts, mesh=mesh, draft_cfg=draft_cfg,
         use_rows=use_rows, use_bias=use_bias)
+    return state, lengths, last, out, _assign_out(state)
 
 
 def _walks_once(cfg: ModelConfig, n_tokens: int, n_rounds: int,
@@ -971,11 +1036,14 @@ def _mixed_step(params, state,
     decode half) — the sentinel-safety invariant for mid-admission rows.
 
     Returns (state', first-token candidates (G,), their logprobs (G,),
-    lengths', last', (toks (R, B, S), lps (R, B, S), counts (R, B)))
-    with S = n_drafts + 1; n_rounds == 0 (no live decode slot) skips the
-    decode half and returns R = 0 outputs.
+    lengths', last', (toks (R, B, S), lps (R, B, S), counts (R, B)),
+    `_assign_out`) with S = n_drafts + 1; n_rounds == 0 (no live decode
+    slot) skips the decode half and returns R = 0 outputs. Each slot's
+    last token stays in `state'["last"]` besides: a decode row's `last'`,
+    and the first token of an admission the step completes.
     """
-    lengths, tables, last_token, live, key = _unpack_patch(patch, rng)
+    lengths, tables, last_token, live, key = _unpack_patch(
+        patch, rng, state["last"], slot_ids_d)
     rng_p, rng_d = jax.random.split(key)
     plogits = dlogits = None
     if _walks_once(cfg, chunk.size + lengths.size, n_rounds, n_drafts,
@@ -1003,7 +1071,8 @@ def _mixed_step(params, state,
         out = (jnp.zeros((0, b, s), jnp.int32),
                jnp.zeros((0, b, s), jnp.float32),
                jnp.zeros((0, b), jnp.int32))
-        return state, ptoks, plps, lengths, last_token, out
+        return (state, ptoks, plps, lengths, last_token, out,
+                _assign_out(state))
     if n_drafts > 0:
         state, lengths, last, out = _spec_core(
             params, state, lengths, tables, last_token, live, stop_len,
@@ -1019,7 +1088,7 @@ def _mixed_step(params, state,
             cfg=cfg, infer_cfg=infer_cfg, n_rounds=n_rounds, mesh=mesh,
             use_rows=use_rows_d, use_bias=use_bias_d)
         out = (dtoks[:, :, None], dlps[:, :, None], dcnts)
-    return state, ptoks, plps, lengths, last, out
+    return state, ptoks, plps, lengths, last, out, _assign_out(state)
 
 
 # ---------------------------------------------------------------------------
@@ -1071,10 +1140,11 @@ class _Plan:
     """An immutable-by-convention PLANNED iteration (async scheduler):
     everything the launch needs, built against the planned frame while
     the previous dispatch runs. The only fields `_launch_plan` rewrites
-    post-commit are the data-dependent decode inputs (d_lens / d_last /
-    d_tables / live_g — a handful of (rows,) gathers from the
-    just-committed ledger); every policy decision and every other
-    array is frozen here."""
+    are the data-dependent decode inputs (d_lens / d_last / d_tables /
+    live_g — a handful of (rows,) gathers): from the planned `frame`
+    where the launch goes ahead of the commit (`waits` is None), from
+    the just-committed ledger where it has to wait for it; every policy
+    decision and every other array is frozen here."""
 
     kind: str                       # "mixed" | "decode"
     sel: list                       # [(job, take, d0)] — empty for decode
@@ -1108,6 +1178,13 @@ class _Plan:
     # `_plan_iteration` with the other launch-stable arrays
     sl_dev: object = None
     lim_dev: object = None
+    # why this launch has to follow the commit of the dispatch in flight
+    # when it was planned (`_launch_waits`: "fill", "drafts", "grammar",
+    # "handoff"); None: it goes ahead of that commit, from `frame`, the
+    # per-slot (lengths, live, last token on the device) that dispatch
+    # leaves, which the planned frame knows exactly
+    waits: "str | None" = "fill"
+    frame: "tuple | None" = None
 
 
 @dataclasses.dataclass
@@ -1313,6 +1390,9 @@ class PagedInferenceServer:
             # per-slot grammar DFA state (constrained decoding); slots
             # without a grammar sit at state 0 of the identity grammar
             "gstate": jnp.zeros((max_slots,), jnp.int32),
+            # per-slot last token, as the newest program left it: what
+            # a launch made ahead of a commit reads (`_unpack_patch`)
+            "last": jnp.zeros((max_slots,), jnp.int32),
         }
         if draft_cfg is not None:
             dcache = paged_engine.init_paged_cache(
@@ -1624,6 +1704,10 @@ class PagedInferenceServer:
         self.overlap = bool(ov)
         self._overlap_enabled = self.overlap and self._mixed_enabled
         self._inflight: _Inflight | None = None
+        # the dispatch launched AHEAD of `_inflight`'s commit, inside
+        # one steady step: `_commit_inflight` moves it up, so outside a
+        # step at most one dispatch is uncommitted, as ever
+        self._ahead: _Inflight | None = None
         # deferred sweep reaps: (slot_id, _Slot, reason) marked while a
         # dispatch is in flight; released right after its commit
         self._reaped: list[tuple[int, _Slot, str]] = []
@@ -2173,13 +2257,6 @@ class PagedInferenceServer:
         ks["keys_window"] = ks.get("keys_window", 0) + win
         if decode:
             ks["keys_window_decode"] = ks.get("keys_window_decode", 0) + win
-
-    def _assign_future(self) -> tuple:
-        """The pools' running assignment counts as the step just launched
-        leaves them (`PagedKVCache.assign`), to be read back with its
-        results; nothing for a model without a routed share."""
-        return (() if self._assign_seen is None
-                else (self.state["pools"]["assign"],))
 
     def _count_assign(self, stats: dict, assign=None) -> None:
         """The flight record's `assign_held`, `assign_zero` and
@@ -2903,7 +2980,7 @@ class PagedInferenceServer:
         if g_iter > 0:
             lim_dev = (None if spec_lens is None else jnp.asarray(
                 self._pad_limits(spec_lens, int(live_g.shape[0]))))
-            self.state, lens, last, (toks, lps, counts) = _spec_rounds(
+            self.state, lens, last, (toks, lps, counts), _ = _spec_rounds(
                 self.params, self.state, patch,
                 jnp.asarray(stop), self._rng, samp,
                 gid, grammar, lora, aid,
@@ -2917,16 +2994,17 @@ class PagedInferenceServer:
             toks, lps, counts, lens, last = jax.device_get(
                 (toks, lps, counts, lens, last))
         else:
-            self.state, lens, last, (toks, lps, counts) = _decode_rounds(
+            (self.state, lens, last, (toks, lps, counts),
+             assign) = _decode_rounds(
                 self.params, self.state, patch, self._rng, samp,
                 gid, grammar, lora, aid, sl_dev,
                 cfg=self.cfg, infer_cfg=self.infer_cfg, n_rounds=n,
                 mesh=self.mesh, use_rows=use_rows, use_bias=use_bias)
             # analysis: allow[lock-discipline] THE sanctioned
             # per-iteration host sync under _step_lock (plain arm)
-            toks, lps, counts, lens, last, *assign = jax.device_get(
-                (toks, lps, counts, lens, last, *self._assign_future()))
-            self._count_assign(self._iter_stats, *assign)
+            toks, lps, counts, lens, last, assign = jax.device_get(
+                (toks, lps, counts, lens, last, assign))
+            self._count_assign(self._iter_stats, assign)
             toks, lps = toks[:, :, None], lps[:, :, None]
             if self.spec_drafts > 0 and self.spec_control is not None:
                 # every live slot decoded plainly: draft-model caches
@@ -3379,7 +3457,8 @@ class PagedInferenceServer:
         lora = self.adapters.device_args() if use_lora else None
         self._stage_program_kind(self._iter_stats, pf["chunk"].size,
                                  live_g.size, n_rounds, g_iter, lora)
-        self.state, ptoks, plps, lens, last, (toks, lps, counts) = \
+        (self.state, ptoks, plps, lens, last, (toks, lps, counts),
+         assign) = \
             _mixed_step(
                 self.params, self.state, jnp.asarray(pf["chunk"]),
                 jnp.asarray(pf["widths"]), jnp.asarray(pf["g_lens"]),
@@ -3412,10 +3491,9 @@ class PagedInferenceServer:
         # analysis: allow[lock-discipline] THE sanctioned per-iteration
         # host sync — one fused dispatch, one device_get, under the
         # step lock that serializes the scheduler by design
-        ptoks, plps, toks, lps, counts, lens, last, *assign = jax.device_get(
-            (ptoks, plps, toks, lps, counts, lens, last,
-             *self._assign_future()))
-        self._count_assign(self._iter_stats, *assign)
+        ptoks, plps, toks, lps, counts, lens, last, assign = jax.device_get(
+            (ptoks, plps, toks, lps, counts, lens, last, assign))
+        self._count_assign(self._iter_stats, assign)
         if prof is not None:
             prof.enter("commit")
 
@@ -3517,6 +3595,9 @@ class PagedInferenceServer:
         # --- the planned frame --------------------------------------------
         planned_active = self.active.copy()
         planned_len = self.lengths.copy()
+        # slots whose last token the in-flight dispatch makes: the host
+        # learns it at the commit, the device has it before
+        made = np.zeros((b,), bool)
         if infl is not None:
             if infl.n_rounds > 0:
                 for i, sid_ in enumerate(infl.live_ids):
@@ -3527,11 +3608,13 @@ class PagedInferenceServer:
                             int(planned_len[sid])
                             + infl.n_rounds * infl.win,
                             int(self.stop_len[sid]) + self.window)
+                        made[sid] = True
             for sid in infl.activating:
                 slot = self._slots[sid]
                 if slot is not None:
                     planned_active[sid] = True
                     planned_len[sid] = len(slot.prompt)
+                    made[sid] = True
         jobs = [j for j in self._jobs if j.planned < int(j.rem_lens[0])]
         if not jobs and not planned_active.any():
             return None
@@ -3701,7 +3784,53 @@ class PagedInferenceServer:
         self._iter_plan_h2d = self._h2d - h2d0
         if prof is not None:
             self._iter_stage_ms = (prof.lap() - t_stage) * 1e3
+        plan.waits = self._launch_waits(plan, infl)
+        stats["launch_ahead"] = plan.waits is None
+        if plan.waits is None:
+            # with no drafts in play a live row advances one token a
+            # round, so the planned lengths are the lengths; a row they
+            # put at its token limit ends at the commit and rides dead
+            live = planned_active & (planned_len < self.stop_len - 1)
+            if plan.kind == "decode" and not live.any():
+                return None  # every row ends at the commit: drain
+            plan.frame = (planned_len, live, made)
+        else:
+            stats["launch_waits"] = plan.waits
         return plan
+
+    def _launch_waits(self, plan: "_Plan", infl) -> "str | None":
+        """Why the launch of `plan` has to follow the commit of `infl`,
+        the dispatch in flight when it was planned, or None: it may go
+        onto the device's queue ahead of that commit. Read from what the
+        plan shows, for this iteration alone (no option chooses):
+
+          "fill"     nothing is in flight: the launch primes the
+                     pipeline behind a sequential iteration;
+          "drafts"   either dispatch runs speculative rounds: how far a
+                     row advances is known at the commit, not before;
+          "grammar"  a constrained row among the plan's: the order such
+                     rows were built and tested under (the DFA state is
+                     the device's, the resume state the host's);
+          "handoff"  an admission the plan completes carries a
+                     disaggregation hand-off: `_handoff_prefetch` copies
+                     the pages its earlier chunks committed.
+
+        Everything else a launch reads is in the planned frame
+        (`_Plan.frame`) or staged already: lengths, live flags and table
+        rows exactly, and each row's last token on the device where the
+        in-flight dispatch makes it."""
+        if infl is None:
+            return "fill"
+        if infl.g_iter > 0 or plan.g_iter > 0:
+            return "drafts"
+        if plan.use_grammar:
+            return "grammar"
+        for job, take, d0 in plan.sel:
+            if d0 + take >= int(job.rem_lens[0]) and getattr(
+                    self._slots[job.slots[0]].req, "_handoff",
+                    None) is not None:
+                return "handoff"
+        return None
 
     def _stage_program_kind(self, stats: dict, chunk_tokens: int,
                             decode_rows: int, n_rounds: int, n_drafts: int,
@@ -3721,47 +3850,89 @@ class PagedInferenceServer:
             decode_rows * (n_drafts + 1) * (n_rounds > 0))
 
     def _launch_plan(self, plan: "_Plan") -> None:
-        """Patch the plan's data-dependent decode inputs from the
-        just-committed ledger, then launch it ASYNCHRONOUSLY — no
-        device_get here; the sync is the next step's
-        `_commit_inflight`. The device has no work until this returns,
-        so the host crosses to it once and dispatches one program: the
-        patch (lengths / last tokens / live flags / table rows, rows
-        whose slot died at the commit deadened: their sentinel tables
-        drop every device write, and `owners` masks their host commit)
-        goes over as one packed array with the dispatch's count
-        (`_feed_patch`), the program makes its own key from it, and
-        everything else was staged by `_plan_iteration` and is passed
-        through untouched. The flight record's `launch_h2d` counts the
-        host arrays handed over here."""
+        """Fill in the plan's data-dependent decode inputs, then launch
+        it ASYNCHRONOUSLY — no device_get here; the sync is a later
+        `_commit_inflight`. The host crosses to the device once and
+        dispatches one program: the patch (lengths / last tokens / live
+        flags / table rows) goes over as one packed array with the
+        dispatch's count (`_feed_patch`), the program makes its own key
+        from it, and everything else was staged by `_plan_iteration` and
+        is passed through untouched. The flight record's `launch_h2d`
+        counts the host arrays handed over here.
+
+        Where the patch comes from is the plan's (`_Plan.waits`):
+
+        AHEAD of the commit (None; the steady state). The dispatch in
+        flight is still running, or still to be read back, and this one
+        goes onto the device's queue behind it, so the chip passes from
+        one to the other with no host in between. The patch is the
+        planned `frame`: lengths and live flags as that dispatch leaves
+        them, the table rows as `_extend_chains_planned` grew them, and
+        for a row whose last token that dispatch makes the flag
+        `_ROW_LAST_ON_DEVICE` in place of the token. A row that ends at
+        the commit still to come (end token, stop string, cancel or
+        deadline seen after this launch) is live here all the same: it
+        computes, it writes past its committed length into pages its
+        slot held at this launch, and `owners` throws its results away
+        at this dispatch's own commit. THE INVARIANT ON RELEASED PAGES:
+        that commit-to-come may release them while this program has yet
+        to write them, and that is safe because every later writer of a
+        page takes the pools from `self.state`, which from this launch
+        on is THIS program's output: a step program of a plan built
+        later (a new admission, a prefix hit that shares the page), a
+        migration import (`_import_pages`) and anything else that
+        scatters into the pools is ordered behind this program by that
+        data dependence, on the one device queue. A window trim and a
+        slot's release write nothing; they hand pages to such a writer.
+        What this program writes there lies past every length the
+        prefix cache keyed (`_committed`), and is the true entry of the
+        row's last token besides.
+
+        AFTER the commit (a reason: `_launch_waits`). The patch is the
+        just-committed ledger; rows whose slot died at the commit are
+        deadened: their sentinel tables drop every device write, and
+        `owners` masks their host commit."""
         prof = self._profiler
         if prof is not None:
             prof.enter("launch")
         h2d0 = self._h2d
         live_ids = plan.live_ids
         nl = len(live_ids)
+        ahead = plan.waits is None
         if nl and plan.n_rounds > 0:
+            # per slot: the planned frame, or the ledger (dead slots
+            # carry sentinel tables and active=False from _release_slot)
+            if ahead:
+                # a row at its token limit rides dead behind a sentinel
+                # table, as a slot released at the commit does
+                lens, live, made = plan.frame
+                flag = np.where(live, np.where(made, _ROW_LAST_ON_DEVICE,
+                                               _ROW_LIVE), _ROW_DEAD)
+                tables = np.where(live[:, None], self.tables, self._no_page)
+            else:
+                lens, flag, tables = self.lengths, self.active, self.tables
             if plan.sl_d is None:
-                # rows ARE slots: the ledger's arrays are the patched
-                # ones (dead slots already carry sentinel tables and
-                # active=False from _release_slot)
-                plan.live_g = self.active
-                plan.d_lens = self.lengths
-                plan.d_tables = self.tables
+                # rows ARE slots: the per-slot arrays are the patch's
+                plan.live_g, plan.d_lens, plan.d_tables = flag, lens, tables
                 plan.d_last = self.last_token
             else:
-                slots, owners = self._slots, plan.owners
-                plan.live_g[:nl] = self.active[live_ids] & np.fromiter(
-                    (slots[sid] is owners[i]
-                     for i, sid in enumerate(live_ids.tolist())),
-                    bool, nl)
-                plan.d_lens[:nl] = self.lengths[live_ids]
+                rows = flag[live_ids]
+                if not ahead:
+                    slots, owners = self._slots, plan.owners
+                    rows &= np.fromiter(
+                        (slots[sid] is owners[i]
+                         for i, sid in enumerate(live_ids.tolist())),
+                        bool, nl)
+                plan.live_g = np.zeros(plan.live_g.shape, np.int32)
+                plan.live_g[:nl] = rows
+                plan.d_lens[:nl] = lens[live_ids]
                 plan.d_last[:nl] = self.last_token[live_ids]
-                plan.d_tables[:nl] = self.tables[live_ids]
+                plan.d_tables[:nl] = tables[live_ids]
             if plan.kind == "decode" and not plan.live_g[:nl].any():
-                # every planned row died at the commit: nothing left
-                # to dispatch — drain the pipeline instead of paying a
-                # fully-inert program
+                # no planned row lives (each died at the commit, or
+                # stands at its token limit): nothing left to dispatch
+                # — drain the pipeline instead of paying a fully-inert
+                # program
                 return
         # analysis: allow[lock-discipline] atomically-swapped
         # reference, rebuilt under _lock pre-admission
@@ -3772,14 +3943,21 @@ class PagedInferenceServer:
         self._stage_program_kind(
             plan.stats, plan.pf["chunk"].size if plan.kind == "mixed" else 0,
             plan.live_g.size, plan.n_rounds, plan.g_iter, lora)
+        if ahead:
+            # who sets the pace, known here: had the program in flight
+            # finished already, the device stood with nothing queued
+            # until the call below (a query: no wait, no transfer)
+            infl = self._inflight
+            infl.stats["host_late"] = infl.futures[0].is_ready()
         if plan.kind == "mixed":
             pf = plan.pf
-            # disaggregation handoff: the in-flight dispatch committed
-            # before this launch, so the plan's sel cursors equal the
-            # committed ones — start the D2H copies for admissions the
-            # plan completes, before the dispatch donates self.state
+            # disaggregation handoff (such a plan waited for the
+            # commit, `_launch_waits`): the plan's sel cursors equal
+            # the committed ones — start the D2H copies for admissions
+            # the plan completes, before the dispatch donates self.state
             self._handoff_prefetch(plan.sel)
-            self.state, ptoks, plps, lens, last, (toks, lps, counts) = \
+            (self.state, ptoks, plps, lens, last, (toks, lps, counts),
+             assign) = \
                 _mixed_step(
                     self.params, self.state, pf["chunk"], pf["widths"],
                     pf["g_lens"], pf["g_tables"], pf["sample_at"],
@@ -3799,7 +3977,8 @@ class PagedInferenceServer:
                     use_bias_d=plan.use_bias_d)
             futures = (ptoks, plps, toks, lps, counts, lens, last)
         elif plan.g_iter > 0:
-            self.state, lens, last, (toks, lps, counts) = _spec_rounds(
+            (self.state, lens, last, (toks, lps, counts),
+             assign) = _spec_rounds(
                 self.params, self.state, patch, plan.d_stop, self._rng,
                 plan.samp_d, plan.gid_d, grammar, lora, plan.aid_d,
                 self.draft_params, plan.sl_dev, plan.lim_dev,
@@ -3809,44 +3988,63 @@ class PagedInferenceServer:
                 use_rows=plan.use_rows_d, use_bias=plan.use_bias_d)
             futures = (toks, lps, counts, lens, last)
         else:
-            self.state, lens, last, (toks, lps, counts) = _decode_rounds(
+            (self.state, lens, last, (toks, lps, counts),
+             assign) = _decode_rounds(
                 self.params, self.state, patch, self._rng, plan.samp_d,
                 plan.gid_d, grammar, lora, plan.aid_d, plan.sl_dev,
                 cfg=self.cfg, infer_cfg=self.infer_cfg,
                 n_rounds=plan.n_rounds, mesh=self.mesh,
                 use_rows=plan.use_rows_d, use_bias=plan.use_bias_d)
             futures = (toks, lps, counts, lens, last)
-        futures += self._assign_future()
+        futures += (assign,)
         self._iter_launch_h2d = self._h2d - h2d0
-        # the launch's end is the delivery's start: `_step_overlap`
-        # wakes the streaming threads under the program launched here
-        t = (prof.enter("deliver") if prof is not None
-             else time.perf_counter())
+        # the launch's end: the start of the wait for the program
+        # before it where the launch went ahead, else of the delivery
+        # (`_step_overlap` wakes the streaming threads under the
+        # program launched here)
+        t = (prof.enter("device" if ahead else "deliver")
+             if prof is not None else time.perf_counter())
         self._iter_launch_ts = t
-        self._inflight = _Inflight(
+        launched = _Inflight(
             kind=plan.kind, futures=futures, sel=plan.sel,
             activating=plan.activating, live_ids=live_ids,
             owners=plan.owners, n_rounds=plan.n_rounds, win=plan.win,
             g_iter=plan.g_iter, spec_lens=plan.spec_lens,
             stats=plan.stats, spans=plan.spans, t_launch=t)
+        if ahead:
+            self._ahead = launched
+        else:
+            self._inflight = launched
 
     def _commit_inflight(self) -> None:
-        """Sync and commit the in-flight dispatch: THE serialized
-        critical path of the async scheduler. One device_get brings
-        the sampled tokens home; the ledger writes, the tokens
-        recorded on their requests, activations, speculation feedback,
-        and deferred sweep reaps all run on the synced values —
-        guarded per row by the owners identity captured at plan time
-        (a whole step ran since the launch). Everything the next
-        launch reads is written here; nobody is told: the stream
-        calls and completions wait on the delivery list until that
-        launch is on the device (`_deliver`)."""
-        infl, self._inflight = self._inflight, None
+        """Sync and commit the OLDEST uncommitted dispatch. One
+        device_get brings the sampled tokens home; the ledger writes,
+        the tokens recorded on their requests, activations, speculation
+        feedback, and deferred sweep reaps all run on the synced values
+        — guarded per row by the owners identity captured at plan time
+        (a whole step ran since the launch). Nobody is told: the stream
+        calls and completions wait on the delivery list (`_deliver`).
+
+        In the steady state the next dispatch is on the device's queue
+        already (`_launch_plan`, ahead: `_ahead`), so the device_get
+        waits on a program behind which the chip finds its next, and
+        the read-back, this commit and the delivery run under that one;
+        the dispatch launched ahead moves up to `_inflight` here. Where
+        the launch had to wait (`_launch_waits`) this is, as it was, the
+        serialized critical path: everything that launch reads is
+        written here. Pages a row's end releases here may still be
+        written by the program launched ahead: `_launch_plan` says why
+        that is safe."""
+        infl, self._inflight, self._ahead = self._inflight, self._ahead, None
         prof = self._profiler
+        st = infl.stats
         # who set the pace: the program had already finished when the
-        # host came for its results, so the device stood idle for the
+        # host put the next one behind it (`_launch_plan` asked), or,
+        # where that launch waits for this commit, when the host came
+        # for its results. Either way the device stood idle for the
         # host in this iteration (a query: no wait, no transfer)
-        host_late = infl.futures[0].is_ready()
+        if "host_late" not in st:
+            st["host_late"] = infl.futures[0].is_ready()
         t_wait = (prof.enter("device") if prof is not None
                   else time.perf_counter())
         # analysis: allow[lock-discipline] THE sanctioned per-iteration
@@ -3855,12 +4053,9 @@ class PagedInferenceServer:
         vals = jax.device_get(infl.futures)
         if prof is not None:
             prof.enter("commit")
-        st = infl.stats
-        if self._assign_seen is not None:
-            *vals, assign = vals
-            self._count_assign(st, assign)
+        *vals, assign = vals
+        self._count_assign(st, assign)
         st["overlap"] = True
-        st["host_late"] = host_late
         # how long the device ran ahead of the host needing results:
         # launch -> the moment this step's overlapped work finished
         # and the sync began. Residual device phase > 0 means the
@@ -3971,10 +4166,19 @@ class PagedInferenceServer:
         """One pipelined scheduler iteration (overlap on). With a
         dispatch in flight: plan iteration N+1 (sweep marks, QoS/DRR
         admission, the whole numpy build) WHILE the device runs
-        iteration N, then sync+commit N, patch, launch N+1, and only
-        then deliver N's tokens and completions to their clients — one
-        fused dispatch and one device_get per step, with only the
-        commit/patch/launch tail serialized against the device.
+        iteration N, then LAUNCH N+1 onto the device's queue behind N,
+        sync+commit N, and deliver N's tokens and completions to their
+        clients — one fused dispatch and one device_get per step, and
+        the chip goes from N to N+1 with no host in between: for the
+        length of the commit two dispatches are uncommitted (`_ahead`
+        behind `_inflight`), at plan time one, as the planned frame
+        assumes. The order is the plan's, chosen per iteration from
+        what it shows (`_launch_waits`): where the launch needs what
+        only the commit knows (draft tokens in play, a constrained
+        row, a hand-off to prefetch) the step takes the order it had:
+        commit N, patch from the ledger, launch N+1, deliver. The same
+        two functions either way; each dispatch's flight record says
+        which (`launch_ahead`, else `launch_waits`).
         With nothing in flight (cold start, post-drain, famine): run
         the byte-identical sequential iteration, then PRIME the
         pipeline by planning and launching the next dispatch before
@@ -4037,9 +4241,18 @@ class PagedInferenceServer:
                         self._faults.check("dispatch")
                     plan = self._plan_iteration()
                     try:
-                        self._commit_inflight()
-                        if plan is not None:
-                            self._launch_plan(plan)
+                        if plan is not None and plan.waits is None:
+                            # N+1 onto the device's queue behind N,
+                            # then N home: a launch that raised still
+                            # commits N
+                            try:
+                                self._launch_plan(plan)
+                            finally:
+                                self._commit_inflight()
+                        else:
+                            self._commit_inflight()
+                            if plan is not None:
+                                self._launch_plan(plan)
                     finally:
                         # after the launch, not before: the streaming
                         # threads these calls wake run under the next
@@ -4703,13 +4916,22 @@ class PagedInferenceServer:
 
     def overlap_stats(self) -> dict:
         """The /stats `overlap` block: the async scheduler's resolved
-        knob state and the live pipeline depth. Scrape path only."""
+        knob state, the live pipeline depth and `launch_ahead_share`.
+        Scrape path only."""
+        ahead = [r["launch_ahead"] for r in self.flight.window()
+                 if "launch_ahead" in r]
         return {
             "enabled": self.overlap,
             "active": self._overlap_enabled,
             # analysis: allow[lock-discipline] racy-by-design
             # monitoring read; staleness bounded by one iteration
             "inflight_depth": 0 if self._inflight is None else 1,
+            # of the overlapped dispatches in the flight window, the %
+            # that went onto the device's queue ahead of the commit
+            # before them (the others' records say why not:
+            # `launch_waits`); nothing before the first
+            **({"launch_ahead_share": 100.0 * sum(ahead) / len(ahead)}
+               if ahead else {}),
         }
 
     def brownout_stats(self) -> dict | None:
@@ -5274,7 +5496,7 @@ class PagedInferenceServer:
             # dispatch's futures (its results belong to requests that
             # just failed; like the wedged-teardown case, any still-
             # running device work finishes into buffers nothing reads)
-            self._inflight = None
+            self._inflight = self._ahead = None
             self._reaped.clear()
         finally:
             if got:

@@ -313,6 +313,30 @@ def test_mixed_constrained_and_free_batch(params):
     assert _valid(r"[0-9]+", con.result())
 
 
+def test_a_constrained_row_keeps_the_launch_behind_the_commit(params):
+    """The overlapped scheduler puts a dispatch on the device's queue
+    ahead of the commit before it only where the plan has no constrained
+    row (`_launch_waits`): with one, the dispatch waits, its flight
+    record says why, and the free rows around it go ahead again once
+    the constrained request is gone."""
+    srv = PagedInferenceServer(params, CFG, ICFG, decode_chunk=1, **SRV_KW)
+    assert srv._overlap_enabled
+    free = srv.submit(TOK.encode("hello"), max_new_tokens=24)
+    con = srv.submit(TOK.encode("n:"), max_new_tokens=6,
+                     sampling=SamplingParams(regex=r"[0-9]+"))
+    srv.run_until_idle()
+    assert _valid(r"[0-9]+", con.result()) and free.done
+    ov = [r for r in srv.flight_window() if r.get("overlap")]
+    waits = [r.get("launch_waits") for r in ov]
+    assert "grammar" in waits and None in waits
+    assert set(waits) <= {"fill", "grammar", None}
+    for r in ov:
+        assert r["launch_ahead"] == ("launch_waits" not in r)
+    # while the constrained row lived no dispatch went ahead
+    first_ahead = waits.index(None)
+    assert "grammar" not in waits[first_ahead:]
+
+
 def test_two_patterns_share_server(params):
     srv = PagedInferenceServer(params, CFG, ICFG, **SRV_KW)
     a = srv.submit(TOK.encode("a"), max_new_tokens=10,

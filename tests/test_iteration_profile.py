@@ -333,6 +333,53 @@ def test_records_tile_the_clock_with_between_ms(params):
     assert all("between_ms" in rec for rec in after[1:])
 
 
+def test_launched_ahead_steps_tile_the_clock(params):
+    """A step that puts the next program on the queue before it commits
+    the one in flight crosses the same boundaries in another order
+    (launch, then device): its phases still partition it, and
+    `between_ms + duration_ms` over an unbroken run of such steps is the
+    run's length within 0.1%, as `PERF.md` section 5 reads the period.
+    The Perfetto iteration track carries the dispatch's `launch_ahead`,
+    and `launch_waits` where it waited."""
+    srv = PagedInferenceServer(params, CFG, GREEDY, scheduler="mixed",
+                               decode_chunk=1, **PAGED_KW)
+    reqs = [srv.submit([5 + i, 9, 3], max_new_tokens=30) for i in range(2)]
+    srv.run_until_idle()
+    assert all(r.done for r in reqs)
+    window = srv.flight_window()
+    run = [r for r in window if r.get("launch_ahead")]
+    assert len(run) >= 20
+    i0 = window.index(run[0])
+    assert window[i0:i0 + len(run)] == run          # unbroken
+    for rec in run:
+        assert "between_ms" in rec and "launch_waits" not in rec
+        assert sum(rec["phases_ms"].values()) == pytest.approx(
+            rec["duration_ms"], rel=1e-9, abs=1e-6)
+        assert (rec["host_ms"] + rec["device_wait_ms"]
+                + rec["overlap_ms"]) == pytest.approx(
+            rec["duration_ms"], rel=1e-9, abs=1e-6)
+        assert isinstance(rec["host_late"], bool)
+    # every step of the run but its last launched the next record's
+    # dispatch ahead: `launch` is among its phases, beside `device`
+    assert all({"launch", "device", "commit", "deliver"}
+               <= set(rec["phases_ms"]) for rec in run[:-1])
+    span = (run[-1]["t_start"] + run[-1]["duration_ms"] * 1e-3
+            - run[0]["t_start"] + run[0]["between_ms"] * 1e-3) * 1e3
+    assert sum(r["between_ms"] + r["duration_ms"] for r in run) \
+        == pytest.approx(span, rel=1e-3)
+    waited = [r for r in window if r.get("overlap")
+              and not r["launch_ahead"]]
+    assert waited and {r["launch_waits"] for r in waited} == {"fill"}
+    iters = {e["args"]["iteration"]: e["args"]
+             for e in scheduler_chrome_trace(window)["traceEvents"]
+             if e["ph"] == "X" and e["tid"] == 0}
+    for rec in window:
+        args = iters[rec["iteration"]]
+        for k in ("launch_ahead", "launch_waits"):
+            assert (k in args) == (k in rec)
+            assert args.get(k) == rec.get(k)
+
+
 def test_records_split_build_and_say_who_set_the_pace(params):
     """`stage_ms` and `plan_h2d` sit on the record of the step that
     planned (beside its `build` and `launch_h2d`), `host_late` on the

@@ -126,7 +126,8 @@ def _step_args(cfg, icfg, sampling, penalties):
     cache, g, d = _rows(cfg)
     state = {"pools": ps._split_cache(cache),
              "hist": jnp.zeros((SLOTS, CONTEXT), jnp.int32),
-             "gstate": jnp.zeros((SLOTS,), jnp.int32)}
+             "gstate": jnp.zeros((SLOTS,), jnp.int32),
+             "last": jnp.zeros((SLOTS,), jnp.int32)}
     if penalties:
         state["prompt_mask"] = jnp.zeros((SLOTS, cfg.vocab_size), bool)
         state["out_counts"] = jnp.zeros((SLOTS, cfg.vocab_size), jnp.int32)
@@ -227,7 +228,7 @@ def test_mixed_step_equals_the_two_cores_apart(case):
     got = _mixed(params, jax.tree.map(jnp.copy, state), group, decode,
                  rng, **kw)
     w_state, w_ptoks, w_plps, w_lens, w_last, (w_t, w_lp, w_n) = want
-    g_state, g_ptoks, g_plps, g_lens, g_last, (g_t, g_lp, g_n) = got
+    g_state, g_ptoks, g_plps, g_lens, g_last, (g_t, g_lp, g_n), _ = got
     np.testing.assert_array_equal(g_ptoks, w_ptoks)
     np.testing.assert_array_equal(g_t, w_t)
     np.testing.assert_array_equal(g_n, w_n)

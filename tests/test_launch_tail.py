@@ -178,7 +178,8 @@ def _decode_args(rows=4, per=8):
         max_pages_per_slot=per)
     state = {"pools": ps._split_cache(cache),
              "hist": jnp.zeros((rows, 64), jnp.int32),
-             "gstate": jnp.zeros((rows,), jnp.int32)}
+             "gstate": jnp.zeros((rows,), jnp.int32),
+             "last": jnp.zeros((rows,), jnp.int32)}
     tables = np.full((rows, per), rows * 3 + 1, np.int32)
     tables[:, :3] = np.arange(rows * 3).reshape(rows, 3)
     ledger = (np.full((rows,), 9, np.int32),          # lengths
@@ -242,6 +243,34 @@ def test_c_patch_round_trip(rows, cols):
         jax.random.key_data(jax.random.fold_in(rng, count)))
 
 
+@pytest.mark.parametrize("compacted", [False, True],
+                         ids=["rows_are_slots", "compacted"])
+def test_c_a_row_takes_its_last_token_from_the_patch_or_the_device(
+        compacted):
+    """The live column's third value: that row's last token is the one
+    the device kept for its slot, whatever the patch says; the other
+    live rows and the dead ones read the patch, and all but the dead
+    are live."""
+    slots = 8
+    kept = jnp.arange(100, 100 + slots, dtype=jnp.int32)
+    slot_ids = (jnp.asarray([6, 2, 5, slots], jnp.int32) if compacted
+                else None)
+    rows = 4 if compacted else slots
+    flags = np.resize([ps._ROW_LAST_ON_DEVICE, ps._ROW_LIVE,
+                       ps._ROW_DEAD, ps._ROW_LAST_ON_DEVICE], rows)
+    last = np.arange(rows, dtype=np.int32) + 40
+    buf = ps._pack_patch(3, np.zeros(rows, np.int32), last, flags,
+                         np.zeros((rows, 2), np.int32))
+    _, _, g_last, g_live, _ = jax.jit(ps._unpack_patch)(
+        jnp.asarray(buf), jax.random.key(0), kept, slot_ids)
+    home = np.asarray(kept)[np.clip(
+        np.arange(rows) if slot_ids is None else np.asarray(slot_ids),
+        0, slots - 1)]
+    np.testing.assert_array_equal(
+        g_last, np.where(flags == ps._ROW_LAST_ON_DEVICE, home, last))
+    np.testing.assert_array_equal(g_live, flags != ps._ROW_DEAD)
+
+
 def test_c_a_patch_is_a_buffer_of_its_own():
     """The transfer may read the host memory after the call returns:
     the ledger goes on changing, the packed buffer does not."""
@@ -280,3 +309,111 @@ def test_d_paths_agree_token_for_token(params, sampling):
     assert run(scheduler="mixed", overlap=False, decode_chunk=1) \
         == overlapped
     assert run(scheduler="alternating") == overlapped
+
+
+# -- (e) the patch of a launch made ahead of the commit ----------------------
+
+def test_e_a_launch_ahead_patches_from_the_planned_frame(params,
+                                                         monkeypatch):
+    """A launch that goes ahead of the commit hands over lengths the
+    ledger does not hold yet and no last token for the rows the
+    dispatch in flight advances; the commit that follows brings the
+    ledger to exactly those lengths, and once the ledger has caught up
+    its last tokens are the ones the device kept. A step still takes
+    the time its phases
+    say: `between_ms + duration_ms` over the window of steps that
+    launched ahead is the window's length within 0.1%."""
+    srv = PagedInferenceServer(
+        params, CFG, GREEDY, scheduler="mixed", overlap=True,
+        flight_recorder_size=512, decode_chunk=1, **SRV_KW)
+    patches = []
+    pack = ps._pack_patch
+    monkeypatch.setattr(ps, "_pack_patch", lambda *a: patches.append(
+        pack(*a)) or patches[-1])
+    launch = srv._launch_plan
+    checked = {"ahead": 0, "waited": 0}
+
+    def watched_launch(plan):
+        n0 = len(patches)
+        before = (srv.lengths.copy(), srv.last_token.copy())
+        launch(plan)
+        if len(patches) == n0:
+            return
+        patch = patches[-1]
+        rows = (np.arange(srv.max_slots) if plan.sl_d is None
+                else plan.live_ids)
+        head = patch[:len(rows)]
+        if plan.waits is not None:
+            # the ledger's own rows, every live one with its token
+            checked["waited"] += 1
+            live = head[:, 2] != ps._ROW_DEAD
+            assert set(head[:, 2].tolist()) <= {ps._ROW_DEAD, ps._ROW_LIVE}
+            np.testing.assert_array_equal(head[live, 0],
+                                          srv.lengths[rows][live])
+            np.testing.assert_array_equal(head[live, 1],
+                                          srv.last_token[rows][live])
+            return
+        checked["ahead"] += 1
+        infl = srv._inflight           # still uncommitted behind it
+        assert srv._ahead is not None and infl is not srv._ahead
+        made = np.isin(rows, np.concatenate(
+            [infl.live_ids if infl.n_rounds else [], infl.activating]))
+        live = head[:, 2] != ps._ROW_DEAD
+        np.testing.assert_array_equal(
+            head[live, 2], np.where(made[live], ps._ROW_LAST_ON_DEVICE,
+                                    ps._ROW_LIVE))
+        # nothing was written into the ledger by the launch
+        np.testing.assert_array_equal(before[0], srv.lengths)
+        np.testing.assert_array_equal(before[1], srv.last_token)
+        pending.append((rows[live], head[live, 0].copy(), made[live]))
+
+    pending = []
+    monkeypatch.setattr(srv, "_launch_plan", watched_launch)
+    first = [srv.submit(p, max_new_tokens=30) for p in (REP, PROMPTS[1])]
+    for _ in range(4):
+        srv.step()
+    rest = [srv.submit(p, max_new_tokens=n)
+            for p, n in ((LONG, 24), (PROMPTS[3], 12))]
+    while any(not r.done for r in first + rest):
+        del pending[:]
+        srv.step()
+        if pending:
+            # the commit that the launch went ahead of has run
+            rows, lens, made = pending.pop()
+            alive = srv.active[rows]
+            np.testing.assert_array_equal(srv.lengths[rows][alive],
+                                          lens[alive])
+            if checked["ahead"] == 12 and srv._inflight is not None:
+                # with the dispatch launched ahead brought home too, the
+                # ledger has caught up with the device: its last tokens
+                # are the ones the newest program kept
+                srv._commit_inflight()
+                srv._deliver()
+                on = srv.active.copy()
+                assert on.sum() >= 2
+                np.testing.assert_array_equal(
+                    np.asarray(srv.state["last"])[on], srv.last_token[on])
+                checked["kept"] = True
+    assert checked["ahead"] >= 20 and checked["waited"] >= 2
+    assert checked.get("kept")
+    window = srv.flight_window()
+    ahead = [i for i, r in enumerate(window)
+             if r.get("launch_ahead") and i and "between_ms" in r]
+    runs, run = [], []
+    for i in ahead:      # the longest unbroken run of such steps
+        run = run + [i] if run and run[-1] == i - 1 else [i]
+        runs.append(run)
+    run = max(runs, key=len)
+    assert len(run) >= 10
+    recs = [window[i] for i in run]
+    span = (recs[-1]["t_start"] + recs[-1]["duration_ms"] * 1e-3
+            - recs[0]["t_start"] + recs[0]["between_ms"] * 1e-3) * 1e3
+    total = sum(r["between_ms"] + r["duration_ms"] for r in recs)
+    assert total == pytest.approx(span, rel=1e-3)
+    # a record is its dispatch's; the step that wrote it launched the
+    # next record's: all but the run's last step launched ahead
+    for r in recs[:-1]:
+        ph = r["phases_ms"]
+        assert {"launch", "device", "commit", "deliver"} <= set(ph)
+        assert sum(ph.values()) == pytest.approx(r["duration_ms"],
+                                                 rel=1e-9, abs=1e-6)

@@ -521,6 +521,281 @@ def test_raising_stream_callback_strands_no_completion(params):
 
 
 # ---------------------------------------------------------------------------
+# the launch ahead of the commit
+# ---------------------------------------------------------------------------
+
+# one token a step: a run of many steady steps, each launched ahead
+AHEAD_KW = dict(SRV_KW, decode_chunk=1)
+
+
+def _overlapped(srv):
+    return [r for r in srv.flight_window() if r.get("overlap")]
+
+
+AHEAD_SAMPLING = {
+    "greedy": (GREEDY, None),
+    "seeded": (dataclasses.replace(GREEDY, temperature=1.0),
+               [SamplingParams(seed=100 + i, temperature=0.9, top_p=0.9)
+                for i in range(len(PROMPTS))]),
+    "penalties": (dataclasses.replace(GREEDY, temperature=1.0),
+                  [SamplingParams(seed=7 + i, temperature=0.8,
+                                  frequency_penalty=0.5,
+                                  presence_penalty=0.3)
+                   for i in range(len(PROMPTS))]),
+}
+
+
+@pytest.mark.parametrize("kind", list(AHEAD_SAMPLING))
+def test_launched_ahead_equals_sequential(params, kind):
+    """Token for token and log-probability for log-probability what the
+    sequential scheduler gives, with nearly every dispatch on the
+    device's queue before the commit of the one before it."""
+    icfg, sampling = AHEAD_SAMPLING[kind]
+
+    def run(ov):
+        srv = PagedInferenceServer(params, CFG, icfg, scheduler="mixed",
+                                   overlap=ov, **AHEAD_KW)
+        return srv, _staggered(srv, PROMPTS, 24, sampling=sampling)
+
+    srv, (toks_on, lps_on) = run(True)
+    _, (toks_off, lps_off) = run(False)
+    assert toks_on == toks_off
+    for a, b in zip(lps_on, lps_off):
+        assert np.allclose(a, b)
+    ov = _overlapped(srv)
+    ahead = [r for r in ov if r["launch_ahead"]]
+    # the dispatches that waited primed the pipeline, nothing else
+    assert {r["launch_waits"] for r in ov if not r["launch_ahead"]} \
+        <= {"fill"}
+    assert len(ahead) >= 20 and len(ahead) > 0.8 * len(ov)
+    assert all("launch_waits" not in r for r in ahead)
+    # the prefill group of a launch ahead too: admissions completed in
+    # dispatches that were on the queue before the commit before them
+    assert any(r.get("prefill_tokens") for r in ahead)
+
+
+def _eos_config(params):
+    """A token the greedy stream of PROMPTS[0] reaches at its fifth
+    place and not before: as the end token, that request ends by it
+    after five tokens while the others decode on."""
+    probe = PagedInferenceServer(params, CFG, GREEDY, scheduler="mixed",
+                                 overlap=False, **AHEAD_KW)
+    toks = probe.generate([PROMPTS[0]], max_new_tokens=12)[0]
+    k = next(i for i in range(3, len(toks)) if toks[i] not in toks[:i])
+    return dataclasses.replace(GREEDY, eos_token_id=int(toks[k])), k
+
+
+def test_row_ending_by_end_token_is_computed_and_discarded(params):
+    """The row of a request that ends by its end token at commit n was
+    live in dispatch n+1, launched before: it is computed there, its
+    results are thrown away at commit n+1, and once released its pages
+    are written by nothing launched after the release."""
+    icfg, k = _eos_config(params)
+    srv = PagedInferenceServer(params, CFG, icfg, scheduler="mixed",
+                               overlap=True, **AHEAD_KW)
+    short = srv.submit(PROMPTS[0], max_new_tokens=40)
+    other = srv.submit(PROMPTS[1], max_new_tokens=40)
+    sid, pages = None, None
+    while not short.done:
+        held = [(i, list(s.pages)) for i, s in enumerate(srv._slots)
+                if s is not None and s.req is short]
+        if held:
+            (sid, pages), = held
+        srv.step()
+    assert short.finish_reason == "eos" and len(short.tokens) == k
+    # the dispatch behind the commit that ended it: launched ahead, the
+    # ended row among its rows, its slot released under it
+    nxt = srv._inflight
+    assert nxt.stats["launch_ahead"] and sid in nxt.live_ids.tolist()
+    assert srv._slots[sid] is None and not srv.active[sid]
+    emitted = srv.tokens_emitted
+    srv.step()                      # commit n+1: the dead row's results
+    assert len(short.tokens) == k and srv.tokens_emitted == emitted + 1
+    assert srv.lengths[sid] == 0 and srv._slots[sid] is None
+
+    def contents():
+        return {name: np.asarray(pool[:, np.asarray(pages)])
+                for name, pool in srv.state["pools"].items()}
+
+    before = contents()
+    for _ in range(6):
+        srv.step()
+        assert srv._inflight is not None
+    for name, was in before.items():
+        np.testing.assert_array_equal(contents()[name], was)
+    srv.run_until_idle()
+    assert other.done and len(other.tokens) == 40
+    s = srv.allocator.stats()
+    assert s.pages_free + s.pages_cached == s.pages_total
+
+
+def test_next_occupant_of_an_ended_rows_slot_sees_none_of_it(params):
+    """Six requests on four slots, two of them ending by the end token
+    under a launched-ahead dispatch: the requests that take their slots
+    (and whatever the device's per-slot state kept of the dead rows)
+    give the sequential scheduler's tokens."""
+    icfg, _ = _eos_config(params)
+    prompts = [PROMPTS[0], PROMPTS[1], PROMPTS[0], LONG, PROMPTS[3],
+               [9, 8, 7, 6]]
+
+    def run(ov):
+        srv = PagedInferenceServer(params, CFG, icfg, scheduler="mixed",
+                                   overlap=ov, **AHEAD_KW)
+        reqs = [srv.submit(p, max_new_tokens=16) for p in prompts]
+        srv.run_until_idle()
+        return srv, [(r.result(), r.finish_reason) for r in reqs]
+
+    srv, on = run(True)
+    assert on == run(False)[1]
+    assert [fr for _, fr in on].count("eos") >= 2
+    assert sum(r["launch_ahead"] for r in _overlapped(srv)) >= 10
+
+
+@pytest.mark.parametrize("how", ["cancel", "deadline"])
+def test_cancel_and_deadline_between_launch_and_commit(params, how):
+    """The request is cancelled (its deadline passes) after dispatch n+1
+    went onto the queue with its row live and before commit n: commit n
+    still records its tokens, the next sweep marks it, n+1's results for
+    it are dropped, and its pages go back once, after commit n+1."""
+    ref = PagedInferenceServer(params, CFG, GREEDY, scheduler="mixed",
+                               overlap=False, **AHEAD_KW)
+    want = ref.generate([PROMPTS[1]], max_new_tokens=12)[0]
+    srv = PagedInferenceServer(params, CFG, GREEDY, scheduler="mixed",
+                               overlap=True, **AHEAD_KW)
+    victim = srv.submit(PROMPTS[0], max_new_tokens=40)
+    other = srv.submit(PROMPTS[1], max_new_tokens=12)
+    while len(victim.tokens) < 3:
+        srv.step()
+    commit = srv._commit_inflight
+    seen = {}
+
+    def strike_then_commit():
+        seen["ahead"] = srv._ahead is not None
+        if how == "cancel":
+            victim.cancel()
+        else:
+            victim.deadline = time.perf_counter() - 1.0
+        srv._commit_inflight = commit
+        commit()
+
+    srv._commit_inflight = strike_then_commit
+    n0 = len(victim.tokens)
+    srv.step()
+    assert seen["ahead"], "the step under test did not launch ahead"
+    assert len(victim.tokens) == n0 + 1 and not victim.done
+    srv.step()
+    assert victim.done and len(victim.tokens) == n0 + 1
+    assert victim.finish_reason == ("cancelled" if how == "cancel"
+                                    else "deadline")
+    srv.run_until_idle()
+    assert other.result() == want
+    s = srv.allocator.stats()
+    assert s.pages_free + s.pages_cached == s.pages_total
+
+
+def test_launch_ahead_that_raises_commits_and_delivers_first(params):
+    """`test_launch_failure_delivers_committed_tokens_first` in the new
+    order: the launch that raises comes BEFORE the commit, and the step
+    still commits the dispatch in flight and hands its tokens over
+    before the error leaves it."""
+    srv = PagedInferenceServer(params, CFG, GREEDY, scheduler="mixed",
+                               overlap=True, **AHEAD_KW)
+    pairs = [_watched(srv, p, 48) for p in ([5, 9, 3], [17, 2, 40, 8, 21])]
+    while min(len(r.tokens) for r, _ in pairs) < 3:
+        srv.step()
+    before = [len(r.tokens) for r, _ in pairs]
+    order = []
+    commit = srv._commit_inflight
+
+    def broken_launch(plan):
+        order.append(("launch", plan.waits))
+        raise RuntimeError("launch failed")
+
+    srv._launch_plan = broken_launch
+    srv._commit_inflight = lambda: (order.append(("commit", None)),
+                                    commit())[1]
+    with pytest.raises(RuntimeError, match="launch failed"):
+        srv.step()
+    assert order == [("launch", None), ("commit", None)]
+    assert srv._inflight is None and srv._ahead is None
+    assert srv._deliveries == []
+    for (req, events), n0 in zip(pairs, before):
+        assert len(req.tokens) == n0 + 1 and not req.done
+        assert events == [("tok", t) for t in req.tokens]
+    srv._fail_all(RuntimeError("launch failed"))
+    assert all(r.done for r, _ in pairs)
+
+
+@pytest.mark.parametrize("why", ["drafts", "handoff"])
+def test_the_old_order_where_the_launch_needs_the_commit(params, why):
+    """Draft tokens in play, or an admission that completes with a
+    hand-off to prefetch: that dispatch waits for the commit before it,
+    its record says so and why, and the tokens are the sequential
+    scheduler's. (A constrained row: tests/test_grammar.py, which has
+    the tokenizer.)"""
+    kw = dict(AHEAD_KW, spec_drafts=2 if why == "drafts" else 0)
+    handed = []
+
+    def run(ov):
+        srv = PagedInferenceServer(params, CFG, GREEDY, scheduler="mixed",
+                                   overlap=ov, **kw)
+        first = srv.submit(REP, max_new_tokens=16)
+        for _ in range(3):
+            srv.step()
+        late = srv.submit(LONG, max_new_tokens=8,
+                          handoff=(handed.append if why == "handoff"
+                                   and ov else None))
+        srv.run_until_idle()
+        return srv, [first.result(), late.result()]
+
+    srv, toks = run(True)
+    assert toks == run(False)[1]
+    ov = _overlapped(srv)
+    waits = [r.get("launch_waits") for r in ov]
+    assert why in waits
+    for r in ov:
+        assert r["launch_ahead"] == ("launch_waits" not in r)
+    if why == "drafts":
+        # a server whose rows draft never launches ahead while they do
+        assert set(waits) <= {"fill", "drafts"}
+    else:
+        # the one dispatch that completed the admission waited; the
+        # decode steps around it went ahead; the callback fired once
+        assert waits.count("handoff") == 1 and None in waits
+        assert [r.request_id for r in handed] == [handed[0].request_id]
+
+
+def test_launch_ahead_share_and_the_records_flag(params):
+    """`/stats`' overlap block gives the share of the window's
+    overlapped dispatches that were launched ahead, from the flag each
+    of their records carries; records of sequential iterations carry
+    neither the flag nor a reason."""
+    srv = PagedInferenceServer(params, CFG, GREEDY, scheduler="mixed",
+                               overlap=True, **AHEAD_KW)
+    assert "launch_ahead_share" not in srv.overlap_stats()
+    reqs = [srv.submit(p, max_new_tokens=20) for p in PROMPTS[:2]]
+    srv.run_until_idle()
+    assert all(r.done for r in reqs)
+    recs = srv.flight_window()
+    ov = [r for r in recs if r.get("overlap")]
+    for r in recs:
+        assert ("launch_ahead" in r) == bool(r.get("overlap"))
+        if not r.get("overlap"):
+            assert "launch_waits" not in r
+    flags = [r["launch_ahead"] for r in ov]
+    assert all(isinstance(f, bool) for f in flags) and sum(flags) >= 15
+    stats = srv.overlap_stats()
+    assert stats["launch_ahead_share"] == pytest.approx(
+        100.0 * sum(flags) / len(flags))
+    assert 80.0 < stats["launch_ahead_share"] < 100.0  # the fill waited
+    off = PagedInferenceServer(params, CFG, GREEDY, scheduler="mixed",
+                               overlap=False, **AHEAD_KW)
+    off.generate([PROMPTS[0]], max_new_tokens=4)
+    assert "launch_ahead_share" not in off.overlap_stats()
+    assert all("launch_ahead" not in r for r in off.flight_window())
+
+
+# ---------------------------------------------------------------------------
 # idle-spin bound
 # ---------------------------------------------------------------------------
 
