@@ -13,7 +13,7 @@ the number and the sizes of the arrays it hands over and from the leaves
 of the call, not from the model's widths, and a tiny program leaves the
 device idle at every launch, as the closed loops of the cells do.
 
-Two tables, one JSON line each:
+Three tables, one JSON line each:
 
 * `loop`: a server driven by `submit` and `step` through some hundreds of
   steady-state launches, with the conversions `paged_server` makes and
@@ -28,6 +28,15 @@ Two tables, one JSON line each:
   whose loop table is in PERF.md section 6), against one packed buffer
   and one transfer (the form since); and a jitted call handed the
   staged patch against one handed the host buffer itself.
+
+* `stage`: what a plan stages while the program before it runs
+  (`_plan_iteration`'s staging block), outside any server: a mixed
+  plan's arrays (a prefill group of 8 rows, `--rows` decode rows over
+  compacted slots) and a decode-only plan's, handed over one
+  `device_put` an array (the form before PR 47: 42 and 16) against
+  packed (`_pack_group`, `_pack_rows`: 2 and 1, the packing timed with
+  them and alone), each behind a running program of some 20 ms and with
+  the device drained; medians in ms.
 
     python benchmarks/launch_tail_bench.py                    # on a TPU
     python benchmarks/launch_tail_bench.py --rows 64 --pages-per-row 128 \
@@ -232,6 +241,83 @@ def alone_table(rows: int, table_cols: int, reps: int) -> dict:
                             *staged), reps)}
 
 
+def _median_ms(fn, reps: int, busy=None) -> float:
+    """`fn` on the host's clock; `busy` puts a program on the device's
+    queue first, so the transfers go behind it as a plan's do."""
+    out = []
+    for _ in range(reps):
+        running = busy() if busy is not None else None
+        t0 = time.perf_counter()
+        y = fn()
+        out.append(time.perf_counter() - t0)
+        jax.block_until_ready((y, running))
+    return round(statistics.median(out) * 1e3, 4)
+
+
+def stage_table(rows: int, table_cols: int, reps: int) -> dict:
+    """A plan's staged arrays one by one against packed, milliseconds a
+    plan: the group is 8 rows of a 256-token chunk and a 2,048-token
+    prompt bucket, the decode rows `rows` over compacted slots."""
+    from cloud_server_tpu.config import InferConfig
+    from cloud_server_tpu.inference.sampling import make_rows
+    r = np.random.default_rng(0)
+    gp, w, pb = 8, 256, 2048
+
+    def ints(*shape, hi=2000):
+        return r.integers(0, hi, shape).astype(np.int32)
+
+    def sampler(n):
+        return make_rows([None] * n, InferConfig(), ints(n))
+
+    chunk, g_tables, prompt_rows = ints(gp, w), ints(gp, table_cols), \
+        ints(gp, pb)
+    head = {name: ints(gp) for name in ps._GROUP_FIELDS}
+    head["count_mask"] = head["count_mask"] > 1000
+    head["scatter_mask"] = head["scatter_mask"] > 1000
+    samp_g, samp_d = sampler(gp), sampler(rows)
+    stop, gid, aid, sl = ints(rows), ints(rows), ints(rows), ints(rows)
+    group_loose = [chunk, g_tables, prompt_rows, *head.values(), *samp_g]
+    rows_loose = [stop, gid, aid, sl, *samp_d]
+
+    def pack_group():
+        return ps._pack_group(chunk, g_tables, prompt_rows, samp_g, **head)
+
+    def pack_rows():
+        return ps._pack_rows(stop, gid, aid, 0, samp_d, sl)
+
+    forms = {
+        "mixed_one_by_one": lambda: [
+            jax.device_put(a) for a in group_loose + rows_loose],
+        "mixed_packed": lambda: [jax.device_put(pack_group()),
+                                 jax.device_put(pack_rows())],
+        "decode_one_by_one": lambda: [
+            jax.device_put(a) for a in rows_loose],
+        "decode_packed": lambda: [jax.device_put(pack_rows())]}
+    # a program of some 20 ms for the transfers to queue behind
+    x = jnp.ones((1024, 1024), jnp.bfloat16)
+    spin = jax.jit(lambda x, n: jax.lax.fori_loop(
+        0, n, lambda _, y: (y @ x) * 1e-3, x))
+    n, took = 4, 0.0
+    while took < 0.02 and n < 2 ** 20:
+        n *= 2
+        jax.block_until_ready(spin(x, n))
+        t0 = time.perf_counter()
+        jax.block_until_ready(spin(x, n))
+        took = time.perf_counter() - t0
+    out = {"arrays": {"mixed_one_by_one": len(group_loose + rows_loose),
+                      "mixed_packed": 2,
+                      "decode_one_by_one": len(rows_loose),
+                      "decode_packed": 1},
+           "behind_program_of_ms": round(took * 1e3, 2),
+           "pack_group_ms": _median_ms(pack_group, reps),
+           "pack_rows_ms": _median_ms(pack_rows, reps)}
+    for name, fn in forms.items():
+        out[name + "_behind_ms"] = _median_ms(fn, reps,
+                                              lambda: spin(x, n))
+        out[name + "_drained_ms"] = _median_ms(fn, reps)
+    return out
+
+
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--config",
@@ -251,6 +337,9 @@ def main() -> None:
     srv, mcfg = build_server(a.config, a.rows, a.pages_per_row, a.seed)
     lines = [{**head, "table": "alone", **alone_table(
         a.rows, srv.tables.shape[1], a.reps)}]
+    print(json.dumps(lines[-1]), flush=True)
+    lines.append({**head, "table": "stage", **stage_table(
+        a.rows, srv.tables.shape[1], a.reps)})
     print(json.dumps(lines[-1]), flush=True)
     clock, records = _Clock(), []
     instrument(srv, clock, records)

@@ -97,7 +97,7 @@ SCHEDULER_LOOPS: dict[str, tuple[str, ...]] = {
         "PagedInferenceServer._stage_spec_stats",
         "PagedInferenceServer._gather_decode_rows",
         "PagedInferenceServer._spec_plan",
-        "PagedInferenceServer._pad_limits",
+        "PagedInferenceServer._chunk_bucket",
         "PagedInferenceServer._drafted_rows",
         "PagedInferenceServer._chunk_rounds",
         "PagedInferenceServer._mixed_rounds",
@@ -193,10 +193,11 @@ PAGE_RELEASING_FUNCS = frozenset({
 # are bounded BY CONSTRUCTION — _plan_iteration computes them through
 # the same audited helpers this pass already trusts (n_rounds via the
 # _mixed_rounds/_chunk_rounds pow2 planners, g_iter via _spec_plan's
-# {0, spec_drafts} quantization) — so _launch_plan replaying them into
-# the jits' static arguments cannot mint new compile variants. Adding
-# a field here is a reviewed decision, exactly like BOUNDED_HELPERS.
-PLAN_BOUNDED_FIELDS = frozenset({"n_rounds", "g_iter"})
+# {0, spec_drafts} quantization, chunk_w via _chunk_bucket's bucket
+# table) — so _launch_plan replaying them into the jits' static
+# arguments cannot mint new compile variants. Adding a field here is a
+# reviewed decision, exactly like BOUNDED_HELPERS.
+PLAN_BOUNDED_FIELDS = frozenset({"n_rounds", "g_iter", "chunk_w"})
 
 # Pure host-side policy modules: scheduling decisions, accounting,
 # telemetry. The servers are the only modules allowed to touch jax.
@@ -228,6 +229,7 @@ HOST_POLICY_MODULES: tuple[str, ...] = (
 BOUNDED_HELPERS = {
     "_pad_pow2",       # next power of two, log2-many values
     "_bucket",         # fixed bucket table lookup
+    "_chunk_bucket",   # a prefill group's chunk width, through _bucket
     "_rem_bucket",     # bucket table / prefill_chunk multiples
     "_chunk_rounds",   # power-of-two round planner (paged)
     "_mixed_rounds",   # power-of-two round planner (mixed budget)

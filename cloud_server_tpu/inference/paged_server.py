@@ -335,6 +335,115 @@ def _unpack_patch(patch, rng, kept=None, slot_ids=None):
             patch[:, 2] != _ROW_DEAD, jax.random.fold_in(rng, patch[0, 3]))
 
 
+# What a plan stages crosses the same way: a (rows, columns) int32 array
+# for the decode rows and one for the prefill group, a column a field,
+# each value's 32 bits as they are (float32 and uint32 through a view on
+# the host and `lax.bitcast_convert_type` in the program, never a cast;
+# bools as 0 and 1). A `SamplingRows` lies in its tuple's order, a column
+# a leaf and `MAX_LOGIT_BIAS` columns each bias leaf.
+_SAMP_DTYPES = SamplingRows(
+    temperature=np.float32, top_k=np.int32, top_p=np.float32,
+    min_p=np.float32, rep=np.float32, pres=np.float32, freq=np.float32,
+    seed=np.uint32, bias_ids=np.int32, bias_vals=np.float32,
+    min_new=np.int32, plen=np.int32)
+_SAMP_WIDTHS = tuple(sampling.MAX_LOGIT_BIAS if f.startswith("bias_")
+                     else 1 for f in SamplingRows._fields)
+_SAMP_COLS = sum(_SAMP_WIDTHS)
+
+# The decode rows' buffer: a row's token limit, grammar, adapter and
+# draft limit, its sampler, and behind them the row's slot where the
+# rows are a gathered subset of the slots. Where rows are slots the
+# column is absent, so the buffer's width says which program this is.
+_ROWS_HEAD = 4
+
+# The prefill group's buffer: the per-row fields below, the row's
+# sampler, its table row (as wide as the patch's), its chunk tokens and
+# its prompt. Chunk and prompt are both a bucket wide and only their sum
+# shows in the shape, so the chunk's width reaches the program as a
+# static (`chunk_w`).
+_GROUP_FIELDS = ("widths", "g_lens", "sample_at", "slot_ids", "prompt_lens",
+                 "orig_lens", "count_mask", "scatter_mask", "gid",
+                 "gstate0", "aid")
+_GROUP_HEAD = len(_GROUP_FIELDS)
+
+
+def _pack_samp(buf, col: int, samp_rows) -> int:
+    for leaf, w in zip(samp_rows, _SAMP_WIDTHS):
+        buf[:, col:col + w] = leaf.reshape(len(leaf), w).view(np.int32)
+        col += w
+    return col
+
+
+def _unpack_samp(buf, col: int):
+    leaves = []
+    for dtype, w in zip(_SAMP_DTYPES, _SAMP_WIDTHS):
+        leaf = buf[:, col] if w == 1 else buf[:, col:col + w]
+        leaves.append(leaf if dtype == np.int32
+                      else lax.bitcast_convert_type(leaf, dtype))
+        col += w
+    return SamplingRows(*leaves), col
+
+
+def _pack_rows(stop_len, gid, aid, draft_limit, samp_rows,
+               slot_ids=None) -> np.ndarray:
+    """The decode rows' launch-stable inputs in a buffer of their own
+    (`_pack_patch` says why a fresh one). `slot_ids` None: rows are
+    slots."""
+    n = len(stop_len)
+    buf = np.empty((n, _ROWS_HEAD + _SAMP_COLS + (slot_ids is not None)),
+                   np.int32)
+    buf[:, 0] = stop_len
+    buf[:, 1] = gid
+    buf[:, 2] = aid
+    buf[:, 3] = draft_limit
+    col = _pack_samp(buf, _ROWS_HEAD, samp_rows)
+    if slot_ids is not None:
+        buf[:, col] = slot_ids
+    return buf
+
+
+@jax.named_scope("decode_rounds")
+def _unpack_rows(rows):
+    """(stop_len, gid, aid, draft_limit, samp_rows, slot_ids) inside a
+    program; `slot_ids` None where the buffer has no such column."""
+    samp_rows, col = _unpack_samp(rows, _ROWS_HEAD)
+    slot_ids = rows[:, col] if rows.shape[1] > col else None
+    return (rows[:, 0], rows[:, 1], rows[:, 2], rows[:, 3], samp_rows,
+            slot_ids)
+
+
+def _pack_group(chunk, g_tables, prompt_rows, samp_rows,
+                **head) -> np.ndarray:
+    """The prefill group of one mixed step in a buffer of its own;
+    `head` is the `_GROUP_FIELDS`, each (rows,)."""
+    gp, w = chunk.shape
+    t = g_tables.shape[1]
+    assert len(head) == _GROUP_HEAD
+    buf = np.empty((gp, _GROUP_HEAD + _SAMP_COLS + t + w
+                    + prompt_rows.shape[1]), np.int32)
+    for col, name in enumerate(_GROUP_FIELDS):
+        buf[:, col] = head[name]
+    col = _pack_samp(buf, _GROUP_HEAD, samp_rows)
+    buf[:, col:col + t] = g_tables
+    buf[:, col + t:col + t + w] = chunk
+    buf[:, col + t + w:] = prompt_rows
+    return buf
+
+
+@jax.named_scope("prefill_group")
+def _unpack_group(group, chunk_w: int, table_cols: int) -> dict:
+    """`_pack_group`'s arguments by name inside a program."""
+    out = {name: group[:, col] for col, name in enumerate(_GROUP_FIELDS)}
+    out["count_mask"] = out["count_mask"] != 0
+    out["scatter_mask"] = out["scatter_mask"] != 0
+    out["samp_rows"], col = _unpack_samp(group, _GROUP_HEAD)
+    out["g_tables"] = group[:, col:col + table_cols]
+    col += table_cols
+    out["chunk"] = group[:, col:col + chunk_w]
+    out["prompt_rows"] = group[:, col + chunk_w:]
+    return out
+
+
 def _assign_out(state):
     """The pools' running assignment counts as the program leaves them
     (`PagedKVCache.assign`), as an output of their own: the state's leaf
@@ -639,15 +748,17 @@ def _decode_plain_core(params, state, lengths, tables, last_token, live,
          static_argnames=("cfg", "infer_cfg", "n_rounds", "mesh",
                           "use_rows", "use_bias"),
          donate_argnums=(1,))
-def _decode_rounds(params, state, patch, rng, samp_rows, gid=None,
-                   grammar=None, lora=None, aid=None, slot_ids=None, *,
+def _decode_rounds(params, state, patch, rows, rng, grammar=None,
+                   lora=None, *,
                    cfg: ModelConfig, infer_cfg: InferConfig, n_rounds: int,
                    mesh=None, use_rows: bool = False,
                    use_bias: bool = False):
     """`_decode_plain_core` as a program of its own, fed the packed
-    patch and the server's key (`_unpack_patch`; a row's last token from
-    the patch or from `state["last"]`, as the patch says), with
-    `_assign_out` behind the core's results."""
+    patch, the packed rows (`_unpack_rows`) and the server's key
+    (`_unpack_patch`; a row's last token from the patch or from
+    `state["last"]`, as the patch says), with `_assign_out` behind the
+    core's results."""
+    _, gid, aid, _, samp_rows, slot_ids = _unpack_rows(rows)
     lengths, tables, last_token, live, key = _unpack_patch(
         patch, rng, state["last"], slot_ids)
     state, lengths, last, out = _decode_plain_core(
@@ -907,13 +1018,14 @@ def _spec_core(params, state, lengths, tables, last_token, live,
          static_argnames=("cfg", "infer_cfg", "n_rounds", "n_drafts",
                           "mesh", "draft_cfg", "use_rows", "use_bias"),
          donate_argnums=(1,))
-def _spec_rounds(params, state, patch, stop_len, rng, samp_rows, gid=None,
-                 grammar=None, lora=None, aid=None, draft_params=None,
-                 slot_ids=None, draft_limit=None, *,
+def _spec_rounds(params, state, patch, rows, rng, grammar=None, lora=None,
+                 draft_params=None, *,
                  cfg: ModelConfig, infer_cfg: InferConfig, n_rounds: int,
                  n_drafts: int, mesh=None, draft_cfg=None,
                  use_rows: bool = False, use_bias: bool = False):
     """`_spec_core` as a program of its own, fed like `_decode_rounds`."""
+    stop_len, gid, aid, draft_limit, samp_rows, slot_ids = _unpack_rows(
+        rows)
     lengths, tables, last_token, live, key = _unpack_patch(
         patch, rng, state["last"], slot_ids)
     state, lengths, last, out = _spec_core(
@@ -969,21 +1081,15 @@ def _sorts_experts(cfg: ModelConfig, params, joined: bool,
 
 @partial(jax.jit,
          static_argnames=("cfg", "infer_cfg", "n_rounds", "n_drafts",
-                          "scatter_prompt", "mesh", "draft_cfg",
-                          "use_rows_p", "use_bias_p",
+                          "scatter_prompt", "chunk_w", "mesh",
+                          "draft_cfg", "use_rows_p", "use_bias_p",
                           "use_rows_d", "use_bias_d"),
          donate_argnums=(1,))
-def _mixed_step(params, state,
-                chunk, widths, g_lens, g_tables, sample_at, slot_ids,
-                prompt_rows, prompt_lens, samp_rows_g, orig_lens,
-                count_mask, scatter_mask, gid_g, gstate0_g,
-                patch, stop_len, samp_rows_b, gid_b, slot_ids_d,
-                draft_limit, rng, grammar=None, lora=None, aid_g=None,
-                aid_b=None,
-                draft_params=None, *,
+def _mixed_step(params, state, group, patch, rows, rng, grammar=None,
+                lora=None, draft_params=None, *,
                 cfg: ModelConfig, infer_cfg: InferConfig, n_rounds: int,
-                n_drafts: int, scatter_prompt: bool, mesh=None,
-                draft_cfg=None,
+                n_drafts: int, scatter_prompt: bool, chunk_w: int,
+                mesh=None, draft_cfg=None,
                 use_rows_p: bool = False, use_bias_p: bool = False,
                 use_rows_d: bool = False, use_bias_d: bool = False):
     """ONE token-budget mixed iteration, ONE jitted program, ONE host
@@ -1027,6 +1133,10 @@ def _mixed_step(params, state,
     arrive as the one packed `patch`, and `rng` is the server's one key:
     `_unpack_patch` takes the array apart and folds the dispatch's count
     into the key, so the step is the only program a dispatch runs.
+    Everything else of the two halves arrives as two more packed
+    arrays, staged while the program before this one ran: `group`
+    (`_unpack_group`, whose chunk is `chunk_w` wide) and `rows`
+    (`_unpack_rows`).
 
     Prefill rows and decode rows are DISJOINT slots (a slot is live xor
     mid-admission), so program order between the halves is irrelevant,
@@ -1042,8 +1152,14 @@ def _mixed_step(params, state,
     last token stays in `state'["last"]` besides: a decode row's `last'`,
     and the first token of an admission the step completes.
     """
+    (stop_len, gid_b, aid_b, draft_limit, samp_rows_b,
+     slot_ids_d) = _unpack_rows(rows)
     lengths, tables, last_token, live, key = _unpack_patch(
         patch, rng, state["last"], slot_ids_d)
+    g = _unpack_group(group, chunk_w, tables.shape[1])
+    chunk, widths, g_lens, g_tables, sample_at = (
+        g["chunk"], g["widths"], g["g_lens"], g["g_tables"],
+        g["sample_at"])
     rng_p, rng_d = jax.random.split(key)
     plogits = dlogits = None
     if _walks_once(cfg, chunk.size + lengths.size, n_rounds, n_drafts,
@@ -1058,10 +1174,10 @@ def _mixed_step(params, state,
             mesh=mesh)
         state = {**state, "pools": _split_cache(cache)}
     state, ptoks, plps = _prefill_core(
-        params, state, chunk, g_lens, g_tables, sample_at, slot_ids,
-        prompt_rows, prompt_lens, rng_p, samp_rows_g, orig_lens,
-        count_mask, gid_g, gstate0_g, grammar, lora, aid_g,
-        draft_params, widths, scatter_mask, plogits,
+        params, state, chunk, g_lens, g_tables, sample_at, g["slot_ids"],
+        g["prompt_rows"], g["prompt_lens"], rng_p, g["samp_rows"],
+        g["orig_lens"], g["count_mask"], g["gid"], g["gstate0"], grammar,
+        lora, g["aid"], draft_params, widths, g["scatter_mask"], plogits,
         cfg=cfg, infer_cfg=infer_cfg, scatter_prompt=scatter_prompt,
         mesh=mesh, draft_cfg=draft_cfg, use_rows=use_rows_p,
         use_bias=use_bias_p)
@@ -1159,12 +1275,10 @@ class _Plan:
     d_lens: np.ndarray
     d_tables: np.ndarray
     d_last: np.ndarray
-    d_stop: np.ndarray
-    samp_d: object
-    gid_d: np.ndarray
-    aid_d: np.ndarray
+    rows: object                    # the decode rows, packed (_pack_rows)
     owners: list                    # _Slot per live row (identity guard)
-    pf: dict | None                 # prefill-half arrays (mixed only)
+    pf: dict | None                 # prefill half (mixed only)
+    chunk_w: int                    # its chunk's bucket (_chunk_bucket)
     scatter_prompt: bool
     use_rows_p: bool
     use_bias_p: bool
@@ -1174,10 +1288,6 @@ class _Plan:
     use_lora: bool
     stats: dict
     spans: list
-    # device copies of `sl_d` and of the padded draft limits, staged by
-    # `_plan_iteration` with the other launch-stable arrays
-    sl_dev: object = None
-    lim_dev: object = None
     # why this launch has to follow the commit of the dispatch in flight
     # when it was planned (`_launch_waits`: "fill", "drafts", "grammar",
     # "handoff"); None: it goes ahead of that commit, from `frame`, the
@@ -2828,7 +2938,8 @@ class PagedInferenceServer:
             p *= 2
         return p
 
-    def _gather_decode_rows(self, active=None):
+    def _gather_decode_rows(self, active=None, g_iter: int = 0,
+                            spec_lens=None):
         """COMPACTED decode sub-batch: one row per LIVE slot, padded to
         a power of two (compile cache). Rows carry sentinel slot ids /
         tables past the live count, so their writes drop everywhere
@@ -2841,7 +2952,14 @@ class PagedInferenceServer:
         slots): steady state keeps the pre-compaction program, so the
         identity gathers of gstate / penalty rows are never paid there.
 
-        Returns (live_ids, sl, arrays...) for the decode cores.
+        Returns (live_ids, sl, live_g, lengths, tables, last, rows):
+        the patch's four arrays loose, because a launch writes them
+        anew, and everything else the decode cores take of a row as the
+        one packed buffer (`_pack_rows`), shared by `_decode_dispatch`,
+        `_mixed_dispatch` and `_plan_iteration` so the paths can never
+        drift. `g_iter`, `spec_lens` are the dispatch's `_spec_plan`:
+        a row's draft limit is its own where the controller gave one,
+        else the dispatch's width, which caps nothing.
         `active` overrides the live mask (the overlap planner's
         planned frame; the gathered lengths/last rows are placeholders
         there — `_launch_plan` re-reads them from the committed ledger
@@ -2849,12 +2967,14 @@ class PagedInferenceServer:
         if active is None:
             active = self.active
         live_ids = np.flatnonzero(active)
-        if len(live_ids) == self.max_slots:
-            return (live_ids, None, active.copy(), self.lengths,
-                    self.tables, self.last_token, self.stop_len,
-                    self.samp_rows, self._gid, self._aid)
-        bg = _pad_pow2(max(len(live_ids), 1))
         nl = len(live_ids)
+        live_limits = g_iter if spec_lens is None else spec_lens
+        if nl == self.max_slots:
+            return (live_ids, None, active.copy(), self.lengths,
+                    self.tables, self.last_token,
+                    _pack_rows(self.stop_len, self._gid, self._aid,
+                               live_limits, self.samp_rows))
+        bg = _pad_pow2(max(nl, 1))
         sl = np.full((bg,), self.max_slots, np.int32)
         sl[:nl] = live_ids
         slr = np.clip(sl, 0, self.max_slots - 1)
@@ -2864,14 +2984,15 @@ class PagedInferenceServer:
         tables = self.tables[slr].copy()
         tables[nl:] = self._no_page
         last = self.last_token[slr].copy()
-        stop = self.stop_len[slr].copy()
-        samp = _gather_samp_rows(self.samp_rows, slr, nl)
-        gid = self._gid[slr].copy()
+        gid = self._gid[slr]
         gid[nl:] = 0
-        aid = self._aid[slr].copy()
+        aid = self._aid[slr]
         aid[nl:] = 0
-        return live_ids, sl, live_g, lengths, tables, last, stop, \
-            samp, gid, aid
+        limits = np.zeros((bg,), np.int32)
+        limits[:nl] = live_limits
+        rows = _pack_rows(self.stop_len[slr], gid, aid, limits,
+                          _gather_samp_rows(self.samp_rows, slr, nl), sl)
+        return live_ids, sl, live_g, lengths, tables, last, rows
 
     def _spec_plan(self, live_ids):
         """Per-iteration speculation plan: (dispatch draft count,
@@ -2894,13 +3015,6 @@ class PagedInferenceServer:
         if max(lens) <= 0:
             return 0, None
         return self.spec_drafts, lens
-
-    def _pad_limits(self, lens, n_rows: int):
-        """(n_rows,) int32 per-row commit caps from the plan's per-live
-        lengths (padding rows 0 — they never commit anyway)."""
-        lim = np.zeros((n_rows,), np.int32)
-        lim[:len(lens)] = lens
-        return lim
 
     def _drafted_rows(self, g_iter: int, spec_lens, nl: int):
         """Per-live-row drafted-token counts for this dispatch's
@@ -2946,9 +3060,9 @@ class PagedInferenceServer:
         self._window_cover_rounds(n, self.lengths, self.active)
         if prof is not None:
             prof.enter("build")
-        (live_ids, sl, live_g, lengths, tables, last_np, stop, samp_g,
-         gid_np, aid_np) = self._gather_decode_rows()
-        g_iter, spec_lens = self._spec_plan(live_ids)
+        g_iter, spec_lens = self._spec_plan(np.flatnonzero(self.active))
+        (live_ids, sl, live_g, lengths, tables, last_np,
+         rows) = self._gather_decode_rows(None, g_iter, spec_lens)
         self._iter_stats.update(
             scheduler=self.scheduler, n_live=len(live_ids),
             decode_rounds=n,
@@ -2960,31 +3074,24 @@ class PagedInferenceServer:
         if self.trace_recorder is not None:
             self._stage_decode_spans(live_ids, n)
         patch = self._feed_patch(lengths, last_np, live_g, tables)
-        samp = jax.tree.map(jnp.asarray, samp_g)
+        rows = self._to_device(rows)
         live = self.active
         use_rows = bool((self._needs_rows & live).any())
         use_bias = bool((self._has_bias & live).any())
         use_grammar = bool(((self._gid > 0) & live).any())
-        gid = jnp.asarray(gid_np)
         # analysis: allow[lock-discipline] atomically-swapped reference,
         # rebuilt under _lock before any request using it is admitted
         grammar = self._grammar_dev if use_grammar else None
         use_lora = bool(((self._aid > 0) & live).any())
         lora = self.adapters.device_args() if use_lora else None
-        aid = jnp.asarray(aid_np)
-        sl_dev = None if sl is None else jnp.asarray(sl)
         self._stage_program_kind(self._iter_stats, 0, live_g.size, n,
                                  g_iter, lora)
         if prof is not None:
             prof.enter("device")
         if g_iter > 0:
-            lim_dev = (None if spec_lens is None else jnp.asarray(
-                self._pad_limits(spec_lens, int(live_g.shape[0]))))
             self.state, lens, last, (toks, lps, counts), _ = _spec_rounds(
-                self.params, self.state, patch,
-                jnp.asarray(stop), self._rng, samp,
-                gid, grammar, lora, aid,
-                self.draft_params, sl_dev, lim_dev,
+                self.params, self.state, patch, rows, self._rng,
+                grammar, lora, self.draft_params,
                 cfg=self.cfg, infer_cfg=self.infer_cfg, n_rounds=n,
                 n_drafts=g_iter, mesh=self.mesh,
                 draft_cfg=self.draft_cfg, use_rows=use_rows,
@@ -2996,8 +3103,8 @@ class PagedInferenceServer:
         else:
             (self.state, lens, last, (toks, lps, counts),
              assign) = _decode_rounds(
-                self.params, self.state, patch, self._rng, samp,
-                gid, grammar, lora, aid, sl_dev,
+                self.params, self.state, patch, rows, self._rng,
+                grammar, lora,
                 cfg=self.cfg, infer_cfg=self.infer_cfg, n_rounds=n,
                 mesh=self.mesh, use_rows=use_rows, use_bias=use_bias)
             # analysis: allow[lock-discipline] THE sanctioned
@@ -3230,22 +3337,28 @@ class PagedInferenceServer:
             sel = [(job, take, cur(job))]
         return sel
 
-    def _build_prefill_group(self, sel) -> dict:
+    def _chunk_bucket(self, sel) -> int:
+        """The width a prefill group's chunk is padded to: the bucket of
+        its widest take (`_mixed_step`'s static `chunk_w`)."""
+        return _bucket(max([t for _, t, _ in sel] + [1]),
+                       self._mixed_buckets)
+
+    def _build_prefill_group(self, sel, w: int) -> dict:
         """Numpy staging for the ragged prefill half of one mixed
         iteration: one row per selected admission chunk, each at its
         own width, padded to a pow2 row count and a bucketed max width
-        (compile cache). `sel` entries are (job, take, d0) — d0 is the
-        remainder offset this chunk starts at: the committed cursor on
-        the sequential path, the PLANNED cursor on the async path (so
-        a launch-ahead iteration never re-prefills tokens already in
-        flight). Shared verbatim by `_mixed_dispatch` and
-        `_plan_iteration` so the two paths can never drift."""
+        `w` (`_chunk_bucket`; compile cache). `sel` entries are (job,
+        take, d0) — d0 is the remainder offset this chunk starts at: the
+        committed cursor on the sequential path, the PLANNED cursor on
+        the async path (so a launch-ahead iteration never re-prefills
+        tokens already in flight). Shared verbatim by `_mixed_dispatch`
+        and `_plan_iteration` so the two paths can never drift, the
+        packing included: `group` is the one array the half hands the
+        device (`_pack_group`), the rest is what the host reads of it."""
         pad_tok = self.infer_cfg.pad_token_id
         b = self.max_slots
         g = len(sel)
         gp = _pad_pow2(max(g, 1))
-        w = _bucket(max([t for _, t, _ in sel] + [1]),
-                    self._mixed_buckets)
         chunk = np.full((gp, w), pad_tok, np.int32)
         widths = np.zeros((gp,), np.int32)
         g_lens = np.zeros((gp,), np.int32)
@@ -3284,22 +3397,23 @@ class PagedInferenceServer:
             if d0 == 0:
                 prompt_rows[i, :pl] = job.prompt_rows[0, :pl]
         sl_real = np.clip(slot_ids, 0, self.max_slots - 1)
-        samp_g = _gather_samp_rows(self.samp_rows, sl_real, g)
-        gid_g = self._gid[sl_real].copy()
+        gid_g = self._gid[sl_real]
         gid_g[g:] = 0
-        gst0_g = self._gstate0[sl_real].copy()
+        gst0_g = self._gstate0[sl_real]
         gst0_g[g:] = 0
-        aid_g = self._aid[sl_real].copy()
+        aid_g = self._aid[sl_real]
         aid_g[g:] = 0
         sel_mask = np.zeros((b,), bool)
         sel_mask[[job.slots[0] for job, _, _ in sel]] = True
-        return {"chunk": chunk, "widths": widths, "g_lens": g_lens,
-                "g_tables": g_tables, "sample_at": sample_at,
-                "slot_ids": slot_ids, "prompt_rows": prompt_rows,
-                "prompt_lens": prompt_lens, "samp_g": samp_g,
-                "orig_lens": orig_lens, "countm": countm,
-                "scatm": scatm, "gid_g": gid_g, "gst0_g": gst0_g,
-                "aid_g": aid_g, "sel_mask": sel_mask}
+        group = _pack_group(
+            chunk, g_tables, prompt_rows,
+            _gather_samp_rows(self.samp_rows, sl_real, g),
+            widths=widths, g_lens=g_lens, sample_at=sample_at,
+            slot_ids=slot_ids, prompt_lens=prompt_lens,
+            orig_lens=orig_lens, count_mask=countm, scatter_mask=scatm,
+            gid=gid_g, gstate0=gst0_g, aid=aid_g)
+        return {"group": group, "chunk_tokens": chunk.size,
+                "scatter_prompt": bool(scatm.any()), "sel_mask": sel_mask}
 
     def _handoff_prefetch(self, sel) -> None:
         """Overlapped KV export for the disaggregation handoff: for
@@ -3423,14 +3537,15 @@ class PagedInferenceServer:
                           "offset": d0}))
 
         # -- ragged prefill group (one row per selected admission) ----------
-        pf = self._build_prefill_group(sel)
+        chunk_w = self._chunk_bucket(sel)
+        pf = self._build_prefill_group(sel, chunk_w)
         sel_mask = pf["sel_mask"]
         use_rows_p = bool((self._needs_rows & sel_mask).any())
         use_bias_p = bool((self._has_bias & sel_mask).any())
 
         # -- decode half (compacted: one row per live slot) -----------------
-        (live_ids, sl_d, live_g, d_lens, d_tables, d_last, d_stop,
-         samp_d, gid_d, aid_d) = self._gather_decode_rows()
+        (live_ids, sl_d, live_g, d_lens, d_tables, d_last,
+         rows) = self._gather_decode_rows(None, g_iter, spec_lens)
         self._iter_stats.update(self._take_keys())
         self._iter_stats.update(
             decode_rows=int(live_g.shape[0]) if n_rounds else 0,
@@ -3455,37 +3570,22 @@ class PagedInferenceServer:
         # prefill chunk)
         self._handoff_prefetch(sel)
         lora = self.adapters.device_args() if use_lora else None
-        self._stage_program_kind(self._iter_stats, pf["chunk"].size,
+        self._stage_program_kind(self._iter_stats, pf["chunk_tokens"],
                                  live_g.size, n_rounds, g_iter, lora)
         (self.state, ptoks, plps, lens, last, (toks, lps, counts),
          assign) = \
             _mixed_step(
-                self.params, self.state, jnp.asarray(pf["chunk"]),
-                jnp.asarray(pf["widths"]), jnp.asarray(pf["g_lens"]),
-                jnp.asarray(pf["g_tables"]), jnp.asarray(pf["sample_at"]),
-                jnp.asarray(pf["slot_ids"]), jnp.asarray(pf["prompt_rows"]),
-                jnp.asarray(pf["prompt_lens"]),
-                jax.tree.map(jnp.asarray, pf["samp_g"]),
-                jnp.asarray(pf["orig_lens"]), jnp.asarray(pf["countm"]),
-                jnp.asarray(pf["scatm"]), jnp.asarray(pf["gid_g"]),
-                jnp.asarray(pf["gst0_g"]),
+                self.params, self.state, self._to_device(pf["group"]),
                 self._feed_patch(d_lens, d_last, live_g, d_tables),
-                jnp.asarray(d_stop),
-                jax.tree.map(jnp.asarray, samp_d),
-                jnp.asarray(gid_d),
-                None if sl_d is None else jnp.asarray(sl_d),
-                None if spec_lens is None else jnp.asarray(
-                    self._pad_limits(spec_lens, int(live_g.shape[0]))),
-                self._rng,
+                self._to_device(rows), self._rng,
                 # analysis: allow[lock-discipline] atomically-swapped
                 # reference, rebuilt under _lock pre-admission
                 self._grammar_dev if use_grammar else None, lora,
-                jnp.asarray(pf["aid_g"]), jnp.asarray(aid_d),
                 self.draft_params,
                 cfg=self.cfg, infer_cfg=self.infer_cfg,
                 n_rounds=n_rounds, n_drafts=g_iter,
-                scatter_prompt=bool(pf["scatm"].any()), mesh=self.mesh,
-                draft_cfg=self.draft_cfg,
+                scatter_prompt=pf["scatter_prompt"], chunk_w=chunk_w,
+                mesh=self.mesh, draft_cfg=self.draft_cfg,
                 use_rows_p=use_rows_p, use_bias_p=use_bias_p,
                 use_rows_d=use_rows_d, use_bias_d=use_bias_d)
         # analysis: allow[lock-discipline] THE sanctioned per-iteration
@@ -3676,10 +3776,14 @@ class PagedInferenceServer:
                               "offset": d0}))
             if prof is not None:
                 prof.enter("build")
-            pf = self._build_prefill_group(sel)
+            chunk_w = self._chunk_bucket(sel)
+            pf = self._build_prefill_group(sel, chunk_w)
             sel_mask = pf["sel_mask"]
-            (live_ids, sl_d, live_g, d_lens, d_tables, d_last, d_stop,
-             samp_d, gid_d, aid_d) = self._gather_decode_rows(live)
+            # a plan whose decode half was dropped gathers no row, and
+            # the controller's lengths are the planned-live slots'
+            (live_ids, sl_d, live_g, d_lens, d_tables, d_last,
+             rows) = self._gather_decode_rows(
+                 live, g_iter, spec_lens if n_rounds else None)
             stats.update(self._take_keys())
             stats.update(
                 decode_rows=int(live_g.shape[0]) if n_rounds else 0,
@@ -3694,10 +3798,10 @@ class PagedInferenceServer:
                 n_rounds=n_rounds, win=win, g_iter=g_iter,
                 spec_lens=spec_lens, live_ids=live_ids, sl_d=sl_d,
                 live_g=live_g, d_lens=d_lens, d_tables=d_tables,
-                d_last=d_last, d_stop=d_stop, samp_d=samp_d,
-                gid_d=gid_d, aid_d=aid_d,
+                d_last=d_last, rows=rows,
                 owners=[self._slots[int(s)] for s in live_ids],
-                pf=pf, scatter_prompt=bool(pf["scatm"].any()),
+                pf=pf, chunk_w=chunk_w,
+                scatter_prompt=pf["scatter_prompt"],
                 use_rows_p=bool((self._needs_rows & sel_mask).any()),
                 use_bias_p=bool((self._has_bias & sel_mask).any()),
                 use_rows_d=bool((self._needs_rows & live).any()),
@@ -3721,10 +3825,11 @@ class PagedInferenceServer:
             self._window_cover_rounds(n, planned_len, planned_active)
             if prof is not None:
                 prof.enter("build")
-            (live_ids, sl_d, live_g, d_lens, d_tables, d_last, d_stop,
-             samp_d, gid_d, aid_d) = self._gather_decode_rows(
-                 planned_active)
-            g_iter, spec_lens = self._spec_plan(live_ids)
+            g_iter, spec_lens = self._spec_plan(
+                np.flatnonzero(planned_active))
+            (live_ids, sl_d, live_g, d_lens, d_tables, d_last,
+             rows) = self._gather_decode_rows(
+                 planned_active, g_iter, spec_lens)
             stats.update(self._take_keys())
             stats.update(
                 scheduler=self.scheduler, n_live=len(live_ids),
@@ -3741,9 +3846,9 @@ class PagedInferenceServer:
                 win=g_iter + 1, g_iter=g_iter, spec_lens=spec_lens,
                 live_ids=live_ids, sl_d=sl_d, live_g=live_g,
                 d_lens=d_lens, d_tables=d_tables, d_last=d_last,
-                d_stop=d_stop, samp_d=samp_d, gid_d=gid_d, aid_d=aid_d,
+                rows=rows,
                 owners=[self._slots[int(s)] for s in live_ids],
-                pf=None, scatter_prompt=False,
+                pf=None, chunk_w=0, scatter_prompt=False,
                 use_rows_p=False, use_bias_p=False,
                 use_rows_d=bool(
                     (self._needs_rows & planned_active).any()),
@@ -3756,31 +3861,17 @@ class PagedInferenceServer:
         # stage the launch-stable inputs onto the device NOW, inside
         # the overlap window: an asynchronous host->device feed (DD2
         # deliberately never flags those), so these transfers ride
-        # behind the in-flight program. `_launch_plan` passes them
-        # through as they are and hands over one array, the patch.
-        # The step's own record says how many went and how long the
-        # block took (`plan_h2d`, `stage_ms`): this plan's stats are
+        # behind the in-flight program: the decode rows' packed buffer
+        # and, of a mixed plan, the prefill group's. `_launch_plan`
+        # passes them through as they are and hands over one array, the
+        # patch. The step's own record says how many went and how long
+        # the block took (`plan_h2d`, `stage_ms`): this plan's stats are
         # recorded a step later, with the program's commit.
         t_stage = prof.lap() if prof is not None else None
         h2d0 = self._h2d
-        put = self._to_device
         if plan.pf is not None:
-            pf = plan.pf
-            for k in ("chunk", "widths", "g_lens", "g_tables",
-                      "sample_at", "slot_ids", "prompt_rows",
-                      "prompt_lens", "orig_lens", "countm", "scatm",
-                      "gid_g", "gst0_g", "aid_g"):
-                pf[k] = put(pf[k])
-            pf["samp_g"] = jax.tree.map(put, pf["samp_g"])
-        plan.d_stop = put(plan.d_stop)
-        plan.samp_d = jax.tree.map(put, plan.samp_d)
-        plan.gid_d = put(plan.gid_d)
-        plan.aid_d = put(plan.aid_d)
-        if plan.sl_d is not None:
-            plan.sl_dev = put(plan.sl_d)
-        if plan.spec_lens is not None:
-            plan.lim_dev = put(self._pad_limits(
-                plan.spec_lens, int(plan.live_g.shape[0])))
+            plan.pf["group"] = self._to_device(plan.pf["group"])
+        plan.rows = self._to_device(plan.rows)
         self._iter_plan_h2d = self._h2d - h2d0
         if prof is not None:
             self._iter_stage_ms = (prof.lap() - t_stage) * 1e3
@@ -3941,7 +4032,8 @@ class PagedInferenceServer:
         patch = self._feed_patch(plan.d_lens, plan.d_last, plan.live_g,
                                  plan.d_tables)
         self._stage_program_kind(
-            plan.stats, plan.pf["chunk"].size if plan.kind == "mixed" else 0,
+            plan.stats,
+            plan.pf["chunk_tokens"] if plan.kind == "mixed" else 0,
             plan.live_g.size, plan.n_rounds, plan.g_iter, lora)
         if ahead:
             # who sets the pace, known here: had the program in flight
@@ -3950,7 +4042,6 @@ class PagedInferenceServer:
             infl = self._inflight
             infl.stats["host_late"] = infl.futures[0].is_ready()
         if plan.kind == "mixed":
-            pf = plan.pf
             # disaggregation handoff (such a plan waited for the
             # commit, `_launch_waits`): the plan's sel cursors equal
             # the committed ones — start the D2H copies for admissions
@@ -3959,17 +4050,13 @@ class PagedInferenceServer:
             (self.state, ptoks, plps, lens, last, (toks, lps, counts),
              assign) = \
                 _mixed_step(
-                    self.params, self.state, pf["chunk"], pf["widths"],
-                    pf["g_lens"], pf["g_tables"], pf["sample_at"],
-                    pf["slot_ids"], pf["prompt_rows"], pf["prompt_lens"],
-                    pf["samp_g"], pf["orig_lens"], pf["countm"],
-                    pf["scatm"], pf["gid_g"], pf["gst0_g"],
-                    patch, plan.d_stop, plan.samp_d, plan.gid_d,
-                    plan.sl_dev, plan.lim_dev, self._rng, grammar, lora,
-                    pf["aid_g"], plan.aid_d, self.draft_params,
+                    self.params, self.state, plan.pf["group"], patch,
+                    plan.rows, self._rng, grammar, lora,
+                    self.draft_params,
                     cfg=self.cfg, infer_cfg=self.infer_cfg,
                     n_rounds=plan.n_rounds, n_drafts=plan.g_iter,
-                    scatter_prompt=plan.scatter_prompt, mesh=self.mesh,
+                    scatter_prompt=plan.scatter_prompt,
+                    chunk_w=plan.chunk_w, mesh=self.mesh,
                     draft_cfg=self.draft_cfg,
                     use_rows_p=plan.use_rows_p,
                     use_bias_p=plan.use_bias_p,
@@ -3979,9 +4066,8 @@ class PagedInferenceServer:
         elif plan.g_iter > 0:
             (self.state, lens, last, (toks, lps, counts),
              assign) = _spec_rounds(
-                self.params, self.state, patch, plan.d_stop, self._rng,
-                plan.samp_d, plan.gid_d, grammar, lora, plan.aid_d,
-                self.draft_params, plan.sl_dev, plan.lim_dev,
+                self.params, self.state, patch, plan.rows, self._rng,
+                grammar, lora, self.draft_params,
                 cfg=self.cfg, infer_cfg=self.infer_cfg,
                 n_rounds=plan.n_rounds, n_drafts=plan.g_iter,
                 mesh=self.mesh, draft_cfg=self.draft_cfg,
@@ -3990,8 +4076,8 @@ class PagedInferenceServer:
         else:
             (self.state, lens, last, (toks, lps, counts),
              assign) = _decode_rounds(
-                self.params, self.state, patch, self._rng, plan.samp_d,
-                plan.gid_d, grammar, lora, plan.aid_d, plan.sl_dev,
+                self.params, self.state, patch, plan.rows, self._rng,
+                grammar, lora,
                 cfg=self.cfg, infer_cfg=self.infer_cfg,
                 n_rounds=plan.n_rounds, mesh=self.mesh,
                 use_rows=plan.use_rows_d, use_bias=plan.use_bias_d)

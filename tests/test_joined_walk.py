@@ -185,11 +185,25 @@ def _apart(params, state, group, decode, rng, *, cfg, icfg, n_rounds,
 def _mixed(params, state, group, decode, rng, *, cfg, icfg, n_rounds,
            use_rows, lower=False):
     fn = ps._mixed_step.lower if lower else ps._mixed_step
-    lengths, tables, last, live, *staged = decode
+    (chunk, widths, g_lens, g_tables, sample_at, slot_ids, prompt_rows,
+     prompt_lens, samp_g, orig_lens, count_mask, scatter_mask, gid_g,
+     gstate0_g) = jax.tree.map(np.asarray, group)
+    (lengths, tables, last, live, stop_len, samp_d, gid_d,
+     slot_ids_d) = jax.tree.map(np.asarray, decode)
     patch = jnp.asarray(ps._pack_patch(COUNT, lengths, last, live, tables))
-    return fn(params, state, *group, patch, *staged, None, rng,
+    packed = jnp.asarray(ps._pack_group(
+        chunk, g_tables, prompt_rows, samp_g, widths=widths, g_lens=g_lens,
+        sample_at=sample_at, slot_ids=slot_ids, prompt_lens=prompt_lens,
+        orig_lens=orig_lens, count_mask=count_mask,
+        scatter_mask=scatter_mask, gid=gid_g, gstate0=gstate0_g,
+        aid=np.zeros_like(gid_g)))
+    rows = jnp.asarray(ps._pack_rows(
+        stop_len, gid_d, np.zeros_like(gid_d), np.zeros_like(gid_d), samp_d,
+        slot_ids_d))
+    return fn(params, state, packed, patch, rows, rng,
               cfg=cfg, infer_cfg=icfg, n_rounds=n_rounds, n_drafts=0,
-              scatter_prompt=True, use_rows_p=use_rows, use_rows_d=use_rows)
+              scatter_prompt=True, chunk_w=chunk.shape[1],
+              use_rows_p=use_rows, use_rows_d=use_rows)
 
 
 SAMPLED = dataclasses.replace(GREEDY, temperature=1.0)

@@ -5,8 +5,14 @@ the device ONE host array (the packed patch, with the dispatch's count
 in it) and dispatches ONE program, which makes its own key from the
 server's one key and that count: no `jax.random.split` runs outside a
 trace, nothing that is on the device already is converted again, and
-the patch comes out of the program bit for bit as it went in.
+the patch comes out of the program bit for bit as it went in. What a
+plan stages while the program before it runs crosses the same way: the
+decode rows' launch-stable inputs as one packed array, a mixed plan's
+prefill group as a second, every field bit for bit.
 """
+
+import dataclasses
+import itertools
 
 import jax
 import jax.numpy as jnp
@@ -14,10 +20,13 @@ import numpy as np
 import pytest
 
 from cloud_server_tpu.config import InferConfig, ModelConfig
+from cloud_server_tpu.data.tokenizer import ByteTokenizer
 from cloud_server_tpu.inference import paged_server as ps
 from cloud_server_tpu.inference.paged_server import PagedInferenceServer
-from cloud_server_tpu.inference.sampling import SamplingParams
+from cloud_server_tpu.inference.sampling import (
+    MAX_LOGIT_BIAS, SamplingParams, SamplingRows, make_rows)
 from cloud_server_tpu.models import transformer
+from cloud_server_tpu.models.lora import LoRAConfig, init_lora_params
 
 CFG = ModelConfig(
     vocab_size=64, embed_dim=32, num_layers=2, num_heads=4, num_kv_heads=2,
@@ -134,16 +143,13 @@ def test_a_launch_hands_the_device_one_array(params, monkeypatch,
     assert set(counts) == {0, 1}
     assert sum(counts) == len(seen["launches"])
     # what the plan staged while the program before it ran, one transfer
-    # an array: 14 of the prefill group and the 12 leaves of its
-    # samplers (a mixed plan), `d_stop`, the decode rows' 12 sampler
-    # leaves, `gid_d`, `aid_d`, the rows' slots where rows are compacted,
-    # the draft limits where drafts run
+    # an array: the decode rows' packed buffer (token limits, grammars,
+    # adapters, draft limits, samplers, the rows' slots where rows are
+    # compacted) and, of a mixed plan, the prefill group's
     staged = {(kind, rows_are_slots): n for (kind, rows_are_slots, *_), n
               in zip(seen["launches"], seen["staged"])}
-    drafts = 1 if spec_drafts else 0
-    assert staged == {("mixed", False): 42 + drafts,
-                      ("decode", False): 16 + drafts,
-                      ("decode", True): 15 + drafts}
+    assert staged == {("mixed", False): 2, ("decode", False): 1,
+                      ("decode", True): 1}
     # on the record of the step that planned, beside that step's launch;
     # a plan whose rows all died at the commit was staged and not launched
     plans = [rec["plan_h2d"] for rec in srv.flight_window()]
@@ -194,11 +200,14 @@ def test_b_the_count_is_an_operand_and_moves_the_sample(params):
     a new count compiles nothing."""
     state, ledger = _decode_args()
     rng = jax.random.key(3)
+    zeros = np.zeros((4,), np.int32)
+    rows = jnp.asarray(ps._pack_rows(
+        zeros, zeros, zeros, zeros, make_rows([None] * 4, SAMPLED, zeros)))
 
     def dispatch(count):
         out = ps._decode_rounds(
             params, jax.tree.map(jnp.copy, state),
-            jnp.asarray(ps._pack_patch(count, *ledger)), rng, None,
+            jnp.asarray(ps._pack_patch(count, *ledger)), rows, rng,
             cfg=CFG, infer_cfg=SAMPLED, n_rounds=1)
         return np.asarray(out[3][0])
 
@@ -282,11 +291,242 @@ def test_c_a_patch_is_a_buffer_of_its_own():
     for a in (lengths, tables):
         assert not np.shares_memory(buf, a)
 
+# -- (f) what a plan stages: two packed arrays, one for decode rounds alone --
+
+def _bits(x):
+    x = np.asarray(x)
+    return x.view(np.int32) if x.dtype.itemsize == 4 else x
+
+
+def _same_bits(got, want):
+    got, want = np.asarray(got), np.asarray(want)
+    assert got.dtype == want.dtype and got.shape == want.shape
+    np.testing.assert_array_equal(_bits(got), _bits(want))
+
+
+def _sampler_rows(n, r):
+    """Rows a cast would not survive: a seed with its top bit set, a
+    negative bias value, `top_k` 0, floats with low mantissa bits."""
+    def f32(lo, hi):
+        return np.nextafter(r.uniform(lo, hi, n).astype(np.float32),
+                            np.float32(hi))
+    bias_ids = r.integers(0, 64, (n, MAX_LOGIT_BIAS), dtype=np.int32)
+    bias_ids[:, 3:] = ps.sampling._BIAS_PAD
+    rows = SamplingRows(
+        temperature=f32(0, 2), top_k=r.integers(0, 3, n, dtype=np.int32),
+        top_p=f32(0, 1), min_p=f32(0, 1), rep=f32(1, 2), pres=f32(-2, 2),
+        freq=f32(-2, 2),
+        seed=r.integers(2 ** 31, 2 ** 32, n, dtype=np.uint32),
+        bias_ids=bias_ids,
+        bias_vals=-r.uniform(0.1, 9, (n, MAX_LOGIT_BIAS)).astype(np.float32),
+        min_new=r.integers(0, 9, n, dtype=np.int32),
+        plen=r.integers(0, 2 ** 20, n, dtype=np.int32))
+    rows.top_k[0] = 0
+    return rows
+
+
+def test_f_the_sampler_columns_are_make_rows_own():
+    made = make_rows(
+        [SamplingParams(seed=2 ** 32 - 1, logit_bias=((3, -2.5),))],
+        SAMPLED, [0], [9])
+    assert [leaf.dtype for leaf in made] == list(ps._SAMP_DTYPES)
+    assert tuple(leaf[0].size for leaf in made) == ps._SAMP_WIDTHS
+    buf = np.empty((1, ps._SAMP_COLS), np.int32)
+    assert ps._pack_samp(buf, 0, made) == ps._SAMP_COLS
+    got, col = jax.jit(ps._unpack_samp, static_argnums=1)(
+        jnp.asarray(buf), 0)
+    assert col == ps._SAMP_COLS
+    for g, w in zip(got, made):
+        _same_bits(g, w)
+
+
+@pytest.mark.parametrize("rows,compacted", [
+    (1, True), (4, False), (8, True), (64, False), (64, True)])
+def test_f_rows_round_trip(rows, compacted):
+    r = np.random.default_rng(rows * 2 + compacted)
+    big = np.iinfo(np.int32).max
+    stop, gid, aid, limit = (r.integers(0, big, rows, dtype=np.int32)
+                             for _ in range(4))
+    slot_ids = (r.permutation(rows).astype(np.int32) if compacted
+                else None)
+    samp = _sampler_rows(rows, r)
+    buf = ps._pack_rows(stop, gid, aid, limit, samp, slot_ids)
+    assert buf.dtype == np.int32 and buf.flags.c_contiguous
+    assert buf.shape == (rows, ps._ROWS_HEAD + ps._SAMP_COLS + compacted)
+    (g_stop, g_gid, g_aid, g_limit, g_samp,
+     g_slots) = jax.jit(ps._unpack_rows)(jnp.asarray(buf))
+    for got, want in ((g_stop, stop), (g_gid, gid), (g_aid, aid),
+                      (g_limit, limit), *zip(g_samp, samp)):
+        _same_bits(got, want)
+    if compacted:
+        _same_bits(g_slots, slot_ids)
+    else:
+        assert g_slots is None
+    # a fresh buffer a plan: the transfer may read it after the call
+    assert not np.shares_memory(
+        buf, ps._pack_rows(stop, gid, aid, limit, samp, slot_ids))
+    assert not any(np.shares_memory(buf, a) for a in (stop, *samp))
+
+
+def _group_arrays(gp, w, pb, table_cols, r):
+    big = np.iinfo(np.int32).max
+    head = {name: r.integers(0, big, gp, dtype=np.int32)
+            for name in ps._GROUP_FIELDS}
+    head["count_mask"] = r.integers(0, 2, gp).astype(bool)
+    head["scatter_mask"] = r.integers(0, 2, gp).astype(bool)
+    return (r.integers(0, big, (gp, w), dtype=np.int32),
+            r.integers(0, big, (gp, table_cols), dtype=np.int32),
+            r.integers(0, big, (gp, pb), dtype=np.int32),
+            _sampler_rows(gp, r), head)
+
+
+# the last two are one packed shape: the static tells them apart
+GROUP_SHAPES = [(1, 16, 16, 8), (2, 16, 32, 8), (8, 256, 2048, 16),
+                (4, 64, 512, 37), (4, 512, 1024, 128), (4, 1024, 512, 128)]
+
+
+@pytest.mark.parametrize("gp,w,pb,table_cols", GROUP_SHAPES)
+def test_f_group_round_trip(gp, w, pb, table_cols):
+    r = np.random.default_rng(gp * 7 + w + pb)
+    chunk, tables, prompts, samp, head = _group_arrays(gp, w, pb,
+                                                       table_cols, r)
+    buf = ps._pack_group(chunk, tables, prompts, samp, **head)
+    assert buf.dtype == np.int32 and buf.flags.c_contiguous
+    assert buf.shape == (gp, ps._GROUP_HEAD + ps._SAMP_COLS + table_cols
+                         + w + pb)
+    got = jax.jit(ps._unpack_group, static_argnums=(1, 2))(
+        jnp.asarray(buf), w, table_cols)
+    assert set(got) == set(ps._GROUP_FIELDS) | {
+        "samp_rows", "g_tables", "chunk", "prompt_rows"}
+    for name in ps._GROUP_FIELDS:
+        _same_bits(got[name], head[name])
+    for g, want in zip(got["samp_rows"], samp):
+        _same_bits(g, want)
+    _same_bits(got["g_tables"], tables)
+    _same_bits(got["chunk"], chunk)
+    _same_bits(got["prompt_rows"], prompts)
+    assert not any(np.shares_memory(buf, a)
+                   for a in (chunk, tables, prompts, *samp))
+    with pytest.raises(AssertionError):
+        ps._pack_group(chunk, tables, prompts, samp,
+                       **{k: v for k, v in head.items() if k != "aid"})
+
+
+@pytest.mark.parametrize("prefill_chunk,prompt_buckets,max_context,pages", [
+    (16, [16, 32], 64, 8),            # this file's servers
+    (256, [512, 1024], 2048, 16),     # five chunk widths, three admissions
+    (1024, [512, 1024, 4096], 10240, 80),  # a chunk as wide as a prompt
+], ids=["tiny", "batch", "long"])
+def test_f_no_two_layouts_share_a_shape_and_statics(
+        prefill_chunk, prompt_buckets, max_context, pages):
+    """What a compiled program is keyed by (the operand's shape and the
+    static `chunk_w`) names one layout, over every group a server's
+    bucket sets can build; the rows' buffer needs no static."""
+    widths = ps._pow2_buckets(16, prefill_chunk)
+    admits = sorted(set(prompt_buckets) | {max_context})
+    r = np.random.default_rng(0)
+    keyed = {}
+    for gp, w, pb in itertools.product((1, 2, 4, 8), widths, admits):
+        chunk, tables, prompts, samp, head = _group_arrays(gp, w, pb,
+                                                           pages, r)
+        buf = ps._pack_group(chunk, tables, prompts, samp, **head)
+        assert keyed.setdefault((buf.shape, w), (gp, w, pb)) == (gp, w, pb)
+    assert len(keyed) == 4 * len(widths) * len(admits)
+    shared = len(keyed) - len({shape for shape, _ in keyed})
+    assert (shared > 0) == (prefill_chunk == 1024)
+    zeros = np.zeros((8,), np.int32)
+    samp = _sampler_rows(8, r)
+    assert ps._pack_rows(zeros, zeros, zeros, zeros, samp).shape \
+        != ps._pack_rows(zeros, zeros, zeros, zeros, samp, zeros).shape
+
+
+@pytest.mark.parametrize("kw", [
+    dict(), dict(spec_drafts=2), dict(spec_drafts=2, spec_control=False),
+], ids=["plain", "adaptive_drafts", "fixed_drafts"])
+def test_f_a_plan_hands_the_device_two_arrays_or_one(params, monkeypatch,
+                                                     kw):
+    """Counted at `_to_device` itself while `_plan_iteration` runs: a
+    mixed plan stages the group's buffer and the rows', decode rounds
+    alone the rows', each a 2-D int32 array; the record of the step that
+    planned says the same."""
+    srv = PagedInferenceServer(
+        params, CFG, GREEDY, scheduler="mixed", overlap=True,
+        flight_recorder_size=512, decode_chunk=1, **{**SRV_KW, **kw})
+    handed, plans, staged = [], [], []
+    put, plan_iteration = srv._to_device, srv._plan_iteration
+
+    def counted(host_array):
+        assert isinstance(host_array, np.ndarray)
+        handed.append(host_array)
+        return put(host_array)
+
+    def watched_plan():
+        del handed[:]
+        plan = plan_iteration()
+        if handed:  # also a plan staged and then dropped: its rows end
+            staged.append(len(handed))
+        if plan is not None:
+            assert all(a.dtype == np.int32 and a.ndim == 2 for a in handed)
+            plans.append((plan.kind, plan.sl_d is None, len(handed),
+                          srv._iter_plan_h2d))
+        return plan
+
+    monkeypatch.setattr(srv, "_to_device", counted)
+    monkeypatch.setattr(srv, "_plan_iteration", watched_plan)
+    first = [srv.submit(p, max_new_tokens=20) for p in (REP, PROMPTS[1])]
+    for _ in range(4):
+        srv.step()
+    rest = [srv.submit(p, max_new_tokens=n)
+            for p, n in ((LONG, 24), (PROMPTS[3], 12))]
+    srv.run_until_idle()
+    assert all(r.done for r in first + rest)
+    assert {(k, s) for k, s, *_ in plans} >= {
+        ("mixed", False), ("decode", False), ("decode", True)}
+    for kind, _, n, counted_by_server in plans:
+        assert n == counted_by_server == (2 if kind == "mixed" else 1)
+        assert n <= (4 if kind == "mixed" else 1)
+    recorded = [rec["plan_h2d"] for rec in srv.flight_window()]
+    assert {1, 2} <= set(recorded) <= {0, 1, 2}
+    assert [n for n in recorded if n] == staged
+
+
+def test_f_a_plan_without_a_decode_round_has_no_draft_limits(params):
+    """A planned step whose decode half was dropped (no page for another
+    round) while the controller holds draft lengths for the planned-live
+    slots: its rows' buffer carries no row, so no limit either. Before
+    PR 47 the padded limits of such a plan did not fit their one row and
+    the scheduler thread died on a ValueError."""
+    prompts = PROMPTS + [REP, [9, 9, 8]]
+
+    def run(**kw):
+        srv = PagedInferenceServer(params, CFG, GREEDY, spec_drafts=2,
+                                   flight_recorder_size=512,
+                                   **{**SRV_KW, **kw})
+        reqs = [srv.submit(p, max_new_tokens=10) for p in prompts[:2]]
+        for _ in range(3):
+            srv.step()
+        reqs += [srv.submit(p, max_new_tokens=10) for p in prompts[2:]]
+        srv.run_until_idle()
+        return srv, [r.result() for r in reqs]
+
+    srv, overlapped = run(scheduler="mixed", overlap=True)
+    assert any(rec.get("decode_rounds") == 0 and rec.get("prefill_tokens")
+               for rec in srv.flight_window())
+    assert run(scheduler="alternating")[1] == overlapped
+
 
 # -- (d) the three scheduler paths, greedy and seeded ------------------------
 
 SEEDED = [SamplingParams(seed=100 + i, temperature=0.9, top_p=0.9)
           for i in range(len(PROMPTS))]
+
+
+def _three_paths(run):
+    overlapped = run(scheduler="mixed", overlap=True)
+    assert run(scheduler="mixed", overlap=False, decode_chunk=1) \
+        == overlapped
+    assert run(scheduler="alternating") == overlapped
+    return overlapped
 
 
 @pytest.mark.parametrize("sampling", [None, SEEDED],
@@ -305,10 +545,62 @@ def test_d_paths_agree_token_for_token(params, sampling):
         srv.run_until_idle()
         return [r.result() for r in reqs]
 
-    overlapped = run(scheduler="mixed", overlap=True)
-    assert run(scheduler="mixed", overlap=False, decode_chunk=1) \
-        == overlapped
-    assert run(scheduler="alternating") == overlapped
+    _three_paths(run)
+
+
+# every field of the packed buffers in play at once: a sampler row with
+# its seed's top bit set, a filter chain, penalties and a bias of either
+# sign; a row under a grammar; a row under an adapter; a seeded row.
+# Drafts at their fixed length: every row's draft limit is the
+# dispatch's width, and a seeded stream is the same on every path
+BYTES = ByteTokenizer()
+CFG_BYTES = dataclasses.replace(CFG, vocab_size=300)
+EOS_BYTES = dataclasses.replace(GREEDY, eos_token_id=BYTES.eos_id)
+LOADED = [
+    SamplingParams(seed=2 ** 31 + 12345, temperature=0.8, top_k=20,
+                   top_p=0.95, min_p=0.01, repetition_penalty=1.1,
+                   presence_penalty=0.3, frequency_penalty=0.2,
+                   logit_bias=((7, -1.5), (9, 2.25)), min_tokens=3),
+    SamplingParams(regex=r"[0-9]{4,6}"),
+    None,
+    SamplingParams(seed=5, temperature=0.7),
+]
+
+
+@pytest.mark.parametrize("spec_drafts", [0, 2], ids=["plain", "drafts"])
+def test_d_paths_agree_with_every_packed_field_in_play(spec_drafts):
+    params = transformer.init_params(CFG_BYTES, jax.random.key(0))
+    lcfg = LoRAConfig(rank=4, alpha=8.0, targets=("wq", "wv"))
+    lora = init_lora_params(CFG_BYTES, lcfg, jax.random.key(1))
+    for i, name in enumerate(sorted(lora["layers"])):
+        shape = lora["layers"][name]["b"].shape
+        lora["layers"][name]["b"] = 0.3 * jax.random.normal(
+            jax.random.key(50 + i), shape)
+    adapters = [None, None, "tuned", None]
+
+    def run(**kw):
+        srv = PagedInferenceServer(
+            params, CFG_BYTES, EOS_BYTES, seed=len(kw), tokenizer=BYTES,
+            spec_drafts=spec_drafts, spec_control=False,
+            **{**SRV_KW, **kw})
+        srv.add_adapter("tuned", lora, lcfg)
+        rows = list(zip(PROMPTS, LOADED, adapters))
+        reqs = [srv.submit(p, max_new_tokens=8, sampling=s, adapter=a)
+                for p, s, a in rows[:2]]
+        for _ in range(3):
+            srv.step()
+        reqs += [srv.submit(p, max_new_tokens=8, sampling=s, adapter=a)
+                 for p, s, a in rows[2:]]
+        srv.run_until_idle()
+        return [r.result() for r in reqs]
+
+    outs = _three_paths(run)
+    digits = BYTES.decode(outs[1])
+    assert digits.isdigit() and 4 <= len(digits) <= 6
+    # the adapter's row is not the base model's
+    base = PagedInferenceServer(params, CFG_BYTES, EOS_BYTES, tokenizer=BYTES,
+                                **SRV_KW)
+    assert base.generate([PROMPTS[2]], max_new_tokens=8)[0] != outs[2]
 
 
 # -- (e) the patch of a launch made ahead of the commit ----------------------
