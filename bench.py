@@ -313,20 +313,12 @@ def serving_bench():
     # — guarded so a churn-time failure cannot void the headline
     # decode rows measured above
     try:
-        out.update(_admission_churn_bench(params_bf16, base, infer_cfg))
+        out.update(_churn_scenario(params_bf16, base, infer_cfg))
     except Exception as exc:  # noqa: BLE001
         print(f"[serving_bench] churn skipped after error: {exc!r}",
               flush=True)
         out["churn_error"] = repr(exc)[:160]
-    # async double-buffered scheduler A/B on the same mix (item 4's
-    # acceptance measurement; same guard discipline)
-    try:
-        out.update(_overlap_churn_bench(params_bf16, base, infer_cfg))
-    except Exception as exc:  # noqa: BLE001
-        print(f"[serving_bench] churn_overlap skipped after error: "
-              f"{exc!r}", flush=True)
-        out["churn_overlap_error"] = repr(exc)[:160]
-    # speculation-under-churn three-way A/B (same guard discipline)
+    # speculation-under-churn A/B (same guard discipline)
     try:
         out.update(_spec_churn_bench(params_bf16, base, infer_cfg))
     except Exception as exc:  # noqa: BLE001
@@ -565,7 +557,7 @@ def _anomaly_forensics_bench(params, base, infer_cfg):
     srv = PagedInferenceServer(
         params, cfg, icfg, max_slots=16, max_context=1024,
         page_size=128, prefill_chunk=256, decode_chunk=8,
-        prompt_buckets=[64, 256], scheduler="mixed",
+        prompt_buckets=[64, 256],
         anomaly=anomaly_cfg, faults=fp)
     mk_prompt = make_prompt_fn(0)
 
@@ -1132,24 +1124,19 @@ def _prefix_cache_churn_bench(params, base, infer_cfg):
 
 
 def _spec_churn_bench(params, base, infer_cfg):
-    """Speculation composed with stall-free batching, the PR 9 win: a
-    three-way A/B under admission churn on a repetition-heavy prompt
-    mix (the n-gram sweet spot — code/tables-like local repetition):
+    """Speculation composed with stall-free batching: an A/B under
+    admission churn on a repetition-heavy prompt mix (the n-gram sweet
+    spot — code/tables-like local repetition):
 
-      * `churn_spec_*`            — mixed + ADAPTIVE n-gram speculation
-                                    (the default controller);
-      * `churn_spec_mixed_plain_*` — mixed, no speculation (what the
+      * `churn_spec_*`            — ADAPTIVE n-gram speculation (the
+                                    default controller);
+      * `churn_spec_mixed_plain_*` — no speculation (what the
                                     speculative arm must beat for the
-                                    window to pay under churn);
-      * `churn_spec_alternating_spec_*` — alternating + fixed-length
-                                    n-gram speculation (paying the
-                                    churn cliff mixed batching fixed).
+                                    window to pay under churn).
 
-    A fourth arm, `churn_spec_draft_model_*`, drives the composition
-    this PR made POSSIBLE — DRAFT-MODEL speculation under the mixed
-    scheduler (pre-PR it silently forced alternating; mixed+n-gram
-    always worked) — through the same churn scenario, one fused
-    dispatch per iteration. Its accept rate reflects the random-init
+    A third arm, `churn_spec_draft_model_*`, drives DRAFT-MODEL
+    speculation through the same churn scenario, one fused dispatch
+    per iteration. Its accept rate reflects the random-init
     draft here (the controller walks poor acceptors off); trained
     draft-model acceptance is measured by the trained_spec section.
 
@@ -1179,10 +1166,10 @@ def _spec_churn_bench(params, base, infer_cfg):
         mlp_dim=1024)
     draft_params = transformer.init_params(draft_cfg, jax.random.key(11))
 
-    def scenario(scheduler, spec, spec_control, rep, draft=False):
+    def scenario(spec, spec_control, rep, draft=False):
         # every arm (and each arm's warm-up vs timed run) draws the
         # IDENTICAL prompt sequence: the A/B ratios must compare
-        # schedulers, not prompt-mix noise
+        # the arms, not prompt-mix noise
         rng = np.random.RandomState(3)
 
         def mk(n):
@@ -1193,11 +1180,10 @@ def _spec_churn_bench(params, base, infer_cfg):
         srv = PagedInferenceServer(
             params, cfg, greedy, max_slots=16, max_context=1024,
             page_size=128, prefill_chunk=256, decode_chunk=8,
-            prompt_buckets=[64, 256, 512], scheduler=scheduler,
+            prompt_buckets=[64, 256, 512],
             spec_drafts=spec, spec_control=spec_control,
             draft_params=draft_params if draft else None,
             draft_cfg=draft_cfg if draft else None)
-        assert srv._mixed_enabled == (scheduler == "mixed")
         first = [srv.submit(mk(64), max_new_tokens=256)
                  for _ in range(8)]
         for _ in range(2):
@@ -1205,8 +1191,7 @@ def _spec_churn_bench(params, base, infer_cfg):
         t0 = time.perf_counter()
         r0, c0 = srv.decode_rounds, srv.decode_tokens_committed
         waves = []
-        # three waves of long admissions while the first batch decodes:
-        # the regime where alternating+spec used to stall
+        # three waves of long admissions while the first batch decodes
         for _ in range(3):
             waves += [srv.submit(mk(400), max_new_tokens=128)
                       for _ in range(4)]
@@ -1229,18 +1214,16 @@ def _spec_churn_bench(params, base, infer_cfg):
 
     out = {}
     arms = {
-        # (scheduler, spec_drafts, spec_control, repetitive, draft)
-        "churn_spec": ("mixed", 3, None, True, False),  # adaptive dflt
-        "churn_spec_mixed_plain": ("mixed", 0, False, True, False),
-        "churn_spec_alternating_spec": ("alternating", 3, False, True,
-                                        False),
-        "churn_spec_draft_model": ("mixed", 3, None, True, True),
-        "spec_adaptive_random": ("mixed", 3, None, False, False),
-        "spec_plain_random": ("mixed", 0, False, False, False),
+        # (spec_drafts, spec_control, repetitive, draft)
+        "churn_spec": (3, None, True, False),  # adaptive dflt
+        "churn_spec_mixed_plain": (0, False, True, False),
+        "churn_spec_draft_model": (3, None, True, True),
+        "spec_adaptive_random": (3, None, False, False),
+        "spec_plain_random": (0, False, False, False),
     }
-    for tag, (sched, spec, ctl, rep, draft) in arms.items():
-        scenario(sched, spec, ctl, rep, draft)  # warm-up compiles
-        tok_s, accept, itl_p99 = scenario(sched, spec, ctl, rep, draft)
+    for tag, (spec, ctl, rep, draft) in arms.items():
+        scenario(spec, ctl, rep, draft)  # warm-up compiles
+        tok_s, accept, itl_p99 = scenario(spec, ctl, rep, draft)
         out[f"{tag}_tok_s"] = tok_s
         out[f"{tag}_itl_ms_p99"] = itl_p99
         if spec:
@@ -1252,114 +1235,13 @@ def _spec_churn_bench(params, base, infer_cfg):
     out["churn_spec_speedup_vs_plain"] = (
         out["churn_spec_tok_s"]
         / max(out["churn_spec_mixed_plain_tok_s"], 1e-9))
-    out["churn_spec_speedup_vs_alternating"] = (
-        out["churn_spec_tok_s"]
-        / max(out["churn_spec_alternating_spec_tok_s"], 1e-9))
     out["spec_adaptive_floor_ratio"] = (
         out["spec_adaptive_random_tok_s"]
         / max(out["spec_plain_random_tok_s"], 1e-9))
-    print(f"[serving_bench] churn_spec speedups: "
-          f"{out['churn_spec_speedup_vs_plain']:.2f}x vs mixed-plain, "
-          f"{out['churn_spec_speedup_vs_alternating']:.2f}x vs "
-          f"alternating+spec; adaptive floor "
+    print(f"[serving_bench] churn_spec speedup: "
+          f"{out['churn_spec_speedup_vs_plain']:.2f}x vs plain; "
+          f"adaptive floor "
           f"{out['spec_adaptive_floor_ratio']:.2f}", flush=True)
-    return out
-
-
-def _admission_churn_bench(params, base, infer_cfg):
-    """Continuous batching under churn, A/B over the scheduler: requests
-    arrive in waves while others decode. "alternating" runs admissions
-    (chunked prefill) as separate dispatches interleaved with decode
-    dispatches; "mixed" fuses both into one token-budget dispatch per
-    iteration (stall-free scheduling — the r6 tentpole).
-
-    Each scenario runs TWICE: once untimed to compile every dispatch
-    shape it triggers (r3's churn_tok_s=2.4 timed ~370 s of remote
-    Mosaic compiles, not serving), then timed with all shapes warm.
-    Reports completed-token throughput, interleaved-decode count, the
-    decode throughput SUSTAINED WHILE ADMISSIONS RUN (the number mixed
-    scheduling exists to lift — alternating r5 landed only 10 decode
-    steps across the whole admission phase), and the request-level
-    latencies chunked prefill exists to bound: TTFT for the long
-    prompts that land mid-decode, and inter-token-latency percentiles
-    for the requests decoding while those admissions run.
-
-    The headline `churn_*` keys are the MIXED run (the default
-    scheduler); `churn_*_alternating` / `churn_*_mixed` carry the A/B
-    and `churn_mixed_speedup` the ratio."""
-    out = {}
-    for sched in ("alternating", "mixed"):
-        res = _churn_scenario(params, base, infer_cfg, sched)
-        out.update({f"{k}_{sched}": v for k, v in res.items()})
-        print(f"[serving_bench] {sched}: churn_tok_s "
-              f"{res['churn_tok_s']:.1f} decode_tok_s_during_admission "
-              f"{res['churn_decode_tok_s_during_admission']:.1f} "
-              f"ttft_ms p50/p95: {res['churn_ttft_ms_p50']:.0f}/"
-              f"{res['churn_ttft_ms_p95']:.0f} "
-              f"itl_ms p50/p99: {res['churn_itl_ms_p50']:.1f}/"
-              f"{res['churn_itl_ms_p99']:.1f}", flush=True)
-        if sched == "mixed":
-            out.update(res)  # headline keys = the default scheduler
-    out["churn_mixed_speedup"] = (out["churn_tok_s_mixed"]
-                                  / max(out["churn_tok_s_alternating"],
-                                        1e-9))
-    print(f"[serving_bench] churn_mixed_speedup: "
-          f"{out['churn_mixed_speedup']:.2f}x", flush=True)
-    return out
-
-
-def _overlap_churn_bench(params, base, infer_cfg):
-    """Async double-buffered scheduler A/B (ROADMAP item 4's
-    acceptance measurement): the SAME churn mix on the mixed
-    scheduler with the launch-ahead pipeline ON vs OFF.
-
-    The decisive key is `churn_host_gap_frac_overlap_{on,off}`: off
-    measures the full serialized host cost per iteration (sweep +
-    admission + build + commit + epilogue over duration); on measures
-    only the residual tail the overlap could NOT hide (commit +
-    launch + epilogue) — per the flight records' phase clocks, not
-    inferred from tok/s. `churn_overlap_speedup` is the end-to-end
-    tok/s ratio, and the per-phase p50s land alongside so a
-    regression is attributable to a specific phase. The overlap-on
-    arm also reports how long the device ran ahead of the host
-    needing results (`churn_overlap_launch_lead_ms_p50`) and what
-    fraction of busy iterations actually pipelined."""
-    out = {}
-    res = {}
-    for tag, ov in (("off", False), ("on", True)):
-        r = _churn_scenario(params, base, infer_cfg, "mixed",
-                            overlap=ov)
-        res[tag] = r
-        out.update({f"{k}_overlap_{tag}": v for k, v in r.items()})
-        print(f"[serving_bench] overlap_{tag}: churn_tok_s "
-              f"{r['churn_tok_s']:.1f} host_gap_frac "
-              f"{r['churn_host_gap_frac']:.4f} itl_ms p50/p99: "
-              f"{r['churn_itl_ms_p50']:.1f}/"
-              f"{r['churn_itl_ms_p99']:.1f}", flush=True)
-    out["churn_overlap_speedup"] = (
-        res["on"]["churn_tok_s"] / max(res["off"]["churn_tok_s"], 1e-9))
-    out["churn_overlap_gap_reduction"] = (
-        res["off"]["churn_host_gap_frac"]
-        - res["on"]["churn_host_gap_frac"])
-    # acceptance: the overlap must MEASURABLY hide host work — the
-    # residual serialized host gap strictly below the sequential gap
-    # on the same mix. The AssertionError surfaces through the
-    # serving-bench section guard as a `churn_overlap_error` key in
-    # the bench JSON (the other sections' failure convention), so a
-    # regression is visible in the artifact without voiding the
-    # headline decode rows — and a CPU rig, where XLA executes
-    # idle-queue dispatches inline so overlap cannot show, records
-    # the error key instead of a bogus pass.
-    
-    assert (out["churn_host_gap_frac_overlap_on"]
-            < out["churn_host_gap_frac_overlap_off"]), (
-        "overlap-on host_gap_frac "
-        f"{out['churn_host_gap_frac_overlap_on']:.4f} not below "
-        f"overlap-off {out['churn_host_gap_frac_overlap_off']:.4f}")
-    print(f"[serving_bench] churn_overlap_speedup: "
-          f"{out['churn_overlap_speedup']:.2f}x, host_gap "
-          f"{out['churn_host_gap_frac_overlap_off']:.4f} -> "
-          f"{out['churn_host_gap_frac_overlap_on']:.4f}", flush=True)
     return out
 
 
@@ -1407,7 +1289,18 @@ _CHURN_SLO_CFG = {
                             "e2e_s": 300.0}}}
 
 
-def _churn_scenario(params, base, infer_cfg, scheduler, overlap=None):
+def _churn_scenario(params, base, infer_cfg):
+    """Continuous batching under churn: requests arrive in waves while
+    others decode, and every step fuses the chunked prefills with the
+    decode rows in one token-budget dispatch.
+
+    The scenario runs TWICE: once untimed to compile every dispatch
+    shape it triggers, then timed with all shapes warm. Reports
+    completed-token throughput, interleaved-decode count, the decode
+    throughput SUSTAINED WHILE ADMISSIONS RUN, and the request-level
+    latencies chunked prefill exists to bound: TTFT for the long
+    prompts that land mid-decode, and inter-token-latency percentiles
+    for the requests decoding while those admissions run."""
     import dataclasses
 
     from cloud_server_tpu.inference.paged_server import PagedInferenceServer
@@ -1425,8 +1318,8 @@ def _churn_scenario(params, base, infer_cfg, scheduler, overlap=None):
         srv = PagedInferenceServer(
             params, cfg, infer_cfg, max_slots=16, max_context=1024,
             page_size=128, prefill_chunk=256, decode_chunk=8,
-            prompt_buckets=[64, 256, 512], scheduler=scheduler,
-            overlap=overlap, tracing=1.0, slo=_CHURN_SLO_CFG)
+            prompt_buckets=[64, 256, 512],
+            tracing=1.0, slo=_CHURN_SLO_CFG)
         mk_prompt = make_prompt_fn(0)
 
         first = [srv.submit(mk_prompt(64), max_new_tokens=256)
@@ -1499,18 +1392,17 @@ def _churn_scenario(params, base, infer_cfg, scheduler, overlap=None):
     util = [rec["budget_utilization"] for rec in flight
             if "budget_utilization" in rec]
     # Iteration-phase profile of the same run: the host-gap fraction
-    # is the serialized host cost per iteration — sequential records
-    # count every non-device phase; overlapped records (the async
-    # scheduler, ROADMAP item 4 — built) count only the residual
-    # commit/launch/epilogue tail, with the hidden sweep/admission/
-    # build in overlap_ms. The per-record identity host_ms +
-    # device_wait_ms + overlap_ms == duration_ms is asserted (the
-    # phase clock partitions the iteration by construction).
-    ph_recs = [rec for rec in flight if "phases_ms" in rec]
+    # is the serialized host cost per iteration, over the records of
+    # steps that committed a program: the residual commit/launch/
+    # epilogue tail, with the hidden sweep/admission/build/deliver in
+    # overlap_ms. The per-record identity host_ms + device_wait_ms +
+    # overlap_ms == duration_ms is asserted (the phase clock
+    # partitions the iteration by construction).
+    ph_recs = [rec for rec in flight if "host_ms" in rec]
     assert ph_recs, "profiling-enabled run produced no phase records"
     for rec in ph_recs:
         assert abs(rec["host_ms"] + rec["device_wait_ms"]
-                   + rec.get("overlap_ms", 0.0)
+                   + rec["overlap_ms"]
                    - rec["duration_ms"]) <= 1e-6 * rec["duration_ms"] \
             + 1e-6, f"phase split does not partition the iteration: {rec}"
     host_gap = (sum(r["host_ms"] for r in ph_recs)
@@ -1555,8 +1447,8 @@ def _churn_scenario(params, base, infer_cfg, scheduler, overlap=None):
             "churn_budget_utilization_mean":
                 sum(util) / len(util) if util else 0.0,
             # host-gap attribution (iteration_profile.py): the share
-            # of each iteration the device idles while the host works
-            # — ROADMAP item 4's claimable headroom, per phase
+            # of each iteration in the host's serialized tail, per
+            # phase
             "churn_host_gap_frac": host_gap,
             **phase_keys}
 

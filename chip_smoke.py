@@ -52,8 +52,7 @@ REQUIRED_PLATFORM = "tpu"
 DEADLINE_S = 1150.0           # the whole run, compilation included
 MAX_LEN = 4096                # serving context (the config declares 131,072)
 # jit names of the paged server's device programs (inference/paged_server.py)
-SERVE_PROGRAMS = ["_mixed_step", "_prefill_chunk", "_decode_rounds",
-                  "_spec_rounds"]
+SERVE_PROGRAMS = ["_mixed_step", "_decode_rounds", "_spec_rounds"]
 KERNEL_TESTS = ["tests/test_paged_attention.py",
                 "tests/test_flash_attention.py", "tests/test_fused_ce.py",
                 "tests/test_moe.py"]

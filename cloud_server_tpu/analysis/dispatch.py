@@ -39,17 +39,20 @@ Rules:
     scheduler's ``_launch_plan`` replays statics the planner already
     computed through those same bounded helpers). A raw ``len(...)``,
     a request field, or any other data-dependent value flags.
-  * ``DD5 overlap write-safety`` — the async double-buffered
-    scheduler plans iteration N+1 WHILE iteration N's dispatch is in
-    flight. A page released during that window can be re-allocated to
-    a new admission while the device still writes it, so the
-    functions in ``OVERLAP_PLAN_FUNCS`` (the plan/launch path and the
-    deferred sweep) must never reach — directly or transitively
-    through same-class helpers — any of the page-releasing /
-    slot-teardown functions in ``PAGE_RELEASING_FUNCS``. Releases
-    belong to the commit (``_commit_inflight`` / ``_apply_reaps``)
-    and to the sequential paths, which only run with nothing in
-    flight.
+  * ``DD5 overlap write-safety`` — the scheduler plans iteration
+    N+1 WHILE iteration N's dispatch is in flight. A page released
+    during that window can be re-allocated to a new admission while
+    the device still writes it, so a plan made under a dispatch in
+    flight must never release one: the functions in
+    ``OVERLAP_PLAN_FUNCS`` (the plan/launch path and the sweep) must
+    never reach — directly or transitively through same-class
+    helpers — any of the page-releasing / slot-teardown functions in
+    ``PAGE_RELEASING_FUNCS``. Releases belong to the commit
+    (``_commit_inflight`` / ``_apply_reaps``) and to a plan made with
+    nothing in flight: the one exemption is a call in the body of an
+    ``if <in-flight dispatch> is None:`` statement, where the tested
+    expression is ``self._inflight`` or a local bound once, by
+    ``name = self._inflight``, in that function.
 
 Stdlib-only (ast); never imports jax or the serving stack.
 """
@@ -74,24 +77,19 @@ CHECKER = "dispatch-discipline"
 SCHEDULER_LOOPS: dict[str, tuple[str, ...]] = {
     "cloud_server_tpu/inference/paged_server.py": (
         "PagedInferenceServer.step",
-        "PagedInferenceServer._step_sequential",
         "PagedInferenceServer.serve_forever",
-        "PagedInferenceServer._step_overlap",
         "PagedInferenceServer._plan_iteration",
         "PagedInferenceServer._launch_plan",
         "PagedInferenceServer._commit_inflight",
-        "PagedInferenceServer._overlap_sweep",
+        "PagedInferenceServer._sweep",
         "PagedInferenceServer._apply_reaps",
         "PagedInferenceServer._extend_chains_planned",
         "PagedInferenceServer._build_prefill_group",
         "PagedInferenceServer._select_prefill",
         "PagedInferenceServer._expire_pending",
-        "PagedInferenceServer._sweep_cancelled",
         "PagedInferenceServer._start_admissions",
-        "PagedInferenceServer._run_one_chunk",
-        "PagedInferenceServer._decode_dispatch",
-        "PagedInferenceServer._mixed_dispatch",
         "PagedInferenceServer._commit_decode_rows",
+        "PagedInferenceServer._complete_admission_chunks",
         "PagedInferenceServer._record_iteration",
         "PagedInferenceServer._stage_decode_spans",
         "PagedInferenceServer._stage_spec_stats",
@@ -103,7 +101,6 @@ SCHEDULER_LOOPS: dict[str, tuple[str, ...]] = {
         "PagedInferenceServer._mixed_rounds",
         "PagedInferenceServer._extend_chains",
         "PagedInferenceServer._preempt_youngest",
-        "PagedInferenceServer._rem_bucket",
         "PagedInferenceServer._ensure_penalty_state",
         "PagedInferenceServer._emit",
         "PagedInferenceServer._finish",
@@ -141,18 +138,14 @@ SCHEDULER_LOOPS: dict[str, tuple[str, ...]] = {
     ),
 }
 
-# The ONE sanctioned per-iteration host sync per dispatch path: these
-# are the commit points where the sampled tokens come home. Everything
-# else on the loop must stay async.
+# The ONE sanctioned per-iteration host sync: the commit point where
+# the sampled tokens come home. Everything else on the loop must stay
+# async.
 SANCTIONED_SYNCS: dict[str, tuple[str, ...]] = {
     "cloud_server_tpu/inference/paged_server.py": (
-        "PagedInferenceServer._run_one_chunk",
-        "PagedInferenceServer._decode_dispatch",
-        "PagedInferenceServer._mixed_dispatch",
-        # async scheduler: the launch-ahead dispatch's commit point —
-        # still ONE device_get per committed iteration; _launch_plan
-        # itself must stay sync-free (DD2 covers it like every other
-        # loop function)
+        # a launched dispatch's commit point: ONE device_get per
+        # committed iteration; _launch_plan itself must stay sync-free
+        # (DD2 covers it like every other loop function)
         "PagedInferenceServer._commit_inflight",
         # live migration: the request export's KV gather — ONE sync per
         # migration, at the commit point (inflight work committed
@@ -162,14 +155,14 @@ SANCTIONED_SYNCS: dict[str, tuple[str, ...]] = {
     ),
 }
 
-# DD5: the async scheduler's plan/launch path — everything that runs
-# while a dispatch may be in flight — and the page-releasing functions
-# it must never reach. Transitive through same-class helper calls.
+# DD5: the plan/launch path — everything that runs while a dispatch
+# may be in flight — and the page-releasing functions it must never
+# reach there. Transitive through same-class helper calls.
 OVERLAP_PLAN_FUNCS: dict[str, tuple[str, ...]] = {
     "cloud_server_tpu/inference/paged_server.py": (
         "PagedInferenceServer._plan_iteration",
         "PagedInferenceServer._extend_chains_planned",
-        "PagedInferenceServer._overlap_sweep",
+        "PagedInferenceServer._sweep",
         "PagedInferenceServer._launch_plan",
         "PagedInferenceServer._build_prefill_group",
         "PagedInferenceServer._select_prefill",
@@ -183,13 +176,13 @@ OVERLAP_PLAN_FUNCS: dict[str, tuple[str, ...]] = {
 }
 PAGE_RELEASING_FUNCS = frozenset({
     "_release_slot", "_preempt_youngest", "_finish", "_extend_chains",
-    "_fail_all", "_sweep_cancelled",
+    "_fail_all", "_apply_reaps",
     # allocator page release (self.allocator.release / the lock-free
     # variants); plan-path code may alloc, never release
     "release",
 })
 
-# DD4: reviewed fields of the async scheduler's _Plan snapshot that
+# DD4: reviewed fields of the scheduler's _Plan snapshot that
 # are bounded BY CONSTRUCTION — _plan_iteration computes them through
 # the same audited helpers this pass already trusts (n_rounds via the
 # _mixed_rounds/_chunk_rounds pow2 planners, g_iter via _spec_plan's
@@ -230,7 +223,6 @@ BOUNDED_HELPERS = {
     "_pad_pow2",       # next power of two, log2-many values
     "_bucket",         # fixed bucket table lookup
     "_chunk_bucket",   # a prefill group's chunk width, through _bucket
-    "_rem_bucket",     # bucket table / prefill_chunk multiples
     "_chunk_rounds",   # power-of-two round planner (paged)
     "_mixed_rounds",   # power-of-two round planner (mixed budget)
     "_spec_plan",      # draft width quantized to {0, spec_drafts}
@@ -561,16 +553,47 @@ def check_scheduler_source(path: str, source: str,
 def check_overlap_source(path: str, source: str,
                          plan_quals: tuple[str, ...]) -> list[Finding]:
     """DD5 over one server module: no page-releasing function is
-    reachable from the overlap plan path — directly, or transitively
-    through same-class ``self.*`` helper calls."""
+    reachable from the plan path under a dispatch in flight — directly,
+    or transitively through same-class ``self.*`` helper calls. What
+    stands in the body of ``if <the in-flight dispatch> is None:`` runs
+    with nothing in flight and is not walked."""
     tree = ast.parse(source, filename=path)
     found, classes = collect_functions(tree)
     out: list[Finding] = []
 
-    def self_calls(fn: ast.AST):
-        """(leaf name, node) for every self.X(...) / X(...) call."""
+    def nothing_in_flight(fn: ast.AST) -> set[int]:
+        """ids of the nodes under an ``if X is None:`` body, X being
+        ``self._inflight`` or a local this function binds once, from
+        it."""
+        bound: dict[str, int] = {}
+        alias: set[str] = set()
         for node in ast.walk(fn):
-            if not isinstance(node, ast.Call):
+            if isinstance(node, ast.Assign):
+                for t in node.targets:
+                    if isinstance(t, ast.Name):
+                        bound[t.id] = bound.get(t.id, 0) + 1
+                        if _dotted(node.value) == "self._inflight":
+                            alias.add(t.id)
+        names = {"self._inflight"} | {a for a in alias if bound[a] == 1}
+        exempt: set[int] = set()
+        for node in ast.walk(fn):
+            if (isinstance(node, ast.If)
+                    and isinstance(node.test, ast.Compare)
+                    and len(node.test.ops) == 1
+                    and isinstance(node.test.ops[0], ast.Is)
+                    and _dotted(node.test.left) in names
+                    and isinstance(node.test.comparators[0], ast.Constant)
+                    and node.test.comparators[0].value is None):
+                for stmt in node.body:
+                    exempt.update(id(n) for n in ast.walk(stmt))
+        return exempt
+
+    def self_calls(fn: ast.AST):
+        """(leaf name, node) for every self.X(...) / X(...) call made
+        while a dispatch may be in flight."""
+        exempt = nothing_in_flight(fn)
+        for node in ast.walk(fn):
+            if not isinstance(node, ast.Call) or id(node) in exempt:
                 continue
             name = _dotted(node.func)
             if name is None:
@@ -661,7 +684,8 @@ register_pass(Pass(
     id=CHECKER,
     title="one sanctioned device_get per scheduler iteration, jax-free "
           "host-policy modules, statically bounded jit static "
-          "arguments, and a release-free overlap plan path",
+          "arguments, and a plan path that releases no page under a "
+          "dispatch in flight",
     run=check_dispatch,
     roster=lambda root: tuple(SCHEDULER_LOOPS) + HOST_POLICY_MODULES,
 ))

@@ -312,28 +312,6 @@ class InferConfig:
     top_p: float = 1.0  # 1.0 => disabled
     eos_token_id: int = -1  # -1 => never stop early
     pad_token_id: int = 0
-    # Paged-server scheduling under admission churn
-    # (PagedInferenceServer constructor arguments of the same names
-    # override these defaults):
-    #   "mixed" — stall-free token-budget batching: chunked prefills
-    #     piggyback on decode batches in one ragged dispatch, so decode
-    #     never stalls behind an admission (Sarathi-style).
-    #   "alternating" — separate prefill-chunk and decode dispatches
-    #     per scheduler step (the pre-mixed behavior; the fallback).
-    scheduler: str = "mixed"
-    # Async double-buffered scheduling (paged server, MIXED scheduler
-    # only — the alternating scheduler always keeps its sequential
-    # per-chunk loop). True (the default) overlaps host policy work —
-    # sweep, QoS/DRR admission, deadline checks, and the numpy
-    # dispatch build — with the device executing the PREVIOUS
-    # iteration's fused program: each step plans iteration N+1 against
-    # the last committed ledger while iteration N runs, then pays only
-    # the sanctioned device_get commit (+ a cheap ledger patch and the
-    # next launch) on the serialized critical path. False restores the
-    # byte-identical sequential loop (plan -> dispatch -> sync ->
-    # commit per step, nothing in flight across steps). Constructor
-    # argument `overlap=` / the CLI's `--no-overlap` override.
-    overlap: bool = True
     # Tokens per mixed iteration: all live decode rows (times their
     # round count) plus however many prefill-chunk tokens fit. 0 = auto:
     # max_slots * (decode window * decode_chunk + prefill_chunk) —
@@ -451,8 +429,6 @@ class InferConfig:
     bundle_on_anomaly: bool = False
 
     def __post_init__(self) -> None:
-        if self.scheduler not in ("mixed", "alternating"):
-            raise ValueError(f"unknown scheduler: {self.scheduler!r}")
         if self.flight_recorder_size <= 0:
             raise ValueError("flight_recorder_size must be positive")
         if not 0.0 <= self.trace_sample_rate <= 1.0:

@@ -92,25 +92,6 @@ def build_parser() -> argparse.ArgumentParser:
                    help="paged server: admission window width — long "
                    "prompts prefill in chunks this wide, interleaved with "
                    "decode dispatches so inter-token latency stays bounded")
-    p.add_argument("--scheduler", choices=["mixed", "alternating"],
-                   default="mixed",
-                   help="paged server scheduling under admission churn: "
-                   "'mixed' (default) fuses chunked prefills and decode "
-                   "rows into one token-budget dispatch per iteration "
-                   "(stall-free — decodes advance during every prefill); "
-                   "'alternating' keeps separate prefill and decode "
-                   "dispatches (the pre-mixed behavior)")
-    p.add_argument("--no-overlap", action="store_true",
-                   help="disable the async double-buffered scheduler "
-                   "(launch-ahead pipelining): by default each "
-                   "iteration's host policy work — sweep, QoS/DRR "
-                   "admission, deadline checks, the numpy dispatch "
-                   "build — runs WHILE the device executes the "
-                   "previous iteration's program, leaving only the "
-                   "commit on the serialized path. This flag restores "
-                   "the strictly sequential plan->dispatch->sync->"
-                   "commit loop (byte-identical pre-overlap behavior; "
-                   "outputs are token-identical either way)")
     p.add_argument("--mixed-token-budget", type=int, default=0,
                    help="mixed scheduler: tokens per fused iteration "
                    "(decode rows first, prefill fills the rest; 0 = auto: "
@@ -429,8 +410,6 @@ def main(argv=None) -> None:
             spec_control=args.spec_control,
             prefill_chunk=prefill_chunk, seed=args.seed,
             allocation=args.allocation,
-            scheduler=args.scheduler,
-            overlap=False if args.no_overlap else None,
             mixed_token_budget=args.mixed_token_budget,
             flight_recorder_size=args.flight_recorder or None,
             draft_params=draft_params, draft_cfg=draft_cfg,

@@ -216,7 +216,8 @@ class AnomalyWatchdog:
             pair[1] += self.alpha_slow * (value - pair[1])
         return pair[0], pair[1]
 
-    def observe_iteration(self, *, now: float, host_gap_frac: float = 0.0,
+    def observe_iteration(self, *, now: float,
+                          host_gap_frac: float | None = 0.0,
                           pending: int = 0, preempt_delta: int = 0,
                           cache_lookup_delta: int = 0,
                           cache_hit_delta: int = 0,
@@ -224,7 +225,9 @@ class AnomalyWatchdog:
         """Fold one busy iteration's signals; returns the rules that
         activated on this call. All inputs are numbers the scheduler's
         `_record_iteration` already computed for the flight record —
-        no measurement of its own, no clock read."""
+        no measurement of its own, no clock read. `host_gap_frac` None:
+        the step waited on no program (a fill) and has no gap to
+        read; the rule's averages stand as they are."""
         burn = None
         # analysis: allow[lock-discipline] scheduler-thread-only
         # counter read: burn_rates takes the SLO tracker's own leaf
@@ -248,7 +251,7 @@ class AnomalyWatchdog:
                 self._last_true.pop("wedged", None)
                 self._open.pop("wedged")["end"] = now
 
-            if self._enabled["host_gap"]:
+            if self._enabled["host_gap"] and host_gap_frac is not None:
                 fast, slow = self._shift("host_gap", host_gap_frac)
                 th = self._th["host_gap"]
                 firing = (warm and fast > th["min_frac"]

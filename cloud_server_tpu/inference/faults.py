@@ -22,10 +22,9 @@ unconfigured server runs the byte-identical pre-fault code):
   * ``submit_reject``    — submit() raises `InjectedFault`:
                            exercises router failover on submit and
                            client 503 handling.
-  * ``dispatch``         — the next dispatch path raises
-                           `InjectedFault` before launching device
-                           work (`_mixed_dispatch` /
-                           `_decode_dispatch` / `_run_one_chunk`):
+  * ``dispatch``         — step() raises `InjectedFault` before
+                           its plan, once a step that has something
+                           to dispatch and before any device work:
                            the scheduler thread crashes exactly the
                            way a poisoned
                            device program would, driving
@@ -366,9 +365,10 @@ class OverloadDetector:
 
     def observe(self, *, pending_age_s: float = 0.0,
                 budget_utilization: float = 0.0,
-                host_gap_frac: float = 0.0) -> int:
+                host_gap_frac: float | None = 0.0) -> int:
         """Fold one busy iteration's signals in; returns the current
-        level. Called by the scheduler once per busy iteration; one
+        level. `host_gap_frac` None: the step waited on no program (a
+        fill) and has no gap to fold in. Called by the scheduler once per busy iteration; one
         monotonic clock read (the detector keeps its OWN timebase so
         hysteresis and staleness compare like with like)."""
         now = self._clock()
@@ -379,8 +379,9 @@ class OverloadDetector:
                                         - ew["pending_age_s"])
             ew["budget_utilization"] += a * (budget_utilization
                                              - ew["budget_utilization"])
-            ew["host_gap_frac"] += a * (host_gap_frac
-                                        - ew["host_gap_frac"])
+            if host_gap_frac is not None:
+                ew["host_gap_frac"] += a * (host_gap_frac
+                                            - ew["host_gap_frac"])
             crossed = sum(1 for name, th in self._thresholds.items()
                           if ew[name] > th)
             raw = 2 if crossed >= 2 else (1 if crossed else 0)

@@ -83,9 +83,8 @@ Endpoints:
                     the iteration profiler on (the default) — an
                     `iteration_profile` summary (per-phase
                     count/mean/p50/p99 ms + host_gap_frac) and an
-                    `overlap` block (the async double-buffered
-                    scheduler's resolved knob state + live pipeline
-                    depth). Paged backends add a `cache` block (the
+                    `overlap` block (the pipeline's live depth and
+                    `launch_ahead_share`). Paged backends add a `cache` block (the
                     /debug/cache payload).
   GET  /debug/scheduler_trace  Chrome-trace/Perfetto export of the
                     flight recorder's recent window (?n=K, default
@@ -93,7 +92,7 @@ Endpoints:
                     admission / build / device / commit / launch /
                     epilogue) plus an iteration track carrying each
                     record's scalars, and an `inflight` track whose
-                    slices render the async scheduler's
+                    slices render the scheduler's
                     launched-ahead dispatches CONCURRENT with the
                     iteration that commits them. Same perf_counter
                     timebase as /traces, and every event tags its
@@ -731,8 +730,8 @@ class HttpFrontend:
         cfn = getattr(self.srv, "cache_stats", None)
         if cfn is not None:
             payload["cache"] = cfn()
-        # async double-buffered scheduler: the knob's resolved state
-        # and the live pipeline depth (single-server debug view; the
+        # the scheduler's pipeline: its live depth and the share of
+        # dispatches launched ahead (single-server debug view; the
         # per-iteration overlap fields ride in flight_recorder records
         # and the folded `overlap` phase in iteration_profile)
         ofn = getattr(self.srv, "overlap_stats", None)

@@ -3,12 +3,11 @@ hot loop.
 
 The flight recorder (PR 3) stamps every busy scheduler iteration with
 one `duration_ms` — enough to see that an iteration was slow, not
-enough to say WHERE the time went. Before the async double-buffered
-scheduler (ROADMAP item 4) can claim to overlap host policy work with
-device compute, the measurement layer must exist: per-phase
-attribution of every iteration, so the host-gap the overlap will hide
-is a measured number (`host_gap_frac`), not an inference from
-end-to-end tok/s.
+enough to say WHERE the time went. The scheduler overlaps host policy
+work with device compute; this is the measurement layer under that
+claim: per-phase attribution of every iteration, so the host gap the
+pipeline hides, and the part it does not, are measured numbers
+(`host_gap_frac`), not inferences from end-to-end tok/s.
 
 Phase taxonomy (one contiguous partition of the iteration, stamped at
 boundaries the scheduler already crosses):
@@ -24,48 +23,48 @@ boundaries the scheduler already crosses):
                 the only phase that waits on the accelerator
     commit      ledger writes, tokens recorded on their requests,
                 grammar / speculation bookkeeping on the synced
-                results, up to the next phase's first statement. On the
-                sequential paths the delivery of those tokens too; an
-                overlapped step wakes nobody here
-    launch      (async scheduler only) the patch + next-dispatch
-                launch. In the steady state it comes BEFORE `device`:
-                the next program goes onto the device's queue while the
-                one in flight still runs, from the planned frame, and
-                `device`, `commit` and the rest follow under it (the
-                record of the dispatch says so: `launch_ahead`). Where
-                the launch needs what only the commit knows
-                (`launch_waits`: draft tokens, a constrained row, a
-                hand-off) it follows the commit, patched from the
-                ledger: the tail of the serialized critical path
-    deliver     (async scheduler only) the commit's stream calls and
-                completions, run after the launch: the streaming
-                threads they wake take the interpreter lock under the
-                program just launched, not between two programs (6 ms
-                of an 8 ms commit with 64 clients attached before;
-                PERF.md, PR 25 and PR 30)
+                results, up to the next phase's first statement. Nobody
+                is woken here
+    launch      the patch + next-dispatch launch. In the steady state
+                it comes BEFORE `device`: the next program goes onto
+                the device's queue while the one in flight still runs,
+                from the planned frame, and `device`, `commit` and the
+                rest follow under it (the record of the dispatch says
+                so: `launch_ahead`). Where the launch needs what only
+                the commit knows (`launch_waits`: draft tokens, a
+                constrained row, a hand-off) it follows the commit,
+                patched from the ledger: the tail of the serialized
+                critical path
+    deliver     the commit's stream calls and completions, run after
+                the launch: the streaming threads they wake take the
+                interpreter lock under the program just launched, not
+                between two programs (6 ms of an 8 ms commit with 64
+                clients attached before; PERF.md, PR 25 and PR 30)
     epilogue    flight-recorder / tracing / SLO bookkeeping at the end
                 of the iteration
 
-SEQUENTIAL iterations (overlap off, or nothing in flight):
-`host_gap_frac` = (everything except `device`) / duration — the
-fraction of each iteration the device sits idle while the host works.
-
-OVERLAPPED iterations (the async double-buffered scheduler, ROADMAP
-item 4 — now built): sweep / admission / build run WHILE the device
-executes the previous iteration's program, and deliver while it
-executes the next one's, so they are no longer device-idle time.
-Those phases fold into `overlap_ms` (and the single
-`overlap`-labeled histogram series), `device` becomes the RESIDUAL
-wait after the overlapped host work, and `host_gap_frac` measures
-only the host tail (`commit` + `launch` + `epilogue`) — the residual
-cost the overlap could not hide where the launch waits for the commit.
-In a step that launched ahead that tail is the same work and the same
-`host_ms`, but it too runs beside a program (`launch` under the one in
-flight, `commit` and `epilogue` under the one just queued): there
-`host_gap_frac` is the tail's share of the step, an upper bound on the
-idle share, and whether the device stood idle at all is the record's
-`host_late`. The per-record identity becomes
+A step that COMMITS a program (every busy step but a fill; its record
+says `overlap: true`): sweep / admission / build run WHILE the device
+executes the program the step commits, and deliver while it executes
+the next one's, so they are not device-idle time. Those phases fold
+into `overlap_ms` (and the single `overlap`-labeled histogram series),
+`device` is the RESIDUAL wait after the overlapped host work, and
+`host_gap_frac` measures only the host tail (`commit` + `launch` +
+`epilogue`) — the residual cost the pipeline could not hide where the
+launch waits for the commit. In a step that launched ahead that tail is
+the same work and the same `host_ms`, but it too runs beside a program
+(`launch` under the one in flight, `commit` and `epilogue` under the
+one just queued): there `host_gap_frac` is the tail's share of the
+step, an upper bound on the idle share, and whether the device stood
+idle at all is the record's `host_late`. The per-record identity is
 `host_ms + device_wait_ms + overlap_ms == duration_ms`.
+
+A FILL step (nothing was in flight: it plans and launches, and commits
+nothing; its record says `fill: true`) has phases and a duration but
+waited on no program: its record carries no `host_ms`,
+`device_wait_ms`, `overlap_ms` or `host_gap_frac`, and its phases go
+into their own histogram series, unfolded, where `profile_summary`
+counts them as host time.
 
 Outside that identity, and in no phase: `between_ms`, from a busy
 step's `end()` to the next step's `begin()` (the loop's yield to the
@@ -119,22 +118,19 @@ from time import perf_counter
 from cloud_server_tpu.utils.serving_metrics import histogram_percentile
 
 # Canonical phase order — the contiguous partition of one iteration.
-# `launch` and `deliver` only appear in iterations of the async
-# scheduler.
 PHASES = ("sweep", "admission", "build", "device", "commit", "launch",
           "deliver", "epilogue")
 
-# Phases that run concurrently with the in-flight device program when
-# the async double-buffered scheduler has a dispatch outstanding; they
-# fold into the `overlap` histogram label and `overlap_ms`.
+# Phases that run concurrently with a device program in a step that
+# commits one; they fold into the `overlap` histogram label and
+# `overlap_ms`.
 OVERLAP_PHASES = ("sweep", "admission", "build", "deliver")
 
 # Histogram label set: the fine-grained phases plus the folded
-# `overlap` series overlapped iterations observe instead of their
+# `overlap` series committing steps observe instead of their
 # sweep/admission/build/deliver split (keeping `profile_summary`'s
-# host-gap arithmetic honest across sequential and overlapped
-# iterations — the fine split of overlapped iterations stays in the
-# flight records).
+# host-gap arithmetic honest across fill and committing steps — the
+# fine split of a committing step stays in its flight record).
 HIST_PHASES = PHASES + ("overlap",)
 
 # Millisecond bucket ladder for the per-phase histograms: sub-0.1 ms
@@ -157,7 +153,7 @@ _ITERATION_EVENT = "sched/iteration"
 
 # Flight-record scalars worth carrying into the Perfetto iteration
 # track's args (post-mortem context next to the phase bars).
-_ITER_ARG_KEYS = ("iteration", "scheduler", "n_live", "decode_rounds",
+_ITER_ARG_KEYS = ("iteration", "fill", "n_live", "decode_rounds",
                   "decode_tokens", "prefill_tokens", "tokens_scheduled",
                   "budget_utilization", "host_ms", "device_wait_ms",
                   "host_gap_frac", "preemptions", "pending", "n_jobs",
@@ -175,10 +171,10 @@ class IterationProfiler:
     boundary goes to the phase that was open, and `phase` opens
     (entering the phase that is already open is no boundary: no clock
     read, no event). Time ACCUMULATES per phase, so a phase visited
-    several times in one iteration — e.g. `build`/`device` per chunk on
-    the alternating scheduler — sums. `end()` closes a busy iteration.
+    several times in one iteration sums. `end()` closes a busy
+    iteration.
     All three return the boundary's timestamp so callers reuse it
-    instead of reading the clock again: the mixed scheduler pays a
+    instead of reading the clock again: the scheduler pays a
     bounded constant number of `perf_counter` reads per iteration
     (asserted by test).
 
@@ -326,30 +322,20 @@ def resolve_profiler(profile, cfg_enabled: bool = True,
 
 
 def derive_gap_fields(phases_ms: dict[str, float],
-                      duration_ms: float,
-                      overlapped: bool = False) -> dict[str, float]:
-    """The derived flight-record fields from one iteration's phase
-    split: host milliseconds (the SERIALIZED host work), the device
-    wait, and the host-gap fraction of the iteration.
-
-    Sequential iterations (`overlapped=False`): host = everything
-    except `device` — the historical definition, byte-identical.
-    Overlapped iterations: sweep/admission/build and deliver ran
-    concurrently with a device program, so they move into `overlap_ms`;
-    `host_ms` keeps only the residual serialized tail (commit + launch
-    + epilogue) and `host_gap_frac` therefore measures what the
-    overlap could NOT hide."""
+                      duration_ms: float) -> dict[str, float]:
+    """The derived flight-record fields of a step that committed a
+    program, from its phase split: sweep/admission/build and deliver
+    ran concurrently with a device program, so they are `overlap_ms`;
+    `host_ms` is the residual serialized tail (commit + launch +
+    epilogue), `device_wait_ms` the wait for the program, and
+    `host_gap_frac` therefore measures what the pipeline could NOT
+    hide."""
     device = phases_ms.get("device", 0.0)
-    if overlapped:
-        overlap = sum(phases_ms.get(p, 0.0) for p in OVERLAP_PHASES)
-        host = sum(v for k, v in phases_ms.items()
-                   if k != "device" and k not in OVERLAP_PHASES)
-        return {"host_ms": host, "device_wait_ms": device,
-                "overlap_ms": overlap,
-                "host_gap_frac": host / duration_ms if duration_ms > 0
-                else 0.0}
-    host = sum(v for k, v in phases_ms.items() if k != "device")
+    overlap = sum(phases_ms.get(p, 0.0) for p in OVERLAP_PHASES)
+    host = sum(v for k, v in phases_ms.items()
+               if k != "device" and k not in OVERLAP_PHASES)
     return {"host_ms": host, "device_wait_ms": device,
+            "overlap_ms": overlap,
             "host_gap_frac": host / duration_ms if duration_ms > 0
             else 0.0}
 
@@ -381,9 +367,9 @@ def profile_summary(snapshot: dict) -> dict | None:
         if phase == "device":
             device_ms += entry["sum"]
         elif phase == "overlap":
-            # host work performed while a dispatch was in flight (the
-            # async scheduler's hidden OVERLAP_PHASES): not
-            # device-idle time, so not host gap
+            # host work performed while a dispatch was in flight (a
+            # committing step's OVERLAP_PHASES): not device-idle time,
+            # so not host gap
             overlap_ms += entry["sum"]
         else:
             host_ms += entry["sum"]
@@ -411,16 +397,14 @@ def scheduler_chrome_trace(records: list[dict]) -> dict:
     doing that iteration").
 
     Phases render laid out consecutively in canonical order inside
-    the iteration window; on the alternating scheduler a phase's bar
-    is its per-iteration SUM (chunks interleave build/device several
-    times), so bar order within an iteration is attribution, not a
-    literal interleaving. Records written with profiling disabled
+    the iteration window: a step that launched ahead crossed `launch`
+    before `device`, so bar order within an iteration is attribution,
+    not a literal interleaving. Records written with profiling disabled
     carry no `t_start`/`phases_ms` and are skipped.
 
-    OVERLAPPED iterations (the async double-buffered scheduler) are
-    NOT disjoint in device time: the program committed by iteration
-    k+1 was launched inside iteration k's window. Each record that
-    launched ahead carries `t_launch`, and the export renders an
+    Iterations are NOT disjoint in device time: the program committed
+    by iteration k+1 was launched inside iteration k's window. Each
+    record of a step that launched carries `t_launch`, and the export renders an
     `inflight` track whose slices span from that launch to the END of
     the NEXT record's residual `device` wait — so the device slice
     visibly runs CONCURRENT with (nested under) the next iteration's

@@ -17,28 +17,24 @@ What the paged design buys over one contiguous cache row a slot:
     dispatches (`prefill_chunk` tokens each), so one long prompt never
     stalls active decodes for its whole prefill — inter-token latency
     stays bounded (the serving bench measures it).
-  * STALL-FREE MIXED BATCHING (scheduler="mixed", the default): while
-    any admission is in flight, each scheduler iteration fuses ONE
-    ragged prefill group (every admitting slot the token budget
-    selected, each at its own width — no remainder-bucket grouping) and
-    the full multi-round decode dispatch into a single jitted program
-    with a single host sync. The alternating scheduler (kept as
-    scheduler="alternating") instead pays one dispatch + sync per
-    admission group plus one per decode dispatch, and shrinks decode to
-    `admit_decode_chunk` rounds whenever admissions are running — which
-    is exactly the churn cliff the r5 bench measured (decode collapsing
-    to ~10 steps across a whole admission phase). Greedy and seeded
-    outputs are token-for-token identical under both schedulers
+  * STALL-FREE MIXED BATCHING: while any admission is in flight, each
+    scheduler iteration fuses ONE ragged prefill group (every admitting
+    slot the token budget selected, each at its own width, no
+    remainder-bucket grouping) and the full multi-round decode
+    dispatch into a single jitted program with a single host sync
+    (`_mixed_step`), so decode keeps its round count while admissions
+    run and a landing prompt never waits out a decode dispatch. Greedy
+    and seeded outputs are token-for-token the dense engine's
     (tests/test_mixed_scheduler.py). `mixed_token_budget` caps the
     tokens packed per iteration (decode rows first, prefill fills the
     rest, one minimal chunk guaranteed so TTFT stays bounded); the
     default is work-conserving.
-  * Decode batch COMPACTION (both schedulers): decode dispatches carry
-    one row per LIVE slot (pow2-padded) with a slot_ids indirection
-    into the per-slot device state, so attention gathers and matmuls
-    scale with occupancy instead of max_slots — a half-admitted batch
-    no longer pays full-batch decode cost. Fully-live batches skip the
-    indirection entirely (the pre-compaction program).
+  * Decode batch COMPACTION: decode dispatches carry one row per LIVE
+    slot (pow2-padded) with a slot_ids indirection into the per-slot
+    device state, so attention gathers and matmuls scale with
+    occupancy instead of max_slots: a half-admitted batch does not pay
+    full-batch decode cost. Fully-live batches skip the indirection
+    entirely.
   * Speculative decoding IS the decode loop (spec_drafts > 0): per-slot
     n-gram proposals drafted on device from each slot's token history,
     verified batch-wide in one W = drafts+1 window, committed per slot
@@ -47,10 +43,9 @@ What the paged design buys over one contiguous cache row a slot:
     No draft model, no extra memory; repetition-heavy decodes commit
     several tokens per model pass. With a DRAFT MODEL
     (`draft_params`/`draft_cfg`) the classic draft/verify loop runs the
-    same way — and BOTH sources now compose with the mixed scheduler:
-    the draft model's chunk prefill and per-round decode discipline are
-    part of the one fused `_mixed_step` program, so speculation no
-    longer forces the alternating scheduler.
+    same way, and BOTH sources compose with mixed batching: the draft
+    model's chunk prefill and per-round decode discipline are part of
+    the one fused `_mixed_step` program.
   * ADAPTIVE speculation (on by default whenever spec_drafts > 0;
     `spec_control=` / `--spec-control`, inference/spec_control.py): a
     host-side controller tracks a rolling accept rate per slot from
@@ -64,43 +59,42 @@ What the paged design buys over one contiguous cache row a slot:
     committed tokens while rejected draft work lands on a per-tenant
     wasted-speculation counter.
 
-  * ASYNC DOUBLE-BUFFERED SCHEDULING (`InferConfig.overlap` /
-    `overlap=`, default on; mixed scheduler only): JAX dispatch is
-    async, so the scheduler pipelines the loop instead of serializing
-    host policy against the device. Each step plans iteration N+1 —
-    sweep, QoS/DRR admission, deadline checks, chain growth, and the
-    whole numpy dispatch build — against the last COMMITTED ledger
-    plus the in-flight dispatch's deterministic effects (job cursors
-    advance by the takes it was launched with; planned lengths use
-    the worst-case rounds*window bound) WHILE the device executes
-    iteration N; then it LAUNCHES N+1 onto the device's queue behind N
-    — lengths, live flags and table rows from that planned frame, which
-    is exact while no draft tokens are in play, and each decode row's
-    last token from the per-slot copy every step program leaves on the
-    device (`state["last"]`; the patch says row by row which) — and
-    only then pays the one sanctioned `device_get` commit of N, under
-    N+1. The chip passes from one program to the next with no host in
-    between, and nothing of the host's loop is serialized against it
-    while the loop is shorter than the program. Where the launch needs
-    what only the commit knows (draft tokens, a constrained row, a
-    hand-off to prefetch: `_launch_waits`, read per iteration from the
-    plan) the step takes the older order for that iteration — commit,
-    patch from the just-committed ledger, launch — and `host_gap_frac`
-    in the flight records measures that residual tail. Write-safety:
-    while a dispatch is in flight the PLANNER never releases pages (no
-    preemption, no slot teardown — sweep reaps are deferred to just
-    after the commit), statically enforced by the dispatch-discipline
-    pass's DD5 rule. A COMMIT does release the pages of the rows that
-    end in it, while the dispatch launched ahead may still write them:
-    every later writer of a page takes the pools from `self.state`,
-    that dispatch's output, and so is ordered behind it
-    (`_launch_plan`). On page famine the plan degrades its round count
-    and the pipeline drains so the next sequential iteration can run
-    the full preemption escalation. Greedy and seeded outputs are
-    token-for-token identical with overlap on or off (scheduling is
-    output-invariant by the same property the mixed/alternating
-    parity pins); overlap=False falls back to the byte-identical
-    sequential loop.
+  * ONE WAY THROUGH A STEP: PLAN, LAUNCH, COMMIT (`step`). JAX
+    dispatch is async, so the scheduler pipelines the loop instead of
+    serializing host policy against the device. Each step plans
+    iteration N+1 — sweep, QoS/DRR admission, deadline checks, chain
+    growth, and the whole numpy dispatch build — against the last
+    COMMITTED ledger plus the in-flight dispatch's deterministic
+    effects (job cursors advance by the takes it was launched with;
+    planned lengths use the worst-case rounds*window bound) WHILE the
+    device executes iteration N; then it LAUNCHES N+1 onto the device's
+    queue behind N — lengths, live flags and table rows from that
+    planned frame, which is exact while no draft tokens are in play,
+    and each decode row's last token from the per-slot copy every step
+    program leaves on the device (`state["last"]`; the patch says row
+    by row which) — and only then pays the one sanctioned `device_get`
+    commit of N, under N+1. The chip passes from one program to the
+    next with no host in between, and nothing of the host's loop is
+    serialized against it while the loop is shorter than the program.
+    Where the launch needs what only the commit knows (draft tokens, a
+    constrained row, a hand-off to prefetch: `_launch_waits`, read per
+    iteration from the plan) the step commits N first, patches from
+    the just-committed ledger and launches, and `host_gap_frac` in the
+    flight records measures that residual tail. With nothing in flight
+    (a cold start, a drained pipeline) the step FILLS the pipeline: it
+    plans against the committed ledger and launches, and the next step
+    commits. Write-safety: a plan made under a dispatch in flight never
+    releases pages (no preemption, no slot teardown — sweep reaps are
+    deferred to just after the commit), statically enforced by the
+    dispatch-discipline pass's DD5 rule. A COMMIT does release the
+    pages of the rows that end in it, while the dispatch launched ahead
+    may still write them: every later writer of a page takes the pools
+    from `self.state`, that dispatch's output, and so is ordered behind
+    it (`_launch_plan`). On page famine such a plan degrades its round
+    count and the pipeline drains; the fill's plan, made with nothing
+    in flight, runs the full preemption escalation. Greedy and seeded
+    outputs are token-for-token identical whichever order a step takes:
+    scheduling is output-invariant (tests/test_overlap.py).
 
 Scheduling state is HOST-authoritative (tables, lengths, active,
 last_token live in numpy and ride into each dispatch as small inputs);
@@ -482,12 +476,11 @@ def _prefill_core(params, state, chunk, g_lens, g_tables, sample_at,
                   cfg: ModelConfig, infer_cfg: InferConfig,
                   scatter_prompt: bool, mesh=None, draft_cfg=None,
                   use_rows: bool = False, use_bias: bool = False):
-    """One admission window for a (padded) G-row group — the traced body
-    shared by `_prefill_chunk` (alternating scheduler: uniform chunk
-    width per group) and `_mixed_step` (mixed scheduler: RAGGED per-row
-    `widths`, since the token budget hands every admitting row a
-    different width in the same call, and a per-row `scatter_mask`,
-    since rows at different admission progress share one dispatch).
+    """One admission window for a (padded) G-row group: the prefill
+    half of `_mixed_step`. The group is RAGGED: per-row `widths`, since
+    the token budget hands every admitting row a different width in the
+    same call, and a per-row `scatter_mask`, since rows at different
+    admission progress share one dispatch.
 
     chunk: (G, Wc) tokens for positions [g_lens, g_lens + Wc) per row —
     rows at different offsets, which is how shared prefixes resume deeper
@@ -617,34 +610,6 @@ def _prefill_core(params, state, chunk, g_lens, g_tables, sample_at,
     # (`_complete_admission_chunks`), the device keeps it from now
     _keep_last(new_state, state, slot_ids, count_mask, toks)
     return new_state, toks, lps
-
-
-@partial(jax.jit,
-         static_argnames=("cfg", "infer_cfg", "scatter_prompt", "mesh",
-                          "draft_cfg", "use_rows", "use_bias"),
-         donate_argnums=(1,))
-def _prefill_chunk(params, state, chunk, g_lens, g_tables, sample_at,
-                   slot_ids, prompt_rows, prompt_lens, rng, count,
-                   samp_rows, orig_lens, count_mask,
-                   gid=None, gstate0=None, grammar=None,
-                   lora=None, aid=None, draft_params=None, widths=None,
-                   scatter_mask=None, *,
-                   cfg: ModelConfig, infer_cfg: InferConfig,
-                   scatter_prompt: bool, mesh=None, draft_cfg=None,
-                   use_rows: bool = False, use_bias: bool = False):
-    """Alternating-scheduler admission dispatch: `_prefill_core` at one
-    uniform chunk width per group (`widths`/`scatter_mask` default to
-    None: every row full-width, every row scattering on its first
-    chunk), under the server's key with the dispatch's `count` folded
-    in (`_unpack_patch`)."""
-    return _prefill_core(
-        params, state, chunk, g_lens, g_tables, sample_at, slot_ids,
-        prompt_rows, prompt_lens, jax.random.fold_in(rng, count),
-        samp_rows, orig_lens, count_mask, gid, gstate0, grammar, lora,
-        aid, draft_params, widths, scatter_mask,
-        cfg=cfg, infer_cfg=infer_cfg, scatter_prompt=scatter_prompt,
-        mesh=mesh, draft_cfg=draft_cfg, use_rows=use_rows,
-        use_bias=use_bias)
 
 
 @jax.named_scope("decode_rounds")
@@ -1103,18 +1068,17 @@ def _mixed_step(params, state, group, patch, rows, rng, grammar=None,
     the draft model's chunk prefill rides inside `_prefill_core`
     (ragged widths included) and its per-round G+1 decode discipline
     rides inside `_spec_core`, so the fastest decode path keeps
-    stall-free batching instead of forcing the alternating scheduler.
-    Draft rounds are funded as decode rows under the token budget — a
-    live slot's decode claim is window = n_drafts + 1 tokens per round,
-    charged against prefill funding by the host's budget split.
+    stall-free batching. Draft rounds are funded as decode rows under
+    the token budget — a live slot's decode claim is window =
+    n_drafts + 1 tokens per round, charged against prefill funding by
+    the host's budget split.
 
     This is what "fused" means here and why it is stall-free WITHOUT
-    extra compute: the alternating scheduler pays one host round trip
-    per admission group PLUS one per decode dispatch each iteration, and
-    shrinks decode to `admit_decode_chunk` (default 1) rounds while any
-    admission is in flight; the mixed program keeps decode at its full
-    round count and retires every prefill chunk in the same dispatch, so
-    decode throughput under churn stays at its steady-state slope.
+    extra compute: decode keeps its full round count while admissions
+    run, and every prefill chunk retires in the same dispatch, so
+    decode throughput under churn stays at its steady-state slope and
+    a step pays one host round trip, not one per admission group and
+    one more for the decode rows.
 
     ONE WALK OF THE LAYERS where that computes the same function
     (`_walks_once`: one plain decode round, no drafts, no draft model,
@@ -1123,11 +1087,10 @@ def _mixed_step(params, state, group, patch, rows, rng, grammar=None,
     in one call (`paged_engine.forward_sets`), so a step streams them
     once, not twice; each half keeps its own cache write, paged kernel,
     unembed and sampler, and everything of the two cores around their
-    forward runs as it is (they are handed the logits). Elsewhere the
-    halves are exactly the alternating dispatches' traced bodies, one
-    walk each. Greedy/seeded outputs are token-for-token identical to
-    the alternating scheduler's either way
-    (tests/test_mixed_scheduler.py).
+    forward runs as it is (they are handed the logits). Elsewhere each
+    half walks the layers itself. Greedy/seeded outputs are
+    token-for-token the dense engine's either way
+    (tests/test_mixed_scheduler.py, tests/test_joined_walk.py).
 
     The decode half's lengths, tables, last tokens and live flags
     arrive as the one packed `patch`, and `rng` is the server's one key:
@@ -1225,35 +1188,32 @@ class _Slot:
 
 @dataclasses.dataclass
 class _AdmitJob:
-    """An in-flight chunked admission: one bucketed group of slots
-    (alternating scheduler) or ONE slot with token-granular progress
-    (mixed scheduler — `done` advances by whatever width the budget
-    granted that iteration, so chunk_w/n_chunks are unused there)."""
+    """One slot's chunked admission, with token-granular progress:
+    `done` advances by whatever width the token budget granted its
+    chunk, so admissions stay individually preemptible and there is no
+    fixed chunk schedule."""
 
-    slots: list[int]
-    chunk_w: int
-    n_chunks: int
-    rows: np.ndarray               # (G, n_chunks*chunk_w) remainder tokens
-    rem_lens: np.ndarray           # (G,) true remainder lengths
-    base_lens: np.ndarray          # (G,) shared_len per row
-    prompt_rows: np.ndarray        # (G, prompt_bucket)
-    prompt_lens: np.ndarray        # (G,)
-    toks: np.ndarray               # captured first-token candidates
-    lps: np.ndarray
-    got: np.ndarray                # bool — sample captured yet
-    next_chunk: int = 0
-    done: int = 0                  # mixed: remainder tokens prefilled
-    # async scheduler: remainder tokens DISPATCHED (committed done +
-    # whatever the in-flight dispatch carries). The overlap planner
-    # selects chunks from this cursor so a launch-ahead iteration can
-    # never re-prefill tokens already in flight; `done` catches up at
-    # each commit, and the two are equal whenever nothing is in flight.
+    slot: int
+    rows: np.ndarray               # (rem_len,) the tokens to prefill
+    rem_len: int
+    base_len: int                  # the slot's shared_len
+    prompt_row: np.ndarray         # (prompt_len,) the whole prompt
+    prompt_len: int
+    tok: int = 0                   # captured first-token candidate
+    lp: float = 0.0
+    got: bool = False              # sample captured yet
+    done: int = 0                  # remainder tokens prefilled (committed)
+    # remainder tokens DISPATCHED (committed done + whatever the
+    # in-flight dispatch carries). The planner selects chunks from this
+    # cursor so a plan never re-prefills tokens already in flight;
+    # `done` catches up at each commit, and the two are equal whenever
+    # nothing is in flight.
     planned: int = 0
 
 
 @dataclasses.dataclass
 class _Plan:
-    """An immutable-by-convention PLANNED iteration (async scheduler):
+    """An immutable-by-convention PLANNED iteration:
     everything the launch needs, built against the planned frame while
     the previous dispatch runs. The only fields `_launch_plan` rewrites
     are the data-dependent decode inputs (d_lens / d_last / d_tables /
@@ -1299,7 +1259,7 @@ class _Plan:
 
 @dataclasses.dataclass
 class _Inflight:
-    """One launched-but-uncommitted dispatch (async scheduler): the
+    """One launched-but-uncommitted dispatch: the
     device futures plus exactly the host context `_commit_inflight`
     needs to scatter the synced results back — and the deterministic
     effects (`activating`, per-row upper bounds via n_rounds*win) the
@@ -1338,14 +1298,12 @@ class PagedInferenceServer:
                  allocation: str = "ondemand",
                  draft_params=None, draft_cfg: ModelConfig | None = None,
                  tokenizer=None, max_pending: int | None = None,
-                 admit_decode_chunk: int | None = 1,
-                 scheduler: str | None = None,
                  mixed_token_budget: int | None = None,
                  metrics: ServingMetrics | None = None,
                  flight_recorder_size: int | None = None,
                  qos=None, tracing=None, slo=None, spec_control=None,
                  iteration_profile=None, faults=None, brownout=None,
-                 anomaly=None, overlap: bool | None = None):
+                 anomaly=None):
         from cloud_server_tpu.models.quantization import QTensor
         target = jnp.dtype(cfg.dtype)
 
@@ -1460,13 +1418,12 @@ class PagedInferenceServer:
                     "tables, which hold one kind of page for the draft; "
                     "a target with window layers takes n-gram speculation "
                     "(spec_drafts without a draft model)")
-            ahead = (2 if (infer_cfg.overlap if overlap is None
-                           else bool(overlap))
-                     and (scheduler or infer_cfg.scheduler) == "mixed" else 1)
+            # two dispatches' writes: the one in flight and the one
+            # planned behind it
             self.window_pages_per_slot = paged_engine.window_pages_per_slot(
                 cfg.sliding_window, page_size,
-                ahead * max(self.prefill_chunk,
-                            self.decode_chunk * self.window),
+                2 * max(self.prefill_chunk,
+                        self.decode_chunk * self.window),
                 self.max_pages_per_slot)
             self.window_pool = WindowPagePool(
                 max_slots * self.window_pages_per_slot)
@@ -1753,34 +1710,11 @@ class PagedInferenceServer:
         # (PERF.md, PR 41: a check that then found no room)
         self._scheduler_join_timeout_s = 120.0
         self._draining = False
-        # admission-latency bound: while prefill jobs are in flight,
-        # decode dispatches shrink to this many rounds (default 1) so a
-        # prompt landing mid-decode waits ~one round — not a full
-        # decode_chunk burst — between each of its prefill chunks.
-        # TTFT p95 is set by this knob; steady-state throughput is not
-        # (decode_chunk applies whenever no admission is running).
-        # None disables the shrink (r4 behavior).
-        if admit_decode_chunk is not None and admit_decode_chunk < 1:
-            raise ValueError("admit_decode_chunk must be >= 1 or None")
-        self.admit_decode_chunk = admit_decode_chunk
-        # Scheduler under admission churn (steady-state decode always
-        # uses the multi-round decode dispatch):
-        #   "mixed" (default) — stall-free token-budget batching: every
-        #     iteration fuses all live decode rows and as many
-        #     prefill-chunk tokens as fit under `mixed_token_budget`
-        #     into ONE ragged window_forward, so decodes never stall
-        #     behind a prefill dispatch and admissions never wait out a
-        #     decode dispatch.
-        #   "alternating" — the r5 behavior (separate prefill-chunk and
-        #     decode dispatches per step); kept as the fallback. Both
-        #     speculation sources (n-gram AND draft-model) run under
-        #     either scheduler: the draft model's prefill/decode
-        #     discipline is fused into `_mixed_step`.
-        sched = scheduler if scheduler is not None else infer_cfg.scheduler
-        if sched not in ("mixed", "alternating"):
-            raise ValueError(f"unknown scheduler: {sched!r}")
-        self.scheduler = sched
-        self._mixed_enabled = sched == "mixed"
+        # stall-free token-budget batching: every iteration fuses all
+        # live decode rows and as many prefill-chunk tokens as fit under
+        # `mixed_token_budget` into ONE program, so decodes never stall
+        # behind a prefill dispatch and admissions never wait out a
+        # decode dispatch
         budget = (mixed_token_budget if mixed_token_budget is not None
                   else infer_cfg.mixed_token_budget)
         if budget is None or budget <= 0:
@@ -1807,12 +1741,8 @@ class PagedInferenceServer:
         # to cancel); stop() notifies for prompt shutdown
         self._work = threading.Condition(self._lock)
         self._step_lock = threading.Lock()
-        # Async double-buffered scheduling (the module docstring's
-        # overlap section): mixed scheduler only — the alternating
-        # scheduler keeps its sequential per-chunk loop.
-        ov = infer_cfg.overlap if overlap is None else bool(overlap)
-        self.overlap = bool(ov)
-        self._overlap_enabled = self.overlap and self._mixed_enabled
+        # the launched-but-uncommitted dispatch (the module docstring's
+        # pipeline section); None: the next step fills the pipeline
         self._inflight: _Inflight | None = None
         # the dispatch launched AHEAD of `_inflight`'s commit, inside
         # one steady step: `_commit_inflight` moves it up, so outside a
@@ -1824,8 +1754,7 @@ class PagedInferenceServer:
         # deferred delivery: what a commit would have woken somebody
         # with, in the order it would have run — (req, token) for a
         # stream call, (req, None) for the request's completion.
-        # `_deliver` runs it after the next launch (overlapped steps)
-        # or right after the commit (sequential ones)
+        # `_deliver` runs it after the step's launch
         self._deliveries: list[tuple[Request, int | None]] = []
         # disaggregated prefill/decode handoff (the ReplicatedRouter's
         # role-specialized fleets): requests whose chunked prefill
@@ -2164,7 +2093,7 @@ class PagedInferenceServer:
         # container, element reads are GIL-atomic, staleness is bounded
         # by one iteration and only steers placement
         jobs = list(self._jobs)
-        n = sum(max(int(job.rem_lens[0]) - job.done, 0)
+        n = sum(max(job.rem_len - job.done, 0)
                 for job in jobs)
         with self._lock:
             n += sum(len(r.prompt) + len(r.tokens)
@@ -2476,11 +2405,6 @@ class PagedInferenceServer:
 
     # -- admission ----------------------------------------------------------
 
-    def _rem_bucket(self, rem: int) -> int:
-        if rem <= self.prefill_chunk:
-            return _bucket(rem, self._rem_buckets)
-        return -(-rem // self.prefill_chunk) * self.prefill_chunk
-
     def _start_admissions(self) -> None:
         """Pop pending requests into slots (pages permitting) and build
         bucketed chunked-prefill jobs.
@@ -2637,181 +2561,14 @@ class PagedInferenceServer:
         now = time.perf_counter()  # one clock read per admission burst
         for slot_id in staged:
             self.metrics.observe_admit(self._slots[slot_id].req, now)
-        pad_tok = self.infer_cfg.pad_token_id
-        if self._mixed_enabled:
-            # mixed scheduler: ONE job per slot — progress is
-            # token-granular (`done`), widths are chosen per iteration
-            # by the token budget, so there is no fixed chunk schedule
-            # to share and admissions stay individually preemptible
-            for slot_id in staged:
-                slot = self._slots[slot_id]
-                rem_toks = slot.prompt[slot.shared_len:]
-                rb = self._rem_bucket(len(rem_toks))
-                pb = _bucket(len(slot.prompt), self._admit_buckets)
-                job = _AdmitJob(
-                    slots=[slot_id], chunk_w=rb, n_chunks=1,
-                    rows=np.full((1, rb), pad_tok, np.int32),
-                    rem_lens=np.asarray([len(rem_toks)], np.int32),
-                    base_lens=np.asarray([slot.shared_len], np.int32),
-                    prompt_rows=np.full((1, pb), pad_tok, np.int32),
-                    prompt_lens=np.asarray([len(slot.prompt)], np.int32),
-                    toks=np.zeros((1,), np.int32),
-                    lps=np.zeros((1,), np.float64),
-                    got=np.zeros((1,), bool))
-                job.rows[0, :len(rem_toks)] = rem_toks
-                job.prompt_rows[0, :len(slot.prompt)] = slot.prompt
-                self._jobs.append(job)
-            return
-        # group by remainder bucket => uniform chunk schedule per job
-        by_bucket: dict[int, list[int]] = {}
         for slot_id in staged:
             slot = self._slots[slot_id]
-            rb = self._rem_bucket(len(slot.prompt) - slot.shared_len)
-            by_bucket.setdefault(rb, []).append(slot_id)
-        for rb, slot_ids in by_bucket.items():
-            w = min(rb, self.prefill_chunk)
-            n_chunks = -(-rb // w)
-            g = len(slot_ids)
-            pb = _bucket(max(len(self._slots[s].prompt) for s in slot_ids),
-                         self._admit_buckets)
-            job = _AdmitJob(
-                slots=list(slot_ids), chunk_w=w, n_chunks=n_chunks,
-                rows=np.full((g, n_chunks * w), pad_tok, np.int32),
-                rem_lens=np.zeros((g,), np.int32),
-                base_lens=np.zeros((g,), np.int32),
-                prompt_rows=np.full((g, pb), pad_tok, np.int32),
-                prompt_lens=np.zeros((g,), np.int32),
-                toks=np.zeros((g,), np.int32),
-                lps=np.zeros((g,), np.float64),
-                got=np.zeros((g,), bool))
-            for i, sid in enumerate(slot_ids):
-                slot = self._slots[sid]
-                rem_toks = slot.prompt[slot.shared_len:]
-                job.rows[i, :len(rem_toks)] = rem_toks
-                job.rem_lens[i] = len(rem_toks)
-                job.base_lens[i] = slot.shared_len
-                job.prompt_rows[i, :len(slot.prompt)] = slot.prompt
-                job.prompt_lens[i] = len(slot.prompt)
-            self._jobs.append(job)
-
-    def _run_one_chunk(self, job: _AdmitJob) -> None:
-        if self._faults is not None:
-            # injected dispatch failure (see _mixed_dispatch)
-            self._faults.check("dispatch")
-        prof = self._profiler
-        if prof is not None:
-            # per-chunk phases ACCUMULATE into the iteration's (the
-            # alternating scheduler runs several chunks per step)
-            prof.enter("build")
-        c = job.next_chunk
-        w = job.chunk_w
-        g = len(job.slots)
-        gp = _pad_pow2(g)  # bound compiles: group rows pad to a power of 2
-
-        def pad_rows(a, fill):
-            if g == gp:
-                return a
-            padded = np.full((gp,) + a.shape[1:], fill, a.dtype)
-            padded[:g] = a
-            return padded
-
-        st = self._iter_stats  # flight recorder: prefill share per iter
-        st.setdefault("scheduler", self.scheduler)
-        st["prefill_tokens"] = st.get("prefill_tokens", 0) + w * g
-        if self.trace_recorder is not None:
-            for sid in job.slots:
-                r = self._slots[sid].req
-                if r.trace is not None:
-                    self._iter_spans.append(
-                        (r, "prefill_chunk",
-                         {"slot": sid, "tokens": w, "chunk": c}))
-        chunk = pad_rows(job.rows[:, c * w:(c + 1) * w],
-                         self.infer_cfg.pad_token_id)
-        g_lens = pad_rows(job.base_lens + c * w, 0)
-        slot_ids = pad_rows(np.asarray(job.slots, np.int32), self.max_slots)
-        for i, sid in enumerate(job.slots):
-            self._window_cover(sid, int(job.base_lens[i]) + (c + 1) * w)
-        g_tables = np.full((gp, self._table_cols), self._no_page, np.int32)
-        g_tables[:g] = self.tables[np.asarray(job.slots)]
-        sample_at = pad_rows(np.clip(job.rem_lens - 1 - c * w, 0, w - 1), 0)
-        in_range = ((job.rem_lens - 1) >= c * w) & (
-            (job.rem_lens - 1) < (c + 1) * w)
-        prompt_rows = pad_rows(job.prompt_rows, self.infer_cfg.pad_token_id)
-        prompt_lens = pad_rows(job.prompt_lens, 0)
-        sl = np.asarray(job.slots)
-        sl_pad = np.zeros((gp,), np.int64)
-        sl_pad[:g] = sl
-        samp_g = _gather_samp_rows(self.samp_rows, sl_pad, g)
-        orig_lens = pad_rows(self.orig_len[sl], 0)
-        count_mask = pad_rows(in_range, False)
-        use_rows = bool(self._needs_rows[sl].any())
-        use_bias = bool(self._has_bias[sl].any())
-        use_grammar = bool((self._gid[sl] > 0).any())
-        # analysis: allow[lifecycle-discipline] a raise in the chunk's device work between the span append and the job removal is terminal for the replica — _fail_all clears _jobs and completes every slot, so the pair is never observed torn
-        gid_g = jnp.asarray(pad_rows(self._gid[sl], 0))
-        gst0_g = jnp.asarray(pad_rows(self._gstate0[sl], 0))
-        use_lora = bool((self._aid[sl] > 0).any())
-        aid_g = jnp.asarray(pad_rows(self._aid[sl], 0))
-
-        self._stage_program_kind(self._iter_stats, chunk.size, 0, 0, 0, None)
-        if prof is not None:
-            prof.enter("device")
-        self.state, toks, lps = _prefill_chunk(
-            self.params, self.state, jnp.asarray(chunk),
-            jnp.asarray(g_lens, jnp.int32), jnp.asarray(g_tables),
-            jnp.asarray(sample_at, jnp.int32), jnp.asarray(slot_ids),
-            jnp.asarray(prompt_rows), jnp.asarray(prompt_lens, jnp.int32),
-            self._rng, np.int32(self._next_dispatch()),
-            jax.tree.map(jnp.asarray, samp_g),
-            jnp.asarray(orig_lens, jnp.int32), jnp.asarray(count_mask),
-            gid_g, gst0_g,
-            # analysis: allow[lock-discipline] _grammar_dev is rebuilt
-            # under _lock at submit/registration time, BEFORE any
-            # request using the new gid can reach admission; the
-            # scheduler reads one atomically-swapped reference
-            self._grammar_dev if use_grammar else None,
-            self.adapters.device_args() if use_lora else None, aid_g,
-            self.draft_params,
-            cfg=self.cfg, infer_cfg=self.infer_cfg,
-            scatter_prompt=(c == 0), mesh=self.mesh,
-            draft_cfg=self.draft_cfg, use_rows=use_rows,
-            use_bias=use_bias)
-        # analysis: allow[lock-discipline] THE sanctioned per-iteration
-        # host sync — _step_lock serializes the scheduler by design
-        # (the dispatch-discipline pass pins the sanctioned set)
-        toks, lps = jax.device_get((toks, lps))
-        if prof is not None:
-            prof.enter("commit")
-        toks, lps = np.asarray(toks)[:g], np.asarray(lps)[:g]
-        job.toks = np.where(in_range, toks, job.toks)
-        job.lps = np.where(in_range, lps, job.lps)
-        job.got |= in_range
-        job.next_chunk += 1
-        for i, sid in enumerate(job.slots):
-            self._window_trim(sid, int(job.base_lens[i]) + min(
-                job.next_chunk * w, int(job.rem_lens[i])))
-
-        if job.next_chunk >= job.n_chunks:
-            # admission complete: activate slots, emit first tokens
-            for i, sid in enumerate(job.slots):
-                slot = self._slots[sid]
-                assert bool(job.got[i]), "first-token sample never captured"
-                self.lengths[sid] = len(slot.prompt)
-                self.last_token[sid] = int(job.toks[i])
-                if slot.req._cancel.is_set():
-                    # cancelled mid-admission: release without ever
-                    # activating (the prefilled KV keys into the radix
-                    # cache — a resubmit would reuse it)
-                    slot = self._release_slot(sid, self._committed(sid))
-                    slot.req.finish_reason = "cancelled"
-                    self._complete_later(slot.req)
-                    continue
-                self.active[sid] = True
-                if self._emit(slot.req, int(job.toks[i]),
-                              float(job.lps[i])):
-                    self._finish(sid)
-            self._jobs.remove(job)
-            self._deliver()
+            prompt = np.asarray(slot.prompt, np.int32)
+            self._jobs.append(_AdmitJob(
+                slot=slot_id, rows=prompt[slot.shared_len:],
+                rem_len=len(prompt) - slot.shared_len,
+                base_len=slot.shared_len, prompt_row=prompt,
+                prompt_len=len(prompt)))
 
     # -- decode -------------------------------------------------------------
 
@@ -2912,60 +2669,47 @@ class PagedInferenceServer:
                 break
         return n_eff
 
-    def _chunk_rounds(self, active=None) -> int:
-        """Rounds this dispatch: bounded by decode_chunk — SHRUNK to
-        admit_decode_chunk while admission jobs are in flight, so a
-        landing prompt is not stuck behind full decode bursts between
-        its prefill chunks (this is the TTFT-vs-throughput knob; see
-        __init__) — and by the tightest remaining budget (in rounds),
-        rounded down to a power of two. `active` overrides the live
-        mask (the overlap planner's PLANNED frame; its slightly stale
-        remaining budgets can only overshoot, which the host emit loop
-        already truncates — the mid-scan EOS case)."""
-        if active is None:
-            active = self.active
+    def _chunk_rounds(self, active) -> int:
+        """Rounds of a decode-only dispatch: bounded by decode_chunk and
+        by the tightest remaining budget (in rounds), rounded down to a
+        power of two. `active` is the plan's live mask (the PLANNED
+        frame; its slightly stale remaining budgets can only overshoot,
+        which the host emit loop already truncates — the mid-scan EOS
+        case)."""
         rem = [s.req.max_new_tokens - len(s.req.tokens)
                for i, s in enumerate(self._slots)
                if s is not None and active[i]]
         if not rem:
             return 1
-        chunk = self.decode_chunk
-        if self._jobs and self.admit_decode_chunk is not None:
-            chunk = self.admit_decode_chunk
-        n = max(1, min(chunk, -(-min(rem) // self.window)))
+        n = max(1, min(self.decode_chunk, -(-min(rem) // self.window)))
         p = 1
         while p * 2 <= n:
             p *= 2
         return p
 
-    def _gather_decode_rows(self, active=None, g_iter: int = 0,
+    def _gather_decode_rows(self, active, g_iter: int = 0,
                             spec_lens=None):
         """COMPACTED decode sub-batch: one row per LIVE slot, padded to
         a power of two (compile cache). Rows carry sentinel slot ids /
         tables past the live count, so their writes drop everywhere
         (the cores' slot_ids indirection). Dispatching only live rows
-        is what keeps decode cost proportional to occupancy — a batch
-        half-full of mid-admission slots used to pay full max_slots
+        is what keeps decode cost proportional to occupancy: a batch
+        half-full of mid-admission slots does not pay full max_slots
         gathers and matmuls every round.
 
         A fully-live batch skips the indirection (sl = None, rows ARE
-        slots): steady state keeps the pre-compaction program, so the
-        identity gathers of gstate / penalty rows are never paid there.
+        slots): the steady state never pays the identity gathers of
+        gstate / penalty rows.
 
         Returns (live_ids, sl, live_g, lengths, tables, last, rows):
         the patch's four arrays loose, because a launch writes them
         anew, and everything else the decode cores take of a row as the
-        one packed buffer (`_pack_rows`), shared by `_decode_dispatch`,
-        `_mixed_dispatch` and `_plan_iteration` so the paths can never
-        drift. `g_iter`, `spec_lens` are the dispatch's `_spec_plan`:
-        a row's draft limit is its own where the controller gave one,
-        else the dispatch's width, which caps nothing.
-        `active` overrides the live mask (the overlap planner's
-        planned frame; the gathered lengths/last rows are placeholders
-        there — `_launch_plan` re-reads them from the committed ledger
-        right before the launch)."""
-        if active is None:
-            active = self.active
+        one packed buffer (`_pack_rows`). `g_iter`, `spec_lens` are the
+        dispatch's `_spec_plan`: a row's draft limit is its own where
+        the controller gave one, else the dispatch's width, which caps
+        nothing. `active` is the plan's live mask (the planned frame);
+        the gathered lengths/last rows are placeholders: `_launch_plan`
+        writes them right before the launch."""
         live_ids = np.flatnonzero(active)
         nl = len(live_ids)
         live_limits = g_iter if spec_lens is None else spec_lens
@@ -3040,109 +2784,18 @@ class PagedInferenceServer:
         if self.spec_control is not None:
             st["spec_draft_lens"] = self.spec_control.draft_lengths()
 
-    def _decode_dispatch(self) -> None:
-        if self._faults is not None:
-            # injected dispatch failure (see _mixed_dispatch)
-            self._faults.check("dispatch")
-        prof = self._profiler
-        if prof is not None:
-            # round planning + chain extension/preemption policy
-            prof.enter("admission")
-        n = self._chunk_rounds()
-        if self.allocation == "ondemand":
-            n_eff = self._extend_chains(n)
-            if n_eff <= 0 or not self.active.any():
-                return  # transient page famine — admissions continue,
-                #         preemption candidates appear next step
-            while n > n_eff:  # keep round counts powers of two (compile
-                n //= 2      # cache) while honouring chain coverage
-            n = max(1, n)
-        self._window_cover_rounds(n, self.lengths, self.active)
-        if prof is not None:
-            prof.enter("build")
-        g_iter, spec_lens = self._spec_plan(np.flatnonzero(self.active))
-        (live_ids, sl, live_g, lengths, tables, last_np,
-         rows) = self._gather_decode_rows(None, g_iter, spec_lens)
-        self._iter_stats.update(
-            scheduler=self.scheduler, n_live=len(live_ids),
-            decode_rounds=n,
-            decode_tokens=len(live_ids) * (g_iter + 1) * n,
-            decode_rows=int(live_g.shape[0]),
-            compaction_ratio=len(live_ids) / max(int(live_g.shape[0]), 1))
-        self._iter_stats.update(self._take_keys())
-        self._stage_spec_stats(g_iter, len(live_ids))
-        if self.trace_recorder is not None:
-            self._stage_decode_spans(live_ids, n)
-        patch = self._feed_patch(lengths, last_np, live_g, tables)
-        rows = self._to_device(rows)
-        live = self.active
-        use_rows = bool((self._needs_rows & live).any())
-        use_bias = bool((self._has_bias & live).any())
-        use_grammar = bool(((self._gid > 0) & live).any())
-        # analysis: allow[lock-discipline] atomically-swapped reference,
-        # rebuilt under _lock before any request using it is admitted
-        grammar = self._grammar_dev if use_grammar else None
-        use_lora = bool(((self._aid > 0) & live).any())
-        lora = self.adapters.device_args() if use_lora else None
-        self._stage_program_kind(self._iter_stats, 0, live_g.size, n,
-                                 g_iter, lora)
-        if prof is not None:
-            prof.enter("device")
-        if g_iter > 0:
-            self.state, lens, last, (toks, lps, counts), _ = _spec_rounds(
-                self.params, self.state, patch, rows, self._rng,
-                grammar, lora, self.draft_params,
-                cfg=self.cfg, infer_cfg=self.infer_cfg, n_rounds=n,
-                n_drafts=g_iter, mesh=self.mesh,
-                draft_cfg=self.draft_cfg, use_rows=use_rows,
-                use_bias=use_bias)
-            # analysis: allow[lock-discipline] THE sanctioned
-            # per-iteration host sync under _step_lock (speculative arm)
-            toks, lps, counts, lens, last = jax.device_get(
-                (toks, lps, counts, lens, last))
-        else:
-            (self.state, lens, last, (toks, lps, counts),
-             assign) = _decode_rounds(
-                self.params, self.state, patch, rows, self._rng,
-                grammar, lora,
-                cfg=self.cfg, infer_cfg=self.infer_cfg, n_rounds=n,
-                mesh=self.mesh, use_rows=use_rows, use_bias=use_bias)
-            # analysis: allow[lock-discipline] THE sanctioned
-            # per-iteration host sync under _step_lock (plain arm)
-            toks, lps, counts, lens, last, assign = jax.device_get(
-                (toks, lps, counts, lens, last, assign))
-            self._count_assign(self._iter_stats, assign)
-            toks, lps = toks[:, :, None], lps[:, :, None]
-            if self.spec_drafts > 0 and self.spec_control is not None:
-                # every live slot decoded plainly: draft-model caches
-                # miss these positions (sticky off), n-gram slots
-                # accrue probe credit
-                self.spec_control.on_plain_dispatch(
-                    [int(s) for s in live_ids], n)
-        if prof is not None:
-            prof.enter("commit")
-        self._commit_decode_rows(live_ids, toks, lps, counts, lens, last,
-                                 self._drafted_rows(g_iter, spec_lens,
-                                                    len(live_ids)))
-        self._deliver()
-
     def _commit_decode_rows(self, live_ids, toks, lps, counts, lens,
-                            last, drafted=None, owners=None) -> None:
+                            last, drafted, owners) -> None:
         """Scatter a compacted decode dispatch's results back to slots
-        and record the tokens (shared by _decode_dispatch,
-        _mixed_dispatch, and the async scheduler's _commit_inflight).
+        and record the tokens (`_commit_inflight`'s decode half).
         Nobody is woken here: stream calls and completions go on the
-        delivery list, which the sequential callers run at once and
-        `_step_overlap` after its launch.
+        delivery list, which `step` runs after its launch.
 
-        `owners` (async scheduler only): the _Slot object each row was
-        planned for. Between a launch-ahead and its commit a whole
-        step ran — a row's slot may have been released and RE-OCCUPIED
-        by a new admission, so the ledger writes and the emit loop
-        must be identity-guarded per row, not just active-guarded.
-        None (the sequential paths, where nothing can change between
-        dispatch and commit) keeps the historical unconditional
-        writes.
+        `owners`: the _Slot object each row was planned for. Between a
+        launch and its commit a whole step ran — a row's slot may have
+        been released and RE-OCCUPIED by a new admission, so the ledger
+        writes and the emit loop are identity-guarded per row, not just
+        active-guarded.
 
         `drafted` (per-live-row drafted-token counts, None when no
         draft rows ran) funds the speculation ledger from numbers the
@@ -3156,15 +2809,11 @@ class PagedInferenceServer:
         lens = np.asarray(lens)
         last = np.asarray(last)
         counts = np.asarray(counts)
-        if owners is None:
-            self.lengths[live_ids] = lens[:nl]
-            self.last_token[live_ids] = last[:nl]
-        else:
-            for i in range(nl):
-                sid = int(live_ids[i])
-                if self._slots[sid] is owners[i] and self.active[sid]:
-                    self.lengths[sid] = lens[i]
-                    self.last_token[sid] = last[i]
+        for i in range(nl):
+            sid = int(live_ids[i])
+            if self._slots[sid] is owners[i] and self.active[sid]:
+                self.lengths[sid] = lens[i]
+                self.last_token[sid] = last[i]
         if self.window_pool is not None:
             # a row passes a page's end once in `page_size` tokens: find
             # those rows in one comparison, on the serialized commit
@@ -3182,7 +2831,7 @@ class PagedInferenceServer:
             for i, sid in enumerate(live_ids):
                 slot = self._slots[sid]
                 if slot is None or not self.active[sid] \
-                        or (owners is not None and slot is not owners[i]):
+                        or slot is not owners[i]:
                     continue
                 c = int(counts[r, i])
                 if drafted is not None and c > 0:
@@ -3213,31 +2862,26 @@ class PagedInferenceServer:
                 self.qos.charge_speculation(tenant, dd, aa)
 
     def _complete_admission_chunks(self, sel, ptoks, plps) -> None:
-        """Prefill progress on the synced first-token candidates:
-        capture samples in range, advance `done` (and the `planned`
-        cursor when nothing is in flight to keep them ahead of it),
-        and ACTIVATE completed admissions — the cancel-at-activation
-        check included. THE one completion block, shared by
-        `_mixed_dispatch` (sequential) and `_commit_inflight`
-        (async), so the two paths can never drift."""
+        """Prefill progress on the synced first-token candidates
+        (`_commit_inflight`'s prefill half): capture samples in range,
+        advance `done`, and ACTIVATE completed admissions, the
+        cancel-at-activation check included."""
         ptoks, plps = np.asarray(ptoks), np.asarray(plps)
         for i, (job, take, d0) in enumerate(sel):
-            sid = job.slots[0]
-            rl = int(job.rem_lens[0])
+            sid = job.slot
+            rl = job.rem_len
             if d0 <= rl - 1 < d0 + take:
-                job.toks[0] = ptoks[i]
-                job.lps[0] = plps[i]
-                job.got[0] = True
+                job.tok = int(ptoks[i])
+                job.lp = float(plps[i])
+                job.got = True
             job.done = d0 + take
-            job.planned = max(job.planned, job.done)
-            self._window_trim(sid, int(job.base_lens[0]) + job.done)
+            self._window_trim(sid, job.base_len + job.done)
             if job.done < rl:
                 continue
             slot = self._slots[sid]
-            assert bool(job.got[0]), \
-                "first-token sample never captured"
+            assert job.got, "first-token sample never captured"
             self.lengths[sid] = len(slot.prompt)
-            self.last_token[sid] = int(job.toks[0])
+            self.last_token[sid] = job.tok
             if slot.req._cancel.is_set():
                 # cancelled mid-admission: release without ever
                 # activating (the prefilled KV keys into the radix
@@ -3247,8 +2891,7 @@ class PagedInferenceServer:
                 self._complete_later(slot.req)
             else:
                 self.active[sid] = True
-                if self._emit(slot.req, int(job.toks[0]),
-                              float(job.lps[0])):
+                if self._emit(slot.req, job.tok, job.lp):
                     self._finish(sid)
                 elif getattr(slot.req, "_handoff", None) is not None:
                     # prefill complete with decode budget left: queue
@@ -3260,19 +2903,16 @@ class PagedInferenceServer:
     # -- mixed (stall-free) scheduling --------------------------------------
 
     def _mixed_rounds(self, n_live: int, prefill_demand: int,
-                      win: int, active=None) -> int:
+                      win: int, active) -> int:
         """Decode rounds for a mixed iteration: the full steady-state
-        count (`_chunk_rounds` WITHOUT the admit shrink — not stalling
-        decode is the point), then squeezed to leave the budget at least
-        one minimal prefill chunk when admissions are waiting, floored
-        at one round and kept a power of two (compile cache). `win` is
-        THIS iteration's decode window (current max draft length + 1 —
-        adaptive speculation shrinks it with demand), so a slot's
-        decode claim against the budget is its honest token count.
-        `active` overrides the live mask (the overlap planner's
-        planned frame — see _chunk_rounds)."""
-        if active is None:
-            active = self.active
+        count (not stalling decode is the point), then squeezed to
+        leave the budget at least one minimal prefill chunk when
+        admissions are waiting, floored at one round and kept a power
+        of two (compile cache). `win` is THIS iteration's decode window
+        (current max draft length + 1 — adaptive speculation shrinks it
+        with demand), so a slot's decode claim against the budget is
+        its honest token count. `active` is the plan's live mask (the
+        planned frame; see _chunk_rounds)."""
         rem = [s.req.max_new_tokens - len(s.req.tokens)
                for i, s in enumerate(self._slots)
                if s is not None and active[i]]
@@ -3289,24 +2929,18 @@ class PagedInferenceServer:
         return p
 
     def _select_prefill(self, jobs, n_live: int, win: int,
-                        n_rounds: int, planned: bool):
-        """Token-budget prefill selection — THE shared policy half of
-        a mixed iteration, used by `_mixed_dispatch` (cursor = the
-        committed `done`) and `_plan_iteration` (cursor = the
-        in-flight-inclusive `planned`), so the two paths can never
-        drift (the array-staging half is `_build_prefill_group`).
-        QoS virtual-time (or FIFO) order; decode rows are funded
-        first, each selected job takes up to `prefill_chunk` tokens
-        of the remainder, and when decode alone saturates the budget
-        the OLDEST admission still gets one minimal chunk (TTFT stays
-        bounded). Returns [(job, take, cursor_offset)]."""
-
-        def cur(j):
-            return j.planned if planned else j.done
-
+                        n_rounds: int):
+        """Token-budget prefill selection: the policy half of a mixed
+        iteration (the array-staging half is `_build_prefill_group`).
+        A job's cursor is `planned`, which counts what the dispatch in
+        flight carries. QoS virtual-time (or FIFO) order; decode rows
+        are funded first, each selected job takes up to `prefill_chunk`
+        tokens of the remainder, and when decode alone saturates the
+        budget the OLDEST admission still gets one minimal chunk (TTFT
+        stays bounded). Returns [(job, take, cursor_offset)]."""
         if self.qos is not None and jobs:
             order = self.qos.order_jobs(
-                [self._slots[j.slots[0]].req.tenant for j in jobs])
+                [self._slots[j.slot].req.tenant for j in jobs])
             jobs = [jobs[i] for i in order]
         sel: list[tuple[_AdmitJob, int, int]] = []
         left = room = self.mixed_token_budget - n_live * win * n_rounds
@@ -3314,7 +2948,7 @@ class PagedInferenceServer:
         for job in jobs:
             if left <= 0:
                 break
-            rem_left = int(job.rem_lens[0]) - cur(job)
+            rem_left = job.rem_len - job.planned
             take = min(rem_left, left, self.prefill_chunk)
             if take <= 0:
                 continue
@@ -3328,13 +2962,12 @@ class PagedInferenceServer:
             if sel and slots > room:
                 break
             widest = max(widest, take)
-            sel.append((job, take, cur(job)))
+            sel.append((job, take, job.planned))
             left -= take
         if jobs and not sel:
             job = jobs[0]
-            take = min(int(job.rem_lens[0]) - cur(job),
-                       self._rem_buckets[0])
-            sel = [(job, take, cur(job))]
+            take = min(job.rem_len - job.planned, self._rem_buckets[0])
+            sel = [(job, take, job.planned)]
         return sel
 
     def _chunk_bucket(self, sel) -> int:
@@ -3348,13 +2981,10 @@ class PagedInferenceServer:
         iteration: one row per selected admission chunk, each at its
         own width, padded to a pow2 row count and a bucketed max width
         `w` (`_chunk_bucket`; compile cache). `sel` entries are (job,
-        take, d0) — d0 is the remainder offset this chunk starts at: the
-        committed cursor on the sequential path, the PLANNED cursor on
-        the async path (so a launch-ahead iteration never re-prefills
-        tokens already in flight). Shared verbatim by `_mixed_dispatch`
-        and `_plan_iteration` so the two paths can never drift, the
-        packing included: `group` is the one array the half hands the
-        device (`_pack_group`), the rest is what the host reads of it."""
+        take, d0): d0 is the remainder offset this chunk starts at, the
+        job's PLANNED cursor, so a plan never re-prefills tokens already
+        in flight. `group` is the one array the half hands the device
+        (`_pack_group`), the rest is what the host reads of it."""
         pad_tok = self.infer_cfg.pad_token_id
         b = self.max_slots
         g = len(sel)
@@ -3369,11 +2999,11 @@ class PagedInferenceServer:
         scatm = np.zeros((gp,), bool)
         scat_plens = []
         for i, (job, take, d0) in enumerate(sel):
-            sid = job.slots[0]
-            rl = int(job.rem_lens[0])
-            chunk[i, :take] = job.rows[0, d0:d0 + take]
+            sid = job.slot
+            rl = job.rem_len
+            chunk[i, :take] = job.rows[d0:d0 + take]
             widths[i] = take
-            g_lens[i] = int(job.base_lens[0]) + d0
+            g_lens[i] = job.base_len + d0
             self._window_cover(sid, int(g_lens[i]) + take)
             if self.window_pool is not None:
                 self._stage_keys(int(g_lens[i]) + take, take, decode=False)
@@ -3383,19 +3013,19 @@ class PagedInferenceServer:
             countm[i] = d0 <= rl - 1 < d0 + take
             scatm[i] = d0 == 0
             if d0 == 0:
-                scat_plens.append(int(job.prompt_lens[0]))
+                scat_plens.append(job.prompt_len)
         pb = (_bucket(max(scat_plens), self._admit_buckets)
               if scat_plens else self._admit_buckets[0])
         prompt_rows = np.full((gp, pb), pad_tok, np.int32)
         prompt_lens = np.zeros((gp,), np.int32)
         orig_lens = np.zeros((gp,), np.int32)
         for i, (job, take, d0) in enumerate(sel):
-            sid = job.slots[0]
-            pl = int(job.prompt_lens[0])
+            sid = job.slot
+            pl = job.prompt_len
             prompt_lens[i] = pl
             orig_lens[i] = self.orig_len[sid]
             if d0 == 0:
-                prompt_rows[i, :pl] = job.prompt_rows[0, :pl]
+                prompt_rows[i, :pl] = job.prompt_row[:pl]
         sl_real = np.clip(slot_ids, 0, self.max_slots - 1)
         gid_g = self._gid[sl_real]
         gid_g[g:] = 0
@@ -3404,7 +3034,7 @@ class PagedInferenceServer:
         aid_g = self._aid[sl_real]
         aid_g[g:] = 0
         sel_mask = np.zeros((b,), bool)
-        sel_mask[[job.slots[0] for job, _, _ in sel]] = True
+        sel_mask[[job.slot for job, _, _ in sel]] = True
         group = _pack_group(
             chunk, g_tables, prompt_rows,
             _gather_samp_rows(self.samp_rows, sl_real, g),
@@ -3431,13 +3061,13 @@ class PagedInferenceServer:
         `_export_request_locked`, or dropped at request completion."""
         ps = self.page_size
         for job, take, d0 in sel:
-            if d0 + take < int(job.rem_lens[0]):
+            if d0 + take < job.rem_len:
                 continue  # not the final chunk
-            sid = job.slots[0]
+            sid = job.slot
             slot = self._slots[sid]
             if slot is None or getattr(slot.req, "_handoff", None) is None:
                 continue
-            n_full = (int(job.base_lens[0]) + d0) // ps
+            n_full = (job.base_len + d0) // ps
             if n_full <= 0 or slot.req.request_id in self._handoff_stash:
                 continue
             ids = np.asarray(slot.pages[:n_full])
@@ -3456,177 +3086,20 @@ class PagedInferenceServer:
             self._handoff_stash[slot.req.request_id] = (
                 tuple(slot.pages[:n_full]), gathered)
 
-    def _mixed_dispatch(self) -> None:
-        """One token-budget iteration: the multi-round decode dispatch
-        for every live slot plus as many prefill-chunk tokens as fit
-        under `mixed_token_budget`, fused into ONE jitted program with
-        ONE host sync (`_mixed_step`).
-
-        Budget split: decode rows are admitted first (live slots advance
-        their full round count every iteration — the stall-free
-        property); the remainder goes to in-flight admissions FIFO, each
-        grabbing up to `prefill_chunk` tokens AT ITS OWN WIDTH — the
-        ragged prefill group replaces the alternating scheduler's
-        per-bucket admission dispatches. When decode alone saturates the
-        budget, the OLDEST admission still gets one minimal chunk so
-        TTFT stays bounded (the budget is a target, not a hard cap).
-        Admitting slots not selected this iteration ride along inert:
-        width 0 and sentinel tables, so nothing they own can be
-        written."""
-        if self._faults is not None:
-            # injected dispatch failure: raises before any device work,
-            # crashing this iteration the way a poisoned program would
-            # (serve_forever catches, _fail_all unblocks every waiter,
-            # the router's breaker/retry path takes it from there)
-            self._faults.check("dispatch")
-        b = self.max_slots
-        demand = sum(int(j.rem_lens[0]) - j.done for j in self._jobs)
-        n_live = int(self.active.sum())
-        g0, _ = self._spec_plan(np.flatnonzero(self.active))
-        n_rounds = self._mixed_rounds(n_live, demand, g0 + 1)
-        if self.allocation == "ondemand" and n_rounds > 0:
-            n_eff = self._extend_chains(n_rounds)
-            if n_eff <= 0 or not self.active.any():
-                n_rounds = 0  # transient page famine: prefill-only
-            else:
-                while n_rounds > n_eff:
-                    n_rounds //= 2
-                n_rounds = max(1, n_rounds)
-        self._window_cover_rounds(n_rounds, self.lengths, self.active)
-        live = self.active if n_rounds > 0 else np.zeros((b,), bool)
-        n_live = int(live.sum())
-        # authoritative speculation plan for the dispatch (re-planned:
-        # _extend_chains may have preempted a slot out of the live set);
-        # draft rounds are funded as decode rows — a slot's claim is
-        # `win` tokens per round, charged against prefill funding below
-        g_iter, spec_lens = self._spec_plan(np.flatnonzero(self.active))
-        win = g_iter + 1
-
-        # weighted-fair funding of the iteration's prefill chunks
-        # (QoS virtual-time order inside _select_prefill; called even
-        # for a single job — it also advances the global virtual time,
-        # so a tenant arriving after an idle gap resumes at the
-        # current time instead of replaying idle credit)
-        sel = self._select_prefill(self._jobs, n_live, win, n_rounds,
-                                   planned=False)
-        if not sel and not n_rounds:
-            return
-        prof = self._profiler
-        if prof is not None:
-            # everything above (budget/round planning, chain extension,
-            # QoS funding order, selection: the host deciding WHAT to
-            # dispatch) ran in step()'s `admission`
-            prof.enter("build")
-        if self.qos is not None:
-            for job, take, _ in sel:
-                self.qos.charge_prefill(
-                    self._slots[job.slots[0]].req.tenant, take)
-        self._iter_stats.update(
-            scheduler="mixed", n_live=n_live, decode_rounds=n_rounds,
-            decode_tokens=n_live * win * n_rounds,
-            prefill_tokens=sum(t for _, t, _ in sel))
-        if n_rounds > 0:
-            self._stage_spec_stats(g_iter, n_live)
-        if self.trace_recorder is not None:
-            for job, take, d0 in sel:
-                r = self._slots[job.slots[0]].req
-                if r.trace is not None:
-                    self._iter_spans.append(
-                        (r, "prefill_chunk",
-                         {"slot": job.slots[0], "tokens": take,
-                          "offset": d0}))
-
-        # -- ragged prefill group (one row per selected admission) ----------
-        chunk_w = self._chunk_bucket(sel)
-        pf = self._build_prefill_group(sel, chunk_w)
-        sel_mask = pf["sel_mask"]
-        use_rows_p = bool((self._needs_rows & sel_mask).any())
-        use_bias_p = bool((self._has_bias & sel_mask).any())
-
-        # -- decode half (compacted: one row per live slot) -----------------
-        (live_ids, sl_d, live_g, d_lens, d_tables, d_last,
-         rows) = self._gather_decode_rows(None, g_iter, spec_lens)
-        self._iter_stats.update(self._take_keys())
-        self._iter_stats.update(
-            decode_rows=int(live_g.shape[0]) if n_rounds else 0,
-            compaction_ratio=(n_live / max(int(live_g.shape[0]), 1)
-                              if n_rounds else 1.0))
-        if self.trace_recorder is not None and n_rounds > 0:
-            self._stage_decode_spans(live_ids, n_rounds)
-        if n_rounds == 0:
-            live_g = np.zeros_like(live_g)
-        use_rows_d = bool((self._needs_rows & live).any())
-        use_bias_d = bool((self._has_bias & live).any())
-        use_grammar = bool(((self._gid > 0) & (live | sel_mask)).any())
-        use_lora = bool(((self._aid > 0) & (live | sel_mask)).any())
-
-        if prof is not None:
-            # host array prep done; the dispatch statement below (arg
-            # transfer + launch) through the sanctioned device_get is
-            # the device phase
-            prof.enter("device")
-        # disaggregation handoff: start the committed-page D2H copies
-        # BEFORE the dispatch donates self.state (overlaps the final
-        # prefill chunk)
-        self._handoff_prefetch(sel)
-        lora = self.adapters.device_args() if use_lora else None
-        self._stage_program_kind(self._iter_stats, pf["chunk_tokens"],
-                                 live_g.size, n_rounds, g_iter, lora)
-        (self.state, ptoks, plps, lens, last, (toks, lps, counts),
-         assign) = \
-            _mixed_step(
-                self.params, self.state, self._to_device(pf["group"]),
-                self._feed_patch(d_lens, d_last, live_g, d_tables),
-                self._to_device(rows), self._rng,
-                # analysis: allow[lock-discipline] atomically-swapped
-                # reference, rebuilt under _lock pre-admission
-                self._grammar_dev if use_grammar else None, lora,
-                self.draft_params,
-                cfg=self.cfg, infer_cfg=self.infer_cfg,
-                n_rounds=n_rounds, n_drafts=g_iter,
-                scatter_prompt=pf["scatter_prompt"], chunk_w=chunk_w,
-                mesh=self.mesh, draft_cfg=self.draft_cfg,
-                use_rows_p=use_rows_p, use_bias_p=use_bias_p,
-                use_rows_d=use_rows_d, use_bias_d=use_bias_d)
-        # analysis: allow[lock-discipline] THE sanctioned per-iteration
-        # host sync — one fused dispatch, one device_get, under the
-        # step lock that serializes the scheduler by design
-        ptoks, plps, toks, lps, counts, lens, last, assign = jax.device_get(
-            (ptoks, plps, toks, lps, counts, lens, last, assign))
-        self._count_assign(self._iter_stats, assign)
-        if prof is not None:
-            prof.enter("commit")
-
-        if n_rounds > 0:
-            if (g_iter == 0 and self.spec_drafts > 0
-                    and self.spec_control is not None):
-                self.spec_control.on_plain_dispatch(
-                    [int(s) for s in live_ids], n_rounds)
-            self._commit_decode_rows(live_ids, np.asarray(toks),
-                                     np.asarray(lps), counts, lens, last,
-                                     self._drafted_rows(g_iter, spec_lens,
-                                                        len(live_ids)))
-
-        # prefill progress: capture first tokens, activate completed
-        # admissions (mirrors _run_one_chunk's completion block)
-        self._complete_admission_chunks(sel, ptoks, plps)
-        self._deliver()
-
-    # -- async double-buffered scheduling (overlap on) ----------------------
+    # -- plan, launch, commit -----------------------------------------------
     #
-    # The pipelined loop (see the module docstring's overlap section):
-    # each step plans iteration N+1 against the PLANNED frame while the
-    # device runs iteration N, pays the one sanctioned device_get
-    # commit, patches the plan's data-dependent inputs from the
-    # just-committed ledger, and launches. Functions on this path obey
-    # one extra invariant the dispatch-discipline pass checks
-    # statically (DD5): the PLAN functions never release pages or tear
-    # down slots — a page freed under an in-flight dispatch could be
-    # re-allocated while the device still writes it.
+    # The pipelined loop (see the module docstring): each step plans
+    # iteration N+1 against the PLANNED frame while the device runs
+    # iteration N, launches it, and pays the one sanctioned device_get
+    # commit of N. Functions on this path obey one extra invariant the
+    # dispatch-discipline pass checks statically (DD5): a plan made
+    # under a dispatch in flight never releases pages or tears down
+    # slots — a page freed under that dispatch could be re-allocated
+    # while the device still writes it.
 
     def _extend_chains_planned(self, n_rounds: int, planned_len,
                                planned_active) -> int:
-        """Planned-frame chain growth for a launch-ahead dispatch:
+        """Chain growth for a plan made under a dispatch in flight:
         cover each planned-live slot's worst-case window writes using
         the PLANNED length upper bound (committed length + the
         in-flight dispatch's rounds*window). Unlike `_extend_chains`
@@ -3634,8 +3107,8 @@ class PagedInferenceServer:
         while a dispatch is in flight): on famine it takes whatever
         pages are available and bounds the dispatch to the rounds
         every chain already covers. 0 drops the decode half; the
-        pipeline then drains, and the next sequential iteration runs
-        the full preemption escalation with nothing in flight."""
+        pipeline then drains, and the next plan, made with nothing in
+        flight, runs the full preemption escalation."""
         n_eff = n_rounds
         for sid in range(self.max_slots):
             slot = self._slots[sid]
@@ -3666,29 +3139,30 @@ class PagedInferenceServer:
         dispatch's deterministic effects (job cursors advanced by the
         takes it carries; slots it completes counted live; lengths at
         their rounds*window upper bound). This is the host policy work
-        the overlap hides under the device: QoS/DRR funding order,
+        the pipeline hides under the device: QoS/DRR funding order,
         token-budget split, chain growth, and all array staging happen
         here, so after the commit only a (rows,)-sized patch and the
         launch remain serialized.
 
-        Returns None when there is nothing to dispatch (the pipeline
-        drains). Never mutates the committed ledger beyond job.planned
-        cursors, QoS prefill charges, and chain growth — and never
-        releases pages (DD5).
+        With nothing in flight (the pipeline's fill) the planned frame
+        IS the committed ledger, and the plan may release pages: chains
+        grow through `_extend_chains`, which preempts on famine, and
+        the speculation plan is made again for the slots that are left.
+        Under a dispatch in flight the plan never releases pages (DD5):
+        `_extend_chains_planned` degrades the round count instead, and
+        a plan with nothing to dispatch drains the pipeline.
 
-        The injected-fault "dispatch" site is NOT checked here but in
-        _step_overlap's steady-state path: checking per plan would
-        hit the site twice on a pipeline-fill step (breaking the
-        FaultPlan's one-hit-per-iteration pacing) and could fire
-        AFTER the fill dispatch already streamed tokens — the fill
-        prime's fault site is the NEXT step's check, before anything
-        of that step has streamed."""
+        Returns None when there is nothing to dispatch. Never mutates
+        the committed ledger beyond job.planned cursors, QoS prefill
+        charges, chain growth and, with nothing in flight, preemption.
+
+        The injected-fault "dispatch" site is `step`'s, once a step
+        before the plan."""
         prof = self._profiler
         if prof is not None:
             # planned-frame budget/round planning, chain growth, QoS
-            # funding order, selection — overlapped host work (a no-op
-            # boundary right after step()'s admissions; a real one
-            # after the pipeline fill's commit)
+            # funding order, selection (a no-op boundary right after
+            # step()'s admissions)
             prof.enter("admission")
         b = self.max_slots
         infl = self._inflight
@@ -3715,64 +3189,79 @@ class PagedInferenceServer:
                     planned_active[sid] = True
                     planned_len[sid] = len(slot.prompt)
                     made[sid] = True
-        jobs = [j for j in self._jobs if j.planned < int(j.rem_lens[0])]
+        jobs = [j for j in self._jobs if j.planned < j.rem_len]
         if not jobs and not planned_active.any():
             return None
+        # --- rounds, and the chains that cover them -----------------------
+        # draft rounds are funded as decode rows: a slot's claim is
+        # `win` tokens per round, charged against prefill funding
+        g_iter, spec_lens = self._spec_plan(np.flatnonzero(planned_active))
+        if jobs:
+            n_rounds = self._mixed_rounds(
+                int(planned_active.sum()),
+                sum(j.rem_len - j.planned for j in jobs), g_iter + 1,
+                planned_active)
+        else:
+            n_rounds = self._chunk_rounds(planned_active)
+        if self.allocation == "ondemand" and n_rounds > 0:
+            if infl is None:
+                n_eff = self._extend_chains(n_rounds)
+                # a preemption takes its slot out of the live set
+                planned_active = self.active.copy()
+                planned_len = self.lengths.copy()
+                g_iter, spec_lens = self._spec_plan(
+                    np.flatnonzero(planned_active))
+            else:
+                n_eff = self._extend_chains_planned(
+                    n_rounds, planned_len, planned_active)
+            if n_eff <= 0 or not planned_active.any():
+                n_rounds = 0  # transient page famine: prefill-only
+            else:
+                while n_rounds > n_eff:  # keep round counts powers of
+                    n_rounds //= 2       # two (compile cache)
+                n_rounds = max(1, n_rounds)
+        if not jobs and n_rounds == 0:
+            return None
+        self._window_cover_rounds(n_rounds, planned_len, planned_active)
+        win = g_iter + 1
         stats: dict = {}
         spans: list = []
         if jobs:
-            # --- token-budget mixed iteration (mirrors _mixed_dispatch)
-            demand = sum(int(j.rem_lens[0]) - j.planned for j in jobs)
-            n_live = int(planned_active.sum())
-            # ONE speculation plan per planned iteration: unlike the
-            # sequential path, _extend_chains_planned can never
-            # preempt a slot out of the live set (DD5), so there is
-            # nothing to re-plan after chain growth
-            g_iter, spec_lens = self._spec_plan(
-                np.flatnonzero(planned_active))
-            n_rounds = self._mixed_rounds(n_live, demand, g_iter + 1,
-                                          active=planned_active)
-            if self.allocation == "ondemand" and n_rounds > 0:
-                n_eff = self._extend_chains_planned(
-                    n_rounds, planned_len, planned_active)
-                if n_eff <= 0:
-                    n_rounds = 0
-                else:
-                    while n_rounds > n_eff:
-                        n_rounds //= 2
-                    n_rounds = max(1, n_rounds)
-            self._window_cover_rounds(n_rounds, planned_len, planned_active)
+            # --- token-budget mixed iteration ------------------------------
             live = (planned_active if n_rounds > 0
                     else np.zeros((b,), bool))
             n_live = int(live.sum())
-            win = g_iter + 1
-            sel = self._select_prefill(jobs, n_live, win, n_rounds,
-                                       planned=True)
+            # weighted-fair funding of the iteration's prefill chunks
+            # (QoS virtual-time order inside _select_prefill; called
+            # even for a single job — it also advances the global
+            # virtual time, so a tenant arriving after an idle gap
+            # resumes at the current time instead of replaying idle
+            # credit)
+            sel = self._select_prefill(jobs, n_live, win, n_rounds)
             if not sel and not n_rounds:
                 return None
             if self.qos is not None:
                 for job, take, _ in sel:
                     self.qos.charge_prefill(
-                        self._slots[job.slots[0]].req.tenant, take)
+                        self._slots[job.slot].req.tenant, take)
             activating: list[int] = []
             for job, take, d0 in sel:
                 job.planned = d0 + take
-                if job.planned >= int(job.rem_lens[0]):
-                    activating.append(job.slots[0])
+                if job.planned >= job.rem_len:
+                    activating.append(job.slot)
             stats.update(
-                scheduler="mixed", n_live=n_live,
-                decode_rounds=n_rounds,
+                n_live=n_live, decode_rounds=n_rounds,
                 decode_tokens=n_live * win * n_rounds,
                 prefill_tokens=sum(t for _, t, _ in sel))
             if n_rounds > 0:
                 self._stage_spec_stats(g_iter, n_live, st=stats)
             if self.trace_recorder is not None:
                 for job, take, d0 in sel:
-                    r = self._slots[job.slots[0]].req
+                    r = self._slots[job.slot].req
                     if r.trace is not None:
                         spans.append(
                             (r, "prefill_chunk",
-                             {"slot": job.slots[0], "tokens": take,
+                             {"slot": job.slot, "tokens": take,
                               "offset": d0}))
             if prof is not None:
                 prof.enter("build")
@@ -3812,38 +3301,25 @@ class PagedInferenceServer:
                     ((self._aid > 0) & (live | sel_mask)).any()),
                 stats=stats, spans=spans)
         else:
-            # --- pure-decode iteration (mirrors _decode_dispatch) ---------
-            n = self._chunk_rounds(active=planned_active)
-            if self.allocation == "ondemand":
-                n_eff = self._extend_chains_planned(
-                    n, planned_len, planned_active)
-                if n_eff <= 0:
-                    return None
-                while n > n_eff:
-                    n //= 2
-                n = max(1, n)
-            self._window_cover_rounds(n, planned_len, planned_active)
+            # --- pure-decode iteration -------------------------------------
             if prof is not None:
                 prof.enter("build")
-            g_iter, spec_lens = self._spec_plan(
-                np.flatnonzero(planned_active))
             (live_ids, sl_d, live_g, d_lens, d_tables, d_last,
              rows) = self._gather_decode_rows(
                  planned_active, g_iter, spec_lens)
             stats.update(self._take_keys())
             stats.update(
-                scheduler=self.scheduler, n_live=len(live_ids),
-                decode_rounds=n,
-                decode_tokens=len(live_ids) * (g_iter + 1) * n,
+                n_live=len(live_ids), decode_rounds=n_rounds,
+                decode_tokens=len(live_ids) * win * n_rounds,
                 decode_rows=int(live_g.shape[0]),
                 compaction_ratio=(len(live_ids)
                                   / max(int(live_g.shape[0]), 1)))
             self._stage_spec_stats(g_iter, len(live_ids), st=stats)
             if self.trace_recorder is not None:
-                self._stage_decode_spans(live_ids, n, out=spans)
+                self._stage_decode_spans(live_ids, n_rounds, out=spans)
             plan = _Plan(
-                kind="decode", sel=[], activating=[], n_rounds=n,
-                win=g_iter + 1, g_iter=g_iter, spec_lens=spec_lens,
+                kind="decode", sel=[], activating=[], n_rounds=n_rounds,
+                win=win, g_iter=g_iter, spec_lens=spec_lens,
                 live_ids=live_ids, sl_d=sl_d, live_g=live_g,
                 d_lens=d_lens, d_tables=d_tables, d_last=d_last,
                 rows=rows,
@@ -3895,8 +3371,8 @@ class PagedInferenceServer:
         onto the device's queue ahead of that commit. Read from what the
         plan shows, for this iteration alone (no option chooses):
 
-          "fill"     nothing is in flight: the launch primes the
-                     pipeline behind a sequential iteration;
+          "fill"     nothing is in flight: there is no commit to go
+                     ahead of, and the launch fills the pipeline;
           "drafts"   either dispatch runs speculative rounds: how far a
                      row advances is known at the commit, not before;
           "grammar"  a constrained row among the plan's: the order such
@@ -3917,8 +3393,8 @@ class PagedInferenceServer:
         if plan.use_grammar:
             return "grammar"
         for job, take, d0 in plan.sel:
-            if d0 + take >= int(job.rem_lens[0]) and getattr(
-                    self._slots[job.slots[0]].req, "_handoff",
+            if d0 + take >= job.rem_len and getattr(
+                    self._slots[job.slot].req, "_handoff",
                     None) is not None:
                 return "handoff"
         return None
@@ -3930,13 +3406,12 @@ class PagedInferenceServer:
         chosen: whether it walks the layers once for a prefill group and
         the decode round together (`_walks_once`; a program of one half
         alone has nothing to join), and whether its expert calls take the
-        sorted dispatch (`_sorts_experts`). An iteration that launches
-        two programs (the alternating scheduler's) says what either did."""
+        sorted dispatch (`_sorts_experts`)."""
         joined = chunk_tokens > 0 and _walks_once(
             self.cfg, chunk_tokens + decode_rows, n_rounds, n_drafts,
             self.draft_cfg, lora)
-        stats["joined"] = joined or stats.get("joined", False)
-        stats["grouped"] = stats.get("grouped", False) or _sorts_experts(
+        stats["joined"] = joined
+        stats["grouped"] = _sorts_experts(
             self.cfg, self.params, joined, chunk_tokens,
             decode_rows * (n_drafts + 1) * (n_rounds > 0))
 
@@ -4086,8 +3561,8 @@ class PagedInferenceServer:
         self._iter_launch_h2d = self._h2d - h2d0
         # the launch's end: the start of the wait for the program
         # before it where the launch went ahead, else of the delivery
-        # (`_step_overlap` wakes the streaming threads under the
-        # program launched here)
+        # (`step` wakes the streaming threads under the program
+        # launched here)
         t = (prof.enter("device" if ahead else "deliver")
              if prof is not None else time.perf_counter())
         self._iter_launch_ts = t
@@ -4170,21 +3645,28 @@ class PagedInferenceServer:
                 counts, lens, last,
                 self._drafted_rows(g_iter, infl.spec_lens,
                                    len(infl.live_ids)),
-                owners=infl.owners)
+                infl.owners)
         if infl.kind == "mixed":
             self._complete_admission_chunks(infl.sel, ptoks, plps)
         self._apply_reaps()
 
-    def _overlap_sweep(self) -> None:
-        """Sweep for an overlapped step: cancelled / deadline-expired
-        SLOT holders are only MARKED (active=False + queued on
-        _reaped) — the in-flight dispatch is still writing their
-        pages, and releasing mid-flight could hand a page to a new
-        admission while the device writes it. `_apply_reaps` releases
-        them right after the commit, in this same step. Pending-queue
-        expiry is pure host state and runs exactly like the
-        sequential sweep."""
-        job_slots = {s for job in self._jobs for s in job.slots}
+    def _sweep(self) -> None:
+        """A step's sweep: cancelled / deadline-expired SLOT holders
+        are only MARKED (active=False + queued on _reaped): a dispatch
+        in flight is still writing their pages, and releasing then
+        could hand a page to a new admission while the device writes
+        it. `_apply_reaps` releases them right after the commit, in
+        this same step (with nothing in flight, right after the
+        sweep): the KV they wrote is fully committed by then, so it
+        stays reusable in the prefix cache. Slots still inside an
+        admission job are left to finish their (bounded) chunks: the
+        commit checks the cancel flag at activation, and an expired
+        request is reaped by the next sweep. Expired PENDING requests
+        are reaped here too (pure host state), so a deadline is honored
+        even if the request never reaches a slot. The expiry clock is
+        read lazily: zero reads per iteration when no live request
+        carries a deadline."""
+        job_slots = {job.slot for job in self._jobs}
         marked = {sid for sid, _, _ in self._reaped}
         now = None
         for sid, slot in enumerate(self._slots):
@@ -4203,10 +3685,11 @@ class PagedInferenceServer:
         self._expire_pending(now)
 
     def _apply_reaps(self) -> None:
-        """Deferred-release half of `_overlap_sweep`, run just after
-        the commit: the marked slots' pages are fully committed KV
-        now, so they release through the normal content-keyed path
-        (reusable in the prefix cache) and the requests complete."""
+        """Deferred-release half of `_sweep`, run just after the commit
+        (with nothing in flight, right after the sweep): the marked
+        slots' pages are fully committed KV now, so they release
+        through the normal content-keyed path (reusable in the prefix
+        cache) and the requests complete."""
         if not self._reaped:
             return
         reaped, self._reaped = self._reaped, []
@@ -4248,151 +3731,11 @@ class PagedInferenceServer:
             except Exception:  # noqa: BLE001 — router-side failure
                 pass
 
-    def _step_overlap(self) -> int:
-        """One pipelined scheduler iteration (overlap on). With a
-        dispatch in flight: plan iteration N+1 (sweep marks, QoS/DRR
-        admission, the whole numpy build) WHILE the device runs
-        iteration N, then LAUNCH N+1 onto the device's queue behind N,
-        sync+commit N, and deliver N's tokens and completions to their
-        clients — one fused dispatch and one device_get per step, and
-        the chip goes from N to N+1 with no host in between: for the
-        length of the commit two dispatches are uncommitted (`_ahead`
-        behind `_inflight`), at plan time one, as the planned frame
-        assumes. The order is the plan's, chosen per iteration from
-        what it shows (`_launch_waits`): where the launch needs what
-        only the commit knows (draft tokens in play, a constrained
-        row, a hand-off to prefetch) the step takes the order it had:
-        commit N, patch from the ledger, launch N+1, deliver. The same
-        two functions either way; each dispatch's flight record says
-        which (`launch_ahead`, else `launch_waits`).
-        With nothing in flight (cold start, post-drain, famine): run
-        the byte-identical sequential iteration, then PRIME the
-        pipeline by planning and launching the next dispatch before
-        returning. Handoff callbacks queued by the step fire after
-        the lock releases (`_drain_handoff_ready`)."""
-        with self._step_lock:
-            self.tracer.step_start()
-            prof = self._profiler
-            try:
-                if self._faults is not None:
-                    self._faults.maybe_stall()
-                    self._faults.maybe_wedge(self._stop)
-                al = self.allocator
-                al.telemetry.iteration = self.flight.iterations + 1
-                if prof is not None:
-                    prof.begin(al.telemetry.iteration)
-                c0 = (al.pages_allocated, al.pages_released,
-                      al.evictions)
-                if self._inflight is None:
-                    # pipeline fill: the sequential iteration, plus a
-                    # launch-ahead prime so the NEXT step overlaps
-                    self._sweep_cancelled()
-                    if prof is not None:
-                        prof.enter("admission")
-                    self._start_admissions()
-                    self._iter_stats = {}
-                    p0 = self.preemptions
-                    t0 = (prof.t0 if prof is not None
-                          else time.perf_counter())
-                    if self._jobs:
-                        self._mixed_dispatch()
-                    elif self.active.any():
-                        self._decode_dispatch()
-                    if self._jobs or self.active.any():
-                        plan = self._plan_iteration()
-                        if plan is not None:
-                            self._launch_plan(plan)
-                    self._record_iteration(t0, p0, c0)
-                    if self._iter_stats:
-                        self.last_busy_ts = self._iter_stats["ts"]
-                    else:
-                        self.idle_iterations += 1
-                    ret = self.num_active
-                else:
-                    # steady state: one commit + one launch per step
-                    self._overlap_sweep()
-                    if prof is not None:
-                        prof.enter("admission")
-                    self._start_admissions()
-                    p0 = self.preemptions
-                    t0 = (prof.t0 if prof is not None
-                          else time.perf_counter())
-                    if self._faults is not None:
-                        # injected dispatch failure: ONE hit per step
-                        # (the fill path's site lives inside its
-                        # sequential dispatch), raised before the
-                        # commit below — serve_forever catches,
-                        # _fail_all drops the in-flight futures and
-                        # unblocks every waiter
-                        self._faults.check("dispatch")
-                    plan = self._plan_iteration()
-                    try:
-                        if plan is not None and plan.waits is None:
-                            # N+1 onto the device's queue behind N,
-                            # then N home: a launch that raised still
-                            # commits N
-                            try:
-                                self._launch_plan(plan)
-                            finally:
-                                self._commit_inflight()
-                        else:
-                            self._commit_inflight()
-                            if plan is not None:
-                                self._launch_plan(plan)
-                    finally:
-                        # after the launch, not before: the streaming
-                        # threads these calls wake run under the next
-                        # program. A launch that raised still leaves
-                        # the committed tokens with their clients
-                        # before _fail_all ends the requests
-                        if prof is not None:
-                            prof.enter("deliver")
-                        self._deliver()
-                    self._record_iteration(t0, p0, c0)
-                    self.last_busy_ts = self._iter_stats["ts"]
-                    ret = self.num_active
-            finally:
-                self.tracer.step_end()
-        self._drain_handoff_ready()
-        return ret
-
     # -- scheduler ----------------------------------------------------------
-
-    def _sweep_cancelled(self) -> None:
-        """Reap cancelled and deadline-expired requests that already
-        hold a slot (pages go back through the normal `_release_slot`
-        path — the KV they wrote is fully committed, so it stays
-        reusable in the prefix cache). Slots still inside an admission
-        job are left to finish their (bounded, already-batched)
-        chunks — _run_one_chunk checks the cancel flag at activation,
-        and an expired request is reaped by the next sweep. Expired
-        PENDING requests are reaped here too, so a deadline is honored
-        even if the request never reaches a slot. The expiry clock is
-        read lazily: zero reads per iteration when no live request
-        carries a deadline."""
-        job_slots = {s for job in self._jobs for s in job.slots}
-        now = None
-        for sid, slot in enumerate(self._slots):
-            if slot is None or sid in job_slots:
-                continue
-            if slot.req._cancel.is_set():
-                slot = self._release_slot(sid, self._committed(sid))
-                slot.req.finish_reason = "cancelled"
-                self._complete(slot.req)
-                continue
-            if slot.req.deadline is not None:
-                if now is None:
-                    now = time.perf_counter()
-                if now > slot.req.deadline:
-                    slot = self._release_slot(sid, self._committed(sid))
-                    slot.req.finish_reason = "deadline"
-                    self._complete(slot.req)
-        self._expire_pending(now)
 
     def _expire_pending(self, now: float | None) -> None:
         """Reap deadline-expired PENDING requests (pure host-queue
-        state — safe whether or not a dispatch is in flight, so both
-        the sequential and the overlap sweep share it). The expiry
+        state, safe whether or not a dispatch is in flight). The expiry
         clock stays lazy: zero reads when nothing pending carries a
         deadline."""
         with self._lock:
@@ -4415,36 +3758,47 @@ class PagedInferenceServer:
             self._complete(r)
 
     def step(self) -> int:
-        """One scheduler iteration: reap cancellations, start
-        admissions, then dispatch. With the mixed scheduler and any
-        admission in flight, prefill chunks and decode rows fuse into
-        ONE token-budget dispatch (stall-free); otherwise (steady state,
-        or the alternating scheduler) prefill chunks and a multi-round
-        decode dispatch run separately. Thread-safe.
+        """One scheduler iteration: plan the next dispatch, launch it,
+        commit the one before it. Nothing else dispatches. Thread-safe.
+
+        With a dispatch in flight: plan iteration N+1 (sweep marks,
+        QoS/DRR admission, the whole numpy build) WHILE the device runs
+        iteration N, then LAUNCH N+1 onto the device's queue behind N,
+        sync+commit N, and deliver N's tokens and completions to their
+        clients — one fused dispatch and one device_get per step, and
+        the chip goes from N to N+1 with no host in between: for the
+        length of the commit two dispatches are uncommitted (`_ahead`
+        behind `_inflight`), at plan time one, as the planned frame
+        assumes. The order is the plan's, chosen per iteration from
+        what it shows (`_launch_waits`): where the launch needs what
+        only the commit knows (draft tokens in play, a constrained
+        row, a hand-off to prefetch) the step commits N, patches from
+        the ledger, launches N+1 and delivers. The same two functions
+        either way; each dispatch's flight record says which
+        (`launch_ahead`, else `launch_waits`).
+
+        With nothing in flight (cold start, post-drain, famine) the
+        step FILLS the pipeline: it releases what the sweep marked,
+        plans against the committed ledger (such a plan may preempt,
+        `_plan_iteration`) and launches; the next step commits it. A
+        fill step commits nothing, so its flight record holds what the
+        step itself did (`fill`, its phases, page flow, preemptions,
+        what the plan staged and the launch handed over) and no token
+        or gap field; the launched program's own record is written by
+        the step that commits it, as every program's is.
+
+        The injected-fault "dispatch" site is checked once a step that
+        has something to dispatch, before the plan: a raise there
+        crashes the iteration before any device work, the way a
+        poisoned program would (serve_forever catches, `_fail_all`
+        drops the in-flight futures and unblocks every waiter, the
+        router's breaker/retry path takes it from there).
 
         With the iteration profiler enabled (the default) every phase
-        boundary is stamped (`sweep` / `admission` here; `build` /
-        `device` / `commit` inside the dispatch paths; `epilogue` in
-        _record_iteration) and the iteration's t0 is the profiler's —
+        boundary is stamped and the iteration's t0 is the profiler's,
         so a busy flight record's `duration_ms` covers the WHOLE
-        iteration and equals `host_ms + device_wait_ms` exactly.
-        Disabled, the historical two-read clock (dispatch start →
-        epilogue) is byte-identical.
-
-        With the async double-buffered scheduler enabled (overlap on,
-        mixed scheduler — the default) the iteration is PIPELINED:
-        see `_step_overlap`. overlap=False keeps the sequential body
-        below byte-identical to the pre-overlap build."""
-        if self._overlap_enabled:
-            return self._step_overlap()
-        ret = self._step_sequential()
-        self._drain_handoff_ready()
-        return ret
-
-    def _step_sequential(self) -> int:
-        """The sequential iteration body of step() (overlap off or the
-        alternating scheduler), split out so step() can fire handoff
-        callbacks AFTER `_step_lock` releases. Byte-identical work."""
+        iteration. Handoff callbacks queued by the step fire after the
+        lock releases (`_drain_handoff_ready`)."""
         with self._step_lock:
             self.tracer.step_start()
             prof = self._profiler
@@ -4467,28 +3821,59 @@ class PagedInferenceServer:
                     prof.begin(al.telemetry.iteration)
                 c0 = (al.pages_allocated, al.pages_released,
                       al.evictions)
-                self._sweep_cancelled()
+                fill = self._inflight is None
+                self._sweep()
+                if fill:
+                    self._apply_reaps()
                 if prof is not None:
                     prof.enter("admission")
                 self._start_admissions()
-                self._iter_stats = {}
                 p0 = self.preemptions
                 t0 = prof.t0 if prof is not None else time.perf_counter()
-                if self._mixed_enabled and self._jobs:
-                    self._mixed_dispatch()
-                else:
-                    for job in list(self._jobs):
-                        self._run_one_chunk(job)
-                    if self.active.any():
-                        self._decode_dispatch()
+                if fill:
+                    self._iter_stats = {}
+                busy = not fill or bool(self._jobs) or bool(
+                    self.active.any())
+                try:
+                    if busy:
+                        if self._faults is not None:
+                            self._faults.check("dispatch")
+                        plan = self._plan_iteration()
+                        if fill:
+                            if plan is not None:
+                                self._launch_plan(plan)
+                                self._iter_stats["fill"] = True
+                        elif plan is not None and plan.waits is None:
+                            # N+1 onto the device's queue behind N,
+                            # then N home: a launch that raised still
+                            # commits N
+                            try:
+                                self._launch_plan(plan)
+                            finally:
+                                self._commit_inflight()
+                        else:
+                            self._commit_inflight()
+                            if plan is not None:
+                                self._launch_plan(plan)
+                finally:
+                    # after the launch, not before: the streaming
+                    # threads these calls wake run under the next
+                    # program. A launch that raised still leaves the
+                    # committed tokens with their clients before
+                    # _fail_all ends the requests
+                    if busy and prof is not None:
+                        prof.enter("deliver")
+                    self._deliver()
                 self._record_iteration(t0, p0, c0)
                 if self._iter_stats:
                     self.last_busy_ts = self._iter_stats["ts"]
                 else:
                     self.idle_iterations += 1
-                return self.num_active
+                ret = self.num_active
             finally:
                 self.tracer.step_end()
+        self._drain_handoff_ready()
+        return ret
 
     def _stage_decode_spans(self, live_ids, n_rounds: int,
                             out: list | None = None) -> None:
@@ -4508,14 +3893,15 @@ class PagedInferenceServer:
     def _record_iteration(self, t0: float, p0: int,
                           c0: tuple[int, int, int]) -> None:
         """Flight-recorder epilogue for one busy scheduler iteration:
-        the dispatch paths filled `_iter_stats` with their token split;
-        this adds the budget/occupancy derived fields and appends ONE
-        ring-buffer record. Idle iterations (nothing dispatched) leave
+        `_iter_stats` is the committed dispatch's, with the token split
+        its plan staged (a fill step's own: `step`); this adds the
+        budget/occupancy derived fields and appends ONE ring-buffer
+        record. Idle iterations (nothing dispatched) leave
         `_iter_stats` empty and record nothing, so the ring holds the
         last N *busy* iterations.
 
-        Tracing epilogue too: spans the dispatch paths staged this
-        iteration are stamped with the SAME (t0, now) frame and the
+        Tracing epilogue too: spans the plan staged for the committed
+        dispatch are stamped with the SAME (t0, now) frame and the
         flight-recorder iteration index — the cross-link that lets a
         slow span answer "what else was the scheduler doing that
         iteration" in one hop, at the cost of zero extra clock reads
@@ -4533,10 +3919,9 @@ class PagedInferenceServer:
             prof.enter("epilogue")
         decode_tokens = st.get("decode_tokens", 0)
         st["tokens_scheduled"] = decode_tokens + st.get("prefill_tokens", 0)
-        if st.get("scheduler") == "mixed":
-            st["budget_tokens"] = self.mixed_token_budget
-            st["budget_utilization"] = (st["tokens_scheduled"]
-                                        / self.mixed_token_budget)
+        st["budget_tokens"] = self.mixed_token_budget
+        st["budget_utilization"] = (st["tokens_scheduled"]
+                                    / self.mixed_token_budget)
         # every preemption requeues its request at the queue front, so
         # this single field IS both the preemption and the requeue count
         st["preemptions"] = self.preemptions - p0
@@ -4549,12 +3934,12 @@ class PagedInferenceServer:
                 for k, v in self.qos.fair_shares().items()}
         st["n_jobs"] = len(self._jobs)
         st["pending"] = self.num_pending
-        # what the launched program was (`_stage_program_kind`); an
-        # iteration that launched none joined and sorted nothing
+        # what the committed program was (`_stage_program_kind`); a
+        # step that committed none joined and sorted nothing
         st.setdefault("joined", False)
         st.setdefault("grouped", False)
         # host arrays handed to the device in this iteration's `launch`
-        # phase; a sequential iteration has no such phase
+        # phase (0: it launched nothing)
         st["launch_h2d"] = self._iter_launch_h2d
         self._iter_launch_h2d = 0
         # what THIS step's planning staged onto the device for the
@@ -4609,10 +3994,11 @@ class PagedInferenceServer:
                 # `duration_ms` the scheduler's period, in no phase
                 st["between_ms"] = prof.between_ms
             overlapped = bool(st.get("overlap"))
-            st.update(derive_gap_fields(phases, st["duration_ms"],
-                                        overlapped))
             hists = self._phase_hists
             if overlapped:
+                # the step committed a program (a fill waited on none
+                # and has no gap fields)
+                st.update(derive_gap_fields(phases, st["duration_ms"]))
                 # sweep/admission/build ran under the in-flight
                 # device program and deliver under the one launched
                 # since: fold them into the `overlap` series
@@ -4630,9 +4016,9 @@ class PagedInferenceServer:
             now = time.perf_counter()
             st["duration_ms"] = (now - t0) * 1e3
         if self._iter_launch_ts is not None:
-            # the launch-ahead performed THIS step (the Perfetto
-            # inflight track pairs it with the NEXT record's residual
-            # device wait)
+            # the launch performed THIS step (the Perfetto inflight
+            # track pairs it with the NEXT record's residual device
+            # wait)
             st["t_launch"] = self._iter_launch_ts
             self._iter_launch_ts = None
         if self._brownout is not None:
@@ -4645,8 +4031,8 @@ class PagedInferenceServer:
                        else now - head.submit_time)
             st["brownout_level"] = self._brownout.observe(
                 pending_age_s=age,
-                budget_utilization=st.get("budget_utilization", 0.0),
-                host_gap_frac=st.get("host_gap_frac", 0.0))
+                budget_utilization=st["budget_utilization"],
+                host_gap_frac=st.get("host_gap_frac"))
         if self._anomaly is not None:
             # watchdog feed: every signal is a field this record
             # already owns (the epilogue clock mark, int deltas) —
@@ -4656,7 +4042,7 @@ class PagedInferenceServer:
             self._anomaly_cache_base = cur
             hit_d = cur[0] - hb[0]
             fired = self._anomaly.observe_iteration(
-                now=now, host_gap_frac=st.get("host_gap_frac", 0.0),
+                now=now, host_gap_frac=st.get("host_gap_frac"),
                 pending=st["pending"],
                 preempt_delta=st["preemptions"],
                 cache_lookup_delta=hit_d + (cur[1] - hb[1]),
@@ -5001,20 +4387,17 @@ class PagedInferenceServer:
         }
 
     def overlap_stats(self) -> dict:
-        """The /stats `overlap` block: the async scheduler's resolved
-        knob state, the live pipeline depth and `launch_ahead_share`.
-        Scrape path only."""
+        """The /stats `overlap` block: the live pipeline depth and
+        `launch_ahead_share`. Scrape path only."""
         ahead = [r["launch_ahead"] for r in self.flight.window()
                  if "launch_ahead" in r]
         return {
-            "enabled": self.overlap,
-            "active": self._overlap_enabled,
             # analysis: allow[lock-discipline] racy-by-design
             # monitoring read; staleness bounded by one iteration
             "inflight_depth": 0 if self._inflight is None else 1,
-            # of the overlapped dispatches in the flight window, the %
-            # that went onto the device's queue ahead of the commit
-            # before them (the others' records say why not:
+            # of the dispatches in the flight window, the % that went
+            # onto the device's queue ahead of the commit before
+            # them (the others' records say why not:
             # `launch_waits`); nothing before the first
             **({"launch_ahead_share": 100.0 * sum(ahead) / len(ahead)}
                if ahead else {}),
@@ -5288,7 +4671,7 @@ class PagedInferenceServer:
                         "request is not live on this server (already "
                         "finished, failed, or cancelled)")
             return self._build_snapshot(req, reason, (), None), None, []
-        if any(sid in job.slots for job in self._jobs):
+        if any(sid == job.slot for job in self._jobs):
             raise RuntimeError(
                 "request is mid-admission (chunked prefill in "
                 "flight); not exportable until prefill completes")
@@ -5494,7 +4877,7 @@ class PagedInferenceServer:
             if self._inflight is not None:
                 self._commit_inflight()
                 self._deliver()
-            job_slots = {s for job in self._jobs for s in job.slots}
+            job_slots = {job.slot for job in self._jobs}
             for sid, slot in enumerate(self._slots):
                 if slot is None or sid in job_slots:
                     continue
@@ -5561,8 +4944,8 @@ class PagedInferenceServer:
             # dispatch (cloud_server_unserialized_teardown_total)
             self.unserialized_teardowns += 1
         try:
-            # what a sequential step that raised inside its commit
-            # left recorded: to the clients first (nothing, otherwise)
+            # what a step that raised inside its commit left recorded:
+            # to the clients first (nothing, otherwise)
             self._deliver()
             with self._lock:
                 pending, self._pending = (list(self._pending),
@@ -5578,8 +4961,7 @@ class PagedInferenceServer:
                     slot.req.finish_reason = f"error: {exc!r}"
                     self._complete(slot.req)
             self._jobs.clear()
-            # async scheduler: drop the launched-but-uncommitted
-            # dispatch's futures (its results belong to requests that
+            # drop the launched-but-uncommitted dispatch's futures (its results belong to requests that
             # just failed; like the wedged-teardown case, any still-
             # running device work finishes into buffers nothing reads)
             self._inflight = self._ahead = None
@@ -5603,14 +4985,13 @@ class PagedInferenceServer:
                 self._fail_all(exc)
                 self._stop.set()
                 return
-            # cooperative yield after every busy step: the sequential
-            # loop's blocking device_get released the GIL for a whole
-            # device step each iteration, guaranteeing stream-consumer
-            # threads (SSE writers, result() waiters) a drain window;
-            # the pipelined loop's syncs can return instantly, so
-            # without an explicit yield a fast scheduler can emit a
-            # whole request before a streaming client's writer thread
-            # runs once — delaying disconnect detection to the end
+            # cooperative yield after every busy step: the pipelined
+            # loop's syncs can return instantly (the program finished
+            # under the host's own work), so without an explicit yield
+            # a fast scheduler can emit a whole request before a
+            # streaming client's writer thread (SSE writers, result()
+            # waiters) runs once — delaying disconnect detection to
+            # the end
             if busy:
                 time.sleep(0)
             # analysis: allow[lock-discipline] idle-polling read on the

@@ -33,8 +33,8 @@ regression tests enforce this):
 
 With no QoS config (`registry is None`) every server path is the
 pre-QoS code byte-for-byte: the schedulers guard every call site with
-`if self.qos is not None`, and the mixed-vs-alternating exact-output
-tests pin the default behavior.
+`if self.qos is not None`, and the exact-output tests against the
+dense engine pin the default behavior.
 
 Work-conservation note: a tenant in generated-token debt is SKIPPED by
 admission only while some other tenant is eligible; when every

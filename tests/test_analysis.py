@@ -745,6 +745,53 @@ def test_dispatch_overlap_plan_release_free():
     assert not [f for f in findings if f.symbol == "S._overlap_sweep"]
 
 
+def test_dispatch_a_plan_with_nothing_in_flight_may_release():
+    """DD5 is about a plan made under a dispatch in flight: what stands
+    in the body of `if <the in-flight dispatch> is None:` runs with
+    nothing in flight and may release pages. The else branch, a test
+    on anything else, and a local bound twice stay policed."""
+    def findings(body):
+        src = ("class S:\n"
+               "    def _extend_chains(self, n):\n"
+               "        self._release_slot(0)\n"
+               "    def _release_slot(self, sid):\n"
+               "        pass\n"
+               "    def _plan_iteration(self):\n" + body)
+        return dispatch.check_overlap_source(
+            "s.py", src, ("S._plan_iteration",))
+
+    assert not findings(
+        "        infl = self._inflight\n"
+        "        if infl is None:\n"
+        "            n = self._extend_chains(2)\n"
+        "        else:\n"
+        "            n = self._grow(2)\n")
+    assert not findings(
+        "        if self._inflight is None:\n"
+        "            self._extend_chains(2)\n")
+    for bad in (
+            # the branch that runs under a dispatch in flight
+            "        infl = self._inflight\n"
+            "        if infl is None:\n"
+            "            pass\n"
+            "        else:\n"
+            "            self._extend_chains(2)\n",
+            # not the in-flight dispatch
+            "        if self._ahead is None:\n"
+            "            self._extend_chains(2)\n",
+            # `is not None`
+            "        if self._inflight is not None:\n"
+            "            self._extend_chains(2)\n",
+            # a local that is bound again
+            "        infl = self._inflight\n"
+            "        infl = None\n"
+            "        if infl is None:\n"
+            "            self._extend_chains(2)\n"):
+        msgs = [f.message for f in findings(bad)]
+        assert any("_extend_chains" in m and "DD5" in m
+                   for m in msgs), (bad, msgs)
+
+
 def test_dispatch_overlap_missing_plan_function_is_a_finding():
     findings = dispatch.check_overlap_source(
         "s.py", "class S:\n    pass\n", ("S._plan_iteration",))
@@ -757,7 +804,7 @@ def test_dispatch_overlap_roster_covers_the_async_scheduler():
     quals = dispatch.OVERLAP_PLAN_FUNCS[rel]
     for want in ("PagedInferenceServer._plan_iteration",
                  "PagedInferenceServer._launch_plan",
-                 "PagedInferenceServer._overlap_sweep",
+                 "PagedInferenceServer._sweep",
                  "PagedInferenceServer._extend_chains_planned"):
         assert want in quals
     # the launch-ahead commit is a sanctioned sync, like every other
@@ -782,7 +829,7 @@ def test_rosters_cover_disaggregation():
     for needed in ("PagedInferenceServer._handoff_prefetch",
                    "PagedInferenceServer._drain_handoff_ready",
                    "PagedInferenceServer.pending_prefill_tokens",
-                   "PagedInferenceServer._step_sequential"):
+                   "PagedInferenceServer.step"):
         assert needed in loops, f"{needed} dropped from SCHEDULER_LOOPS"
     assert ("PagedInferenceServer._handoff_prefetch"
             in dispatch.OVERLAP_PLAN_FUNCS[rel]), \

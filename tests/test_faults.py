@@ -403,7 +403,9 @@ def test_brownout_sheds_low_classes_not_interactive(params):
     keep = srv.submit(PROMPT, max_new_tokens=8, tenant="inter")
     queued = srv.submit(PROMPT, max_new_tokens=8, tenant="inter")
     queued2 = srv.submit(PROMPT, max_new_tokens=8, tenant="inter")
-    srv.step()  # busy iteration: detector grades overloaded
+    srv.step()  # the fill: the head of the queue has waited
+    assert srv.brownout_stats()["level"] == 1
+    srv.step()  # a committed program: its budget's use is a signal too
     assert srv.brownout_stats()["level"] == 2
     with pytest.raises(BrownoutShedError) as ei:
         srv.submit(PROMPT, tenant="scraper")

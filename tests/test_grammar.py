@@ -314,13 +314,12 @@ def test_mixed_constrained_and_free_batch(params):
 
 
 def test_a_constrained_row_keeps_the_launch_behind_the_commit(params):
-    """The overlapped scheduler puts a dispatch on the device's queue
+    """The scheduler puts a dispatch on the device's queue
     ahead of the commit before it only where the plan has no constrained
     row (`_launch_waits`): with one, the dispatch waits, its flight
     record says why, and the free rows around it go ahead again once
     the constrained request is gone."""
     srv = PagedInferenceServer(params, CFG, ICFG, decode_chunk=1, **SRV_KW)
-    assert srv._overlap_enabled
     free = srv.submit(TOK.encode("hello"), max_new_tokens=24)
     con = srv.submit(TOK.encode("n:"), max_new_tokens=6,
                      sampling=SamplingParams(regex=r"[0-9]+"))

@@ -12,6 +12,7 @@ import urllib.request
 
 import jax
 import pytest
+from serial_order import waits
 
 from cloud_server_tpu.config import InferConfig, ModelConfig
 from cloud_server_tpu.inference import iteration_profile as ip
@@ -169,11 +170,12 @@ def test_lap_reads_the_clock_and_moves_no_boundary(monkeypatch):
 
 
 def test_derive_gap_fields():
-    d = derive_gap_fields({"sweep": 1.0, "admission": 2.0, "device": 7.0},
-                          10.0)
-    assert d["host_ms"] == pytest.approx(3.0)
-    assert d["device_wait_ms"] == pytest.approx(7.0)
-    assert d["host_gap_frac"] == pytest.approx(0.3)
+    d = derive_gap_fields({"sweep": 1.0, "admission": 2.0, "device": 6.0,
+                           "commit": 1.0}, 10.0)
+    assert d["overlap_ms"] == pytest.approx(3.0)
+    assert d["host_ms"] == pytest.approx(1.0)
+    assert d["device_wait_ms"] == pytest.approx(6.0)
+    assert d["host_gap_frac"] == pytest.approx(0.1)
     assert derive_gap_fields({}, 0.0)["host_gap_frac"] == 0.0
 
 
@@ -183,15 +185,13 @@ def test_derive_gap_fields_overlapped_hides_plan_and_deliver():
     are `overlap_ms`; the serialized tail is commit, launch, epilogue."""
     phases = {"sweep": 0.5, "admission": 1.5, "build": 3.0, "device": 30.0,
               "commit": 2.0, "launch": 4.0, "deliver": 6.0, "epilogue": 1.0}
-    d = derive_gap_fields(phases, 48.0, overlapped=True)
+    d = derive_gap_fields(phases, 48.0)
     assert d["overlap_ms"] == pytest.approx(11.0)
     assert d["host_ms"] == pytest.approx(7.0)
     assert d["device_wait_ms"] == pytest.approx(30.0)
     assert d["host_ms"] + d["device_wait_ms"] + d["overlap_ms"] \
         == pytest.approx(48.0)
     assert d["host_gap_frac"] == pytest.approx(7.0 / 48.0)
-    # the same split, sequential: nothing hides
-    assert derive_gap_fields(phases, 48.0)["host_ms"] == pytest.approx(18.0)
 
 
 def test_resolve_profiler_forms():
@@ -231,49 +231,87 @@ def _churn(srv, n_first=2, long_len=40):
 
 
 def test_flight_records_carry_phase_split(params):
-    srv = PagedInferenceServer(params, CFG, GREEDY, scheduler="mixed",
-                               **PAGED_KW)
+    srv = PagedInferenceServer(params, CFG, GREEDY, **PAGED_KW)
     reqs = _churn(srv)
     assert all(r.done for r in reqs)
     window = srv.flight_window()
     assert window
+    # every record is a fill step's or that of a step that committed
+    ov = [rec for rec in window if rec.get("overlap")]
+    assert ov and all(rec.get("fill") for rec in window if rec not in ov)
     for rec in window:
         phases = rec["phases_ms"]
         assert set(phases) <= set(PHASES)
         assert all(v >= 0.0 for v in phases.values())
+        assert rec["t_start"] > 0.0 and "epilogue" in phases
+    for rec in ov:
+        phases = rec["phases_ms"]
         # the acceptance identity: the phase split PARTITIONS the
-        # iteration — host + device-wait (+ overlapped host work on
-        # async-scheduler iterations) reassemble duration exactly
+        # iteration — the host's tail, the device wait and the host
+        # work hidden under a program reassemble duration exactly
         assert (rec["host_ms"] + rec["device_wait_ms"]
-                + rec.get("overlap_ms", 0.0)) == pytest.approx(
+                + rec["overlap_ms"]) == pytest.approx(
             rec["duration_ms"], rel=1e-9, abs=1e-6)
         assert 0.0 <= rec["host_gap_frac"] <= 1.0
-        assert rec["t_start"] > 0.0
-        # a busy mixed iteration crossed every boundary
-        assert "device" in phases and "epilogue" in phases
-    # the default scheduler pipelines: the steady-state records are
-    # overlapped and carry the async fields
-    ov = [rec for rec in window if rec.get("overlap")]
-    assert ov, "default mixed churn produced no overlapped iterations"
-    for rec in ov:
+        # a step that committed crossed every boundary
+        assert "device" in phases
         assert "inflight_depth" not in rec
         assert rec["overlap_launch_lead_ms"] >= 0.0
-    # per-phase histograms observed once per busy iteration
+    # per-phase histograms observed once per committing iteration
     snap = srv.metrics_snapshot()
     dev = snap['cloud_server_iter_phase_ms{phase="device"}']
     assert dev["type"] == "histogram"
-    assert dev["count"] == srv.flight.iterations
+    assert dev["count"] == len(ov)
     summary = srv.iteration_profile_stats()
     assert set(summary["phases"]) <= set(PHASES) | {"overlap"}
     assert 0.0 <= summary["host_gap_frac"] <= 1.0
+
+
+def test_the_fill_steps_record(params):
+    """A step with nothing in flight plans and launches and commits
+    nothing. Its record says `fill` and holds what the step itself did:
+    its phases (no `device`, no `commit`), the launch and what the plan
+    staged, page flow and pool state; no token split, no gap fields, no
+    launch flag: those are the record's of the step that commits the
+    program, one step later."""
+    srv = PagedInferenceServer(params, CFG, GREEDY, **PAGED_KW)
+    srv.submit([5, 9, 3], max_new_tokens=4)
+    srv.step()
+    rec, = srv.flight_window()
+    assert rec["fill"] is True and rec["iteration"] == 1
+    assert set(rec["phases_ms"]) == {"sweep", "admission", "build",
+                                     "launch", "deliver", "epilogue"}
+    assert rec["duration_ms"] == pytest.approx(
+        sum(rec["phases_ms"].values()), rel=1e-9, abs=1e-6)
+    assert rec["launch_h2d"] == 1 and rec["plan_h2d"] == 2
+    assert rec["stage_ms"] >= 0.0 and rec["t_launch"] >= rec["t_start"]
+    assert rec["pages_allocated"] >= 1 and rec["preemptions"] == 0
+    assert rec["tokens_scheduled"] == 0
+    assert rec["budget_utilization"] == 0.0
+    assert rec["joined"] is False and rec["grouped"] is False
+    for absent in ("overlap", "host_ms", "device_wait_ms", "overlap_ms",
+                   "host_gap_frac", "n_live", "decode_rounds",
+                   "prefill_tokens", "launch_ahead", "launch_waits",
+                   "host_late", "between_ms"):
+        assert absent not in rec, absent
+    srv.step()
+    nxt = srv.flight_window()[-1]
+    # the program the fill launched: committed, and recorded, here
+    assert nxt["overlap"] and nxt["launch_waits"] == "fill"
+    assert nxt["prefill_tokens"] == 3 and "fill" not in nxt
+    assert nxt["between_ms"] >= 0.0
+    # the fill's phases are host time in the histograms, unfolded
+    snap = srv.metrics_snapshot()
+    assert snap['cloud_server_iter_phase_ms{phase="build"}']["count"] == 1
+    assert snap['cloud_server_iter_phase_ms{phase="overlap"}']["count"] == 1
+    srv.run_until_idle()
 
 
 def test_overlapped_records_carry_the_delivery(params):
     """A step that committed and launched has a `deliver` phase, the
     count of stream calls and completions it made, and the identity
     with `deliver` among the hidden phases, not in the host tail."""
-    srv = PagedInferenceServer(params, CFG, GREEDY, scheduler="mixed",
-                               **PAGED_KW)
+    srv = PagedInferenceServer(params, CFG, GREEDY, **PAGED_KW)
     streamed = []
     reqs = [srv.submit([5 + i, 9, 3], max_new_tokens=8,
                        stream=streamed.append) for i in range(2)]
@@ -307,8 +345,7 @@ def test_records_tile_the_clock_with_between_ms(params):
     busy steps starts where the one before closed, and a record after
     an idle step has none again. `between_ms` is outside the identity
     `host_ms + device_wait_ms + overlap_ms == duration_ms`."""
-    srv = PagedInferenceServer(params, CFG, GREEDY, scheduler="mixed",
-                               **PAGED_KW)
+    srv = PagedInferenceServer(params, CFG, GREEDY, **PAGED_KW)
     assert all(r.done for r in _churn(srv))
     window = srv.flight_window()
     assert len(window) > 3 and "between_ms" not in window[0]
@@ -341,8 +378,7 @@ def test_launched_ahead_steps_tile_the_clock(params):
     run's length within 0.1%, as `PERF.md` section 5 reads the period.
     The Perfetto iteration track carries the dispatch's `launch_ahead`,
     and `launch_waits` where it waited."""
-    srv = PagedInferenceServer(params, CFG, GREEDY, scheduler="mixed",
-                               decode_chunk=1, **PAGED_KW)
+    srv = PagedInferenceServer(params, CFG, GREEDY, decode_chunk=1, **PAGED_KW)
     reqs = [srv.submit([5 + i, 9, 3], max_new_tokens=30) for i in range(2)]
     srv.run_until_idle()
     assert all(r.done for r in reqs)
@@ -386,8 +422,7 @@ def test_records_split_build_and_say_who_set_the_pace(params):
     record of the step that committed the program: a bool on every
     overlapped record, true where the program was known ready before
     the step came for it."""
-    srv = PagedInferenceServer(params, CFG, GREEDY, scheduler="mixed",
-                               **PAGED_KW)
+    srv = PagedInferenceServer(params, CFG, GREEDY, **PAGED_KW)
     first = [srv.submit([5 + i, 9, 3], max_new_tokens=8) for i in range(2)]
     srv.step()
     srv.step()
@@ -417,8 +452,7 @@ def test_records_split_build_and_say_who_set_the_pace(params):
         assert rec["launch_h2d"] <= min(rec["plan_h2d"], 1)
     assert any(rec["plan_h2d"] for rec in window)
     # with the profiler off the counter stays and the time goes
-    off = PagedInferenceServer(params, CFG, GREEDY, scheduler="mixed",
-                               iteration_profile=False, **PAGED_KW)
+    off = PagedInferenceServer(params, CFG, GREEDY, iteration_profile=False, **PAGED_KW)
     assert all(r.done for r in _churn(off))
     for rec in off.flight_window():
         assert "stage_ms" not in rec and "between_ms" not in rec
@@ -426,17 +460,17 @@ def test_records_split_build_and_say_who_set_the_pace(params):
         assert isinstance(rec.get("host_late", False), bool)
 
 
-@pytest.mark.parametrize("overlap", [True, False])
-def test_records_say_which_programs_walked_the_layers_once(params, overlap):
+@pytest.mark.parametrize("order", ["ahead", "waits"])
+def test_records_say_which_programs_walked_the_layers_once(params, order):
     """`joined`: true on the records of mixed steps whose program took
     the one walk (one plain decode round beside a prefill group), false
     on decode-only programs and on steps of several rounds, and on
     every record, whichever way its step was launched."""
     def churn(**kw):
-        srv = PagedInferenceServer(params, CFG, GREEDY, scheduler="mixed",
-                                   overlap=overlap, **PAGED_KW, **kw)
+        srv = waits(PagedInferenceServer(params, CFG, GREEDY, **PAGED_KW,
+                                         **kw), order == "waits")
         assert all(r.done for r in _churn(srv))
-        return srv.flight_window()
+        return [r for r in srv.flight_window() if not r.get("fill")]
 
     window = churn(decode_chunk=1)
     assert all(isinstance(rec["joined"], bool) for rec in window)
@@ -450,16 +484,6 @@ def test_records_say_which_programs_walked_the_layers_once(params, overlap):
     for rec in churn():
         assert rec["joined"] == (rec.get("prefill_tokens", 0) > 0
                                  and rec["decode_rounds"] == 1)
-
-
-def test_alternating_scheduler_phase_split(params):
-    srv = PagedInferenceServer(params, CFG, GREEDY,
-                               scheduler="alternating", **PAGED_KW)
-    reqs = _churn(srv)
-    assert all(r.done for r in reqs)
-    for rec in srv.flight_window():
-        assert rec["host_ms"] + rec["device_wait_ms"] == pytest.approx(
-            rec["duration_ms"], rel=1e-9, abs=1e-6)
 
 
 def test_profiler_disabled_keeps_old_shape(params):
@@ -494,8 +518,7 @@ def test_profiled_mixed_step_dispatch_sync_and_clock_counts(
     work, else the decode/spec program — and ONE device_get (the
     previous launch's commit)."""
     from cloud_server_tpu.inference import paged_server as ps
-    srv = PagedInferenceServer(params, CFG, GREEDY, scheduler="mixed",
-                               iteration_profile=True, **PAGED_KW)
+    srv = PagedInferenceServer(params, CFG, GREEDY, iteration_profile=True, **PAGED_KW)
     warm = srv.submit([5, 9, 3, 1], max_new_tokens=24)
     srv.step()
     assert srv.num_active == 1
@@ -592,8 +615,7 @@ def test_idle_vs_busy_visibility(params):
 
 
 def test_scheduler_chrome_trace_wellformed(params):
-    srv = PagedInferenceServer(params, CFG, GREEDY, scheduler="mixed",
-                               **PAGED_KW)
+    srv = PagedInferenceServer(params, CFG, GREEDY, **PAGED_KW)
     reqs = _churn(srv)
     assert all(r.done for r in reqs)
     window = srv.flight_window()
@@ -667,8 +689,7 @@ def test_cross_link_span_to_iteration_roundtrip(params):
     carries an iteration index; the flight record with that index
     frames the span exactly (same t0/now pair), and the Perfetto
     export's iteration event agrees."""
-    srv = PagedInferenceServer(params, CFG, GREEDY, scheduler="mixed",
-                               tracing=1.0, **PAGED_KW)
+    srv = PagedInferenceServer(params, CFG, GREEDY, tracing=1.0, **PAGED_KW)
     reqs = _churn(srv)
     assert all(r.done for r in reqs)
     window = srv.flight_window()

@@ -15,9 +15,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from serial_order import waits
 
 from cloud_server_tpu.config import InferConfig, ModelConfig
-from cloud_server_tpu.inference import paged_engine
+from cloud_server_tpu.inference import engine, paged_engine
 from cloud_server_tpu.inference import paged_server as ps
 from cloud_server_tpu.inference.paged_server import PagedInferenceServer
 from cloud_server_tpu.inference.sampling import SamplingParams, make_rows
@@ -295,13 +296,19 @@ def _staggered(srv, sampling):
     return [(r.result(), r.logprobs) for r in reqs]
 
 
-@pytest.mark.parametrize("overlap", [True, False])
+def _server(params, cfg, icfg, order, **kw):
+    return waits(PagedInferenceServer(params, cfg, icfg, **kw),
+                 order == "waits")
+
+
+@pytest.mark.parametrize("order", ["ahead", "waits"])
 @pytest.mark.parametrize("name", ["dense", "dropless_moe"])
 @pytest.mark.parametrize("seeded", [False, True])
-def test_joined_server_streams_equal_alternating(name, seeded, overlap):
-    """At one decode round a step the mixed scheduler's programs take
-    the one walk; what the clients get is the alternating scheduler's
-    stream, token for token, with its log-probabilities."""
+def test_joined_server_streams_equal_two_walks(name, seeded, order):
+    """At one decode round a step the programs take the one walk; what
+    the clients get is the stream of the programs that walk the layers
+    twice (two rounds a step: `_walks_once`), token for token, with its
+    log-probabilities, and greedy the dense engine's."""
     cfg = CONFIGS[name]
     params = _params(cfg)
     icfg = SAMPLED if seeded else GREEDY
@@ -309,27 +316,36 @@ def test_joined_server_streams_equal_alternating(name, seeded, overlap):
                                presence_penalty=0.4)
                 if seeded else None
                 for i in range(len(PROMPTS))]
-    mixed = PagedInferenceServer(params, cfg, icfg, scheduler="mixed",
-                                 overlap=overlap, **SRV_KW)
-    alt = PagedInferenceServer(params, cfg, icfg, scheduler="alternating",
-                               **SRV_KW)
-    got, want = _staggered(mixed, sampling), _staggered(alt, sampling)
+    joined = _server(params, cfg, icfg, order, **SRV_KW)
+    two = PagedInferenceServer(params, cfg, icfg,
+                               **dict(SRV_KW, decode_chunk=2))
+    got, want = _staggered(joined, sampling), _staggered(two, sampling)
     for (g_toks, g_lps), (w_toks, w_lps) in zip(got, want):
         assert g_toks == w_toks
         np.testing.assert_allclose(g_lps, w_lps, rtol=1e-4, atol=1e-5)
-    records = mixed.flight_window()
+    if not seeded:
+        for p, (g_toks, _) in zip(PROMPTS, got):
+            ref = engine.generate(
+                params, np.asarray([p], np.int32), jax.random.key(1),
+                cfg=cfg, infer_cfg=dataclasses.replace(
+                    icfg, max_decode_len=12))
+            assert g_toks == list(np.asarray(ref)[0]), p
+    records = joined.flight_window()
     assert any(r["joined"] for r in records)
     # a program of decode rounds alone has nothing to join
     assert all(not r["joined"] for r in records
                if not r.get("prefill_tokens"))
+    # the reference did walk twice wherever it ran two rounds
+    assert any(r.get("prefill_tokens") and r["decode_rounds"] == 2
+               and not r["joined"] for r in two.flight_window())
 
 
-@pytest.mark.parametrize("overlap", [True, False])
+@pytest.mark.parametrize("order", ["ahead", "waits"])
 @pytest.mark.parametrize("name,threshold", [
     ("dropless_moe", 18), ("dropless_moe", 10 ** 9), ("can_drop", 18),
     ("dense", 18)])
 def test_records_say_which_programs_sorted_their_experts(
-        name, threshold, overlap, monkeypatch):
+        name, threshold, order, monkeypatch):
     """`grouped`: true on the record of a step whose joined call (16
     chunk tokens a prompt row and the decode rows) is over the threshold,
     false on a program of decode rounds alone (4 rows at most), at a
@@ -344,14 +360,14 @@ def test_records_say_which_programs_sorted_their_experts(
     # nothing new in a process that never unmaps compiled code
     cfg = CONFIGS[name]
     if sorts:
-        cfg = dataclasses.replace(cfg, norm_eps=1e-5 + 1e-9 * (1 + overlap))
+        cfg = dataclasses.replace(
+            cfg, norm_eps=1e-5 + 1e-9 * (1 + (order == "ahead")))
     traced = []
     real = moe._grouped_experts
     monkeypatch.setattr(moe, "_grouped_experts",
                         lambda rows, *a, **kw: traced.append(
                             rows.shape[0]) or real(rows, *a, **kw))
-    srv = PagedInferenceServer(_params(cfg), cfg, GREEDY, scheduler="mixed",
-                               overlap=overlap, **SRV_KW)
+    srv = _server(_params(cfg), cfg, GREEDY, order, **SRV_KW)
     _staggered(srv, [None] * len(PROMPTS))
     records = srv.flight_window()
     assert all(isinstance(r["grouped"], bool) for r in records)

@@ -13,6 +13,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from serial_order import waits
 
 from cellbench import families, reference, serve
 from cellbench.families import longcat_flash
@@ -252,9 +253,11 @@ def make_server(model, **kw):
     if "decode_attention_impl" in opts:
         mcfg = dataclasses.replace(
             mcfg, decode_attention_impl=opts.pop("decode_attention_impl"))
-    return PagedInferenceServer(
+    # `waits`: every launch after the commit before it (serial_order)
+    waiting = opts.pop("waits", False)
+    return waits(PagedInferenceServer(
         weights, mcfg, InferConfig(max_decode_len=64, temperature=0.0,
-                                   eos_token_id=-1), **opts)
+                                   eos_token_id=-1), **opts), waiting)
 
 
 def serve_all(srv, prompts, max_new=24):
@@ -284,13 +287,12 @@ def worst_logprob_diff(model, prompts, handles):
 
 
 @pytest.mark.parametrize("mode", [
-    dict(scheduler="mixed", overlap=True),
-    dict(scheduler="mixed", overlap=False),
-    dict(scheduler="alternating"),
-    dict(scheduler="mixed", overlap=True, mixed_token_budget=40),
-    dict(scheduler="mixed", overlap=True, decode_attention_impl="pallas",
+    dict(),
+    dict(waits=True),
+    dict(mixed_token_budget=40),
+    dict(decode_attention_impl="pallas",
          page_size=128, max_context=256, num_pages=16, prefill_chunk=128),
-], ids=["overlap", "sequential", "alternating", "budget", "kernel"])
+], ids=["ahead", "waits", "budget", "kernel"])
 def test_served_requests_are_the_reference(model, mode):
     """Three requests of 174, 61 and 114 tokens through the server:
     chunked prefill, then decode through the latent pages, every served

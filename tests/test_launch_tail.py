@@ -1,6 +1,6 @@
 """One program and one transfer in the launch (CPU, tiny widths).
 
-Between a read-back and the next program the overlapped scheduler hands
+Between a read-back and the next program the scheduler hands
 the device ONE host array (the packed patch, with the dispatch's count
 in it) and dispatches ONE program, which makes its own key from the
 server's one key and that count: no `jax.random.split` runs outside a
@@ -18,9 +18,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from serial_order import waits
 
 from cloud_server_tpu.config import InferConfig, ModelConfig
 from cloud_server_tpu.data.tokenizer import ByteTokenizer
+from cloud_server_tpu.inference import engine
 from cloud_server_tpu.inference import paged_server as ps
 from cloud_server_tpu.inference.paged_server import PagedInferenceServer
 from cloud_server_tpu.inference.sampling import (
@@ -112,8 +114,7 @@ def _watch_launches(srv, monkeypatch):
 def test_a_launch_hands_the_device_one_array(params, monkeypatch,
                                              spec_drafts):
     srv = PagedInferenceServer(
-        params, CFG, GREEDY, scheduler="mixed", overlap=True,
-        flight_recorder_size=512, spec_drafts=spec_drafts, decode_chunk=1,
+        params, CFG, GREEDY, flight_recorder_size=512, spec_drafts=spec_drafts, decode_chunk=1,
         **SRV_KW)
     seen = _watch_launches(srv, monkeypatch)
     first = [srv.submit(p, max_new_tokens=20) for p in (REP, PROMPTS[1])]
@@ -137,8 +138,8 @@ def test_a_launch_hands_the_device_one_array(params, monkeypatch,
     assert seen["host_args"] == 0
     assert seen["eager_splits"] == 0
     # each launch that went out sits in the record of the step that made
-    # it; a step without one (sequential, or every planned row dead at
-    # the commit) records 0
+    # it; a step without one (the pipeline drains, or every planned row
+    # is dead at the commit) records 0
     counts = [rec["launch_h2d"] for rec in srv.flight_window()]
     assert set(counts) == {0, 1}
     assert sum(counts) == len(seen["launches"])
@@ -159,19 +160,22 @@ def test_a_launch_hands_the_device_one_array(params, monkeypatch,
 
 # -- (b) the key is made inside the program ----------------------------------
 
+def _server(params, cfg, icfg, waiting=False, **kw):
+    """The server as it is, or (`waiting`) with every launch made to
+    wait for the commit before it."""
+    return waits(PagedInferenceServer(params, cfg, icfg,
+                                      **{**SRV_KW, **kw}), waiting)
+
+
 def _sampled_streams(params, seed, **kw):
-    srv = PagedInferenceServer(params, CFG, SAMPLED, seed=seed,
-                               **{**SRV_KW, **kw})
+    srv = _server(params, CFG, SAMPLED, seed=seed, **kw)
     reqs = [srv.submit(p, max_new_tokens=10) for p in PROMPTS]
     srv.run_until_idle()
     return [r.result() for r in reqs]
 
 
-@pytest.mark.parametrize("kw", [
-    dict(scheduler="mixed", overlap=True),
-    dict(scheduler="mixed", overlap=False),
-    dict(scheduler="alternating"),
-], ids=["overlapped", "no_overlap", "alternating"])
+@pytest.mark.parametrize("kw", [dict(), dict(waiting=True)],
+                         ids=["ahead", "waits"])
 def test_b_a_server_seed_gives_its_stream_again(params, kw):
     again = _sampled_streams(params, 7, **kw)
     assert _sampled_streams(params, 7, **kw) == again
@@ -450,8 +454,7 @@ def test_f_a_plan_hands_the_device_two_arrays_or_one(params, monkeypatch,
     alone the rows', each a 2-D int32 array; the record of the step that
     planned says the same."""
     srv = PagedInferenceServer(
-        params, CFG, GREEDY, scheduler="mixed", overlap=True,
-        flight_recorder_size=512, decode_chunk=1, **{**SRV_KW, **kw})
+        params, CFG, GREEDY, flight_recorder_size=512, decode_chunk=1, **{**SRV_KW, **kw})
     handed, plans, staged = [], [], []
     put, plan_iteration = srv._to_device, srv._plan_iteration
 
@@ -499,9 +502,8 @@ def test_f_a_plan_without_a_decode_round_has_no_draft_limits(params):
     prompts = PROMPTS + [REP, [9, 9, 8]]
 
     def run(**kw):
-        srv = PagedInferenceServer(params, CFG, GREEDY, spec_drafts=2,
-                                   flight_recorder_size=512,
-                                   **{**SRV_KW, **kw})
+        srv = _server(params, CFG, GREEDY, spec_drafts=2,
+                      flight_recorder_size=512, **kw)
         reqs = [srv.submit(p, max_new_tokens=10) for p in prompts[:2]]
         for _ in range(3):
             srv.step()
@@ -509,32 +511,33 @@ def test_f_a_plan_without_a_decode_round_has_no_draft_limits(params):
         srv.run_until_idle()
         return srv, [r.result() for r in reqs]
 
-    srv, overlapped = run(scheduler="mixed", overlap=True)
+    srv, served = run()
     assert any(rec.get("decode_rounds") == 0 and rec.get("prefill_tokens")
                for rec in srv.flight_window())
-    assert run(scheduler="alternating")[1] == overlapped
+    for p, o in zip(prompts, served):
+        ref = engine.generate(
+            params, np.asarray([p], np.int32), jax.random.key(1), cfg=CFG,
+            infer_cfg=dataclasses.replace(GREEDY, max_decode_len=10))
+        assert o == list(np.asarray(ref)[0]), p
 
 
-# -- (d) the three scheduler paths, greedy and seeded ------------------------
+# -- (d) both orders of a step, greedy and seeded ----------------------------
 
 SEEDED = [SamplingParams(seed=100 + i, temperature=0.9, top_p=0.9)
           for i in range(len(PROMPTS))]
 
 
-def _three_paths(run):
-    overlapped = run(scheduler="mixed", overlap=True)
-    assert run(scheduler="mixed", overlap=False, decode_chunk=1) \
-        == overlapped
-    assert run(scheduler="alternating") == overlapped
-    return overlapped
+def _both_orders(run):
+    ahead = run()
+    assert run(waiting=True, decode_chunk=1) == ahead
+    return ahead
 
 
 @pytest.mark.parametrize("sampling", [None, SEEDED],
                          ids=["greedy", "seeded"])
-def test_d_paths_agree_token_for_token(params, sampling):
+def test_d_orders_agree_token_for_token(params, sampling):
     def run(**kw):
-        srv = PagedInferenceServer(params, CFG, GREEDY, seed=len(kw),
-                                   **{**SRV_KW, **kw})
+        srv = _server(params, CFG, GREEDY, seed=len(kw), **kw)
         sp = sampling or [None] * len(PROMPTS)
         reqs = [srv.submit(p, max_new_tokens=8, sampling=s)
                 for p, s in zip(PROMPTS[:2], sp[:2])]
@@ -545,14 +548,14 @@ def test_d_paths_agree_token_for_token(params, sampling):
         srv.run_until_idle()
         return [r.result() for r in reqs]
 
-    _three_paths(run)
+    _both_orders(run)
 
 
 # every field of the packed buffers in play at once: a sampler row with
 # its seed's top bit set, a filter chain, penalties and a bias of either
 # sign; a row under a grammar; a row under an adapter; a seeded row.
 # Drafts at their fixed length: every row's draft limit is the
-# dispatch's width, and a seeded stream is the same on every path
+# dispatch's width, and a seeded stream is the same in either order
 BYTES = ByteTokenizer()
 CFG_BYTES = dataclasses.replace(CFG, vocab_size=300)
 EOS_BYTES = dataclasses.replace(GREEDY, eos_token_id=BYTES.eos_id)
@@ -568,7 +571,7 @@ LOADED = [
 
 
 @pytest.mark.parametrize("spec_drafts", [0, 2], ids=["plain", "drafts"])
-def test_d_paths_agree_with_every_packed_field_in_play(spec_drafts):
+def test_d_orders_agree_with_every_packed_field_in_play(spec_drafts):
     params = transformer.init_params(CFG_BYTES, jax.random.key(0))
     lcfg = LoRAConfig(rank=4, alpha=8.0, targets=("wq", "wv"))
     lora = init_lora_params(CFG_BYTES, lcfg, jax.random.key(1))
@@ -579,10 +582,9 @@ def test_d_paths_agree_with_every_packed_field_in_play(spec_drafts):
     adapters = [None, None, "tuned", None]
 
     def run(**kw):
-        srv = PagedInferenceServer(
+        srv = _server(
             params, CFG_BYTES, EOS_BYTES, seed=len(kw), tokenizer=BYTES,
-            spec_drafts=spec_drafts, spec_control=False,
-            **{**SRV_KW, **kw})
+            spec_drafts=spec_drafts, spec_control=False, **kw)
         srv.add_adapter("tuned", lora, lcfg)
         rows = list(zip(PROMPTS, LOADED, adapters))
         reqs = [srv.submit(p, max_new_tokens=8, sampling=s, adapter=a)
@@ -594,7 +596,7 @@ def test_d_paths_agree_with_every_packed_field_in_play(spec_drafts):
         srv.run_until_idle()
         return [r.result() for r in reqs]
 
-    outs = _three_paths(run)
+    outs = _both_orders(run)
     digits = BYTES.decode(outs[1])
     assert digits.isdigit() and 4 <= len(digits) <= 6
     # the adapter's row is not the base model's
@@ -616,8 +618,7 @@ def test_e_a_launch_ahead_patches_from_the_planned_frame(params,
     say: `between_ms + duration_ms` over the window of steps that
     launched ahead is the window's length within 0.1%."""
     srv = PagedInferenceServer(
-        params, CFG, GREEDY, scheduler="mixed", overlap=True,
-        flight_recorder_size=512, decode_chunk=1, **SRV_KW)
+        params, CFG, GREEDY, flight_recorder_size=512, decode_chunk=1, **SRV_KW)
     patches = []
     pack = ps._pack_patch
     monkeypatch.setattr(ps, "_pack_patch", lambda *a: patches.append(

@@ -1,12 +1,14 @@
-"""Async double-buffered scheduler (ROADMAP item 4): exact-output
-parity overlap-on vs overlap-off, pipeline dispatch discipline, fault
+"""The pipelined step (plan, launch, commit): exact outputs whichever
+order a step takes, the fill, pipeline dispatch discipline, fault
 recovery with a dispatch in flight, deferred sweep reaps, the overlap
 observability fields, and the idle-spin bound.
 
-The load-bearing guarantee mirrors the mixed/alternating parity: the
-pipeline changes only WHEN host policy runs relative to the device,
-never what is computed — greedy and seeded outputs are token-for-token
-identical with the overlap on or off.
+The load-bearing guarantee: the pipeline changes only WHEN host policy
+runs relative to the device, never what is computed — greedy and seeded
+outputs are token-for-token identical whether a launch goes ahead of
+the commit before it or waits for it (`serial_order.waits`: the order
+drafts, a constrained row and a hand-off take in production), and
+greedy ones are the dense engine's.
 """
 
 import dataclasses
@@ -16,8 +18,11 @@ import jax
 import numpy as np
 import pytest
 
+from serial_order import waits
+
 from cloud_server_tpu.config import InferConfig, ModelConfig
-from cloud_server_tpu.inference.faults import FaultPlan
+from cloud_server_tpu.inference import engine
+from cloud_server_tpu.inference.faults import FaultPlan, InjectedFault
 from cloud_server_tpu.inference.paged_server import PagedInferenceServer
 from cloud_server_tpu.inference.sampling import SamplingParams
 from cloud_server_tpu.models import transformer
@@ -42,6 +47,20 @@ def params():
     return transformer.init_params(CFG, jax.random.key(0))
 
 
+def _server(params, icfg, ahead, **kw):
+    """The server as it is (`ahead`), or with every launch made to wait
+    for the commit before it."""
+    return waits(PagedInferenceServer(params, CFG, icfg, **kw), not ahead)
+
+
+def _engine_reference(params, prompt, n_new):
+    icfg = dataclasses.replace(GREEDY, max_decode_len=n_new)
+    toks = engine.generate(
+        params, np.asarray([prompt], np.int32), jax.random.key(1),
+        cfg=CFG, infer_cfg=icfg)
+    return list(np.asarray(toks)[0])
+
+
 def _staggered(srv, prompts, max_new, sampling=None):
     sp = sampling or [None] * len(prompts)
     reqs = [srv.submit(p, max_new_tokens=max_new, sampling=s)
@@ -55,33 +74,25 @@ def _staggered(srv, prompts, max_new, sampling=None):
 
 
 # ---------------------------------------------------------------------------
-# exact-output parity: overlap on == overlap off
+# exact-output parity: launched ahead == waiting for the commit == engine
 # ---------------------------------------------------------------------------
 
 
-def test_overlap_greedy_equals_sequential(params):
-    def run(ov):
-        srv = PagedInferenceServer(params, CFG, GREEDY, scheduler="mixed",
-                                   overlap=ov, **SRV_KW)
-        assert srv._overlap_enabled == ov
-        return _staggered(srv, PROMPTS, 8)
-
-    toks_on, lps_on = run(True)
-    toks_off, lps_off = run(False)
-    assert toks_on == toks_off
-    for a, b in zip(lps_on, lps_off):
-        assert np.allclose(a, b)
+def test_pipelined_greedy_equals_the_dense_engine(params):
+    srv = PagedInferenceServer(params, CFG, GREEDY, **SRV_KW)
+    toks, _ = _staggered(srv, PROMPTS, 8)
+    for p, o in zip(PROMPTS, toks):
+        assert o == _engine_reference(params, p, 8), p
 
 
-def test_overlap_seeded_sampling_equals_sequential(params):
+def test_seeded_sampling_equals_the_waiting_order(params):
     icfg = dataclasses.replace(GREEDY, temperature=1.0)
     sp = [SamplingParams(seed=100 + i, temperature=0.9, top_p=0.9,
                          presence_penalty=0.4)
           for i in range(len(PROMPTS))]
 
     def run(ov):
-        srv = PagedInferenceServer(params, CFG, icfg, scheduler="mixed",
-                                   overlap=ov, **SRV_KW)
+        srv = _server(params, icfg, ov, **SRV_KW)
         return _staggered(srv, PROMPTS, 10, sampling=sp)[0]
 
     assert run(True) == run(False)
@@ -89,15 +100,14 @@ def test_overlap_seeded_sampling_equals_sequential(params):
 
 def test_overlap_spec_greedy_parity(params):
     """n-gram speculation under the pipeline: the adaptive controller's
-    feedback lands one iteration later than sequentially (it reads the
-    commit), which may change DRAFT LENGTHS — but greedy outputs are
-    exact at any draft length schedule, so tokens must not move."""
-    def run(ov):
-        srv = PagedInferenceServer(params, CFG, GREEDY, scheduler="mixed",
-                                   overlap=ov, spec_drafts=2, **SRV_KW)
-        return _staggered(srv, [REP, REP, [5, 9, 3], REP], 10)[0]
-
-    assert run(True) == run(False)
+    feedback reads the commit, which may change DRAFT LENGTHS from one
+    schedule to another — but greedy outputs are exact at any draft
+    length schedule: they are the dense engine's."""
+    prompts = [REP, REP, [5, 9, 3], REP]
+    srv = PagedInferenceServer(params, CFG, GREEDY, spec_drafts=2,
+                               **SRV_KW)
+    for p, o in zip(prompts, _staggered(srv, prompts, 10)[0]):
+        assert o == _engine_reference(params, p, 10), p
 
 
 def test_overlap_penalties_and_grammarless_rows_parity(params):
@@ -107,8 +117,7 @@ def test_overlap_penalties_and_grammarless_rows_parity(params):
     icfg = dataclasses.replace(GREEDY, temperature=1.0)
 
     def run(ov):
-        srv = PagedInferenceServer(params, CFG, icfg, scheduler="mixed",
-                                   overlap=ov, **SRV_KW)
+        srv = _server(params, icfg, ov, **SRV_KW)
         r0 = srv.submit(PROMPTS[0], max_new_tokens=16,
                         sampling=SamplingParams(
                             seed=7, temperature=0.8,
@@ -125,27 +134,207 @@ def test_overlap_penalties_and_grammarless_rows_parity(params):
 
 
 def test_overlap_preemption_parity(params):
-    """On-demand paging under pool pressure: the overlap planner never
-    preempts mid-flight — it degrades and drains the pipeline so the
-    next sequential iteration runs the escalation — but preemption
-    still HAPPENS and outputs stay exact."""
+    """On-demand paging under pool pressure: a plan made under a
+    dispatch in flight never preempts — it degrades and the pipeline
+    drains, so the fill's plan runs the escalation — but preemption
+    still HAPPENS and outputs stay exact, whichever order the steps
+    take."""
     kw = dict(SRV_KW, max_slots=3, num_pages=14)
+    prompts = ([1, 2, 3], [4, 5, 6], list(range(1, 10)))
 
     def run(ov):
-        srv = PagedInferenceServer(params, CFG, GREEDY, scheduler="mixed",
-                                   overlap=ov, allocation="ondemand",
-                                   **kw)
-        reqs = [srv.submit(p, max_new_tokens=10)
-                for p in ([1, 2, 3], [4, 5, 6], list(range(1, 10)))]
+        srv = _server(params, GREEDY, ov, allocation="ondemand", **kw)
+        reqs = [srv.submit(p, max_new_tokens=10) for p in prompts]
         srv.run_until_idle()
         return [r.result() for r in reqs], srv.preemptions
 
     toks_on, pre_on = run(True)
     toks_off, pre_off = run(False)
     assert toks_on == toks_off
-    # same pool pressure: the pipeline may shift WHICH iteration
+    for p, o in zip(prompts, toks_on):
+        assert o == _engine_reference(params, list(p), 10), p
+    # same pool pressure: the order may shift WHICH iteration
     # preempts, not whether the workload needed it
     assert (pre_on > 0) == (pre_off > 0)
+
+
+def _releases(srv):
+    """Every page release of `srv` from now on, as (pages, whether a
+    dispatch was in flight or launched ahead at the time)."""
+    seen = []
+    release = srv.allocator.release
+
+    def noting(pages, *a, **k):
+        seen.append((list(pages), srv._inflight is not None
+                     or srv._ahead is not None))
+        return release(pages, *a, **k)
+
+    srv.allocator.release = noting
+    return seen
+
+
+# a pool too small for every chain: three rows of 3-token prompts and
+# 40 answer tokens want 6 pages each, the pool has 12
+FAMINE_KW = dict(SRV_KW, max_slots=3, num_pages=12, decode_chunk=1,
+                 allocation="ondemand")
+FAMINE_PROMPTS = ([1, 2, 3], [4, 5, 6], [7, 8, 9])
+
+
+def test_a_plan_with_nothing_in_flight_may_preempt(params):
+    """The pool runs dry under three growing chains: the plans made
+    under a dispatch in flight degrade and the pipeline drains, the
+    next plan (a fill, on the committed ledger) preempts the youngest,
+    and every output is the dense engine's."""
+    srv = PagedInferenceServer(params, CFG, GREEDY, **FAMINE_KW)
+    at = []   # was anything in flight when a slot was preempted?
+    preempt = srv._preempt_youngest
+
+    def noting(protect):
+        at.append(srv._inflight is not None)
+        return preempt(protect)
+
+    srv._preempt_youngest = noting
+    reqs = [srv.submit(p, max_new_tokens=40) for p in FAMINE_PROMPTS]
+    srv.run_until_idle()
+    assert srv.preemptions > 0 and at and not any(at)
+    for p, r in zip(FAMINE_PROMPTS, reqs):
+        assert r.result() == _engine_reference(params, list(p), 40), p
+    # the preemption is on the record of the fill step that made it
+    recs = srv.flight_window()
+    assert sum(r["preemptions"] for r in recs) == srv.preemptions
+    assert all(r.get("fill") for r in recs if r["preemptions"])
+    s = srv.allocator.stats()
+    assert s.pages_free + s.pages_cached == s.pages_total
+
+
+def test_a_plan_under_a_dispatch_in_flight_releases_no_page(params):
+    """The converse: in the same famine no page goes back while a plan
+    is being made or launched under a dispatch in flight. What is
+    released with a dispatch in flight is released by a commit (a row
+    that ended), never by `_plan_iteration` or `_launch_plan`."""
+    srv = PagedInferenceServer(params, CFG, GREEDY, **FAMINE_KW)
+    seen = _releases(srv)
+    planning = {"on": False}
+    plan, launch = srv._plan_iteration, srv._launch_plan
+
+    def guarded(f):
+        def g(*a):
+            planning["on"] = srv._inflight is not None
+            try:
+                return f(*a)
+            finally:
+                planning["on"] = False
+        return g
+
+    srv._plan_iteration, srv._launch_plan = guarded(plan), guarded(launch)
+    release = srv.allocator.release
+
+    def checked(pages, *a, **k):
+        assert not planning["on"], \
+            "a plan under a dispatch in flight released pages"
+        return release(pages, *a, **k)
+
+    srv.allocator.release = checked
+    reqs = [srv.submit(p, max_new_tokens=40) for p in FAMINE_PROMPTS]
+    srv.run_until_idle()
+    assert all(r.done for r in reqs) and srv.preemptions > 0
+    assert seen, "nothing was released at all"
+
+
+# ---------------------------------------------------------------------------
+# the fill: a plan, launched
+# ---------------------------------------------------------------------------
+
+
+def _counting(monkeypatch):
+    """Count program launches and device_get calls from now on."""
+    from cloud_server_tpu.inference import paged_server as ps
+    calls = {"dispatch": 0, "get": 0}
+    orig_get = jax.device_get
+
+    def wrap(f):
+        def w(*a, **k):
+            calls["dispatch"] += 1
+            return f(*a, **k)
+        return w
+
+    for n in ("_mixed_step", "_decode_rounds", "_spec_rounds"):
+        monkeypatch.setattr(ps, n, wrap(getattr(ps, n)))
+    monkeypatch.setattr(jax, "device_get",
+                        lambda x: (calls.__setitem__(
+                            "get", calls["get"] + 1), orig_get(x))[1])
+    return calls
+
+
+@pytest.mark.parametrize("kind", ["mixed", "decode", "drafts"])
+def test_fill_is_a_plan_launched(params, monkeypatch, kind):
+    """After a step with nothing in flight one dispatch is in flight,
+    nothing is committed and nothing was read back; the next step
+    commits it; the streams are the dense engine's. A mixed fill (a
+    cold start), a decode-only fill (the pipeline drained under live
+    rows, as a page famine leaves it) and one whose rows draft."""
+    kw = dict(SRV_KW, decode_chunk=2,
+              spec_drafts=2 if kind == "drafts" else 0)
+    srv = PagedInferenceServer(params, CFG, GREEDY, **kw)
+    prompts = [REP, [5, 9, 3]]
+    reqs = [srv.submit(p, max_new_tokens=24) for p in prompts]
+    if kind != "mixed":
+        # live rows and nothing in flight: commit what is in flight
+        # without launching behind it, as a drained pipeline leaves it
+        while not all(r.tokens for r in reqs):
+            srv.step()
+        with srv._step_lock:
+            srv._commit_inflight()
+            srv._deliver()
+        assert srv._inflight is None and srv.active.sum() == 2
+    calls = _counting(monkeypatch)
+    emitted = [len(r.tokens) for r in reqs]
+    n_rec = len(srv.flight_window())
+    srv.step()
+    assert calls == {"dispatch": 1, "get": 0}
+    infl = srv._inflight
+    assert infl is not None and srv._ahead is None
+    assert infl.kind == ("mixed" if kind == "mixed" else "decode")
+    assert (infl.g_iter > 0) == (kind == "drafts")
+    assert infl.stats["launch_waits"] == "fill"
+    assert [len(r.tokens) for r in reqs] == emitted
+    rec, = srv.flight_window()[n_rec:]
+    assert rec["fill"] and "overlap" not in rec and "n_live" not in rec
+    srv.step()
+    assert calls["get"] == 1 and calls["dispatch"] == 2
+    if kind != "mixed":
+        assert all(len(r.tokens) > n for r, n in zip(reqs, emitted))
+    committed = srv.flight_window()[-1]
+    assert committed["overlap"] and committed["launch_waits"] == "fill"
+    assert ("spec_rows" in committed) == (kind == "drafts")
+    monkeypatch.undo()
+    srv.run_until_idle()
+    for p, r in zip(prompts, reqs):
+        assert r.result() == _engine_reference(params, p, 24), p
+
+
+def test_one_fault_hit_a_step_through_a_fill(params):
+    """`FaultPlan`'s "dispatch" site is hit once in every step that has
+    something to dispatch, the fill included, and in no idle one: a
+    fault armed after two hits fires in the third busy step, with the
+    fill's dispatch and its successor behind it."""
+    fp = FaultPlan()
+    srv = PagedInferenceServer(params, CFG, GREEDY, faults=fp,
+                               **dict(SRV_KW, decode_chunk=1))
+    srv.step()                      # idle: no hit
+    fp.arm("dispatch", after=2, count=1)
+    req = srv.submit([5, 9, 3], max_new_tokens=20)
+    srv.step()                      # the fill: hit 1
+    assert srv._inflight is not None and not req.tokens
+    srv.step()                      # hit 2
+    n = len(req.tokens)
+    assert n >= 1
+    with pytest.raises(InjectedFault):
+        srv.step()                  # hit 3 fires, before the plan
+    assert len(req.tokens) == n and srv._inflight is not None
+    assert fp.stats()["fired"].get("dispatch") == 1
+    srv.run_until_idle()            # the fault is spent: the stream ends
+    assert req.result() == _engine_reference(params, [5, 9, 3], 20)
 
 
 # ---------------------------------------------------------------------------
@@ -154,34 +343,14 @@ def test_overlap_preemption_parity(params):
 
 
 def test_overlap_dispatch_and_sync_count(params, monkeypatch):
-    """Steady-state pipelined steps issue exactly ONE fused dispatch
-    (either kind) and ONE device_get; the pipeline-FILL step is the
-    documented exception — it completes its own iteration
-    synchronously AND primes the launch-ahead (two dispatches, one
-    sync), so per-step emission counts match the sequential loop."""
-    from cloud_server_tpu.inference import paged_server as ps
-    srv = PagedInferenceServer(params, CFG, GREEDY, scheduler="mixed",
-                               overlap=True, **SRV_KW)
-    calls = {"dispatch": 0, "get": 0}
-    origs = {n: getattr(ps, n) for n in
-             ("_mixed_step", "_decode_rounds", "_spec_rounds")}
-    orig_get = jax.device_get
-
-    def wrap(name):
-        def w(*a, **k):
-            calls["dispatch"] += 1
-            return origs[name](*a, **k)
-        return w
-
-    for n in origs:
-        monkeypatch.setattr(ps, n, wrap(n))
-    monkeypatch.setattr(jax, "device_get",
-                        lambda x: (calls.__setitem__(
-                            "get", calls["get"] + 1), orig_get(x))[1])
-
+    """Every busy step issues exactly ONE fused dispatch (either kind);
+    every step but the fill pays ONE device_get, and the fill none: it
+    launches and commits nothing."""
+    srv = PagedInferenceServer(params, CFG, GREEDY, **SRV_KW)
+    calls = _counting(monkeypatch)
     warm = srv.submit([5, 9, 3, 1], max_new_tokens=24)
-    srv.step()  # FILL: sequential iteration + pipeline prime
-    assert calls == {"dispatch": 2, "get": 1}
+    srv.step()  # FILL: a plan, launched
+    assert calls == {"dispatch": 1, "get": 0}
     assert srv._inflight is not None
     long = srv.submit(LONG, max_new_tokens=4)
     steps = 0
@@ -193,40 +362,9 @@ def test_overlap_dispatch_and_sync_count(params, monkeypatch):
         assert calls["get"] - before["get"] == 1
         assert steps < 50
     assert steps >= 2
-    for n, f in origs.items():
-        monkeypatch.setattr(ps, n, f)
-    monkeypatch.setattr(jax, "device_get", orig_get)
+    monkeypatch.undo()
     srv.run_until_idle()
     assert warm.done and long.done
-
-
-def test_overlap_off_is_sequential(params):
-    """overlap=False: nothing is ever left in flight across steps and
-    the records carry no overlap fields — the byte-identical
-    sequential loop."""
-    srv = PagedInferenceServer(params, CFG, GREEDY, scheduler="mixed",
-                               overlap=False, **SRV_KW)
-    assert not srv._overlap_enabled
-    srv.submit(LONG, max_new_tokens=6)
-    while srv.num_pending or srv.num_active or srv._jobs:
-        srv.step()
-        assert srv._inflight is None
-    for rec in srv.flight_window():
-        assert "overlap" not in rec
-        assert "launch" not in rec.get("phases_ms", {})
-        assert "t_launch" not in rec
-
-
-def test_overlap_requires_mixed_scheduler(params):
-    """The alternating scheduler keeps its sequential per-chunk loop
-    regardless of the knob (overlap applies to the fused dispatch)."""
-    srv = PagedInferenceServer(params, CFG, GREEDY,
-                               scheduler="alternating", overlap=True,
-                               **SRV_KW)
-    assert srv.overlap and not srv._overlap_enabled
-    srv.submit(PROMPTS[0], max_new_tokens=4)
-    srv.run_until_idle()
-    assert srv._inflight is None
 
 
 # ---------------------------------------------------------------------------
@@ -236,14 +374,13 @@ def test_overlap_requires_mixed_scheduler(params):
 
 def test_overlap_cancel_inflight_defers_release(params):
     """A cancel landing while the victim's rows are mid-flight is
-    MARKED by the overlap sweep (active=False) and released right
+    MARKED by the sweep (active=False) and released right
     after the commit — never under the running dispatch — and the
     allocator's page accounting balances afterwards."""
-    srv = PagedInferenceServer(params, CFG, GREEDY, scheduler="mixed",
-                               overlap=True, **SRV_KW)
+    srv = PagedInferenceServer(params, CFG, GREEDY, **SRV_KW)
     victim = srv.submit([5, 9, 3], max_new_tokens=30)
     other = srv.submit([7, 2, 4], max_new_tokens=6)
-    srv.step()          # fill + prime: a decode dispatch is in flight
+    srv.step()          # the fill: a dispatch is in flight
     assert srv._inflight is not None
     victim.cancel()
     srv.step()          # sweep marks; commit; deferred release applies
@@ -257,8 +394,7 @@ def test_overlap_cancel_inflight_defers_release(params):
 def test_overlap_deadline_expires_active_under_pipeline(params):
     # decode_chunk=1: one token per iteration, so the deadline
     # reliably expires MID-decode with a dispatch in flight
-    srv = PagedInferenceServer(params, CFG, GREEDY, scheduler="mixed",
-                               overlap=True, decode_chunk=1, **SRV_KW)
+    srv = PagedInferenceServer(params, CFG, GREEDY, decode_chunk=1, **SRV_KW)
     doomed = srv.submit([5, 9, 3], max_new_tokens=50, deadline_s=0.2)
     srv.step()
     deadline = time.perf_counter() + 30
@@ -281,8 +417,7 @@ def test_overlap_dispatch_fault_fails_all_and_drops_inflight(params):
     must drop the in-flight futures, unblock every waiter, and keep
     gap-free traces for the failed requests."""
     fp = FaultPlan()
-    srv = PagedInferenceServer(params, CFG, GREEDY, scheduler="mixed",
-                               overlap=True, tracing=1.0,
+    srv = PagedInferenceServer(params, CFG, GREEDY, tracing=1.0,
                                **SRV_KW).start()
     try:
         ok = srv.submit([5, 9, 3], max_new_tokens=4)
@@ -306,8 +441,7 @@ def test_overlap_wedge_teardown_counter(params):
     pipeline: _fail_all's bounded acquire times out against a held
     step lock, teardown proceeds, the event is counted, and the
     in-flight dispatch is dropped."""
-    srv = PagedInferenceServer(params, CFG, GREEDY, scheduler="mixed",
-                               overlap=True, **SRV_KW)
+    srv = PagedInferenceServer(params, CFG, GREEDY, **SRV_KW)
     req = srv.submit([5, 9, 3], max_new_tokens=8)
     srv.step()
     assert srv._inflight is not None
@@ -328,10 +462,8 @@ def test_overlap_wedge_teardown_counter(params):
 
 
 def test_overlap_flight_fields_and_stats_block(params):
-    srv = PagedInferenceServer(params, CFG, GREEDY, scheduler="mixed",
-                               overlap=True, **SRV_KW)
-    assert srv.overlap_stats() == {"enabled": True, "active": True,
-                                   "inflight_depth": 0}
+    srv = PagedInferenceServer(params, CFG, GREEDY, **SRV_KW)
+    assert srv.overlap_stats() == {"inflight_depth": 0}
     first = [srv.submit([5 + i, 9, 3], max_new_tokens=8)
              for i in range(2)]
     srv.step()
@@ -382,8 +514,7 @@ def _watched(srv, prompt, max_new, sampling=None):
 def test_overlap_delivers_after_the_launch(params):
     """In a step that commits and launches, every stream callback and
     every `_done.set()` runs with the next program already in flight."""
-    srv = PagedInferenceServer(params, CFG, GREEDY, scheduler="mixed",
-                               overlap=True, **SRV_KW)
+    srv = PagedInferenceServer(params, CFG, GREEDY, **SRV_KW)
     step = {"n": 0, "steady": False, "launched": -1}
     seen = []   # (kind, steady step, in flight, launched in this step)
     launch = srv._launch_plan
@@ -422,15 +553,15 @@ def test_overlap_delivers_after_the_launch(params):
 
 
 @pytest.mark.parametrize("spec_drafts", [0, 2])
-def test_delivery_order_equals_sequential(params, spec_drafts):
+def test_delivery_order_equals_the_waiting_order(params, spec_drafts):
     """What each client is woken with, and in which order, does not
-    depend on when it is woken: overlap on and off give the same
+    depend on when it is woken: launches that go ahead of the commit
+    and launches that wait for it give the same
     stream calls and the completion last, for a length finish and a
     stop sequence, plain and speculative."""
     kw = dict(SRV_KW, spec_drafts=spec_drafts)
     prompts = [REP, [5, 9, 3], REP[:9], [17, 2, 40, 8, 21]]
-    probe = PagedInferenceServer(params, CFG, GREEDY, scheduler="mixed",
-                                 overlap=False, **kw)
+    probe = PagedInferenceServer(params, CFG, GREEDY, **kw)
     free = [probe.submit(p, max_new_tokens=12) for p in prompts]
     probe.run_until_idle()
     # a two-token stop sequence the second and third requests will meet
@@ -438,8 +569,7 @@ def test_delivery_order_equals_sequential(params, spec_drafts):
              None]
 
     def run(ov):
-        srv = PagedInferenceServer(params, CFG, GREEDY, scheduler="mixed",
-                                   overlap=ov, **kw)
+        srv = _server(params, GREEDY, ov, **kw)
         pairs = [_watched(srv, p, 12, None if st is None
                           else SamplingParams(stop=[st]))
                  for p, st in zip(prompts[:2], stops[:2])]
@@ -470,8 +600,7 @@ def test_launch_failure_delivers_committed_tokens_first(params):
     """A launch that raises after the commit: the tokens the commit
     recorded reach their streams before the error completion, as
     they did when the commit itself woke the clients."""
-    srv = PagedInferenceServer(params, CFG, GREEDY, scheduler="mixed",
-                               overlap=True, **SRV_KW)
+    srv = PagedInferenceServer(params, CFG, GREEDY, **SRV_KW)
     pairs = [_watched(srv, p, 48) for p in ([5, 9, 3], [17, 2, 40, 8, 21])]
     while min(len(r.tokens) for r, _ in pairs) < 3:
         srv.step()
@@ -501,8 +630,7 @@ def test_raising_stream_callback_strands_no_completion(params):
     when the commit called it; the completion queued behind it on the
     delivery list still runs: that request has no slot any more, so
     `_fail_all` could not find it and its waiter would hang."""
-    srv = PagedInferenceServer(params, CFG, GREEDY, scheduler="mixed",
-                               overlap=True, **SRV_KW)
+    srv = PagedInferenceServer(params, CFG, GREEDY, **SRV_KW)
 
     def bad_stream(token):
         if len(bad.tokens) >= 6:
@@ -546,25 +674,25 @@ AHEAD_SAMPLING = {
 
 
 @pytest.mark.parametrize("kind", list(AHEAD_SAMPLING))
-def test_launched_ahead_equals_sequential(params, kind):
+def test_launched_ahead_equals_the_waiting_order(params, kind):
     """Token for token and log-probability for log-probability what the
-    sequential scheduler gives, with nearly every dispatch on the
-    device's queue before the commit of the one before it."""
+    steps give where every launch waits for the commit before it, with
+    nearly every dispatch on the device's queue before that commit."""
     icfg, sampling = AHEAD_SAMPLING[kind]
 
     def run(ov):
-        srv = PagedInferenceServer(params, CFG, icfg, scheduler="mixed",
-                                   overlap=ov, **AHEAD_KW)
+        srv = _server(params, icfg, ov, **AHEAD_KW)
         return srv, _staggered(srv, PROMPTS, 24, sampling=sampling)
 
     srv, (toks_on, lps_on) = run(True)
-    _, (toks_off, lps_off) = run(False)
+    off, (toks_off, lps_off) = run(False)
     assert toks_on == toks_off
     for a, b in zip(lps_on, lps_off):
         assert np.allclose(a, b)
+    assert not any(r["launch_ahead"] for r in _overlapped(off))
     ov = _overlapped(srv)
     ahead = [r for r in ov if r["launch_ahead"]]
-    # the dispatches that waited primed the pipeline, nothing else
+    # the dispatches that waited filled the pipeline, nothing else
     assert {r["launch_waits"] for r in ov if not r["launch_ahead"]} \
         <= {"fill"}
     assert len(ahead) >= 20 and len(ahead) > 0.8 * len(ov)
@@ -578,8 +706,7 @@ def _eos_config(params):
     """A token the greedy stream of PROMPTS[0] reaches at its fifth
     place and not before: as the end token, that request ends by it
     after five tokens while the others decode on."""
-    probe = PagedInferenceServer(params, CFG, GREEDY, scheduler="mixed",
-                                 overlap=False, **AHEAD_KW)
+    probe = PagedInferenceServer(params, CFG, GREEDY, **AHEAD_KW)
     toks = probe.generate([PROMPTS[0]], max_new_tokens=12)[0]
     k = next(i for i in range(3, len(toks)) if toks[i] not in toks[:i])
     return dataclasses.replace(GREEDY, eos_token_id=int(toks[k])), k
@@ -591,8 +718,7 @@ def test_row_ending_by_end_token_is_computed_and_discarded(params):
     results are thrown away at commit n+1, and once released its pages
     are written by nothing launched after the release."""
     icfg, k = _eos_config(params)
-    srv = PagedInferenceServer(params, CFG, icfg, scheduler="mixed",
-                               overlap=True, **AHEAD_KW)
+    srv = PagedInferenceServer(params, CFG, icfg, **AHEAD_KW)
     short = srv.submit(PROMPTS[0], max_new_tokens=40)
     other = srv.submit(PROMPTS[1], max_new_tokens=40)
     sid, pages = None, None
@@ -633,14 +759,13 @@ def test_next_occupant_of_an_ended_rows_slot_sees_none_of_it(params):
     """Six requests on four slots, two of them ending by the end token
     under a launched-ahead dispatch: the requests that take their slots
     (and whatever the device's per-slot state kept of the dead rows)
-    give the sequential scheduler's tokens."""
+    give the tokens of the order in which every launch waits."""
     icfg, _ = _eos_config(params)
     prompts = [PROMPTS[0], PROMPTS[1], PROMPTS[0], LONG, PROMPTS[3],
                [9, 8, 7, 6]]
 
     def run(ov):
-        srv = PagedInferenceServer(params, CFG, icfg, scheduler="mixed",
-                                   overlap=ov, **AHEAD_KW)
+        srv = _server(params, icfg, ov, **AHEAD_KW)
         reqs = [srv.submit(p, max_new_tokens=16) for p in prompts]
         srv.run_until_idle()
         return srv, [(r.result(), r.finish_reason) for r in reqs]
@@ -657,11 +782,8 @@ def test_cancel_and_deadline_between_launch_and_commit(params, how):
     went onto the queue with its row live and before commit n: commit n
     still records its tokens, the next sweep marks it, n+1's results for
     it are dropped, and its pages go back once, after commit n+1."""
-    ref = PagedInferenceServer(params, CFG, GREEDY, scheduler="mixed",
-                               overlap=False, **AHEAD_KW)
-    want = ref.generate([PROMPTS[1]], max_new_tokens=12)[0]
-    srv = PagedInferenceServer(params, CFG, GREEDY, scheduler="mixed",
-                               overlap=True, **AHEAD_KW)
+    want = _engine_reference(params, PROMPTS[1], 12)
+    srv = PagedInferenceServer(params, CFG, GREEDY, **AHEAD_KW)
     victim = srv.submit(PROMPTS[0], max_new_tokens=40)
     other = srv.submit(PROMPTS[1], max_new_tokens=12)
     while len(victim.tokens) < 3:
@@ -698,8 +820,7 @@ def test_launch_ahead_that_raises_commits_and_delivers_first(params):
     order: the launch that raises comes BEFORE the commit, and the step
     still commits the dispatch in flight and hands its tokens over
     before the error leaves it."""
-    srv = PagedInferenceServer(params, CFG, GREEDY, scheduler="mixed",
-                               overlap=True, **AHEAD_KW)
+    srv = PagedInferenceServer(params, CFG, GREEDY, **AHEAD_KW)
     pairs = [_watched(srv, p, 48) for p in ([5, 9, 3], [17, 2, 40, 8, 21])]
     while min(len(r.tokens) for r, _ in pairs) < 3:
         srv.step()
@@ -730,15 +851,14 @@ def test_launch_ahead_that_raises_commits_and_delivers_first(params):
 def test_the_old_order_where_the_launch_needs_the_commit(params, why):
     """Draft tokens in play, or an admission that completes with a
     hand-off to prefetch: that dispatch waits for the commit before it,
-    its record says so and why, and the tokens are the sequential
-    scheduler's. (A constrained row: tests/test_grammar.py, which has
+    its record says so and why, and the tokens are those of the order
+    in which every launch waits. (A constrained row: tests/test_grammar.py, which has
     the tokenizer.)"""
     kw = dict(AHEAD_KW, spec_drafts=2 if why == "drafts" else 0)
     handed = []
 
     def run(ov):
-        srv = PagedInferenceServer(params, CFG, GREEDY, scheduler="mixed",
-                                   overlap=ov, **kw)
+        srv = _server(params, GREEDY, ov, **kw)
         first = srv.submit(REP, max_new_tokens=16)
         for _ in range(3):
             srv.step()
@@ -757,7 +877,9 @@ def test_the_old_order_where_the_launch_needs_the_commit(params, why):
         assert r["launch_ahead"] == ("launch_waits" not in r)
     if why == "drafts":
         # a server whose rows draft never launches ahead while they do
-        assert set(waits) <= {"fill", "drafts"}
+        # (a chunk of a cold start's prompt, with no row live yet, may)
+        assert set(waits) <= {"fill", "drafts", None}
+        assert not any(r["launch_ahead"] for r in ov if r.get("spec_rows"))
     else:
         # the one dispatch that completed the admission waited; the
         # decode steps around it went ahead; the callback fired once
@@ -767,11 +889,10 @@ def test_the_old_order_where_the_launch_needs_the_commit(params, why):
 
 def test_launch_ahead_share_and_the_records_flag(params):
     """`/stats`' overlap block gives the share of the window's
-    overlapped dispatches that were launched ahead, from the flag each
-    of their records carries; records of sequential iterations carry
-    neither the flag nor a reason."""
-    srv = PagedInferenceServer(params, CFG, GREEDY, scheduler="mixed",
-                               overlap=True, **AHEAD_KW)
+    dispatches that were launched ahead, from the flag each of their
+    records carries; a fill step's own record carries neither the flag
+    nor a reason."""
+    srv = PagedInferenceServer(params, CFG, GREEDY, **AHEAD_KW)
     assert "launch_ahead_share" not in srv.overlap_stats()
     reqs = [srv.submit(p, max_new_tokens=20) for p in PROMPTS[:2]]
     srv.run_until_idle()
@@ -788,11 +909,9 @@ def test_launch_ahead_share_and_the_records_flag(params):
     assert stats["launch_ahead_share"] == pytest.approx(
         100.0 * sum(flags) / len(flags))
     assert 80.0 < stats["launch_ahead_share"] < 100.0  # the fill waited
-    off = PagedInferenceServer(params, CFG, GREEDY, scheduler="mixed",
-                               overlap=False, **AHEAD_KW)
+    off = waits(PagedInferenceServer(params, CFG, GREEDY, **AHEAD_KW))
     off.generate([PROMPTS[0]], max_new_tokens=4)
-    assert "launch_ahead_share" not in off.overlap_stats()
-    assert all("launch_ahead" not in r for r in off.flight_window())
+    assert off.overlap_stats()["launch_ahead_share"] == 0.0
 
 
 # ---------------------------------------------------------------------------

@@ -491,23 +491,34 @@ def test_eviction_under_churn(params):
     assert srv.allocator.evictions > 0
 
 
-def test_admit_decode_chunk_bounds_rounds(params):
-    """While an admission job is in flight, decode dispatches shrink to
-    admit_decode_chunk rounds (TTFT bound); full decode_chunk resumes
-    once admissions drain. None disables the shrink."""
+def test_admit_job_holds_one_slot(params):
+    """An admission job is one slot's: scalars for its slot, lengths and
+    captured sample, rows for its tokens, and a cursor that advances by
+    whatever width the budget granted its chunk, so two admissions of
+    one burst progress, and end, each on its own."""
     long_prompt = list(range(1, 29))  # 2 chunks at prefill_chunk=16
-    for knob, during in ((1, 1), (2, 2), (None, 8)):
-        srv = PagedInferenceServer(params, CFG, GREEDY, decode_chunk=8,
-                                   admit_decode_chunk=knob, **SRV_KW)
-        # budget large enough that remaining-tokens never bounds the
-        # dispatch below decode_chunk during this test
-        r0 = srv.submit(PROMPTS[0], max_new_tokens=40)
-        while not srv.active.any():
-            srv.step()
-        assert not srv._jobs and srv._chunk_rounds() == 8
-        srv.submit(long_prompt, max_new_tokens=8)
-        srv.step()  # admission job started
-        assert srv._jobs
-        assert srv._chunk_rounds() == during, knob
-        srv.run_until_idle()
-        assert len(r0.result()) == 40
+    srv = PagedInferenceServer(params, CFG, GREEDY, **SRV_KW)
+    reqs = [srv.submit(p, max_new_tokens=6)
+            for p in (PROMPTS[0], long_prompt)]
+    srv.step()
+    short, long = sorted(srv._jobs, key=lambda j: j.rem_len)
+    for job, prompt in ((short, PROMPTS[0]), (long, long_prompt)):
+        assert isinstance(job.slot, int) and srv._slots[job.slot] is not None
+        assert (job.rem_len, job.base_len, job.prompt_len) == (
+            len(prompt), 0, len(prompt))
+        assert job.rows.shape == job.prompt_row.shape == (len(prompt),)
+        assert list(job.rows) == prompt
+        assert (job.got, job.done) == (False, 0)
+    assert not hasattr(short, "slots") and short.slot != long.slot
+    # the fill's plan took a chunk of each; nothing is committed yet
+    assert (short.planned, long.planned) == (len(PROMPTS[0]), 16)
+    srv.step()
+    # the short one is admitted and gone, the long one is half way
+    assert srv._jobs == [long] and (long.done, long.planned) == (16, 28)
+    assert srv.active[short.slot] and not long.got
+    srv.step()
+    assert not srv._jobs and long.got and long.done == 28
+    assert long.tok == reqs[1].tokens[0]
+    srv.run_until_idle()
+    for r, p in zip(reqs, (PROMPTS[0], long_prompt)):
+        assert r.result() == _engine_reference(params, p, 6)

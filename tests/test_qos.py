@@ -313,10 +313,8 @@ def test_single_tenant_parity_token_for_token(params):
     not change ONE token of the mixed scheduler's output — DRR over a
     single tenant IS FIFO, and weighted-fair prefill funding over one
     tenant IS the FIFO job order."""
-    plain = PagedInferenceServer(params, CFG, GREEDY, scheduler="mixed",
-                                 **PAGED_KW)
-    qosd = PagedInferenceServer(params, CFG, GREEDY, scheduler="mixed",
-                                qos={"default": {"weight": 2.0}},
+    plain = PagedInferenceServer(params, CFG, GREEDY, **PAGED_KW)
+    qosd = PagedInferenceServer(params, CFG, GREEDY, qos={"default": {"weight": 2.0}},
                                 **PAGED_KW)
     out_p = _staggered_run(plain, PROMPTS, 12)
     out_q = _staggered_run(qosd, PROMPTS, 12)
@@ -329,8 +327,7 @@ def test_fairness_converges_to_weight_ratio(params):
     identical floods; per-tenant generated-token counts converge to
     ~3:1 while both backlogs last."""
     srv = PagedInferenceServer(
-        params, CFG, GREEDY, scheduler="mixed",
-        qos={"quantum": 1, "tenants": {"a": {"weight": 3.0},
+        params, CFG, GREEDY, qos={"quantum": 1, "tenants": {"a": {"weight": 3.0},
                                        "b": {"weight": 1.0}}},
         **{**PAGED_KW, "max_slots": 2})
     reqs = []
@@ -359,8 +356,7 @@ def test_starvation_free_best_effort_under_interactive_flood(params):
     tenant floods: its admissions interleave into the flood (bounded
     queue-wait) instead of waiting for the flood to drain."""
     srv = PagedInferenceServer(
-        params, CFG, GREEDY, scheduler="mixed",
-        qos={"quantum": 1,
+        params, CFG, GREEDY, qos={"quantum": 1,
              "tenants": {"fg": {"weight": 8.0, "priority": "interactive"},
                          "bg": {"weight": 1.0,
                                 "priority": "best_effort"}}},
@@ -385,7 +381,7 @@ def test_preemption_victim_order_prefers_best_effort(params):
     preemption would never evict first — is chosen when it belongs to
     the best-effort tenant."""
     srv = PagedInferenceServer(
-        params, CFG, GREEDY, scheduler="mixed", allocation="ondemand",
+        params, CFG, GREEDY, allocation="ondemand",
         max_slots=4, max_context=64, page_size=8, prefill_chunk=16,
         prompt_buckets=[16], num_pages=32, decode_chunk=1,
         qos={"tenants": {"bg": {"priority": "best_effort"},
@@ -422,7 +418,7 @@ def test_preemption_under_qos_keeps_outputs_exact(params):
     into the flight recorder and per-tenant counters."""
     prompts = [[(i * 9 + k) % 60 + 1 for k in range(8)] for i in range(6)]
     srv = PagedInferenceServer(
-        params, CFG, GREEDY, scheduler="mixed", allocation="ondemand",
+        params, CFG, GREEDY, allocation="ondemand",
         max_slots=6, max_context=64, page_size=8, prefill_chunk=16,
         prompt_buckets=[16], num_pages=12, decode_chunk=2,
         qos={"tenants": {"bg": {"priority": "best_effort"},
@@ -454,8 +450,7 @@ def test_mixed_step_dispatch_count_with_qos(params, monkeypatch):
     observability PR pinned for the unconfigured server)."""
     from cloud_server_tpu.inference import paged_server as ps
     srv = PagedInferenceServer(
-        params, CFG, GREEDY, scheduler="mixed",
-        qos={"tenants": {"a": {"weight": 3.0}, "b": {"weight": 1.0}}},
+        params, CFG, GREEDY, qos={"tenants": {"a": {"weight": 3.0}, "b": {"weight": 1.0}}},
         **PAGED_KW)
     warm = srv.submit([5, 9, 3, 1], max_new_tokens=24, tenant="a")
     srv.step()

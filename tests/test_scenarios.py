@@ -559,8 +559,7 @@ def test_replay_driven_step_dispatch_and_sync_count(params, monkeypatch):
     (the test_observability guard's invariant, with the replay driver
     in the loop)."""
     from cloud_server_tpu.inference import paged_server as ps
-    srv = PagedInferenceServer(params, CFG, GREEDY, scheduler="mixed",
-                               **PAGED_KW)
+    srv = PagedInferenceServer(params, CFG, GREEDY, **PAGED_KW)
     warm = srv.submit([5, 9, 3, 1], max_new_tokens=40)
     srv.step()  # a warm decode runs while the replay fires events
     assert srv.num_active == 1

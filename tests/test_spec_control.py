@@ -155,10 +155,10 @@ def test_mixed_draft_spec_adaptive_dispatch_and_sync_count(
     from cloud_server_tpu.inference import paged_server as ps
     draft_params, draft_cfg = _draft_setup()
     srv = PagedInferenceServer(
-        params, CFG, GREEDY, scheduler="mixed", spec_drafts=2,
+        params, CFG, GREEDY, spec_drafts=2,
         draft_params=draft_params, draft_cfg=draft_cfg,
         spec_control={"cooldown": 1, "ewma": 0.5}, **SRV_KW)
-    assert srv._mixed_enabled and srv.spec_control is not None
+    assert srv.spec_control is not None
     warm = srv.submit([5, 9, 3, 1], max_new_tokens=24)
     srv.step()
     assert srv.num_active == 1
@@ -216,8 +216,7 @@ def test_mixed_draft_spec_adaptive_dispatch_and_sync_count(
 
 
 def test_flight_recorder_and_metrics_record_speculation(params):
-    srv = PagedInferenceServer(params, CFG, GREEDY, scheduler="mixed",
-                               spec_drafts=3, **SRV_KW)
+    srv = PagedInferenceServer(params, CFG, GREEDY, spec_drafts=3, **SRV_KW)
     rep = [3, 4, 5, 6] * 5
     srv.generate([rep, [7, 8, 9]], max_new_tokens=10)
     recs = [r for r in srv.flight_window() if r.get("spec_rows")]
@@ -241,8 +240,7 @@ def test_qos_wasted_speculation_ledger(params):
     """Committed tokens bill the generated bucket; rejected draft work
     lands on the per-tenant wasted-speculation counter only."""
     reg = TenantRegistry({"tenants": {"a": {"weight": 2.0}}})
-    srv = PagedInferenceServer(params, CFG, GREEDY, scheduler="mixed",
-                               spec_drafts=3, qos=reg, **SRV_KW)
+    srv = PagedInferenceServer(params, CFG, GREEDY, spec_drafts=3, qos=reg, **SRV_KW)
     r = srv.submit([3, 4, 5, 6] * 5, max_new_tokens=10, tenant="a")
     srv.run_until_idle()
     s = reg.stats()["a"]
@@ -258,8 +256,7 @@ def test_router_merges_speculation_stats(params):
     """Fleet /stats `speculation`: counts sum across replicas and the
     accept-rate ratio recomputes from the merged totals (never a sum
     of per-replica ratios), like tenant_fair_share."""
-    reps = [PagedInferenceServer(params, CFG, GREEDY, scheduler="mixed",
-                                 spec_drafts=2, **SRV_KW)
+    reps = [PagedInferenceServer(params, CFG, GREEDY, spec_drafts=2, **SRV_KW)
             for _ in range(2)]
     router = ReplicatedRouter(reps)
     for rep in reps:  # drive each replica directly so both have counts

@@ -5,6 +5,7 @@ flight record's index and adding up to its `duration_ms`; the program's
 own capture keeps the Python tracer off; the step programs' ops carry
 the scope names a device trace tells them apart by."""
 import glob
+import itertools
 import os
 
 import jax
@@ -57,8 +58,7 @@ def traced_churn(tmp_path, **server_kw):
     """A few warm steps untraced, then a churn (two decoding rows and a
     long prompt admitted in chunks) inside the program's own capture."""
     params = transformer.init_params(CFG, jax.random.key(0))
-    srv = PagedInferenceServer(params, CFG, GREEDY, scheduler="mixed",
-                               **PAGED_KW, **server_kw)
+    srv = PagedInferenceServer(params, CFG, GREEDY, **PAGED_KW, **server_kw)
     warm = [srv.submit([5 + i, 9, 3], max_new_tokens=8) for i in range(2)]
     srv.step()
     srv.step()
@@ -86,21 +86,25 @@ def test_every_busy_iteration_is_events_on_the_profilers_clock(tmp_path):
         inside = [e for e in events if e[0] != "sched/iteration"
                   and e[3].get("iteration") == rec["iteration"]
                   and t0 <= e[1] and e[1] + e[2] <= t0 + dur]
+        inside.sort(key=lambda e: e[1])
         names = [e[0][len("sched/"):] for e in inside]
         assert set(names) <= set(PHASES)
         # the phases the record crossed, and no other
         assert set(names) == set(rec["phases_ms"])
-        by_phase = {}
-        for name, e in zip(names, inside):
-            by_phase[name] = by_phase.get(name, 0.0) + e[2] / 1e6
-        # same boundaries, two clocks: event by event within 2% of the
-        # iteration, and the sum within 2% of `duration_ms`
-        total = sum(by_phase.values())
-        assert total == pytest.approx(rec["duration_ms"], rel=0.02)
-        assert dur / 1e6 == pytest.approx(rec["duration_ms"], rel=0.02)
-        for name, ms in by_phase.items():
-            assert ms == pytest.approx(rec["phases_ms"][name],
-                                       abs=0.02 * rec["duration_ms"])
+        # each crossed once, in the record's order; a step that launched
+        # ahead crossed `launch` before `device`
+        crossed = [n for n, _ in itertools.groupby(names)]
+        order = [p for p in PHASES if p in rec["phases_ms"]]
+        ahead = list(order)
+        if "launch" in ahead and "device" in ahead:
+            ahead.remove("launch")
+            ahead.insert(ahead.index("device"), "launch")
+        assert crossed in (order, ahead), (crossed, rec["phases_ms"])
+        # one after the other on the profiler's clock, and all of them
+        # inside the iteration's event: their sum does not exceed it
+        for before, after in zip(inside, inside[1:]):
+            assert before[1] + before[2] <= after[1]
+        assert sum(e[2] for e in inside) <= dur
 
 
 def test_profiler_off_emits_no_event(tmp_path):
@@ -146,9 +150,8 @@ def test_mixed_step_ops_carry_the_scope_names(monkeypatch, decode_chunk):
     walk they share where they share it, and the expert einsums in its
     `op_name`s (metadata only)."""
     params = moe.init_params(MOE_CFG, jax.random.key(0))
-    srv = PagedInferenceServer(params, MOE_CFG, GREEDY, scheduler="mixed",
-                               overlap=False, decode_chunk=decode_chunk,
-                               **PAGED_KW)
+    srv = PagedInferenceServer(params, MOE_CFG, GREEDY,
+                               decode_chunk=decode_chunk, **PAGED_KW)
     texts = []
     orig = ps._mixed_step
 

@@ -30,16 +30,14 @@ def model():
 
 
 def test_the_pool_is_sized_by_the_program_from_what_it_knows(model):
-    """ceil((window - 1 + writes ahead) / page) + 1 pages a slot: one
-    dispatch ahead without overlap, two with it (the plan of the next is
-    built while one runs). No option of the server sets it."""
+    """ceil((window - 1 + writes ahead) / page) + 1 pages a slot, two
+    dispatches ahead (the plan of the next is built while one runs). No
+    option of the server sets it."""
     import inspect
-    seq = make_server(model, overlap=False)
-    ovl = make_server(model, overlap=True)
-    assert seq.window_pages_per_slot == -(-(WINDOW - 1 + CHUNK) // PAGE) + 1
-    assert ovl.window_pages_per_slot == -(-(WINDOW - 1 + 2 * CHUNK)
+    srv = make_server(model)
+    assert srv.window_pages_per_slot == -(-(WINDOW - 1 + 2 * CHUNK)
                                           // PAGE) + 1
-    assert seq.window_pool.num_pages == 4 * seq.window_pages_per_slot
+    assert srv.window_pool.num_pages == 4 * srv.window_pages_per_slot
     # the published sizes: 4,096 keys, pages of 128, chunks of 256
     assert paged_engine.window_pages_per_slot(4096, 128, 256, 128) == 35
     assert paged_engine.window_pages_per_slot(4096, 128, 512, 128) == 37
@@ -68,14 +66,14 @@ def test_a_freed_page_is_never_read(model):
                 **pools, "wk": pools["wk"].at[:, free].set(1e4),
                 "wv": pools["wv"].at[:, free].set(1e4)}
 
-    srv = make_server(model, scheduler="mixed", overlap=False)
+    srv = make_server(model, waits=True)
     prompts, handles = serve_all(srv, each_step=poison)
     assert worst_logprob_diff(model, prompts, handles) < LOGPROB_ATOL
     assert_pages_balance(srv)
 
 
 def test_no_launched_program_can_read_a_returned_page(model):
-    """Under the overlapped scheduler the plan of step N + 1 is built
+    """The plan of step N + 1 is built
     before step N commits and launched after it. After every step, every
     page the launched program's rows read (from its first query's bound
     to its last write) is held by its slot, and nothing behind the
@@ -89,7 +87,7 @@ def test_no_launched_program_can_read_a_returned_page(model):
         if infl is None:
             return
         seen["launched"] += 1
-        rows = [(job.slots[0], int(job.base_lens[0]) + d0, take)
+        rows = [(job.slot, job.base_len + d0, take)
                 for job, take, d0 in infl.sel]
         if infl.n_rounds:
             rows += [(int(sid), int(srv.lengths[sid]), infl.win)
@@ -105,7 +103,7 @@ def test_no_launched_program_can_read_a_returned_page(model):
             assert int(srv._win_lo[sid]) <= first
             seen["returned_before_launch"] += int(srv._win_lo[sid] > 0)
 
-    srv = make_server(model, scheduler="mixed", overlap=True)
+    srv = make_server(model)
     prompts, handles = serve_all(srv, each_step=check)
     assert seen["launched"] > 40 and seen["returned_before_launch"] > 20
     assert worst_logprob_diff(model, prompts, handles) < LOGPROB_ATOL
@@ -115,7 +113,7 @@ def test_preemption_and_resume(model):
     """A full pool too small for the three requests together: the
     youngest is preempted, gives back the pages of both kinds, and
     resumes by prefilling prompt and answer so far through both pools."""
-    srv = make_server(model, num_pages=22, scheduler="mixed", overlap=False)
+    srv = make_server(model, num_pages=22, waits=True)
     prompts, handles = serve_all(srv)
     assert srv.preemptions >= 1
     assert worst_logprob_diff(model, prompts, handles) < LOGPROB_ATOL
@@ -123,7 +121,7 @@ def test_preemption_and_resume(model):
 
 
 def test_the_int8_cache_has_scale_pools_of_both_kinds(model):
-    srv = make_server(model, kv_cache_dtype="int8", scheduler="mixed")
+    srv = make_server(model, kv_cache_dtype="int8")
     pools = srv.state["pools"]
     assert set(pools) == {"k", "v", "k_scale", "v_scale",
                           "wk", "wv", "wk_scale", "wv_scale"}
@@ -144,8 +142,7 @@ def test_the_int8_cache_has_scale_pools_of_both_kinds(model):
 
 
 def test_records_and_stats_describe_both_pools(model):
-    srv = make_server(model, scheduler="mixed", overlap=True,
-                      flight_recorder_size=512)
+    srv = make_server(model, flight_recorder_size=512)
     serve_all(srv)
     recs = srv.flight_window()
     assert sum(r.get("pages_returned", 0) for r in recs) \
@@ -172,7 +169,7 @@ def test_what_moves_or_shares_one_kind_of_page_refuses(model):
     it, each with an error that names the mechanism."""
     from cloud_server_tpu.inference.router import ReplicatedRouter
     _, mcfg, weights, _ = model
-    srv = make_server(model, scheduler="mixed", overlap=False)
+    srv = make_server(model)
     prompt = list(map(int, tokens_of(70, 5)))
     for _ in range(2):
         h = srv.submit(prompt, max_new_tokens=4)

@@ -29,14 +29,12 @@ def model():
 
 
 @pytest.mark.parametrize("mode", [
-    dict(scheduler="mixed", overlap=True),
-    dict(scheduler="mixed", overlap=False),
-    dict(scheduler="alternating"),
-    dict(scheduler="mixed", overlap=True, mixed_token_budget=40),
-    dict(scheduler="mixed", overlap=True, spec_drafts=2),
-    dict(scheduler="mixed", overlap=True, allocation="reserve"),
-], ids=["overlap", "sequential", "alternating", "budget", "ngram-spec",
-        "reserve"])
+    dict(),
+    dict(waits=True),
+    dict(mixed_token_budget=40),
+    dict(spec_drafts=2),
+    dict(allocation="reserve"),
+], ids=["ahead", "waits", "budget", "ngram-spec", "reserve"])
 def test_served_requests_are_the_reference_and_pages_go_back(model, mode):
     """Three requests of 190, 77 and 130 tokens through the server, past
     the window by up to nine pages: every served log-probability against

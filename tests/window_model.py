@@ -10,6 +10,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from serial_order import waits
 
 from cellbench import families, reference, serve
 from cloud_server_tpu.config import InferConfig, ModelConfig
@@ -56,9 +57,11 @@ def make_server(model, **kw):
     if "kv_cache_dtype" in opts:
         mcfg = dataclasses.replace(mcfg,
                                    kv_cache_dtype=opts.pop("kv_cache_dtype"))
-    return PagedInferenceServer(
+    # `waits`: every launch after the commit before it (serial_order)
+    waiting = opts.pop("waits", False)
+    return waits(PagedInferenceServer(
         weights, mcfg, InferConfig(max_decode_len=64, temperature=0.0,
-                                   eos_token_id=-1), **opts)
+                                   eos_token_id=-1), **opts), waiting)
 
 
 PROMPTS = (150, 37, 90)
