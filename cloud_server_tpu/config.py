@@ -98,8 +98,39 @@ class ModelConfig:
     # The layer's body: "single" (attention, then the MLP or the experts)
     # | "double_shortcut": two attention blocks and two dense MLPs of
     # `mlp_dim` in one layer, the experts reading the first half's normed
-    # stream and added after the second half (models/latent.py).
+    # stream and added after the second half (models/latent.py)
+    # | "parallel_mixer": a Mamba-2 mixer and the attention block side by
+    # side on one normed input, then the dense MLP (models/mixer.py).
     layer_body: str = "single"
+    # The mixer of the parallel body, `ssm_heads` > 0: `ssm_heads` heads of
+    # `ssm_head_dim` channels, each with a state of (ssm_head_dim,
+    # ssm_state_dim); `ssm_groups` groups share the state's input and
+    # output projections (B and C); a depthwise causal convolution of
+    # `ssm_conv_width` taps before the scan; `ssm_chunk` tokens a chunk of
+    # the chunked scan. The paged cache holds, beside the pages, one
+    # recurrent state a slot a layer in float32 and the convolution's last
+    # `ssm_conv_width - 1` inputs (`PagedKVCache.ssm`, `.conv`).
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_state_dim: int = 0
+    ssm_groups: int = 1
+    ssm_conv_width: int = 4
+    ssm_chunk: int = 128
+    # Scalar multipliers a model is published with (maximal-update
+    # parametrisation), each 1 where a model states none and applied
+    # only by the parallel body: on the embedding and on the logits, on
+    # the attention block's input, its keys and its output, on the
+    # mixer's input and output, on the five parts of the mixer's input
+    # projection (gate, x, B, C, dt) and on the MLP's gate and output.
+    embedding_multiplier: float = 1.0
+    lm_head_multiplier: float = 1.0
+    attention_in_multiplier: float = 1.0
+    attention_out_multiplier: float = 1.0
+    key_multiplier: float = 1.0
+    ssm_in_multiplier: float = 1.0
+    ssm_out_multiplier: float = 1.0
+    ssm_multipliers: tuple = (1.0, 1.0, 1.0, 1.0, 1.0)
+    mlp_multipliers: tuple = (1.0, 1.0)
     # One chip's share of a wider router. `num_experts` routed experts are
     # held (the first of `num_routed_experts`, the router's columns for
     # experts that have weights; 0 = every expert is held), behind them
@@ -145,6 +176,11 @@ class ModelConfig:
             # JSON gives lists; a jit static argument must be hashable
             object.__setattr__(self, name, tuple(
                 int(bool(f)) for f in getattr(self, name)))
+        for name, n in (("ssm_multipliers", 5), ("mlp_multipliers", 2)):
+            vals = tuple(float(v) for v in getattr(self, name))
+            if len(vals) != n:
+                raise ValueError(f"{name} takes {n} scalars, got {vals}")
+            object.__setattr__(self, name, vals)
         if self.mlp_activation not in ("silu", "relu"):
             raise ValueError(
                 f"unknown mlp_activation: {self.mlp_activation!r}")
@@ -153,8 +189,29 @@ class ModelConfig:
         if any(self.window_layout) and self.sliding_window <= 0:
             raise ValueError("window_layout names window layers but "
                              "sliding_window is not set")
-        if self.layer_body not in ("single", "double_shortcut"):
+        if self.layer_body not in ("single", "double_shortcut",
+                                   "parallel_mixer"):
             raise ValueError(f"unknown layer_body: {self.layer_body!r}")
+        if (self.layer_body == "parallel_mixer") != (self.ssm_heads > 0):
+            raise ValueError(
+                "the mixer's sizes (ssm_heads) and the parallel body "
+                "(layer_body='parallel_mixer') come together: no program "
+                "serves one without the other")
+        if self.ssm_heads and (
+                min(self.ssm_head_dim, self.ssm_state_dim, self.ssm_chunk,
+                    self.ssm_conv_width - 1) < 1
+                or self.ssm_heads % max(self.ssm_groups, 1)):
+            raise ValueError(
+                "a mixer needs ssm_head_dim, ssm_state_dim and ssm_chunk "
+                "of 1 or more, 2 or more convolution taps, and ssm_groups "
+                "dividing ssm_heads")
+        if self.ssm_heads and (self.has_window_layers or self.kv_lora_rank
+                               or self.kv_cache_dtype != "model"
+                               or self.num_experts >= 2):
+            raise ValueError(
+                "a slot's recurrent state is float32 beside full pages in "
+                "the model's dtype and a dense MLP: no window layers, no "
+                "latent cache, no int8 cache or state, no experts")
         if (self.layer_body == "double_shortcut") != (self.kv_lora_rank > 0):
             raise ValueError(
                 "latent attention (kv_lora_rank) and the double layer "
@@ -224,6 +281,16 @@ class ModelConfig:
         vector and the shared rotary key; 0 without latent attention."""
         return (self.kv_lora_rank + self.qk_rope_head_dim
                 if self.kv_lora_rank else 0)
+
+    @property
+    def ssm_inner(self) -> int:
+        """The mixer's inner width: heads x channels a head."""
+        return self.ssm_heads * self.ssm_head_dim
+
+    @property
+    def ssm_conv_dim(self) -> int:
+        """Channels through the mixer's convolution: x, B and C."""
+        return self.ssm_inner + 2 * self.ssm_groups * self.ssm_state_dim
 
     @property
     def attention_blocks(self) -> int:

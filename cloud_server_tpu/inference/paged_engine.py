@@ -74,7 +74,7 @@ import jax.numpy as jnp
 from cloud_server_tpu.config import ModelConfig
 from cloud_server_tpu.inference import multi_lora
 from cloud_server_tpu.inference.engine import _kv_quant, _mlp_apply
-from cloud_server_tpu.models import latent, moe, transformer
+from cloud_server_tpu.models import latent, mixer, moe, transformer
 from cloud_server_tpu.ops import rms_norm, rope_table
 from cloud_server_tpu.ops.paged_attention import (
     paged_attention, paged_attention_tp, paged_attention_xla)
@@ -110,6 +110,16 @@ class PagedKVCache(NamedTuple):
     # so far, modulo 2**32. It rides the pools through every program, so
     # the host reads it with a step's results and takes differences.
     assign: jnp.ndarray | None = None
+    # A model with a mixer beside its attention (`ModelConfig.ssm_heads`):
+    # a kind of its own beside the full kind's pages, one STATE a slot and
+    # not pages. Per layer (a tuple of `num_layers` arrays, so that a
+    # layer's update is its own buffer's, in place) the recurrent state
+    # (slots, heads, head_dim, state_dim) in float32 and the convolution's
+    # last inputs (slots, taps - 1, channels). Its rule: a row at position 0
+    # enters with zero, every row set hands on what it leaves, nothing is
+    # trimmed, shared or keyed (`models/mixer.py`).
+    ssm: tuple | None = None
+    conv: tuple | None = None
 
     @property
     def page_size(self) -> int:
@@ -197,6 +207,14 @@ def init_paged_cache(cfg: ModelConfig, *, num_pages: int, page_size: int,
     k, v, ks, vs = pools("full", num_pages)
     lengths = jnp.zeros((batch,), jnp.int32)
     tables = jnp.full((batch, max_pages_per_slot), num_pages, jnp.int32)
+    if cfg.ssm_heads:  # a state for every slot: admission never waits
+        s_shape, c_shape = mixer.state_shapes(cfg, batch)
+        return PagedKVCache(
+            k, v, lengths, tables,
+            ssm=tuple(jnp.zeros(s_shape, jnp.float32)
+                      for _ in range(cfg.num_layers)),
+            conv=tuple(jnp.zeros(c_shape, dtype)
+                       for _ in range(cfg.num_layers)))
     if "window" not in kinds:
         return PagedKVCache(k, v, lengths, tables, ks, vs)
     wk, wv, wks, wvs = pools("window", window_num_pages)
@@ -224,10 +242,17 @@ def hbm_bytes(cache: PagedKVCache) -> int:
     """Device bytes held by the pools of every kind (the capacity
     comparison the paged layout exists to win — see
     tests/test_paged_server.py)."""
+    return state_bytes(cache) + sum(
+        p.size * p.dtype.itemsize
+        for p in (cache.k, cache.v, cache.k_scale, cache.v_scale,
+                  cache.wk, cache.wv, cache.wk_scale, cache.wv_scale)
+        if p is not None)
+
+
+def state_bytes(cache: PagedKVCache) -> int:
+    """Device bytes of the per-slot states of a model with a mixer."""
     return sum(p.size * p.dtype.itemsize
-               for p in (cache.k, cache.v, cache.k_scale, cache.v_scale,
-                         cache.wk, cache.wv, cache.wk_scale, cache.wv_scale)
-               if p is not None)
+               for p in (cache.ssm or ()) + (cache.conv or ()))
 
 
 def _write_window(cache: PagedKVCache, layer: int, k, v, pos):
@@ -332,6 +357,10 @@ class RowSet(NamedTuple):
     widths: jnp.ndarray | None = None     # (B,) valid widths, ragged rows
     logits_at: jnp.ndarray | None = None  # (B,) in-window index to unembed
     scope: str | None = None  # names the set's own ops in a device trace
+    # (B,) int32, a model with a mixer: the slot whose state each row reads
+    # and leaves advanced; any id past the slots for a row that must leave
+    # none (padding, a decode row that is not live)
+    slots: jnp.ndarray | None = None
 
 
 def _side_by_side(parts):
@@ -394,6 +423,13 @@ def forward_sets(params, cfg: ModelConfig, cache: PagedKVCache,
     if cfg.latent_dim and (lora is not None or mesh is not None):
         raise ValueError("the double layer with latent attention takes no "
                          "adapter and no mesh")
+    if cfg.ssm_heads and (lora is not None or mesh is not None
+                          or all_logits
+                          or any(s.slots is None for s in sets)):
+        raise ValueError(
+            "the parallel body takes no adapter and no mesh, every row set "
+            "names its rows' slots, and a window is not verified position "
+            "by position: a state has no roll-back")
     use_pallas = cfg.decode_attention_impl == "pallas"
     shared = "joined_walk" if joined else None
     rows = []  # per set: positions, write positions, lengths after, block
@@ -425,6 +461,8 @@ def forward_sets(params, cfg: ModelConfig, cache: PagedKVCache,
             cfg, cache.max_context)
         embed = params["embed"]["tokens"].astype(cfg.dtype)
         x = _side_by_side([embed[s.tokens] for s in sets])  # (B, W, D)
+        if cfg.embedding_multiplier != 1.0:
+            x = x * cfg.embedding_multiplier
         pos_all = _side_by_side([r[0] for r in rows])
     pools = cache
 
@@ -440,6 +478,11 @@ def forward_sets(params, cfg: ModelConfig, cache: PagedKVCache,
                 x, lp, layer_idx, params, cfg, pools, sets, rows, cos, sin,
                 pos_all, shared, use_pallas)
             assigned = assigned + counts
+            continue
+        if cfg.layer_body == "parallel_mixer":
+            x, pools = _parallel_layer(x, lp, layer_idx, cfg, pools, sets,
+                                       rows, cos, sin, pos_all, shared,
+                                       use_pallas)
             continue
         ll = (None if lora is None
               else multi_lora.layer_lora(lora, aid, layer_idx))
@@ -499,6 +542,8 @@ def forward_sets(params, cfg: ModelConfig, cache: PagedKVCache,
                 logits.append(transformer.unembed(x_sel, params, cfg))
             else:
                 logits.append(None)
+            if cfg.lm_head_multiplier != 1.0 and logits[-1] is not None:
+                logits[-1] = logits[-1] * cfg.lm_head_multiplier
     if pools.assign is not None:
         pools = pools._replace(assign=pools.assign + assigned)
     return logits, pools._replace(lengths=cache.lengths,
@@ -558,12 +603,74 @@ def _double_layer(x, lp, layer_idx: int, params, cfg: ModelConfig, pools,
         return x + shortcut, pools, aux["assign"]
 
 
+def _parallel_layer(x, lp, layer_idx: int, cfg: ModelConfig, pools, sets,
+                    rows, cos, sin, pos_all, shared, use_pallas: bool):
+    """One parallel layer of `forward_sets`' walk (`models/mixer.py`): the
+    mixer and the attention block read one normed input and are added to
+    the stream together, then the dense MLP. What is per token (the norms,
+    both blocks' projections, the mixer's gate and norm, the MLP) runs once
+    over all sets' tokens; each set writes its keys and values and runs the
+    paged kernel under `attn`, and runs its convolution and its scan
+    against its rows' states under `ssm`: a window of chunk rows through
+    the chunked scan, gathered and scattered by slot, the decode rows (a
+    set without `widths`, one token a row) through the update over the
+    layer's whole state in place. Returns (x', pools')."""
+    with _scope(shared):
+        u = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
+        with jax.named_scope("attn"):
+            qkv = mixer.attention_qkv(u, lp, cfg, cos, sin, pos_all)
+        with jax.named_scope("ssm"):
+            z, xbc, dt_raw = mixer.project_in(u, lp, cfg)
+    ssm, conv = list(pools.ssm), list(pools.conv)
+    outs, mixed = [], []
+    for i, (s, (_, wpos, lens_after, ppb)) in enumerate(zip(sets, rows)):
+        with _scope(s.scope), jax.named_scope("attn"):
+            q, k, v = (_part(y, sets, i) for y in qkv)
+            view = _write_window(
+                pools.of_kind("full", s.tables)._replace(lengths=s.lengths),
+                layer_idx, k, v, wpos)
+            pools = pools.with_kind("full", view)
+            if use_pallas:
+                o = paged_attention(q, view.k, view.v, lens_after,
+                                    view.tables, layer_idx,
+                                    pages_per_block=ppb, widths=s.widths)
+            else:
+                o = paged_attention_xla(q, view.k, view.v, lens_after,
+                                        view.tables, layer_idx,
+                                        widths=s.widths)
+        outs.append(o)
+        with _scope(s.scope), jax.named_scope("ssm"):
+            operands = (_part(xbc, sets, i), _part(dt_raw, sets, i),
+                        ssm[layer_idx], conv[layer_idx], s.slots, s.lengths)
+            if s.widths is None and s.tokens.shape[1] == 1:
+                # the decode rows: every row one real token
+                y, ssm[layer_idx], conv[layer_idx] = mixer.step_slots(
+                    *operands, lp, cfg)
+            else:
+                y, ssm[layer_idx], conv[layer_idx] = mixer.mix_rows(
+                    *operands, s.widths, lp, cfg)
+        mixed.append(y)
+    with _scope(shared):
+        with jax.named_scope("attn"):
+            attn = mixer.attention_out(_side_by_side(outs), lp, cfg)
+        with jax.named_scope("ssm"):
+            mix = mixer.project_out(_side_by_side(mixed), z, lp, cfg)
+        f32 = jnp.float32
+        x = (x.astype(f32) + cfg.ssm_out_multiplier * mix.astype(f32)
+             + cfg.attention_out_multiplier * attn.astype(f32)
+             ).astype(x.dtype)
+        x = x + mixer.mlp(rms_norm(x, lp["mlp_norm"], cfg.norm_eps), lp,
+                          cfg)
+    return x, pools._replace(ssm=tuple(ssm), conv=tuple(conv))
+
+
 def window_forward(params, tokens: jnp.ndarray, cfg: ModelConfig,
                    cache: PagedKVCache, *, logits_at: jnp.ndarray | None,
                    all_logits: bool = False,
                    pages_per_block: int | None = None,
                    mesh=None, tp_axis: str = "tp",
-                   lora=None, aid=None, widths: jnp.ndarray | None = None):
+                   lora=None, aid=None, widths: jnp.ndarray | None = None,
+                   slots: jnp.ndarray | None = None):
     """Forward W new positions per slot against the paged cache: the
     one-set case of `forward_sets`.
 
@@ -583,6 +690,7 @@ def window_forward(params, tokens: jnp.ndarray, cfg: ModelConfig,
         window as [lengths[b], lengths[b] + widths[b]) exactly as a
         width-widths[b] uniform dispatch would. Rows with width 0 are
         fully inert (sentinel-table discipline still applies on top).
+      slots: (B,) int32, a model with a mixer: `RowSet.slots`.
       lora, aid: multi-adapter serving — (stacks, scales) from
         inference.multi_lora.AdapterSet.device_args + per-slot adapter
         ids (B,); each layer gathers its per-row (a, b, scale) and the
@@ -599,7 +707,8 @@ def window_forward(params, tokens: jnp.ndarray, cfg: ModelConfig,
     """
     (logits,), cache = forward_sets(
         params, cfg, cache,
-        [RowSet(tokens, cache.lengths, cache.tables, widths, logits_at)],
+        [RowSet(tokens, cache.lengths, cache.tables, widths, logits_at,
+                slots=slots)],
         all_logits=all_logits, pages_per_block=pages_per_block, mesh=mesh,
         tp_axis=tp_axis, lora=lora, aid=aid)
     return logits, cache

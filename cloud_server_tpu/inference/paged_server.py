@@ -257,7 +257,7 @@ def _grammar_mask(grammar, gid, st, eos_id):
 
 
 _POOL_NAMES = ("k", "v", "k_scale", "v_scale",
-               "wk", "wv", "wk_scale", "wv_scale", "assign")
+               "wk", "wv", "wk_scale", "wv_scale", "assign", "ssm", "conv")
 
 
 def _make_cache(pools, lengths, tables):
@@ -270,7 +270,11 @@ def _split_cache(cache):
     """The cache's pools by name; a pool the model does not have (the
     scales of a bf16 cache, the window kind of a model without window
     layers, the values of a latent cache) is left out. `assign`, the
-    assignment counts of a model with a routed share, rides with them."""
+    assignment counts of a model with a routed share, rides with them, as
+    do the per-slot states of a model with a mixer (`ssm`, `conv`): every
+    program that carries the pages carries them, donated as they are, so
+    a step launched ahead of the last one's commit reads the states that
+    one wrote."""
     return {name: getattr(cache, name) for name in _POOL_NAMES
             if getattr(cache, name) is not None}
 
@@ -448,6 +452,16 @@ def _assign_out(state):
     return None if assign is None else jnp.copy(assign)
 
 
+def _state_slots(cfg: ModelConfig, state, sids, live):
+    """A decode round's `RowSet.slots`, for a model with a mixer: a live
+    row's slot, and for a row that is not live (dead, or padding) the id
+    past the slots, under which its state is neither advanced nor
+    written. None for a model without per-slot state."""
+    if not cfg.ssm_heads:
+        return None
+    return jnp.where(live, sids, state["last"].shape[0]).astype(jnp.int32)
+
+
 def _keep_last(new_state, state, slots, mask, tokens):
     """Leave `tokens` in the per-slot last tokens of `new_state` for the
     rows under `mask` (rows' `slots`; a padding row's sentinel drops):
@@ -512,7 +526,8 @@ def _prefill_core(params, state, chunk, g_lens, g_tables, sample_at,
         cache = _make_cache(state["pools"], g_lens, g_tables)
         logits, cache = paged_engine.window_forward(
             params, chunk, cfg, cache, logits_at=sample_at, mesh=mesh,
-            lora=lora, aid=aid, widths=widths)
+            lora=lora, aid=aid, widths=widths,
+            slots=slot_ids if cfg.ssm_heads else None)
         new_state["pools"] = _split_cache(cache)
 
     has_pen = "prompt_mask" in state  # buffers materialize lazily
@@ -647,6 +662,7 @@ def _decode_plain_core(params, state, lengths, tables, last_token, live,
     batch_idx = jnp.arange(lengths.shape[0])
     (sids, sids_r, pm, oc0, gstate0,
      full_oc, full_gstate) = _gather_slot_state(state, slot_ids, batch_idx)
+    state_slots = _state_slots(cfg, state, sids, live)
 
     def body(carry, rng_t):
         lengths, last, hist, pools, oc, gstate = carry
@@ -660,7 +676,7 @@ def _decode_plain_core(params, state, lengths, tables, last_token, live,
             round_logits, cache = paged_engine.window_forward(
                 params, last[:, None], cfg, cache,
                 logits_at=jnp.zeros_like(lengths), mesh=mesh,
-                lora=lora, aid=aid)
+                lora=lora, aid=aid, slots=state_slots)
             pools = _split_cache(cache)
         else:
             round_logits = logits
@@ -1129,11 +1145,14 @@ def _mixed_step(params, state, group, patch, rows, rng, grammar=None,
                    draft_cfg, lora):
         (plogits, dlogits), cache = paged_engine.forward_sets(
             params, cfg, _make_cache(state["pools"], g_lens, g_tables),
-            [paged_engine.RowSet(chunk, g_lens, g_tables, widths,
-                                 sample_at, "prefill_group"),
-             paged_engine.RowSet(last_token[:, None], lengths, tables,
-                                 None, jnp.zeros_like(lengths),
-                                 "decode_rounds")],
+            [paged_engine.RowSet(
+                chunk, g_lens, g_tables, widths, sample_at, "prefill_group",
+                g["slot_ids"] if cfg.ssm_heads else None),
+             paged_engine.RowSet(
+                 last_token[:, None], lengths, tables, None,
+                 jnp.zeros_like(lengths), "decode_rounds",
+                 _state_slots(cfg, state, jnp.arange(lengths.shape[0])
+                              if slot_ids_d is None else slot_ids_d, live))],
             mesh=mesh)
         state = {**state, "pools": _split_cache(cache)}
     state, ptoks, plps = _prefill_core(
@@ -1399,6 +1418,13 @@ class PagedInferenceServer:
                 "layer) is served without a draft model, whose pools hold "
                 "keys and values by the target's tables, and without a "
                 "mesh, which shards pools over key heads it does not have")
+        if cfg.ssm_heads and (spec_drafts > 0 or mesh is not None):
+            raise ValueError(
+                "a model with a recurrent state a slot is served without "
+                "speculative decoding (spec_drafts, a draft model), which "
+                "verifies a window and keeps part of it where a state has "
+                "no roll-back, and without a mesh, over which nothing "
+                "shards the states")
         self.draft_cfg = draft_cfg
         self.draft_params = (None if draft_params is None else jax.tree.map(
             cast_leaf, draft_params,
@@ -1427,6 +1453,11 @@ class PagedInferenceServer:
                 self.max_pages_per_slot)
             self.window_pool = WindowPagePool(
                 max_slots * self.window_pages_per_slot)
+        # what the prefix cache cannot vouch for: the window kind's pages,
+        # or a state for which it holds no snapshot. Such a model's slots
+        # key and share nothing, so every prompt starts at position 0
+        self._keys_nothing = (self.window_pool is not None
+                              or bool(cfg.ssm_heads))
         # the tables' width and their "no page": a table per kind side
         # by side (`PagedKVCache.tables`), one id past both pools
         kinds = 2 if self.window_pool is not None else 1
@@ -1446,6 +1477,7 @@ class PagedInferenceServer:
             max_pages_per_slot=self.max_pages_per_slot,
             window_num_pages=(None if self.window_pool is None
                               else self.window_pool.num_pages))
+        self.ssm_state_bytes = paged_engine.state_bytes(cache)
         # per-request sampling penalty state ("prompt_mask" /
         # "out_counts", (B, V) per slot) is NOT allocated here — the
         # first admission that needs penalties materializes it
@@ -1905,8 +1937,8 @@ class PagedInferenceServer:
         # prefill completes with decode budget left — the router's
         # hook migrates it to a decode replica. Rides IN through
         # submit for the same reason fail_handler does.
-        if handoff is not None and self.cfg.latent_dim:
-            self._refuse_window("the disaggregated hand-off (handoff=)")
+        if handoff is not None:
+            self._refuse_slot_state("the disaggregated hand-off (handoff=)")
         req._handoff = handoff
         req._on_cancel = self._handle_cancel  # before it can be seen
         with self._lock:
@@ -2351,10 +2383,11 @@ class PagedInferenceServer:
         retires a slot (finish, preemption, failure) goes through here;
         what happens to the request afterwards is the caller's story."""
         slot = self._slots[slot_id]
-        # a model with window layers keys nothing: a later hit on the full
-        # kind's pages would find the window kind's given back
+        # a model whose slots hold more than the full kind's pages keys
+        # nothing: a later hit on those pages would find the window
+        # kind's given back, or no snapshot of the state at their end
         self.allocator.release(
-            slot.pages, [] if self.window_pool is not None else keyed_tokens,
+            slot.pages, [] if self._keys_nothing else keyed_tokens,
             namespace=slot.req.adapter or "", tenant=slot.req.tenant)
         self._slots[slot_id] = None
         self._window_free(slot_id, int(self._win_hi[slot_id]))
@@ -2436,10 +2469,10 @@ class PagedInferenceServer:
                 req = self._pending[idx]
                 prompt = list(req.prompt) + list(req.tokens)
                 remaining = req.max_new_tokens - len(req.tokens)
-                if self.window_pool is not None:
-                    # no prefix hit for a model with window layers (see
-                    # `_release_slot`): every admission prefills its whole
-                    # prompt through both pools
+                if self._keys_nothing:
+                    # no prefix hit for such a model (see `_release_slot`):
+                    # every admission prefills its whole prompt, through
+                    # both pools or from a zeroed state
                     shared, shared_len = [], 0
                 else:
                     shared, shared_len = self.allocator.lookup_prefix(
@@ -3253,6 +3286,13 @@ class PagedInferenceServer:
                 n_live=n_live, decode_rounds=n_rounds,
                 decode_tokens=n_live * win * n_rounds,
                 prefill_tokens=sum(t for _, t, _ in sel))
+            if self.cfg.ssm_heads:
+                # real tokens through the chunked scan, and the rows that
+                # stand at position 0 and enter with a zeroed state
+                stats.update(
+                    ssm_chunk_tokens=stats["prefill_tokens"],
+                    ssm_resets=sum(job.base_len + d0 == 0
+                                   for job, _, d0 in sel))
             if n_rounds > 0:
                 self._stage_spec_stats(g_iter, n_live, st=stats)
             if self.trace_recorder is not None:
@@ -3506,6 +3546,10 @@ class PagedInferenceServer:
         lora = self.adapters.device_args() if plan.use_lora else None
         patch = self._feed_patch(plan.d_lens, plan.d_last, plan.live_g,
                                  plan.d_tables)
+        if self.cfg.ssm_heads:
+            # rows whose state this program advances by one token a round
+            plan.stats["ssm_decode_rows"] = plan.n_rounds * int(
+                np.count_nonzero(plan.live_g))
         self._stage_program_kind(
             plan.stats,
             plan.pf["chunk_tokens"] if plan.kind == "mixed" else 0,
@@ -4367,6 +4411,10 @@ class PagedInferenceServer:
                     "window_pool_active": self.window_pool.active,
                     "window_num_pages": self.window_pool.num_pages,
                     "window_pages_per_slot": self.window_pages_per_slot}),
+                # the per-slot states of a model with a mixer: a state for
+                # every slot, held whether the slot is or not
+                **({} if not self.cfg.ssm_heads else {
+                    "ssm_state_bytes": self.ssm_state_bytes}),
             },
             "prefix": {
                 "hit_pages": hit_pages,
@@ -4555,21 +4603,26 @@ class PagedInferenceServer:
 
     # -- live migration -----------------------------------------------------
 
-    def _refuse_window(self, mechanism: str) -> None:
-        """Raise for a mechanism that moves or shares pages of the full
-        kind only, on a model that also has window layers, or whose pages
-        are latent entries."""
+    def _refuse_slot_state(self, mechanism: str) -> None:
+        """Raise for a mechanism that carries a request from one server
+        to another as pages of keys and values of the full kind, on a
+        model whose slots hold what it cannot carry: pages of a second
+        kind, latent entries, or a state beside the pages."""
         if self.cfg.latent_dim:
-            raise ValueError(
-                f"{mechanism} exports pages of keys and values and this "
-                "model's pages hold latent entries (latent attention, "
-                "LongCat-Flash's double layer), which nothing on the other "
-                "side could read; not supported for such a model")
-        if self.window_pool is not None:
-            raise ValueError(
-                f"{mechanism} moves the full kind's pages only and this "
-                "model also has sliding-window layers, whose pages it "
-                "would leave behind; not supported for such a model")
+            held = ("its pages hold latent entries, which nothing on the "
+                    "other side could read")
+        elif self.window_pool is not None:
+            held = ("it also has sliding-window layers, whose pages would "
+                    "be left behind")
+        elif self.cfg.ssm_heads:
+            held = ("a slot also holds a recurrent state beside its pages, "
+                    "which has no export and no import")
+        else:
+            return
+        raise ValueError(
+            f"{mechanism} moves pages of keys and values of the full kind "
+            f"only and this model's slots hold more: {held}; not supported "
+            "for such a model")
 
     def migrate_export(self, req: Request, *, reason: str = "failover",
                        evacuate: bool = True):
@@ -4592,7 +4645,7 @@ class PagedInferenceServer:
         outcome back. A request mid-admission (chunked prefill still
         dispatching) is not exportable and raises RuntimeError; the
         caller lets it finish or fail normally."""
-        self._refuse_window("live migration (migrate_export)")
+        self._refuse_slot_state("live migration (migrate_export)")
         led = self._migration
         led.record_export_start()
         try:
@@ -4781,7 +4834,7 @@ class PagedInferenceServer:
         Returns the new Request handle. Only NEW tokens are emitted
         on `stream`; the snapshot's already-delivered tokens are
         pre-filled so the client keeps one contiguous stream."""
-        self._refuse_window("live migration (migrate_import)")
+        self._refuse_slot_state("live migration (migrate_import)")
         from cloud_server_tpu.inference.migration import (
             MIGRATION_VERSION)
         led = self._migration
@@ -5047,7 +5100,7 @@ class PagedInferenceServer:
         with self._lock:
             self._draining = True
         if migrate is not None:
-            self._refuse_window("drain(migrate=...)")
+            self._refuse_slot_state("drain(migrate=...)")
             self._evacuate(migrate)
         deadline = (None if timeout is None
                     else time.perf_counter() + timeout)

@@ -181,22 +181,23 @@ class ReplicatedRouter:
         # to the role-less router (pinned by the existing exact-output
         # and dispatch-count guard tests).
         for r in self.replicas:
-            if getattr(getattr(r, "cfg", None), "has_window_layers", False):
-                # failover's live migration, drain(migrate=True) and the
-                # prefill/decode hand-off all export the full kind's
-                # pages alone (inference/migration.py)
-                raise ValueError(
-                    "ReplicatedRouter: live migration and the "
-                    "disaggregated hand-off move one kind of page; a "
-                    "model with sliding-window layers is served by a lone "
-                    "PagedInferenceServer")
-            if getattr(getattr(r, "cfg", None), "latent_dim", 0):
-                raise ValueError(
-                    "ReplicatedRouter: live migration and the "
-                    "disaggregated hand-off export pages of keys and "
-                    "values; a model with latent attention (LongCat-"
-                    "Flash's double layer) is served by a lone "
-                    "PagedInferenceServer")
+            # failover's live migration, drain(migrate=True) and the
+            # prefill/decode hand-off all export the full kind's pages
+            # alone (inference/migration.py)
+            cfg = getattr(r, "cfg", None)
+            for held, what in (
+                    ("has_window_layers", "sliding-window layers, whose "
+                     "pages would be left behind"),
+                    ("latent_dim", "latent attention, whose pages hold "
+                     "latent entries"),
+                    ("ssm_heads", "a recurrent state a slot beside its "
+                     "pages")):
+                if getattr(cfg, held, 0):
+                    raise ValueError(
+                        "ReplicatedRouter: live migration and the "
+                        "disaggregated hand-off move pages of keys and "
+                        f"values of one kind; a model with {what} is "
+                        "served by a lone PagedInferenceServer")
         if roles is None:
             self.roles = [ROLE_COLOCATED] * len(self.replicas)
         else:

@@ -139,6 +139,12 @@ def attention_qkv(x, lp, cfg: ModelConfig, cos, sin, positions=None,
     `rope`: whether this layer rotates q and k (`cfg.layer_rope`): a
     bool where the caller unrolls its layers, a traced flag where it
     scans over them."""
+    if cfg.layer_body != "single":
+        raise NotImplementedError(
+            f"layer_body={cfg.layer_body!r} is walked by the paged server "
+            "alone (inference/paged_engine.forward_sets): the training "
+            "scans and the contiguous cache run single layers and hold no "
+            "latent pages and no state a slot")
     h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
     q = jnp.einsum("bsd,dhk->bshk", h, lp["wq"].astype(cfg.dtype))
     k = jnp.einsum("bsd,dhk->bshk", h, lp["wk"].astype(cfg.dtype))
