@@ -401,10 +401,15 @@ def forward_sets(params, cfg: ModelConfig, cache: PagedKVCache,
     run once per set, each at its own window width (a decode row as a
     width-1 row of a 256-wide window would waste the wide kernel). The
     sets' rows are disjoint slots, so the order of their writes does
-    not matter. With more than one set the shared work lies under the
-    scope `joined_walk` and each set's own (cache write, kernel,
-    unembed) under its `scope`; one set adds no scope of its own and is
-    exactly `window_forward`.
+    not matter. The tail of the walk (final norm, head, its multiplier
+    and soft cap) runs once too where more than one set names a
+    `logits_at`: each set's wanted rows are taken out first and meet the
+    head, the model's largest matrix, in one product, of which each set
+    is handed its rows. With more than one set the shared work lies
+    under the scope `joined_walk` (that product under
+    `joined_walk/unembed`) and each set's own (cache write, kernel, the
+    pick of its wanted rows) under its `scope`; one set adds no scope of
+    its own and is exactly `window_forward`.
 
     Joining is the caller's decision: the result equals separate walks
     only where a token's MLP output does not depend on which other
@@ -528,22 +533,40 @@ def forward_sets(params, cfg: ModelConfig, cache: PagedKVCache,
                            stack=(params["layers"], layer_idx),
                            layer_in=x_in)
 
-    logits = []
-    for i, s in enumerate(sets):
-        with _scope(s.scope), jax.named_scope("unembed"):
-            b, w = s.tokens.shape
-            xs = rms_norm(_part(x, sets, i), params["final_norm"]["scale"],
-                          cfg.norm_eps)
-            if all_logits:
-                logits.append(transformer.unembed(xs, params, cfg))
-            elif s.logits_at is not None:
-                # (B, D)
-                x_sel = xs[jnp.arange(b), jnp.clip(s.logits_at, 0, w - 1)]
-                logits.append(transformer.unembed(x_sel, params, cfg))
-            else:
-                logits.append(None)
-            if cfg.lm_head_multiplier != 1.0 and logits[-1] is not None:
-                logits[-1] = logits[-1] * cfg.lm_head_multiplier
+    def head(xs):  # final-norm'd rows to logits
+        out = transformer.unembed(xs, params, cfg)
+        return (out if cfg.lm_head_multiplier == 1.0
+                else out * cfg.lm_head_multiplier)
+
+    def at_rows(xs, s):  # (B, W, D) -> (B, D) at the set's `logits_at`
+        b, w = s.tokens.shape
+        return xs[jnp.arange(b), jnp.clip(s.logits_at, 0, w - 1)]
+
+    scale = params["final_norm"]["scale"]
+    wanted = [i for i, s in enumerate(sets) if s.logits_at is not None]
+    logits = [None] * len(sets)
+    if len(wanted) > 1 and not all_logits:
+        # the head is the model's largest matrix: the wanted rows of all
+        # sets meet it in ONE product, so a step streams it once
+        picked = []
+        for i in wanted:
+            with _scope(sets[i].scope), jax.named_scope("unembed"):
+                picked.append(at_rows(_part(x, sets, i), sets[i]))
+        with _scope(shared), jax.named_scope("unembed"):
+            both = head(rms_norm(jnp.concatenate(picked),  # (sum B, D)
+                                 scale, cfg.norm_eps))
+        at = 0
+        for i, p in zip(wanted, picked):
+            with _scope(sets[i].scope), jax.named_scope("unembed"):
+                logits[i] = both[at:at + p.shape[0]]
+            at += p.shape[0]
+    else:
+        for i, s in enumerate(sets):
+            if not all_logits and s.logits_at is None:
+                continue
+            with _scope(s.scope), jax.named_scope("unembed"):
+                xs = rms_norm(_part(x, sets, i), scale, cfg.norm_eps)
+                logits[i] = head(xs if all_logits else at_rows(xs, s))
     if pools.assign is not None:
         pools = pools._replace(assign=pools.assign + assigned)
     return logits, pools._replace(lengths=cache.lengths,

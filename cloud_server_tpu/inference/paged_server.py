@@ -477,7 +477,9 @@ def _keep_last(new_state, state, slots, mask, tokens):
 # window lie under `prefill_group/`, ops of the decode or speculative
 # rounds under `decode_rounds/`, and inside either under `attn`, the
 # `moe_*` scopes of models/moe.py (`mlp` for a dense block), `unembed`
-# and `sample`. Names are HLO metadata only: no program, cache key or
+# and `sample`; what a one-walk step runs once for both halves lies
+# under `joined_walk/`, its one product with the head (`unembed`)
+# included. Names are HLO metadata only: no program, cache key or
 # result changes with them.
 @jax.named_scope("prefill_group")
 def _prefill_core(params, state, chunk, g_lens, g_tables, sample_at,
@@ -1101,10 +1103,12 @@ def _mixed_step(params, state, group, patch, rows, rng, grammar=None,
     no live adapter, an MLP under which no token can be dropped): the
     chunk tokens and the decode round's rows meet every layer's weights
     in one call (`paged_engine.forward_sets`), so a step streams them
-    once, not twice; each half keeps its own cache write, paged kernel,
-    unembed and sampler, and everything of the two cores around their
-    forward runs as it is (they are handed the logits). Elsewhere each
-    half walks the layers itself. Greedy/seeded outputs are
+    once, not twice, and the head with them: the group's `sample_at`
+    rows and the decode rows are unembedded in one product. Each half
+    keeps its own cache write, paged kernel and sampler, and everything
+    of the two cores around their forward runs as it is (they are handed
+    the logits). Elsewhere each half walks the layers itself, head and
+    all. Greedy/seeded outputs are
     token-for-token the dense engine's either way
     (tests/test_mixed_scheduler.py, tests/test_joined_walk.py).
 

@@ -172,18 +172,21 @@ def test_mixed_step_ops_carry_the_scope_names(monkeypatch, decode_chunk):
     # the weights' side of a layer, once for both halves or once in each
     shared = "joined_walk/" if joined else "prefill_group/"
     for scope in (shared + "attn/", shared + "moe_route/",
-                  shared + "moe_experts/", "prefill_group/attn/",
-                  "prefill_group/unembed/", "prefill_group/sample/",
+                  shared + "moe_experts/", shared + "unembed/",
+                  "prefill_group/attn/", "prefill_group/sample/",
                   "decode_rounds/", "/attn/", "/moe_dispatch/",
                   "/moe_experts/", "/moe_combine/", "/unembed/",
                   "/sample/"):
         assert any(scope in n for n in names), scope
     assert any("joined_walk/" in n for n in names) == joined
     decode = [n for n in names if "decode_rounds/" in n]
-    # a half's own experts only where it walks the layers itself; its
-    # cache write, kernel, unembed and sampler always
+    # a half's own experts and its own product with the head only where
+    # it walks the layers itself; its cache write, kernel and sampler
+    # always
     assert any("/moe_experts/" in n for n in decode) != joined
-    for scope in ("/attn/", "/unembed/", "/sample/"):
+    dots = [n for n in names if "/unembed/" in n and "dot_general" in n]
+    assert dots and all(("joined_walk/" in n) == joined for n in dots), dots
+    for scope in ("/attn/", "/sample/"):
         assert any(scope in n for n in decode), scope
     # every op of the program itself lies in one of the halves or in
     # the walk they share (the reducers XLA's CPU backend names
