@@ -58,7 +58,7 @@ DECODE_ROWS = 64
 
 
 def _layers(cfg: ModelConfig, n_layers: int, key):
-    d, e, f = cfg.embed_dim, cfg.num_experts, cfg.mlp_dim
+    d, e, f = cfg.embed_dim, cfg.num_experts, cfg.expert_width
     dt = jnp.dtype(cfg.dtype)
     ks = jax.random.split(key, 4)
 
@@ -67,7 +67,9 @@ def _layers(cfg: ModelConfig, n_layers: int, key):
                 * fan_in ** -0.5).astype(dt)
 
     return {"mlp_norm": jnp.ones((n_layers, d), dt),
-            "router": w(ks[0], (n_layers, d, e), d),
+            # a share's router has columns for experts held elsewhere
+            "router": w(ks[0], (n_layers, d, cfg.router_width), d),
+            "router_bias": jnp.zeros((n_layers, cfg.router_width), dt),
             "w_gate": w(ks[1], (n_layers, e, d, f), d),
             "w_up": w(ks[2], (n_layers, e, d, f), d),
             "w_down": w(ks[3], (n_layers, e, f, d), f)}
@@ -146,7 +148,12 @@ def main() -> None:
     ap.add_argument("--between", default="192,256,320,384,448,512,576",
                     help="the T at which both dispatches are measured")
     ap.add_argument("--tiles", action="store_true",
-                    help="sweep the grouped matmul's row and weight tiles")
+                    help="sweep the way in's column tile and grid order")
+    ap.add_argument("--sorted", default="",
+                    help="the T at which the sorted dispatch alone is "
+                         "measured")
+    ap.add_argument("--tile-tokens", default="320,576,1088,2112",
+                    help="the T of the tile sweep's calls")
     ap.add_argument("--skew", type=float, default=0.0,
                     help="how far the router is from even: every token "
                          "gets this much of one common vector, so the "
@@ -160,19 +167,24 @@ def main() -> None:
                                  "kind": dev.device_kind}}), flush=True)
     cfg = _config(a.config)
     if a.tiny:
-        cfg = dataclasses.replace(cfg, embed_dim=64, mlp_dim=128,
-                                  dtype="float32")
+        cfg = dataclasses.replace(
+            cfg, embed_dim=64, mlp_dim=128, dtype="float32",
+            expert_mlp_dim=128 if cfg.expert_mlp_dim else 0)
         a.reps, a.sets = 2, 1
     elif dev.platform != "tpu":
         raise SystemExit("real widths are measured on a TPU only")
     print(json.dumps({"config": a.config, "embed_dim": cfg.embed_dim,
-                      "mlp_dim": cfg.mlp_dim, "experts": cfg.num_experts,
+                      "expert_width": cfg.expert_width,
+                      "experts": cfg.num_experts,
                       "a_token": cfg.num_experts_per_token,
                       "activation": cfg.mlp_activation,
                       "router_input": cfg.router_input,
                       "skew": a.skew,
                       "placed_min_tokens": moe.grouped_min_tokens(cfg),
-                      "placed_tilings": moe._gmm_tilings(cfg)}), flush=True)
+                      "placed_tilings": {
+                          t: moe._gmm_tilings(
+                              cfg, t * cfg.num_experts_per_token)
+                          for t in (320, 576, 1088, 2112)}}), flush=True)
     layers = _layers(cfg, 2, jax.random.PRNGKey(0))
     lines = []
 
@@ -236,24 +248,33 @@ def main() -> None:
             measure(f"{name}_{t}", one, (x_of(t, 4), layers), tokens=t)
     moe.grouped_min_tokens = placed
 
+    # the sorted dispatch alone, as placed, at the calls a step makes
+    moe.grouped_min_tokens = lambda cfg: 1
+    for t in [int(t) for t in a.sorted.split(",") if t]:
+        measure(f"sorted_{t}", one, (x_of(t, 4), layers), tokens=t)
+    moe.grouped_min_tokens = placed
+
     if a.tiles:
-        # the sorted dispatch alone at a decode-and-chunks call and at a
-        # full step's, over row tiles and weight tiles; the placed pair
-        # first. `_grouped_experts` takes the pair as a static argument,
-        # so every case is a trace of its own
-        d, f = cfg.embed_dim, cfg.mlp_dim
+        # the sorted dispatch alone at calls whose experts have one row
+        # tile and at calls whose experts have several, over the way in's
+        # column tiles in both grid orders (the block the whole width: the
+        # visits outermost; the block one column tile: the columns
+        # outermost); the placed pair first. `_grouped_experts` takes the
+        # pair as a static argument, so every case is a trace of its own
+        d, f = cfg.embed_dim, cfg.expert_width
         placed_t = moe._gmm_tilings
         moe.grouped_min_tokens = lambda cfg: 1
-        cases = [placed_t(cfg)]
-        for rows in (128, 256, 512):
-            for kin, nin in ((d, f), (d, f // 2), (d // 2, f)):
-                for kout, nout in ((f, d), (f, d // 2), (f // 2, d)):
-                    pair = ((rows, kin, nin), (rows, kout, nout))
+        way_out = moe._gmm_tiling(f, d)
+        columns = [tn for tn in (256, 512, 768, 1024, 2048) if f % tn == 0]
+        for t in [int(t) for t in a.tile_tokens.split(",") if t]:
+            cases = [placed_t(cfg, t * cfg.num_experts_per_token)]
+            for tn in columns:
+                for pair in (((moe._GMM_ROWS, tn, f), way_out),
+                             ((moe._GMM_ROWS, tn, tn), way_out)):
                     if pair not in cases:
                         cases.append(pair)
-        for pair in cases:
-            moe._gmm_tilings = lambda cfg, pair=pair: pair
-            for t in (512 + DECODE_ROWS, 2048 + DECODE_ROWS):
+            for pair in cases:
+                moe._gmm_tilings = lambda cfg, n, pair=pair: pair
                 measure(f"tiles_{t}", one, (x_of(t, 5), layers),
                         tokens=t, tilings=pair)
         moe._gmm_tilings = placed_t

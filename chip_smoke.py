@@ -55,7 +55,7 @@ MAX_LEN = 4096                # serving context (the config declares 131,072)
 SERVE_PROGRAMS = ["_mixed_step", "_decode_rounds", "_spec_rounds"]
 KERNEL_TESTS = ["tests/test_paged_attention.py",
                 "tests/test_flash_attention.py", "tests/test_fused_ce.py",
-                "tests/test_moe.py"]
+                "tests/test_moe.py", "tests/test_grouped_matmul.py"]
 
 _T0 = time.monotonic()
 

@@ -57,6 +57,7 @@ from jax import lax
 from cloud_server_tpu.config import ModelConfig
 from cloud_server_tpu.models import transformer
 from cloud_server_tpu.ops import gated, rms_norm, rope_table
+from cloud_server_tpu.ops.grouped_matmul import gated_grouped_matmul
 from cloud_server_tpu.parallel.mesh import maybe_current_mesh
 
 Params = dict
@@ -261,28 +262,26 @@ def _one_hot_routing(gate_vals, gate_idx, e: int, capacity: int):
 # The expert layer
 # ---------------------------------------------------------------------------
 
-# (rows, contraction, columns) tiles of the grouped matmul kernel on the
-# TPU, for x @ w_gate|w_up and for act @ w_down. A row tile that straddles
-# two experts is computed for both (the kernel visits it once for each),
-# so fewer rows waste less; a weight tile is fetched once per row tile
-# unless it spans the contraction (as 4,096 does on the way in at
-# Mixtral's widths: an expert's consecutive row tiles then reuse it), so
-# more rows fetch less. `_gmm_tilings` places them from
-# the widths: the weight tile is the largest of 4 MiB or less that spans
-# the contraction where the contraction is 4,096 or less, and takes 1,024
-# of it otherwise. At (4,096 x 14,336) that is the (256, 4096, 512) and
-# (256, 1024, 2048) of the v5e sweep of PERF.md (PR 26); at (2,560 x 768)
+# (rows, contraction, columns) tiles of megablox's grouped matmul kernel on
+# the TPU, the way out's (act @ w_down). A row tile that straddles two
+# experts is computed for both (the kernel visits it once for each), so
+# fewer rows waste less; a weight tile is fetched once per row tile
+# unless it spans the contraction, so more rows fetch less. `_gmm_tiling`
+# places them from the widths: the weight tile is the largest of 4 MiB or
+# less that spans the contraction where the contraction is 4,096 or less,
+# and takes 1,024 of it otherwise. At (14,336 x 4,096) that is the
+# (256, 1024, 2048) of the v5e sweep of PERF.md (PR 26); at (768 x 2,560)
 # an expert's whole matrix is one tile (the sweep of PR 35).
-# `_GMM_ROWS` is both tilings' row tile, and the unit of the sorted rows'
-# layout: the buffer they are gathered out to is whole row tiles
-# (`_sorted_buffer_rows`), and where it has room an expert's extent is
-# rounded up to whole tiles too (`_aligned_layout`), so the next expert
-# starts on a tile and the tile is visited once. The rows of an extent
-# that no assignment landed on are computed and never gathered back; rows
-# past the last extent belong to no expert and are not computed. A
-# mixed step's one walk brings k * (chunk tokens + decode rows) of them,
-# never whole tiles: padding each matmul's operand instead copied the
-# (rows, F) activation once a layer.
+# `_GMM_ROWS` is the row tile of the way in and of the way out, and the
+# unit of the sorted rows' layout: the buffer they are gathered out to is
+# whole row tiles (`_sorted_buffer_rows`), and where it has room an
+# expert's extent is rounded up to whole tiles too (`_aligned_layout`), so
+# the next expert starts on a tile and the tile is visited once. The rows
+# of an extent that no assignment landed on are computed and never
+# gathered back; rows past the last extent belong to no expert and are
+# not computed. A mixed step's one walk brings k * (chunk tokens + decode
+# rows) of them, never whole tiles: padding each matmul's operand instead
+# copied the (rows, F) activation once a layer.
 _GMM_ROWS = 256
 _GMM_WEIGHT_TILE_BYTES = 4 << 20
 
@@ -294,9 +293,42 @@ def _gmm_tiling(k: int, n: int, itemsize: int = 2) -> tuple:
     return (_GMM_ROWS, tk, max(128, min(n, tn)))
 
 
-def _gmm_tilings(cfg: ModelConfig) -> tuple:
-    """The tilings of the way in (D -> F) and of the way out (F -> D)."""
-    return (_gmm_tiling(cfg.embed_dim, cfg.expert_width),
+# The way in's weight tiles, one of `w_gate` and one of `w_up` a step, may
+# be this large each. Placed by the v5e sweep of PERF.md (PR 51), ms a
+# layer at one row tile an expert: at (4,096 x 14,336) 512 columns 4.05,
+# 1,024 (8 MiB) 4.19, 2,048 4.28; at (6,144 x 2,048) 256 columns (3 MiB)
+# 3.88, 512 (6 MiB) 3.81, 1,024 3.82.
+_GATED_WEIGHT_TILE_BYTES = 6 << 20
+
+
+def _gated_tiling(d: int, f: int, n_assignments: int, e: int,
+                  itemsize: int = 2) -> tuple:
+    """(rows, columns, block width) of the way in, x @ w_gate, x @ w_up
+    and the activation as one kernel (`ops/grouped_matmul.py`, under VMEM
+    it sizes from these), for (M, d) rows and `e` experts of (d, f). The
+    row tile spans the contraction; each of the two weight tiles is the
+    widest of `_GATED_WEIGHT_TILE_BYTES` or less that spans it too and
+    divides the columns (an expert's whole matrix at (2,560 x 768)).
+    Where an even router gives every expert one row tile or less the
+    block is the whole width: the visits are the outer loop, a visit's
+    row tile is fetched once and its expert's weights stream past it.
+    Where experts have several row tiles the block is one column tile:
+    the columns are the outer loop, as in megablox, and a weight tile is
+    fetched once for an expert's consecutive row tiles, the rows once a
+    column tile."""
+    tn = max((tn for tn in range(128, f + 1, 128) if f % tn == 0
+              and d * tn * itemsize <= _GATED_WEIGHT_TILE_BYTES), default=f)
+    one_tile = n_assignments <= e * _GMM_ROWS
+    return (_GMM_ROWS, tn, f if one_tile else tn)
+
+
+def _gmm_tilings(cfg: ModelConfig, n_assignments: int) -> tuple:
+    """The tilings of the way in (D -> F, `_gated_tiling`) and of the way
+    out (F -> D, `_gmm_tiling`) for a call of `n_assignments` sorted
+    rows, of which a share's router lands its held experts' part here."""
+    held = n_assignments * cfg.num_experts // cfg.router_width
+    return (_gated_tiling(cfg.embed_dim, cfg.expert_width, held,
+                          cfg.num_experts),
             _gmm_tiling(cfg.expert_width, cfg.embed_dim))
 
 
@@ -324,18 +356,23 @@ def _grouped_matmul(lhs, rhs, group_sizes, tiling, kernel: bool):
                    tiling=(tm, min(tk, k), min(tn, rhs.shape[2])))
 
 
-# jitted so that its trace (three Pallas kernels on the TPU) is cached by
+# jitted so that its trace (two Pallas kernels on the TPU) is cached by
 # shape: every layer of every step program calls it, at a few row counts
 @partial(jax.jit, static_argnames=("kernel", "activation", "tilings"))
 def _grouped_experts(rows, w_gate, w_up, w_down, group_sizes, kernel: bool,
-                     activation: str = "silu", tilings: tuple | None = None):
+                     activation: str, tilings: tuple):
     """Gated experts over rows sorted by expert: (M, D) -> (M, D).
-    `tilings`: `_gmm_tilings`' pair, placed from the widths without one."""
-    t_in, t_out = tilings or (_gmm_tiling(*w_gate.shape[1:]),
-                              _gmm_tiling(*w_down.shape[1:]))
-    gate = _grouped_matmul(rows, w_gate, group_sizes, t_in, kernel)
-    up = _grouped_matmul(rows, w_up, group_sizes, t_in, kernel)
-    act = gated(gate, up, activation)
+    `tilings`: `_gmm_tilings`' pair. On the TPU the way in is one kernel
+    (`gated_grouped_matmul`: no (M, F) product is written before the
+    activation), the way out megablox's; `lax.ragged_dot` elsewhere."""
+    t_in, t_out = tilings
+    if kernel:
+        act = gated_grouped_matmul(rows, w_gate, w_up, group_sizes,
+                                   activation=activation, tiling=t_in)
+    else:
+        act = gated(_grouped_matmul(rows, w_gate, group_sizes, None, False),
+                    _grouped_matmul(rows, w_up, group_sizes, None, False),
+                    activation)
     return _grouped_matmul(act, w_down, group_sizes, t_out, kernel)
 
 
@@ -485,7 +522,7 @@ def _moe_grouped(tokens, router_logits, layers, layer, cfg: ModelConfig,
                                          + layers[name].shape[2:])
                     for name in ("w_gate", "w_up", "w_down")),
             group_sizes, kernel=jax.default_backend() == "tpu",
-            activation=cfg.mlp_activation, tilings=_gmm_tilings(cfg))
+            activation=cfg.mlp_activation, tilings=_gmm_tilings(cfg, t * k))
     with jax.named_scope("moe_combine"):
         # every assignment's row, by sorting the permutation back: a sort
         # of 12,672 pairs cost 8 us on the v5e, the scatter 75
