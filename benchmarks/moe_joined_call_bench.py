@@ -60,13 +60,23 @@ DECODE_ROWS = 64
 def _layers(cfg: ModelConfig, n_layers: int, key):
     d, e, f = cfg.embed_dim, cfg.num_experts, cfg.expert_width
     dt = jnp.dtype(cfg.dtype)
-    ks = jax.random.split(key, 4)
+    ks = jax.random.split(key, 7)
 
     def w(k, shape, fan_in):
         return (jax.random.normal(k, shape, jnp.float32)
                 * fan_in ** -0.5).astype(dt)
 
-    return {"mlp_norm": jnp.ones((n_layers, d), dt),
+    # what the model states of the block beside its routed experts
+    stated = {}
+    if cfg.shared_expert_dim:
+        fs = cfg.shared_expert_dim
+        stated.update(shared_w_gate=w(ks[4], (n_layers, d, fs), d),
+                      shared_w_up=w(ks[5], (n_layers, d, fs), d),
+                      shared_w_down=w(ks[6], (n_layers, fs, d), fs))
+    if cfg.post_norms:
+        stated["mlp_post_norm"] = jnp.ones((n_layers, d), dt)
+    return {**stated,
+            "mlp_norm": jnp.ones((n_layers, d), dt),
             # a share's router has columns for experts held elsewhere
             "router": w(ks[0], (n_layers, d, cfg.router_width), d),
             "router_bias": jnp.zeros((n_layers, cfg.router_width), dt),

@@ -117,8 +117,9 @@ class ModelConfig:
     ssm_conv_width: int = 4
     ssm_chunk: int = 128
     # Scalar multipliers a model is published with (maximal-update
-    # parametrisation), each 1 where a model states none and applied
-    # only by the parallel body: on the embedding and on the logits, on
+    # parametrisation), each 1 where a model states none: on the embedding
+    # (applied by every walk of the paged server and by the expert model's
+    # scans) and, applied only by the parallel body, on the logits, on
     # the attention block's input, its keys and its output, on the
     # mixer's input and output, on the five parts of the mixer's input
     # projection (gate, x, B, C, dt) and on the MLP's gate and output.
@@ -143,6 +144,29 @@ class ModelConfig:
     num_zero_experts: int = 0
     routed_scaling_factor: float = 0.0
     expert_mlp_dim: int = 0
+    # Leading dense layers before the expert layers: the first
+    # `num_dense_layers` layers have a dense MLP of `mlp_dim`, the others
+    # experts of `expert_mlp_dim`, and the parameters are two stacks with
+    # different leaves (`layer_stack`). `shared_expert_dim` > 0: beside
+    # its routed experts an expert layer has one always-on gated MLP of
+    # that width, added ungated.
+    num_dense_layers: int = 0
+    shared_expert_dim: int = 0
+    # The router's score: "softmax" (the renormalised top-k gates) |
+    # "sigmoid": a score an expert, the choice the top k of score + a
+    # balancing bias (a leaf, in the choice only), the gates the kept
+    # scores divided by their sum, times `route_scale`.
+    router_score: str = "softmax"
+    route_scale: float = 1.0
+    # The attention block of a single layer: an RMSNorm a head on q and k
+    # before the rotation (`qk_norm`, one scale vector of `head_dim` a
+    # layer each); the kernel's output times the sigmoid of a projection of
+    # the block's normed input, before the output projection
+    # (`attention_gate`); a norm on each block's output before it is added
+    # to the stream (`post_norms`, behind the attention and behind the MLP).
+    qk_norm: bool = False
+    attention_gate: bool = False
+    post_norms: bool = False
     # rematerialisation policy for the layer scan:
     # "none" | "full" | "dots" | "attn" (save only flash-attention residuals)
     remat: str = "full"
@@ -229,6 +253,32 @@ class ModelConfig:
                 "a router wider than the experts held needs "
                 "routed_scaling_factor > 0 and 2 <= num_experts <= "
                 "num_routed_experts")
+        if self.router_score not in ("softmax", "sigmoid"):
+            raise ValueError(f"unknown router_score: {self.router_score!r}")
+        if self.router_score == "softmax" and self.route_scale != 1.0:
+            raise ValueError(
+                "route_scale is the sigmoid router's: the softmax router's "
+                "kept gates are renormalised and unscaled")
+        if self.router_score == "sigmoid" and (
+                self.num_experts < 2 or self.routed_scaling_factor > 0):
+            raise ValueError(
+                "a sigmoid router chooses among experts that are all held "
+                "here: it needs num_experts >= 2, and no program serves one "
+                "chip's share of it (routed_scaling_factor)")
+        if (self.num_dense_layers or self.shared_expert_dim) and (
+                self.num_experts < 2 or self.routed_scaling_factor > 0
+                or self.layer_body != "single"
+                or not 0 <= self.num_dense_layers < self.num_layers):
+            raise ValueError(
+                "leading dense layers and a shared expert belong to single "
+                "layers of experts that are all held here, with at least "
+                "one expert layer behind the dense ones")
+        if self.layer_body != "single" and (
+                self.qk_norm or self.attention_gate or self.post_norms):
+            raise ValueError(
+                "qk_norm, attention_gate and post_norms are the single "
+                "layer's attention block's: the double layer and the "
+                "parallel body have blocks of their own")
         if self.num_heads % max(self.num_kv_heads, 1) != 0:
             raise ValueError(
                 f"num_heads={self.num_heads} must be a multiple of "
@@ -307,6 +357,14 @@ class ModelConfig:
     @property
     def expert_width(self) -> int:
         return self.expert_mlp_dim or self.mlp_dim
+
+    def layer_stack(self, layer: int) -> tuple:
+        """(name of the stack of parameters `layer` lies in, its index
+        there): the leading dense layers are `lead_layers`, every other
+        layer, and every layer of a model without them, `layers`."""
+        lead = self.num_dense_layers
+        return ("lead_layers", layer) if layer < lead else (
+            "layers", layer - lead)
 
     def layer_pool(self, layer: int) -> tuple:
         """(kind, index of `layer` among the layers of its kind)."""

@@ -111,6 +111,7 @@ def _mlp_apply(x, lp, cfg: ModelConfig, lora=None, stack=None,
 
 
 def init_cache(cfg: ModelConfig, batch: int, max_len: int) -> KVCache:
+    transformer.one_stack(cfg, "the contiguous cache of inference.engine")
     if cfg.has_layer_pattern:
         raise ValueError(
             "the contiguous cache of inference.engine holds one kind of "

@@ -69,6 +69,8 @@ class AdapterSet:
             ) -> int:
         if name in self._ids:
             raise ValueError(f"adapter {name!r} already registered")
+        from cloud_server_tpu.models.transformer import one_stack
+        one_stack(self.model_cfg, "a per-request adapter")
         bad = set(lora_cfg.targets) - set(_DENSE_TARGETS)
         if bad:
             raise ValueError(

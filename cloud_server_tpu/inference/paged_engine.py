@@ -105,10 +105,13 @@ class PagedKVCache(NamedTuple):
     wv: jnp.ndarray | None = None
     wk_scale: jnp.ndarray | None = None
     wv_scale: jnp.ndarray | None = None
-    # (3,) int32, a model whose router is wider than the experts held:
-    # the assignments to held, identity and absent experts of every walk
-    # so far, modulo 2**32. It rides the pools through every program, so
-    # the host reads it with a step's results and takes differences.
+    # int32 running counts of the router's assignments over every walk so
+    # far, modulo 2**32, one for each of `assign_names(cfg)`: a model whose
+    # router is wider than the experts held counts those to held, identity
+    # and absent experts; a model whose router is balanced by a bias counts
+    # them all, and the most any one expert of any layer received in a
+    # walk. It rides the pools through every program, so the host reads it
+    # with a step's results and takes differences.
     assign: jnp.ndarray | None = None
     # A model with a mixer beside its attention (`ModelConfig.ssm_heads`):
     # a kind of its own beside the full kind's pages, one STATE a slot and
@@ -156,6 +159,16 @@ class PagedKVCache(NamedTuple):
                              wv_scale=view.v_scale)
 
 
+def assign_names(cfg: ModelConfig) -> tuple:
+    """What `PagedKVCache.assign` counts for this model, as the flight
+    record names it; empty for a model that keeps no such counts."""
+    if cfg.routed_scaling_factor > 0:
+        return ("assign_held", "assign_zero", "assign_absent")
+    if cfg.router_score == "sigmoid":
+        return ("assign_total", "assign_peak")
+    return ()
+
+
 def window_pages_per_slot(window: int, page_size: int, max_write: int,
                           max_pages_per_slot: int) -> int:
     """The most pages of the window kind one slot can hold: those that
@@ -185,14 +198,15 @@ def init_paged_cache(cfg: ModelConfig, *, num_pages: int, page_size: int,
         raise ValueError(f"unknown kv_cache_dtype: {cfg.kv_cache_dtype!r}")
     int8 = cfg.kv_cache_dtype == "int8"
     dtype = jnp.int8 if int8 else jnp.dtype(cfg.dtype)
+    names = assign_names(cfg)
+    assign = jnp.zeros((len(names),), jnp.int32) if names else None
     if cfg.latent_dim:  # one latent entry a token a block, no values
         return PagedKVCache(
             jnp.zeros((cfg.num_layers * cfg.attention_blocks, num_pages, 1,
                        cfg.latent_dim, page_size), dtype), None,
             jnp.zeros((batch,), jnp.int32),
             jnp.full((batch, max_pages_per_slot), num_pages, jnp.int32),
-            assign=(jnp.zeros((3,), jnp.int32)
-                    if cfg.routed_scaling_factor > 0 else None))
+            assign=assign)
 
     def pools(kind: str, pages: int):
         shape = (kinds.count(kind), pages, cfg.num_kv_heads, cfg.head_dim,
@@ -216,12 +230,13 @@ def init_paged_cache(cfg: ModelConfig, *, num_pages: int, page_size: int,
             conv=tuple(jnp.zeros(c_shape, dtype)
                        for _ in range(cfg.num_layers)))
     if "window" not in kinds:
-        return PagedKVCache(k, v, lengths, tables, ks, vs)
+        return PagedKVCache(k, v, lengths, tables, ks, vs, assign=assign)
     wk, wv, wks, wvs = pools("window", window_num_pages)
     tables = jnp.concatenate(
         [tables, jnp.full((batch, max_pages_per_slot), window_num_pages,
                           jnp.int32)], axis=1)
-    return PagedKVCache(k, v, lengths, tables, ks, vs, wk, wv, wks, wvs)
+    return PagedKVCache(k, v, lengths, tables, ks, vs, wk, wv, wks, wvs,
+                        assign=assign)
 
 
 def quantize_pool(pool: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
@@ -474,9 +489,11 @@ def forward_sets(params, cfg: ModelConfig, cache: PagedKVCache,
     # each layer's kind is static (the walk is unrolled): a model of one
     # kind adds no scope and no argument, and builds the program it built
     two_kinds = cfg.has_window_layers
-    assigned = 0
+    assigned, loads = 0, []
     for layer_idx in range(cfg.num_layers):
-        lp = jax.tree.map(lambda p: p[layer_idx], params["layers"])
+        # the stack the layer lies in, and its place there: static too
+        stack_name, at_stack = cfg.layer_stack(layer_idx)
+        lp = jax.tree.map(lambda p: p[at_stack], params[stack_name])
         if cfg.layer_body == "double_shortcut":
             # the layer's body is the configuration's: a trace-time branch
             x, pools, counts = _double_layer(
@@ -529,9 +546,19 @@ def forward_sets(params, cfg: ModelConfig, cache: PagedKVCache,
             with jax.named_scope("attn"):
                 x = transformer.attention_out(x, _side_by_side(outs), lp,
                                               cfg, lora=ll)
-            x = _mlp_apply(x, lp, cfg, lora=ll,
-                           stack=(params["layers"], layer_idx),
-                           layer_in=x_in)
+            if stack_name == "lead_layers":
+                with jax.named_scope("lead_dense"):
+                    x = transformer.mlp_block(x, lp, cfg, lora=ll)
+            elif cfg.router_score == "sigmoid":
+                # a router balanced by a bias: how even its load is rides
+                # home with the step's results
+                x, aux = moe.moe_mlp_block(
+                    x, lp, cfg, (params[stack_name], at_stack), x_in)
+                loads.append(aux["load"])
+            else:
+                x = _mlp_apply(x, lp, cfg, lora=ll,
+                               stack=(params[stack_name], at_stack),
+                               layer_in=x_in)
 
     def head(xs):  # final-norm'd rows to logits
         out = transformer.unembed(xs, params, cfg)
@@ -568,6 +595,9 @@ def forward_sets(params, cfg: ModelConfig, cache: PagedKVCache,
                 xs = rms_norm(_part(x, sets, i), scale, cfg.norm_eps)
                 logits[i] = head(xs if all_logits else at_rows(xs, s))
     if pools.assign is not None:
+        if loads:  # (expert layers, E): this walk's assignments
+            load = jnp.stack(loads)
+            assigned = jnp.stack([load.sum(), load.max()])
         pools = pools._replace(assign=pools.assign + assigned)
     return logits, pools._replace(lengths=cache.lengths,
                                   tables=cache.tables)

@@ -1422,6 +1422,11 @@ class PagedInferenceServer:
                 "layer) is served without a draft model, whose pools hold "
                 "keys and values by the target's tables, and without a "
                 "mesh, which shards pools over key heads it does not have")
+        if cfg.num_dense_layers and mesh is not None:
+            raise ValueError(
+                "a model with leading dense layers has its parameters in "
+                "two stacks of layers, and serving under a mesh places "
+                "one: not supported for such a model")
         if cfg.ssm_heads and (spec_drafts > 0 or mesh is not None):
             raise ValueError(
                 "a model with a recurrent state a slot is served without "
@@ -1474,8 +1479,9 @@ class PagedInferenceServer:
         self.window_pages_peak_slot = 0
         self._keys_stage: dict = {}
         # the running assignment counts last read back (`_count_assign`)
-        self._assign_seen = (np.zeros((3,), np.uint32)
-                             if cfg.routed_scaling_factor > 0 else None)
+        self._assign_names = paged_engine.assign_names(cfg)
+        self._assign_seen = np.zeros((len(self._assign_names),), np.uint32)
+        self._assign_last = dict.fromkeys(self._assign_names, 0)
         cache = paged_engine.init_paged_cache(
             cfg, num_pages=num_pages, page_size=page_size, batch=max_slots,
             max_pages_per_slot=self.max_pages_per_slot,
@@ -2334,15 +2340,18 @@ class PagedInferenceServer:
             ks["keys_window_decode"] = ks.get("keys_window_decode", 0) + win
 
     def _count_assign(self, stats: dict, assign=None) -> None:
-        """The flight record's `assign_held`, `assign_zero` and
-        `assign_absent`: what the step added to the running counts
-        `assign`, as read back; nothing without them."""
+        """The flight record's assignment counts, by the names the model
+        keeps them under (`paged_engine.assign_names`: `assign_held`,
+        `assign_zero` and `assign_absent` of a routed share;
+        `assign_total` and `assign_peak` of a router balanced by a bias):
+        what the step added to the running counts `assign`, as read back;
+        nothing without them."""
         if assign is not None:
             now = np.asarray(assign).astype(np.uint32)
-            held, zero, absent = (now - self._assign_seen).tolist()
+            self._assign_last = dict(zip(
+                self._assign_names, (now - self._assign_seen).tolist()))
             self._assign_seen = now
-            stats.update(assign_held=held, assign_zero=zero,
-                         assign_absent=absent)
+            stats.update(self._assign_last)
 
     def _take_keys(self) -> dict:
         """The flight record's `keys_full`, `keys_window` and
@@ -4252,6 +4261,20 @@ class PagedInferenceServer:
         reg.counter("window_pages_returned_total",
                     "Window-kind pages given back to their pool"
                     ).set_total(0 if wp is None else wp.pages_returned)
+        # a router balanced by a bias (a sigmoid router): how even its
+        # load was in the newest step read back; always registered (0 for
+        # any other model)
+        # analysis: allow[lock-discipline] racy-by-design monitoring: the
+        # dict is swapped whole by `_count_assign`, never updated in place
+        load = self._assign_last
+        reg.gauge("expert_assign_total",
+                  "Router assignments of the newest step read back, over "
+                  "all expert layers (a model whose router is balanced "
+                  "by a bias)").set(load.get("assign_total", 0))
+        reg.gauge("expert_assign_peak",
+                  "The most assignments any one expert of any layer "
+                  "received in a walk of the newest step read back"
+                  ).set(load.get("assign_peak", 0))
         reg.gauge("cache_namespaces",
                   "Distinct KV namespaces (base model + LoRA "
                   "adapters) that touched the prefix cache").set(

@@ -47,7 +47,24 @@ _STRUCTURAL_FIELDS = frozenset({
     "head_dim", "mlp_dim", "tie_embeddings", "num_experts",
     "rope_theta", "rope_scaling", "rope_scaling_factor",
     "rope_low_freq_factor", "rope_high_freq_factor", "rope_original_max_len",
+    "num_dense_layers", "shared_expert_dim", "router_score", "qk_norm",
+    "attention_gate", "post_norms",
 })
+
+
+def _llama_leaves_only(cfg: ModelConfig) -> None:
+    """Raise for a model with leaves the LLaMA-family state dict has no
+    name for here: the converter would leave them out, or never fill them."""
+    stated = [f for f in ("num_dense_layers", "shared_expert_dim", "qk_norm",
+                          "attention_gate", "post_norms")
+              if getattr(cfg, f)]
+    if cfg.router_score != "softmax":
+        stated.append("router_score")
+    if stated:
+        raise ValueError(
+            "the converter maps the LLaMA family's leaves (one stack of "
+            "layers: two norms, q, k, v, o and a gated MLP); this model "
+            f"states more ({stated}), which it would drop or leave unfilled")
 
 
 def _rope_fields_from_hf(hf_config: Any) -> dict:
@@ -154,6 +171,7 @@ def params_from_hf(state_dict: Mapping[str, Any], cfg: ModelConfig,
     ignorable buffer pattern; leftovers (e.g. attention biases from a
     checkpoint with attention_bias=True) raise instead of being silently
     dropped."""
+    _llama_leaves_only(cfg)
     L, D, H, KH, Dh = (cfg.num_layers, cfg.embed_dim, cfg.num_heads,
                        cfg.num_kv_heads, cfg.head_dim)
     out_dtype = jnp.dtype(dtype or cfg.param_dtype)
@@ -222,6 +240,7 @@ def params_from_hf(state_dict: Mapping[str, Any], cfg: ModelConfig,
 def params_to_hf(params: Mapping[str, Any], cfg: ModelConfig) -> dict:
     """Inverse of `params_from_hf`: our tree -> HF state-dict numpy arrays
     (torch-free; wrap with torch.from_numpy for transformers)."""
+    _llama_leaves_only(cfg)
     L, D, H, KH, Dh = (cfg.num_layers, cfg.embed_dim, cfg.num_heads,
                        cfg.num_kv_heads, cfg.head_dim)
     lp = params["layers"]

@@ -153,6 +153,7 @@ def init_lora_params(model_cfg: ModelConfig, lora_cfg: LoRAConfig,
         raise ValueError(
             f"LoRA targets {sorted(bad)} do not exist for this model "
             f"family (valid here: {sorted(table)})")
+    transformer.one_stack(model_cfg, "a LoRA adapter")
     shapes = base_module.param_shapes(model_cfg)["layers"]
     keys = jax.random.split(rng, len(lora_cfg.targets))
     out: dict[str, Any] = {"layers": {}}

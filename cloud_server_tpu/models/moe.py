@@ -39,6 +39,15 @@ row), the identity experts' term is added beside them (`_share_identity`)
 and the absent experts' terms are left out: the partial sum a chip holds
 before the exchange.
 
+A router balanced by a bias (`cfg.router_score` "sigmoid": Trinity-Mini's
+family): a sigmoid score an expert, the choice the top k of score + bias,
+the gates the kept scores renormalised and scaled (`_sigmoid_gates`); both
+dispatches carry them, and `aux["load"]` is the call's assignments an
+expert. Beside the routed experts a layer may have a shared expert, one
+always-on gated MLP added once in `moe_mlp_block`, and the model leading
+dense layers in a stack of their own before the expert stack
+(`param_shapes`; the scans below run one stack after the other).
+
 Attention/norms/rope are shared with the dense model; only the MLP is
 replaced by the expert layer. Layers are stacked and scanned like
 `models/transformer.py`; the router aux losses ride the scan carry.
@@ -105,7 +114,14 @@ def grouped_min_tokens(cfg: ModelConfig) -> int:
     that land here. (At 64 tokens the sorted dispatch reads 1.29 against
     1.69: 16 assignments reach some ten of the 16 experts and it fetches
     no expert without a row; one threshold cannot say that, and a
-    deployment's exchange brings every expert 32 times the rows.)"""
+    deployment's exchange brings every expert 32 times the rows.)
+
+    The fourth configuration (PERF.md, PR 52): 128 experts of 1,024, 8 a
+    token, beside a shared expert, 1,024 assignments' worth where the fit
+    says 125 tokens. Measured, ms a layer, dense and sorted: 2.26 and 2.35
+    at 64 tokens, 2.29 and 2.41 at 96, 2.58 and 2.46 at 128, 2.70 and 2.49
+    at 160, 2.79 and 2.52 at 192, 3.69 and 2.55 at 256, 4.23 and 2.61 at
+    320. The crossing lies between 96 and 128: the fit holds."""
     assignments = (cfg.num_experts * cfg.num_experts_per_token
                    * cfg.num_experts / cfg.router_width)
     return round(GROUPED_MIN_TOKENS * min(1.0, (16 / assignments) ** 0.2))
@@ -161,6 +177,29 @@ def _top_k_gates(router_logits: jnp.ndarray, k: int):
     gate_vals = gate_vals / jnp.maximum(
         gate_vals.sum(axis=-1, keepdims=True), 1e-9)
     return probs, gate_vals, gate_idx
+
+
+def _sigmoid_gates(router_logits: jnp.ndarray, bias, cfg: ModelConfig):
+    """A sigmoid router (`cfg.router_score`): (T, E) logits -> scores (T,
+    E), a sigmoid an expert; the k chosen experts (T, k), the top of score
+    + `bias` (E,), the balancing bias that moves the choice and never a
+    gate; and their gates (T, k) float32, the kept scores divided by their
+    sum and times `cfg.route_scale`."""
+    scores = jax.nn.sigmoid(router_logits)
+    _, gate_idx = lax.top_k(scores + bias.astype(jnp.float32),
+                            cfg.num_experts_per_token)
+    gate_vals = jnp.take_along_axis(scores, gate_idx, axis=1)
+    gate_vals = gate_vals / (gate_vals.sum(axis=-1, keepdims=True) + 1e-20)
+    return scores, cfg.route_scale * gate_vals, gate_idx
+
+
+def _gates(router_logits: jnp.ndarray, bias, cfg: ModelConfig):
+    """(scores (T, E), gates (T, k), experts (T, k)) of a router over
+    experts that are all held here, by the model's score function; `bias`
+    (E,) is the sigmoid router's, the layer's leaf `router_bias`."""
+    if cfg.router_score == "sigmoid":
+        return _sigmoid_gates(router_logits, bias, cfg)
+    return _top_k_gates(router_logits, cfg.num_experts_per_token)
 
 
 def _share_gates(router_logits: jnp.ndarray, bias, cfg: ModelConfig):
@@ -222,11 +261,18 @@ def top_k_routing(router_logits: jnp.ndarray, k: int, capacity: int):
         router probability.
       aux: dict with load-balance / z-loss ingredients.
     """
-    probs, gate_vals, gate_idx = _top_k_gates(router_logits, k)
+    return _routing(router_logits, *_top_k_gates(router_logits, k), capacity)
+
+
+def _routing(router_logits, probs, gate_vals, gate_idx, capacity: int):
+    """`top_k_routing` from gates already chosen (`_gates`); beside the
+    router's stats `aux` holds `load` (E,) int32, the call's assignments
+    an expert."""
     dispatch, combine, assign, keep = _one_hot_routing(
         gate_vals, gate_idx, router_logits.shape[1], capacity)
     aux = _router_aux(router_logits, probs, assign[:, 0, :],
                       1.0 - keep[:, 0, :].sum() / router_logits.shape[0])
+    aux["load"] = assign.sum(axis=(0, 1)).astype(jnp.int32)
     return dispatch, combine, aux
 
 
@@ -479,7 +525,9 @@ def _moe_grouped(tokens, router_logits, layers, layer, cfg: ModelConfig,
         aux = {}
     else:
         with jax.named_scope("moe_route"):
-            probs, gate_vals, gate_idx = _top_k_gates(router_logits, k)
+            bias = (layers["router_bias"][layer]
+                    if cfg.router_score == "sigmoid" else None)
+            probs, gate_vals, gate_idx = _gates(router_logits, bias, cfg)
             aux = _router_aux(
                 router_logits, probs,
                 jax.nn.one_hot(gate_idx[:, 0], e, dtype=jnp.float32),
@@ -496,6 +544,7 @@ def _moe_grouped(tokens, router_logits, layers, layer, cfg: ModelConfig,
         # of 12,672 ones cost 0.11 ms on the v5e, these a hundredth
         experts = jnp.arange(e, dtype=jnp.int32)
         counts = (expert_of[:, None] == experts).sum(0, dtype=jnp.int32)
+        aux["load"] = counts
         n_rows = _sorted_buffer_rows(t * k, cfg)
         sizes, shift = _aligned_layout(counts, n_rows)
         row_of_rank = ranks + jnp.where(
@@ -588,8 +637,9 @@ def moe_mlp(x: jnp.ndarray, lp: dict, cfg: ModelConfig, stack=None,
                 *chosen, cfg.num_experts, _capacity(cfg, b * s))
             aux = {"assign": assign}
         else:
-            dispatch, combine, aux = top_k_routing(
-                router_logits, cfg.num_experts_per_token,
+            dispatch, combine, aux = _routing(
+                router_logits,
+                *_gates(router_logits, lp.get("router_bias"), cfg),
                 _capacity(cfg, b * s))
 
     # (T, E, C) x (T, D) -> (E, C, D): the all-to-all, inserted by XLA from
@@ -615,27 +665,46 @@ def moe_mlp(x: jnp.ndarray, lp: dict, cfg: ModelConfig, stack=None,
 # ---------------------------------------------------------------------------
 
 def param_shapes(cfg: ModelConfig) -> dict[str, Any]:
+    """The expert model's leaves. `layers` is the stack of expert layers;
+    a model with leading dense layers has them in a stack of their own
+    before it, `lead_layers`, with the dense model's leaves
+    (`cfg.layer_stack`)."""
     shapes = transformer.param_shapes(cfg)
-    L, D, E, F = (cfg.num_layers, cfg.embed_dim, cfg.num_experts, cfg.mlp_dim)
-    layers = shapes["layers"]
-    for k in ("w_gate", "w_up", "w_down"):
-        del layers[k]
+    lead = cfg.num_dense_layers
+    L, D, E, F = (cfg.num_layers - lead, cfg.embed_dim, cfg.num_experts,
+                  cfg.expert_width)
+    layers = transformer.dense_layer_shapes(cfg, L)
     layers["router"] = (L, D, E)
     layers["w_gate"] = (L, E, D, F)
     layers["w_up"] = (L, E, D, F)
     layers["w_down"] = (L, E, F, D)
+    if cfg.router_score == "sigmoid":
+        layers["router_bias"] = (L, E)
+    if cfg.shared_expert_dim:
+        Fs = cfg.shared_expert_dim
+        layers.update(shared_w_gate=(L, D, Fs), shared_w_up=(L, D, Fs),
+                      shared_w_down=(L, Fs, D))
+    shapes["layers"] = layers
+    if lead:
+        shapes["lead_layers"] = transformer.dense_layer_shapes(cfg, lead)
     return shapes
 
 
 def param_logical_axes(cfg: ModelConfig) -> dict[str, Any]:
     axes = transformer.param_logical_axes(cfg)
     layers = axes["layers"]
-    for k in ("w_gate", "w_up", "w_down"):
-        del layers[k]
     layers["router"] = ("layers", "embed", None)
     layers["w_gate"] = ("layers", "experts", "embed", "expert_mlp")
     layers["w_up"] = ("layers", "experts", "embed", "expert_mlp")
     layers["w_down"] = ("layers", "experts", "expert_mlp", "embed")
+    if cfg.router_score == "sigmoid":
+        layers["router_bias"] = ("layers", None)
+    if cfg.shared_expert_dim:
+        layers.update(shared_w_gate=("layers", "embed", "mlp"),
+                      shared_w_up=("layers", "embed", "mlp"),
+                      shared_w_down=("layers", "mlp", "embed"))
+    if cfg.num_dense_layers:
+        axes["lead_layers"] = transformer.dense_layer_axes(cfg)
     return axes
 
 
@@ -648,18 +717,23 @@ def init_params(cfg: ModelConfig, rng: jax.Array) -> Params:
         shapes, is_leaf=lambda x: isinstance(x, tuple))
     keys = jax.random.split(rng, len(paths))
     fan_in = {"router": cfg.embed_dim, "w_gate": cfg.embed_dim,
-              "w_up": cfg.embed_dim, "w_down": cfg.mlp_dim,
+              "w_up": cfg.embed_dim, "shared_w_gate": cfg.embed_dim,
+              "shared_w_up": cfg.embed_dim,
               "tokens": cfg.embed_dim, "kernel": cfg.embed_dim,
               "wq": cfg.embed_dim, "wk": cfg.embed_dim, "wv": cfg.embed_dim,
-              "wo": cfg.num_heads * cfg.head_dim}
+              "wg": cfg.embed_dim, "wo": cfg.num_heads * cfg.head_dim}
     out = []
     for (path, shape), key in zip(paths, keys):
         name = path[-1].key
         path_str = "/".join(p.key for p in path)
         if "norm" in path_str:
             out.append(jnp.ones(shape, dtype))
+        elif name == "router_bias":  # the balancing bias starts at zero
+            out.append(jnp.zeros(shape, dtype))
         else:
-            std = 1.0 / math.sqrt(fan_in[name])
+            # a way out, dense, expert or shared, is (..., width, D)
+            std = 1.0 / math.sqrt(shape[-2] if name.endswith("w_down")
+                                  else fan_in[name])
             out.append((jax.random.truncated_normal(
                 key, -2.0, 2.0, shape, jnp.float32) * std).astype(dtype))
     return jax.tree.unflatten(treedef, out)
@@ -682,6 +756,16 @@ def moe_mlp_block(x, lp, cfg: ModelConfig, stack=None, layer_in=None):
                              "to hand the layer's input to the MLP block")
         router_x = layer_in
     out, aux = moe_mlp(h, lp, cfg, stack, router_x)
+    if cfg.shared_expert_dim:
+        # the always-on expert: once on the normed rows, ungated, whichever
+        # dispatch the routed experts took
+        with jax.named_scope("moe_shared"):
+            act = gated(h @ lp["shared_w_gate"].astype(cfg.dtype),
+                        h @ lp["shared_w_up"].astype(cfg.dtype),
+                        cfg.mlp_activation)
+            out = out + act @ lp["shared_w_down"].astype(cfg.dtype)
+    if cfg.post_norms:
+        out = rms_norm(out, lp["mlp_post_norm"], cfg.norm_eps)
     return x + out, aux
 
 
@@ -714,6 +798,8 @@ def forward_hidden(params: Params, tokens: jnp.ndarray, cfg: ModelConfig,
     table = transformer.constrain(params["embed"]["tokens"].astype(cfg.dtype),
                       ("vocab", None))
     x = table[tokens]
+    if cfg.embedding_multiplier != 1.0:
+        x = x * cfg.embedding_multiplier
     x = transformer.constrain(x, ("batch", "sequence", None))
     positions = None
     if segment_ids is not None:
@@ -727,6 +813,21 @@ def forward_hidden(params: Params, tokens: jnp.ndarray, cfg: ModelConfig,
     block = transformer.apply_remat(block, cfg)
 
     flags = transformer.layer_flags(cfg)
+    lead = cfg.num_dense_layers
+    if lead:
+        # the leading dense layers: a scan of their own stack, the dense
+        # model's block, before the scan over the expert layers
+        dense = transformer.apply_remat(
+            partial(transformer._block, cfg=cfg, cos=cos, sin=sin,
+                    attn_fn=attn_fn, positions=positions), cfg)
+        x, _ = lax.scan(
+            lambda c, xs: ((dense(c, xs) if flags is None
+                            else dense(c, xs[0], flags=xs[1])), None),
+            x, params["lead_layers"] if flags is None else (
+                params["lead_layers"],
+                jax.tree.map(lambda f: f[:lead], flags)))
+        if flags is not None:
+            flags = jax.tree.map(lambda f: f[lead:], flags)
 
     def scan_body(carry, xs):
         x, lb, rz, dropped = carry
@@ -740,7 +841,7 @@ def forward_hidden(params: Params, tokens: jnp.ndarray, cfg: ModelConfig,
         scan_body, (x, zero, zero, zero),
         params["layers"] if flags is None else (params["layers"], flags))
     x = rms_norm(x, params["final_norm"]["scale"], cfg.norm_eps)
-    n = cfg.num_layers
+    n = cfg.num_layers - lead
     aux = {"load_balance": lb / n, "router_z": rz / n, "dropped_frac": dropped / n}
     return x, aux
 

@@ -39,11 +39,14 @@ import jax.numpy as jnp
 # per-output-channel (and per-layer, per-expert) everywhere else.
 _REDUCE_AXES: dict[tuple[str, int], tuple[int, ...]] = {
     # dense attention (L, D, H|KH, Dh): contract D
-    ("wq", 4): (1,), ("wk", 4): (1,), ("wv", 4): (1,),
+    ("wq", 4): (1,), ("wk", 4): (1,), ("wv", 4): (1,), ("wg", 4): (1,),
     # attention out (L, H, Dh, D): contract H, Dh
     ("wo", 4): (1, 2),
     # dense MLP (L, D, F) / (L, F, D): contract axis 1
     ("w_gate", 3): (1,), ("w_up", 3): (1,), ("w_down", 3): (1,),
+    # an expert layer's shared expert, a dense MLP beside the routed ones
+    ("shared_w_gate", 3): (1,), ("shared_w_up", 3): (1,),
+    ("shared_w_down", 3): (1,),
     # MoE experts (L, E, D, F) / (L, E, F, D): contract axis 2
     ("w_gate", 4): (2,), ("w_up", 4): (2,), ("w_down", 4): (2,),
     # untied lm_head (D, V): contract D

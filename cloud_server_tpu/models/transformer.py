@@ -36,23 +36,37 @@ NEG_INF = -1e30  # finite stand-in for -inf (keeps exp/where NaN-free)
 # Init
 # ---------------------------------------------------------------------------
 
+def dense_layer_shapes(cfg: ModelConfig, n_layers: int) -> dict[str, Any]:
+    """The leaves of a stack of `n_layers` dense layers: the attention
+    block's, with what the model states of it beside q, k, v and o, and a
+    dense MLP of `mlp_dim`."""
+    L, D, H, KH, Dh, F = (n_layers, cfg.embed_dim, cfg.num_heads,
+                          cfg.num_kv_heads, cfg.head_dim, cfg.mlp_dim)
+    shapes = {
+        "attn_norm": (L, D),
+        "mlp_norm": (L, D),
+        "wq": (L, D, H, Dh),
+        "wk": (L, D, KH, Dh),
+        "wv": (L, D, KH, Dh),
+        "wo": (L, H, Dh, D),
+        "w_gate": (L, D, F),
+        "w_up": (L, D, F),
+        "w_down": (L, F, D),
+    }
+    if cfg.qk_norm:
+        shapes.update(q_norm=(L, Dh), k_norm=(L, Dh))
+    if cfg.attention_gate:
+        shapes["wg"] = (L, D, H, Dh)
+    if cfg.post_norms:
+        shapes.update(attn_post_norm=(L, D), mlp_post_norm=(L, D))
+    return shapes
+
+
 def param_shapes(cfg: ModelConfig) -> dict[str, Any]:
-    L, D, H, KH, Dh, F, V = (cfg.num_layers, cfg.embed_dim, cfg.num_heads,
-                             cfg.num_kv_heads, cfg.head_dim, cfg.mlp_dim,
-                             cfg.vocab_size)
+    D, V = cfg.embed_dim, cfg.vocab_size
     shapes = {
         "embed": {"tokens": (V, D)},
-        "layers": {
-            "attn_norm": (L, D),
-            "mlp_norm": (L, D),
-            "wq": (L, D, H, Dh),
-            "wk": (L, D, KH, Dh),
-            "wv": (L, D, KH, Dh),
-            "wo": (L, H, Dh, D),
-            "w_gate": (L, D, F),
-            "w_up": (L, D, F),
-            "w_down": (L, F, D),
-        },
+        "layers": dense_layer_shapes(cfg, cfg.num_layers),
         "final_norm": {"scale": (D,)},
     }
     if not cfg.tie_embeddings:
@@ -60,21 +74,34 @@ def param_shapes(cfg: ModelConfig) -> dict[str, Any]:
     return shapes
 
 
+def dense_layer_axes(cfg: ModelConfig) -> dict[str, Any]:
+    """`dense_layer_shapes`' leaves as tuples of logical axis names."""
+    axes = {
+        "attn_norm": ("layers", "norm"),
+        "mlp_norm": ("layers", "norm"),
+        "wq": ("layers", "embed", "heads", "head_dim"),
+        "wk": ("layers", "embed", "kv_heads", "head_dim"),
+        "wv": ("layers", "embed", "kv_heads", "head_dim"),
+        "wo": ("layers", "heads", "head_dim", "embed"),
+        "w_gate": ("layers", "embed", "mlp"),
+        "w_up": ("layers", "embed", "mlp"),
+        "w_down": ("layers", "mlp", "embed"),
+    }
+    if cfg.qk_norm:
+        axes.update(q_norm=("layers", "norm"), k_norm=("layers", "norm"))
+    if cfg.attention_gate:
+        axes["wg"] = ("layers", "embed", "heads", "head_dim")
+    if cfg.post_norms:
+        axes.update(attn_post_norm=("layers", "norm"),
+                    mlp_post_norm=("layers", "norm"))
+    return axes
+
+
 def param_logical_axes(cfg: ModelConfig) -> dict[str, Any]:
     """Same structure as params; leaves are tuples of logical axis names."""
     axes = {
         "embed": {"tokens": ("vocab", "embed")},
-        "layers": {
-            "attn_norm": ("layers", "norm"),
-            "mlp_norm": ("layers", "norm"),
-            "wq": ("layers", "embed", "heads", "head_dim"),
-            "wk": ("layers", "embed", "kv_heads", "head_dim"),
-            "wv": ("layers", "embed", "kv_heads", "head_dim"),
-            "wo": ("layers", "heads", "head_dim", "embed"),
-            "w_gate": ("layers", "embed", "mlp"),
-            "w_up": ("layers", "embed", "mlp"),
-            "w_down": ("layers", "mlp", "embed"),
-        },
+        "layers": dense_layer_axes(cfg),
         "final_norm": {"scale": ("norm",)},
     }
     if not cfg.tie_embeddings:
@@ -82,10 +109,21 @@ def param_logical_axes(cfg: ModelConfig) -> dict[str, Any]:
     return axes
 
 
+def one_stack(cfg: ModelConfig, what: str) -> None:
+    """Raise for `what`, a walker of `params["layers"]` alone, on a model
+    whose layers lie in two stacks (`cfg.layer_stack`)."""
+    if cfg.num_dense_layers:
+        raise ValueError(
+            f"{what} walks one stack of layers (params['layers']) and a "
+            "model with leading dense layers has them in a stack of their "
+            "own before it (params['lead_layers']): not supported for such "
+            "a model")
+
+
 def _fan_in(name: str, cfg: ModelConfig) -> int:
     D, H, KH, Dh, F = (cfg.embed_dim, cfg.num_heads, cfg.num_kv_heads,
                        cfg.head_dim, cfg.mlp_dim)
-    table = {"tokens": D, "kernel": D, "wq": D, "wk": D, "wv": D,
+    table = {"tokens": D, "kernel": D, "wq": D, "wk": D, "wv": D, "wg": D,
              "wo": H * Dh, "w_gate": D, "w_up": D, "w_down": F}
     return table[name]
 
@@ -156,6 +194,9 @@ def attention_qkv(x, lp, cfg: ModelConfig, cos, sin, positions=None,
             k = k + lora_row_delta(h, lora["wk"]).reshape(k.shape)
         if "wv" in lora:
             v = v + lora_row_delta(h, lora["wv"]).reshape(v.shape)
+    if cfg.qk_norm:  # a head, over its channels, before the rotation
+        q = rms_norm(q, lp["q_norm"], cfg.norm_eps)
+        k = rms_norm(k, lp["k_norm"], cfg.norm_eps)
     if rope is False:
         return q, k, v
     qr = apply_rope(q, cos, sin, positions)
@@ -166,11 +207,23 @@ def attention_qkv(x, lp, cfg: ModelConfig, cos, sin, positions=None,
 
 
 def attention_out(x, o, lp, cfg: ModelConfig, lora=None):
-    """Output projection + residual add (the attention block's second half)."""
+    """Output projection + residual add (the attention block's second
+    half). `x` is the block's input: under `cfg.attention_gate` its norm,
+    the one q, k and v were projected from, is projected once more and the
+    kernel's output `o` multiplied by that gate's sigmoid before `wo`;
+    under `cfg.post_norms` the block's output is normed before the add."""
+    if cfg.attention_gate:
+        with jax.named_scope("attn_gate"):
+            h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
+            g = jnp.einsum("bsd,dhk->bshk", h, lp["wg"].astype(cfg.dtype))
+            o = (o.astype(jnp.float32)
+                 * jax.nn.sigmoid(g.astype(jnp.float32))).astype(o.dtype)
     y = jnp.einsum("bshk,hkd->bsd", o, lp["wo"].astype(cfg.dtype))
     if lora and "wo" in lora:
         b_, s_ = o.shape[:2]
         y = y + lora_row_delta(o.reshape(b_, s_, -1), lora["wo"])
+    if cfg.post_norms:
+        y = rms_norm(y, lp["attn_post_norm"], cfg.norm_eps)
     return x + y
 
 
@@ -217,6 +270,8 @@ def mlp_block(x, lp, cfg: ModelConfig, lora=None):
     down = jnp.einsum("bsf,fd->bsd", act, lp["w_down"].astype(cfg.dtype))
     if lora and "w_down" in lora:
         down = down + lora_row_delta(act, lora["w_down"])
+    if cfg.post_norms:
+        down = rms_norm(down, lp["mlp_post_norm"], cfg.norm_eps)
     return x + down
 
 

@@ -196,6 +196,7 @@ def make_pipelined_hidden(model_cfg, mesh: Mesh, num_microbatches: int,
 
     rules = rules or DEFAULT_RULES
     pp = mesh.shape["pp"]
+    transformer.one_stack(model_cfg, "the pipelined stack")
     if model_cfg.num_layers % pp:
         raise ValueError(f"num_layers={model_cfg.num_layers} not divisible "
                          f"by pp={pp}")

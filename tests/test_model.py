@@ -137,3 +137,57 @@ def test_fused_ce_matches_dense():
     for a, b in zip(flat_f, flat_d):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    atol=1e-5, rtol=1e-4)
+
+
+# -- what a model states of its blocks beside q, k, v, o and a gated MLP ----
+
+def test_stated_leaves_are_declared_drawn_and_counted():
+    """`qk_norm`, `attention_gate` and `post_norms` on the dense model, and
+    the expert model's two stacks: every leaf declared is drawn, has its
+    logical axes, and the count is the widths' arithmetic."""
+    from cloud_server_tpu.models import moe
+    dense = ModelConfig(**{**TINY.__dict__, "qk_norm": True,
+                           "attention_gate": True, "post_norms": True})
+    params = transformer.init_params(dense, jax.random.key(0))
+    assert jax.tree.map(lambda x: tuple(x.shape),
+                        params) == transformer.param_shapes(dense)
+    extra = {"q_norm": (2, 8), "k_norm": (2, 8), "wg": (2, 32, 4, 8),
+             "attn_post_norm": (2, 32), "mlp_post_norm": (2, 32)}
+    assert {k: params["layers"][k].shape for k in extra} == extra
+    assert set(transformer.param_shapes(TINY)["layers"]) == set(
+        params["layers"]) - set(extra)
+    for name in ("q_norm", "k_norm", "attn_post_norm", "mlp_post_norm"):
+        assert (np.asarray(params["layers"][name]) == 1).all()
+    two = ModelConfig(**{**dense.__dict__, "num_layers": 5,
+                         "num_dense_layers": 2, "num_experts": 4,
+                         "num_experts_per_token": 2, "expert_mlp_dim": 16,
+                         "shared_expert_dim": 24,
+                         # room for every row: a later token drops none
+                         "expert_capacity_factor": 2.0,
+                         "router_score": "sigmoid", "route_scale": 2.0})
+    shapes = moe.param_shapes(two)
+    params = moe.init_params(two, jax.random.key(1))
+    assert jax.tree.map(lambda x: tuple(x.shape), params) == shapes
+    axes = moe.param_logical_axes(two)
+    is_axes = lambda x: isinstance(x, tuple) and all(  # noqa: E731
+        isinstance(i, (str, type(None))) for i in x)
+    assert jax.tree.map(len, axes, is_leaf=is_axes) == jax.tree.map(
+        len, shapes, is_leaf=lambda x: isinstance(x, tuple))
+    d, h, kh, dh = 32, 4, 2, 8
+    attention = 2 * d * h * dh + d * h * dh + 2 * d * kh * dh + 4 * d + 2 * dh
+    lead = attention + 3 * d * 64
+    layer = (attention + 4 * 3 * d * 16 + 3 * d * 24 + d * 4 + 4)
+    count = lambda t: sum(x.size for x in jax.tree.leaves(t))  # noqa: E731
+    assert count(params["lead_layers"]) == 2 * lead
+    assert count(params["layers"]) == 3 * layer
+    assert shapes["lead_layers"]["w_gate"] == (2, 32, 64)
+    assert shapes["layers"]["w_gate"] == (3, 4, 32, 16)
+    assert (np.asarray(params["layers"]["router_bias"]) == 0).all()
+    # the scans over both stacks: a forward, causal like any other
+    tokens = jax.random.randint(jax.random.key(2), (1, 16), 0, 64)
+    base, aux = moe.forward(params, tokens, two)
+    assert base.shape == (1, 16, 64) and np.isfinite(np.asarray(base)).all()
+    pert, _ = moe.forward(params, tokens.at[0, -1].add(1) % 64, two)
+    np.testing.assert_allclose(np.asarray(base[0, :-1]),
+                               np.asarray(pert[0, :-1]), atol=1e-5)
+    assert set(aux) == {"load_balance", "router_z", "dropped_frac"}

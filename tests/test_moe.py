@@ -569,3 +569,62 @@ def test_compiled_on_tpu_grouped_dispatch(monkeypatch):
     np.testing.assert_allclose(
         np.asarray(got, np.float32), want, rtol=2.0 ** -6,
         atol=2.0 ** -6 * np.abs(want).max())
+
+
+# -- a sigmoid router balanced by a bias ------------------------------------
+
+SIGMOID = ModelConfig(**{**MOE_TINY.__dict__, "router_score": "sigmoid",
+                         "route_scale": 2.5, "num_experts": 4,
+                         "num_experts_per_token": 2})
+
+
+@pytest.mark.parametrize("scale", [1.0, 2.5])
+def test_sigmoid_gates_are_the_hand_computation(scale):
+    """A score an expert; the choice is the top k of score + bias; the
+    gates are the kept scores, renormalised and scaled, and never hold the
+    bias."""
+    cfg = ModelConfig(**{**SIGMOID.__dict__, "route_scale": scale})
+    logits = jnp.asarray([[2.0, 1.0, 0.0, -1.0],
+                          [0.5, 0.4, 0.3, 0.2]])
+    bias = jnp.asarray([0.0, 0.0, 0.0, 0.9])
+    scores, gates, chosen = moe._sigmoid_gates(logits, bias, cfg)
+    s = 1 / (1 + np.exp(-np.asarray(logits, np.float64)))
+    np.testing.assert_allclose(scores, s, rtol=1e-6)
+    # token 0: expert 3's score 0.269 + 0.9 passes every other; token 1:
+    # 0.550 + 0.9 too. Without the bias neither would choose it
+    np.testing.assert_array_equal(np.asarray(chosen), [[3, 0], [3, 0]])
+    assert not (np.argsort(-s, 1)[:, :2] == 3).any()
+    kept = np.take_along_axis(s, np.asarray(chosen), 1)
+    np.testing.assert_allclose(
+        gates, scale * kept / kept.sum(1, keepdims=True), rtol=1e-6)
+    np.testing.assert_allclose(np.asarray(gates).sum(1), scale, rtol=1e-6)
+    # a larger bias moves no gate while the choice stays
+    _, again, same = moe._sigmoid_gates(logits, 3.0 * bias, cfg)
+    np.testing.assert_array_equal(np.asarray(same), np.asarray(chosen))
+    np.testing.assert_array_equal(np.asarray(again), np.asarray(gates))
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_both_dispatches_carry_the_sigmoid_gates(monkeypatch, dtype):
+    """The sorted and the one-hot dispatch of a sigmoid router: the same
+    output, the same load an expert, and the softmax router's aux keys."""
+    cfg = ModelConfig(**{**SIGMOID.__dict__, "dtype": dtype,
+                         "param_dtype": dtype, "num_experts": 8,
+                         "num_experts_per_token": 3, "num_layers": LAYERS,
+                         "expert_capacity_factor": 8 / 3})
+    layers = jax.tree.map(
+        lambda p: p.astype(dtype),
+        moe.init_params(cfg, jax.random.key(2))["layers"])
+    layers["router_bias"] = (0.2 * jax.random.normal(
+        jax.random.key(3), layers["router_bias"].shape)).astype(dtype)
+    x = jax.random.normal(jax.random.key(4), (2, 96, cfg.embed_dim)).astype(
+        dtype)
+    (out_g, aux_g), (out_d, aux_d) = _both_dispatches(
+        monkeypatch, x, layers, cfg)
+    np.testing.assert_array_equal(np.asarray(aux_g["load"]),
+                                  np.asarray(aux_d["load"]))
+    assert int(aux_g["load"].sum()) == 192 * 3
+    assert float(aux_d["dropped_frac"]) == float(aux_g["dropped_frac"]) == 0
+    got, want = np.asarray(out_g, np.float32), np.asarray(out_d, np.float32)
+    tol = 1e-5 if dtype == "float32" else 2.0 ** -6 * np.abs(want).max()
+    np.testing.assert_allclose(got, want, atol=tol, rtol=tol)
