@@ -28,6 +28,12 @@ visit (PERF.md, PR 39), so the next writer reads the fit. The router
 of the bench is even; `--skew 1.5` gives it the second cell's deeper
 layers' (a third of the experts near empty, a few with most rows), where
 the visits are the experts that have rows and a layout moves little.
+Since PR 53 a visit computes only the sub-tiles its expert has a row in:
+`rows_computed_ratio` is the rows the way in computes (each expert's
+count rounded up to the kernel's sub-tiles) over the call's assignments,
+the cells' `expert_rows_computed_ratio` for one call; `--sub-rows`
+measures the `--sorted` calls again under other sub-tiles (256, the row
+tile, is a visit that computes its whole tile).
 
 Prints one JSON line per measurement and writes them to
 `chiprun_out/moe_joined_call_bench.jsonl`.
@@ -53,6 +59,7 @@ if ROOT not in sys.path:
 
 from cloud_server_tpu.config import ModelConfig  # noqa: E402
 from cloud_server_tpu.models import moe  # noqa: E402
+from cloud_server_tpu.ops import grouped_matmul  # noqa: E402
 
 DECODE_ROWS = 64
 
@@ -121,9 +128,10 @@ def _sorted_call(fn, args) -> dict:
     seen = []
     real = moe._grouped_experts
 
-    def spy(rows, w_gate, w_up, w_down, group_sizes, **kw):
-        seen.append((rows.shape[0], group_sizes))
-        return real(rows, w_gate, w_up, w_down, group_sizes, **kw)
+    def spy(rows, w_gate, w_up, w_down, group_sizes, group_rows, **kw):
+        seen.append((rows.shape[0], (group_sizes, group_rows)))
+        return real(rows, w_gate, w_up, w_down, group_sizes, group_rows,
+                    **kw)
 
     def sizes(*xs):
         fn(*xs)
@@ -131,17 +139,21 @@ def _sorted_call(fn, args) -> dict:
 
     moe._grouped_experts = spy
     try:
-        group_sizes = jax.jit(sizes)(*args)
+        groups = jax.jit(sizes)(*args)
     finally:
         moe._grouped_experts = real
-    if group_sizes is None:
+    if groups is None:
         return {}
+    group_sizes, group_rows = groups
     _, visits = make_group_metadata(
         group_sizes=group_sizes, m=seen[-1][0], tm=moe._GMM_ROWS,
         start_group=0, num_nonzero_groups=group_sizes.shape[0],
         visit_empty_groups=False)
     return {"visits": int(visits), "buffer_rows": seen[-1][0],
-            "computed_rows": int(group_sizes.sum())}
+            "extent_rows": int(group_sizes.sum()),
+            "rows_computed_ratio": round(
+                int(grouped_matmul.rows_computed(group_rows))
+                / int(group_rows.sum()), 4)}
 
 
 def main() -> None:
@@ -162,6 +174,9 @@ def main() -> None:
     ap.add_argument("--sorted", default="",
                     help="the T at which the sorted dispatch alone is "
                          "measured")
+    ap.add_argument("--sub-rows", default="",
+                    help="sub-tiles of the way in to measure the --sorted "
+                         "calls under, beside the placed one")
     ap.add_argument("--tile-tokens", default="320,576,1088,2112",
                     help="the T of the tile sweep's calls")
     ap.add_argument("--skew", type=float, default=0.0,
@@ -191,6 +206,7 @@ def main() -> None:
                       "router_input": cfg.router_input,
                       "skew": a.skew,
                       "placed_min_tokens": moe.grouped_min_tokens(cfg),
+                      "placed_sub_rows": grouped_matmul.SUB_ROWS,
                       "placed_tilings": {
                           t: moe._gmm_tilings(
                               cfg, t * cfg.num_experts_per_token)
@@ -258,10 +274,19 @@ def main() -> None:
             measure(f"{name}_{t}", one, (x_of(t, 4), layers), tokens=t)
     moe.grouped_min_tokens = placed
 
-    # the sorted dispatch alone, as placed, at the calls a step makes
+    # the sorted dispatch alone, as placed, at the calls a step makes;
+    # then under other sub-tiles of the way in (the kernel reads the
+    # constant when it is traced: the traces are given back between them)
     moe.grouped_min_tokens = lambda cfg: 1
-    for t in [int(t) for t in a.sorted.split(",") if t]:
-        measure(f"sorted_{t}", one, (x_of(t, 4), layers), tokens=t)
+    placed_sub = grouped_matmul.SUB_ROWS
+    for sub in [placed_sub] + [int(r) for r in a.sub_rows.split(",") if r]:
+        grouped_matmul.SUB_ROWS = sub
+        jax.clear_caches()
+        for t in [int(t) for t in a.sorted.split(",") if t]:
+            measure(f"sorted_{t}", one, (x_of(t, 4), layers), tokens=t,
+                    sub_rows=sub)
+    grouped_matmul.SUB_ROWS = placed_sub
+    jax.clear_caches()
     moe.grouped_min_tokens = placed
 
     if a.tiles:

@@ -75,7 +75,7 @@ from cloud_server_tpu.config import ModelConfig
 from cloud_server_tpu.inference import multi_lora
 from cloud_server_tpu.inference.engine import _kv_quant, _mlp_apply
 from cloud_server_tpu.models import latent, mixer, moe, transformer
-from cloud_server_tpu.ops import rms_norm, rope_table
+from cloud_server_tpu.ops import grouped_matmul, rms_norm, rope_table
 from cloud_server_tpu.ops.paged_attention import (
     paged_attention, paged_attention_tp, paged_attention_xla)
 
@@ -109,9 +109,11 @@ class PagedKVCache(NamedTuple):
     # far, modulo 2**32, one for each of `assign_names(cfg)`: a model whose
     # router is wider than the experts held counts those to held, identity
     # and absent experts; a model whose router is balanced by a bias counts
-    # them all, and the most any one expert of any layer received in a
-    # walk. It rides the pools through every program, so the host reads it
-    # with a step's results and takes differences.
+    # them all, the most any one expert of any layer received in a walk,
+    # and the rows the sorted dispatch's way in computes for them (each
+    # expert's count rounded up to the kernel's sub-tiles). It rides the
+    # pools through every program, so the host reads it with a step's
+    # results and takes differences.
     assign: jnp.ndarray | None = None
     # A model with a mixer beside its attention (`ModelConfig.ssm_heads`):
     # a kind of its own beside the full kind's pages, one STATE a slot and
@@ -165,7 +167,7 @@ def assign_names(cfg: ModelConfig) -> tuple:
     if cfg.routed_scaling_factor > 0:
         return ("assign_held", "assign_zero", "assign_absent")
     if cfg.router_score == "sigmoid":
-        return ("assign_total", "assign_peak")
+        return ("assign_total", "assign_peak", "assign_rows_computed")
     return ()
 
 
@@ -597,7 +599,8 @@ def forward_sets(params, cfg: ModelConfig, cache: PagedKVCache,
     if pools.assign is not None:
         if loads:  # (expert layers, E): this walk's assignments
             load = jnp.stack(loads)
-            assigned = jnp.stack([load.sum(), load.max()])
+            assigned = jnp.stack([load.sum(), load.max(),
+                                  grouped_matmul.rows_computed(load)])
         pools = pools._replace(assign=pools.assign + assigned)
     return logits, pools._replace(lengths=cache.lengths,
                                   tables=cache.tables)

@@ -2343,7 +2343,8 @@ class PagedInferenceServer:
         """The flight record's assignment counts, by the names the model
         keeps them under (`paged_engine.assign_names`: `assign_held`,
         `assign_zero` and `assign_absent` of a routed share;
-        `assign_total` and `assign_peak` of a router balanced by a bias):
+        `assign_total`, `assign_peak` and `assign_rows_computed` of a
+        router balanced by a bias):
         what the step added to the running counts `assign`, as read back;
         nothing without them."""
         if assign is not None:
@@ -4275,6 +4276,11 @@ class PagedInferenceServer:
                   "The most assignments any one expert of any layer "
                   "received in a walk of the newest step read back"
                   ).set(load.get("assign_peak", 0))
+        reg.gauge("expert_assign_rows_computed",
+                  "Rows the sorted dispatch's way in computes for the "
+                  "newest step's assignments: each expert's count rounded "
+                  "up to the kernel's sub-tiles, over all expert layers"
+                  ).set(load.get("assign_rows_computed", 0))
         reg.gauge("cache_namespaces",
                   "Distinct KV namespaces (base model + LoRA "
                   "adapters) that touched the prefix cache").set(

@@ -323,11 +323,16 @@ def _one_hot_routing(gate_vals, gate_idx, e: int, capacity: int):
 # whole row tiles (`_sorted_buffer_rows`), and where it has room an
 # expert's extent is rounded up to whole tiles too (`_aligned_layout`), so
 # the next expert starts on a tile and the tile is visited once. The rows
-# of an extent that no assignment landed on are computed and never
-# gathered back; rows past the last extent belong to no expert and are
-# not computed. A mixed step's one walk brings k * (chunk tokens + decode
-# rows) of them, never whole tiles: padding each matmul's operand instead
-# copied the (rows, F) activation once a layer.
+# of an extent that no assignment landed on are never gathered back
+# (`row_of` names only rows an assignment landed on): the way in is told
+# each expert's real rows beside its extent and computes, of a visited
+# tile, only the sub-tiles that hold one (`ops/grouped_matmul.py`,
+# `SUB_ROWS`); what stands in the others is not defined and the way out,
+# megablox's, multiplies it row by row into rows as little read. Rows past
+# the last extent belong to no expert and are not computed. A mixed step's
+# one walk brings k * (chunk tokens + decode rows) of them, never whole
+# tiles: padding each matmul's operand instead copied the (rows, F)
+# activation once a layer.
 _GMM_ROWS = 256
 _GMM_WEIGHT_TILE_BYTES = 4 << 20
 
@@ -405,16 +410,22 @@ def _grouped_matmul(lhs, rhs, group_sizes, tiling, kernel: bool):
 # jitted so that its trace (two Pallas kernels on the TPU) is cached by
 # shape: every layer of every step program calls it, at a few row counts
 @partial(jax.jit, static_argnames=("kernel", "activation", "tilings"))
-def _grouped_experts(rows, w_gate, w_up, w_down, group_sizes, kernel: bool,
-                     activation: str, tilings: tuple):
+def _grouped_experts(rows, w_gate, w_up, w_down, group_sizes, group_rows,
+                     kernel: bool, activation: str, tilings: tuple):
     """Gated experts over rows sorted by expert: (M, D) -> (M, D).
+    `group_sizes` are the experts' extents in the buffer, `group_rows` the
+    rows at the head of each that an assignment landed on
+    (`_aligned_layout`); the rest of an extent is defined in no result.
     `tilings`: `_gmm_tilings`' pair. On the TPU the way in is one kernel
     (`gated_grouped_matmul`: no (M, F) product is written before the
-    activation), the way out megablox's; `lax.ragged_dot` elsewhere."""
+    activation, and of a row tile only the sub-tiles with a real row are
+    computed), the way out megablox's, which multiplies an extent's every
+    row; `lax.ragged_dot` elsewhere."""
     t_in, t_out = tilings
     if kernel:
         act = gated_grouped_matmul(rows, w_gate, w_up, group_sizes,
-                                   activation=activation, tiling=t_in)
+                                   group_rows, activation=activation,
+                                   tiling=t_in)
     else:
         act = gated(_grouped_matmul(rows, w_gate, group_sizes, None, False),
                     _grouped_matmul(rows, w_up, group_sizes, None, False),
@@ -469,7 +480,9 @@ def _aligned_layout(counts, n_rows: int):
     made room and the router is near even; an expert without rows stays
     0), and how far an expert's rows lie behind their place in the packed
     order: an expert whose predecessors are all padded starts on a row
-    tile, and no tile of its extent is computed for another expert."""
+    tile, and no tile of its extent is computed for another expert. The
+    expert's `counts` rows lead its extent: what the kernel is handed
+    beside `sizes` as the rows that are real."""
     padded = -(-counts // _GMM_ROWS) * _GMM_ROWS
     room = n_rows - counts.sum()
     sizes = jnp.where(jnp.cumsum(padded - counts) <= room, padded, counts)
@@ -562,15 +575,17 @@ def _moe_grouped(tokens, router_logits, layers, layer, cfg: ModelConfig,
         rows = tokens[src]
         # the stack seen as L * E groups, every other layer's empty: the
         # kernel then reads this layer's experts where they lie
-        group_sizes = lax.dynamic_update_slice(
-            jnp.zeros((n_layers * e,), jnp.int32), sizes,
-            (jnp.asarray(layer, jnp.int32) * e,))
+        group_sizes, group_rows = (
+            lax.dynamic_update_slice(
+                jnp.zeros((n_layers * e,), jnp.int32), of_layer,
+                (jnp.asarray(layer, jnp.int32) * e,))
+            for of_layer in (sizes, counts))
     with jax.named_scope("moe_experts"), jax.named_scope("grouped"):
         ys = _grouped_experts(
             rows, *(layers[name].reshape((n_layers * e,)
                                          + layers[name].shape[2:])
                     for name in ("w_gate", "w_up", "w_down")),
-            group_sizes, kernel=jax.default_backend() == "tpu",
+            group_sizes, group_rows, kernel=jax.default_backend() == "tpu",
             activation=cfg.mlp_activation, tilings=_gmm_tilings(cfg, t * k))
     with jax.named_scope("moe_combine"):
         # every assignment's row, by sorting the permutation back: a sort

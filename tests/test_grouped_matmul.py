@@ -15,7 +15,7 @@ import pytest
 
 from cloud_server_tpu.config import InferConfig, ModelConfig
 from cloud_server_tpu.models import moe
-from cloud_server_tpu.ops import gated
+from cloud_server_tpu.ops import gated, grouped_matmul
 from cloud_server_tpu.ops.grouped_matmul import (
     _vmem_bytes, gated_grouped_matmul)
 
@@ -31,29 +31,51 @@ def _give_programs_back():
 
 
 def _sizes(layout):
-    """(group sizes (G,), buffer rows) as `_moe_grouped` hands them."""
+    """(the groups' extents (G,), the real rows at the head of each (G,),
+    buffer rows) as `_moe_grouped` hands them."""
     one_layer = {
         # every extent whole row tiles (`_aligned_layout` with room)
-        "aligned": ([TM, 2 * TM, TM, TM], 5 * TM),
+        "aligned": ([TM, 2 * TM, TM, TM], None, 5 * TM),
         # packed end to end: tiles shared by two and by three experts
-        "packed": ([100, 300, 40, 72], 2 * TM),
-        "empty_expert": ([200, 0, 56, 256], 2 * TM),
+        "packed": ([100, 300, 40, 72], None, 2 * TM),
+        "empty_expert": ([200, 0, 56, 256], None, 2 * TM),
         # the buffer longer than the extents: its last rows are no one's
-        "rows_past": ([TM, 30, TM, 0], 4 * TM),
+        "rows_past": ([TM, 30, TM, 0], None, 4 * TM),
         # one chip's share: 270 of 13,056 rows land on a held expert
-        "share": ([70, 61, 80, 59], 13056),
+        "share": ([70, 61, 80, 59], None, 13056),
+        # padded extents beside their real rows: experts that fill a
+        # sub-tile's first row, part of one, one, one and a row, all but
+        # a row of the tile, and the tile
+        "padded_tiles": ([TM] * 6, [1, 16, 64, 65, 255, 256], 6 * TM),
+        # an expert of two tiles and 16 rows of a third, between two of
+        # less than a sub-tile
+        "two_tiles_and_16": ([TM, 3 * TM, TM], [40, 2 * TM + 16, 3],
+                             5 * TM),
+        # packed: a group starts inside a tile and ends inside the next
+        "packed_across": ([150, 200, 20], None, 2 * TM),
+        # an empty group between two full ones
+        "empty_between": ([TM, 0, TM], [TM, 0, TM], 2 * TM),
+        # the first experts padded, the last packed where room ran out
+        "room_ran_out": ([TM, TM, 70, 30], [130, 9, 70, 30], 3 * TM),
     }
     if layout == "stack":  # L * E groups, every other layer's empty
         sizes = np.zeros((LAYERS * E,), np.int32)
         sizes[LAYER * E:(LAYER + 1) * E] = [100, 300, 40, 72]
-        return sizes, 2 * TM
-    sizes, m = one_layer[layout]
-    return np.asarray(sizes, np.int32), m
+        return sizes, sizes, 2 * TM
+    sizes, real, m = one_layer[layout]
+    sizes = np.asarray(sizes, np.int32)
+    return sizes, sizes if real is None else np.asarray(real, np.int32), m
+
+
+def _real_rows(sizes, real):
+    """The buffer rows that are some group's real rows."""
+    starts = np.cumsum(sizes) - sizes
+    return np.concatenate([np.arange(s, s + n) for s, n in zip(starts, real)])
 
 
 def _float64(rows, w_gate, w_up, sizes, activation):
-    """act(rows @ w_gate[g]) * (rows @ w_up[g]) of every group's rows, in
-    float64 from the operands as they are."""
+    """act(rows @ w_gate[g]) * (rows @ w_up[g]) of every row of every
+    group's extent, in float64 from the operands as they are."""
     x = np.asarray(rows.astype(jnp.float32), np.float64)
     out = np.zeros((int(sizes.sum()), w_gate.shape[2]))
     start = 0
@@ -78,23 +100,37 @@ ORDERS = {"visits_outer": (TM, 128, F), "columns_outer": (TM, 128, 128),
     ("float32", "silu"), ("float32", "relu"), ("bfloat16", "silu"),
     ("bfloat16", "relu")])
 @pytest.mark.parametrize("order", list(ORDERS))
-@pytest.mark.parametrize("layout", ["stack", "aligned", "packed",
-                                    "empty_expert", "rows_past", "share"])
-def test_the_kernel_is_the_products_and_the_activation(layout, order, dtype,
-                                                       activation):
-    sizes, m = _sizes(layout)
+@pytest.mark.parametrize("layout", [
+    "stack", "aligned", "packed", "empty_expert", "rows_past", "share",
+    "padded_tiles", "two_tiles_and_16", "packed_across", "empty_between",
+    "room_ran_out"])
+def test_the_kernel_is_the_products_and_the_activation(
+        monkeypatch, layout, order, dtype, activation):
+    sizes, real, m = _sizes(layout)
     ks = jax.random.split(jax.random.key(len(layout)), 3)
     rows = jax.random.normal(ks[0], (m, D)).astype(dtype)
     w_gate, w_up = (
         (jax.random.normal(k, (len(sizes), D, F)) * 0.3).astype(dtype)
         for k in ks[1:])
-    got = gated_grouped_matmul(
-        rows, w_gate, w_up, jnp.asarray(sizes), activation=activation,
-        tiling=ORDERS[order], interpret=True)
+    # the rows no group owns hold NaN: none may reach a real row
+    at = _real_rows(sizes, real)
+    rows = jnp.full_like(rows, jnp.nan).at[at].set(rows[at])
+    args = (rows, w_gate, w_up, jnp.asarray(sizes), jnp.asarray(real))
+    got = gated_grouped_matmul(*args, activation=activation,
+                               tiling=ORDERS[order], interpret=True)
     assert got.shape == (m, F) and got.dtype == jnp.dtype(dtype)
-    n = int(sizes.sum())
-    got = np.asarray(got[:n].astype(jnp.float32), np.float64)
-    want = _float64(rows, w_gate, w_up, sizes, activation)
+    # every real row is, bit for bit, what a visit that computes its whole
+    # row tile in one product gives it (a sub-tile as long as the tile:
+    # the kernel as it was before a visit knew its group's real rows)
+    monkeypatch.setattr(grouped_matmul, "SUB_ROWS", TM)
+    whole_tiles = gated_grouped_matmul.__wrapped__(
+        *args, activation=activation, tiling=ORDERS[order], interpret=True)
+    np.testing.assert_array_equal(
+        np.asarray(got.astype(jnp.float32))[at],
+        np.asarray(whole_tiles.astype(jnp.float32))[at])
+    got = np.asarray(got.astype(jnp.float32), np.float64)[at]
+    want = _float64(rows, w_gate, w_up, sizes, activation)[at]
+    assert np.isfinite(got).all()
     if dtype == "float32":
         np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
     else:
@@ -107,16 +143,30 @@ def test_the_kernel_is_the_products_and_the_activation(layout, order, dtype,
         *(moe._grouped_matmul(rows, w, jnp.asarray(sizes), None, False)
           for w in (w_gate, w_up)), activation)
     np.testing.assert_allclose(
-        got, np.asarray(off_chip[:n].astype(jnp.float32)),
+        got, np.asarray(off_chip.astype(jnp.float32))[at],
         rtol=2.0 ** -6 if dtype == "bfloat16" else 2e-5,
         atol=(2.0 ** -6 if dtype == "bfloat16" else 2e-5)
         * np.abs(want).max())
 
 
+@pytest.mark.parametrize("counts,want", [
+    # an even router at 2,112 tokens of 128 x 8: 132 rows an expert are
+    # three sub-tiles of 64, where a whole row tile is 256
+    ([132] * 128, 128 * 192),
+    # a one-sided one: eight experts hold every row
+    ([2112] * 8 + [0] * 120, 8 * 2112),
+    ([[1, 128, 129, 0], [256, 257, 0, 0]], (1 + 2 + 3 + 4 + 5) * 64),
+], ids=["even", "one_sided", "layers"])
+def test_the_rows_computed_are_the_counts_in_whole_sub_tiles(counts, want):
+    assert grouped_matmul.SUB_ROWS == 64 and TM % grouped_matmul.SUB_ROWS == 0
+    got = grouped_matmul.rows_computed(jnp.asarray(counts, jnp.int32))
+    assert got.dtype == jnp.int32 and int(got) == want
+
+
 def test_a_tiling_that_does_not_divide_is_refused():
     rows, w = jnp.zeros((TM, D)), jnp.zeros((2, D, F))
     with pytest.raises(ValueError, match="does not divide"):
-        gated_grouped_matmul(rows, w, w, jnp.zeros((2,), jnp.int32),
+        gated_grouped_matmul(rows, w, w, *[jnp.zeros((2,), jnp.int32)] * 2,
                              activation="silu", tiling=(TM, 96, F),
                              interpret=True)
 
@@ -170,7 +220,7 @@ def test_compiled_on_tpu_at_the_cells_widths(d, f, activation, tiling):
     rows = jax.random.normal(ks[0], (3 * TM, d)).astype(jnp.bfloat16)
     w_gate, w_up = ((jax.random.normal(k, (4, d, f)) * d ** -0.5).astype(
         jnp.bfloat16) for k in ks[1:])
-    got = gated_grouped_matmul(rows, w_gate, w_up, sizes,
+    got = gated_grouped_matmul(rows, w_gate, w_up, sizes, sizes,
                                activation=activation, tiling=tiling)
     def product(w):  # each group's rows by its matrix, rounded as `gmm`
         return jnp.concatenate([
@@ -209,7 +259,7 @@ def test_a_sorted_layer_is_two_kernels_and_one_array_of_the_experts_width(
         tilings=((TM, 128, f), (TM, f, d))))(
             S((m, d), jnp.bfloat16), S((g, d, f), jnp.bfloat16),
             S((g, d, f), jnp.bfloat16), S((g, f, d), jnp.bfloat16),
-            S((g,), jnp.int32))
+            S((g,), jnp.int32), S((g,), jnp.int32))
     eqns = list(_outer_eqns(jaxpr.jaxpr))
     kernels = [e for e in eqns if e.primitive.name == "pallas_call"]
     assert len(kernels) == 2

@@ -355,16 +355,19 @@ def test_aligned_layout_matches_dense(monkeypatch, e, k, router):
     real = moe._grouped_experts
     monkeypatch.setattr(
         moe, "_grouped_experts",
-        lambda rows, wg, wu, wd, sizes, **kw: seen.append(
-            (rows.shape[0], np.asarray(sizes))) or real(
-                rows, wg, wu, wd, sizes, **kw))
+        lambda rows, wg, wu, wd, sizes, real_rows, **kw: seen.append(
+            (rows.shape[0], np.asarray(sizes), np.asarray(real_rows)))
+        or real(rows, wg, wu, wd, sizes, real_rows, **kw))
     (out_g, _), (out_d, _) = _both_dispatches(monkeypatch, x, layers, cfg)
     np.testing.assert_allclose(np.asarray(out_g), np.asarray(out_d),
                                atol=1e-5, rtol=1e-5)
-    (n_rows, sizes), = seen
+    (n_rows, sizes, real_rows), = seen
     assert n_rows == moe._sorted_buffer_rows(t * k, cfg)
     mine = sizes[LAYER * e:(LAYER + 1) * e]
     assert sizes.sum() == mine.sum() <= n_rows
+    # beside the extents, the assignments that landed at the head of each
+    assert real_rows.sum() == t * k and (real_rows <= sizes).all()
+    assert (-(-real_rows // moe._GMM_ROWS) * moe._GMM_ROWS == sizes).all()
     assert (mine % moe._GMM_ROWS == 0).all()
     if router in ("one_expert", "one_tile"):
         assert mine[1] == -(-t // moe._GMM_ROWS) * moe._GMM_ROWS
@@ -375,6 +378,48 @@ def test_aligned_layout_matches_dense(monkeypatch, e, k, router):
     if (e, k) == (8, 2) and router == "uniform":
         # 320 tokens of 8 x 2: every expert on a tile of its own
         assert (mine == moe._GMM_ROWS).all()
+
+
+@pytest.mark.parametrize("kind", ["every_expert_held", "a_share"])
+def test_a_row_no_assignment_landed_on_reaches_no_token(monkeypatch, kind):
+    """What the experts' way in leans on since a visit computes only the
+    sub-tiles its expert has a row in (`ops/grouped_matmul.py`): with every
+    buffer row that no assignment landed on overwritten by NaN between the
+    experts and the combine (the rest of a padded extent, the rows behind
+    the last expert's), the layer's output is finite and the dense
+    dispatch's, through `_weighted_sum` and, for one chip's share of a
+    wider router, through `_weighted_sum_held`."""
+    e, k, t = 8, 2, 320
+    cfg, layers = _stack(e, k, "float32")
+    if kind == "a_share":  # 8 of 12 routed experts held, 4 identity ones
+        cfg = ModelConfig(**{**cfg.__dict__, "num_routed_experts": 12,
+                             "num_zero_experts": 4,
+                             "routed_scaling_factor": 2.0})
+        layers["router"] = jax.random.normal(
+            jax.random.key(3), (LAYERS, cfg.embed_dim, 16)) * 0.3
+        layers["router_bias"] = jnp.zeros((LAYERS, 16))
+    # room to pad every expert's extent, which tiny widths are not given
+    monkeypatch.setattr(
+        moe, "_sorted_buffer_rows",
+        lambda n, cfg: (n // moe._GMM_ROWS + cfg.num_experts) * moe._GMM_ROWS)
+    holes = []
+    real = moe._grouped_experts
+
+    def holed(rows, wg, wu, wd, sizes, real_rows, **kw):
+        ys = real(rows, wg, wu, wd, sizes, real_rows, **kw)
+        starts = jnp.cumsum(sizes) - sizes
+        at = jnp.arange(ys.shape[0])[:, None]
+        landed = ((at >= starts) & (at < starts + real_rows)).any(1)
+        holes.append(int((~landed).sum()))
+        return jnp.where(landed[:, None], ys, jnp.nan)
+
+    monkeypatch.setattr(moe, "_grouped_experts", holed)
+    x = jax.random.normal(jax.random.key(e), (1, t, cfg.embed_dim))
+    (out_g, _), (out_d, _) = _both_dispatches(monkeypatch, x, layers, cfg)
+    assert holes and holes[0] >= e * moe._GMM_ROWS - t * k > 0
+    assert np.isfinite(np.asarray(out_g)).all()
+    np.testing.assert_allclose(np.asarray(out_g), np.asarray(out_d),
+                               atol=1e-5, rtol=1e-5)
 
 
 def _values(jaxpr):

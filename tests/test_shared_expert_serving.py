@@ -25,6 +25,7 @@ from cloud_server_tpu.inference import engine, paged_engine, paged_server
 from cloud_server_tpu.inference.paged_server import PagedInferenceServer
 from cloud_server_tpu.models import hf_convert, lora, moe, transformer
 from cloud_server_tpu.models.quantization import QTensor, quantize_params
+from cloud_server_tpu.ops import grouped_matmul
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 WINDOW, PAGE, CHUNK = 40, 16, 32
@@ -205,11 +206,66 @@ def test_served_requests_are_both_forwards_and_pages_go_back(model, mode):
         rows = r["assign_total"] // 12
         assert rows * 3 / 8 <= r["assign_peak"] <= rows
         assert "assign_held" not in r
+        # each expert's rows of each layer rounded up to whole sub-tiles
+        sub = grouped_matmul.SUB_ROWS
+        assert r["assign_rows_computed"] % sub == 0
+        assert r["assign_total"] <= r["assign_rows_computed"] \
+            < r["assign_total"] + 4 * 8 * sub
     snap = srv.metrics_snapshot()
     assert snap["cloud_server_expert_assign_total"]["value"] == \
         recs[-1]["assign_total"]
     assert snap["cloud_server_expert_assign_peak"]["value"] == \
         recs[-1]["assign_peak"]
+    assert snap["cloud_server_expert_assign_rows_computed"]["value"] == \
+        recs[-1]["assign_rows_computed"]
+
+
+@pytest.mark.parametrize("router", ["seeded", "one_sided"])
+def test_a_walk_counts_the_rows_the_way_in_computes(model, monkeypatch,
+                                                    router):
+    """`assign_rows_computed`, the third running count of `PagedKVCache.
+    assign`: over a walk's four expert layers, each expert's assignments
+    rounded up to the kernel's sub-tiles (`grouped_matmul.SUB_ROWS`),
+    summed; under the fixture's drawn bias, and under one that sends every
+    token to the same three experts."""
+    _, mcfg, weights, _ = model
+    if router == "one_sided":
+        bias = weights["layers"]["router_bias"]
+        weights = {**weights, "layers": {
+            **weights["layers"],
+            "router_bias": jnp.zeros_like(bias).at[:, 2:5].set(1e3)}}
+    b, w = 4, 75
+    cache = paged_engine.init_paged_cache(
+        mcfg, num_pages=32, page_size=PAGE, batch=b, max_pages_per_slot=8)
+    tokens = jnp.asarray(tokens_of(b * w, 7).reshape(b, w), jnp.int32)
+
+    def walk(weights, cache):
+        loads = []
+        real = moe.moe_mlp_block
+
+        def spy(*a, **kw):
+            out, aux = real(*a, **kw)
+            loads.append(aux["load"])
+            return out, aux
+
+        monkeypatch.setattr(moe, "moe_mlp_block", spy)
+        _, pools = paged_engine.window_forward(
+            weights, tokens, mcfg, cache,
+            logits_at=jnp.zeros((b,), jnp.int32))
+        monkeypatch.setattr(moe, "moe_mlp_block", real)
+        return pools.assign, jnp.stack(loads)
+
+    assign, loads = (np.asarray(a) for a in jax.jit(walk)(weights, cache))
+    sub = grouped_matmul.SUB_ROWS
+    assert loads.shape == (4, 8) and (loads.sum(1) == 3 * b * w).all()
+    total, peak, computed = assign.tolist()
+    assert (total, peak) == (loads.sum(), loads.max())
+    assert computed == (-(-loads // sub) * sub).sum()
+    if router == "one_sided":  # three experts a layer hold every row
+        assert (loads[:, 2:5] == b * w).all()
+        assert computed == 4 * 3 * -(-b * w // sub) * sub
+    else:
+        assert (loads > 0).sum() > 4 * 3 and computed > total
 
 
 def test_the_scopes_are_in_the_lowered_walk_and_in_no_other_models(model):
@@ -229,7 +285,7 @@ def test_the_scopes_are_in_the_lowered_walk_and_in_no_other_models(model):
 
     cache, text = lowered(model[1], model[2])
     assert all(n + "/" in text for n in names)
-    assert cache.assign.shape == (2,)
+    assert cache.assign.shape == (3,)  # total, peak, rows computed
     _, other, weights, _ = make_model()
     cache, text = lowered(other, weights)
     assert not any(n in text for n in ("moe_shared", "lead_dense",
